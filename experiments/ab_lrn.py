@@ -6,13 +6,14 @@ import time
 
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/sparknet_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
+from sparknet_tpu.utils.compile_cache import configure_compile_cache
+configure_compile_cache()
 
 from sparknet_tpu.models import zoo
 from sparknet_tpu.proto import Message

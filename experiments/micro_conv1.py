@@ -6,12 +6,14 @@ import time
 
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/sparknet_jax_cache")
+from sparknet_tpu.utils.compile_cache import configure_compile_cache
+configure_compile_cache()
 
 from tests.test_layers import make_layer
 

@@ -77,11 +77,12 @@ echo "simfleet smoke OK"
 bash scripts/smoke.sh trace || exit 1
 echo "trace smoke OK"
 
-# perf-regression gate: the committed bench_details.json rows must sit
-# within their own noise tolerance of the committed medians (pure JSON
-# compare, no accelerator; a fresh bench run's rows are gated the same
-# way by `python bench.py --check --details <new rows>`)
-python bench.py --check || exit 1
+# perf-regression gate, plumbing only: the made-up fixture rows gate
+# against themselves (pure JSON compare, no accelerator). A chip run's
+# rows are gated by `python bench.py --check --details <new rows>
+# --check-baseline <rows kept from an earlier chip run>`
+python bench.py --check --details tests/fixtures/bench_check_rows.json \
+    || exit 1
 echo "bench --check OK"
 
 set -o pipefail

@@ -16,7 +16,6 @@ import numpy as np
 from ..proto import Message
 from ..models import zoo
 from ..data.transforms import transform_train, transform_test, compute_mean
-from ..data.synthetic import class_gaussian_images
 from ..parallel import make_mesh, DataParallelSolver, LocalSGDSolver
 
 SOURCE_SIZE = 256
@@ -181,14 +180,29 @@ class ImageNetApp:
         return self.solver
 
 
+_PROTO_GRID = 16     # class prototypes are 16x16 blocks, upsampled x16
+
+
 def _synthetic_source(rng, num_classes, batch=64):
-    """Endless (images uint8 (N,3,256,256), labels) batch generator."""
+    """Endless (images uint8 (N,3,256,256), labels) batch generator of
+    class-gaussians: one coarse prototype per class, FIXED for the run
+    and shared by the train and the test stream (so labels carry signal
+    from batch to batch and the test score means something), plus fresh
+    noise per image. Prototypes are kept at 3x16x16 and upsampled, so
+    1000 classes cost 3 MB, not 786 MB a draw."""
+    protos = np.random.RandomState(1234).randn(
+        num_classes, 3, _PROTO_GRID, _PROTO_GRID).astype(np.float32)
+    up = SOURCE_SIZE // _PROTO_GRID
+    noise = np.random.default_rng(int(rng.randint(1 << 31)))
+
     def gen():
         while True:
-            images, labels = class_gaussian_images(
-                batch, shape=(3, SOURCE_SIZE, SOURCE_SIZE),
-                num_classes=num_classes, seed=int(rng.randint(1 << 31)))
-            img8 = np.clip(np.asarray(images) * 32 + 128, 0, 255) \
-                .astype(np.uint8)
-            yield img8, np.asarray(labels)
+            labels = rng.randint(0, num_classes, size=batch).astype(np.int32)
+            images = np.repeat(np.repeat(protos[labels], up, axis=2),
+                               up, axis=3)
+            images = 64.0 * images + 128.0
+            images += noise.standard_normal(images.shape,
+                                            dtype=np.float32) * 32.0
+            img8 = np.clip(images, 0, 255).astype(np.uint8)
+            yield img8, labels
     return gen()

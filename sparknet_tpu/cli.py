@@ -19,15 +19,6 @@ import os
 import sys
 import time
 
-# Honor JAX_PLATFORMS for every verb: deployment sitecustomize modules may
-# force-register an accelerator platform and override the env var's effect
-# (see tests/conftest.py) — "JAX_PLATFORMS=cpu sparknet lm --ep 4" on a
-# virtual CPU mesh must still work on such hosts.
-if os.environ.get("JAX_PLATFORMS"):
-    import jax
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
-
 def _mesh_arg(s):
     """"data=8,seq=2" -> {"data": 8, "seq": 2}; "8" -> {"data": 8}."""
     if s.isdigit():
@@ -2229,6 +2220,10 @@ def main(argv=None):
     args = p.parse_args(argv)
     if args.verb in ("train", "test", "time", "device_query", "cifar",
                      "imagenet", "lm", "serve"):
+        # one persistent compile cache for every verb that compiles,
+        # placed by JAX_COMPILATION_CACHE_DIR or fixed in the checkout
+        from .utils.compile_cache import configure_compile_cache
+        configure_compile_cache()
         # multi-host bootstrap (no-op single-process; SPARKNET_COORDINATOR
         # et al. select the jax.distributed rendezvous — see DEPLOY.md)
         from .parallel import distributed_init

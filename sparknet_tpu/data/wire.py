@@ -3,8 +3,8 @@
 The device-transform split (device_transform.py) already ships raw uint8
 records instead of float32 crops — 3.2-4x fewer bytes. This module is the
 next turn of the same screw, for links where the H2D wire is the bound
-(BENCH_r04: pure transfer ~62 img/s at 192 KB/image vs an 11,913 img/s
-device step):
+(an earlier rig: pure transfer ~62 img/s at 192 KB/image vs an 11,913
+img/s device step):
 
   precrop  — the host slices each record's crop window (using the SAME
              y/x draws that ride along as aux arrays) before shipping, so

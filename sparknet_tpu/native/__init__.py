@@ -3,12 +3,21 @@
 The reference shipped its native engine as a cmake-built libccaffe.so loaded
 via JNA (CaffeLibrary.java:9); here the native surface is the host data
 pipeline only (XLA owns device kernels), compiled lazily with g++ and loaded
-via ctypes. Everything has a numpy fallback — ``available()`` says which
-path is active.
+via ctypes. Everything has a numpy path — ``available()`` says which is
+active. The numpy path is for machines without a compiler: where g++
+exists and the build or load fails, that is an error, not a fallback.
+
+The binary is git-ignored and ``-march=native``, so it is only ever valid
+for the source and the CPU that built it: its file name carries a digest
+of pipeline.cpp, the flags and this machine's CPU flags. A tree copied to
+another machine (or with an edited source) finds no binary under its own
+name and builds one — never an mtime comparison, which a copy resets.
 """
 
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
 import threading
 
@@ -16,7 +25,7 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "pipeline.cpp")
-_SO = os.path.join(_DIR, "libsparknet_native.so")
+_FLAGS = ["-O3", "-march=native", "-shared", "-fPIC", "-std=c++17"]
 _ABI = 3
 
 _lock = threading.Lock()
@@ -24,23 +33,54 @@ _lib = None
 _tried = False
 
 
-def _build():
+def _cpu_flags():
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return line.split(":", 1)[1].strip()
+    except OSError:
+        pass
+    return ""
+
+
+def lib_path():
+    """The binary this source builds to on this machine."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_cpu_flags().encode())
+    return os.path.join(_DIR,
+                        f"libsparknet_native.{h.hexdigest()[:12]}.so")
+
+
+def build():
+    """Compile pipeline.cpp to lib_path(), replacing whatever is there.
+    Raises on failure."""
     # Compile to a per-pid temp file and rename atomically: concurrent
     # builders (pytest workers, multi-host on a shared FS) must never dlopen
     # a partially written .so, and rename() makes the publish atomic.
-    tmp = f"{_SO}.build.{os.getpid()}"
-    base = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17"]
+    so = lib_path()
+    tmp = f"{so}.build.{os.getpid()}"
+    base = ["g++"] + _FLAGS
     try:
         try:
             subprocess.run(base + ["-fopenmp", _SRC, "-o", tmp], check=True,
                            capture_output=True)
         except subprocess.CalledProcessError:   # no libgomp: single-threaded
-            subprocess.run(base + [_SRC, "-o", tmp], check=True,
-                           capture_output=True)
-        os.replace(tmp, _SO)
+            try:
+                subprocess.run(base + [_SRC, "-o", tmp], check=True,
+                               capture_output=True)
+            except subprocess.CalledProcessError as e:
+                raise RuntimeError(
+                    "g++ failed on native/pipeline.cpp:\n"
+                    + e.stderr.decode("utf-8", "replace")[-4000:]) from e
+        os.replace(tmp, so)
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
+    return so
 
 
 def _load():
@@ -49,18 +89,18 @@ def _load():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        try:
-            if not os.path.exists(_SO) or \
-                    os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-                _build()
-            lib = ctypes.CDLL(_SO)
-            if lib.native_abi_version() != _ABI:
-                _build()
-                lib = ctypes.CDLL(_SO)
-            _bind(lib)
-            _lib = lib
-        except Exception:
-            _lib = None
+        so = lib_path()
+        if not os.path.exists(so):
+            if shutil.which("g++") is None:
+                return None              # no compiler here: numpy path
+            build()
+        lib = ctypes.CDLL(so)
+        if lib.native_abi_version() != _ABI:
+            raise RuntimeError(
+                f"{so} reports ABI {lib.native_abi_version()}, the "
+                f"bindings expect {_ABI}: bump _ABI with pipeline.cpp")
+        _bind(lib)
+        _lib = lib
         return _lib
 
 

@@ -16,6 +16,18 @@ import jax.numpy as jnp
 from ..graph.registry import Layer, register
 
 
+# XLA:TPU rewrites convolutions whose batch is under 8 with its
+# space-to-batch pass, and that pass carries its layout on through the
+# pool into this channel-window sum and gets the shape wrong (libtpu
+# 0.0.34: CaffeNet's TEST forward is refused at batch 1-7 with "Binary op
+# with incompatible shapes f32[..,96] and f32[..,92]", the train step
+# aborts the compiler in space_to_batch_converter.cc). A barrier in front
+# of the window sum stops the propagation; batches of 8 and more never
+# enter the pass and keep their program as it was.
+# tests/test_tpu_compile.py::test_caffenet_forward_small_batch_compiles
+_S2B_BATCH = 8
+
+
 def _lrn_mode():
     # read the env var here (NOT via pallas_lrn.lrn_mode) so the default
     # xla path never imports pallas/mosaic at all
@@ -58,6 +70,8 @@ class LRN(Layer):
         else:
             half = (self.size - 1) // 2
             sq = x * x
+            if x.shape[0] < _S2B_BATCH:
+                sq = lax.optimization_barrier(sq)
             ssum = lax.reduce_window(
                 sq, 0.0, lax.add,
                 window_dimensions=(1, self.size, 1, 1),

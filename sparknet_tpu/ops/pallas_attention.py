@@ -21,7 +21,8 @@ streamed innermost (the FlashAttention-2 recurrences):
     dQ_i   += scale * dS_ij K_j
     dK_j   += scale * dS_ij^T Q_i
 
-Interpret mode (CPU tests) engages automatically off-TPU.
+Interpret mode engages on the CPU backend only (the tests); any other
+backend compiles the kernel or raises.
 """
 
 import functools
@@ -30,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .pallas_lrn import _should_interpret
 
 NEG_INF = -1e30
 LANES = 128
@@ -279,8 +282,6 @@ def _flash_backward(q, k, v, o, lse, g, causal, scale, block_q, block_k,
             dv.reshape(b, h, s, d))
 
 
-def _should_interpret():
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

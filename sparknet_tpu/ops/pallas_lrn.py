@@ -36,7 +36,10 @@ SPATIAL_BLOCK = 512
 
 
 def _should_interpret():
-    return jax.default_backend() != "tpu"
+    """Interpret mode is for the CPU tests only. Every other backend
+    compiles the kernel, and raises where it cannot: nothing on the chip
+    path quietly runs the interpreter instead."""
+    return jax.default_backend() == "cpu"
 
 
 def _window_sum(t, size, lo):

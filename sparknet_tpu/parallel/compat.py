@@ -1,36 +1,20 @@
-"""jax API compatibility shims for the parallel layer.
+"""The two jax entry points every mesh solver goes through.
 
-shard_map graduated from ``jax.experimental.shard_map`` into the top
-``jax`` namespace across jax releases, renaming ``check_rep`` to
-``check_vma`` on the way. The mesh solvers must run on both vintages
-(the CI image pins an older jax than TPU pods ship), so every shard_map
-call in this package goes through this wrapper instead of ``jax.*``.
+Both are plain ``jax`` API in the one installation this tree runs on
+(jax 0.9: ``jax.shard_map`` with ``check_vma``, ``jax.lax.axis_size``);
+the names stay so the parallel layer has one place to look when jax
+moves them again.
 """
 
 import jax
 
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None, check_vma=None):
-    """jax.shard_map where available, else jax.experimental.shard_map
-    with check_vma mapped onto the old check_rep flag."""
-    try:
-        sm = jax.shard_map          # new-style (deprecation getattr may
-    except AttributeError:          # raise on older jax)
-        sm = None
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as old_sm
-    kw = {} if check_vma is None else {"check_rep": bool(check_vma)}
-    return old_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def axis_size(name):
-    """jax.lax.axis_size where available (newer jax), else the classic
-    psum-of-ones — only valid inside shard_map/pmap, like the original."""
-    try:
-        return jax.lax.axis_size(name)
-    except AttributeError:
-        return jax.lax.psum(1, name)
+    """Size of a mesh axis — only valid inside shard_map, like psum."""
+    return jax.lax.axis_size(name)

@@ -436,6 +436,12 @@ class TestSimfleetAndCli:
 
 
 # ------------------------------------------------- bench --check --------
+# made-up rows (the file says so itself): they exercise the gate, they
+# were never measured anywhere
+BENCH_ROWS = os.path.join(REPO, "tests", "fixtures",
+                          "bench_check_rows.json")
+
+
 class TestBenchCheck:
     def _run(self, *extra):
         return subprocess.run(
@@ -444,12 +450,12 @@ class TestBenchCheck:
             env=dict(os.environ, JAX_PLATFORMS="cpu"))
 
     def test_committed_rows_pass_the_gate(self):
-        res = self._run()
+        res = self._run("--details", BENCH_ROWS)
         assert res.returncode == 0, res.stderr
         assert "bench --check: OK" in res.stderr
 
     def test_seeded_regression_fails_naming_the_row(self, tmp_path):
-        with open(os.path.join(REPO, "bench_details.json")) as f:
+        with open(BENCH_ROWS) as f:
             d = json.load(f)
         for r in d["rows"]:
             if r.get("model") == "googlenet":
@@ -464,10 +470,10 @@ class TestBenchCheck:
 
     def test_noise_tolerance_widens_to_the_committed_spread(self,
                                                             tmp_path):
-        """The host_fed row's committed windows spread ~27% below the
+        """The host_fed row's baseline windows spread 27% below the
         median; a 20% dip must still pass (the gate is noise-tolerant),
         while a 40% dip fails."""
-        with open(os.path.join(REPO, "bench_details.json")) as f:
+        with open(BENCH_ROWS) as f:
             d = json.load(f)
         for r in d["rows"]:
             if r.get("mode") == "host_fed":
@@ -483,7 +489,7 @@ class TestBenchCheck:
         assert self._run("--details", str(bad)).returncode == 1
 
     def test_missing_row_fails(self, tmp_path):
-        with open(os.path.join(REPO, "bench_details.json")) as f:
+        with open(BENCH_ROWS) as f:
             d = json.load(f)
         d["rows"] = [r for r in d["rows"]
                      if r.get("model") != "googlenet"]

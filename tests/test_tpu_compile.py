@@ -1,0 +1,141 @@
+"""The main path's pallas kernels, compiled for a DESCRIBED v5e at real
+widths — no chip attached, nothing runs (on-chip-measurement guide §2).
+
+Interpret mode (every other kernel test) cannot see what the TPU compiler
+refuses: a slice off the tiling, too much VMEM, a kernel that cannot be
+partitioned. These few compiles (~1-2 s each) guard that at no chip time.
+
+Rules this file keeps, because breaking them turns the whole suite into 0
+passes under the driver's six xdist workers: the topology is described
+inside a module-scoped, non-autouse fixture (never at import, in a skipif,
+in parametrize or in conftest.py); everything built from it is built in a
+fixture or a test; no child process compiles; all such tests live in this
+ONE file, so one worker loads libtpu and keeps it.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from sparknet_tpu.ops import pallas_attention as pa
+from sparknet_tpu.ops import pallas_epilogue as pe
+from sparknet_tpu.ops import pallas_lrn as plrn
+
+LRN = dict(size=5, alpha=1e-4, beta=0.75, k=1.0)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes_dtypes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes_dtypes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# flash attention at the d1024 LM's shape (8 heads -> D=128) and at the
+# half-lane D=64 variant, S=4096, default 512x512 blocks
+@pytest.mark.parametrize("head_dim", [128, 64])
+def test_flash_forward_compiles(one_chip, head_dim):
+    shp = ((4, 8, 4096, head_dim), jnp.bfloat16)
+    scale = head_dim ** -0.5
+    _compile(lambda q, k, v: pa._flash_forward(
+        q, k, v, True, scale, 512, 512, False), one_chip, shp, shp, shp)
+
+
+@pytest.mark.parametrize("head_dim", [128, 64])
+def test_flash_backward_compiles(one_chip, head_dim):
+    shp = ((4, 8, 4096, head_dim), jnp.bfloat16)
+    scale = head_dim ** -0.5
+    # lse exactly as the forward emits it, whatever its layout
+    lse = jax.eval_shape(
+        lambda q: pa._flash_forward(q, q, q, True, scale, 512, 512, True),
+        jax.ShapeDtypeStruct(*shp))[1]
+    _compile(lambda q, k, v, o, lse, g: pa._flash_backward(
+        q, k, v, o, lse, g, True, scale, 512, 512, False),
+        one_chip, shp, shp, shp, shp, (lse.shape, lse.dtype), shp)
+
+
+# LRN where CaffeNet runs it (after each pool) and at GoogLeNet's conv2
+# site, the one 3-op conv+relu+lrn site SPARKNET_EPILOGUE=auto fuses
+CAFFENET_NORM1 = (256, 96, 27, 27)
+CAFFENET_NORM2 = (256, 256, 13, 13)
+GOOGLENET_CONV2 = (256, 192, 56, 56)
+
+
+@pytest.mark.parametrize("shape", [CAFFENET_NORM1, CAFFENET_NORM2,
+                                   GOOGLENET_CONV2])
+def test_lrn_forward_compiles(one_chip, shape):
+    _compile(lambda x: plrn._call_fwd(x, LRN["size"], LRN["alpha"],
+                                      LRN["beta"], LRN["k"], False),
+             one_chip, (shape, jnp.bfloat16))
+
+
+@pytest.mark.parametrize("shape", [CAFFENET_NORM1, CAFFENET_NORM2,
+                                   GOOGLENET_CONV2])
+def test_lrn_backward_compiles(one_chip, shape):
+    _compile(lambda x, g: plrn._call_bwd(x, g, LRN["size"], LRN["alpha"],
+                                         LRN["beta"], LRN["k"], False),
+             one_chip, (shape, jnp.bfloat16), (shape, jnp.bfloat16))
+
+
+@pytest.mark.parametrize("shape", [CAFFENET_NORM1, CAFFENET_NORM2,
+                                   GOOGLENET_CONV2])
+def test_bias_relu_lrn_compiles(one_chip, shape):
+    kernel = functools.partial(pe._bias_relu_lrn_kernel, LRN["size"],
+                               LRN["alpha"], LRN["beta"], LRN["k"])
+    _compile(lambda x, b: pe._call_epilogue(kernel, x, b, False),
+             one_chip, (shape, jnp.bfloat16), ((shape[1],), jnp.float32))
+
+
+def test_bias_relu_compiles(one_chip):
+    _compile(lambda x, b: pe._call_epilogue(pe._bias_relu_kernel, x, b,
+                                            False),
+             one_chip, (GOOGLENET_CONV2, jnp.bfloat16),
+             ((GOOGLENET_CONV2[1],), jnp.float32))
+
+
+# libtpu 0.0.34 refuses CaffeNet's forward at batch 1-7 (the serve tier's
+# small buckets) unless ops/lrn.py keeps XLA's space-to-batch pass out of
+# the LRN window sum: found by chip_smoke.py's serve phase, PR 22
+@pytest.mark.parametrize("batch", [1, 4])
+def test_caffenet_forward_small_batch_compiles(one_chip, batch):
+    from sparknet_tpu.graph.compiler import CompiledNet, TEST
+    from sparknet_tpu.models import zoo
+    from sparknet_tpu.serve.engine import deploy_net_param
+    shape = (batch, 3, 227, 227)
+    net = CompiledNet(deploy_net_param(
+        zoo.caffenet(batch_size=batch, num_classes=1000)), TEST,
+        feed_shapes={"data": shape})
+    params, state = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    def forward(p, s, x):
+        return net.apply(p, s, {"data": x}, train=False)[0]["fc8"]
+
+    jax.jit(forward).lower(
+        on_chip(params), on_chip(state),
+        jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)).compile()
