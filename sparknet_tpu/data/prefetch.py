@@ -28,10 +28,18 @@ import queue
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 
 _END = object()
 _ERR = object()     # a worker died; the queue stays FIFO so items the
                     # worker produced before failing still arrive first
+
+
+def _nbytes(batch):
+    """Bytes of a batch's arrays (0 for what has no ``nbytes``)."""
+    vals = batch.values() if isinstance(batch, dict) else [batch]
+    return sum(int(getattr(v, "nbytes", 0)) for v in vals)
 
 
 class PrefetchIterator:
@@ -50,6 +58,13 @@ class PrefetchIterator:
              is the bound.
     extra: optional static fields (echo factor, wire mode, ingest shard)
            merged into stats() and the ``prefetch`` event.
+    tracer: the obs.trace.Tracer that times both sides of the queue (the
+            process-wide default when None). Per get: ``prefetch.wait``,
+            the time the consumer was blocked. Per item, on the worker's
+            thread: ``prefetch.produce`` (next(source) + transform, with
+            the item's ``bytes``; with workers > 1 it includes the wait
+            for the shared source) and ``prefetch.put_wait``, the time it
+            then stood before a full queue — the producer's slack.
 
     A worker exception is propagated to the consumer exactly once, with
     the original traceback, after any batches produced before the failure;
@@ -59,7 +74,10 @@ class PrefetchIterator:
     """
 
     def __init__(self, source, depth=2, transform=None, workers=1,
-                 metrics=None, name="prefetch", emit_every=100, extra=None):
+                 metrics=None, name="prefetch", emit_every=100, extra=None,
+                 tracer=None):
+        from ..obs.trace import default_tracer
+        self._tracer = tracer if tracer is not None else default_tracer()
         self._q = queue.Queue(maxsize=depth)
         self._transform = transform
         self._stop = threading.Event()
@@ -88,21 +106,28 @@ class PrefetchIterator:
             t.start()
 
     def _run(self):
+        tr = self._tracer
         try:
             while not self._stop.is_set():
-                with self._src_lock:
-                    try:
-                        item = next(self._source)
-                    except StopIteration:
-                        break
-                if self._transform is not None:
-                    item = self._transform(item)
-                while not self._stop.is_set():
-                    try:
-                        self._q.put(item, timeout=0.1)
-                        break
-                    except queue.Full:
-                        continue
+                t0 = tr.now_ns()
+                with TraceAnnotation("sparknet.prefetch.produce"):
+                    with self._src_lock:
+                        try:
+                            item = next(self._source)
+                        except StopIteration:
+                            break
+                    if self._transform is not None:
+                        item = self._transform(item)
+                t1 = tr.now_ns()
+                with TraceAnnotation("sparknet.prefetch.put_wait"):
+                    while not self._stop.is_set():
+                        try:
+                            self._q.put(item, timeout=0.1)
+                            break
+                        except queue.Full:
+                            continue
+                tr.record("prefetch.produce", t0, t1, bytes=_nbytes(item))
+                tr.record("prefetch.put_wait", t1, tr.now_ns())
         except BaseException as e:     # surfaced on the consumer side
             if self._error is None:    # first failure wins
                 self._error = e
@@ -145,7 +170,8 @@ class PrefetchIterator:
             self._empty_gets += 1
         if self._metrics is not None and self._gets % self._emit_every == 0:
             self._emit_stats()
-        item = self._q.get()
+        with self._tracer.hot_span("prefetch.wait"):
+            item = self._q.get()
         if item is _END or item is _ERR:
             self._finish()
         return item
@@ -218,13 +244,8 @@ class H2DStager:
         self._dispatch_s = 0.0              # spk: guarded-by=_lock
         self._wait_s = 0.0                  # spk: guarded-by=_lock
 
-    @staticmethod
-    def _nbytes(batch):
-        vals = batch.values() if isinstance(batch, dict) else [batch]
-        return sum(int(getattr(v, "nbytes", 0)) for v in vals)
-
     def __call__(self, batch):
-        nbytes = self._nbytes(batch)
+        nbytes = _nbytes(batch)
         if self._chaos is not None:
             self._chaos.maybe_slow_h2d(nbytes=nbytes)
         put = self._jax.device_put

@@ -439,7 +439,6 @@ class CompiledNet:
         a site engages only when its whole conv/ReLU(/LRN) window lies
         inside [lo, hi), else the layers run unfused (correct either
         way)."""
-        from . import fission
         skip = set()
         for li in range(lo, hi):
             if li in skip:
@@ -449,44 +448,55 @@ class CompiledNet:
                 for t in tops:
                     blobs[t] = jnp.asarray(batch[t])
                 continue
-            lparams = self.resolve_params(params, lp.name)
-            bvals = [blobs[b] for b in bottoms]
-            lrng = jax.random.fold_in(rng, li) if impl.needs_rng else None
-            fuse = ep.get(li) if ep else None
-            if fuse is not None and max(x for x in fuse
-                                        if x is not None) < hi:
-                from ..ops import pallas_epilogue as pe
-                ri, lrni = fuse
-                bvals = [fission.materialize(v) for v in bvals]
-                y = impl.apply_raw(lparams, bvals, train, lrng)
-                b = lparams[1]
-                skip.add(ri)
-                if lrni is None:
-                    # ReLU is the in-place rebind: the fused output IS
-                    # the conv/relu blob, bit-for-bit
-                    blobs[tops[0]] = pe.bias_relu(y, b)
-                else:
-                    lm = self.layers[lrni][1]
-                    blobs[self.layers[lrni][3][0]] = pe.bias_relu_lrn(
-                        y, b, lm.size, lm.alpha, lm.beta, lm.k)
-                    skip.add(lrni)
-                    # the relu'd pre-LRN blob is never materialized;
-                    # absent, never stale (plan proved no consumer)
-                    blobs.pop(tops[0], None)
-                continue
-            tvals = fission.try_apply(lp, impl, lparams, bvals,
-                                      train, lrng) if fiss else None
-            if tvals is None:
-                # normal path; any virtual concat bottom materializes here
-                bvals = [fission.materialize(v) for v in bvals]
-                if impl.has_state:
-                    tvals, st = impl.apply_stateful(
-                        lparams, state[lp.name], bvals, train, lrng)
-                    new_state[lp.name] = st
-                else:
-                    tvals = impl.apply(lparams, bvals, train, lrng)
-            for t, v in zip(tops, tvals):
-                blobs[t] = v
+            # the layer's name on every op it traces (backward ops carry
+            # it as transpose(jvp(<name>))): what a device trace is read by
+            with jax.named_scope(lp.name):
+                self._apply_layer(li, params, state, new_state, blobs,
+                                  train, rng, fiss, ep, hi, skip)
+
+    def _apply_layer(self, li, params, state, new_state, blobs, train, rng,
+                     fiss, ep, hi, skip):
+        """One non-feed layer of _apply_range — or its whole fused
+        conv/ReLU(/LRN) window, whose other layers join ``skip``."""
+        from . import fission
+        lp, impl, bottoms, tops = self.layers[li]
+        lparams = self.resolve_params(params, lp.name)
+        bvals = [blobs[b] for b in bottoms]
+        lrng = jax.random.fold_in(rng, li) if impl.needs_rng else None
+        fuse = ep.get(li) if ep else None
+        if fuse is not None and max(x for x in fuse if x is not None) < hi:
+            from ..ops import pallas_epilogue as pe
+            ri, lrni = fuse
+            bvals = [fission.materialize(v) for v in bvals]
+            y = impl.apply_raw(lparams, bvals, train, lrng)
+            b = lparams[1]
+            skip.add(ri)
+            if lrni is None:
+                # ReLU is the in-place rebind: the fused output IS
+                # the conv/relu blob, bit-for-bit
+                blobs[tops[0]] = pe.bias_relu(y, b)
+            else:
+                lm = self.layers[lrni][1]
+                blobs[self.layers[lrni][3][0]] = pe.bias_relu_lrn(
+                    y, b, lm.size, lm.alpha, lm.beta, lm.k)
+                skip.add(lrni)
+                # the relu'd pre-LRN blob is never materialized;
+                # absent, never stale (plan proved no consumer)
+                blobs.pop(tops[0], None)
+            return
+        tvals = fission.try_apply(lp, impl, lparams, bvals,
+                                  train, lrng) if fiss else None
+        if tvals is None:
+            # normal path; any virtual concat bottom materializes here
+            bvals = [fission.materialize(v) for v in bvals]
+            if impl.has_state:
+                tvals, st = impl.apply_stateful(
+                    lparams, state[lp.name], bvals, train, lrng)
+                new_state[lp.name] = st
+            else:
+                tvals = impl.apply(lparams, bvals, train, lrng)
+        for t, v in zip(tops, tvals):
+            blobs[t] = v
 
     def _scan_runs(self):
         """Scan-over-layers sites, cached: maximal runs of >= 2
@@ -633,8 +643,10 @@ class CompiledNet:
         def body(x, ps):
             sblobs = {entry: x}
             for j, (lp, impl, bottoms, tops) in enumerate(g0):
-                tvals = impl.apply(ps[j], [sblobs[b] for b in bottoms],
-                                   train, None)
+                # one traced body serves every group: group 0's names
+                with jax.named_scope(lp.name):
+                    tvals = impl.apply(ps[j], [sblobs[b] for b in bottoms],
+                                       train, None)
                 for t, v in zip(tops, tvals):
                     sblobs[t] = v
             return sblobs[body_out], None

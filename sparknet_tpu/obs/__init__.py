@@ -6,8 +6,10 @@ structured way to measure it — loss and timing went to glog and ad-hoc
 prints (SURVEY.md section 5). This package is the measurement layer every
 perf PR reports against:
 
-  trace.py      nested span tracer (JSONL events + Chrome trace_event
-                export) and the steady-state jax.profiler toggle
+  trace.py      the one span system: an always-on ring, profiler
+                annotations on the device trace's clock, JSONL `span`
+                events, jax's compile events, Chrome trace_event export;
+                and the steady-state jax.profiler toggle
   stepstats.py  host-dispatch vs device-wall step accounting, recompile
                 detection, p50/p95/p99 step-time histograms
   comms.py      bytes moved per sync round (ring-allreduce cost model,
@@ -31,7 +33,8 @@ JSONL stream carries spans, steps, comms, recompiles, watchdog barks,
 prefetch gauges, and the training curve together.
 """
 
-from .trace import Tracer, JaxProfiler, chrome_from_spans, export_chrome
+from .trace import (Tracer, JaxProfiler, chrome_from_spans, export_chrome,
+                    default_tracer)
 from .stepstats import StepAccounting, percentiles, device_memory
 from .comms import (CommsMeter, tree_bytes, ring_allreduce_bytes,
                     broadcast_collect_bytes, all_to_all_bytes)
@@ -41,6 +44,7 @@ from .memstats import MemoryMonitor
 
 __all__ = [
     "Tracer", "JaxProfiler", "chrome_from_spans", "export_chrome",
+    "default_tracer",
     "StepAccounting", "percentiles", "device_memory",
     "CommsMeter", "tree_bytes", "ring_allreduce_bytes",
     "broadcast_collect_bytes", "all_to_all_bytes",

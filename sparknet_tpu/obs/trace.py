@@ -1,101 +1,273 @@
-"""Span tracer: nested monotonic-clock spans over the JSONL metrics
-stream.
+"""Span tracer: the program's one span system.
 
-Each completed span emits one ``span`` event (name, start/duration in ms
-relative to the tracer epoch, nesting depth, parent span name, thread id,
-plus caller attrs). Spans nest per-thread via a thread-local stack, so a
-prefetch worker's spans interleave correctly with the training loop's.
-The tracer also keeps a bounded in-memory buffer of completed spans for
-Chrome ``trace_event`` export — load the file in chrome://tracing or
-Perfetto next to a jax.profiler device trace.
+Every span is three things at once:
 
-Timestamps come from the injectable time seam (resilience.seam.Clock),
-on its MONOTONIC source: an NTP step or suspend/resume mid-run cannot
-fold spans over each other (the same fix PR 15 applied to lease ages),
-and a simulated run can hand the tracer a SimClock so spans land on the
-virtual timeline the fleet merger aligns against.
+  * a record in the tracer's in-memory ring (name, start, end, parent
+    span, thread, small attrs such as a step's ``iter``) — always on, a
+    few MB at most, oldest dropped first and counted;
+  * a ``jax.profiler.TraceAnnotation("sparknet." + name)``: with a
+    profiler session open it stands on the ``/host:CPU`` plane in the
+    profiler's own nanoseconds, so an idle gap of the device can be laid
+    against what the host was doing; with no session it is an idle
+    TraceMe (well under a microsecond);
+  * for ``span()`` only, one ``span`` event on the JSONL metrics stream
+    (name, start/duration in ms relative to the tracer epoch, nesting
+    depth, parent, thread id, caller attrs).
+
+The hot loop (``Solver.train_step``, ``PrefetchIterator``) uses
+``hot_span()`` / ``step()`` / ``record()``, which never touch the JSONL
+sink: per-step cost is a few clock reads, idle TraceMes and ring appends.
+Spans nest per thread on ONE process-wide stack, whichever tracer opened
+them, so jax's compile events (``compile.trace`` / ``.lower`` /
+``.backend`` / ``.cache_load``, heard through ``jax.monitoring``) find the
+span open on their thread as parent and land in that span's tracer.
+
+``default_tracer()`` is the process-wide tracer that ``Solver`` and
+``PrefetchIterator`` use when none is passed; ``default_tracer().spans()``
+reads the ring. "Off" means no profiler session is running: there is no
+switch.
+
+Ring timestamps are ``time.perf_counter_ns`` (monotonic: an NTP step or
+a suspend mid-run cannot fold spans over each other).
 
 ``JaxProfiler`` packages the steady-state one-block device-trace toggle
 that used to live inline in cli.cmd_train.
 """
 
+import collections
 import json
 import os
 import threading
-from contextlib import contextmanager
+import time
 
-from ..resilience.seam import WALL_CLOCK
+from jax import monitoring
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+#: records a ring holds: about 75 a second in a host-fed training loop
+#: (three per step, three per prefetched batch), so over a minute and a
+#: half of history in about 3 MB (370 B a record, measured)
+RING = 8192
+
+_tls = threading.local()
+
+
+def _stack():
+    """This thread's open spans, innermost last — shared by every tracer
+    of the process."""
+    st = getattr(_tls, "stack", None)
+    if st is None:
+        st = _tls.stack = []
+    return st
+
+
+class _Span:
+    """One open span: on the thread's stack, annotated for the profiler,
+    recorded into its tracer's ring (and sink, for ``span()``) on exit."""
+
+    __slots__ = ("tracer", "name", "attrs", "to_sink", "parent", "t0",
+                 "_ann")
+
+    def __init__(self, tracer, name, attrs, to_sink):
+        self.tracer, self.name, self.attrs = tracer, name, attrs
+        self.to_sink = to_sink
+
+    def __enter__(self):
+        st = _stack()
+        self.parent = st[-1].name if st else None
+        st.append(self)
+        self._ann = TraceAnnotation("sparknet." + self.name)
+        self._ann.__enter__()
+        self.t0 = self.tracer.now_ns()
+        return self.attrs
+
+    def __exit__(self, *exc):
+        t1 = self.tracer.now_ns()
+        self._ann.__exit__(*exc)
+        st = _stack()
+        st.pop()
+        self.tracer._put(self.name, self.t0, t1, len(st), self.parent,
+                         self.attrs, self.to_sink)
+        return False
+
+
+class _Step(_Span):
+    """A training step on the hot path: the step span (also a
+    ``StepTraceAnnotation("train", step_num=iter)``, which profiler tools
+    group device work by) split into consecutive phases, each a child
+    span carrying the step's ``iter``. After exit ``host_s`` holds the
+    seconds of the last phase — the enqueue of the jitted call, which is
+    what step accounting reports as host dispatch time."""
+
+    __slots__ = ("_step_ann", "_phase", "host_s")
+
+    def __init__(self, tracer, name, it, first_phase):
+        super().__init__(tracer, name, {"iter": it}, False)
+        self._phase = first_phase
+
+    def __enter__(self):
+        self._step_ann = StepTraceAnnotation("train",
+                                             step_num=self.attrs["iter"])
+        self._step_ann.__enter__()
+        super().__enter__()
+        self._phase = self._open(self._phase)
+        return self
+
+    def _open(self, name):
+        phase = _Span(self.tracer, name, self.attrs, False)
+        phase.__enter__()
+        return phase
+
+    def phase(self, name):
+        """End the current phase and begin ``name``."""
+        self._phase.__exit__(None, None, None)
+        self._phase = self._open(name)
+
+    def __exit__(self, *exc):
+        self._phase.__exit__(*exc)
+        self.host_s = (self.tracer.now_ns() - self._phase.t0) * 1e-9
+        super().__exit__(*exc)
+        self._step_ann.__exit__(*exc)
+        return False
 
 
 class Tracer:
-    """Nested spans over a MetricsLogger sink (sink=None -> spans still
-    nest and buffer for Chrome export, nothing hits the JSONL)."""
+    """Nested spans into a ring, the profiler's host plane, and (for
+    ``span()``) a MetricsLogger sink. sink=None -> nothing hits the JSONL."""
 
-    def __init__(self, sink=None, max_buffer=100_000, clock=None):
+    now_ns = staticmethod(time.perf_counter_ns)    # the ring's clock
+
+    def __init__(self, sink=None, max_buffer=RING):
         self.sink = sink
-        self.clock = clock if clock is not None else WALL_CLOCK
-        self.t0 = self.clock.monotonic()
-        self._tls = threading.local()
+        self.t0 = self.now_ns()
         self._lock = threading.Lock()
-        self._buf = []              # spk: guarded-by=_lock
+        self._buf = collections.deque(maxlen=max_buffer)  # spk: guarded-by=_lock
         self.dropped = 0            # spk: guarded-by=_lock
         self.max_buffer = max_buffer
+        _listen_for_compiles()
 
-    def _stack(self):
-        st = getattr(self._tls, "stack", None)
-        if st is None:
-            st = self._tls.stack = []
-        return st
+    _stack = staticmethod(_stack)
 
-    @contextmanager
     def span(self, name, **attrs):
         """Context manager timing one phase; yields the attrs dict so the
-        body can attach fields discovered mid-span (attrs["n"] = ...)."""
-        st = self._stack()
-        parent = st[-1] if st else None
-        st.append(name)
-        start = self.clock.monotonic() - self.t0
-        try:
-            yield attrs
-        finally:
-            st.pop()
-            end = self.clock.monotonic() - self.t0
-            rec = {"name": name, "start_ms": round(start * 1e3, 3),
-                   "dur_ms": round((end - start) * 1e3, 3),
-                   "depth": len(st), "parent": parent,
-                   "tid": threading.get_ident()}
-            rec.update(attrs)
-            self._record(rec)
+        body can attach fields discovered mid-span (attrs["n"] = ...).
+        Recorded in the ring and emitted as a ``span`` event."""
+        return _Span(self, name, attrs, True)
+
+    def hot_span(self, name, **attrs):
+        """``span()`` for the hot loop: ring and profiler only, never the
+        JSONL sink."""
+        return _Span(self, name, attrs, False)
+
+    def step(self, name, it, first_phase):
+        """The hot loop's step span with phases (see ``_Step``)."""
+        return _Step(self, name, it, first_phase)
+
+    def record(self, name, start_ns, end_ns, **small):
+        """A finished span from ``now_ns()`` stamps the caller already
+        holds; its parent is the span open on this thread. Ring only."""
+        st = _stack()
+        self._put(name, start_ns, end_ns, len(st),
+                  st[-1].name if st else None, small, False)
 
     def instant(self, name, **attrs):
         """A zero-duration mark (Chrome 'instant' event)."""
-        rec = {"name": name,
-               "start_ms": round((self.clock.monotonic() - self.t0) * 1e3, 3),
-               "dur_ms": 0.0, "depth": len(self._stack()),
-               "parent": self._stack()[-1] if self._stack() else None,
-               "tid": threading.get_ident()}
-        rec.update(attrs)
-        self._record(rec)
+        now = self.now_ns()
+        st = _stack()
+        self._put(name, now, now, len(st), st[-1].name if st else None,
+                  attrs, True)
 
-    def _record(self, rec):
+    def _put(self, name, start_ns, end_ns, depth, parent, attrs, to_sink):
+        rec = (name, start_ns, end_ns, depth, parent,
+               threading.get_ident(), attrs)
         with self._lock:
-            if len(self._buf) < self.max_buffer:
-                self._buf.append(rec)
-            else:
-                self.dropped += 1
-        if self.sink is not None:
-            self.sink.log("span", **rec)
+            if len(self._buf) == self.max_buffer:
+                self.dropped += 1   # the ring drops its oldest record
+            self._buf.append(rec)
+        if to_sink and self.sink is not None:
+            self.sink.log("span", **self._as_dict(rec))
 
-    def spans(self):
+    def _as_dict(self, rec):
+        name, start_ns, end_ns, depth, parent, tid, attrs = rec
+        out = {"name": name,
+               "start_ms": round((start_ns - self.t0) * 1e-6, 6),
+               "dur_ms": round((end_ns - start_ns) * 1e-6, 6),
+               "depth": depth, "parent": parent, "tid": tid}
+        out.update(attrs)
+        return out
+
+    def spans(self, *names):
+        """The ring's records, oldest first, as dicts (name, start_ms,
+        dur_ms, depth, parent, tid + attrs); only those called one of
+        ``names`` when any are given."""
         with self._lock:
-            return list(self._buf)
+            recs = list(self._buf)
+        return [self._as_dict(r) for r in recs
+                if not names or r[0] in names]
 
     def export_chrome(self, path):
         """Write buffered spans as a Chrome trace_event JSON file."""
         with self._lock:
             # one consistent snapshot: buffer and its drop count
-            spans, dropped = list(self._buf), self.dropped
-        return export_chrome(path, spans, dropped=dropped)
+            recs, dropped = list(self._buf), self.dropped
+        return export_chrome(path, [self._as_dict(r) for r in recs],
+                             dropped=dropped)
+
+
+_default = None
+_listening = False
+# one lock for both module-level singletons; re-entrant because
+# default_tracer() constructs a Tracer, which registers the listener
+_module_lock = threading.RLock()
+
+
+def default_tracer():
+    """The process-wide tracer: what ``Solver`` and ``PrefetchIterator``
+    record into when no tracer is passed, and the handle a reader in the
+    same process uses (``default_tracer().spans("solver.step")``)."""
+    global _default
+    if _default is None:
+        with _module_lock:
+            if _default is None:
+                _default = Tracer()
+    return _default
+
+
+# jax reports each trace, lowering, backend compile and persistent-cache
+# load as it ends, on the thread that did it, with the jitted function's
+# name (not for cache loads: the backend event that follows names it)
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load",
+}
+#: jax reports a trace for every jitted helper it meets while tracing a
+#: program — jnp functions by the thousand, under a millisecond each —
+#: which would flush the ring; a trace is kept from 10 ms up (a training
+#: step traces for seconds)
+_MIN_TRACE_S = 0.01
+
+
+def _on_compile(event, seconds, **kw):
+    name = _COMPILE_EVENTS.get(event)
+    if name is None or (name == "compile.trace" and seconds < _MIN_TRACE_S):
+        return
+    st = _stack()
+    tracer = st[-1].tracer if st else default_tracer()
+    end = tracer.now_ns()
+    tracer.record(name, end - int(seconds * 1e9), end,
+                  seconds=round(seconds, 6), **kw)
+
+
+def _listen_for_compiles():
+    """Register the compile listener, once per process."""
+    global _listening
+    if _listening:
+        return
+    with _module_lock:
+        if not _listening:
+            monitoring.register_event_duration_secs_listener(_on_compile)
+            _listening = True
 
 
 def chrome_from_spans(spans, pid=None):

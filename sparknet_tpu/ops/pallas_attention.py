@@ -140,6 +140,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, LANES), jnp.float32),   # l
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     return out.reshape(b, h, s, d), lse
 
@@ -260,6 +261,7 @@ def _flash_backward(q, k, v, o, lse, g, causal, scale, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(qf, kf, vf, dof, of, lse)
 
     # second kernel iterates (bh, j, i): Q/dO stream innermost
@@ -277,6 +279,7 @@ def _flash_backward(q, k, v, o, lse, g, causal, scale, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dkv",
     )(qf, kf, vf, dof, of, lse)
     return (dq.reshape(b, h, s, d), dk.reshape(b, h, s, d),
             dv.reshape(b, h, s, d))

@@ -64,7 +64,7 @@ def _bias_relu_lrn_kernel(size, alpha, beta, k, x_ref, b_ref, out_ref):
     out_ref[0] = (y * scale ** (-beta)).astype(out_ref.dtype)
 
 
-def _call_epilogue(kernel, x, b, interpret):
+def _call_epilogue(kernel, name, x, b, interpret):
     n, c, h, w = x.shape
     xf = x.reshape(n, c, h * w)
     bt = _bias_tile(b, x.dtype)
@@ -78,6 +78,7 @@ def _call_epilogue(kernel, x, b, interpret):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(xf.shape, x.dtype),
         interpret=interpret,
+        name=name,      # what a device trace and the HLO call it
     )(xf, bt)
     return out.reshape(n, c, h, w)
 
@@ -86,7 +87,8 @@ def _call_epilogue(kernel, x, b, interpret):
 @jax.custom_vjp
 def bias_relu(x, b):
     """max(x + b[None,:,None,None], 0) on NCHW, one fused pass."""
-    return _call_epilogue(_bias_relu_kernel, x, b, _should_interpret())
+    return _call_epilogue(_bias_relu_kernel, "bias_relu", x, b,
+                          _should_interpret())
 
 
 def _br_fwd(x, b):
@@ -112,7 +114,7 @@ def bias_relu_lrn(x, b, size, alpha, beta, k):
     """lrn_across(max(x + b, 0)) on NCHW in ONE fused read/write."""
     return _call_epilogue(
         functools.partial(_bias_relu_lrn_kernel, size, alpha, beta, k),
-        x, b, _should_interpret())
+        "bias_relu_lrn", x, b, _should_interpret())
 
 
 def _brl_fwd(x, b, size, alpha, beta, k):

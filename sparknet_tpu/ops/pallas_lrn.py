@@ -85,6 +85,7 @@ def _call_fwd(x, size, alpha, beta, k, interpret):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(xf.shape, x.dtype),
         interpret=interpret,
+        name="lrn_fwd",
     )(xf)
     return out.reshape(n, c, h, w)
 
@@ -102,6 +103,7 @@ def _call_bwd(x, g, size, alpha, beta, k, interpret):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(xf.shape, g.dtype),
         interpret=interpret,
+        name="lrn_bwd",
     )(xf, gf)
     return dx.reshape(n, c, h, w)
 

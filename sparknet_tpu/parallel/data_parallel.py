@@ -422,24 +422,25 @@ class DataParallelSolver(Solver):
                 paper_broadcast_collect_bytes=broadcast_collect_bytes(gb, n))
 
     def train_step(self, batch):
-        batch = {k: np.asarray(v) for k, v in batch.items()}
-        iter_size = int(self.param.iter_size)
-        self.check_batch(batch, leading=(iter_size,) if iter_size > 1 else ())
-        if self._jit_train is None:
-            self._jit_train = self._sharded_step(batch)
-        self.rng, key = jax.random.split(self.rng)
-        import time as _t
-        t0 = _t.perf_counter()
-        dev_batch = shard_batch(batch, self.mesh, self.axis,
-                                batch_dim=0 if int(self.param.iter_size) == 1
-                                else 1)
-        self.params, self.state, self.history, loss, aux = self._jit_train(
-            self.params, self.state, self.history, dev_batch,
-            jnp.asarray(self.iter, jnp.int32), key, self._alive_mask(),
-            self._staleness_lag())
-        self.iter += 1
-        host_s = _t.perf_counter() - t0
-        self._timing["train_step"] += host_s
+        with self._step_span() as span:
+            batch = {k: np.asarray(v) for k, v in batch.items()}
+            iter_size = int(self.param.iter_size)
+            self.check_batch(batch,
+                             leading=(iter_size,) if iter_size > 1 else ())
+            if self._jit_train is None:
+                self._jit_train = self._sharded_step(batch)
+            self.rng, key = jax.random.split(self.rng)
+            # the enqueue counts laying the batch over the mesh
+            span.phase("solver.enqueue")
+            dev_batch = shard_batch(batch, self.mesh, self.axis,
+                                    batch_dim=0 if iter_size == 1 else 1)
+            self.params, self.state, self.history, loss, aux = \
+                self._jit_train(
+                    self.params, self.state, self.history, dev_batch,
+                    jnp.asarray(self.iter, jnp.int32), key,
+                    self._alive_mask(), self._staleness_lag())
+            self.iter += 1
+        host_s = span.host_s
         if self.staleness is not None and self.elastic is not None:
             # step-granularity version clocks: the DP twin of the
             # LocalSGD round bookkeeping (park/unpark events flow from
@@ -956,6 +957,26 @@ class LocalSGDSolver(Solver):
         if quorum_err is not None:
             raise quorum_err
 
+    def _enqueue_round(self, batches):
+        """Dispatch one compiled round of tau steps under the step span
+        (the enqueue counts laying the batches over the mesh) and advance
+        ``iter``. -> (the closed span, loss, aux)."""
+        with self._step_span() as span:
+            if self._jit_round is None:
+                self._jit_round = self._build_round(batches)
+            self.rng, key = jax.random.split(self.rng)
+            shard_axes = (self.host_axis, self.axis) \
+                if self.host_axis is not None else self.axis
+            span.phase("solver.enqueue")
+            dev = shard_batch(batches, self.mesh, shard_axes, batch_dim=1)
+            self.params, self.state, self.history, loss, aux = \
+                self._jit_round(
+                    self.params, self.state, self.history, dev,
+                    jnp.asarray(self.iter, jnp.int32), key,
+                    self._alive_mask(), self._staleness_lag())
+            self.iter += self.tau
+        return span, loss, aux
+
     def _train_round_relay(self, batches):
         """The cross-host tier over the rendezvous directory
         (heartbeat.FileConsensus): run the LOCAL compiled round (tier 1
@@ -967,17 +988,7 @@ class LocalSGDSolver(Solver):
         import math as _m
         import time as _t
         t0 = _t.perf_counter()
-        if self._jit_round is None:
-            self._jit_round = self._build_round(batches)
-        self.rng, key = jax.random.split(self.rng)
-        shard_axes = (self.host_axis, self.axis) \
-            if self.host_axis is not None else self.axis
-        dev = shard_batch(batches, self.mesh, shard_axes, batch_dim=1)
-        self.params, self.state, self.history, loss, _ = self._jit_round(
-            self.params, self.state, self.history, dev,
-            jnp.asarray(self.iter, jnp.int32), key, self._alive_mask(),
-            self._staleness_lag())
-        self.iter += self.tau
+        _, loss, _ = self._enqueue_round(batches)
         # tier 2: fetch (replicated locally — one local device read),
         # exchange through the directory, adopt the consensus
         leaves_p, tdef_p = jax.tree_util.tree_flatten(
@@ -1020,9 +1031,7 @@ class LocalSGDSolver(Solver):
         vv = np.asarray(aux["valid"], np.float64) > 0
         round_loss = float(np.nanmean(wl[vv])) if vv.any() \
             else local_loss
-        host_s = _t.perf_counter() - t0
-        self._timing["train_round"] += host_s
-        self._obs_step(host_s, round_loss, batches)
+        self._obs_step(_t.perf_counter() - t0, round_loss, batches)
         out = self._chaos_loss(jnp.float32(round_loss))
         self._observe_sync_round(
             dict(aux, kind="params"),
@@ -1050,21 +1059,9 @@ class LocalSGDSolver(Solver):
             self._heartbeat_gate(timeout=0.0 if async_on else None)
         if self._relay is not None:
             return self._train_round_relay(batches)
-        if self._jit_round is None:
-            self._jit_round = self._build_round(batches)
-        self.rng, key = jax.random.split(self.rng)
-        t0 = _t.perf_counter()
-        shard_axes = (self.host_axis, self.axis) \
-            if self.host_axis is not None else self.axis
-        dev = shard_batch(batches, self.mesh, shard_axes, batch_dim=1)
-        self.params, self.state, self.history, loss, aux = self._jit_round(
-            self.params, self.state, self.history, dev,
-            jnp.asarray(self.iter, jnp.int32), key, self._alive_mask(),
-            self._staleness_lag())
-        self.iter += self.tau
-        host_s = _t.perf_counter() - t0
-        self._timing["train_round"] += host_s
-        self._obs_step(host_s, loss, batches)
+        span, loss, aux = self._enqueue_round(batches)
+        t0 = _t.perf_counter() - span.host_s    # when the enqueue began
+        self._obs_step(span.host_s, loss, batches)
         loss = self._chaos_loss(loss)   # may stall (the injected straggler)
         if self.chaos is not None and not async_on:
             # a chaos slow_worker under the SYNCHRONOUS barrier is a

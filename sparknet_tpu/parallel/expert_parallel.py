@@ -252,27 +252,25 @@ class ExpertParallelSolver(Solver):
                            seq_axis=self.seq_axis, global_feed=True)
 
     def train_step(self, batch):
-        import time as _time
-        self.check_batch(batch, split_across_hosts=False)
-        if not getattr(self, "_feed_checked", False):
-            self._feed_checked = True
-            check_global_feed(batch)
-        self.rng, key = jax.random.split(self.rng)
-        t0 = _time.perf_counter()
-        with self._axes_context():
-            if self._jit_train is None:
-                self._jit_train = self._sharded_step(batch)
-            dev = self._shard(batch)
-            if self._it_dev is None:
-                self._it_dev = jnp.asarray(self.iter, jnp.int32)
-            (self.params, self.state, self.history, loss,
-             self._it_dev, aux) = self._jit_train(
-                self.params, self.state, self.history, dev,
-                self._it_dev, key)
-        self.iter += 1
-        host_s = _time.perf_counter() - t0
-        self._timing["train_step"] += host_s
-        self._obs_step(host_s, loss, batch, aux=aux or None)
+        with self._step_span() as span:
+            self.check_batch(batch, split_across_hosts=False)
+            if not getattr(self, "_feed_checked", False):
+                self._feed_checked = True
+                check_global_feed(batch)
+            self.rng, key = jax.random.split(self.rng)
+            span.phase("solver.enqueue")
+            with self._axes_context():
+                if self._jit_train is None:
+                    self._jit_train = self._sharded_step(batch)
+                dev = self._shard(batch)
+                if self._it_dev is None:
+                    self._it_dev = jnp.asarray(self.iter, jnp.int32)
+                (self.params, self.state, self.history, loss,
+                 self._it_dev, aux) = self._jit_train(
+                    self.params, self.state, self.history, dev,
+                    self._it_dev, key)
+            self.iter += 1
+        self._obs_step(span.host_s, loss, batch, aux=aux or None)
         return loss
 
     def _build_eval_step(self):

@@ -15,6 +15,7 @@ weight copies (Net.scala:126-148) that the reference paid per sync round.
 
 import collections
 import os
+import re
 import time
 
 import numpy as np
@@ -73,17 +74,22 @@ class Solver:
                  tracer=None):
         self.param = solver_param
         self.log = log_fn or (lambda *a: None)
-        # structured observability hooks, armed by default from the CLI:
-        # a JSONL MetricsLogger (or path), a span Tracer over it, step
-        # accounting + comms metering (sparknet_tpu.obs), and an optional
-        # Watchdog that step() beats once per iteration
+        # structured observability hooks: a JSONL MetricsLogger (or
+        # path) armed by default from the CLI, with step accounting +
+        # comms metering over it (sparknet_tpu.obs), an optional Watchdog
+        # that step() beats once per iteration — and the span tracer,
+        # which is always on: the caller's, one over this solver's own
+        # metrics stream, or the process-wide default
         self._own_metrics = isinstance(metrics, str)
         if isinstance(metrics, str):
             from ..utils.metrics import MetricsLogger
             metrics = MetricsLogger(metrics)
         self.metrics = metrics
-        from ..obs import Tracer
-        self.tracer = tracer if tracer is not None else Tracer(self.metrics)
+        from ..obs.trace import Tracer, default_tracer
+        if tracer is None:
+            tracer = Tracer(self.metrics) if self.metrics is not None \
+                else default_tracer()
+        self.tracer = tracer
         self.stepstats = self.comms = None
         self._comms_registered = False
         # training-dynamics health layer (obs divergence/health/memstats):
@@ -125,43 +131,49 @@ class Solver:
         from ..resilience.chaos import active_chaos
         self.chaos = active_chaos()
         train_np, test_np = resolve_nets(solver_param, base_dir, net_param)
-        # NetState from the solver (reference solver.cpp InitTrainNet /
-        # InitTestNets: train_state / test_state merge into the filter
-        # state — e.g. mnist_autoencoder_solver's per-test-net
-        # 'test-on-train'/'test-on-test' stages select among same-named
-        # Data layers). Like the single test_net, only test_state[0] is
-        # instantiated here.
-        ts = solver_param.train_state \
-            if solver_param.has("train_state") else None
-        self.net = CompiledNet(train_np, TRAIN, feed_shapes=feed_shapes,
-                               dtype=dtype, compute_dtype=compute_dtype,
-                               level=int(ts.level) if ts else 0,
-                               stages=tuple(ts.stage) if ts else ())
-        self.test_net = None
-        if test_np is not None:
-            es = solver_param.test_state[0] \
-                if solver_param.test_state else None
-            try:
-                self.test_net = CompiledNet(
-                    test_np, TEST,
-                    feed_shapes=test_feed_shapes or feed_shapes, dtype=dtype,
+        with self.tracer.hot_span("solver.init"):
+            with self.tracer.hot_span("net.build"):
+                # NetState from the solver (reference solver.cpp
+                # InitTrainNet / InitTestNets: train_state / test_state
+                # merge into the filter state — e.g.
+                # mnist_autoencoder_solver's per-test-net 'test-on-train'
+                # /'test-on-test' stages select among same-named Data
+                # layers). Like the single test_net, only test_state[0]
+                # is instantiated here.
+                ts = solver_param.train_state \
+                    if solver_param.has("train_state") else None
+                self.net = CompiledNet(
+                    train_np, TRAIN, feed_shapes=feed_shapes, dtype=dtype,
                     compute_dtype=compute_dtype,
-                    level=int(es.level) if es else 0,
-                    stages=tuple(es.stage) if es else ())
-            except ValueError:
-                # a shared `net` whose data layer is TRAIN-only has no
-                # TEST-phase graph; without a test_iter schedule the
-                # reference never instantiates test nets at all
-                # (solver.cpp InitTestNets), so train-only it is
-                if sp_test_scheduled(solver_param):
-                    raise
-                self.log("No TEST-phase net; training without a test net")
-
-        seed = int(solver_param.random_seed)
-        self.rng = jax.random.PRNGKey(seed if seed >= 0 else
-                                      int(time.time_ns() % (2 ** 31)))
-        self.rng, init_key = jax.random.split(self.rng)
-        self.params, self.state = self.net.init(init_key)
+                    level=int(ts.level) if ts else 0,
+                    stages=tuple(ts.stage) if ts else ())
+                self.test_net = None
+                if test_np is not None:
+                    es = solver_param.test_state[0] \
+                        if solver_param.test_state else None
+                    try:
+                        self.test_net = CompiledNet(
+                            test_np, TEST,
+                            feed_shapes=test_feed_shapes or feed_shapes,
+                            dtype=dtype, compute_dtype=compute_dtype,
+                            level=int(es.level) if es else 0,
+                            stages=tuple(es.stage) if es else ())
+                    except ValueError:
+                        # a shared `net` whose data layer is TRAIN-only
+                        # has no TEST-phase graph; without a test_iter
+                        # schedule the reference never instantiates test
+                        # nets at all (solver.cpp InitTestNets), so
+                        # train-only it is
+                        if sp_test_scheduled(solver_param):
+                            raise
+                        self.log("No TEST-phase net; training without a "
+                                 "test net")
+            seed = int(solver_param.random_seed)
+            self.rng = jax.random.PRNGKey(seed if seed >= 0 else
+                                          int(time.time_ns() % (2 ** 31)))
+            self.rng, init_key = jax.random.split(self.rng)
+            with self.tracer.hot_span("net.init"):
+                self.params, self.state = self.net.init(init_key)
 
         mults = {}
         for lname, refs in self.net.param_refs.items():
@@ -178,7 +190,6 @@ class Solver:
             maxlen=max(1, int(solver_param.average_loss)))
         self._jit_train = None
         self._jit_eval = None
-        self._timing = collections.defaultdict(float)
         # optional on-device input transforms (data/device_transform.py):
         # pure fns applied to the feed dict INSIDE the jitted step, letting
         # the host ship raw uint8 records + tiny offset arrays instead of
@@ -256,7 +267,9 @@ class Solver:
             return net.loss_fn
 
         def lf(params, state, batch, rng):
-            return net.loss_fn(params, state, tf(batch), rng)
+            with jax.named_scope("input_transform"):
+                batch = tf(batch)
+            return net.loss_fn(params, state, batch, rng)
         return lf
 
     # -- compiled steps ----------------------------------------------------
@@ -302,6 +315,27 @@ class Solver:
         return {"argument_bytes": arg, "output_bytes": out,
                 "temp_bytes": tmp, "alias_bytes": ali,
                 "peak_bytes": arg + out + tmp - ali}
+
+    def op_scopes(self, batch):
+        """{HLO instruction name: op path} of the COMPILED train step,
+        e.g. "fusion.306" -> "jit(step)/transpose(jvp(conv1))/
+        conv_general_dilated": the layer (its ``jax.named_scope``) and the
+        direction (``jvp`` forward, ``transpose(jvp(...))`` backward,
+        ``update``, ``input_transform``) of what a device trace lists
+        under XLA's own names. jax.profiler.ProfileData shows a TPU
+        event's instruction name and no path (the raw XSpace keeps it as
+        ``tf_op`` on the event's metadata), so a reducer joins on this. A
+        fusion answers with the path of its root; what XLA made itself
+        (a relayout) has none. jax leaves metadata out of the persistent
+        cache's key: an executable loaded from a cache that an older
+        commit wrote answers with the paths of the compile that wrote it
+        (PERF.md section 3)."""
+        batch = {k: jnp.asarray(v) for k, v in batch.items()}
+        text = self._memory_step_fn(batch).lower(
+            *self._memory_step_args(batch)).compile().as_text()
+        return dict(re.findall(
+            r'^\s*(?:ROOT )?%?([\w.\-]+) = .*metadata=\{[^}]*'
+            r'op_name="([^"]+)"', text, re.M))
 
     def _train_step_fn(self):
         """The pure (uncompiled) train step — subclasses re-jit it with
@@ -894,24 +928,31 @@ class Solver:
                     + (f" (this host's slice of {pcount} hosts)"
                        if pcount > 1 else ""))
 
+    def _step_span(self):
+        """The span every train_step / train_round variant runs under:
+        ``solver.step`` with its ``iter``, opening in its ``solver.prep``
+        phase (batch check, key split, wrapping); the caller switches to
+        ``solver.enqueue`` at the jitted call."""
+        return self.tracer.step("solver.step", self.iter, "solver.prep")
+
     def train_step(self, batch):
         """One optimization step; returns the (unsmoothed) loss value."""
-        if self._jit_train is None:
-            self._jit_train = self._build_train_step()
-        iter_size = int(self.param.iter_size)
-        self.check_batch(batch, leading=(iter_size,) if iter_size > 1 else ())
-        self.rng, key = jax.random.split(self.rng)
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        t0 = time.perf_counter()
-        if self._it_dev is None:
-            self._it_dev = jnp.asarray(self.iter, jnp.int32)
-        self.params, self.state, self.history, loss, self._it_dev = \
-            self._jit_train(self.params, self.state, self.history, batch,
-                            self._it_dev, key)
-        self.iter += 1
-        host_s = time.perf_counter() - t0
-        self._timing["train_step"] += host_s
-        self._obs_step(host_s, loss, batch)
+        with self._step_span() as span:
+            if self._jit_train is None:
+                self._jit_train = self._build_train_step()
+            iter_size = int(self.param.iter_size)
+            self.check_batch(batch,
+                             leading=(iter_size,) if iter_size > 1 else ())
+            self.rng, key = jax.random.split(self.rng)
+            batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            if self._it_dev is None:
+                self._it_dev = jnp.asarray(self.iter, jnp.int32)
+            span.phase("solver.enqueue")
+            self.params, self.state, self.history, loss, self._it_dev = \
+                self._jit_train(self.params, self.state, self.history, batch,
+                                self._it_dev, key)
+            self.iter += 1
+        self._obs_step(span.host_s, loss, batch)
         return self._chaos_loss(loss)
 
     def step(self, num_iters, data_iter, test_data_fn=None):
@@ -959,7 +1000,9 @@ class Solver:
             disp = sp.display and (self.iter - 1) % sp.display == 0
             if not disp:
                 if self.iter % self._sync_stride == 0:
-                    v = float(loss)
+                    with self.tracer.hot_span("solver.fetch",
+                                              iter=self.iter - 1):
+                        v = float(loss)
                     if self.watchdog is not None:
                         self.watchdog.beat(v)
                     if self._maybe_recover(v):
@@ -969,7 +1012,9 @@ class Solver:
                     self.watchdog.beat()
             if disp:
                 # ONE fetch for the whole smoothing window
-                sm = self.smoothed_loss()
+                with self.tracer.hot_span("solver.fetch",
+                                          iter=self.iter - 1):
+                    sm = self.smoothed_loss()
                 if self.watchdog is not None:
                     self.watchdog.beat(sm)
                 if self._maybe_recover(sm):

@@ -156,6 +156,7 @@ class Updater:
     def init(self, params):
         return init_history(self.solver_type, params)
 
+    @jax.named_scope("update")      # the optimizer's share of a trace
     def __call__(self, params, grads, history, rate, it, clip_fn=None):
         """One update: returns (new_params, new_history).
 

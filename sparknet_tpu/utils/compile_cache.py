@@ -15,6 +15,15 @@ host's CPU may not run on this one.
 
 import os
 
+#: frames of the Python call stack a lowered op's location keeps. One, by
+#: limiting the traceback and NOT by switching
+#: jax_include_full_tracebacks_in_locations off, which PR 22 did: that
+#: also moves the name stack out of the op's name, and the compiled step's
+#: op_name then reads "conv_general_dilated" where it should read
+#: "jit(step)/jvp(conv1)/conv_general_dilated" — every jax.named_scope
+#: lost on the way to the profile (PR 25, chip traces).
+LOCATION_FRAMES = 1
+
 CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".jax_cache")
@@ -36,7 +45,7 @@ def configure_compile_cache():
     # bench.py, chip_smoke.py's HLO child) got another cache key, and the
     # d1024 LM step never hit (PR 22, chip runs 1-2). The innermost frame
     # is location enough.
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    jax.config.update("jax_traceback_in_locations_limit", LOCATION_FRAMES)
     if env:
         return env
     jax.config.update("jax_compilation_cache_dir", CACHE_DIR)

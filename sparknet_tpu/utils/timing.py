@@ -1,5 +1,5 @@
 """Timing — the reference util/benchmark.cpp Timer/CPUTimer, plus a
-step-rate tracker and XLA profiler hookup.
+step-rate tracker.
 
 Reference Timer used CUDA events for device-accurate timing; on TPU the
 analog is waiting for the device before reading the clock. A value fetch
@@ -7,7 +7,6 @@ analog is waiting for the device before reading the clock. A value fetch
 probe times the two on every run and prints which waited.
 """
 
-import contextlib
 import time
 
 import numpy as np
@@ -66,15 +65,3 @@ class StepTimer:
         if not ts:
             return float("nan")
         return sum(b for _, b in ts) / sum(t for t, _ in ts)
-
-
-@contextlib.contextmanager
-def xla_profile(log_dir="/tmp/sparknet_profile"):
-    """Capture an XLA profiler trace around a block (view with
-    tensorboard/xprof) — the `caffe time` deep-dive analog on TPU."""
-    import jax
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield log_dir
-    finally:
-        jax.profiler.stop_trace()

@@ -44,11 +44,17 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, one_chip, *shapes_dtypes):
+def _compile(fn, one_chip, *shapes_dtypes, kernels=()):
+    """Compile for the described chip; the program holds a TPU kernel, and
+    each of `kernels` as an instruction under the name its pallas_call
+    gives (what a device trace then calls it)."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
             for s, d in shapes_dtypes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for name in kernels:
+        assert f"%{name}" in text and f"/{name}/pallas_call" in text, name
     return compiled
 
 
@@ -59,7 +65,8 @@ def test_flash_forward_compiles(one_chip, head_dim):
     shp = ((4, 8, 4096, head_dim), jnp.bfloat16)
     scale = head_dim ** -0.5
     _compile(lambda q, k, v: pa._flash_forward(
-        q, k, v, True, scale, 512, 512, False), one_chip, shp, shp, shp)
+        q, k, v, True, scale, 512, 512, False), one_chip, shp, shp, shp,
+        kernels=["flash_fwd"])
 
 
 @pytest.mark.parametrize("head_dim", [128, 64])
@@ -72,7 +79,8 @@ def test_flash_backward_compiles(one_chip, head_dim):
         jax.ShapeDtypeStruct(*shp))[1]
     _compile(lambda q, k, v, o, lse, g: pa._flash_backward(
         q, k, v, o, lse, g, True, scale, 512, 512, False),
-        one_chip, shp, shp, shp, shp, (lse.shape, lse.dtype), shp)
+        one_chip, shp, shp, shp, shp, (lse.shape, lse.dtype), shp,
+        kernels=["flash_dq", "flash_dkv"])
 
 
 # LRN where CaffeNet runs it (after each pool) and at GoogLeNet's conv2
@@ -87,7 +95,7 @@ GOOGLENET_CONV2 = (256, 192, 56, 56)
 def test_lrn_forward_compiles(one_chip, shape):
     _compile(lambda x: plrn._call_fwd(x, LRN["size"], LRN["alpha"],
                                       LRN["beta"], LRN["k"], False),
-             one_chip, (shape, jnp.bfloat16))
+             one_chip, (shape, jnp.bfloat16), kernels=["lrn_fwd"])
 
 
 @pytest.mark.parametrize("shape", [CAFFENET_NORM1, CAFFENET_NORM2,
@@ -95,7 +103,8 @@ def test_lrn_forward_compiles(one_chip, shape):
 def test_lrn_backward_compiles(one_chip, shape):
     _compile(lambda x, g: plrn._call_bwd(x, g, LRN["size"], LRN["alpha"],
                                          LRN["beta"], LRN["k"], False),
-             one_chip, (shape, jnp.bfloat16), (shape, jnp.bfloat16))
+             one_chip, (shape, jnp.bfloat16), (shape, jnp.bfloat16),
+             kernels=["lrn_bwd"])
 
 
 @pytest.mark.parametrize("shape", [CAFFENET_NORM1, CAFFENET_NORM2,
@@ -103,15 +112,17 @@ def test_lrn_backward_compiles(one_chip, shape):
 def test_bias_relu_lrn_compiles(one_chip, shape):
     kernel = functools.partial(pe._bias_relu_lrn_kernel, LRN["size"],
                                LRN["alpha"], LRN["beta"], LRN["k"])
-    _compile(lambda x, b: pe._call_epilogue(kernel, x, b, False),
-             one_chip, (shape, jnp.bfloat16), ((shape[1],), jnp.float32))
+    _compile(lambda x, b: pe._call_epilogue(kernel, "bias_relu_lrn", x, b,
+                                            False),
+             one_chip, (shape, jnp.bfloat16), ((shape[1],), jnp.float32),
+             kernels=["bias_relu_lrn"])
 
 
 def test_bias_relu_compiles(one_chip):
-    _compile(lambda x, b: pe._call_epilogue(pe._bias_relu_kernel, x, b,
-                                            False),
+    _compile(lambda x, b: pe._call_epilogue(pe._bias_relu_kernel,
+                                            "bias_relu", x, b, False),
              one_chip, (GOOGLENET_CONV2, jnp.bfloat16),
-             ((GOOGLENET_CONV2[1],), jnp.float32))
+             ((GOOGLENET_CONV2[1],), jnp.float32), kernels=["bias_relu"])
 
 
 # libtpu 0.0.34 refuses CaffeNet's forward at batch 1-7 (the serve tier's
@@ -139,3 +150,41 @@ def test_caffenet_forward_small_batch_compiles(one_chip, batch):
     jax.jit(forward).lower(
         on_chip(params), on_chip(state),
         jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)).compile()
+
+
+def test_kernel_bytes_ignore_the_call_stack_and_op_names_keep_the_scope(
+        one_chip):
+    """What utils/compile_cache.py sets before the first compile on a chip:
+    a kernel's serialized MLIR (part of the step's cache key) is the same
+    from any entry point, and the compiled op_name still carries the
+    named_scope path."""
+    import re
+    from sparknet_tpu.utils.compile_cache import LOCATION_FRAMES
+    args = [jax.ShapeDtypeStruct((8, 192, 56, 56), jnp.bfloat16,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((192,), jnp.float32, sharding=one_chip)]
+
+    def loss(x, b):
+        with jax.named_scope("conv2"):
+            y = plrn._call_fwd(x + b[None, :, None, None].astype(x.dtype),
+                               LRN["size"], LRN["alpha"], LRN["beta"],
+                               LRN["k"], False)
+        return y.astype(jnp.float32).sum()
+
+    def shallow():
+        return jax.jit(loss).lower(*args)
+
+    def deep():
+        return (lambda: (lambda: jax.jit(loss).lower(*args))())()
+
+    old = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", LOCATION_FRAMES)
+    try:
+        a, b = shallow(), deep()
+        kernels = [re.findall(r'backend_config = "([^"]+)"', low.as_text())
+                   for low in (a, b)]
+        assert kernels[0] and kernels[0] == kernels[1]
+        assert 'op_name="jit(loss)/conv2/lrn_fwd/pallas_call"' in \
+            a.compile().as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", old)
