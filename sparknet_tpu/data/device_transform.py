@@ -7,7 +7,8 @@ layout is the reference's own storage layout: ship the raw uint8 source batch (2
 image, 3.2x less; 4x less for uncropped CIFAR records) and apply the
 reference transform semantics (data_transformer.cpp:42-51:
 ``top[mirrored_index] = (src[data_index] - mean[data_index]) * scale``)
-on-chip, where XLA fuses them into the first conv's input pipeline.
+on-chip, as the first operations of the step (under the ``input_transform``
+scope in a trace; a layout copy stands between them and the first conv).
 
 The split of responsibilities keeps the reference's per-record randomness
 exactly where it lives in Caffe (host-side ``Rand()`` in the data layer's
@@ -15,19 +16,37 @@ transform call) while moving the bandwidth-heavy work on-device:
 
   host:   draws per-image crop offsets and mirror flags — tiny int arrays
           (a few bytes/image) riding along with the uint8 batch;
-  device: gathers the crop windows (vmapped ``lax.dynamic_slice``), applies
-          the mirror, subtracts the mean (full mean source-indexed *before*
-          the mirror, per-channel mean after — both per the reference), and
-          scales.
+  device: selects each image's window with two batched matmuls against
+          0/1 selectors built from those arrays — rows
+          ``Sy[n,i,h] = (h == y_n + i)``, columns
+          ``Sx[n,w,j] = (w == x_n + (flip_n ? crop-1-j : j))``, so the
+          mirror is a reversed column index and costs nothing of its own —
+          then subtracts the mean (full mean selected at the same source
+          window, per-channel mean as is — both per the reference) and
+          scales. One routine whatever the configuration: no crop is the
+          column selector alone at the record's width, no crop and no
+          mirror is a cast.
 
-Bit-exactness against the native host kernel (native/pipeline.cpp
-transform_batch) on identical offsets/flags is asserted by
-tests/test_device_transform.py; the two paths share the same float32
-operation order so they agree exactly, not just approximately.
+Why matmuls: XLA:TPU lowers per-image ``lax.dynamic_slice`` offsets to a
+serial loop over the batch and a lane-dimension reverse to a slow copy
+(together a quarter of a CaffeNet b1536 step, PERF.md PR 26); the MXU does
+the same selection in two passes with no loop. Each output element is one
+source value times 1 plus zeros, so nothing is rounded: uint8 is exact in
+bfloat16 (the MXU's native operand), float32 records and the full-size
+mean go through float32 selectors at ``Precision.HIGHEST``. The known
+cost is on a CPU backend, where the selectors are more arithmetic than a
+slice (about 1.7e8 FLOP an image at 3x256x256 -> 227: 0.7 ms against
+0.1 ms an image on the sandbox's CPU, beside CaffeNet's 4.3e9 FLOP an
+image); the code does not branch on the backend for it.
+
+The contract: the output is bit-equal to the host kernel (native/pipeline.cpp
+transform_batch) on the cropped window, given identical offsets/flags.
+tests/test_device_transform.py asserts it for every configuration; after
+the exact selection the two paths share the same float32 operation order
+(``(v - mean) * scale``), so they agree exactly, not just approximately.
 """
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -38,6 +57,56 @@ def aux_keys(data_top):
     """Names of the host-side randomness arrays riding with ``data_top``.
     '#' keeps them out of any legal prototxt blob namespace."""
     return (f"{data_top}#y", f"{data_top}#x", f"{data_top}#flip")
+
+
+def _row_selector(ys, size, extent):
+    """Sy[n,i,h] = (h == ys[n] + i)."""
+    rows = ys[:, None].astype(jnp.int32) + jnp.arange(size, dtype=jnp.int32)
+    return rows[:, :, None] == jnp.arange(extent, dtype=jnp.int32)
+
+
+def _col_selector(xs, flips, size, extent):
+    """Sx[n,w,j] = (w == xs[n] + (flips[n] ? size-1-j : j)); ``xs`` None is
+    offset 0, ``flips`` None is no mirror."""
+    j = jnp.arange(size, dtype=jnp.int32)[None, :]
+    if flips is not None:
+        j = jnp.where(flips[:, None] != 0, size - 1 - j, j)
+    if xs is not None:
+        j = j + xs[:, None].astype(jnp.int32)
+    return jnp.arange(extent, dtype=jnp.int32)[None, :, None] == \
+        j[:, None, :]
+
+
+def _window(src, ys, xs, flips, crop):
+    """float32(src[n, :, ys[n]:ys[n]+crop, xs[n]:xs[n]+crop]), mirrored
+    where ``flips[n]``; ``ys``/``xs`` None keep the whole extent. ``src``
+    is (N,C,H,W), or (1,C,H,W) shared by the batch (the mean).
+
+    Two batched matmuls against 0/1 selectors. Each output element is one
+    source value times 1 plus zeros, so the result is exact as long as
+    the operand type holds the source exactly: uint8 in bfloat16, the
+    MXU's native operand; anything else in float32 at Precision.HIGHEST.
+    """
+    if src.dtype == jnp.uint8:
+        dt, prec = jnp.bfloat16, None
+    else:
+        dt, prec = jnp.float32, lax.Precision.HIGHEST
+    shared = src.shape[0] == 1
+    h, w = src.shape[2:]
+    out = src.astype(dt)
+    if ys is not None:
+        sy = _row_selector(ys, crop, h).astype(dt)
+        out = jnp.einsum("nih,chw->nciw" if shared else "nih,nchw->nciw",
+                         sy, out[0] if shared else out, precision=prec,
+                         preferred_element_type=jnp.float32).astype(dt)
+        shared = False
+    if xs is not None or flips is not None:
+        sx = _col_selector(xs, flips, w if xs is None else crop, w)
+        out = jnp.einsum("ciw,nwj->ncij" if shared else "nciw,nwj->ncij",
+                         out[0] if shared else out, sx.astype(dt),
+                         precision=prec,
+                         preferred_element_type=jnp.float32)
+    return out.astype(jnp.float32)
 
 
 class DeviceTransformer:
@@ -92,11 +161,11 @@ class DeviceTransformer:
         passes every other entry (labels, extra feeds) through.
 
         ``precropped``: the wire codec already sliced the crop window from
-        the uint8 source on the host (data/wire.py), so skip the crop
-        gather — but still consume the y/x aux to slice the full-size mean
-        at the ORIGINAL source coordinates, keeping the float32 op order
-        (and output bits) identical to the uncropped path: slicing uint8
-        then casting equals casting then slicing.
+        the uint8 source on the host (data/wire.py), so the record's own
+        window starts at 0,0 (no row selector, the column selector only
+        mirrors) — but the y/x aux still place the full-size mean's window
+        at the ORIGINAL source coordinates, so the output bits are those
+        of the uncropped path.
         """
         t = self.h
         crop = t.crop_size
@@ -109,32 +178,19 @@ class DeviceTransformer:
             batch = dict(batch)
             x = batch.pop(data_top)
             c = x.shape[1]
-            out = x.astype(jnp.float32)
             flips = batch.pop(kf, None)
+            ys = xs = None
             if crop:
-                ys = batch.pop(ky)
-                xs = batch.pop(kx)
-
-                if not precropped:
-                    def win(img, y, x0):
-                        return lax.dynamic_slice(img, (0, y, x0),
-                                                 (c, crop, crop))
-                    out = jax.vmap(win)(out, ys, xs)
-                if mean is not None and full_mean:
-                    # source-indexed mean window, subtracted pre-mirror
-                    out = out - jax.vmap(
-                        lambda y, x0: lax.dynamic_slice(
-                            mean, (0, y, x0), (c, crop, crop)))(ys, xs)
-                if flips is not None:
-                    out = jnp.where(flips[:, None, None, None] != 0,
-                                    out[..., ::-1], out)
-            else:
-                if mean is not None and full_mean:
-                    out = out - mean[None]
-                if flips is not None:
-                    out = jnp.where(flips[:, None, None, None] != 0,
-                                    out[..., ::-1], out)
-            if mean is not None and not full_mean:
+                ys, xs = batch.pop(ky), batch.pop(kx)
+            # a precropped record's own window starts at 0,0
+            ry, rx = (None, None) if precropped else (ys, xs)
+            out = _window(x, ry, rx, flips, crop)
+            if mean is not None and full_mean:
+                # source-indexed: the mean's window sits at the ORIGINAL
+                # y/x, and the mirror (a reversed column index) moves both
+                # operands of the subtraction alike
+                out = out - _window(mean[None], ys, xs, flips, crop)
+            elif mean is not None:
                 m = mean
                 if m.shape[0] == 1 and c > 1:
                     m = jnp.broadcast_to(m, (c,))

@@ -9,11 +9,12 @@ img/s device step):
   precrop  — the host slices each record's crop window (using the SAME
              y/x draws that ride along as aux arrays) before shipping, so
              the wire carries crop^2 pixels instead of src^2. Exact
-             integer uint8 slicing, no float math: the device path skips
-             its crop gather but still slices the full-size mean at the
-             ORIGINAL y/x and mirrors on-device, so the float32 op order
-             — and therefore every output bit — is unchanged
-             (DeviceTransformer.device_fn(precropped=True)).
+             integer uint8 slicing, no float math: the device path takes
+             the record's window at 0,0 (no row selector) but still
+             selects the full-size mean at the ORIGINAL y/x and mirrors
+             on-device through its column selector, so every output bit
+             is unchanged: bit-equal to the host kernel on the cropped
+             window (DeviceTransformer.device_fn(precropped=True)).
              CaffeNet geometry: 256^2 -> 227^2 is 1.27x.
   pack     — lossless bit-pack for low-entropy sources: when every pixel
              value fits in 1/2/4 bits, 8/4/2 pixels share each shipped

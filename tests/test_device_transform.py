@@ -26,8 +26,8 @@ def _batch(n=6, c=3, h=40, w=40, seed=0):
         0, 256, (n, c, h, w)).astype(np.uint8)
 
 
-def _run_device(devt, images, aux):
-    fn = jax.jit(devt.device_fn())
+def _run_device(devt, images, aux, precropped=False):
+    fn = jax.jit(devt.device_fn(precropped=precropped))
     out = fn({"data": jnp.asarray(images), "label": jnp.zeros(len(images)),
               **{k: jnp.asarray(v) for k, v in aux.items()}})
     assert set(out) == {"data", "label"}          # aux consumed
@@ -266,3 +266,135 @@ def test_device_cache_gates(tmp_path):
     src = DatumBatchSource(str(tmp_path / "db"), 16, device_transform=True)
     assert maybe_device_cache(src, iter_size=4) is src
     assert maybe_device_cache(src, iter_size=1) is not src
+
+
+# -- the selector form: every configuration, bit for bit -------------------
+
+_KY, _KX, _KF = aux_keys("data")
+
+
+def _edge_case_batch(n, c, h, w, crop, mirror, seed):
+    """Records with the values 0 and 255, windows at both edges of the
+    record, flips mixed: what a one-hot selector could get wrong."""
+    rs = np.random.RandomState(seed)
+    images = rs.randint(0, 256, (n, c, h, w)).astype(np.uint8)
+    images[0], images[1 % n] = 0, 255
+    aux = {}
+    if crop:
+        ys = rs.randint(0, h - crop + 1, n)
+        xs = rs.randint(0, w - crop + 1, n)
+        ys[:4] = [0, h - crop, 0, h - crop][:n]
+        xs[:4] = [w - crop, 0, 0, w - crop][:n]
+        aux[_KY], aux[_KX] = ys.astype(np.int32), xs.astype(np.int32)
+    if mirror:
+        aux[_KF] = (np.arange(n) % 2 == 0).astype(np.uint8)
+    return images, aux
+
+
+def _transformer(crop, mean_kind, mirror, scale, record_shape):
+    tp = Message("TransformationParameter", mirror=bool(mirror), scale=scale)
+    if crop:
+        tp.crop_size = crop
+    if mean_kind == "mean_value":
+        tp.mean_value.extend([104.25, 116.7, 122.9])
+    devt = build_device_transformer(tp, phase=0)
+    if mean_kind == "mean_file":                  # bypass mean_file I/O
+        devt.h.mean = (np.random.RandomState(1).rand(*record_shape)
+                       .astype(np.float32) * 255)
+        devt.h.full_mean = True
+    return devt
+
+
+def _host(devt, images, aux):
+    """The host kernel on the same draws (no crop: the whole square
+    record is the window)."""
+    t = devt.h
+    return native.transform_batch(
+        images, t.crop_size or images.shape[2], ys=aux.get(_KY),
+        xs=aux.get(_KX), mirror=aux.get(_KF), mean=t.mean, scale=t.scale,
+        full_mean=t.full_mean)
+
+
+def _device(devt, images, aux, precropped=False):
+    if precropped and devt.h.crop_size:           # what data/wire.py ships
+        crop = devt.h.crop_size
+        images = np.stack([im[:, y:y + crop, x:x + crop] for im, y, x in
+                           zip(images, aux[_KY], aux[_KX])])
+    out = _run_device(devt, images, aux, precropped)
+    assert out.dtype == np.float32
+    return out
+
+
+@pytest.mark.parametrize("precropped", [False, True])
+@pytest.mark.parametrize("scale", [1.0, 0.017])
+@pytest.mark.parametrize("mirror", [False, True])
+@pytest.mark.parametrize("mean_kind", ["mean_file", "mean_value", "no_mean"])
+@pytest.mark.parametrize("crop", [0, 28])
+def test_bit_equal_to_host_kernel(crop, mean_kind, mirror, scale,
+                                  precropped):
+    # cropped records are not square, so a transposed selector shows
+    c, h, w = (3, 40, 36) if crop else (3, 32, 32)
+    images, aux = _edge_case_batch(6, c, h, w, crop, mirror, seed=17)
+    devt = _transformer(crop, mean_kind, mirror, scale, (c, h, w))
+    np.testing.assert_array_equal(_device(devt, images, aux, precropped),
+                                  _host(devt, images, aux))
+
+
+@pytest.mark.parametrize("batch_kind", ["all_flip", "no_flip", "batch_of_1"])
+@pytest.mark.parametrize("mean_kind", ["mean_file", "mean_value"])
+@pytest.mark.parametrize("crop", [0, 28])
+def test_bit_equal_uniform_flips_and_batch_of_one(crop, mean_kind,
+                                                  batch_kind):
+    c, h, w = (3, 40, 36) if crop else (3, 32, 32)
+    n = 1 if batch_kind == "batch_of_1" else 5
+    images, aux = _edge_case_batch(n, c, h, w, crop, True, seed=23)
+    aux[_KF] = np.full(n, batch_kind != "no_flip", np.uint8)
+    devt = _transformer(crop, mean_kind, True, 0.5, (c, h, w))
+    np.testing.assert_array_equal(_device(devt, images, aux),
+                                  _host(devt, images, aux))
+
+
+@pytest.mark.parametrize("crop", [0, 28])
+def test_float_records_select_exactly(crop):
+    """float_data records (db_source ships them as float32) hold values no
+    bfloat16 does: they take the float32 selectors."""
+    c, h, w = 3, 40, 36
+    images, aux = _edge_case_batch(6, c, h, w, crop, True, seed=29)
+    images = (np.random.RandomState(30).randn(*images.shape) * 100) \
+        .astype(np.float32)
+    devt = _transformer(crop, "mean_file", True, 0.25, (c, h, w))
+    devt.h.rng = np.random.RandomState(31)
+    aux = devt.aux(len(images), (c, h, w))
+    devt.h.rng = np.random.RandomState(31)  # the host call draws the same
+    np.testing.assert_array_equal(_device(devt, images, aux),
+                                  devt.h(images))
+
+
+def _whole_and_feed(n=8):
+    c, h, w, crop = 3, 40, 36, 28
+    images, aux = _edge_case_batch(n, c, h, w, crop, True, seed=37)
+    devt = _transformer(crop, "mean_file", True, 0.017, (c, h, w))
+    feed = {"data": jnp.asarray(images),
+            **{k: jnp.asarray(v) for k, v in aux.items()}}
+    return devt.device_fn(), feed, _host(devt, images, aux)
+
+
+def test_scan_over_micro_batches_equals_whole_batch():
+    fn, feed, host = _whole_and_feed()
+    micro = jax.tree_util.tree_map(
+        lambda a: a.reshape((4, 2) + a.shape[1:]), feed)
+    _, out = jax.jit(lambda m: jax.lax.scan(
+        lambda carry, b: (carry, fn(b)["data"]), 0, m))(micro)
+    np.testing.assert_array_equal(
+        np.asarray(out).reshape(host.shape), host)
+
+
+def test_shard_map_slices_equal_whole_batch():
+    from jax.sharding import Mesh, PartitionSpec as P
+    from sparknet_tpu.parallel.compat import shard_map
+    fn, feed, host = _whole_and_feed()
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    out = jax.jit(shard_map(lambda b: fn(b)["data"], mesh=mesh,
+                            in_specs=(P("data"),), out_specs=P("data")))(
+        feed)
+    np.testing.assert_array_equal(np.asarray(out), host)

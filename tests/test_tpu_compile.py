@@ -188,3 +188,40 @@ def test_kernel_bytes_ignore_the_call_stack_and_op_names_keep_the_scope(
             a.compile().as_text()
     finally:
         jax.config.update("jax_traceback_in_locations_limit", old)
+
+
+# the host-fed cell's input transform (benchmark/feeds/hostfed_u8.py:
+# device_fn() + the feed's bf16 cast) at its real shape, and the no-crop
+# CIFAR shape: crop and mirror are selector matmuls, so the program holds
+# no loop over the batch, no lane reverse and no float32 copy of the record
+@pytest.mark.parametrize("record,crop", [((3, 256, 256), 227),
+                                         ((3, 32, 32), 0)])
+def test_input_transform_is_two_matmuls_and_no_loop(one_chip, record, crop):
+    from sparknet_tpu.data.device_transform import build_device_transformer
+    from sparknet_tpu.proto import Message
+    n = 1536
+    tp = Message("TransformationParameter", mirror=True)
+    if crop:
+        tp.crop_size = crop
+    tp.mean_value.extend([104.0, 117.0, 123.0])
+    devt = build_device_transformer(tp, phase=0)
+    inner = devt.device_fn()
+
+    def transform(b):
+        b = inner(b)
+        b["data"] = b["data"].astype(jnp.bfloat16)
+        return b
+
+    dtypes = {k: a.dtype for k, a in devt.aux(0, record).items()}
+    dtypes["data"] = jnp.uint8
+    batch = {k: jax.ShapeDtypeStruct(shape, dtypes[k], sharding=one_chip)
+             for k, shape in devt.raw_overrides(n, record).items()}
+    compiled = jax.jit(transform).lower(batch).compile()
+    text = compiled.as_text()
+    assert " while(" not in text and " reverse(" not in text
+    assert " gather(" not in text and " dynamic-slice(" not in text
+    assert " convolution(" in text              # the selectors, on the MXU
+    if crop:        # no float32 copy of the record (uncropped, the
+        # transform's float32 OUTPUT has the record's shape)
+        assert "f32[%d,%d,%d,%d]" % ((n,) + record) not in text
+    assert compiled.cost_analysis()["bytes accessed"] < 4e9
