@@ -3,9 +3,9 @@
 The timed object itself — the solver the window drives — takes its first
 three steps through the window's own call and feed. From it: each step's
 loss; per weight blob the first gradient as the optimizer got it, worked
-out from the momentum history after one step; per blob the weights' change
-after the three. The plain reference follows the same three steps from the
-same weights and inputs.
+out from its state after one step by the formula of the configuration's
+solver type; per blob the weights' change after the three. The plain
+reference follows the same three steps from the same weights and inputs.
 
 Blobs are compared by the worst one: the norm of the difference over the
 reference's norm of that blob or of the median blob, whichever is larger
@@ -24,22 +24,42 @@ def leaf_order(specs):
     return [(name, i) for name, blobs in specs for i in range(len(blobs))]
 
 
-def first_gradients(momentum, w0, specs, solver):
-    """[blob] of the first step's gradients from the optimizer's state:
-    Caffe's SGD leaves history = lr x lr_mult x (g + weight_decay x
+def _from_momentum(h, w0, solver, lr_mult, decay_mult):
+    """Caffe's SGD leaves history = lr x lr_mult x (g + weight_decay x
     decay_mult x w0) after one step from a zero history."""
-    lr, wd = solver["base_lr"], solver["weight_decay"]
-    mults = (solver["weight_mults"], solver["bias_mults"])
+    return h / (solver["base_lr"] * lr_mult) \
+        - solver["weight_decay"] * decay_mult * w0
+
+
+def _from_first_moment(m1, w0, solver, lr_mult, decay_mult):
+    """Caffe's Adam leaves its first moment m1 = (1 - momentum) x (g +
+    weight_decay x decay_mult x w0) after one step from zero moments: the
+    decay is added before the moments are taken."""
+    return m1 / (1.0 - solver["momentum"]) \
+        - solver["weight_decay"] * decay_mult * w0
+
+
+GRADIENT_FROM_SLOT0 = {"SGD": _from_momentum, "Adam": _from_first_moment}
+
+
+def first_gradients(slot0, w0, specs, solver):
+    """[blob] of the first step's gradients from the optimizer's state:
+    `slot0` is {layer: [blob]} of the first history slot after one step
+    from a zero history, and the formula is that of `solver["type"]`
+    ("SGD" when absent); each blob's multipliers stand in `specs`."""
+    kind = solver.get("type", "SGD")
+    if kind not in GRADIENT_FROM_SLOT0:
+        raise SystemExit(f"benchmark: check.py cannot work the first "
+                         f"gradient out of the state of a {kind!r} solver")
+    gradient = GRADIENT_FROM_SLOT0[kind]
+    mults = {name: [b[2] for b in blobs] for name, blobs in specs}
 
     @jax.jit
-    def f(momentum, w0):
-        out = []
-        for name, i in leaf_order(specs):
-            lr_mult, decay_mult = mults[i]
-            out.append(momentum[name][i] / (lr * lr_mult)
-                       - wd * decay_mult * w0[name][i])
-        return out
-    return f(momentum, w0)
+    def f(slot0, w0):
+        return [gradient(slot0[name][i], w0[name][i], solver,
+                         *mults[name][i])
+                for name, i in leaf_order(specs)]
+    return f(slot0, w0)
 
 
 def leaves(tree, specs, minus=None):

@@ -28,10 +28,12 @@ def main(argv=None):
     ap.add_argument("--control-seeds", type=int, default=None,
                     help="run the control on the first N seeds only")
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--dir", default=harness.HERE)
     args = ap.parse_args(argv)
     if args.rehearse:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    cell = harness.Cell(args.workload, rehearse=args.rehearse)
+    cell = harness.Cell(args.workload, rehearse=args.rehearse,
+                        here=os.path.abspath(args.dir))
 
     import check
     harness.find_device(cell.chips, args.rehearse)

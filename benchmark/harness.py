@@ -48,7 +48,7 @@ MARKS = Marks()
 
 
 def load_json(*parts):
-    with open(os.path.join(HERE, *parts)) as f:
+    with open(os.path.join(*parts)) as f:
         return json.load(f)
 
 
@@ -57,25 +57,47 @@ def by_path(path):
     return getattr(importlib.import_module(mod), attr)
 
 
-class Cell:
-    """The files of one workload, found by the names in BENCHMARK.json."""
+def load_reference(config, batch):
+    """What `reference/<name>.py` hands the harness for `config` at `batch`
+    (`reference.plain.LayerList` describes it): the module's own
+    `build(config, batch)`, or its layer list through `LayerList`."""
+    mod = importlib.import_module(f"reference.{config['reference']}")
+    if hasattr(mod, "build"):
+        return mod.build(config, batch)
+    import flops
+    from reference import plain
+    return plain.LayerList(*flops.reference_net(config, batch),
+                           config["solver"])
 
-    def __init__(self, name, rehearse=False):
-        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-            self.bench = json.load(f)
+
+class Cell:
+    """The files of one workload, found by the names in the BENCHMARK.json
+    beside `here`: the benchmark's own directory, or one laid out like it
+    (the tests' fixtures), whose files are found first and which may use
+    the benchmark's own by name."""
+
+    def __init__(self, name, rehearse=False, here=HERE):
+        if here not in sys.path:
+            sys.path.insert(0, here)
+        import reference           # the one directory here with an __init__
+        if os.path.join(here, "reference") not in reference.__path__:
+            reference.__path__.insert(0, os.path.join(here, "reference"))
+        self.here = here
+        self.bench = load_json(os.path.dirname(here), "BENCHMARK.json")
         entry = next((w for w in self.bench["workloads"]
                       if w["name"] == name), None)
         if entry is None:
             raise SystemExit(f"benchmark: no workload {name!r} in "
                              "BENCHMARK.json")
         self.name = name
-        self.file = load_json("workloads", f"{name}.json")
+        self.file = load_json(here, "workloads", f"{name}.json")
         for key in ("config", "traffic", "chips"):
             if self.file[key] != entry[key]:
                 raise SystemExit(f"benchmark: {name}: {key} differs between "
                                  "BENCHMARK.json and the cell file")
-        self.config = load_json("configs", f"{entry['config']}.json")
-        self.traffic = load_json("traffic", f"{entry['traffic']}.json")
+        self.config = load_json(here, "configs", f"{entry['config']}.json")
+        self.traffic = load_json(here, "traffic",
+                                 f"{entry['traffic']}.json")
         self.chips = int(entry["chips"])
         self.toy = self.file.get("toy", {}) if rehearse else {}
         self.batch = int(self.toy.get("batch", self.traffic["batch"]))
@@ -89,12 +111,10 @@ class Cell:
                            **self.file.get("check_limits", {}))
         self.limits.update(self.toy.get("check_limits", {}))
 
-        import flops
-        from reference import plain
         self.sized_config = dict(self.config, builder_args=self.builder_args)
-        self.layers, self.data_shape = flops.reference_net(
-            self.sized_config, self.batch)
-        self.specs = plain.param_specs(self.layers, self.data_shape)
+        self.ref = load_reference(self.sized_config, self.batch)
+        self.specs = self.ref.specs
+        self.data_shape = tuple(self.ref.inputs[0][1])
 
     def flops_per_sample(self):
         import flops
@@ -116,7 +136,8 @@ def find_device(chips, rehearse=False):
     if len(devs) < chips:
         raise SystemExit(f"benchmark: the cell needs {chips} chips, jax "
                          f"found {len(devs)}; no result")
-    peak = flops.peak_for(devs[0].device_kind, load_json("peaks.json"))
+    peak = flops.peak_for(devs[0].device_kind,
+                          load_json(HERE, "peaks.json"))
     if peak is None:
         raise SystemExit(f"benchmark: device kind {devs[0].device_kind!r} "
                          "is not in benchmark/peaks.json; no result")
@@ -160,14 +181,19 @@ class Timed:
         self.cell = cell
         net = by_path(cell.config["builder"])(batch_size=cell.batch,
                                               **cell.builder_args)
-        s = cell.solver_cfg
-        sp = Message("SolverParameter", base_lr=s["base_lr"],
-                     lr_policy=s["lr_policy"], momentum=s["momentum"],
-                     weight_decay=s["weight_decay"], display=0,
-                     random_seed=seed % (2 ** 31 - 1))
+        # the configuration's solver block is the SolverParameter's fields
+        # (type, rates, moments, decay), less what only its reference reads
+        fields = {k: v for k, v in cell.solver_cfg.items()
+                  if k not in ("weight_mults", "bias_mults", "note")}
+        sp = Message("SolverParameter", display=0,
+                     random_seed=seed % (2 ** 31 - 1), **fields)
         cls = by_path(cell.file.get("solver",
                                     "sparknet_tpu.solver.solver:Solver"))
-        self.solver = cls(sp, net_param=net, log_fn=None)
+        # arguments of the solver's constructor; a `*dtype` is named
+        import jax.numpy as jnp
+        args = {k: getattr(jnp, v) if k.endswith("dtype") else v
+                for k, v in cell.config.get("solver_args", {}).items()}
+        self.solver = cls(sp, net_param=net, log_fn=None, **args)
         MARKS.mark("solver")
         self.feed = None
         self.feed_wait, self.dispatch, self.losses = [], [], []
@@ -204,7 +230,7 @@ class Timed:
         if self.feed is not None:
             self.feed.close()
         mod = importlib.import_module(f"feeds.{cell.traffic['feed']}")
-        self.feed = mod.build(traffic=cell.traffic, config=cell.config,
+        self.feed = mod.build(traffic=cell.traffic, config=cell.sized_config,
                               seed=seed, solver=solver,
                               data_shape=cell.data_shape,
                               num_classes=cell.num_classes)
@@ -253,13 +279,14 @@ class Timed:
         got["losses"].append(float(self.one_step()))
         first_step_s = time.perf_counter() - t
         MARKS.mark("step1")
-        # the program keeps its SGD momentum as history[layer][blob][0]
-        momentum = {n: [h[0] for h in blobs]
-                    for n, blobs in solver.history.items()}
+        # the program keeps SGD's momentum, and Adam's first moment, as
+        # history[layer][blob][0]
+        slot0 = {n: [h[0] for h in blobs]
+                 for n, blobs in solver.history.items()}
         # kept on the host so that the window's memory stays the program's
         got["grads"] = jax.device_get(check.first_gradients(
-            momentum, self.w0, cell.specs, cell.solver_cfg))
-        del momentum
+            slot0, self.w0, cell.specs, cell.solver_cfg))
+        del slot0
         for _ in range(CHECKED_STEPS - 1):
             got["losses"].append(float(self.one_step()))
         got["dparams"] = jax.device_get(
@@ -329,22 +356,20 @@ def run_reference(cell, seed, inputs, control=False):
     configuration's `check.control` names the types one step below the
     ones it states, for the blobs and for the stored weights."""
     import jax
+    import jax.numpy as jnp
     import check
     import weights
-    from reference import plain
     block = cell.toy.get("reference_block_rows",
                          cell.config["check"].get("reference_block_rows"))
-    import jax.numpy as jnp
     low = cell.config["check"]["control"] if control else {}
-    step = plain.make_step(
-        cell.layers, cell.data_shape, cell.solver_cfg, block_rows=block,
+    step = cell.ref.make_step(
+        cell.solver_cfg, block_rows=block,
         quant=getattr(jnp, low["activations"]) if low else None,
         masters=getattr(jnp, low["masters"]) if low else None)
     MARKS.mark("reference build")
     with jax.default_matmul_precision("highest"):
         w0 = weights.make_weights(cell.specs, seed)
-        params = w0
-        history = jax.tree_util.tree_map(lambda x: x * 0, w0)
+        params, history = w0, None
         out = {"losses": []}
         key = weights.seed_key(seed, weights.STEPS)
         for i, (data, labels) in enumerate(inputs):

@@ -1,7 +1,10 @@
 """Plain float32 reference for Caffe-style CNN training: forward, loss,
-gradients and the momentum-SGD update in straightforward jax.numpy / lax,
-written from the published layer definitions (Caffe's layer catalogue) and
-importing nothing of the program under test.
+gradients and the solver's update (SGD with momentum, Adam) in
+straightforward jax.numpy / lax, written from the published layer
+definitions (Caffe's layer catalogue) and importing nothing of the program
+under test. A reference of another kind of model is a file of its own with
+`build(config, batch)`; `round_to`, `fake_quant` and `make_update` here are
+for it too. `LayerList`, at the end, is what the harness is handed.
 
 A net is the list of the published prototxt's TRAIN-phase layers in the
 file's order (`reference/<config>.py` builds it; the accuracy layers, which
@@ -151,7 +154,7 @@ def conv_fc_macs(layers, data_shape):
 
 # ---------------------------------------------------------------- forward
 
-def _round_to(x, dtype):
+def round_to(x, dtype):
     """x rounded to a float `dtype`'s exponent and mantissa width, a type
     narrower than bfloat16 with a per-tensor scale first. By
     `lax.reduce_precision`: a convert there and back is removed by XLA:TPU
@@ -164,15 +167,15 @@ def _round_to(x, dtype):
     return lax.reduce_precision(x / scale, info.nexp, info.nmant) * scale
 
 
-def _fake_quant(x, dtype):
+def fake_quant(x, dtype):
     """A blob kept in `dtype`: its value is rounded on the way forward and
     its gradient on the way back, as in a program whose activations and
     their gradients live in that type."""
     @jax.custom_vjp
     def f(x):
-        return _round_to(x, dtype)
-    f.defvjp(lambda x: (_round_to(x, dtype), None),
-             lambda _, g: (_round_to(g, dtype),))
+        return round_to(x, dtype)
+    f.defvjp(lambda x: (round_to(x, dtype), None),
+             lambda _, g: (round_to(g, dtype),))
     return f(x)
 
 
@@ -206,7 +209,7 @@ def forward_loss(params, data, labels, layers, masks=None, quant=None):
     dropout). The loss is the SUM over rows of the weighted per-row losses:
     the caller divides by the whole batch."""
     def store(x):
-        return x if quant is None else _fake_quant(x, quant)
+        return x if quant is None else fake_quant(x, quant)
     blobs = {"data": store(data.astype(jnp.float32))}
     total = 0.0
     for l in layers:
@@ -221,7 +224,7 @@ def forward_loss(params, data, labels, layers, masks=None, quant=None):
         if t == "conv":
             w, b = params[l["name"]]
             if quant is not None:
-                w = _fake_quant(w, quant)
+                w = fake_quant(w, quant)
             y = lax.conv_general_dilated(
                 x, w, (l["stride"],) * 2, [(l["pad"],) * 2] * 2,
                 dimension_numbers=("NCHW", "OIHW", "NCHW"),
@@ -231,7 +234,7 @@ def forward_loss(params, data, labels, layers, masks=None, quant=None):
             w, b = params[l["name"]]
             x = x.reshape(x.shape[0], -1)
             if quant is not None:
-                w = _fake_quant(w, quant)
+                w = fake_quant(w, quant)
             y = x @ w.T + b
         elif t == "relu":
             y = jnp.maximum(x, 0)
@@ -278,47 +281,89 @@ def dropout_masks(layers, shapes, key):
             for i, l in enumerate(layers) if l["type"] == "dropout"}
 
 
-def make_step(layers, data_shape, solver, block_rows=None, quant=None,
+def make_update(solver, mults, masters=None):
+    """-> update(params, history, grads) -> (params, history): one
+    iteration of Caffe's solver of `solver["type"]` ("SGD" when absent) at
+    a fixed rate. Every blob: L2 decay (weight_decay x decay_mult x w)
+    added to its gradient, then
+      SGD   h = momentum x h + lr x lr_mult x g;  w -= h
+      Adam  m1 = b1 x m1 + (1 - b1) x g,  m2 = b2 x m2 + (1 - b2) x g^2;
+            w -= lr x lr_mult x sqrt(1 - b2^t) / (1 - b1^t)
+                 x m1 / (sqrt(m2) + delta),  t counted from 1
+    (sgd_solver.cpp, adam_solver.cpp; b1, b2 = momentum, momentum2).
+    `mults` is {layer: [(lr_mult, decay_mult) per blob]}. A history is
+    (steps taken, {layer: [[slot, ...] per blob]}), None for a zero one;
+    `masters` rounds the stored weights and slots to that type (the
+    control's bfloat16 masters)."""
+    kind = solver.get("type", "SGD")
+    lr, wd = solver["base_lr"], solver["weight_decay"]
+    if kind == "SGD":
+        mom, n_slots = solver["momentum"], 1
+
+        def one(g, slots, rate, t):
+            h = mom * slots[0] + rate * g
+            return h, [h]
+    elif kind == "Adam":
+        b1, b2 = solver["momentum"], solver["momentum2"]
+        delta, n_slots = solver["delta"], 2
+
+        def one(g, slots, rate, t):
+            m1 = b1 * slots[0] + (1.0 - b1) * g
+            m2 = b2 * slots[1] + (1.0 - b2) * g * g
+            correction = jnp.sqrt(1.0 - b2 ** t) / (1.0 - b1 ** t)
+            return rate * correction * m1 / (jnp.sqrt(m2) + delta), [m1, m2]
+    else:
+        raise ValueError(f"reference/plain.py has no update for the solver "
+                         f"type {kind!r}")
+
+    @jax.jit
+    def update(params, history, grads):
+        taken, slots = history
+        t = (taken + 1).astype(jnp.float32)
+        new_p, new_s = {}, {}
+        for name, blobs in params.items():
+            ps, ss = [], []
+            for i, w in enumerate(blobs):
+                lr_mult, decay_mult = mults[name][i]
+                g = grads[name][i] + wd * decay_mult * w
+                u, s = one(g, slots[name][i], lr * lr_mult, t)
+                w = w - u
+                if masters is not None:
+                    w = round_to(w, masters)
+                    s = [round_to(x, masters) for x in s]
+                ps.append(w)
+                ss.append(s)
+            new_p[name], new_s[name] = ps, ss
+        return new_p, (taken + 1, new_s)
+
+    def from_zero(params, history, grads):
+        if history is None:
+            history = (jnp.zeros((), jnp.int32), {
+                name: [[w * 0 for _ in range(n_slots)] for w in blobs]
+                for name, blobs in params.items()})
+        return update(params, history, grads)
+    return from_zero
+
+
+def make_step(layers, data_shape, solver, mults, block_rows=None, quant=None,
               masters=None, with_dropout=True):
     """-> step(params, history, data, labels, key) -> (params, history,
-    loss, grads). One iteration of Caffe's SGD solver: gradients of the
-    batch-mean loss, L2 decay (weight_decay x decay_mult) added, history =
-    momentum x history + lr x lr_mult x that, weights minus history.
-    Weights take (lr_mult, decay_mult) = solver["weight_mults"], biases
-    solver["bias_mults"]. Gradients are accumulated over blocks of
-    `block_rows` rows so that a float32 pass of the whole batch need not
-    fit at once."""
+    loss, grads). One iteration of Caffe's solver (`make_update`, which
+    `mults` is for) on the gradients of the batch-mean loss. Gradients are
+    accumulated over blocks of `block_rows` rows so that a float32 pass of
+    the whole batch need not fit at once."""
     n = data_shape[0]
     rows = block_rows or n
     if n % rows:
         raise ValueError(f"block of {rows} rows does not divide batch {n}")
     shapes = infer_shapes(layers, data_shape)
-    lr, mom = solver["base_lr"], solver["momentum"]
-    wd = solver["weight_decay"]
-    mults = (tuple(solver["weight_mults"]), tuple(solver["bias_mults"]))
+    update = make_update(solver, mults, masters)
 
     @jax.jit
     def block_grad(params, data, labels, masks):
         def lf(p):
             return forward_loss(p, data, labels, layers, masks, quant) / n
         return jax.value_and_grad(lf)(params)
-
-    @jax.jit
-    def update(params, history, grads):
-        new_p, new_h = {}, {}
-        for name, blobs in params.items():
-            ps, hs = [], []
-            for i, w in enumerate(blobs):
-                lr_mult, decay_mult = mults[i]
-                g = grads[name][i] + wd * decay_mult * w
-                h = mom * history[name][i] + lr * lr_mult * g
-                w = w - h
-                if masters is not None:
-                    w, h = _round_to(w, masters), _round_to(h, masters)
-                ps.append(w)
-                hs.append(h)
-            new_p[name], new_h[name] = ps, hs
-        return new_p, new_h
 
     def step(params, history, data, labels, key):
         masks = dropout_masks(layers, shapes, key) if with_dropout else None
@@ -335,3 +380,36 @@ def make_step(layers, data_shape, solver, block_rows=None, quant=None,
         return params, history, loss, grads
 
     return step
+
+
+# ---------------------------------------------- what the harness is handed
+
+class LayerList:
+    """A `reference/<name>.py` that gives a layer list (`layers()` and
+    `data_shape()`, no `build`) as the object the harness is handed: what a
+    module's own `build(config, batch)` returns.
+
+    specs      [(layer, [(shape, filler, (lr_mult, decay_mult)) per blob])]
+    inputs     [(name, shape, type)] of what a step is fed
+    make_step  (solver, block_rows, quant, masters) -> step(params,
+               history, data, labels, key) -> (params, history, loss,
+               grads); the history is the reference's own, None at the
+               first step
+    """
+
+    def __init__(self, layers, data_shape, solver):
+        self.layers, self.data_shape = layers, tuple(data_shape)
+        # a layer list's weights take (lr_mult, decay_mult) =
+        # solver["weight_mults"], its biases solver["bias_mults"]
+        pair = (tuple(solver["weight_mults"]), tuple(solver["bias_mults"]))
+        self.specs = [
+            (name, [(shape, filler, pair[i])
+                    for i, (shape, filler) in enumerate(blobs)])
+            for name, blobs in param_specs(layers, data_shape)]
+        self.inputs = [("data", self.data_shape, "float32"),
+                       ("label", (self.data_shape[0],), "int32")]
+
+    def make_step(self, solver, block_rows=None, quant=None, masters=None):
+        mults = {name: [b[2] for b in blobs] for name, blobs in self.specs}
+        return make_step(self.layers, self.data_shape, solver, mults,
+                         block_rows=block_rows, quant=quant, masters=masters)

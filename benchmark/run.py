@@ -18,6 +18,7 @@ _T0 = time.perf_counter()           # set-up is counted from process start
 import argparse
 import importlib
 import json
+import math
 import os
 import shutil
 import sys
@@ -36,11 +37,26 @@ def main(argv=None):
     ap.add_argument("--seconds", type=float, default=None)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--dir", default=harness.HERE,
+                    help="a directory laid out like this one, beside a "
+                         "BENCHMARK.json of its own (the tests' fixtures)")
     args = ap.parse_args(argv)
     if args.rehearse:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-    cell = harness.Cell(args.workload, rehearse=args.rehearse)
+    cell = harness.Cell(args.workload, rehearse=args.rehearse,
+                        here=os.path.abspath(args.dir))
+    # the trace stays on disk until the per-layer readers have run
+    trace_dir = os.path.join(harness.ROOT, ".bench_trace", cell.name) \
+        if args.trace else None
+    try:
+        return run(args, cell, trace_dir)
+    finally:
+        if trace_dir:
+            shutil.rmtree(trace_dir, ignore_errors=True)
+
+
+def run(args, cell, trace_dir):
     seconds = args.seconds if args.seconds is not None \
         else cell.toy.get("seconds", cell.bench["run_seconds"])
 
@@ -63,8 +79,6 @@ def main(argv=None):
 
     # -- set-up ----------------------------------------------------------
     timed = harness.Timed(cell, args.seed)
-    trace_dir = os.path.join(harness.ROOT, ".bench_trace", cell.name) \
-        if args.trace else None
     try:
         got, first_step_s = timed.checked_steps()
         for _ in range(harness.WARM_UNITS):
@@ -111,23 +125,25 @@ def main(argv=None):
         f"feed {json.dumps(feed_stats)}")
     say(f"# memory: peak {mem_peak} B; device {json.dumps(mem_stats)}")
 
-    trace = None
+    trace = xplane = None
     if args.trace:
-        events = trace_reduce.read_events(trace_reduce.find_xplane(trace_dir))
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        trace = trace_reduce.reduce_events(events)
+        xplane = trace_reduce.find_xplane(trace_dir)
+        trace = trace_reduce.reduce_events(trace_reduce.read_events(xplane))
         if trace is None and not args.rehearse:
             raise SystemExit("benchmark: the trace holds no operation on a "
                              f"{trace_reduce.DEVICE_PLANE}* plane; no result")
+        if trace:
+            say(f"# trace {xplane} ({os.path.getsize(xplane)} B, kept for "
+                f"the readers): {len(trace['op_seconds'])} operations by "
+                f"name, {sum(trace['op_seconds'].values()):.6f} s summed "
+                f"against {trace['busy_s']:.6f} s busy; idle by the "
+                f"program's spans {json.dumps(trace['idle_gaps_program'])}")
 
     harness.MARKS.mark("reduce")
     # the reference, now that the program's state is freed
     want = harness.run_reference(cell, args.seed, ref_inputs)
     del ref_inputs
     rows = check.compare(got, want, cell.limits, cell.specs)
-    for name, value, limit, ok, note in rows:
-        say(f"# check {name} = {value:.6g} (limit {limit:g}) "
-            f"{'ok' if ok else 'FAILED'}; {note}")
     correct = all(r[3] for r in rows) and failed == 0
     harness.MARKS.mark("compare")
     say(f"# seconds: {harness.MARKS}")
@@ -135,7 +151,9 @@ def main(argv=None):
     if args.trace:
         ctx = {"feed_wait_s": timed.feed_wait, "dispatch_s": timed.dispatch,
                "compile_s": first_step_s, "trace": trace,
-               "traffic": cell.traffic, "flops_per_step": fps * cell.batch,
+               "op_seconds": (trace or {}).get("op_seconds"),
+               "idle_gaps_program": (trace or {}).get("idle_gaps_program"),
+               "xplane": xplane, "traffic": cell.traffic, "flops_per_step": fps * cell.batch,
                "peak": peak, "sync_every": cell.sync_every,
                "batch": cell.batch, "chips": len(devices)}
         metrics = {}
@@ -154,6 +172,12 @@ def main(argv=None):
     say(f"# setup_s {setup_s:.3f} (first step {first_step_s:.3f} s) besides "
         f"{runtime_start_s:.3f} s of accelerator runtime start-up; whole "
         f"run {time.perf_counter() - _T0:.1f} s")
+    # every number compared beside its limit: the last lines of stderr,
+    # and the last key of the result
+    for name, value, limit, ok, note in rows:
+        print(f"# check {name} = {value:.6g} (limit {limit:g}) "
+              f"{'ok' if ok else 'FAILED'}; {note}", file=sys.stderr,
+              flush=True)
     if args.rehearse:
         say(f"# rehearsal on {devices[0].platform}: control flow only, "
             f"correct={correct}, no result line")
@@ -166,8 +190,13 @@ def main(argv=None):
     if trace:
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
-        result["breakdown"] = {"device_ops": trace["device_ops"],
-                               "idle_gaps": trace["idle_gaps"]}
+        result["breakdown"] = {
+            "device_ops": trace["device_ops"],
+            "idle_gaps": trace["idle_gaps"],
+            "idle_gaps_program": trace["idle_gaps_program"][:10]}
+    result["check"] = {
+        name: {"value": value if math.isfinite(value) else None,
+               "limit": limit} for name, value, limit, _, _ in rows}
     say(json.dumps(result))
     return 0
 

@@ -40,7 +40,8 @@ def _rehearse(capsys):
     import run
     rc = run.main(["--workload", CELL, "--seed", "11", "--trace", "0",
                    "--rehearse"])
-    return rc, capsys.readouterr().out
+    got = capsys.readouterr()
+    return rc, got.out + got.err        # the check's rows stand on stderr
 
 
 def test_a_step_that_returns_its_state_unchanged_is_not_correct(

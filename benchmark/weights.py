@@ -3,7 +3,7 @@
 The benchmark makes the weights (not the program), so that the program
 under test and the plain reference start from the same numbers and neither
 takes anything the other has made. Fillers follow Caffe: gaussian(std),
-xavier = uniform(+-sqrt(3 / fan_in)), constant.
+xavier = uniform(+-sqrt(3 / fan_in)), uniform(lo, hi), constant.
 """
 
 import math
@@ -21,6 +21,9 @@ def _fill(key, shape, filler):
     if kind == "xavier":
         scale = math.sqrt(3.0 / (math.prod(shape) // shape[0]))
         return jax.random.uniform(key, shape, jnp.float32, -scale, scale)
+    if kind == "uniform":
+        return jax.random.uniform(key, shape, jnp.float32, filler[1],
+                                  filler[2])
     raise ValueError(f"unknown filler {filler!r}")
 
 
@@ -36,9 +39,9 @@ WEIGHTS, INPUTS, STEPS = 1, 2, 3
 
 
 def make_weights(specs, seed):
-    """{layer: [blob, ...]} in float32 for `specs`
-    (reference.plain.param_specs) from `seed`; the same seed gives the
-    same weights."""
+    """{layer: [blob, ...]} in float32 for `specs` (a reference's: per
+    layer its blobs' (shape, filler, ...)) from `seed`; the same seed gives
+    the same weights."""
 
     @jax.jit
     def build(key):
@@ -46,6 +49,6 @@ def make_weights(specs, seed):
         for i, (name, blobs) in enumerate(specs):
             lkey = jax.random.fold_in(key, i)
             out[name] = [_fill(jax.random.fold_in(lkey, j), shape, filler)
-                         for j, (shape, filler) in enumerate(blobs)]
+                         for j, (shape, filler, *_) in enumerate(blobs)]
         return out
     return build(seed_key(seed, WEIGHTS))
