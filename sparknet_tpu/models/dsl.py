@@ -3,7 +3,8 @@
 The capability of the reference's Scala DSL (Layers.scala:18-137 — RDDLayer,
 ConvolutionLayer, PoolingLayer, InnerProductLayer, ReLULayer, SoftmaxWithLoss,
 NetParam), extended with the builders the bigger nets need (LRN, Dropout,
-Concat, Accuracy, BatchNorm, Eltwise, Attention). Each returns a proto
+Concat, Accuracy, BatchNorm, Eltwise, Attention, GatedDeltaNet, RMSNorm,
+MoE). Each returns a proto
 Message, so DSL output and parsed prototxt are the same IR.
 """
 
@@ -61,8 +62,11 @@ def PoolingLayer(name, bottoms, pooling, kernel, stride, pad=None):
 
 
 def InnerProductLayer(name, bottoms, num_output, weight_filler=None,
-                      bias_filler=None, param=None, axis=None):
+                      bias_filler=None, param=None, axis=None,
+                      bias_term=None):
     ip = dict(num_output=num_output)
+    if bias_term is not None:
+        ip["bias_term"] = bias_term
     if weight_filler is not None:
         ip["weight_filler"] = weight_filler
     if bias_filler is not None:
@@ -123,20 +127,75 @@ def SoftmaxLayer(name, bottoms):
     return _base("Softmax", name, bottoms)
 
 
+def _with_params(lp, param):
+    for p in (param or []):
+        lp.add("param", **p)
+    return lp
+
+
 def AttentionLayer(name, bottoms, num_heads, head_dim=None, causal=False,
-                   ring=False, flash=False):
+                   ring=False, flash=False, num_kv_heads=None,
+                   qk_norm=False, rotary_dim=0, rope_theta=None,
+                   output_gate=False, norm_eps=None, weight_filler=None,
+                   param=None):
     """sparknet_tpu extension for the long-context path (see
-    parallel.ring_attention, ops.pallas_attention)."""
+    parallel.ring_attention, ops.pallas_attention). `num_kv_heads` selects
+    the grouped-query form (bias-free q/k/v/out projections; qk_norm,
+    rotary_dim, rope_theta, output_gate belong to it)."""
     ap = dict(num_heads=num_heads, causal=causal, ring=ring, flash=flash)
     if head_dim is not None:
         ap["head_dim"] = head_dim
-    return _base("Attention", name, bottoms, attention_param=ap)
+    if num_kv_heads is not None:
+        ap.update(num_kv_heads=num_kv_heads, qk_norm=qk_norm,
+                  rotary_dim=rotary_dim, output_gate=output_gate)
+        if rope_theta is not None:
+            ap["rope_theta"] = rope_theta
+        if norm_eps is not None:
+            ap["norm_eps"] = norm_eps
+    if weight_filler is not None:
+        ap["weight_filler"] = weight_filler
+    return _with_params(_base("Attention", name, bottoms,
+                              attention_param=ap), param)
 
 
-def EmbedLayer(name, bottoms, input_dim, num_output, weight_filler=None):
+def GatedDeltaNetLayer(name, bottoms, num_k_heads, num_v_heads, head_k_dim,
+                       head_v_dim, conv_kernel=4, chunk=None, norm_eps=None,
+                       weight_filler=None, param=None):
+    """sparknet_tpu extension: Gated DeltaNet linear attention
+    (ops/deltanet.py); `param` lists its seven blobs' multipliers
+    (W_qkvz, W_ba, conv, A_log, dt_bias, norm, W_out)."""
+    gp = dict(num_k_heads=num_k_heads, num_v_heads=num_v_heads,
+              head_k_dim=head_k_dim, head_v_dim=head_v_dim,
+              conv_kernel=conv_kernel)
+    if chunk is not None:
+        gp["chunk"] = chunk
+    if norm_eps is not None:
+        gp["norm_eps"] = norm_eps
+    if weight_filler is not None:
+        gp["weight_filler"] = weight_filler
+    return _with_params(_base("GatedDeltaNet", name, bottoms,
+                              gated_delta_net_param=gp), param)
+
+
+def RMSNormLayer(name, bottoms, tops=None, eps=None, zero_centered=None,
+                 param=None):
+    """sparknet_tpu extension: last-axis RMS norm, one blob."""
+    rp = {}
+    if eps is not None:
+        rp["eps"] = eps
+    if zero_centered is not None:
+        rp["zero_centered"] = zero_centered
+    return _with_params(_base("RMSNorm", name, bottoms, tops=tops,
+                              rms_norm_param=rp or None), param)
+
+
+def EmbedLayer(name, bottoms, input_dim, num_output, weight_filler=None,
+               bias_term=None):
     ep = dict(input_dim=input_dim, num_output=num_output)
     if weight_filler is not None:
         ep["weight_filler"] = weight_filler
+    if bias_term is not None:
+        ep["bias_term"] = bias_term
     return _base("Embed", name, bottoms, embed_param=ep)
 
 
@@ -151,11 +210,34 @@ def PositionalEmbedLayer(name, bottoms, max_positions, num_output,
 
 def MoELayer(name, bottoms, num_experts, hidden_dim=None,
              capacity_factor=None, expert_parallel=False,
-             aux_loss_weight=None, weight_filler=None, stats=False):
-    """sparknet_tpu extension: Switch-style MoE FFN. aux_loss_weight adds a
-    second top carrying the load-balancing loss with that loss_weight;
-    stats=True adds a third (weight-0) diagnostics top with per-expert
-    token fractions + the overflow fraction."""
+             aux_loss_weight=None, weight_filler=None, stats=False,
+             top_k=None, experts_held=None, first_expert=None,
+             shared_hidden_dim=None, norm_topk_prob=None, tile_rows=None):
+    """sparknet_tpu extension: MoE FFN. The top-1 Switch form:
+    aux_loss_weight adds a second top carrying the load-balancing loss
+    with that loss_weight; stats=True adds a third (weight-0) diagnostics
+    top with per-expert token fractions + the overflow fraction. Naming
+    `top_k` selects the no-drop form (ops/moe.py: gated SiLU experts,
+    top_k of num_experts with renormalised weights, `experts_held` of
+    them from `first_expert` on held here, an optional shared expert);
+    there stats=True adds one (weight-0) top [share of pairs on held
+    experts, largest over mean held load]."""
+    if top_k is not None:
+        mp = dict(num_experts=num_experts, gated_experts=True, top_k=top_k)
+        for key, val in (("hidden_dim", hidden_dim),
+                         ("experts_held", experts_held),
+                         ("first_expert", first_expert),
+                         ("shared_hidden_dim", shared_hidden_dim),
+                         ("norm_topk_prob", norm_topk_prob),
+                         ("tile_rows", tile_rows),
+                         ("weight_filler", weight_filler)):
+            if val is not None:
+                mp[key] = val
+        tops = [name, f"{name}_stats"] if stats else [name]
+        lp = _base("MoE", name, bottoms, tops=tops, moe_param=mp)
+        if stats:
+            lp.loss_weight.extend([0.0, 0.0])
+        return lp
     mp = dict(num_experts=num_experts, expert_parallel=expert_parallel)
     if hidden_dim is not None:
         mp["hidden_dim"] = hidden_dim

@@ -5,7 +5,8 @@ caffe/models/bvlc_googlenet), re-expressed with the DSL so the framework is
 self-contained — no prototxt files needed (though stock ones load too).
 """
 
-from .dsl import (NetParam, RDDLayer, ConvolutionLayer, PoolingLayer,
+from .dsl import (GatedDeltaNetLayer, RMSNormLayer,
+                  NetParam, RDDLayer, ConvolutionLayer, PoolingLayer,
                   InnerProductLayer, ReLULayer, SoftmaxWithLoss,
                   AccuracyLayer, LRNLayer, DropoutLayer, ConcatLayer,
                   EltwiseLayer, AttentionLayer, EmbedLayer,
@@ -339,6 +340,92 @@ def transformer_lm(vocab_size=512, seq_len=256, batch_size=8, d_model=256,
         SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
     ]
     return NetParam("TransformerLM", *layers)
+
+
+def qwen3_next(vocab_size=151936, seq_len=8192, batch_size=2,
+               hidden_size=2048, num_hidden_layers=48,
+               full_attention_interval=4, num_attention_heads=16,
+               num_key_value_heads=2, head_dim=256,
+               partial_rotary_factor=0.25, rope_theta=1e7,
+               rms_norm_eps=1e-6, linear_num_key_heads=16,
+               linear_num_value_heads=32, linear_key_head_dim=128,
+               linear_value_head_dim=128, linear_conv_kernel_dim=4,
+               num_experts=512, num_experts_per_tok=10,
+               moe_intermediate_size=512,
+               shared_expert_intermediate_size=512, norm_topk_prob=True,
+               experts_held=None, first_expert=0, flash=True,
+               moe_stats=False, init_std=0.02):
+    """Qwen3-Next (`model_type` qwen3_next) as a trainable net: blocks of
+    h = x + Mixer(RMSNorm(x)), out = h + MoE(RMSNorm(h)), the mixer gated
+    grouped-query attention (query/key RMSNorm, rotary embedding on
+    `partial_rotary_factor` of the head, an output gate) in every
+    `full_attention_interval`-th block and Gated DeltaNet in the others;
+    a no-drop top-k MoE with a shared expert after every mixer; untied
+    embedding and head; mean cross-entropy per token. Defaults are the
+    published sizes of Qwen3-Next-80B-A3B.
+
+    One chip's share of an expert-parallel group: `experts_held` experts
+    from `first_expert` on (the router keeps `num_experts` outputs),
+    `vocab_size` the held rows of embedding and head (ids and labels come
+    from that slice), `num_hidden_layers` the layers of this pipeline
+    stage. Left out: the multi-token-prediction module, the router's
+    auxiliary loss, dropout (none published).
+
+    Layers are named block{i}/ln1 | mixer | res1 | ln2 | moe | res2, so the
+    remat groups are the blocks and a run of like blocks scans."""
+    e = hidden_size
+    gauss = dict(type="gaussian", std=init_std)
+    keep, nodecay = dict(lr_mult=1, decay_mult=1), dict(lr_mult=1,
+                                                        decay_mult=0)
+    layers = [
+        RDDLayer("data", [batch_size, seq_len]),
+        RDDLayer("label", [batch_size, seq_len]),
+        EmbedLayer("tok_embed", ["data"], vocab_size, e,
+                   weight_filler=gauss, bias_term=False),
+    ]
+    x = "tok_embed"
+    for i in range(num_hidden_layers):
+        p = f"block{i}"
+        if (i + 1) % full_attention_interval == 0:
+            mixer = AttentionLayer(
+                f"{p}/mixer", [f"{p}/ln1"], num_attention_heads,
+                head_dim=head_dim, causal=True, flash=flash,
+                num_kv_heads=num_key_value_heads, qk_norm=True,
+                rotary_dim=int(head_dim * partial_rotary_factor),
+                rope_theta=rope_theta, output_gate=True,
+                norm_eps=rms_norm_eps, weight_filler=gauss,
+                param=[keep] * 4 + [nodecay] * 2)
+        else:
+            mixer = GatedDeltaNetLayer(
+                f"{p}/mixer", [f"{p}/ln1"], linear_num_key_heads,
+                linear_num_value_heads, linear_key_head_dim,
+                linear_value_head_dim, conv_kernel=linear_conv_kernel_dim,
+                norm_eps=rms_norm_eps, weight_filler=gauss,
+                param=[keep] * 3 + [nodecay] * 3 + [keep])
+        layers += [
+            RMSNormLayer(f"{p}/ln1", [x], eps=rms_norm_eps,
+                         param=[nodecay]),
+            mixer,
+            EltwiseLayer(f"{p}/res1", [x, f"{p}/mixer"]),
+            RMSNormLayer(f"{p}/ln2", [f"{p}/res1"], eps=rms_norm_eps,
+                         param=[nodecay]),
+            MoELayer(f"{p}/moe", [f"{p}/ln2"], num_experts,
+                     hidden_dim=moe_intermediate_size,
+                     top_k=num_experts_per_tok, experts_held=experts_held,
+                     first_expert=first_expert,
+                     shared_hidden_dim=shared_expert_intermediate_size,
+                     norm_topk_prob=norm_topk_prob, weight_filler=gauss,
+                     stats=moe_stats),
+            EltwiseLayer(f"{p}/res2", [f"{p}/res1", f"{p}/moe"]),
+        ]
+        x = f"{p}/res2"
+    layers += [
+        RMSNormLayer("ln_f", [x], eps=rms_norm_eps, param=[nodecay]),
+        InnerProductLayer("lm_head", ["ln_f"], vocab_size,
+                          weight_filler=gauss, axis=2, bias_term=False),
+        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
+    ]
+    return NetParam("Qwen3Next", *layers)
 
 
 def transformer_lm_pieces(vocab_size=512, seq_len=256, batch_size=8,

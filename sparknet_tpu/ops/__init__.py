@@ -16,6 +16,7 @@ from . import (  # noqa: F401
     losses,
     feed,
     attention,
+    deltanet,
     moe,
     python_layer,
 )

@@ -161,5 +161,10 @@ class Dropout(_Elementwise):
             return [x]
         ratio = self.lp.dropout_param.dropout_ratio
         keep = 1.0 - ratio
-        mask = jax.random.bernoulli(rng, keep, x.shape)
+        # one shard of a data-parallel step keeps its rows of the global
+        # batch's draw: the mesh step equals the one-device step
+        from ..parallel import context
+        mask = context.shard_rows(
+            lambda k, shape: jax.random.bernoulli(k, keep, shape),
+            rng, x.shape)
         return [jnp.where(mask, x / keep, 0).astype(x.dtype)]

@@ -7,8 +7,21 @@ keeps one large MXU matmul; when the net is traced inside a sequence-sharded
 shard_map (parallel.context provides a "seq" axis) and attention_param.ring
 is set, the core switches to ring attention over the mesh — the layer code
 is identical on 1 chip and on a 64-way ring.
+
+The grouped-query form (attention_param.num_kv_heads set) is the attention
+of today's language models: separate bias-free projections
+  q (H*D, E) — or [q | gate] per head, (H*2D, E), with output_gate —,
+  k (Hkv*D, E), v (Hkv*D, E), out (E, H*D), then with qk_norm the two
+  zero-centred RMSNorm weights (D,) of the query and key heads;
+each key-value head serves H / Hkv query heads; rotary embedding
+(rotate-half convention, positions 0..S-1) on the first rotary_dim
+dimensions of every head, the rest untouched; softmax(q k^T / sqrt(D)) v;
+with output_gate o * sigmoid(gate) before the out projection. The flash
+kernel reads the shared key-value heads in place (no repeat in memory);
+the dense path repeats them.
 """
 
+import jax
 import jax.numpy as jnp
 
 from ..proto import Message
@@ -16,6 +29,25 @@ from ..graph.registry import Layer, register
 from ..parallel import context
 from ..parallel.ring import ring_attention, dense_attention
 from .convolution import _param_mults
+from .normalization import rms_norm
+
+
+def rotary(x, rotary_dim, theta):
+    """Rotate-half rotary embedding of x (B, S, H, D) on its first
+    `rotary_dim` dimensions at positions 0..S-1, in float32."""
+    if not rotary_dim:
+        return x
+    s = x.shape[1]
+    inv = theta ** (-jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
+                    / rotary_dim)
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None, :]
+    xr = x[..., :rotary_dim].astype(jnp.float32)
+    half = rotary_dim // 2
+    rot = jnp.concatenate([-xr[..., half:], xr[..., :half]], -1)
+    out = (xr * cos + rot * sin).astype(x.dtype)
+    return jnp.concatenate([out, x[..., rotary_dim:]], -1)
 
 
 @register
@@ -35,13 +67,44 @@ class Attention(Layer):
         self.ring = bool(p.ring)
         self.flash = bool(p.flash)
         self.inner = self.num_heads * self.head_dim
+        # grouped-query form
+        self.gqa = p.has("num_kv_heads")
+        self.kv_heads = int(p.num_kv_heads) if self.gqa else self.num_heads
+        self.qk_norm = bool(p.qk_norm)
+        self.rotary_dim = int(p.rotary_dim)
+        self.rope_theta = float(p.rope_theta)
+        self.output_gate = bool(p.output_gate)
+        self.norm_eps = float(p.norm_eps)
+        if self.num_heads % self.kv_heads:
+            raise ValueError(f"{lp.name}: num_heads {self.num_heads} is not "
+                             f"a multiple of num_kv_heads {self.kv_heads}")
+        if not self.gqa and (self.qk_norm or self.rotary_dim
+                             or self.output_gate):
+            raise ValueError(f"{lp.name}: qk_norm, rotary_dim and "
+                             "output_gate need num_kv_heads (the "
+                             "grouped-query form)")
+        if self.gqa and self.ring:
+            raise ValueError(f"{lp.name}: the grouped-query form has no "
+                             "ring mode")
 
     def param_shapes(self):
-        mults = _param_mults(self.lp, 4)
         # unlike stock Caffe layers (default constant-0), an attention with
         # zero projections is a degenerate identity-killer — default xavier
         wf = self.p.weight_filler if self.p.has("weight_filler") \
             else Message("FillerParameter", type="xavier")
+        if self.gqa:
+            mults = _param_mults(self.lp, 6)
+            kv = self.kv_heads * self.head_dim
+            q_out = self.inner * (2 if self.output_gate else 1)
+            shapes = [((q_out, self.embed), wf, *mults[0]),
+                      ((kv, self.embed), wf, *mults[1]),
+                      ((kv, self.embed), wf, *mults[2]),
+                      ((self.embed, self.inner), wf, *mults[3])]
+            if self.qk_norm:
+                shapes += [((self.head_dim,), None, *mults[4]),
+                           ((self.head_dim,), None, *mults[5])]
+            return shapes
+        mults = _param_mults(self.lp, 4)
         return [
             ((3 * self.inner, self.embed), wf, *mults[0]),   # fused qkv
             ((3 * self.inner,), None, *mults[1]),
@@ -54,6 +117,8 @@ class Attention(Layer):
 
     def apply(self, params, bottoms, train, rng):
         x = bottoms[0]                                   # (B, S, E)
+        if self.gqa:
+            return [self._apply_gqa(params, x)]
         wqkv, bqkv, wo, bo = [p.astype(x.dtype) for p in params]
         b, s, _ = x.shape
         qkv = x @ wqkv.T + bqkv                          # (B, S, 3*H*D)
@@ -69,3 +134,35 @@ class Attention(Layer):
             o = dense_attention(q, k, v, causal=self.causal)
         o = jnp.moveaxis(o, 2, 1).reshape(b, s, self.inner)
         return [o @ wo.T + bo]
+
+    def _apply_gqa(self, params, x):
+        wq, wk, wv, wo = [p.astype(x.dtype) for p in params[:4]]
+        b, s, _ = x.shape
+        h, hk, d = self.num_heads, self.kv_heads, self.head_dim
+        q = x @ wq.T
+        gate = None
+        if self.output_gate:
+            q = q.reshape(b, s, h, 2 * d)
+            q, gate = q[..., :d], q[..., d:].reshape(b, s, h * d)
+        q = q.reshape(b, s, h, d)
+        k = (x @ wk.T).reshape(b, s, hk, d)
+        v = (x @ wv.T).reshape(b, s, hk, d)
+        if self.qk_norm:
+            q = rms_norm(q, params[4], self.norm_eps)
+            k = rms_norm(k, params[5], self.norm_eps)
+        with jax.named_scope("rope"):
+            q = rotary(q, self.rotary_dim, self.rope_theta)
+            k = rotary(k, self.rotary_dim, self.rope_theta)
+        q, k, v = [jnp.moveaxis(a, 1, 2) for a in (q, k, v)]  # (B, H, S, D)
+        with jax.named_scope("attn_core"):
+            if self.flash and s % 128 == 0:
+                from .pallas_attention import flash_attention
+                o = flash_attention(q, k, v, self.causal)
+            else:
+                o = dense_attention(q, jnp.repeat(k, h // hk, axis=1),
+                                    jnp.repeat(v, h // hk, axis=1),
+                                    causal=self.causal)
+        o = jnp.moveaxis(o, 2, 1).reshape(b, s, h * d)
+        if gate is not None:
+            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+        return o @ wo.T

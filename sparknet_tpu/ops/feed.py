@@ -161,6 +161,8 @@ class DummyData(Layer):
 
     def apply(self, params, bottoms, train, rng):
         import jax
+        from ..parallel import context
+        rng = context.shard_key(rng)
         keys = jax.random.split(rng, len(self.shapes)) if rng is not None \
             else [None] * len(self.shapes)
         return [F.fill(k, s, f) for k, s, f in

@@ -36,6 +36,20 @@ class _Loss(Layer):
         return [()]
 
 
+CHUNK_BYTES = 1 << 28       # float32 logits above this go block by block
+
+
+def _row_chunk(rows, classes):
+    """Rows a block for `SoftmaxWithLoss` when float32 logits of (rows,
+    classes) pass CHUNK_BYTES: the largest divisor of `rows` whose block
+    stays under half of it; 0 = all at once (every CNN head)."""
+    if rows * classes * 4 <= CHUNK_BYTES:
+        return 0
+    limit = max(1, CHUNK_BYTES // 2 // (classes * 4))
+    return next((r for r in range(min(limit, rows), 0, -1)
+                 if rows % r == 0), 0)
+
+
 @register
 class SoftmaxWithLoss(_Loss):
     type_name = "SoftmaxWithLoss"
@@ -59,11 +73,24 @@ class SoftmaxWithLoss(_Loss):
         # after moveaxis+reshape? moveaxis gives (outer..., inner..., C) ->
         # rows enumerate outer-major, inner-minor: matches (i * inner + j).
         lab_flat = lab.reshape(-1).astype(jnp.int32)
-        logp = jax.nn.log_softmax(xm.astype(jnp.float32), axis=-1)
-        # Caffe clamps prob at FLT_MIN -> logp at log(FLT_MIN)
-        picked = jnp.maximum(
-            jnp.take_along_axis(logp, lab_flat[:, None], axis=-1)[:, 0],
-            np.log(FLT_MIN))
+
+        def pick(xm, lab_flat):
+            logp = jax.nn.log_softmax(xm.astype(jnp.float32), axis=-1)
+            # Caffe clamps prob at FLT_MIN -> logp at log(FLT_MIN)
+            return jnp.maximum(
+                jnp.take_along_axis(logp, lab_flat[:, None], axis=-1)[:, 0],
+                np.log(FLT_MIN))
+        rows = _row_chunk(outer * inner, c)
+        if rows:
+            # a language model's logits (tokens x vocabulary): the float32
+            # log-softmax and its cotangent are made a block of rows at a
+            # time and never stand whole; the backward recomputes a block
+            picked = jax.lax.map(
+                lambda a: jax.checkpoint(pick)(*a),
+                (xm.reshape(-1, rows, c), lab_flat.reshape(-1, rows))
+            ).reshape(-1)
+        else:
+            picked = pick(xm, lab_flat)
         if self.ignore_label is not None:
             valid = (lab_flat != self.ignore_label)
             picked = jnp.where(valid, picked, 0.0)

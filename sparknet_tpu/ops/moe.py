@@ -1,4 +1,38 @@
-"""Switch-style mixture-of-experts FFN with expert parallelism.
+"""Mixture-of-experts FFN: the top-1 Switch form with expert parallelism,
+and the no-drop top-k form of today's language models.
+
+Two forms, chosen by moe_param.gated_experts:
+
+* False (the default): top-1 routing with a capacity cut, ReLU experts
+  with biases, optional all_to_all expert parallelism: everything below
+  the next rule. `top_k = 1` with a `capacity_factor` is this form.
+* True: p = softmax(W_r x) over ALL num_experts in float32; the top_k
+  largest, their weights divided by their sum (norm_topk_prob);
+  routed = sum over the chosen experts THAT THIS LAYER HOLDS of
+  p_e W_down,e (SiLU(W_gate,e x) * W_up,e x), no bias anywhere. The layer
+  holds `experts_held` experts starting at the router's output
+  `first_expert` (0 held = all of them: the whole layer); the router keeps
+  num_experts outputs either way, and what the absent experts would add is
+  left out — one chip's share of an expert-parallel group, run without
+  its exchange. NO TOKEN IS DROPPED, whatever the imbalance: the
+  token-expert pairs are sorted by expert and the held experts' pairs run
+  tile by tile (`tile_rows` rows of one expert at a time, as many tiles as
+  the routing needs: a loop of dynamic length, so neither memory nor work
+  is bound by the worst case of all tokens x top_k rows). With
+  shared_hidden_dim > 0 a shared expert sees every token:
+  shared = sigmoid(w_s . x) W_down (SiLU(W_gate x) * W_up x), and
+  y = routed + shared. Tops: [output] or [output, stats]; stats (weight
+  0, kept as layer state so that the solver can read it where it already
+  waits for a loss) = [share of the token-expert pairs that land on held
+  experts, largest over mean load of the held experts]. Blobs:
+    router (num_experts, E) | w_gate (held, F, E) | w_up (held, F, E)
+    | w_down (held, E, F) | then with a shared expert: ws_gate (Fs, E)
+    | ws_up (Fs, E) | ws_down (E, Fs) | shared gate (1, E)
+  Scopes inside the layer's own: moe_route (softmax, top-k, sort),
+  moe_dispatch (gathering a tile's rows), moe_experts (the three
+  products), moe_combine (weighting and scattering back), moe_shared.
+
+The top-1 form:
 
 sparknet_tpu extension (no reference twin — SURVEY.md section 2c lists
 EP/MoE as absent from the CNN-era reference); the expert-parallel half of
@@ -47,6 +81,7 @@ shard dim 0 across the expert axis):
   | w2 (num_experts, E, F) | b2 (num_experts, E)
 """
 
+import functools
 import math
 
 import jax
@@ -57,6 +92,133 @@ from ..proto import Message
 from ..graph.registry import Layer, register
 from ..parallel import context
 from .convolution import _param_mults
+
+
+# -- the no-drop form: held experts over ragged groups, tile by tile --------
+
+def max_tiles(n_tokens, top_k, held, tile):
+    """Static length of the tile table: a token's top_k experts differ, so
+    at most n_tokens x min(top_k, held) pairs land here, and each held
+    expert's group is padded to whole tiles."""
+    return -(-n_tokens * min(top_k, held) // tile) + held
+
+
+def plan_tiles(pair_expert, held, tile, n_tiles):
+    """From each pair's local expert (`held` = not held here), the plan
+    the tile loops follow: `order` (pairs sorted by expert, the held ones
+    first), per held expert its `count` and where its group starts in
+    `order`, per tile its expert, and the number of tiles in use."""
+    order = jnp.argsort(pair_expert, stable=True)
+    bounds = jnp.searchsorted(pair_expert[order], jnp.arange(held + 1))
+    start, count = bounds[:-1], bounds[1:] - bounds[:-1]
+    tiles = -(-count // tile)
+    tile_end = jnp.cumsum(tiles)
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        tile_end, jnp.arange(n_tiles), side="right"), held - 1)
+    return {"order": order.astype(jnp.int32), "count": count,
+            "start": start.astype(jnp.int32),
+            "tile_start": (tile_end - tiles).astype(jnp.int32),
+            "tile_expert": tile_expert.astype(jnp.int32),
+            "used": tile_end[-1].astype(jnp.int32)}
+
+
+def _tile_rows(plan, t, tile, top_k):
+    """Tile t's rows: (expert, pair index, token index, valid) — the
+    rows of one held expert's group, `tile` at a time."""
+    e = plan["tile_expert"][t]
+    off = (t - plan["tile_start"][e]) * tile + jnp.arange(tile)
+    valid = off < plan["count"][e]
+    pair = plan["order"][jnp.minimum(plan["start"][e] + off,
+                                     plan["order"].shape[0] - 1)]
+    return e, pair, pair // top_k, valid
+
+
+def _expert_tile(xt, g, u):
+    a = jnp.dot(xt, g.T, preferred_element_type=jnp.float32)
+    b = jnp.dot(xt, u.T, preferred_element_type=jnp.float32)
+    return a, b
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def held_experts(x, pair_weight, plan, wg, wu, wd, tile, top_k):
+    """sum over each token's held experts e of pair_weight x W_down,e
+    (SiLU(W_gate,e x) * W_up,e x). x (n, E) and the weights in the
+    compute type, pair_weight (n x top_k,) float32 (token-major), `plan`
+    from `plan_tiles`. -> (n, E) float32. A `fori_loop` over the tiles in
+    use; the backward pass is a second such loop that recomputes each
+    tile, so nothing is stored per tile."""
+    return _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k)[0]
+
+
+def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k):
+    n, e_dim = x.shape
+
+    def body(t, y):
+        with jax.named_scope("moe_dispatch"):
+            e, pair, tok, valid = _tile_rows(plan, t, tile, top_k)
+            xt = x[tok]
+        with jax.named_scope("moe_experts"):
+            a, b = _expert_tile(xt, wg[e], wu[e])
+            h = (jax.nn.silu(a) * b).astype(x.dtype)
+            yt = jnp.dot(h, wd[e].T, preferred_element_type=jnp.float32)
+        with jax.named_scope("moe_combine"):
+            wt = jnp.where(valid, pair_weight[pair], 0.0)
+            return y.at[tok].add(yt * wt[:, None])
+
+    y = lax.fori_loop(0, plan["used"], body,
+                      jnp.zeros((n, e_dim), jnp.float32))
+    return y, (x, pair_weight, plan, wg, wu, wd)
+
+
+def _held_bwd(tile, top_k, res, dy):
+    x, pair_weight, plan, wg, wu, wd = res
+    dy = dy.astype(jnp.float32)
+
+    def body(t, carry):
+        dx, dpw, dwg, dwu, dwd = carry
+        with jax.named_scope("moe_dispatch"):
+            e, pair, tok, valid = _tile_rows(plan, t, tile, top_k)
+            xt, dyt = x[tok], dy[tok]
+        with jax.named_scope("moe_experts"):
+            a, b = _expert_tile(xt, wg[e], wu[e])
+            sa = jax.nn.sigmoid(a)
+            silu = a * sa
+            h = (silu * b).astype(x.dtype)
+            wt = jnp.where(valid, pair_weight[pair], 0.0)
+            # d pair_weight = dy . (h W_down^T), row by row
+            out = jnp.dot(h, wd[e].T, preferred_element_type=jnp.float32)
+            dwt = jnp.where(valid, jnp.sum(dyt * out, -1), 0.0)
+            dyw = (dyt * wt[:, None]).astype(x.dtype)
+            dh = jnp.dot(dyw, wd[e], preferred_element_type=jnp.float32)
+            da = (dh * b * (sa + silu * (1.0 - sa))).astype(x.dtype)
+            db = (dh * silu).astype(x.dtype)
+            dxt = jnp.dot(da, wg[e], preferred_element_type=jnp.float32) \
+                + jnp.dot(db, wu[e], preferred_element_type=jnp.float32)
+
+            def acc(total, part):
+                return lax.dynamic_update_index_in_dim(
+                    total, total[e] + part, e, 0)
+            dwg = acc(dwg, jnp.dot(da.T, xt,
+                                   preferred_element_type=jnp.float32))
+            dwu = acc(dwu, jnp.dot(db.T, xt,
+                                   preferred_element_type=jnp.float32))
+            dwd = acc(dwd, jnp.dot(dyw.T, h,
+                                   preferred_element_type=jnp.float32))
+        with jax.named_scope("moe_combine"):
+            dx = dx.at[tok].add(dxt)
+            # a padding row's pair index may repeat a real one: add 0 there
+            dpw = dpw.at[pair].add(dwt)
+        return dx, dpw, dwg, dwu, dwd
+
+    zeros = [jnp.zeros(a.shape, jnp.float32)
+             for a in (x, pair_weight, wg, wu, wd)]
+    dx, dpw, dwg, dwu, dwd = lax.fori_loop(0, plan["used"], body,
+                                           tuple(zeros))
+    return (dx.astype(x.dtype), dpw, None, dwg.astype(wg.dtype),
+            dwu.astype(wu.dtype), dwd.astype(wd.dtype))
+
+
+held_experts.defvjp(_held_fwd, _held_bwd)
 
 
 @register
@@ -75,11 +237,39 @@ class MoE(Layer):
         self.hidden = int(p.hidden_dim) or 4 * self.embed
         self.capacity_factor = float(p.capacity_factor)
         self.expert_parallel = bool(int(p.expert_parallel))
+        self.gated = bool(int(p.gated_experts))
+        self.top_k = int(p.top_k)
+        self.norm_topk = bool(int(p.norm_topk_prob))
+        self.held = int(p.experts_held) or self.num_experts
+        self.first = int(p.first_expert)
+        self.shared_hidden = int(p.shared_hidden_dim)
+        self.tile = int(p.tile_rows)
+        if not self.gated and (self.top_k != 1 or p.has("experts_held")
+                               or self.shared_hidden):
+            raise ValueError(
+                f"{lp.name}: top_k > 1, experts_held and a shared expert "
+                "belong to the no-drop form (moe_param.gated_experts)")
+        if self.gated:
+            if self.first + self.held > self.num_experts \
+                    or self.top_k > self.num_experts:
+                raise ValueError(
+                    f"{lp.name}: experts {self.first}..{self.first + self.held}"
+                    f" and top_k {self.top_k} of {self.num_experts}")
+            # the statistics live in the layer's state: the solver reads
+            # them where it already waits for a loss
+            self.has_state = len(lp.top) > 1
+            if self.has_state:
+                self.monitor = ("moe.load", ("held_share", "max_over_mean"))
+
+    def state_shapes(self):
+        return [((2,), 0.0)] if self.gated and len(self.lp.top) > 1 else []
 
     def _capacity(self, n):
         return max(1, math.ceil(n / self.num_experts * self.capacity_factor))
 
     def param_shapes(self):
+        if self.gated:
+            return self._gated_param_shapes()
         mults = _param_mults(self.lp, 5)
         X, E, F = self.num_experts, self.embed, self.hidden
 
@@ -98,8 +288,24 @@ class MoE(Layer):
                 ((X, E, F), wf or xavier(F), *mults[3]),    # w2
                 ((X, E), None, *mults[4])]                  # b2
 
+    def _gated_param_shapes(self):
+        mults = _param_mults(self.lp, 8)
+        E, F, Fs = self.embed, self.hidden, self.shared_hidden
+        wf = self.p.weight_filler if self.p.has("weight_filler") \
+            else Message("FillerParameter", type="gaussian", std=0.02)
+        shapes = [((self.num_experts, E), wf, *mults[0]),   # router
+                  ((self.held, F, E), wf, *mults[1]),       # w_gate
+                  ((self.held, F, E), wf, *mults[2]),       # w_up
+                  ((self.held, E, F), wf, *mults[3])]       # w_down
+        if Fs:
+            shapes += [((Fs, E), wf, *mults[4]), ((Fs, E), wf, *mults[5]),
+                       ((E, Fs), wf, *mults[6]), ((1, E), wf, *mults[7])]
+        return shapes
+
     def out_shapes(self):
         shapes = [tuple(self.bottom_shapes[0])]
+        if self.gated:
+            return shapes + [(2,)] * (len(self.lp.top) - 1)
         if len(self.lp.top) > 1:
             shapes.append(())                     # aux load-balancing loss
         if len(self.lp.top) > 2:
@@ -109,6 +315,8 @@ class MoE(Layer):
         return shapes
 
     def apply(self, params, bottoms, train, rng):
+        if self.gated:
+            return self.apply_stateful(params, [], bottoms, train, rng)[0]
         x = bottoms[0]
         router, w1, b1, w2, b2 = params
         b, s, e = x.shape
@@ -183,3 +391,49 @@ class MoE(Layer):
                 tops.append(lax.stop_gradient(
                     jnp.concatenate([frac, overflow[None]])))
         return tops
+
+    # -- the no-drop form ---------------------------------------------------
+    def route(self, xt, router):
+        """-> (expert indices (n, k) into all the router's outputs, their
+        weights (n, k) float32)."""
+        logits = jnp.dot(xt.astype(jnp.float32),
+                         router.astype(jnp.float32).T,
+                         precision=lax.Precision.HIGHEST)
+        top, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), self.top_k)
+        if self.norm_topk:
+            top = top / jnp.sum(top, -1, keepdims=True)
+        return idx, top
+
+    def apply_stateful(self, params, state, bottoms, train, rng):
+        x = bottoms[0]
+        b, s, e = x.shape
+        n, k, held = b * s, self.top_k, self.held
+        xt = x.reshape(n, e)
+        with jax.named_scope("moe_route"):
+            idx, top = self.route(xt, params[0])
+            local = idx.reshape(n * k) - self.first
+            pair_expert = jnp.where((local >= 0) & (local < held), local,
+                                    held).astype(jnp.int32)
+            n_tiles = max_tiles(n, k, held, self.tile)
+            plan = plan_tiles(pair_expert, held, self.tile, n_tiles)
+        wg, wu, wd = (w.astype(x.dtype) for w in params[1:4])
+        y = held_experts(xt, top.reshape(n * k), plan, wg, wu, wd,
+                         self.tile, k)
+        if self.shared_hidden:
+            with jax.named_scope("moe_shared"):
+                sg, su, sd, gate = (w.astype(x.dtype) for w in params[4:8])
+                h = jax.nn.silu(xt @ sg.T) * (xt @ su.T)
+                open_ = jax.nn.sigmoid(jnp.dot(
+                    xt, gate.T, preferred_element_type=jnp.float32))
+                y = y + open_ * jnp.dot(h, sd.T,
+                                        preferred_element_type=jnp.float32)
+        tops = [y.reshape(b, s, e).astype(x.dtype)]
+        if len(self.lp.top) > 1:
+            load = plan["count"].astype(jnp.float32)
+            here = jnp.sum(load)
+            stats = lax.stop_gradient(jnp.stack([
+                here / (n * k),
+                jnp.max(load) * held / jnp.maximum(here, 1.0)]))
+            tops.append(stats)
+            return tops, [stats]
+        return tops, state

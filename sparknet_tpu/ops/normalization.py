@@ -1,4 +1,4 @@
-"""Normalization layers: BatchNorm (stateful), MVN, and LayerNorm.
+"""Normalization layers: BatchNorm (stateful), MVN, LayerNorm and RMSNorm.
 
 BatchNorm matches reference batch_norm_layer.cpp: three non-learnable blobs
 [running_mean*s, running_var*s, s] where s is the accumulated scale factor;
@@ -14,10 +14,14 @@ to zero mean and, optionally, unit variance with divisor (std + eps).
 LayerNorm is a sparknet_tpu extension (no CNN-era reference twin): last-axis
 normalization with learned gamma/beta, the transformer-block complement of
 the Attention layer. Statistics in fp32 regardless of activation dtype (the
-bf16 mixed-precision path keeps reductions exact).
+bf16 mixed-precision path keeps reductions exact). RMSNorm is its
+mean-free, bias-free sibling with one blob, in the zero-centred form
+(1 + w) or the plain one; `rms_norm` is also what the attention layer's
+query/key norm and the Gated DeltaNet's gated norm call.
 """
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ..graph.registry import Layer, register
@@ -111,6 +115,46 @@ class LayerNorm(Layer):
             y = y * params[0].astype(jnp.float32) \
                 + params[1].astype(jnp.float32)
         return [y.astype(x.dtype)]
+
+
+def rms_norm(x, w, eps, zero_centered=True):
+    """x / sqrt(mean(x^2) + eps) * (1 + w) over the last axis (or * w when
+    not zero_centered), computed in float32, returned in x's type."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    wf = w.astype(jnp.float32)
+    return (y * (1.0 + wf if zero_centered else wf)).astype(x.dtype)
+
+
+@register
+class RMSNorm(Layer):
+    """sparknet_tpu extension: last-axis RMS norm with one learned blob,
+    no mean subtraction and no bias. `zero_centered` (the default) is the
+    form y = x / rms(x) * (1 + w) with w filled with 0; otherwise
+    y = x / rms(x) * w with w filled with 1. Statistics in float32."""
+
+    type_name = "RMSNorm"
+
+    def __init__(self, lp, bottom_shapes, phase):
+        super().__init__(lp, bottom_shapes, phase)
+        p = lp.rms_norm_param
+        self.eps = float(p.eps)
+        self.zero_centered = bool(int(p.zero_centered))
+        self.dim = int(bottom_shapes[0][-1])
+
+    def param_shapes(self):
+        from ..proto import Message
+        from .convolution import _param_mults
+        fill = Message("FillerParameter", type="constant",
+                       value=0.0 if self.zero_centered else 1.0)
+        return [((self.dim,), fill, *_param_mults(self.lp, 1)[0])]
+
+    def out_shapes(self):
+        return [self.bottom_shapes[0]]
+
+    def apply(self, params, bottoms, train, rng):
+        return [rms_norm(bottoms[0], params[0], self.eps,
+                         self.zero_centered)]
 
 
 @register

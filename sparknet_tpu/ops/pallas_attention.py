@@ -109,21 +109,35 @@ def _fit_block(block, s):
     return largest
 
 
+def _group(q, k):
+    """Query heads per key-value head: q is (B, H, S, D), k and v
+    (B, Hkv, S, D) with H a multiple of Hkv; query head h of a batch row
+    reads key-value head h // (H / Hkv), so with heads flattened
+    batch-major, row bh of q reads row bh // group of k and v."""
+    h, hkv = q.shape[1], k.shape[1]
+    if h % hkv:
+        raise ValueError(f"flash attention: {h} query heads over {hkv} "
+                         "key-value heads")
+    return h // hkv
+
+
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
     b, h, s, d = q.shape
+    grp = _group(q, k)
     block_q = _fit_block(block_q, s)
     block_k = _fit_block(block_k, s)
     qf = q.reshape(b * h, s, d)
-    kf = k.reshape(b * h, s, d)
-    vf = v.reshape(b * h, s, d)
+    kf = k.reshape(b * h // grp, s, d)
+    vf = v.reshape(b * h // grp, s, d)
     kernel = functools.partial(_fa_kernel, causal=causal, scale=scale)
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, s // block_q, s // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0)),
+            # query head bh reads key-value head bh // grp in place
+            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh // grp, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh // grp, j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
@@ -189,14 +203,15 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dk_ref, dv_ref,
-                dk_acc, dv_acc, *, causal, scale):
+                dk_acc, dv_acc, *, causal, scale, nq):
     _, bq, d = q_ref.shape
     bk = k_ref.shape[1]
-    ki = pl.program_id(1)       # note: grid is (bh, j, i) here
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    ki = pl.program_id(1)       # note: grid is (kv head, j, group x i) here
+    t = pl.program_id(2)        # the group's query heads one after another,
+    qi = t % nq                 # each over its nq query blocks
+    nt = pl.num_programs(2)
 
-    @pl.when(qi == 0)
+    @pl.when(t == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -232,7 +247,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dk_ref, dv_ref,
             ds, qb, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(t == nt - 1)
     def _finish():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -241,16 +256,19 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dk_ref, dv_ref,
 def _flash_backward(q, k, v, o, lse, g, causal, scale, block_q, block_k,
                     interpret):
     b, h, s, d = q.shape
+    grp = _group(q, k)
+    hkv = h // grp
     block_q = _fit_block(block_q, s)
     block_k = _fit_block(block_k, s)
+    nq = s // block_q
     qf = q.reshape(b * h, s, d)
-    kf = k.reshape(b * h, s, d)
-    vf = v.reshape(b * h, s, d)
+    kf = k.reshape(b * hkv, s, d)
+    vf = v.reshape(b * hkv, s, d)
     dof = g.reshape(b * h, s, d)
     of = o.reshape(b * h, s, d)
 
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh, j, 0))
+    k_spec = pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh // grp, j, 0))
     lse_spec = pl.BlockSpec((1, block_q, LANES),
                             lambda bh, i, j: (bh, i, 0))
     dq = pl.pallas_call(
@@ -264,25 +282,28 @@ def _flash_backward(q, k, v, o, lse, g, causal, scale, block_q, block_k,
         name="flash_dq",
     )(qf, kf, vf, dof, of, lse)
 
-    # second kernel iterates (bh, j, i): Q/dO stream innermost
-    qT_spec = pl.BlockSpec((1, block_q, d), lambda bh, j, i: (bh, i, 0))
-    kT_spec = pl.BlockSpec((1, block_k, d), lambda bh, j, i: (bh, j, 0))
+    # second kernel iterates (kv head, j, t): Q/dO stream innermost, the
+    # group's query heads one after another (t = head in group x nq + i),
+    # so a shared key-value head's gradient is summed in the kernel
+    qT_spec = pl.BlockSpec((1, block_q, d),
+                           lambda bk, j, t: (bk * grp + t // nq, t % nq, 0))
+    kT_spec = pl.BlockSpec((1, block_k, d), lambda bk, j, t: (bk, j, 0))
     lseT_spec = pl.BlockSpec((1, block_q, LANES),
-                             lambda bh, j, i: (bh, i, 0))
+                             lambda bk, j, t: (bk * grp + t // nq, t % nq, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, scale=scale),
-        grid=(b * h, s // block_k, s // block_q),
+        functools.partial(_dkv_kernel, causal=causal, scale=scale, nq=nq),
+        grid=(b * hkv, s // block_k, grp * nq),
         in_specs=[qT_spec, kT_spec, kT_spec, qT_spec, qT_spec, lseT_spec],
         out_specs=[kT_spec, kT_spec],
-        out_shape=[jax.ShapeDtypeStruct((b * h, s, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, s, d), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((b * hkv, s, d), k.dtype),
+                   jax.ShapeDtypeStruct((b * hkv, s, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
         name="flash_dkv",
     )(qf, kf, vf, dof, of, lse)
-    return (dq.reshape(b, h, s, d), dk.reshape(b, h, s, d),
-            dv.reshape(b, h, s, d))
+    return (dq.reshape(b, h, s, d), dk.reshape(b, hkv, s, d),
+            dv.reshape(b, hkv, s, d))
 
 
 
@@ -291,7 +312,10 @@ def _flash_backward(q, k, v, o, lse, g, causal, scale, block_q, block_k,
 def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
                     block_k=512):
     """Flash attention (B, H, S, D) -> (B, H, S, D); exact, O(block) VMEM
-    in both forward and backward. scale defaults to 1/sqrt(D).
+    in both forward and backward. scale defaults to 1/sqrt(D). k and v may
+    have fewer heads, (B, Hkv, S, D) with H a multiple of Hkv: query head
+    h reads key-value head h // (H / Hkv) in place, and the backward sums
+    a shared head's gradient over its group inside the kernel.
 
     Default blocks are 512x512: the grid's K/V dimension is sequential,
     so small blocks are dispatch-latency-bound — at S=32k, 512x512 runs

@@ -119,7 +119,8 @@ def stochastic_pool(x, kernel, stride, pad, out, train, rng):
         # all-nonpositive windows: fall back to uniform choice over window
         dead = jnp.all(p <= 0, axis=2, keepdims=True)
         logits = jnp.where(dead, jnp.zeros_like(logits), logits)
-        idx = jax.random.categorical(rng, logits, axis=2)
+        from ..parallel import context
+        idx = jax.random.categorical(context.shard_key(rng), logits, axis=2)
         return jnp.take_along_axis(p, idx[:, :, None], axis=2)[:, :, 0]
     denom = jnp.sum(p, axis=2)
     num = jnp.sum(p * p, axis=2)

@@ -57,6 +57,50 @@ def world_context(**info):
         _state.world = prev
 
 
+def batch_shard():
+    """(mesh axis, shards) while a per-step data-parallel solver traces
+    one shard's share of a global batch, else None. A layer that draws a
+    random number per sample (Dropout) draws for the whole batch and keeps
+    its shard's rows, so that the mesh step equals the one-device step on
+    the same global batch; any other random layer takes `shard_key`."""
+    return getattr(_state, "batch_shard", None)
+
+
+@contextlib.contextmanager
+def batch_shard_context(axis, shards):
+    prev = batch_shard()
+    _state.batch_shard = (axis, int(shards))
+    try:
+        yield
+    finally:
+        _state.batch_shard = prev
+
+
+def shard_key(rng):
+    """`rng` folded with this shard's index under `batch_shard_context`
+    (a stream of its own per shard), `rng` itself elsewhere."""
+    shard = batch_shard()
+    if shard is None or rng is None:
+        return rng
+    import jax
+    return jax.random.fold_in(rng, jax.lax.axis_index(shard[0]))
+
+
+def shard_rows(draw, rng, shape):
+    """`draw(rng, shape)` for this shard's rows of a per-sample draw: under
+    `batch_shard_context` the draw is made for the global batch (`shape[0]`
+    x shards rows) and this shard's rows are cut out of it."""
+    shard = batch_shard()
+    if shard is None:
+        return draw(rng, shape)
+    import jax
+    axis, n = shard
+    rows = shape[0]
+    full = draw(rng, (rows * n,) + tuple(shape[1:]))
+    return jax.lax.dynamic_slice_in_dim(
+        full, jax.lax.axis_index(axis) * rows, rows, axis=0)
+
+
 # -- host topology (multi-host runtime) -------------------------------------
 # Unlike the trace-time axis/world contexts above, the host topology is a
 # process-wide constant: one process == one fault domain, fixed at

@@ -177,8 +177,11 @@ def _rebatch(net, n, seq=1):
                     f"by seq axis size {seq}")
             out[1] = s[1] // seq
         local[name] = tuple(out)
-    return CompiledNet(net.net_param, net.phase, feed_shapes=local,
+    twin = CompiledNet(net.net_param, net.phase, feed_shapes=local,
                        dtype=net.dtype, compute_dtype=net.compute_dtype)
+    # the knobs the solver's constructor may have set (Solver(remat=...))
+    twin.remat, twin.scan = net.remat, net.scan
+    return twin
 
 
 def _one_spec(ndim, axis, batch_dim=0, seq_axis=None, seq_dim=1):
@@ -214,6 +217,14 @@ class DataParallelSolver(Solver):
         # the per-shard nets: same params, feed blobs at batch/n — the graph
         # each device traces (the user-facing self.net keeps global shapes)
         n = self.mesh.shape[axis]
+        if jax.process_count() == 1:
+            # replicated over the mesh from the start (the step's
+            # in_specs), so that whoever replaces a blob keeps that
+            # placement: weights committed to one device cannot enter a
+            # program that spans the mesh
+            rep = NamedSharding(self.mesh, P())
+            self.params, self.state, self.history = jax.device_put(
+                (self.params, self.state, self.history), rep)
         self.local_net = _rebatch(self.net, n)
         self.local_test_net = _rebatch(self.test_net, n) \
             if self.test_net is not None else None
@@ -270,17 +281,23 @@ class DataParallelSolver(Solver):
             return loss, grads, new_state
 
         def step(params, state, history, batch, it, rng, alive, lag):
-            # per-device rng stream (dropout must differ across shards)
+            # one step rng for the global batch: a layer that draws per
+            # sample (Dropout) keeps its shard's rows of the global draw,
+            # so the mesh step equals the one-device step on the same
+            # batch; any other random layer folds its shard's index in
+            # (parallel.context.batch_shard, read while `one_grad` traces)
             w = jax.lax.axis_index(axis)
             my_alive = alive[w]
-            rng = jax.random.fold_in(rng, w)
             if iter_size == 1:
-                loss, grads, state = one_grad(params, state, batch, rng)
+                with context.batch_shard_context(axis, n_workers):
+                    loss, grads, state = one_grad(params, state, batch, rng)
             else:
                 def body(carry, micro):
                     acc, state, i = carry
-                    loss, g, state = one_grad(
-                        params, state, micro, jax.random.fold_in(rng, i))
+                    with context.batch_shard_context(axis, n_workers):
+                        loss, g, state = one_grad(
+                            params, state, micro,
+                            jax.random.fold_in(rng, i))
                     # fp32 accumulation regardless of param dtype (the
                     # mixed-precision contract; bitwise the old
                     # zeros_like path for fp32 params)
@@ -423,7 +440,10 @@ class DataParallelSolver(Solver):
 
     def train_step(self, batch):
         with self._step_span() as span:
-            batch = {k: np.asarray(v) for k, v in batch.items()}
+            # a batch that already lives on the devices stays there
+            # (shard_batch reshards it without a host round trip)
+            batch = {k: v if isinstance(v, jax.Array) else np.asarray(v)
+                     for k, v in batch.items()}
             iter_size = int(self.param.iter_size)
             self.check_batch(batch,
                              leading=(iter_size,) if iter_size > 1 else ())

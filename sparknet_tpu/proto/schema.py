@@ -233,6 +233,9 @@ MESSAGES = {
         "attention_param": (200, "AttentionParameter", "opt", None),
         "layer_norm_param": (201, "LayerNormParameter", "opt", None),
         "moe_param": (202, "MoEParameter", "opt", None),
+        "rms_norm_param": (203, "RMSNormParameter", "opt", None),
+        "gated_delta_net_param": (204, "GatedDeltaNetParameter", "opt",
+                                  None),
     },
     "TransformationParameter": {
         "scale": (1, "float", "opt", 1.0),
@@ -575,6 +578,33 @@ MESSAGES = {
         "ring": (4, "bool", "opt", False),
         "weight_filler": (5, "FillerParameter", "opt", None),
         "flash": (6, "bool", "opt", False),   # pallas flash kernel per chip
+        # grouped-query form (ops/attention.py): setting num_kv_heads
+        # selects separate, bias-free q/k/v/out projections, each
+        # key-value head serving num_heads / num_kv_heads query heads
+        "num_kv_heads": (7, "uint32", "opt", None),
+        "qk_norm": (8, "bool", "opt", False),     # RMSNorm per head on q, k
+        "rotary_dim": (9, "uint32", "opt", 0),    # leading dims rotated
+        "rope_theta": (10, "float", "opt", 10000.0),
+        "output_gate": (11, "bool", "opt", False),  # o * sigmoid(gate)
+        "norm_eps": (12, "float", "opt", 1e-6),
+    },
+    # sparknet_tpu extension: last-axis RMS norm, y = x / rms(x) * (1 + w)
+    # (zero_centered, w filled with 0) or * w (w filled with 1).
+    "RMSNormParameter": {
+        "eps": (1, "float", "opt", 1e-6),
+        "zero_centered": (2, "bool", "opt", True),
+    },
+    # sparknet_tpu extension: Gated DeltaNet linear attention
+    # (ops/deltanet.py), the recurrent-state mixer.
+    "GatedDeltaNetParameter": {
+        "num_k_heads": (1, "uint32", "opt", 1),
+        "num_v_heads": (2, "uint32", "opt", 1),
+        "head_k_dim": (3, "uint32", "opt", 128),
+        "head_v_dim": (4, "uint32", "opt", 128),
+        "conv_kernel": (5, "uint32", "opt", 4),
+        "chunk": (6, "uint32", "opt", 64),
+        "norm_eps": (7, "float", "opt", 1e-6),
+        "weight_filler": (8, "FillerParameter", "opt", None),
     },
     # sparknet_tpu extension: last-axis layer norm for transformer blocks.
     "LayerNormParameter": {
@@ -589,6 +619,21 @@ MESSAGES = {
         "capacity_factor": (3, "float", "opt", 1.25),
         "expert_parallel": (4, "bool", "opt", False),
         "weight_filler": (5, "FillerParameter", "opt", None),
+        # the no-drop form (gated_experts): top_k routing over ALL
+        # num_experts with renormalised weights, bias-free SiLU-gated
+        # experts, nothing dropped whatever the imbalance. experts_held /
+        # first_expert say which of the router's outputs this layer holds
+        # weights for (0 held = all): what absent experts would add is
+        # left out. shared_hidden_dim > 0 adds a shared expert behind a
+        # sigmoid gate. capacity_factor and expert_parallel belong to the
+        # top-1 Switch form only.
+        "gated_experts": (6, "bool", "opt", False),
+        "top_k": (7, "uint32", "opt", 1),
+        "norm_topk_prob": (8, "bool", "opt", True),
+        "experts_held": (9, "uint32", "opt", 0),
+        "first_expert": (10, "uint32", "opt", 0),
+        "shared_hidden_dim": (11, "uint32", "opt", 0),
+        "tile_rows": (12, "uint32", "opt", 128),
     },
 }
 

@@ -71,7 +71,7 @@ class Solver:
     def __init__(self, solver_param, net_param=None, feed_shapes=None,
                  test_feed_shapes=None, base_dir="", dtype=jnp.float32,
                  log_fn=print, metrics=None, compute_dtype=None,
-                 tracer=None):
+                 tracer=None, remat=None):
         self.param = solver_param
         self.log = log_fn or (lambda *a: None)
         # structured observability hooks: a JSONL MetricsLogger (or
@@ -168,6 +168,14 @@ class Solver:
                             raise
                         self.log("No TEST-phase net; training without a "
                                  "test net")
+            if remat is not None:
+                # the policy `set_remat` takes, said where the solver is
+                # built (no jit exists yet, so nothing to rebuild)
+                from ..graph.compiler import REMAT_POLICIES
+                if remat not in REMAT_POLICIES:
+                    raise ValueError(f"remat policy {remat!r}: want one "
+                                     f"of {REMAT_POLICIES}")
+                self.net.remat = remat
             seed = int(solver_param.random_seed)
             self.rng = jax.random.PRNGKey(seed if seed >= 0 else
                                           int(time.time_ns() % (2 ** 31)))
@@ -182,6 +190,11 @@ class Solver:
                 mults[lname] = [
                     (self.net.param_meta[k][2], self.net.param_meta[k][3])
                     for k in owned]
+        # layers that keep statistics in their state for the tracer
+        # (ops/moe.py): read where `step` already waits for a loss
+        self._monitors = [(lp.name, impl.monitor)
+                          for lp, impl, _, _ in self.net.layers
+                          if getattr(impl, "monitor", None)]
         self.updater = Updater(solver_param, mults)
         self.history = self.updater.init(self.params)
         self.lr_fn = make_lr_fn(solver_param)
@@ -208,6 +221,17 @@ class Solver:
         # iteration counter kept ON DEVICE: feeding a fresh host scalar
         # every step is a blocking H2D put; a resident counter is free
         self._it_dev = None
+
+    def _record_monitors(self):
+        """Inside a ``solver.fetch`` span, after the loss has come: the
+        statistics that layers keep in their state, one instant a layer
+        (``moe.load``: share of the token-expert pairs on held experts,
+        largest over mean held load). The step has finished by then, so
+        this waits for nothing."""
+        for lname, (event, fields) in self._monitors:
+            vals = jax.device_get(self.state[lname][0])
+            self.tracer.instant(event, layer=lname, **{
+                f: float(v) for f, v in zip(fields, vals)})
 
     def smoothed_loss(self):
         """Mean of the average_loss-window losses (one device fetch), or
@@ -1003,6 +1027,7 @@ class Solver:
                     with self.tracer.hot_span("solver.fetch",
                                               iter=self.iter - 1):
                         v = float(loss)
+                        self._record_monitors()
                     if self.watchdog is not None:
                         self.watchdog.beat(v)
                     if self._maybe_recover(v):
@@ -1015,6 +1040,7 @@ class Solver:
                 with self.tracer.hot_span("solver.fetch",
                                           iter=self.iter - 1):
                     sm = self.smoothed_loss()
+                    self._record_monitors()
                 if self.watchdog is not None:
                     self.watchdog.beat(sm)
                 if self._maybe_recover(sm):

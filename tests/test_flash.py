@@ -92,3 +92,55 @@ def test_flash_fits_blocks_to_indivisible_sequence():
     q, k, v = _rand_qkv(1, 1, 1031, 8)    # prime S > max block
     with pytest.raises(ValueError, match="usable flash block"):
         flash_attention(q, k, v, False)
+
+
+# -- shared key-value heads and head size 256 (the grouped-query form) -------
+
+def _gqa(b, h, hkv, s, d, seed=3):
+    rs = np.random.RandomState(seed)
+    mk = lambda n: jnp.asarray(rs.randn(b, n, s, d) * 0.5,   # noqa: E731
+                               jnp.float32)
+    return mk(h), mk(hkv), mk(hkv)
+
+
+@pytest.mark.parametrize("h,hkv,d", [(4, 2, 64), (8, 1, 32), (4, 2, 256)])
+def test_flash_shared_kv_heads_forward(h, hkv, d):
+    """Query head i reads key-value head i // (H / Hkv) in place."""
+    q, k, v = _gqa(2, h, hkv, 256, d)
+    out = flash_attention(q, k, v, True, None, 128, 128)
+    want = dense_attention(q, jnp.repeat(k, h // hkv, axis=1),
+                           jnp.repeat(v, h // hkv, axis=1), causal=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("h,hkv,d", [(4, 2, 64), (4, 2, 256), (6, 3, 32)])
+def test_flash_shared_kv_heads_backward(h, hkv, d):
+    """A shared head's gradient is the sum over its group, made inside the
+    dK/dV kernel; dk and dv come back with the key-value heads' shape."""
+    q, k, v = _gqa(1, h, hkv, 256, d, seed=4)
+    tgt = jnp.asarray(np.random.RandomState(8).randn(1, h, 256, d),
+                      jnp.float32)
+
+    def loss_flash(q, k, v):
+        return jnp.sum((flash_attention(q, k, v, True, None, 128, 128)
+                        - tgt) ** 2)
+
+    def loss_dense(q, k, v):
+        o = dense_attention(q, jnp.repeat(k, h // hkv, axis=1),
+                            jnp.repeat(v, h // hkv, axis=1), causal=True)
+        return jnp.sum((o - tgt) ** 2)
+
+    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    assert gf[1].shape == k.shape and gf[2].shape == v.shape
+    for a, b, name in zip(gf, gd, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-3, rtol=1e-3,
+                                   err_msg=f"d{name} mismatch")
+
+
+def test_flash_rejects_heads_that_do_not_divide():
+    q, k, v = _gqa(1, 4, 3, 128, 32)
+    with pytest.raises(ValueError, match="query heads"):
+        flash_attention(q, k, v, True, None, 128, 128)

@@ -231,3 +231,102 @@ def test_moe_gradients_match_dense_mask_formulation():
     for a, b in zip(gp, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=1e-4)
+
+
+# -- the no-drop form: top-k over all experts, a share of them held ----------
+
+def _layer(lp, shapes):
+    from sparknet_tpu.graph.registry import get
+    return get(lp.type)(lp, shapes, 0)
+
+
+def _topk_reference(x, params, top_k, first, held, shared):
+    """numpy, float64: every token's top_k experts with renormalised
+    weights, the held ones applied one pair at a time, nothing dropped."""
+    ps = [np.asarray(p, np.float64) for p in params]
+    router, wg, wu, wd = ps[:4]
+    b, s, e = x.shape
+    xt = np.asarray(x, np.float64).reshape(-1, e)
+    logits = xt @ router.T
+    p = np.exp(logits - logits.max(1, keepdims=True))
+    p /= p.sum(1, keepdims=True)
+
+    def silu(a):
+        return a / (1.0 + np.exp(-a))
+    y = np.zeros_like(xt)
+    pairs_here = 0
+    for i in range(len(xt)):
+        top = np.argsort(-p[i], kind="stable")[:top_k]
+        w = p[i, top] / p[i, top].sum()
+        for ex, wt in zip(top, w):
+            if first <= ex < first + held:
+                j = ex - first
+                y[i] += wt * (wd[j] @ (silu(wg[j] @ xt[i])
+                                       * (wu[j] @ xt[i])))
+                pairs_here += 1
+    if shared:
+        sg, su, sd, gate = ps[4:8]
+        opened = 1.0 / (1.0 + np.exp(-(xt @ gate.T)))
+        y += opened * ((silu(xt @ sg.T) * (xt @ su.T)) @ sd.T)
+    return y.reshape(b, s, e), pairs_here
+
+
+@pytest.mark.parametrize("top_k,held,first,shared,tile", [
+    (2, 8, 0, 0, 4),          # the whole layer, no shared expert
+    (4, 8, 0, 16, 128),       # one ragged tile an expert
+    (4, 3, 2, 16, 4),         # a share of three experts from the third on
+    (8, 8, 0, 16, 8),         # every expert chosen by every token
+])
+def test_moe_topk_no_drop_matches_pairwise_reference(top_k, held, first,
+                                                     shared, tile):
+    lp = dsl.MoELayer("moe", ["x"], 8, hidden_dim=12, top_k=top_k,
+                      experts_held=held, first_expert=first,
+                      shared_hidden_dim=shared, tile_rows=tile)
+    layer = _layer(lp, [(2, 10, 6)])
+    params = _params(layer, seed=3)
+    assert [p.shape for p in params[:4]] == [(8, 6), (held, 12, 6),
+                                             (held, 12, 6), (held, 6, 12)]
+    x = jnp.asarray(np.random.RandomState(4).randn(2, 10, 6), jnp.float32)
+    want, pairs_here = _topk_reference(x, params, top_k, first, held,
+                                       bool(shared))
+    assert pairs_here > 0
+    np.testing.assert_allclose(
+        np.asarray(layer.apply(params, [x], True, None)[0]), want,
+        atol=2e-5, rtol=2e-5)
+
+
+def test_moe_no_drop_under_total_imbalance():
+    """Every token's first choice is expert 0: the Switch form with its
+    capacity cut would drop most of them, the no-drop form keeps all."""
+    lp = dsl.MoELayer("moe", ["x"], 8, hidden_dim=12, top_k=2,
+                      tile_rows=4)
+    layer = _layer(lp, [(2, 10, 6)])
+    params = _params(layer, seed=5)
+    x = jnp.asarray(np.abs(np.random.RandomState(6).randn(2, 10, 6)) + 0.5,
+                    jnp.float32)
+    params[0] = params[0].at[0].set(jnp.full((6,), 5.0))
+    idx, _ = layer.route(x.reshape(20, 6), params[0])
+    assert int(jnp.sum(idx[:, 0] == 0)) == 20
+    want, _ = _topk_reference(x, params, 2, 0, 8, False)
+    np.testing.assert_allclose(
+        np.asarray(layer.apply(params, [x], True, None)[0]), want,
+        atol=2e-5, rtol=2e-5)
+
+
+def test_moe_forms_do_not_mix():
+    with pytest.raises(ValueError, match="no-drop form"):
+        lp = dsl.MoELayer("moe", ["x"], 8, hidden_dim=12)
+        lp.moe_param.top_k = 2
+        _layer(lp, [(2, 10, 6)])
+    with pytest.raises(ValueError, match="experts 4..12"):
+        _layer(dsl.MoELayer("moe", ["x"], 8, hidden_dim=12, top_k=2,
+                                experts_held=8, first_expert=4),
+                   [(2, 10, 6)])
+
+
+def test_moe_top1_with_capacity_is_the_switch_form():
+    """`top_k = 1` and a capacity factor stay the Switch layer: five blobs
+    with biases, tokens over capacity dropped."""
+    lp = dsl.MoELayer("moe", ["x"], 4, hidden_dim=8, capacity_factor=0.5)
+    layer = _layer(lp, [(1, 16, 6)])
+    assert not layer.gated and len(layer.param_shapes()) == 5

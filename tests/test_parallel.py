@@ -76,6 +76,43 @@ class TestDataParallel:
                 np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                            atol=2e-4)
 
+    def test_dropout_is_the_global_batch_draw(self):
+        """A shard keeps its rows of the global batch's dropout draw, so a
+        net WITH dropout trains on the mesh as on one device, step for
+        step (the four-chip benchmark cell's check leans on this); and a
+        batch that already lies on the mesh is not fetched to the host."""
+        def net(batch):
+            return dsl.NetParam(
+                "drop", dsl.RDDLayer("data", [batch, 12]),
+                dsl.RDDLayer("label", [batch]),
+                dsl.InnerProductLayer("fc1", ["data"], 32,
+                                      weight_filler=dict(type="xavier")),
+                dsl.ReLULayer("relu", ["fc1"], tops=["fc1"]),
+                dsl.DropoutLayer("drop", ["fc1"], tops=["fc1"], ratio=0.5),
+                dsl.InnerProductLayer("fc2", ["fc1"], 5,
+                                      weight_filler=dict(type="xavier")),
+                dsl.SoftmaxWithLoss("loss", ["fc2", "label"]))
+        sp = small_solver_param()
+        rs = np.random.RandomState(0)
+        data = rs.randn(3, 16, 12).astype(np.float32)
+        labels = rs.randint(0, 5, (3, 16))
+        ref = Solver(sp, net_param=net(16))
+        dp = DataParallelSolver(sp, net_param=net(16))
+        dp.params = jax.tree_util.tree_map(jnp.array, ref.params)
+        from sparknet_tpu.parallel.data_parallel import shard_batch
+        for i in range(3):
+            batch = {"data": data[i], "label": labels[i]}
+            placed = shard_batch(batch, dp.mesh, dp.axis)
+            l0, l1 = ref.train_step(batch), dp.train_step(placed)
+            np.testing.assert_allclose(float(l0), float(l1), rtol=2e-5)
+        for lname in ref.params:
+            for a, b in zip(ref.params[lname], dp.params[lname]):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           atol=2e-5)
+        # the solver's own state starts replicated over its mesh
+        fresh = DataParallelSolver(sp, net_param=net(16))
+        assert len(fresh.params["fc1"][0].sharding.device_set) == 8
+
     def test_loss_decreases(self):
         net = lenet_net(32)
         dp = DataParallelSolver(small_solver_param(base_lr=0.005),

@@ -83,6 +83,24 @@ def test_flash_backward_compiles(one_chip, head_dim):
         kernels=["flash_dq", "flash_dkv"])
 
 
+# the grouped-query pass of the hybrid LM: 16 query heads over 2 key-value
+# heads of 256 at S=8192, read in place; the dK/dV kernel sums a group
+def test_flash_gqa_head256_compiles(one_chip):
+    q = ((2, 16, 8192, 256), jnp.bfloat16)
+    kv = ((2, 2, 8192, 256), jnp.bfloat16)
+    scale = 256 ** -0.5
+    _compile(lambda q, k, v: pa._flash_forward(
+        q, k, v, True, scale, 512, 512, False), one_chip, q, kv, kv,
+        kernels=["flash_fwd"])
+    lse = jax.eval_shape(
+        lambda q, k: pa._flash_forward(q, k, k, True, scale, 512, 512, True),
+        jax.ShapeDtypeStruct(*q), jax.ShapeDtypeStruct(*kv))[1]
+    _compile(lambda q, k, v, o, lse, g: pa._flash_backward(
+        q, k, v, o, lse, g, True, scale, 512, 512, False),
+        one_chip, q, kv, kv, q, (lse.shape, lse.dtype), q,
+        kernels=["flash_dq", "flash_dkv"])
+
+
 # LRN where CaffeNet runs it (after each pool) and at GoogLeNet's conv2
 # site, the one 3-op conv+relu+lrn site SPARKNET_EPILOGUE=auto fuses
 CAFFENET_NORM1 = (256, 96, 27, 27)
