@@ -25,16 +25,38 @@ at its start, the chunk's u solve the unit lower-triangular system
 so with T = (I + A)^-1:  U = T (beta V) - T (beta exp(G) K) S0,
     O = (exp(G) Q) S0 + (M * (Q K^T)) U,   M_ij = exp(G_i - G_j), j <= i,
     S_end = exp(G_end) S0 + (exp(G_end - G) K)^T U.
-T, T (beta V), T (beta exp(G) K) and M * (Q K^T) are made for a group of
-16 chunks at once; a `lax.scan` over the group's chunks carries S, and a
-`lax.scan` over the groups carries it on. A is strictly lower triangular,
-so its powers vanish at `chunk` and the inverse is the exact product
-(I - A)(I + A^2)(I + A^4)...: log2(chunk) squarings, all matrix products.
-State, decays and the triangular system are float32 at the highest matmul
-precision; the group's body is checkpointed, so the backward pass stores
-one state per GROUP, recomputes a group at a time and never holds a state
-per token, nor the chunk matrices of more than one group (at 16,384
-tokens all groups at once took 11 GB).
+Two forms compute this, chosen where the layer is traced from what it can
+see there (no switch, no argument):
+
+- the pallas kernels of ops/pallas_deltanet.py (`gdn_chunk_fwd`,
+  `gdn_chunk_bwd`) when Dk and Dv are multiples of the lane width 128 and
+  `chunk` is 64: a chunk's triangular system, its products and the state
+  stay in VMEM, the state carried across a sequential grid axis. The
+  backward STORES each chunk's T and the state at the start of every 8th
+  chunk (134 + 67 MB a layer at 2 x 8,192 tokens) and runs 8 chunks
+  forward again, in VMEM, before it walks them back; q and k go in as the
+  conv leaves them and are normalised inside. The CPU backend runs the
+  kernels in interpret mode (the tests).
+  The module is imported in the branch of `apply` that calls it: a
+  process that traces no such layer never imports pallas
+  (tests/test_pallas_deltanet.py holds that).
+- the XLA form below for every other shape (the tests' toy heads), which
+  is also the oracle between the kernels and the token-by-token
+  reference: T, T (beta V), T (beta exp(G) K) and M * (Q K^T) are made for
+  a group of 16 chunks at once; a `lax.scan` over the group's chunks
+  carries S, and a `lax.scan` over the groups carries it on. The group's
+  body is checkpointed, so the backward pass stores one state per GROUP,
+  recomputes a group at a time and never holds a state per token, nor the
+  chunk matrices of more than one group (at 16,384 tokens all groups at
+  once took 11 GB).
+
+Which one a layer took is in the ring of obs/trace.py: one `gdn.path`
+record a trace of the layer, `path` = `kernel` or `xla` with the `reason`.
+A is strictly lower triangular, so its powers vanish at `chunk` and the
+inverse is the exact product (I - A)(I + A^2)(I + A^4)...: log2(chunk)
+squarings, all matrix products (the kernels use it on the diagonal blocks
+of 16 and join the blocks exactly). State, decays and the triangular
+system are float32 at the highest matmul precision in both forms.
 """
 
 import jax
@@ -43,6 +65,7 @@ from jax import lax
 
 from ..proto import Message
 from ..graph.registry import Layer, register
+from ..obs.trace import default_tracer
 from .convolution import _param_mults
 from .normalization import rms_norm
 
@@ -152,6 +175,15 @@ def gated_delta_rule(q, k, v, beta, g, chunk=64, group=16, prepare=None):
     return jnp.moveaxis(o, 0, 1).reshape(b, groups * length, h, dv)[:, :t]
 
 
+L2_EPS = 1e-6
+
+
+def l2_normalize(u):
+    """u / |u| over the last axis, in float32."""
+    u = u.astype(jnp.float32)
+    return u * lax.rsqrt(jnp.sum(u * u, -1, keepdims=True) + L2_EPS)
+
+
 def causal_depthwise_conv(x, w):
     """y_t = sum_j w[c, j] x_{t-K+1+j}: x (B, T, C), w (C, K)."""
     k = w.shape[1]
@@ -179,6 +211,16 @@ class GatedDeltaNet(Layer):
         if self.chunk & (self.chunk - 1):
             raise ValueError(f"{lp.name}: chunk {self.chunk} is not a "
                              "power of two")
+
+    def _why_xla(self):
+        """Why this layer's shapes keep the XLA form, or None when the
+        kernels take them."""
+        if self.dk % 128 or self.dv % 128:
+            return (f"head sizes {self.dk} and {self.dv} are not multiples "
+                    "of the lane width 128")
+        if self.chunk != 64:
+            return f"chunk {self.chunk} is not 64"
+        return None
 
     def param_shapes(self):
         mults = _param_mults(self.lp, 7)
@@ -220,16 +262,24 @@ class GatedDeltaNet(Layer):
             g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
                 ba[..., hv:] + dt_bias.astype(jnp.float32))
 
-            def l2(u):
-                u = u.astype(jnp.float32)
-                return u * lax.rsqrt(jnp.sum(u * u, -1, keepdims=True)
-                                     + 1e-6)
-
-            def prepare(q, k):      # float32, a group of chunks at a time
-                return (jnp.repeat(l2(q), hv // hk, axis=2) * dk ** -0.5,
-                        jnp.repeat(l2(k), hv // hk, axis=2))
-            o = gated_delta_rule(q, k, v, beta, g, self.chunk,
-                                 prepare=prepare)
+            why_xla = self._why_xla()
+            tracer = default_tracer()
+            now = tracer.now_ns()
+            tracer.record("gdn.path", now, now, layer=self.lp.name,
+                          path="xla" if why_xla else "kernel",
+                          reason=why_xla or "head sizes and chunk fit")
+            if why_xla:
+                def prepare(q, k):  # float32, a group of chunks at a time
+                    return (jnp.repeat(l2_normalize(q), hv // hk, axis=2)
+                            * dk ** -0.5,
+                            jnp.repeat(l2_normalize(k), hv // hk, axis=2))
+                o = gated_delta_rule(q, k, v, beta, g, self.chunk,
+                                     prepare=prepare)
+            else:
+                # here and not at the top: a process without such a layer
+                # never imports pallas (1.4 s of every cell's set-up, PR 29)
+                from .pallas_deltanet import chunk_rule
+                o, _ = chunk_rule(q, k, v, beta, g, chunk=self.chunk)
         with jax.named_scope("gdn_gate_norm"):
             o = rms_norm(o, norm, self.eps, zero_centered=False)
             o = o * jax.nn.silu(z.reshape(b, t, hv, dv).astype(jnp.float32))

@@ -22,6 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from sparknet_tpu.ops import pallas_attention as pa
+from sparknet_tpu.ops import pallas_deltanet as pd
 from sparknet_tpu.ops import pallas_epilogue as pe
 from sparknet_tpu.ops import pallas_lrn as plrn
 
@@ -99,6 +100,64 @@ def test_flash_gqa_head256_compiles(one_chip):
         q, k, v, o, lse, g, True, scale, 512, 512, False),
         one_chip, q, kv, kv, q, (lse.shape, lse.dtype), q,
         kernels=["flash_dq", "flash_dkv"])
+
+
+# the gated delta rule's kernel pair at the hybrid LM's shape: 2 x 8,192
+# tokens, 32 value heads over 16 key heads of 128, chunks of 64, q, k and
+# v bfloat16 as the conv leaves them
+GDN_B, GDN_T, GDN_HK, GDN_HV, GDN_D, GDN_CHUNK = 2, 8192, 16, 32, 128, 64
+
+
+@pytest.mark.parametrize("which", ["forward", "forward_for_backward",
+                                   "backward"])
+def test_gdn_chunk_kernels_compile(one_chip, which):
+    n, r = GDN_T // GDN_CHUNK, GDN_HV // GDN_HK
+    qk = ((GDN_B, GDN_T, GDN_HK * GDN_D), jnp.bfloat16)
+    v = ((GDN_B, GDN_T, GDN_HV * GDN_D), jnp.bfloat16)
+    rows = ((GDN_B, GDN_HK, n, r * GDN_CHUNK), jnp.float32)
+    state = ((GDN_B, GDN_HV, GDN_D, GDN_D), jnp.float32)
+    if which != "backward":     # differentiated (with residuals), or not
+        _compile(lambda *a: pd._forward(
+            *a, GDN_CHUNK, pd.GROUP, False,
+            which == "forward_for_backward"), one_chip,
+            qk, qk, v, rows, rows, state, kernels=["gdn_chunk_fwd"])
+        return
+    residuals = jax.eval_shape(
+        lambda *a: pd._forward(*a, GDN_CHUNK, pd.GROUP, False)[2:],
+        *[jax.ShapeDtypeStruct(*a) for a in (qk, qk, v, rows, rows, state)])
+    do = (v[0], jnp.float32)
+    _compile(lambda *a: pd._backward(*a, GDN_CHUNK, pd.GROUP, False),
+             one_chip, qk, qk, v, rows, rows,
+             *[(x.shape, x.dtype) for x in residuals], do, state,
+             kernels=["gdn_chunk_bwd"])
+
+
+def test_gated_delta_net_backward_holds_less_than_the_scans_did(
+        one_chip, monkeypatch):
+    """One layer's forward and backward at the cell's shape through the
+    kernel pair: the scans of checkpointed groups needed 3.2 GB of
+    temporaries here (PR 28, this compile), and the cell's memory stands
+    at 15.96 of 16 GB: the kernels' stored states must not need more."""
+    from sparknet_tpu.graph.registry import get as get_layer
+    from sparknet_tpu.models import dsl
+    # the described chip is not the backend: the kernels, not interpret
+    monkeypatch.setattr(pd, "_should_interpret", lambda: False)
+    embed = 2048
+    lp = dsl.GatedDeltaNetLayer("mixer", ["x"], GDN_HK, GDN_HV, GDN_D,
+                                GDN_D, conv_kernel=4)
+    x = ((GDN_B, GDN_T, embed), jnp.bfloat16)
+    impl = get_layer(lp.type)(lp, [x[0]], 0)
+    blobs = [(s[0], jnp.float32) for s in impl.param_shapes()]
+
+    def grads(x, cot, *blobs):
+        def loss(x, blobs):
+            y = impl.apply(list(blobs), [x], True, None)[0]
+            return jnp.sum(y.astype(jnp.float32) * cot)
+        return jax.grad(loss, (0, 1))(x, blobs)
+
+    compiled = _compile(grads, one_chip, x, (x[0], jnp.float32), *blobs,
+                        kernels=["gdn_chunk_fwd", "gdn_chunk_bwd"])
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
 
 
 # LRN where CaffeNet runs it (after each pool) and at GoogLeNet's conv2
