@@ -30,6 +30,10 @@ The last line of stdout is the result, and only a run on a TPU prints one:
 Without a TPU the script exits 1 before any phase. `--toy` shrinks every
 size so the control flow can be rehearsed on the CPU; a toy run never
 prints "ok": true and never exits 0.
+
+The rows it prints (steps a second, round times) are a bring-up check
+through the CLI and no record of speed: `benchmark/` is the record
+(`python benchmark/run.py --workload <cell>`, PERF.md).
 """
 
 import argparse

@@ -77,14 +77,6 @@ echo "simfleet smoke OK"
 bash scripts/smoke.sh trace || exit 1
 echo "trace smoke OK"
 
-# perf-regression gate, plumbing only: the made-up fixture rows gate
-# against themselves (pure JSON compare, no accelerator). A chip run's
-# rows are gated by `python bench.py --check --details <new rows>
-# --check-baseline <rows kept from an earlier chip run>`
-python bench.py --check --details tests/fixtures/bench_check_rows.json \
-    || exit 1
-echo "bench --check OK"
-
 set -o pipefail
 rm -f /tmp/_t1.log
 timeout -k 10 870 env JAX_PLATFORMS=cpu \

@@ -48,7 +48,7 @@ python -m sparknet_tpu lint --strict --cache --jobs "$JOBS" \
 #    intentional positive and is excluded everywhere.)
 python -m sparknet_tpu lint --strict --cache --jobs "$JOBS" \
     --select SPK105 --exclude fixtures \
-    --root . sparknet_tpu tests scripts experiments bench.py
+    --root . sparknet_tpu tests scripts experiments
 
 # 4. relaxed per-tree profiles (the shared baseline stays empty)
 python -m sparknet_tpu lint --strict --cache --jobs "$JOBS" \
@@ -58,4 +58,4 @@ python -m sparknet_tpu lint --strict --cache --jobs "$JOBS" \
 python -m sparknet_tpu lint --strict --cache --jobs "$JOBS" \
     --select @tools \
     --baseline .sparknet-lint-baseline.json \
-    --root . scripts experiments bench.py
+    --root . scripts experiments

@@ -15,7 +15,7 @@ sides:
   SPK401 (error)  a consumer filters on an event/kind string nobody
                   emits (checked against the live registry ∪ the
                   committed schema — the schema covers emitters
-                  outside the lint target, e.g. repo-root bench.py)
+                  outside the lint target, e.g. a user's app)
   SPK402 (error)  an emit site drifts from the committed schema: the
                   event is unregistered, or it passes fields the
                   schema doesn't list — regenerate the schema
@@ -184,7 +184,7 @@ def unknown_event_consumer(module, ctx):
     produces — the filter matches nothing, the report/pane shows zeros,
     and nobody notices. Known names = the live emit registry of this
     lint run ∪ the committed event schema (which covers emitters
-    outside the lint target, like repo-root bench.py)."""
+    outside the lint target, like a user's app)."""
     proj = ctx.project
     schema = load_schema()
     known_events = set(proj.events)

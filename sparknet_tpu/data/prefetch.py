@@ -144,8 +144,17 @@ class PrefetchIterator:
         finally:
             with self._live_lock:
                 self._live -= 1
-                if self._live == 0:
-                    self._q.put(_END)
+                last = self._live == 0
+            # a put that was already waiting when close() drained lands
+            # after the drain and fills the queue: once the consumer is
+            # done nobody makes room, so the end marker gives up then
+            while last:
+                try:
+                    self._q.put(_END, timeout=0.1)
+                    break
+                except queue.Full:
+                    if self._done:
+                        break
 
     def __iter__(self):
         return self

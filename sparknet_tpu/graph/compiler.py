@@ -296,6 +296,9 @@ class CompiledNet:
         # trace time
         self.remat = None
         self.scan = None
+        # virtual channel-concats (graph/fission.py); False compiles the
+        # literal graph, which tests/test_fission.py compares against
+        self.fission = True
         self._scan_cache = None
         self._epilogue_cache = None
 
@@ -433,7 +436,7 @@ class CompiledNet:
         return {ci: v for ci, v in plan.items() if v[1] is not None}
 
     def _apply_range(self, params, state, new_state, blobs, lo, hi, batch,
-                     train, rng, fiss, ep=None):
+                     train, rng, ep=None):
         """Run layers [lo, hi) over the mutable blob dict (the body the
         remat segments replay). ``ep``: active epilogue-fusion sites;
         a site engages only when its whole conv/ReLU(/LRN) window lies
@@ -452,10 +455,10 @@ class CompiledNet:
             # it as transpose(jvp(<name>))): what a device trace is read by
             with jax.named_scope(lp.name):
                 self._apply_layer(li, params, state, new_state, blobs,
-                                  train, rng, fiss, ep, hi, skip)
+                                  train, rng, ep, hi, skip)
 
     def _apply_layer(self, li, params, state, new_state, blobs, train, rng,
-                     fiss, ep, hi, skip):
+                     ep, hi, skip):
         """One non-feed layer of _apply_range — or its whole fused
         conv/ReLU(/LRN) window, whose other layers join ``skip``."""
         from . import fission
@@ -485,7 +488,7 @@ class CompiledNet:
                 blobs.pop(tops[0], None)
             return
         tvals = fission.try_apply(lp, impl, lparams, bvals,
-                                  train, lrng) if fiss else None
+                                  train, lrng) if self.fission else None
         if tvals is None:
             # normal path; any virtual concat bottom materializes here
             bvals = [fission.materialize(v) for v in bvals]
@@ -701,7 +704,6 @@ class CompiledNet:
         if rng is None:
             rng = jax.random.PRNGKey(0)
         from . import fission
-        fiss = fission.enabled()
         pol = (self.remat if self.remat is not None else _env_remat()) \
             if train else "none"
         if pol not in REMAT_POLICIES:
@@ -734,12 +736,11 @@ class CompiledNet:
                            for j in range(li + 1, end)):
                         self._apply_range(params, state, new_state, blobs,
                                           li, end, batch, train, rng,
-                                          fiss, ep=ep)
+                                          ep=ep)
                         li = end
                         continue
                 self._apply_range(params, state, new_state, blobs,
-                                  li, li + 1, batch, train, rng, fiss,
-                                  ep=ep)
+                                  li, li + 1, batch, train, rng, ep=ep)
                 li += 1
                 continue
             # remat segment [li, hi): close over statics, checkpoint the
@@ -759,7 +760,7 @@ class CompiledNet:
                           for n, v in zip(in_names, in_vals)}
                 sstate = dict(state)
                 self._apply_range(params, state, sstate, sblobs,
-                                  lo, hi, batch, train, rng, fiss, ep=ep)
+                                  lo, hi, batch, train, rng, ep=ep)
                 return ([fission.materialize(sblobs[n])
                          for n in out_names],
                         [sstate[n] for n in seg_states])

@@ -27,18 +27,13 @@ materializations and DCE removes unused ones. Numerics: fission reorders
 the input-channel summation (k partial convs instead of one), so outputs
 match the fused form to accumulation rounding, not bit-exactly.
 
-Enabled by default; set SPARKNET_FISSION=0 to compile the literal graph.
+On for every net (``CompiledNet.fission``); tests/test_fission.py clears
+the attribute on the net it builds to compile the literal graph.
 """
-
-import os
 
 import jax.numpy as jnp
 
 MAX_POOL, AVE_POOL = 0, 1
-
-
-def enabled():
-    return os.environ.get("SPARKNET_FISSION", "1") != "0"
 
 
 class Branches:

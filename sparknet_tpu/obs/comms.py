@@ -3,10 +3,10 @@ broadcast/collect cost model.
 
 Collectives run *inside* compiled XLA programs, so their traffic can't be
 counted at runtime from the host; instead each solver registers its
-per-round collective volume analytically at step-build time (the same
-ring cost model bench.py's multi-chip projection uses: a pmean of B bytes
-over N peers moves 2(N-1)/N * B past every chip). Host->device feed
-traffic IS measurable and is counted directly from the batch arrays.
+per-round collective volume analytically at step-build time (the ring
+cost model: a pmean of B bytes over N peers moves 2(N-1)/N * B past
+every chip). Host->device feed traffic IS measurable and is counted
+directly from the batch arrays.
 
 This is the tau-tradeoff of the SparkNet paper measured directly: a
 LocalSGD round of tau steps does ONE param-sized allreduce (the paper's

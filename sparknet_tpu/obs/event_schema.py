@@ -15,14 +15,6 @@ EVENTS = {
         "fields": ['kind'],
         "open": True,
     },
-    'bench_config': {
-        "fields": ['device', 'iters_per_window', 'peak_bf16_flops', 'platform', 'warmup', 'windows'],
-        "open": False,
-    },
-    'bench_headline': {
-        "fields": [],
-        "open": True,
-    },
     'canary': {
         "fields": ['action', 'base_err_rate', 'base_p99_ms', 'baseline_sha', 'err_rate', 'p99_ms', 'pct', 'reason', 'requests', 'sha'],
         "open": False,

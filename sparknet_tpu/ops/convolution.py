@@ -4,7 +4,10 @@ Replaces Caffe's im2col+GEMM path (reference base_conv_layer.cpp,
 util/im2col.cpp) with ``lax.conv_general_dilated`` — XLA tiles the conv
 directly onto the MXU, so there is no materialized im2col buffer and no
 hand-written GEMM. Grouped convolution (AlexNet conv2/4/5) maps to
-``feature_group_count``.
+``feature_group_count``. A grouped conv runs NHWC and every other conv
+NCHW: the feature-group split tiles along the minor (lane) axis, which
+measured +13% on CaffeNet (earlier rig, PERF.md section 6); the layer sees
+its own ``group``, so nothing selects the layout from outside.
 
 Shape/param semantics match reference conv_layer.cpp / base_conv_layer.cpp:
   out = (in + 2*pad - kernel) / stride + 1      (floor)
@@ -14,39 +17,9 @@ Deconvolution is the conv transpose (reference deconv_layer.cpp):
 with weight blob (C_in, num_output/group, kh, kw).
 """
 
-import os
-
-import numpy as np
 from jax import lax
-import jax.numpy as jnp
 
 from ..graph.registry import Layer, register
-
-
-def _conv_s2d():
-    """Space-to-depth policy for strided shallow-channel stem convs:
-    auto — rewrite when it's the measured win (group==1, square stride>1,
-           few input channels: the CaffeNet/GoogLeNet conv1 shape class),
-    on   — rewrite every eligible conv, off — never.
-
-    A 3-channel 11x11/4 conv1 contracts 3 channels against the MXU's
-    128-lane axis (<3% occupancy, PERF.md). Rewriting
-    conv(x, W, stride b) == conv(s2d_b(x), W', stride 1) trades b*b more
-    input channels (3 -> 48 at b=4) for 1/b the spatial extent per axis:
-    the same FLOPs land on 16x fuller lanes (plus a ceil(k/b) fringe of
-    zero taps). Weights stay in the stock (O, C, kh, kw) blob — the
-    rewrite is a trace-time reshape, so checkpoints are unaffected."""
-    return os.environ.get("SPARKNET_CONV_S2D", "off").lower()
-
-
-def _conv_layout():
-    """Layout policy for Convolution.apply, read per trace:
-    auto  — NHWC only for grouped convs (measured +13% on CaffeNet; the
-            feature-group split tiles along the minor/lane axis),
-    nhwc  — every conv runs NHWC (boundary transposes cancel between
-            adjacent convs under XLA),
-    nchw  — every conv runs NCHW (the reference's native layout)."""
-    return os.environ.get("SPARKNET_CONV_LAYOUT", "auto").lower()
 
 
 def _pair(rep_field, h_field, w_field, lp_param, default):
@@ -113,52 +86,13 @@ class Convolution(Layer):
         ow = (w + 2 * self.pw - self.kw) // self.sw + 1
         return [(n, self.num_output, oh, ow)]
 
-    def _s2d_eligible(self):
-        s2d = _conv_s2d()
-        if s2d == "off" or self.group != 1 or self.sh != self.sw \
-                or self.sh < 2:
-            return False
-        c = self.weight_shape[1]
-        if s2d == "on":
-            return True
-        # auto: stem-conv shape class — shallow input channels where lane
-        # occupancy is the bottleneck and b*b*C still fits one 128-lane tile
-        return c <= 8 and c * self.sh * self.sw <= 128
-
-    def _s2d_conv(self, x, w):
-        """conv(x, w, stride b) as conv(s2d_b(x), w', stride 1), exact."""
-        b = self.sh
-        n, c, h, wd = x.shape
-        o = self.num_output
-        kh2, kw2 = -self.kh % b, -self.kw % b     # pad kernel to mult of b
-        KH, KW = self.kh + kh2, self.kw + kw2
-        oh, ow = self.out_shapes()[0][2:]
-        th, tw = (oh - 1) * b + KH, (ow - 1) * b + KW  # padded extents
-        x = jnp.pad(x, ((0, 0), (0, 0),
-                        (self.ph, max(th - h - self.ph, 0)),
-                        (self.pw, max(tw - wd - self.pw, 0))))
-        x = x[:, :, :th, :tw]
-        x = x.reshape(n, c, th // b, b, tw // b, b) \
-             .transpose(0, 1, 3, 5, 2, 4).reshape(n, c * b * b,
-                                                  th // b, tw // b)
-        w = jnp.pad(w, ((0, 0), (0, 0), (0, kh2), (0, kw2)))
-        w = w.reshape(o, c, KH // b, b, KW // b, b) \
-             .transpose(0, 1, 3, 5, 2, 4).reshape(o, c * b * b,
-                                                  KH // b, KW // b)
-        return lax.conv_general_dilated(
-            x, w, window_strides=(1, 1), padding="VALID",
-            dimension_numbers=("NCHW", "OIHW", "NCHW"))
-
     def apply_raw(self, params, bottoms, train, rng):
         """The convolution WITHOUT its bias add, NCHW out. The fused-
         epilogue path (graph/compiler.py + ops/pallas_epilogue.py) calls
         this and applies bias+ReLU(+LRN) in one pallas pass."""
         x = bottoms[0]
         w = params[0].astype(x.dtype)
-        if self._s2d_eligible():
-            return self._s2d_conv(x, w)
-        layout = _conv_layout()
-        nhwc = self.group > 1 if layout == "auto" else layout == "nhwc"
+        nhwc = self.group > 1
         if nhwc:
             x, w = x.transpose(0, 2, 3, 1), w.transpose(2, 3, 1, 0)
         y = lax.conv_general_dilated(
@@ -182,27 +116,20 @@ class Convolution(Layer):
     def apply_fissioned(self, params, branches, train, rng):
         """conv over a virtual concat (graph/fission.py): one partial conv
         per branch with the matching input-channel slice of the SAME
-        weight blob, summed; bias added once. group==1 only (the same
-        layout policy as apply — under "auto" that means NCHW here)."""
+        weight blob, summed; bias added once. group==1 only, so NCHW."""
         w = params[0]
-        nhwc = _conv_layout() == "nhwc"
         y = None
         off = 0
         for x in branches.parts:
             c = x.shape[1]
             wi = w[:, off:off + c].astype(x.dtype)
             off += c
-            if nhwc:
-                x, wi = x.transpose(0, 2, 3, 1), wi.transpose(2, 3, 1, 0)
             yi = lax.conv_general_dilated(
                 x, wi,
                 window_strides=(self.sh, self.sw),
                 padding=[(self.ph, self.ph), (self.pw, self.pw)],
-                dimension_numbers=("NHWC", "HWIO", "NHWC") if nhwc
-                else ("NCHW", "OIHW", "NCHW"))
+                dimension_numbers=("NCHW", "OIHW", "NCHW"))
             y = yi if y is None else y + yi
-        if nhwc:
-            y = y.transpose(0, 3, 1, 2)
         if self.bias_term:
             y = y + params[1].astype(y.dtype)[None, :, None, None]
         return y

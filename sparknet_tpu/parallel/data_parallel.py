@@ -395,13 +395,13 @@ class DataParallelSolver(Solver):
 
     def _register_comms(self, cm):
         """Per-step DP sync: the grads+state pmean over the data axis —
-        the P2PSync replacement, costed with the same ring model as
-        bench.py's projection. With bucketed overlap on (the default,
-        parallel/overlap.py) the gradient volume is registered per
-        bucket in issue order; every bucket but the last-issued one
-        (the stem/embedding grads backward finishes last) can hide
-        under the backward tail, so the meter marks them overlappable
-        and `sparknet report` decomposes overlapped vs exposed bytes."""
+        the P2PSync replacement, costed with obs/comms.py's ring model.
+        With bucketed overlap on (the default, parallel/overlap.py) the
+        gradient volume is registered per bucket in issue order; every
+        bucket but the last-issued one (the stem/embedding grads backward
+        finishes last) can hide under the backward tail, so the meter
+        marks them overlappable and `sparknet report` decomposes
+        overlapped vs exposed bytes."""
         from ..obs.comms import (tree_bytes, ring_allreduce_bytes,
                                  broadcast_collect_bytes)
         from .overlap import bucket_sizes, overlap_enabled, plan_buckets

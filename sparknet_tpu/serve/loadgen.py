@@ -7,7 +7,7 @@ loop — requests arrive on a fixed-rate clock REGARDLESS of completions
 (the honest way to measure latency under load: a closed loop slows its
 own arrival rate when the server stalls, hiding the tail — the
 coordinated-omission trap). Both emit `bench` rows through the metrics
-stream, so serve latency lands in the same stream bench.py writes.
+stream.
 """
 
 import json

@@ -1,5 +1,6 @@
 """Where JAX's persistent compilation cache lives — one rule for every
-entry point (cli.main, bench.py, chip_smoke.py's children, experiments/).
+entry point (cli.main, benchmark/run.py, chip_smoke.py's children,
+experiments/).
 
 The directory is part of the cache key, so it must be the same from run
 to run: where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself
@@ -42,7 +43,7 @@ def configure_compile_cache():
     # A pallas kernel rides in its custom call as serialized MLIR WITH
     # debug locations, and by default a location holds the Python call
     # stack: the same step lowered from another entry point (the lm verb,
-    # bench.py, chip_smoke.py's HLO child) got another cache key, and the
+    # chip_smoke.py's HLO child) got another cache key, and the
     # d1024 LM step never hit (PR 22, chip runs 1-2). The innermost frame
     # is location enough.
     jax.config.update("jax_traceback_in_locations_limit", LOCATION_FRAMES)

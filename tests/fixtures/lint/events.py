@@ -17,7 +17,7 @@ def emit_unregistered(metrics):
 
 
 def emit_drifted(metrics):
-    metrics.log("bench_config", bogus_field=1)          # SPK402 field drift
+    metrics.log("ghost_reaped", bogus_field=1)          # SPK402 field drift
 
 
 def consume(e):

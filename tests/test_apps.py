@@ -16,9 +16,9 @@ from sparknet_tpu.models.proto_loader import (
 from sparknet_tpu.utils.signals import SignalPolicy
 from sparknet_tpu import cli
 
-from conftest import reference_path
+from conftest import REFERENCE
 
-CIFAR_PROTO_DIR = reference_path("caffe", "examples", "cifar10")
+CIFAR_PROTO_DIR = os.path.join(REFERENCE, "caffe", "examples", "cifar10")
 
 
 class TestTransforms:
@@ -261,7 +261,7 @@ class TestAppIntegration:
 # stock mnist solver family: solver-type x lr-policy parity proven against
 # stock FILES (Adam / RMSProp / SGD+multistep / AdaDelta / AdaGrad /
 # Nesterov), not just the analytic unit tests in test_solver.py
-_MNIST = reference_path("caffe", "examples", "mnist")
+_MNIST = os.path.join(REFERENCE, "caffe", "examples", "mnist")
 _LENET_SHAPES = ["--input-shape", "data=64,1,28,28",
                  "--input-shape", "label=64"]
 _AE_SHAPES = ["--input-shape", "data=100,1,28,28"]

@@ -1,10 +1,13 @@
 """What the chip bring-up added around the entry points: the one compile
 cache rule, a smoke parent that never touches jax, no result without a
-TPU, and a benchmark that refuses a device it has no peak for."""
+TPU, a benchmark that refuses a device it has no peak for, and the list
+of variables that ops/ and graph/ may still read."""
 
 import ast
+import glob
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -91,6 +94,32 @@ def test_only_the_helper_names_a_cache_dir():
     assert hits == ["sparknet_tpu/utils/compile_cache.py"]
 
 
+# ------------------------------------------- no lever in the environment --
+# what ops/ and graph/ still read from the process's environment: a
+# lowering is a function of the layer's arguments and of what it can
+# observe. The list may only shrink (ROADMAP D1, D12): delete a name that
+# has left the tree, never add one. The last is a search path, no lever.
+_ENV_READS = {"SPARKNET_EPILOGUE", "SPARKNET_LRN", "SPARKNET_REMAT",
+              "SPARKNET_SCAN", "SPARKNET_PRECISION",
+              "SPARKNET_PYTHON_LAYER_PATH"}
+
+
+def test_ops_and_graph_read_only_the_listed_variables():
+    found = set()
+    for sub in ("ops", "graph"):
+        for path in glob.glob(os.path.join(REPO, "sparknet_tpu", sub, "**",
+                                           "*.py"), recursive=True):
+            with open(path) as fh:
+                for n, line in enumerate(fh, 1):
+                    if "environ" not in line and "getenv" not in line:
+                        continue
+                    named = re.findall(r"[\"']([A-Z][A-Z0-9_]+)[\"']", line)
+                    # a name built at run time would hide from the list
+                    assert len(named) == 1, f"{path}:{n}: {line.strip()}"
+                    found.add(named[0])
+    assert found == _ENV_READS, found ^ _ENV_READS
+
+
 # ---------------------------------------------------------- chip_smoke --
 def test_smoke_parent_module_imports_only_stdlib():
     with open(os.path.join(REPO, "chip_smoke.py")) as f:
@@ -147,28 +176,38 @@ def test_smoke_on_cpu_exits_nonzero_without_a_result():
     assert "no TPU" in res.stderr
 
 
-# --------------------------------------------------------------- bench --
-_BENCH_UNKNOWN_KIND = """
-import sys, types
-import bench
-bench.bench_device = lambda: types.SimpleNamespace(
-    platform="tpu", device_kind="TPU v99 imaginary")
-sys.argv = ["bench.py", "--metrics", ""]
-sys.exit(bench.main())
+# ----------------------------------------------------------- benchmark --
+# the two refusals of the measurement path, asked of benchmark/ (which
+# the tests only run): no TPU, no result; no published peak, no default
+_UNKNOWN_KIND = """
+import json, os, sys, types
+sys.path.insert(0, os.path.join(os.getcwd(), "benchmark"))
+import jax
+import flops, harness
+with open(os.path.join("benchmark", "peaks.json")) as f:
+    peaks = json.load(f)
+assert flops.peak_for("TPU v99 imaginary", peaks) is None
+assert flops.peak_for("TPU v5 lite", peaks)["bf16_flops"] > 0
+jax.devices = lambda *a: [types.SimpleNamespace(
+    platform="tpu", device_kind="TPU v99 imaginary")]
+harness.find_device(1)
+print("a device came back")
 """
 
 
-def test_bench_refuses_a_device_kind_without_a_peak():
-    res = _py(_BENCH_UNKNOWN_KIND)
+def test_benchmark_refuses_a_device_kind_without_a_peak():
+    res = _py(_UNKNOWN_KIND)
     assert res.returncode not in (0, None)
-    assert "TPU v99 imaginary" in res.stderr and "_PEAK" in res.stderr
-    assert "#BENCH" not in res.stderr      # no row was measured
+    assert "TPU v99 imaginary" in res.stderr and "peaks.json" in res.stderr
+    assert "no result" in res.stderr and "a device came back" not in res.stdout
 
 
-def test_bench_refuses_to_run_without_a_tpu():
-    res = subprocess.run([sys.executable, "bench.py", "--metrics", ""],
-                         cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-                         capture_output=True, text=True, timeout=300)
+def test_benchmark_refuses_to_run_without_a_tpu():
+    res = subprocess.run(
+        [sys.executable, os.path.join("benchmark", "run.py"),
+         "--workload", "caffenet_b1536_resident"],
+        cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=300)
     assert res.returncode != 0
     assert "no TPU" in res.stderr
-    assert not res.stdout.strip()          # no headline JSON either
+    assert not res.stdout.strip()          # no result line

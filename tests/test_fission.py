@@ -2,7 +2,6 @@
 must be numerically equivalent to the literal graph — same loss, same
 gradients — while never materializing inception concats in the hot path."""
 
-import os
 import re
 
 import numpy as np
@@ -53,23 +52,16 @@ def inception_net(batch=4, stochastic_pool=False):
 
 
 def _loss_and_grads(net_param, on, batch, seed=0):
-    old = os.environ.get("SPARKNET_FISSION")
-    os.environ["SPARKNET_FISSION"] = "1" if on else "0"
-    try:
-        net = CompiledNet(net_param, TRAIN)
-        params, state = net.init(jax.random.PRNGKey(seed))
+    net = CompiledNet(net_param, TRAIN)
+    net.fission = on
+    params, state = net.init(jax.random.PRNGKey(seed))
 
-        def lf(p):
-            loss, _ = net.loss_fn(p, state, batch,
-                                  rng=jax.random.PRNGKey(1))
-            return loss
-        loss, grads = jax.value_and_grad(lf)(params)
-        return float(loss), grads
-    finally:
-        if old is None:
-            os.environ.pop("SPARKNET_FISSION", None)
-        else:
-            os.environ["SPARKNET_FISSION"] = old
+    def lf(p):
+        loss, _ = net.loss_fn(p, state, batch,
+                              rng=jax.random.PRNGKey(1))
+        return loss
+    loss, grads = jax.value_and_grad(lf)(params)
+    return float(loss), grads
 
 
 @pytest.fixture(scope="module")
@@ -95,18 +87,14 @@ def test_fission_matches_literal_graph(batch):
 def test_fission_emits_no_module1_concat(batch):
     """With every module-1 consumer fissionable, the compiled training HLO
     contains no concatenate at the module-1 activation shape."""
-    os.environ["SPARKNET_FISSION"] = "1"
-    try:
-        net = CompiledNet(inception_net(), TRAIN)
-        params, state = net.init(jax.random.PRNGKey(0))
+    net = CompiledNet(inception_net(), TRAIN)
+    params, state = net.init(jax.random.PRNGKey(0))
 
-        def lf(p, batch):
-            loss, _ = net.loss_fn(p, state, batch,
-                                  rng=jax.random.PRNGKey(1))
-            return loss
-        txt = jax.jit(jax.grad(lf)).lower(params, batch).as_text()
-    finally:
-        os.environ.pop("SPARKNET_FISSION", None)
+    def lf(p, batch):
+        loss, _ = net.loss_fn(p, state, batch,
+                              rng=jax.random.PRNGKey(1))
+        return loss
+    txt = jax.jit(jax.grad(lf)).lower(params, batch).as_text()
     # inc1 is (4,26,16,16); its consumers (two convs + MAX pool->conv) all
     # stay virtual, so no concatenate of that shape may appear fwd or bwd
     assert not re.search(r'\[4,26,16,16\][^=]*concatenate', txt), \

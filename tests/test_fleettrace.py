@@ -1,16 +1,13 @@
 """Fleet observability plane (obs/fleettrace.py, obs/critpath.py, the
-`sparknet trace` CLI verb, bench --check): clock-offset estimation from
+`sparknet trace` CLI verb): clock-offset estimation from
 heartbeat trace_align beacons under wall jumps and drifting monotonic
 clocks, merged-timeline determinism, torn/partial stream recovery,
 critical-path straggler attribution against the chaos injectors
-(slow_host / slow_worker) end-to-end through REAL coordinators, the
-simfleet path through the same machinery, and the perf-regression
-gate."""
+(slow_host / slow_worker) end-to-end through REAL coordinators, and the
+simfleet path through the same machinery."""
 
 import json
 import os
-import subprocess
-import sys
 import threading
 import time
 
@@ -24,7 +21,6 @@ from sparknet_tpu.resilience.heartbeat import HeartbeatCoordinator
 from sparknet_tpu.sim import FleetSim
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
 
 
 class _Sink:
@@ -433,68 +429,3 @@ class TestSimfleetAndCli:
                    "arrived": [0], "dead": []})
         txt = st.render("mem:fleet")
         assert "fleet:" in txt and "beacon" in txt
-
-
-# ------------------------------------------------- bench --check --------
-# made-up rows (the file says so itself): they exercise the gate, they
-# were never measured anywhere
-BENCH_ROWS = os.path.join(REPO, "tests", "fixtures",
-                          "bench_check_rows.json")
-
-
-class TestBenchCheck:
-    def _run(self, *extra):
-        return subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py"), "--check",
-             *extra], cwd=REPO, capture_output=True, text=True,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"))
-
-    def test_committed_rows_pass_the_gate(self):
-        res = self._run("--details", BENCH_ROWS)
-        assert res.returncode == 0, res.stderr
-        assert "bench --check: OK" in res.stderr
-
-    def test_seeded_regression_fails_naming_the_row(self, tmp_path):
-        with open(BENCH_ROWS) as f:
-            d = json.load(f)
-        for r in d["rows"]:
-            if r.get("model") == "googlenet":
-                sp = r["images_per_sec_spread"]
-                sp["median"] *= 0.5
-        doctored = tmp_path / "regressed.json"
-        doctored.write_text(json.dumps(d))
-        res = self._run("--details", str(doctored))
-        assert res.returncode == 1
-        assert "REGRESSED" in res.stderr
-        assert "googlenet" in res.stderr
-
-    def test_noise_tolerance_widens_to_the_committed_spread(self,
-                                                            tmp_path):
-        """The host_fed row's baseline windows spread 27% below the
-        median; a 20% dip must still pass (the gate is noise-tolerant),
-        while a 40% dip fails."""
-        with open(BENCH_ROWS) as f:
-            d = json.load(f)
-        for r in d["rows"]:
-            if r.get("mode") == "host_fed":
-                r["images_per_sec_spread"]["median"] *= 0.8
-        ok = tmp_path / "dip20.json"
-        ok.write_text(json.dumps(d))
-        assert self._run("--details", str(ok)).returncode == 0
-        for r in d["rows"]:
-            if r.get("mode") == "host_fed":
-                r["images_per_sec_spread"]["median"] *= 0.5
-        bad = tmp_path / "dip60.json"
-        bad.write_text(json.dumps(d))
-        assert self._run("--details", str(bad)).returncode == 1
-
-    def test_missing_row_fails(self, tmp_path):
-        with open(BENCH_ROWS) as f:
-            d = json.load(f)
-        d["rows"] = [r for r in d["rows"]
-                     if r.get("model") != "googlenet"]
-        doctored = tmp_path / "missing.json"
-        doctored.write_text(json.dumps(d))
-        res = self._run("--details", str(doctored))
-        assert res.returncode == 1
-        assert "MISSING" in res.stderr

@@ -9,6 +9,7 @@ worker exception reaches the consumer at most once, with the original
 traceback, after the items produced before the failure.
 """
 
+import threading
 import traceback
 
 import numpy as np
@@ -81,6 +82,39 @@ class TestEchoIterator:
         st = it.stats()
         assert st["echo"] == 2 and st["k"] == 1
         it.close()
+        for t in src._threads:
+            t.join(timeout=5)
+            assert not t.is_alive()
+
+    def test_close_before_a_waiting_put_lands_leaks_no_worker(self):
+        """The order a loaded machine produces: the worker's put of batch
+        1 is already waiting on the full queue when the consumer takes
+        batch 0 and closes, and it lands after close() has drained. The
+        worker's end marker then finds the queue full with nobody left
+        to make room; it must give up, not wait for ever."""
+        patched, waiting, closed = (threading.Event() for _ in range(3))
+
+        def source():
+            gen = _batches(2)
+            yield next(gen)
+            patched.wait(5)
+            yield next(gen)
+
+        src = PrefetchIterator(source(), depth=1)
+        real_put = src._q.put
+
+        def late_put(item, *a, **kw):
+            if isinstance(item, dict) and item["label"][0] == 1:
+                waiting.set()
+                closed.wait(5)
+            return real_put(item, *a, **kw)
+
+        src._q.put = late_put
+        patched.set()
+        assert next(src)["label"][0] == 0
+        assert waiting.wait(5)
+        src.close()
+        closed.set()
         for t in src._threads:
             t.join(timeout=5)
             assert not t.is_alive()

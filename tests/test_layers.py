@@ -59,21 +59,101 @@ def check_grad(f, x, step=1e-2, tol=2e-2):
                                err_msg="analytic vs numeric gradient")
 
 
+def direct_conv(x, w, b, stride, pad, group=1):
+    """The Caffe formula tap by tap: y[n, o, p, q] = b[o] + sum over
+    (c, i, j) of x[n, c, p*s + i - pad, q*s + j - pad] * w[o, c, i, j],
+    each group over its own channels. No conv primitive, so jax.grad of
+    it is a gradient reference too."""
+    n, c, h, wd = x.shape
+    o, cg, kh, kw = w.shape
+    oh = (h + 2 * pad - kh) // stride + 1
+    ow = (wd + 2 * pad - kw) // stride + 1
+    xp = jnp.pad(x, ((0, 0), (0, 0), (pad, pad), (pad, pad)))
+    xp = xp.reshape(n, group, cg, *xp.shape[2:])
+    wg = w.reshape(group, o // group, cg, kh, kw)
+    y = 0.0
+    for i in range(kh):
+        for j in range(kw):
+            win = xp[:, :, :, i:i + stride * (oh - 1) + 1:stride,
+                     j:j + stride * (ow - 1) + 1:stride]
+            y = y + jnp.einsum("ngchw,goc->ngohw", win, wg[:, :, :, i, j],
+                               precision="highest")
+    return y.reshape(n, o, oh, ow) + b[None, :, None, None]
+
+
+# (in_shape, num_output, kernel, stride, pad, group): the stem convs of the
+# two benchmark CNNs, CaffeNet's grouped conv2, and odd strided corners
+CONV_GEOMETRIES = [
+    pytest.param((2, 3, 5, 5), 4, 3, 1, 1, 1, id="k3s1p1"),
+    pytest.param((2, 3, 227, 227), 8, 11, 4, 0, 1, id="caffenet-conv1"),
+    pytest.param((2, 3, 224, 224), 8, 7, 2, 3, 1, id="googlenet-conv1"),
+    pytest.param((1, 3, 33, 33), 4, 5, 3, 2, 1, id="odd-k5s3p2"),
+    pytest.param((2, 8, 27, 27), 16, 5, 1, 2, 2, id="caffenet-conv2-group2"),
+    pytest.param((1, 4, 16, 16), 4, 4, 4, 0, 1, id="k-divisible-by-s"),
+    pytest.param((1, 2, 15, 17), 3, 3, 2, 1, 1, id="rect-input"),
+]
+
+
+def _conv_case(in_shape, num_output, k, s, p, group):
+    layer, _ = make_layer(
+        "Convolution", [in_shape],
+        convolution_param=dict(num_output=num_output, kernel_size=[k],
+                               stride=[s], pad=[p], group=group))
+    params = init_params(layer)
+    x = jnp.asarray(np.random.RandomState(3).randn(*in_shape), jnp.float32)
+    return layer, params, x
+
+
 class TestConvolution:
-    def test_forward_matches_direct(self):
-        layer, _ = make_layer(
-            "Convolution", [(2, 3, 5, 5)],
-            convolution_param=dict(num_output=4, kernel_size=[3], stride=[1],
-                                   pad=[1]))
-        params = init_params(layer)
-        x = jnp.asarray(RNG.randn(2, 3, 5, 5), jnp.float32)
+    @pytest.mark.parametrize("in_shape,num_output,k,s,p,group",
+                             CONV_GEOMETRIES)
+    def test_forward_matches_direct(self, in_shape, num_output, k, s, p,
+                                    group):
+        layer, params, x = _conv_case(in_shape, num_output, k, s, p, group)
         (y,) = layer.apply(params, [x], False, None)
-        assert y.shape == (2, 4, 5, 5)
-        # direct computation at one output position
-        w, b = np.asarray(params[0]), np.asarray(params[1])
-        xp = np.pad(np.asarray(x), ((0, 0), (0, 0), (1, 1), (1, 1)))
-        want = (xp[1, :, 2:5, 1:4] * w[3]).sum() + b[3]
-        np.testing.assert_allclose(y[1, 3, 2, 1], want, rtol=2e-5)
+        assert y.shape == tuple(layer.out_shapes()[0])
+        want = direct_conv(x, params[0], params[1], s, p, group)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("in_shape,num_output,k,s,p,group",
+                             CONV_GEOMETRIES[1:5])
+    def test_gradients_match_direct(self, in_shape, num_output, k, s, p,
+                                    group):
+        layer, params, x = _conv_case(in_shape, num_output, k, s, p, group)
+
+        def loss(conv):
+            def f(w, b, xv):
+                y = conv(w, b, xv)
+                return (y * jnp.cos(jnp.arange(y.size, dtype=jnp.float32)
+                                    .reshape(y.shape))).sum()
+            return f
+
+        got = jax.grad(loss(lambda w, b, xv: layer.apply(
+            [w, b], [xv], False, None)[0]), argnums=(0, 1, 2))(*params, x)
+        want = jax.grad(loss(lambda w, b, xv: direct_conv(
+            xv, w, b, s, p, group)), argnums=(0, 1, 2))(*params, x)
+        assert got[0].shape == params[0].shape
+        for g, r in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("group,lhs_spec", [(1, (0, 1, 2, 3)),
+                                                (2, (0, 3, 1, 2))])
+    def test_layout_follows_the_group(self, group, lhs_spec):
+        """The layer picks its layout from its own ``group``: a grouped
+        conv lowers NHWC (batch 0, features 3, spatial 1-2), any other
+        NCHW. Read from the jaxpr's dimension numbers."""
+        layer, params, x = _conv_case((1, 4, 8, 8), 6, 3, 1, 1, group)
+        jaxpr = jax.make_jaxpr(
+            lambda w, xv: layer.apply_raw([w], [xv], False, None))(
+                params[0], x)
+        (conv,) = [e for e in jaxpr.jaxpr.eqns
+                   if e.primitive.name == "conv_general_dilated"]
+        dn = conv.params["dimension_numbers"]
+        assert tuple(dn.lhs_spec) == lhs_spec
+        assert tuple(dn.out_spec) == lhs_spec
+        assert conv.params["feature_group_count"] == group
 
     def test_grouped(self):
         layer, _ = make_layer(
