@@ -15,21 +15,39 @@ Two forms, chosen by moe_param.gated_experts:
   num_experts outputs either way, and what the absent experts would add is
   left out — one chip's share of an expert-parallel group, run without
   its exchange. NO TOKEN IS DROPPED, whatever the imbalance: the
-  token-expert pairs are sorted by expert and the held experts' pairs run
-  tile by tile (`tile_rows` rows of one expert at a time, as many tiles as
-  the routing needs: a loop of dynamic length, so neither memory nor work
-  is bound by the worst case of all tokens x top_k rows). With
+  token-expert pairs are sorted by expert (one stable sort), so the held
+  experts' pairs come first and each expert's are one contiguous group,
+  unpadded; they run a WINDOW of rows at a time (`window_rows`, static:
+  the pairs an even routing sends here and a quarter more, in whole
+  tiles of `tile_rows`, at most `WINDOW_TILES` tiles), as many windows
+  as the routing needs: a loop of dynamic length, so memory is bound by
+  one window's buffers and work by the routing, neither by the worst
+  case of all tokens x top_k rows. A window is one gather of its rows of
+  x, a grouped product over its ragged groups (gate, up, SiLU x up, down;
+  backward the same recomputed, then dh, dx and the three weight
+  gradients as per-group lhs^T rhs accumulated in float32) and one
+  scatter-add. The product has two implementations behind `_grouped`,
+  chosen at trace time from what the layer can see (`_why_xla`): on a
+  TPU backend, with embed and hidden widths multiples of 128 and
+  tile_rows of 8, the megablox kernels through ops/pallas_moe.py
+  (`moe_gmm_fwd`, `moe_gmm_bwd`, `moe_gmm_dw` in a device trace, the
+  row tile `tile_rows`, the number of row tiles a traced value);
+  elsewhere XLA's `lax.ragged_dot_general` over the same window. Which
+  one is in the ring of obs/trace.py, one `moe.path` record a trace of
+  the layer (`path` = `kernel` or `xla`, with the `reason`). With
   shared_hidden_dim > 0 a shared expert sees every token:
   shared = sigmoid(w_s . x) W_down (SiLU(W_gate x) * W_up x), and
   y = routed + shared. Tops: [output] or [output, stats]; stats (weight
   0, kept as layer state so that the solver can read it where it already
-  waits for a loss) = [share of the token-expert pairs that land on held
-  experts, largest over mean load of the held experts]. Blobs:
+  waits for a loss, as `moe.load`) = [share of the token-expert pairs
+  that land on held experts, largest over mean load of the held experts,
+  windows the loop ran: more than 1 is skew spilling past a window].
+  Blobs:
     router (num_experts, E) | w_gate (held, F, E) | w_up (held, F, E)
     | w_down (held, E, F) | then with a shared expert: ws_gate (Fs, E)
     | ws_up (Fs, E) | ws_down (E, Fs) | shared gate (1, E)
   Scopes inside the layer's own: moe_route (softmax, top-k, sort),
-  moe_dispatch (gathering a tile's rows), moe_experts (the three
+  moe_dispatch (gathering a window's rows), moe_experts (the grouped
   products), moe_combine (weighting and scattering back), moe_shared.
 
 The top-1 form:
@@ -90,129 +108,171 @@ from jax import lax
 
 from ..proto import Message
 from ..graph.registry import Layer, register
+from ..obs.trace import default_tracer
 from ..parallel import context
 from .convolution import _param_mults
 
 
-# -- the no-drop form: held experts over ragged groups, tile by tile --------
+# -- the no-drop form: held experts over ragged groups, a window at a time ---
 
-def max_tiles(n_tokens, top_k, held, tile):
-    """Static length of the tile table: a token's top_k experts differ, so
-    at most n_tokens x min(top_k, held) pairs land here, and each held
-    expert's group is padded to whole tiles."""
-    return -(-n_tokens * min(top_k, held) // tile) + held
+# the most rows of a window, in tiles (8,192 rows at tile_rows 128)
+WINDOW_TILES = 64
+
+_NT = (((1,), (2,)), ((), ()))      # lhs (m, k) . rhs (g, n, k)
+_NN = (((1,), (1,)), ((), ()))      # lhs (m, k) . rhs (g, k, n)
+_TN = (((0,), (0,)), ((), ()))      # lhs (m, k), rhs (m, n) -> (g, k, n)
 
 
-def plan_tiles(pair_expert, held, tile, n_tiles):
+def window_rows(n_tokens, top_k, held, num_experts, tile):
+    """Static rows of a window, in whole tiles: the pairs that an even
+    routing sends here (n_tokens x top_k x held / num_experts) and a
+    quarter more, so that one window takes them and skew spills into a
+    second; never more than WINDOW_TILES tiles, nor than every pair that
+    can land here (a token's top_k experts differ: at most n_tokens x
+    min(top_k, held)). Gathers, scatter-adds and buffers are paid for
+    every row of a window, filled or not."""
+    even = n_tokens * top_k * held / num_experts
+    most = n_tokens * min(top_k, held)
+    return min(WINDOW_TILES, math.ceil(min(1.25 * even, most) / tile)) * tile
+
+
+def plan_windows(pair_expert, held, window):
     """From each pair's local expert (`held` = not held here), the plan
-    the tile loops follow: `order` (pairs sorted by expert, the held ones
-    first), per held expert its `count` and where its group starts in
-    `order`, per tile its expert, and the number of tiles in use."""
-    order = jnp.argsort(pair_expert, stable=True)
-    bounds = jnp.searchsorted(pair_expert[order], jnp.arange(held + 1))
-    start, count = bounds[:-1], bounds[1:] - bounds[:-1]
-    tiles = -(-count // tile)
-    tile_end = jnp.cumsum(tiles)
-    tile_expert = jnp.minimum(jnp.searchsorted(
-        tile_end, jnp.arange(n_tiles), side="right"), held - 1)
-    return {"order": order.astype(jnp.int32), "count": count,
-            "start": start.astype(jnp.int32),
-            "tile_start": (tile_end - tiles).astype(jnp.int32),
-            "tile_expert": tile_expert.astype(jnp.int32),
-            "used": tile_end[-1].astype(jnp.int32)}
+    the window loops follow: `order` (pairs sorted by expert, the held
+    ones first, so each held expert's pairs are one contiguous group;
+    padded so that every window is a slice of it), `bounds` (group e is
+    order[bounds[e]:bounds[e + 1]]; bounds[held] pairs land here), per held
+    expert its `count`, and the number of `windows` of `window` rows of
+    `order` that hold them all. One sort and one count: no gather."""
+    order = jnp.argsort(pair_expert, stable=True).astype(jnp.int32)
+    bounds = jnp.sum(pair_expert[None, :] < jnp.arange(held + 1)[:, None],
+                     axis=1, dtype=jnp.int32)
+    return {"order": jnp.pad(order, (0, -order.shape[0] % window)),
+            "bounds": bounds, "count": bounds[1:] - bounds[:-1],
+            "windows": -(-bounds[-1] // window)}
 
 
-def _tile_rows(plan, t, tile, top_k):
-    """Tile t's rows: (expert, pair index, token index, valid) — the
-    rows of one held expert's group, `tile` at a time."""
-    e = plan["tile_expert"][t]
-    off = (t - plan["tile_start"][e]) * tile + jnp.arange(tile)
-    valid = off < plan["count"][e]
-    pair = plan["order"][jnp.minimum(plan["start"][e] + off,
-                                     plan["order"].shape[0] - 1)]
-    return e, pair, pair // top_k, valid
+def _window(plan, w, window, top_k):
+    """Window w of the sorted pairs: (pair index, token index, valid, each
+    group's rows inside the window). Rows past the last held pair are not
+    valid and belong to no group."""
+    lo = w * window
+    pair = lax.dynamic_slice(plan["order"], (lo,), (window,))
+    edges = jnp.clip(plan["bounds"], lo, lo + window)
+    return (pair, pair // top_k,
+            lo + jnp.arange(window) < plan["bounds"][-1],
+            edges[1:] - edges[:-1])
 
 
-def _expert_tile(xt, g, u):
-    a = jnp.dot(xt, g.T, preferred_element_type=jnp.float32)
-    b = jnp.dot(xt, u.T, preferred_element_type=jnp.float32)
-    return a, b
+def _grouped(kernel, tile, sizes):
+    """The grouped product over a window's ragged groups, in one of two
+    implementations: `dot(lhs, rhs, transpose_rhs, name)` -> (m, n) float32
+    and `dot_t(lhs, rhs, total, name)` -> total + per group lhs^T rhs. The
+    kernels leave the rows of no group unwritten, the XLA form zeroes
+    them: the caller masks."""
+    if kernel:
+        # here and not at the top: a process without such a layer never
+        # imports pallas (1.4 s of every cell's set-up, PR 29)
+        from . import pallas_moe
+        return (functools.partial(pallas_moe.grouped_dot, sizes, tile),
+                functools.partial(pallas_moe.grouped_dot_t, sizes, tile))
+
+    def ragged(lhs, rhs, dims, group_dims, name):
+        with jax.named_scope(name):
+            return lax.ragged_dot_general(
+                lhs, rhs, sizes, lax.RaggedDotDimensionNumbers(
+                    dot_dimension_numbers=dims, lhs_ragged_dimensions=[0],
+                    rhs_group_dimensions=group_dims),
+                preferred_element_type=jnp.float32)
+
+    def dot(lhs, rhs, transpose_rhs, name):
+        return ragged(lhs, rhs, _NT if transpose_rhs else _NN, [0], name)
+
+    def dot_t(lhs, rhs, total, name):
+        return total + ragged(lhs, rhs, _TN, [], name)
+    return dot, dot_t
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def held_experts(x, pair_weight, plan, wg, wu, wd, tile, top_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def held_experts(x, pair_weight, plan, wg, wu, wd, tile, top_k, window,
+                 kernel):
     """sum over each token's held experts e of pair_weight x W_down,e
     (SiLU(W_gate,e x) * W_up,e x). x (n, E) and the weights in the
     compute type, pair_weight (n x top_k,) float32 (token-major), `plan`
-    from `plan_tiles`. -> (n, E) float32. A `fori_loop` over the tiles in
-    use; the backward pass is a second such loop that recomputes each
-    tile, so nothing is stored per tile."""
-    return _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k)[0]
+    from `plan_windows(.., window)`, `window` a multiple of `tile`, the row
+    tile of the grouped product; `kernel`: the pallas product, else XLA's.
+    -> (n, E) float32. A `fori_loop` over the windows in use: one gather,
+    the grouped products, one scatter-add a window; the backward pass is a
+    second such loop that recomputes each window, so nothing is stored per
+    window."""
+    return _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window,
+                     kernel)[0]
 
 
-def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k):
-    n, e_dim = x.shape
-
-    def body(t, y):
+# jitted, so that the layers of one shape (and a layer's recomputation)
+# trace and lower the window's body once between them: the kernels'
+# tracing is seconds of a step's first call
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window, kernel):
+    def body(w, y):
         with jax.named_scope("moe_dispatch"):
-            e, pair, tok, valid = _tile_rows(plan, t, tile, top_k)
-            xt = x[tok]
+            pair, tok, valid, sizes = _window(plan, w, window, top_k)
+            xw = x[tok]
         with jax.named_scope("moe_experts"):
-            a, b = _expert_tile(xt, wg[e], wu[e])
+            dot, _ = _grouped(kernel, tile, sizes)
+            a = dot(xw, wg, True, "moe_gmm_fwd")
+            b = dot(xw, wu, True, "moe_gmm_fwd")
             h = (jax.nn.silu(a) * b).astype(x.dtype)
-            yt = jnp.dot(h, wd[e].T, preferred_element_type=jnp.float32)
+            out = dot(h, wd, True, "moe_gmm_fwd")
         with jax.named_scope("moe_combine"):
-            wt = jnp.where(valid, pair_weight[pair], 0.0)
-            return y.at[tok].add(yt * wt[:, None])
+            wt = pair_weight[pair]
+            return y.at[tok].add(
+                jnp.where(valid[:, None], out * wt[:, None], 0.0))
 
-    y = lax.fori_loop(0, plan["used"], body,
-                      jnp.zeros((n, e_dim), jnp.float32))
+    y = lax.fori_loop(0, plan["windows"], body,
+                      jnp.zeros(x.shape, jnp.float32))
     return y, (x, pair_weight, plan, wg, wu, wd)
 
 
-def _held_bwd(tile, top_k, res, dy):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _held_bwd(tile, top_k, window, kernel, res, dy):
     x, pair_weight, plan, wg, wu, wd = res
     dy = dy.astype(jnp.float32)
 
-    def body(t, carry):
+    def body(w, carry):
         dx, dpw, dwg, dwu, dwd = carry
         with jax.named_scope("moe_dispatch"):
-            e, pair, tok, valid = _tile_rows(plan, t, tile, top_k)
-            xt, dyt = x[tok], dy[tok]
+            pair, tok, valid, sizes = _window(plan, w, window, top_k)
+            xw, dyr = x[tok], dy[tok]
         with jax.named_scope("moe_experts"):
-            a, b = _expert_tile(xt, wg[e], wu[e])
+            dot, dot_t = _grouped(kernel, tile, sizes)
+            a = dot(xw, wg, True, "moe_gmm_fwd")
+            b = dot(xw, wu, True, "moe_gmm_fwd")
             sa = jax.nn.sigmoid(a)
             silu = a * sa
             h = (silu * b).astype(x.dtype)
             wt = jnp.where(valid, pair_weight[pair], 0.0)
             # d pair_weight = dy . (h W_down^T), row by row
-            out = jnp.dot(h, wd[e].T, preferred_element_type=jnp.float32)
-            dwt = jnp.where(valid, jnp.sum(dyt * out, -1), 0.0)
-            dyw = (dyt * wt[:, None]).astype(x.dtype)
-            dh = jnp.dot(dyw, wd[e], preferred_element_type=jnp.float32)
+            out = dot(h, wd, True, "moe_gmm_fwd")
+            dwt = jnp.where(valid, jnp.sum(dyr * out, -1), 0.0)
+            dyw = (dyr * wt[:, None]).astype(x.dtype)
+            dh = dot(dyw, wd, False, "moe_gmm_bwd")
             da = (dh * b * (sa + silu * (1.0 - sa))).astype(x.dtype)
             db = (dh * silu).astype(x.dtype)
-            dxt = jnp.dot(da, wg[e], preferred_element_type=jnp.float32) \
-                + jnp.dot(db, wu[e], preferred_element_type=jnp.float32)
-
-            def acc(total, part):
-                return lax.dynamic_update_index_in_dim(
-                    total, total[e] + part, e, 0)
-            dwg = acc(dwg, jnp.dot(da.T, xt,
-                                   preferred_element_type=jnp.float32))
-            dwu = acc(dwu, jnp.dot(db.T, xt,
-                                   preferred_element_type=jnp.float32))
-            dwd = acc(dwd, jnp.dot(dyw.T, h,
-                                   preferred_element_type=jnp.float32))
+            dxw = dot(da, wg, False, "moe_gmm_bwd") \
+                + dot(db, wu, False, "moe_gmm_bwd")
+            dwg = dot_t(da, xw, dwg, "moe_gmm_dw")
+            dwu = dot_t(db, xw, dwu, "moe_gmm_dw")
+            dwd = dot_t(dyw, h, dwd, "moe_gmm_dw")
         with jax.named_scope("moe_combine"):
-            dx = dx.at[tok].add(dxt)
-            # a padding row's pair index may repeat a real one: add 0 there
+            dx = dx.at[tok].add(jnp.where(valid[:, None], dxw, 0.0))
+            # a row of no group may repeat a real pair's index: add 0 there
             dpw = dpw.at[pair].add(dwt)
         return dx, dpw, dwg, dwu, dwd
 
     zeros = [jnp.zeros(a.shape, jnp.float32)
              for a in (x, pair_weight, wg, wu, wd)]
-    dx, dpw, dwg, dwu, dwd = lax.fori_loop(0, plan["used"], body,
+    dx, dpw, dwg, dwu, dwd = lax.fori_loop(0, plan["windows"], body,
                                            tuple(zeros))
     return (dx.astype(x.dtype), dpw, None, dwg.astype(wg.dtype),
             dwu.astype(wu.dtype), dwd.astype(wd.dtype))
@@ -259,10 +319,25 @@ class MoE(Layer):
             # them where it already waits for a loss
             self.has_state = len(lp.top) > 1
             if self.has_state:
-                self.monitor = ("moe.load", ("held_share", "max_over_mean"))
+                self.monitor = ("moe.load", ("held_share", "max_over_mean",
+                                             "windows"))
 
     def state_shapes(self):
-        return [((2,), 0.0)] if self.gated and len(self.lp.top) > 1 else []
+        return [((3,), 0.0)] if self.gated and len(self.lp.top) > 1 else []
+
+    def _why_xla(self, dtype):
+        """Why the held experts' grouped product keeps the XLA form here,
+        or None when the kernels of ops/pallas_moe.py take it."""
+        if jax.default_backend() != "tpu":
+            return f"the backend is {jax.default_backend()}, not a TPU"
+        if self.embed % 128 or self.hidden % 128:
+            return (f"widths {self.embed} and {self.hidden} are not "
+                    "multiples of the lane width 128")
+        if self.tile % 8:
+            return f"tile_rows {self.tile} is not a multiple of 8"
+        if dtype not in (jnp.bfloat16, jnp.float32):
+            return f"the compute type is {jnp.dtype(dtype).name}"
+        return None
 
     def _capacity(self, n):
         return max(1, math.ceil(n / self.num_experts * self.capacity_factor))
@@ -305,7 +380,7 @@ class MoE(Layer):
     def out_shapes(self):
         shapes = [tuple(self.bottom_shapes[0])]
         if self.gated:
-            return shapes + [(2,)] * (len(self.lp.top) - 1)
+            return shapes + [(3,)] * (len(self.lp.top) - 1)
         if len(self.lp.top) > 1:
             shapes.append(())                     # aux load-balancing loss
         if len(self.lp.top) > 2:
@@ -414,11 +489,17 @@ class MoE(Layer):
             local = idx.reshape(n * k) - self.first
             pair_expert = jnp.where((local >= 0) & (local < held), local,
                                     held).astype(jnp.int32)
-            n_tiles = max_tiles(n, k, held, self.tile)
-            plan = plan_tiles(pair_expert, held, self.tile, n_tiles)
+            window = window_rows(n, k, held, self.num_experts, self.tile)
+            plan = plan_windows(pair_expert, held, window)
+        why_xla = self._why_xla(x.dtype)
+        tracer = default_tracer()
+        now = tracer.now_ns()
+        tracer.record("moe.path", now, now, layer=self.lp.name,
+                      path="xla" if why_xla else "kernel",
+                      reason=why_xla or "backend, widths and tile_rows fit")
         wg, wu, wd = (w.astype(x.dtype) for w in params[1:4])
         y = held_experts(xt, top.reshape(n * k), plan, wg, wu, wd,
-                         self.tile, k)
+                         self.tile, k, window, why_xla is None)
         if self.shared_hidden:
             with jax.named_scope("moe_shared"):
                 sg, su, sd, gate = (w.astype(x.dtype) for w in params[4:8])
@@ -433,7 +514,8 @@ class MoE(Layer):
             here = jnp.sum(load)
             stats = lax.stop_gradient(jnp.stack([
                 here / (n * k),
-                jnp.max(load) * held / jnp.maximum(here, 1.0)]))
+                jnp.max(load) * held / jnp.maximum(here, 1.0),
+                plan["windows"].astype(jnp.float32)]))
             tops.append(stats)
             return tops, [stats]
         return tops, state

@@ -184,6 +184,14 @@ print("CNN", pallas())
 
 from sparknet_tpu.graph.registry import get
 from sparknet_tpu.models import dsl
+lp = dsl.MoELayer("moe", ["x"], 8, hidden_dim=128, top_k=2, experts_held=4,
+                  first_expert=0, tile_rows=8)
+impl = get(lp.type)(lp, [(1, 16, 128)], 0)
+blobs = [jax.ShapeDtypeStruct(s[0], "float32") for s in impl.param_shapes()]
+jax.eval_shape(lambda p, x: impl.apply(p, [x], True, None)[0], blobs,
+               jax.ShapeDtypeStruct((1, 16, 128), "float32"))
+print("MOE", pallas())
+
 lp = dsl.GatedDeltaNetLayer("mixer", ["x"], 1, 2, 128, 128)
 impl = get(lp.type)(lp, [(1, 64, 32)], 0)
 blobs = [jax.ShapeDtypeStruct(s[0], "float32") for s in impl.param_shapes()]
@@ -202,15 +210,18 @@ def test_a_process_that_steps_caffenet_never_imports_pallas():
     module is imported in the branch that calls it (ops/lrn.py,
     ops/attention.py, graph/compiler.py, ops/deltanet.py): importing the
     package, the solver and the zoo, building CaffeNet and tracing and
-    compiling one step of it leaves pallas out of `sys.modules`; tracing
-    a GatedDeltaNet at head size 128 brings it in."""
+    compiling one step of it leaves pallas out of `sys.modules`, and so
+    does tracing the no-drop MoE where its grouped product is XLA's (off
+    the TPU, ops/pallas_moe.py is never imported); tracing a GatedDeltaNet
+    at head size 128 brings it in."""
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run([sys.executable, "-c", _GUARD], cwd=REPO, env=env,
                          capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stderr[-3000:]
     lines = dict(ln.split(" ", 1) for ln in res.stdout.splitlines()
-                 if ln.startswith(("CNN ", "GDN ")))
+                 if ln.startswith(("CNN ", "MOE ", "GDN ")))
     assert lines["CNN"] == "[]", lines["CNN"]
+    assert lines["MOE"] == "[]", lines["MOE"]
     assert "jax.experimental.pallas" in lines["GDN"]
     assert "jax.experimental.pallas.tpu" in lines["GDN"]

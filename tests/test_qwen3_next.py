@@ -5,7 +5,7 @@ lies, not copied): small widths, seeded weights, float32 on the CPU.
 The reference computes the DeltaNet token by token, attention as a masked
 softmax, the MoE as a loop over the held experts with a mask; the program
 computes them in chunks, through the dense or the flash path, and over
-ragged groups tile by tile.
+ragged groups a window of rows at a time.
 """
 
 import importlib
@@ -333,13 +333,16 @@ def test_moe_shares_add_up_to_the_uncut_layer():
     assert np.abs(np.asarray(routed[0][0] - want_y)).max() > 1e-3
 
 
-def test_moe_tile_table_bounds_what_can_be_routed():
-    """Each held expert's group is padded to whole tiles; the static table
-    holds the worst case: every token's min(top_k, held) pairs land here,
-    spread so that every group has a ragged last tile."""
+def test_moe_window_plan_bounds_what_can_be_routed():
+    """The pairs on held experts lie sorted by expert, unpadded, and run a
+    window of rows at a time; the static bound is the worst case: every
+    token's min(top_k, held) pairs land here, however they are spread."""
     n, k, held, tile = 96, 10, 8, 8
-    bound = moe_ops.max_tiles(n, k, held, tile)
-    assert bound == math.ceil(n * 8 / tile) + held
+    # 8 of 32 experts: an even routing sends 240 pairs, a window takes 300
+    window = moe_ops.window_rows(n, k, held, 32, tile)
+    assert window == 38 * tile
+    bound = math.ceil(n * 8 / window)           # windows, at the very most
+    assert bound == 3
     rng = np.random.RandomState(0)
     for trial in range(20):
         # every token sends min(k, held) = 8 pairs to the 8 held experts
@@ -351,26 +354,37 @@ def test_moe_tile_table_bounds_what_can_be_routed():
             pairs = np.tile(np.arange(held), n)
         pair_expert = np.concatenate(
             [pairs.reshape(n, 8), np.full((n, k - 8), held)], 1).reshape(-1)
-        plan = moe_ops.plan_tiles(jnp.asarray(pair_expert, jnp.int32), held,
-                                  tile, bound)
-        assert int(plan["used"]) <= bound
+        plan = moe_ops.plan_windows(jnp.asarray(pair_expert, jnp.int32),
+                                    held, window)
+        assert int(plan["windows"]) == bound
         np.testing.assert_array_equal(np.asarray(plan["count"]), counts)
-    # one token-expert pair each for 8 experts: 8 ragged tiles
-    plan = moe_ops.plan_tiles(jnp.arange(8, dtype=jnp.int32), held, tile,
-                              moe_ops.max_tiles(1, 8, held, tile))
-    assert int(plan["used"]) == 8 <= moe_ops.max_tiles(1, 8, held, tile)
+        # the held pairs first, each expert's one contiguous group; then
+        # padding, so that every window is a slice
+        order = np.asarray(plan["order"])
+        assert len(order) % window == 0 and not order[n * k:].any()
+        assert (np.diff(pair_expert[order[:n * k]]) >= 0).all()
+        np.testing.assert_array_equal(np.asarray(plan["bounds"]),
+                                      n * np.arange(held + 1))
+    # one token-expert pair each for 8 experts: one window, where the
+    # tile table needed 8 ragged tiles
+    one = moe_ops.window_rows(1, 8, held, 8, tile)
+    plan = moe_ops.plan_windows(jnp.arange(8, dtype=jnp.int32), held, one)
+    assert one == tile and int(plan["windows"]) == 1
 
 
 def test_moe_statistics_top():
     impl = moe_layer(held=8, first=0, stats=True)
-    assert impl.has_state and impl.out_shapes()[1] == (2,)
+    assert impl.has_state and impl.out_shapes()[1] == (3,)
     blobs = fill(impl, jax.random.PRNGKey(18))
     x = jax.random.normal(jax.random.PRNGKey(19), (2, 48, 32))
-    (y, stats), state = impl.apply_stateful(blobs, [jnp.zeros(2)], [x],
+    (y, stats), state = impl.apply_stateful(blobs, [jnp.zeros(3)], [x],
                                             True, None)
     idx, _ = impl.route(x.reshape(96, 32), blobs[0])
     load = np.bincount(np.asarray(idx).reshape(-1), minlength=32)[:8]
-    close(stats, [load.sum() / 960.0, load.max() / load.mean()])
+    # share of the pairs held here, largest over mean load, windows run
+    close(stats, [load.sum() / 960.0, load.max() / load.mean(),
+                  math.ceil(load.sum() / moe_ops.window_rows(
+                      96, 10, 8, 32, 8))])
     close(state[0], stats)
 
 
@@ -520,4 +534,5 @@ def test_moe_load_is_recorded_where_the_solver_fetches_a_loss():
                                            for i in range(4)}
     for r in loads:
         assert 0.0 < r["held_share"] < 1.0 and r["max_over_mean"] >= 1.0
+        assert r["windows"] >= 1.0
         assert r["parent"] == "solver.fetch"

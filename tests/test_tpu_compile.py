@@ -21,10 +21,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from sparknet_tpu.ops import moe as moe_ops
 from sparknet_tpu.ops import pallas_attention as pa
 from sparknet_tpu.ops import pallas_deltanet as pd
 from sparknet_tpu.ops import pallas_epilogue as pe
 from sparknet_tpu.ops import pallas_lrn as plrn
+from sparknet_tpu.ops import pallas_moe as pm
 
 LRN = dict(size=5, alpha=1e-4, beta=0.75, k=1.0)
 
@@ -130,6 +132,44 @@ def test_gdn_chunk_kernels_compile(one_chip, which):
              one_chip, qk, qk, v, rows, rows,
              *[(x.shape, x.dtype) for x in residuals], do, state,
              kernels=["gdn_chunk_bwd"])
+
+
+# the held experts' grouped products at the hybrid LM's shape: 2 x 8,192
+# tokens, top-10 of 512 experts with 16 held, 2048 x 512, one window of
+# 6,400 rows in tiles of 128, bfloat16 in, float32 out
+MOE_N, MOE_K, MOE_HELD, MOE_E, MOE_F, MOE_TILE = 16384, 10, 16, 2048, 512, 128
+
+
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_moe_grouped_products_compile(one_chip, monkeypatch, which):
+    # the kernels interpret themselves wherever the backend is the CPU,
+    # as it is here: steer that in the test, the program has no option
+    monkeypatch.setattr(pm, "_should_interpret", lambda: False)
+    window = moe_ops.window_rows(MOE_N, MOE_K, MOE_HELD, 512, MOE_TILE)
+    assert window == 6400
+    x = ((MOE_N, MOE_E), jnp.bfloat16)
+    pairs = ((MOE_N * MOE_K,), jnp.float32)
+    experts = ((MOE_N * MOE_K,), jnp.int32)
+    up = ((MOE_HELD, MOE_F, MOE_E), jnp.bfloat16)
+    down = ((MOE_HELD, MOE_E, MOE_F), jnp.bfloat16)
+
+    def run(x, pw, pair_expert, wg, wu, wd):
+        plan = moe_ops.plan_windows(pair_expert, MOE_HELD, window)
+        return moe_ops.held_experts(x, pw, plan, wg, wu, wd, MOE_TILE,
+                                    MOE_K, window, True)
+    if which == "forward":
+        compiled = _compile(run, one_chip, x, pairs, experts, up, up, down,
+                            kernels=["moe_gmm_fwd"])
+    else:
+        def grads(x, pw, pair_expert, wg, wu, wd, dy):
+            return jax.vjp(lambda x, pw, wg, wu, wd: run(
+                x, pw, pair_expert, wg, wu, wd), x, pw, wg, wu, wd)[1](dy)
+        compiled = _compile(
+            grads, one_chip, x, pairs, experts, up, up, down,
+            ((MOE_N, MOE_E), jnp.float32),
+            kernels=["moe_gmm_fwd", "moe_gmm_bwd", "moe_gmm_dw"])
+    # a window's buffers, not tokens x top_k rows of anything
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 def test_gated_delta_net_backward_holds_less_than_the_scans_did(
