@@ -509,7 +509,10 @@ class CompiledNet:
 
         Two groups are identical when every corresponding layer matches
         on type, name suffix, prefix-stripped bottoms/tops, top blob
-        shapes, and owned param shapes/dtypes — and is stateless,
+        shapes, owned param shapes/dtypes and its own settings (the
+        layer's fields less its name, bottoms, tops and blobs: a windowed
+        attention and a global one of the same shapes are not one body)
+        — and is stateless,
         rng-free, loss-free, feed-free, with no cross-layer param
         sharing. Chaining requires group i's one external input to be
         group i-1's one externally consumed top, read by nothing else.
@@ -521,9 +524,7 @@ class CompiledNet:
         Returns [{lo, hi, glen, n, entry, body_out, out}]: layer range,
         group length/count, group-0's external input blob, group-0's
         boundary top (the scan carry), and the LAST group's boundary
-        blob name (where the carry lands). Config fields that don't
-        change shapes (e.g. LayerNorm eps) are not compared; the zoo
-        emits blocks from one generator, so they cannot differ there."""
+        blob name (where the carry lands)."""
         if self._scan_cache is not None:
             return self._scan_cache
         pgroups = []                       # (prefix, lo, hi)
@@ -563,7 +564,9 @@ class CompiledNet:
                 pshapes = tuple(
                     (self.param_meta[k][0],)
                     for k in self.param_refs[lp.name])
-                sig.append((lp.type, lp.name[strip:], tuple(bsig),
+                settings = {f: getattr(lp, f) for f in lp.set_fields()
+                            if f not in ("name", "bottom", "top", "blobs")}
+                sig.append((lp.type, lp.name[strip:], settings, tuple(bsig),
                             tuple(t[strip:] if t.startswith(pfx + "/")
                                   else "\x00T:" + t for t in tops),
                             tuple(tuple(self.blob_shapes[t]) for t in tops),

@@ -137,12 +137,16 @@ def AttentionLayer(name, bottoms, num_heads, head_dim=None, causal=False,
                    ring=False, flash=False, num_kv_heads=None,
                    qk_norm=False, rotary_dim=0, rope_theta=None,
                    output_gate=False, norm_eps=None, weight_filler=None,
-                   param=None):
+                   param=None, window=None):
     """sparknet_tpu extension for the long-context path (see
     parallel.ring_attention, ops.pallas_attention). `num_kv_heads` selects
     the grouped-query form (bias-free q/k/v/out projections; qk_norm,
-    rotary_dim, rope_theta, output_gate belong to it)."""
+    rotary_dim, rope_theta, output_gate belong to it). `window` (with
+    causal): a sliding window of that many keys, the query's own among
+    them."""
     ap = dict(num_heads=num_heads, causal=causal, ring=ring, flash=flash)
+    if window:
+        ap["window"] = window
     if head_dim is not None:
         ap["head_dim"] = head_dim
     if num_kv_heads is not None:
@@ -212,7 +216,8 @@ def MoELayer(name, bottoms, num_experts, hidden_dim=None,
              capacity_factor=None, expert_parallel=False,
              aux_loss_weight=None, weight_filler=None, stats=False,
              top_k=None, experts_held=None, first_expert=None,
-             shared_hidden_dim=None, norm_topk_prob=None, tile_rows=None):
+             shared_hidden_dim=None, norm_topk_prob=None, tile_rows=None,
+             expert_activation=None):
     """sparknet_tpu extension: MoE FFN. The top-1 Switch form:
     aux_loss_weight adds a second top carrying the load-balancing loss
     with that loss_weight; stats=True adds a third (weight-0) diagnostics
@@ -221,7 +226,8 @@ def MoELayer(name, bottoms, num_experts, hidden_dim=None,
     top_k of num_experts with renormalised weights, `experts_held` of
     them from `first_expert` on held here, an optional shared expert);
     there stats=True adds one (weight-0) top [share of pairs on held
-    experts, largest over mean held load]."""
+    experts, largest over mean held load]. `expert_activation` "relu"
+    makes the experts ReLU-gated; a second bottom feeds the router."""
     if top_k is not None:
         mp = dict(num_experts=num_experts, gated_experts=True, top_k=top_k)
         for key, val in (("hidden_dim", hidden_dim),
@@ -230,6 +236,7 @@ def MoELayer(name, bottoms, num_experts, hidden_dim=None,
                          ("shared_hidden_dim", shared_hidden_dim),
                          ("norm_topk_prob", norm_topk_prob),
                          ("tile_rows", tile_rows),
+                         ("expert_activation", expert_activation),
                          ("weight_filler", weight_filler)):
             if val is not None:
                 mp[key] = val

@@ -428,6 +428,97 @@ def qwen3_next(vocab_size=151936, seq_len=8192, batch_size=2,
     return NetParam("Qwen3Next", *layers)
 
 
+def smallthinker(vocab_size=151936, seq_len=16384, batch_size=2,
+                 hidden_size=2560, num_hidden_layers=52,
+                 num_attention_heads=28, num_key_value_heads=4,
+                 head_dim=128, rope_theta=1.5e6, rms_norm_eps=1e-6,
+                 rope_layout=None, sliding_window_layout=None,
+                 sliding_window_size=4096, moe_num_primary_experts=64,
+                 moe_num_active_primary_experts=6, moe_ffn_hidden_size=768,
+                 norm_topk_prob=True, experts_held=None, first_expert=0,
+                 flash=True, moe_stats=False):
+    """SmallThinker (`model_name` smallthinker_21b_instruct) as a trainable
+    net: blocks of h = RMSNorm(x), y = x + Attn(h), out = y + MoE(RMSNorm(y))
+    with the ROUTER READING h, the block's pre-attention norm, and the
+    experts the post-attention one (the MoE layer's second bottom);
+    grouped-query attention without bias, query/key norm or gate, layer l
+    windowed (`sliding_window_size` keys, the query's own among them) where
+    `sliding_window_layout[l]` and with rotate-half rotary on the whole
+    head where `rope_layout[l]`, global and without any positional encoding
+    where they are 0 (both default to 0, 1, 1, 1 repeating: one global
+    NoPE layer before every three windowed rotary ones); a no-drop top-k
+    MoE of ReLU-gated experts, no shared expert; plain RMSNorm (w filled
+    with 1); untied embedding and head; mean cross-entropy per token.
+    Defaults are the published sizes of SmallThinker-21BA3B-Instruct.
+
+    One chip's share of an expert-parallel group as in `qwen3_next`:
+    `experts_held` from `first_expert` on, `vocab_size` the held rows,
+    `num_hidden_layers` this pipeline stage's layers (the first of the
+    layouts). Left out: the router's auxiliary loss, dropout.
+
+    Matrices are filled gaussian(0.02), the embedding gaussian(1): at 1 a
+    token's own vector, not the blocks' output that all tokens share,
+    carries the residual stream from the first step, and the routers see
+    the tokens apart (at 0.02 the deeper layers route nearly every token to
+    the same six experts).
+
+    Layers are named block{i}/ln1 | attn | res1 | ln2 | moe | res2; the
+    three window blocks of a period are alike and scan, the global one is
+    a body of its own."""
+    e = hidden_size
+    period = [0, 1, 1, 1]
+    rotary, windowed = (
+        list(given) if given is not None
+        else [period[i % 4] for i in range(num_hidden_layers)]
+        for given in (rope_layout, sliding_window_layout))
+    gauss = dict(type="gaussian", std=0.02)
+    keep, nodecay = dict(lr_mult=1, decay_mult=1), dict(lr_mult=1,
+                                                        decay_mult=0)
+    layers = [
+        RDDLayer("data", [batch_size, seq_len]),
+        RDDLayer("label", [batch_size, seq_len]),
+        EmbedLayer("tok_embed", ["data"], vocab_size, e,
+                   weight_filler=dict(type="gaussian", std=1.0),
+                   bias_term=False),
+    ]
+    x = "tok_embed"
+    for i in range(num_hidden_layers):
+        p = f"block{i}"
+        layers += [
+            RMSNormLayer(f"{p}/ln1", [x], eps=rms_norm_eps,
+                         zero_centered=False, param=[nodecay]),
+            AttentionLayer(
+                f"{p}/attn", [f"{p}/ln1"], num_attention_heads,
+                head_dim=head_dim, causal=True, flash=flash,
+                num_kv_heads=num_key_value_heads,
+                rotary_dim=head_dim if rotary[i] else 0,
+                rope_theta=rope_theta, weight_filler=gauss,
+                window=sliding_window_size if windowed[i] else 0,
+                param=[keep] * 4),
+            EltwiseLayer(f"{p}/res1", [x, f"{p}/attn"]),
+            RMSNormLayer(f"{p}/ln2", [f"{p}/res1"], eps=rms_norm_eps,
+                         zero_centered=False, param=[nodecay]),
+            MoELayer(f"{p}/moe", [f"{p}/ln2", f"{p}/ln1"],
+                     moe_num_primary_experts,
+                     hidden_dim=moe_ffn_hidden_size,
+                     top_k=moe_num_active_primary_experts,
+                     experts_held=experts_held, first_expert=first_expert,
+                     norm_topk_prob=norm_topk_prob,
+                     expert_activation="relu", weight_filler=gauss,
+                     stats=moe_stats),
+            EltwiseLayer(f"{p}/res2", [f"{p}/res1", f"{p}/moe"]),
+        ]
+        x = f"{p}/res2"
+    layers += [
+        RMSNormLayer("ln_f", [x], eps=rms_norm_eps, zero_centered=False,
+                     param=[nodecay]),
+        InnerProductLayer("lm_head", ["ln_f"], vocab_size,
+                          weight_filler=gauss, axis=2, bias_term=False),
+        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
+    ]
+    return NetParam("SmallThinker", *layers)
+
+
 def transformer_lm_pieces(vocab_size=512, seq_len=256, batch_size=8,
                           d_model=256, num_heads=8, d_ff=None,
                           max_positions=None, flash=True):

@@ -19,6 +19,14 @@ dimensions of every head, the rest untouched; softmax(q k^T / sqrt(D)) v;
 with output_gate o * sigmoid(gate) before the out projection. The flash
 kernel reads the shared key-value heads in place (no repeat in memory);
 the dense path repeats them.
+
+attention_param.window (with causal) is a sliding window: query i sees keys
+i - window < j <= i. The flash kernel then runs over the band of key blocks
+alone (`flash_swa_*` in a device trace), the dense path masks the same way.
+Which core a layer took is in the ring of obs/trace.py, one `attn.path`
+record a trace of the layer: `path` = `kernel`, `dense` or `ring`, the
+`reason`, the `window`, and `live_blocks` / `causal_blocks`, the key blocks
+the kernel visits over those of the causal half (equal without a window).
 """
 
 import jax
@@ -26,6 +34,7 @@ import jax.numpy as jnp
 
 from ..proto import Message
 from ..graph.registry import Layer, register
+from ..obs.trace import default_tracer
 from ..parallel import context
 from ..parallel.ring import ring_attention, dense_attention
 from .convolution import _param_mults
@@ -75,6 +84,10 @@ class Attention(Layer):
         self.rope_theta = float(p.rope_theta)
         self.output_gate = bool(p.output_gate)
         self.norm_eps = float(p.norm_eps)
+        self.window = int(p.window)
+        if self.window and (not self.causal or self.ring):
+            raise ValueError(f"{lp.name}: a window needs causal attention "
+                             "and has no ring mode")
         if self.num_heads % self.kv_heads:
             raise ValueError(f"{lp.name}: num_heads {self.num_heads} is not "
                              f"a multiple of num_kv_heads {self.kv_heads}")
@@ -124,16 +137,43 @@ class Attention(Layer):
         qkv = x @ wqkv.T + bqkv                          # (B, S, 3*H*D)
         qkv = qkv.reshape(b, s, 3, self.num_heads, self.head_dim)
         q, k, v = [jnp.moveaxis(qkv[:, :, i], 1, 2) for i in range(3)]
-        seq_axis = context.axis("seq")
-        if self.ring and seq_axis is not None:
-            o = ring_attention(q, k, v, seq_axis, causal=self.causal)
-        elif self.flash and s % 128 == 0:
-            from .pallas_attention import flash_attention
-            o = flash_attention(q, k, v, self.causal)
-        else:
-            o = dense_attention(q, k, v, causal=self.causal)
+        o = self._core(q, k, v)
         o = jnp.moveaxis(o, 2, 1).reshape(b, s, self.inner)
         return [o @ wo.T + bo]
+
+    def _core(self, q, k, v):
+        """softmax(q k^T / sqrt(D) + mask) v of q (B, H, S, D) against k, v
+        (B, Hkv, S, D): over the ring, through the flash kernel or dense,
+        chosen from what the layer sees, and recorded as `attn.path`."""
+        s, grp = q.shape[2], q.shape[1] // k.shape[1]
+        seq_axis = context.axis("seq")
+        live = half = 0
+        if self.ring and seq_axis is not None:
+            path, reason = "ring", f"ring over the mesh axis {seq_axis}"
+            o = ring_attention(q, k, v, seq_axis, causal=self.causal)
+        elif self.flash and s % 128 == 0:
+            # here and not at the top: a process without such a layer never
+            # imports pallas (1.4 s of every cell's set-up, PR 29)
+            from .pallas_attention import band_blocks, flash_attention
+            path, reason = "kernel", "flash is set and 128 divides S"
+            if self.causal:
+                live, half = band_blocks(s, self.window)
+            o = flash_attention(q, k, v, self.causal, None, 512, 512,
+                                self.window)
+        else:
+            path = "dense"
+            reason = "flash is not set" if not self.flash \
+                else f"128 does not divide the sequence length {s}"
+            if grp > 1:
+                k, v = (jnp.repeat(a, grp, axis=1) for a in (k, v))
+            o = dense_attention(q, k, v, causal=self.causal,
+                                window=self.window)
+        tracer = default_tracer()
+        now = tracer.now_ns()
+        tracer.record("attn.path", now, now, layer=self.lp.name, path=path,
+                      reason=reason, window=self.window, live_blocks=live,
+                      causal_blocks=half)
+        return o
 
     def _apply_gqa(self, params, x):
         wq, wk, wv, wo = [p.astype(x.dtype) for p in params[:4]]
@@ -155,13 +195,7 @@ class Attention(Layer):
             k = rotary(k, self.rotary_dim, self.rope_theta)
         q, k, v = [jnp.moveaxis(a, 1, 2) for a in (q, k, v)]  # (B, H, S, D)
         with jax.named_scope("attn_core"):
-            if self.flash and s % 128 == 0:
-                from .pallas_attention import flash_attention
-                o = flash_attention(q, k, v, self.causal)
-            else:
-                o = dense_attention(q, jnp.repeat(k, h // hk, axis=1),
-                                    jnp.repeat(v, h // hk, axis=1),
-                                    causal=self.causal)
+            o = self._core(q, k, v)
         o = jnp.moveaxis(o, 2, 1).reshape(b, s, h * d)
         if gate is not None:
             o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)

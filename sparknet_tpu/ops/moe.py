@@ -9,7 +9,13 @@ Two forms, chosen by moe_param.gated_experts:
 * True: p = softmax(W_r x) over ALL num_experts in float32; the top_k
   largest, their weights divided by their sum (norm_topk_prob);
   routed = sum over the chosen experts THAT THIS LAYER HOLDS of
-  p_e W_down,e (SiLU(W_gate,e x) * W_up,e x), no bias anywhere. The layer
+  p_e W_down,e (act(W_gate,e x) * W_up,e x), no bias anywhere, act SiLU
+  or, with moe_param.expert_activation "relu", ReLU (the forward, its
+  recomputation and the backward alike: the derivative is a mask). With
+  a SECOND BOTTOM the router reads that one and the experts the first
+  (a block that routes from its pre-attention norm while its experts
+  read the post-attention one): p = softmax(W_r bottom[1]), and the
+  router's gradient goes to the second bottom alone. The layer
   holds `experts_held` experts starting at the router's output
   `first_expert` (0 held = all of them: the whole layer); the router keeps
   num_experts outputs either way, and what the absent experts would add is
@@ -115,8 +121,10 @@ from .convolution import _param_mults
 
 # -- the no-drop form: held experts over ragged groups, a window at a time ---
 
-# the most rows of a window, in tiles (8,192 rows at tile_rows 128)
-WINDOW_TILES = 64
+# the most rows of a window, in tiles (32,768 rows at tile_rows 128: a
+# window's scatter-add costs about 8 ms besides its rows on a v5e, so a share
+# whose even routing fits one window should get it in one, PR 33)
+WINDOW_TILES = 256
 
 _NT = (((1,), (2,)), ((), ()))      # lhs (m, k) . rhs (g, n, k)
 _NN = (((1,), (1,)), ((), ()))      # lhs (m, k) . rhs (g, k, n)
@@ -193,11 +201,27 @@ def _grouped(kernel, tile, sizes):
     return dot, dot_t
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _gate(a, act):
+    """act(a) of the gate's float32 product."""
+    return jnp.maximum(a, 0.0) if act == "relu" else jax.nn.silu(a)
+
+
+def _gate_and_slope(a, act):
+    """(act(a), act'(a)): ReLU's derivative is a mask, 0 at 0."""
+    if act == "relu":
+        on = a > 0
+        return jnp.where(on, a, 0.0), on.astype(a.dtype)
+    sa = jax.nn.sigmoid(a)
+    silu = a * sa
+    return silu, sa + silu * (1.0 - sa)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
 def held_experts(x, pair_weight, plan, wg, wu, wd, tile, top_k, window,
-                 kernel):
+                 kernel, act="silu"):
     """sum over each token's held experts e of pair_weight x W_down,e
-    (SiLU(W_gate,e x) * W_up,e x). x (n, E) and the weights in the
+    (act(W_gate,e x) * W_up,e x), `act` "silu" or "relu". x (n, E) and the
+    weights in the
     compute type, pair_weight (n x top_k,) float32 (token-major), `plan`
     from `plan_windows(.., window)`, `window` a multiple of `tile`, the row
     tile of the grouped product; `kernel`: the pallas product, else XLA's.
@@ -206,14 +230,15 @@ def held_experts(x, pair_weight, plan, wg, wu, wd, tile, top_k, window,
     second such loop that recomputes each window, so nothing is stored per
     window."""
     return _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window,
-                     kernel)[0]
+                     kernel, act)[0]
 
 
 # jitted, so that the layers of one shape (and a layer's recomputation)
 # trace and lower the window's body once between them: the kernels'
 # tracing is seconds of a step's first call
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
-def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window, kernel):
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
+def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window, kernel,
+              act="silu"):
     def body(w, y):
         with jax.named_scope("moe_dispatch"):
             pair, tok, valid, sizes = _window(plan, w, window, top_k)
@@ -222,7 +247,7 @@ def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window, kernel):
             dot, _ = _grouped(kernel, tile, sizes)
             a = dot(xw, wg, True, "moe_gmm_fwd")
             b = dot(xw, wu, True, "moe_gmm_fwd")
-            h = (jax.nn.silu(a) * b).astype(x.dtype)
+            h = (_gate(a, act) * b).astype(x.dtype)
             out = dot(h, wd, True, "moe_gmm_fwd")
         with jax.named_scope("moe_combine"):
             wt = pair_weight[pair]
@@ -234,8 +259,8 @@ def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window, kernel):
     return y, (x, pair_weight, plan, wg, wu, wd)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def _held_bwd(tile, top_k, window, kernel, res, dy):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _held_bwd(tile, top_k, window, kernel, act, res, dy):
     x, pair_weight, plan, wg, wu, wd = res
     dy = dy.astype(jnp.float32)
 
@@ -248,17 +273,16 @@ def _held_bwd(tile, top_k, window, kernel, res, dy):
             dot, dot_t = _grouped(kernel, tile, sizes)
             a = dot(xw, wg, True, "moe_gmm_fwd")
             b = dot(xw, wu, True, "moe_gmm_fwd")
-            sa = jax.nn.sigmoid(a)
-            silu = a * sa
-            h = (silu * b).astype(x.dtype)
+            gate, dgate = _gate_and_slope(a, act)
+            h = (gate * b).astype(x.dtype)
             wt = jnp.where(valid, pair_weight[pair], 0.0)
             # d pair_weight = dy . (h W_down^T), row by row
             out = dot(h, wd, True, "moe_gmm_fwd")
             dwt = jnp.where(valid, jnp.sum(dyr * out, -1), 0.0)
             dyw = (dyr * wt[:, None]).astype(x.dtype)
             dh = dot(dyw, wd, False, "moe_gmm_bwd")
-            da = (dh * b * (sa + silu * (1.0 - sa))).astype(x.dtype)
-            db = (dh * silu).astype(x.dtype)
+            da = (dh * b * dgate).astype(x.dtype)
+            db = (dh * gate).astype(x.dtype)
             dxw = dot(da, wg, False, "moe_gmm_bwd") \
                 + dot(db, wu, False, "moe_gmm_bwd")
             dwg = dot_t(da, xw, dwg, "moe_gmm_dw")
@@ -304,6 +328,19 @@ class MoE(Layer):
         self.first = int(p.first_expert)
         self.shared_hidden = int(p.shared_hidden_dim)
         self.tile = int(p.tile_rows)
+        self.act = str(p.expert_activation)
+        self.router_bottom = len(bottom_shapes) > 1
+        if self.act not in ("silu", "relu"):
+            raise ValueError(f"{lp.name}: expert_activation {self.act!r}: "
+                             "want silu or relu")
+        if not self.gated and (self.act != "silu" or self.router_bottom):
+            raise ValueError(
+                f"{lp.name}: expert_activation and a second bottom for the "
+                "router belong to the no-drop form (moe_param.gated_experts)")
+        if self.router_bottom and tuple(bottom_shapes[1]) != (b, s, e):
+            raise ValueError(
+                f"{lp.name}: the router's bottom {tuple(bottom_shapes[1])} "
+                f"is not the experts' {(b, s, e)}")
         if not self.gated and (self.top_k != 1 or p.has("experts_held")
                                or self.shared_hidden):
             raise ValueError(
@@ -485,7 +522,8 @@ class MoE(Layer):
         n, k, held = b * s, self.top_k, self.held
         xt = x.reshape(n, e)
         with jax.named_scope("moe_route"):
-            idx, top = self.route(xt, params[0])
+            routed = bottoms[1].reshape(n, e) if self.router_bottom else xt
+            idx, top = self.route(routed, params[0])
             local = idx.reshape(n * k) - self.first
             pair_expert = jnp.where((local >= 0) & (local < held), local,
                                     held).astype(jnp.int32)
@@ -496,10 +534,11 @@ class MoE(Layer):
         now = tracer.now_ns()
         tracer.record("moe.path", now, now, layer=self.lp.name,
                       path="xla" if why_xla else "kernel",
-                      reason=why_xla or "backend, widths and tile_rows fit")
+                      reason=why_xla or "backend, widths and tile_rows fit",
+                      activation=self.act)
         wg, wu, wd = (w.astype(x.dtype) for w in params[1:4])
         y = held_experts(xt, top.reshape(n * k), plan, wg, wu, wd,
-                         self.tile, k, window, why_xla is None)
+                         self.tile, k, window, why_xla is None, self.act)
         if self.shared_hidden:
             with jax.named_scope("moe_shared"):
                 sg, su, sd, gate = (w.astype(x.dtype) for w in params[4:8])

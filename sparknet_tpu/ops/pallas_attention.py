@@ -21,6 +21,18 @@ streamed innermost (the FlashAttention-2 recurrences):
     dQ_i   += scale * dS_ij K_j
     dK_j   += scale * dS_ij^T Q_i
 
+A sliding window (`window` > 0, a trace-time constant, with `causal`): key
+j is visible to query i iff i - window < j <= i. The window form's grids
+are the BAND's, not the square's: a query block's key axis runs over the
+`_band` of key blocks that hold a visible key (its first block from the
+block index, at most (window + block_q - 2) // block_k + 2 of them), and a
+key block's query axis likewise, so the blocks wholly left of the window
+are never fetched or visited, as those above the diagonal are not; the
+partial blocks at both edges are masked. Those calls are named
+`flash_swa_fwd`, `flash_swa_dq`, `flash_swa_dkv`. With window 0, or a
+window that covers the sequence, the kernels are traced as the causal form,
+without a window term.
+
 Interpret mode engages on the CPU backend only (the tests); any other
 backend compiles the kernel or raises.
 """
@@ -29,6 +41,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -38,8 +51,50 @@ NEG_INF = -1e30
 LANES = 128
 
 
+def _key_band(qi, bq, bk, window, xp=jnp):
+    """(first, last) key block with a key visible to query block `qi`:
+    from the first row's oldest key to the last row's own. `xp` is numpy
+    for the static extents, jax.numpy inside a kernel or an index map."""
+    return (xp.maximum(qi * bq - (window - 1), 0) // bk,
+            (qi * bq + bq - 1) // bk)
+
+
+def _query_band(ki, bq, bk, window, nq, xp=jnp):
+    """(first, last) query block that sees a key of key block `ki`: from
+    its first key's own row to the last key's youngest reader."""
+    return ((ki * bk) // bq,
+            xp.minimum((ki * bk + bk + window - 2) // bq, nq - 1))
+
+
+def band_blocks(s, window, block_q=512, block_k=512):
+    """(key blocks the window form visits, key blocks of the causal half),
+    summed over the query blocks of one head at sequence length `s`: what
+    `attn.path` reports. Without a window the two are equal."""
+    bq, bk = _fit_block(block_q, s), _fit_block(block_k, s)
+    first, last = _key_band(np.arange(s // bq), bq, bk,
+                            window if 0 < window < s else s, np)
+    return int(np.sum(last - first + 1)), int(np.sum(last + 1))
+
+
+def _band_extent(s, window, bq, bk):
+    """Static extents of the window form's inner grid axes: the most key
+    blocks any query block visits, the most query blocks any key block."""
+    nq, nk = s // bq, s // bk
+    kf, kl = _key_band(np.arange(nq), bq, bk, window, np)
+    qf, ql = _query_band(np.arange(nk), bq, bk, window, nq, np)
+    return int(np.max(kl - kf)) + 1, int(np.max(ql - qf)) + 1
+
+
+def _visible(qi, ki, bq, bk, window):
+    """The (bq, bk) mask of query block qi against key block ki under a
+    window: the key not ahead of the query, and fewer than `window` back."""
+    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    return (q_pos >= k_pos) & (q_pos - k_pos < window)
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-               *, causal, scale):
+               *, causal, scale, window=0):
     _, bq, d = q_ref.shape
     bk = k_ref.shape[1]
     qi = pl.program_id(1)
@@ -52,8 +107,16 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # causal: skip K/V blocks wholly above the diagonal
-    live = (ki * bk <= qi * bq + bq - 1) if causal else True
+    if window:
+        # the key axis is the band's: step ki is key block first + ki, and
+        # the steps past the diagonal block (short bands at the start of
+        # the sequence) do nothing
+        first, last = _key_band(qi, bq, bk, window)
+        kj = first + ki
+        live = kj <= last
+    else:
+        # causal: skip K/V blocks wholly above the diagonal
+        live = (ki * bk <= qi * bq + bq - 1) if causal else True
 
     @pl.when(live)
     def _step():
@@ -62,7 +125,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         vb = v_ref[0].astype(jnp.float32)
         sc = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        if causal:
+        if window:
+            sc = jnp.where(_visible(qi, kj, bq, bk, window), sc, NEG_INF)
+        elif causal:
             q_pos = qi * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 0)
             k_pos = ki * bk + jax.lax.broadcasted_iota(
@@ -121,23 +186,47 @@ def _group(q, k):
     return h // hkv
 
 
-def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
+def _window_of(window, causal, s):
+    """The window the kernels are traced with: 0 (the causal form, no
+    window term) when none is asked or it covers the sequence."""
+    if window and not causal:
+        raise ValueError("flash attention: a window needs causal=True")
+    return window if 0 < window < s else 0
+
+
+def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
+                   window=0):
     b, h, s, d = q.shape
     grp = _group(q, k)
     block_q = _fit_block(block_q, s)
     block_k = _fit_block(block_k, s)
+    window = _window_of(window, causal, s)
     qf = q.reshape(b * h, s, d)
     kf = k.reshape(b * h // grp, s, d)
     vf = v.reshape(b * h // grp, s, d)
-    kernel = functools.partial(_fa_kernel, causal=causal, scale=scale)
+    kernel = functools.partial(_fa_kernel, causal=causal, scale=scale,
+                               window=window)
+    if window:
+        # the key axis is a query block's band; past its diagonal block
+        # the index stays there, so nothing more is fetched
+        steps = _band_extent(s, window, block_q, block_k)[0]
+
+        def kv_block(bh, i, j):
+            first, last = _key_band(i, block_q, block_k, window)
+            return (bh // grp, jnp.minimum(first + j, last), 0)
+    else:
+        steps = s // block_k
+
+        # query head bh reads key-value head bh // grp in place
+        def kv_block(bh, i, j):
+            return (bh // grp, j, 0)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, s // block_q, s // block_k),
+        grid=(b * h, s // block_q, steps),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            # query head bh reads key-value head bh // grp in place
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh // grp, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh // grp, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, block_k, d), kv_block),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
@@ -154,13 +243,13 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, LANES), jnp.float32),   # l
         ],
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_swa_fwd" if window else "flash_fwd",
     )(qf, kf, vf)
     return out.reshape(b, h, s, d), lse
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
-               acc_ref, *, causal, scale):
+               acc_ref, *, causal, scale, window=0):
     _, bq, d = q_ref.shape
     bk = k_ref.shape[1]
     qi = pl.program_id(1)
@@ -171,7 +260,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    live = (ki * bk <= qi * bq + bq - 1) if causal else True
+    if window:
+        first, last = _key_band(qi, bq, bk, window)
+        kj = first + ki
+        live = kj <= last
+    else:
+        live = (ki * bk <= qi * bq + bq - 1) if causal else True
 
     @pl.when(live)
     def _step():
@@ -183,7 +277,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
         delta = jnp.sum(dob * ob, axis=1, keepdims=True)        # (bq, 1)
         sc = scale * jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
                                          preferred_element_type=jnp.float32)
-        if causal:
+        if window:
+            sc = jnp.where(_visible(qi, kj, bq, bk, window), sc, NEG_INF)
+        elif causal:
             q_pos = qi * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 0)
             k_pos = ki * bk + jax.lax.broadcasted_iota(
@@ -203,12 +299,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dk_ref, dv_ref,
-                dk_acc, dv_acc, *, causal, scale, nq):
+                dk_acc, dv_acc, *, causal, scale, nq, window=0, band=0):
     _, bq, d = q_ref.shape
     bk = k_ref.shape[1]
     ki = pl.program_id(1)       # note: grid is (kv head, j, group x i) here
     t = pl.program_id(2)        # the group's query heads one after another,
-    qi = t % nq                 # each over its nq query blocks
+    if window:                  # each over the `band` query blocks that
+        first, last = _query_band(ki, bq, bk, window, nq)   # may see ki
+        qi = first + t % band
+    else:
+        qi = t % nq             # each over its nq query blocks
     nt = pl.num_programs(2)
 
     @pl.when(t == 0)
@@ -216,7 +316,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dk_ref, dv_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    live = (ki * bk <= qi * bq + bq - 1) if causal else True
+    if window:
+        live = qi <= last
+    else:
+        live = (ki * bk <= qi * bq + bq - 1) if causal else True
 
     @pl.when(live)
     def _step():
@@ -228,7 +331,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dk_ref, dv_ref,
         delta = jnp.sum(dob * ob, axis=1, keepdims=True)
         sc = scale * jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
                                          preferred_element_type=jnp.float32)
-        if causal:
+        if window:
+            sc = jnp.where(_visible(qi, ki, bq, bk, window), sc, NEG_INF)
+        elif causal:
             q_pos = qi * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 0)
             k_pos = ki * bk + jax.lax.broadcasted_iota(
@@ -254,45 +359,67 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dk_ref, dv_ref,
 
 
 def _flash_backward(q, k, v, o, lse, g, causal, scale, block_q, block_k,
-                    interpret):
+                    interpret, window=0):
     b, h, s, d = q.shape
     grp = _group(q, k)
     hkv = h // grp
     block_q = _fit_block(block_q, s)
     block_k = _fit_block(block_k, s)
+    window = _window_of(window, causal, s)
     nq = s // block_q
     qf = q.reshape(b * h, s, d)
     kf = k.reshape(b * hkv, s, d)
     vf = v.reshape(b * hkv, s, d)
     dof = g.reshape(b * h, s, d)
     of = o.reshape(b * h, s, d)
+    if window:
+        key_steps, band = _band_extent(s, window, block_q, block_k)
+
+        def kv_block(bh, i, j):
+            first, last = _key_band(i, block_q, block_k, window)
+            return (bh // grp, jnp.minimum(first + j, last), 0)
+
+        # t = head in group x band + step: the band of query blocks that
+        # may see key block j, the index held at its last one
+        def q_block(bk, j, t):
+            first, last = _query_band(j, block_q, block_k, window, nq)
+            return (bk * grp + t // band,
+                    jnp.minimum(first + t % band, last), 0)
+    else:
+        key_steps, band = s // block_k, nq
+
+        def kv_block(bh, i, j):
+            return (bh // grp, j, 0)
+
+        def q_block(bk, j, t):
+            return (bk * grp + t // nq, t % nq, 0)
 
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda bh, i, j: (bh // grp, j, 0))
+    k_spec = pl.BlockSpec((1, block_k, d), kv_block)
     lse_spec = pl.BlockSpec((1, block_q, LANES),
                             lambda bh, i, j: (bh, i, 0))
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, scale=scale),
-        grid=(b * h, s // block_q, s // block_k),   # K/V innermost
+        functools.partial(_dq_kernel, causal=causal, scale=scale,
+                          window=window),
+        grid=(b * h, s // block_q, key_steps),      # K/V innermost
         in_specs=[q_spec, k_spec, k_spec, q_spec, q_spec, lse_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-        name="flash_dq",
+        name="flash_swa_dq" if window else "flash_dq",
     )(qf, kf, vf, dof, of, lse)
 
     # second kernel iterates (kv head, j, t): Q/dO stream innermost, the
     # group's query heads one after another (t = head in group x nq + i),
     # so a shared key-value head's gradient is summed in the kernel
-    qT_spec = pl.BlockSpec((1, block_q, d),
-                           lambda bk, j, t: (bk * grp + t // nq, t % nq, 0))
+    qT_spec = pl.BlockSpec((1, block_q, d), q_block)
     kT_spec = pl.BlockSpec((1, block_k, d), lambda bk, j, t: (bk, j, 0))
-    lseT_spec = pl.BlockSpec((1, block_q, LANES),
-                             lambda bk, j, t: (bk * grp + t // nq, t % nq, 0))
+    lseT_spec = pl.BlockSpec((1, block_q, LANES), q_block)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, scale=scale, nq=nq),
-        grid=(b * hkv, s // block_k, grp * nq),
+        functools.partial(_dkv_kernel, causal=causal, scale=scale, nq=nq,
+                          window=window, band=band),
+        grid=(b * hkv, s // block_k, grp * band),
         in_specs=[qT_spec, kT_spec, kT_spec, qT_spec, qT_spec, lseT_spec],
         out_specs=[kT_spec, kT_spec],
         out_shape=[jax.ShapeDtypeStruct((b * hkv, s, d), k.dtype),
@@ -300,22 +427,22 @@ def _flash_backward(q, k, v, o, lse, g, causal, scale, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
-        name="flash_dkv",
+        name="flash_swa_dkv" if window else "flash_dkv",
     )(qf, kf, vf, dof, of, lse)
     return (dq.reshape(b, h, s, d), dk.reshape(b, hkv, s, d),
             dv.reshape(b, hkv, s, d))
 
 
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
-                    block_k=512):
+                    block_k=512, window=0):
     """Flash attention (B, H, S, D) -> (B, H, S, D); exact, O(block) VMEM
     in both forward and backward. scale defaults to 1/sqrt(D). k and v may
     have fewer heads, (B, Hkv, S, D) with H a multiple of Hkv: query head
     h reads key-value head h // (H / Hkv) in place, and the backward sums
-    a shared head's gradient over its group inside the kernel.
+    a shared head's gradient over its group inside the kernel. `window`
+    (with `causal`): query i sees keys i - window < j <= i, and the blocks
+    outside that band are not visited.
 
     Default blocks are 512x512: the grid's K/V dimension is sequential,
     so small blocks are dispatch-latency-bound — at S=32k, 512x512 runs
@@ -324,22 +451,22 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
     clamp to S for short sequences."""
     scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                            _should_interpret())
+                            _should_interpret(), window)
     return out
 
 
-def _fwd(q, k, v, causal, scale, block_q, block_k):
+def _fwd(q, k, v, causal, scale, block_q, block_k, window):
     scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                              _should_interpret())
+                              _should_interpret(), window)
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, scale, block_q, block_k, res, g):
+def _bwd(causal, scale, block_q, block_k, window, res, g):
     q, k, v, o, lse = res
     scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     return _flash_backward(q, k, v, o, lse, g, causal, scale, block_q,
-                           block_k, _should_interpret())
+                           block_k, _should_interpret(), window)
 
 
 flash_attention.defvjp(_fwd, _bwd)

@@ -113,15 +113,17 @@ def ulysses_attention(q, k, v, axis_name, causal=False, scale=None):
     return head_to_seq(out)
 
 
-def dense_attention(q, k, v, causal=False, scale=None):
-    """Plain full attention (B, H, S, D) — the single-device reference."""
+def dense_attention(q, k, v, causal=False, scale=None, window=0):
+    """Plain full attention (B, H, S, D) — the single-device reference.
+    `window` (with causal): query i sees keys i - window < j <= i."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     s = jnp.einsum("bhqd,bhkd->bhqk", (q * scale).astype(jnp.float32),
                    k.astype(jnp.float32))
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
-        mask = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
+        ahead = jnp.arange(sq)[:, None] - jnp.arange(sk)[None, :]
+        mask = (ahead >= 0) & (ahead < window) if window else ahead >= 0
         s = jnp.where(mask[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p,

@@ -587,6 +587,10 @@ MESSAGES = {
         "rope_theta": (10, "float", "opt", 10000.0),
         "output_gate": (11, "bool", "opt", False),  # o * sigmoid(gate)
         "norm_eps": (12, "float", "opt", 1e-6),
+        # sliding window (with causal): query i sees keys i - window < j
+        # <= i, its own among them; 0 = none. The flash kernel skips the
+        # blocks outside the band, the dense path masks the same way.
+        "window": (13, "uint32", "opt", 0),
     },
     # sparknet_tpu extension: last-axis RMS norm, y = x / rms(x) * (1 + w)
     # (zero_centered, w filled with 0) or * w (w filled with 1).
@@ -634,6 +638,11 @@ MESSAGES = {
         "first_expert": (10, "uint32", "opt", 0),
         "shared_hidden_dim": (11, "uint32", "opt", 0),
         "tile_rows": (12, "uint32", "opt", 128),
+        # the no-drop form's expert, W_down (act(W_gate x) * W_up x):
+        # "silu" or "relu". With a second bottom the router reads that one
+        # (another normalised view of the residual) and the experts the
+        # first.
+        "expert_activation": (13, "string", "opt", "silu"),
     },
 }
 

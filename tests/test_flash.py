@@ -144,3 +144,129 @@ def test_flash_rejects_heads_that_do_not_divide():
     q, k, v = _gqa(1, 4, 3, 128, 32)
     with pytest.raises(ValueError, match="query heads"):
         flash_attention(q, k, v, True, None, 128, 128)
+
+
+# -- the sliding window: key j visible to query i iff i - window < j <= i ---
+
+def _masked_dense(q, k, v, window):
+    """Masked-softmax attention with the mask written out here (not the
+    program's dense path): shared key-value heads repeated."""
+    g = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
+    s = q.shape[2]
+    sc = jnp.einsum("bhqd,bhkd->bhqk", q, k) / q.shape[-1] ** 0.5
+    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    seen = (j <= i) & (i - j < window if window else True)
+    p = jax.nn.softmax(jnp.where(seen, sc, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+def _rand_gqa(h, hkv, s, d, seed=0):
+    rs = np.random.RandomState(seed)
+    mk = lambda n: jnp.asarray(rs.randn(2, n, s, d) * 0.5,  # noqa: E731
+                               jnp.float32)
+    return mk(h), mk(hkv), mk(hkv), mk(h)
+
+
+# (S, window, block_q, block_k, heads, kv heads): S not a multiple of the
+# window, window = one block, a window off every block edge, unlike block
+# sizes either way, 7 queries a key-value head
+WINDOW_CASES = {
+    "s320_w128": (320, 128, 64, 64, 2, 1),
+    "window_is_one_block": (256, 64, 64, 64, 4, 2),
+    "window_off_the_blocks": (256, 100, 64, 32, 2, 2),
+    "wide_key_blocks": (256, 37, 32, 64, 2, 1),
+    "wide_query_blocks": (384, 64, 128, 64, 2, 1),
+    "seven_queries_a_kv_head": (256, 96, 64, 64, 7, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(WINDOW_CASES))
+def test_window_kernels_match_masked_dense(case):
+    s, window, bq, bk, h, hkv = WINDOW_CASES[case]
+    q, k, v, cot = _rand_gqa(h, hkv, s, 32, seed=3)
+    out = flash_attention(q, k, v, True, None, bq, bk, window)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_masked_dense(q, k, v, window)),
+                               atol=2e-5, rtol=2e-5)
+    got = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, True, None, bq, bk, window) * cot), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda q, k, v: jnp.sum(
+        _masked_dense(q, k, v, window) * cot), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=5e-5)
+    # the program's dense path masks the same way
+    rep = lambda a: jnp.repeat(a, h // hkv, axis=1)  # noqa: E731
+    np.testing.assert_allclose(
+        np.asarray(dense_attention(q, rep(k), rep(v), causal=True,
+                                   window=window)),
+        np.asarray(_masked_dense(q, k, v, window)), atol=2e-5, rtol=2e-5)
+
+
+def _kernel_calls(fn, *args):
+    """[(name, grid)] of the pallas calls in fn's jaxpr, custom_vjp and
+    all."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((eqn.params["name"],
+                              tuple(eqn.params["grid_mapping"].grid)))
+            for val in eqn.params.values():
+                for sub in (val if isinstance(val, (list, tuple))
+                            else [val]):
+                    inner = getattr(sub, "jaxpr", None)
+                    if inner is not None:
+                        walk(getattr(inner, "jaxpr", inner))
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def test_window_grids_are_the_band_and_dead_blocks_are_skipped():
+    """S 512, window 128, blocks of 64: a query block's band is 3 key
+    blocks of the 8, a key block's 3 query blocks, and the kernels carry
+    the window's names."""
+    q, k, v, cot = _rand_gqa(4, 2, 512, 32)
+    step = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, True, None, 64, 64, 128) * cot), (0, 1, 2))
+    assert _kernel_calls(step, q, k, v) == [
+        ("flash_swa_fwd", (8, 8, 3)), ("flash_swa_dq", (8, 8, 3)),
+        ("flash_swa_dkv", (4, 8, 2 * 3))]
+    from sparknet_tpu.ops.pallas_attention import band_blocks
+    assert band_blocks(512, 128, 64, 64) == (1 + 2 + 6 * 3, 36)
+    # the cell's shape: under half of the causal half's blocks
+    live, causal = band_blocks(16384, 4096)
+    assert (live, causal) == (252, 528) and live / causal < 0.5
+    assert band_blocks(16384, 0) == (528, 528)
+
+
+@pytest.mark.parametrize("window", [0, 256, 1000])
+def test_no_window_and_a_window_that_covers_the_sequence_are_the_causal_form(
+        window):
+    """The accepted cell's guard: window 0, and a window >= S, trace the
+    three kernels as the causal form does (equal jaxprs: no window term,
+    the square's grid, the same names), so the results are its results bit
+    for bit."""
+    q, k, v, cot = _rand_gqa(4, 2, 256, 32, seed=5)
+
+    def step(window):
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, True, None, 64, 64, window) * cot), (0, 1, 2))
+    causal = jax.value_and_grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, True, None, 64, 64) * cot), (0, 1, 2))
+    assert str(jax.make_jaxpr(step(window))(q, k, v)) == \
+        str(jax.make_jaxpr(causal)(q, k, v))
+    assert [n for n, _ in _kernel_calls(step(window), q, k, v)] == [
+        "flash_fwd", "flash_dq", "flash_dkv"]
+    got, want = step(window)(q, k, v), causal(q, k, v)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_window_needs_causal():
+    q, k, v = _rand_qkv(1, 2, 128, 32)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, False, None, 64, 64, 32)

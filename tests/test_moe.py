@@ -330,3 +330,106 @@ def test_moe_top1_with_capacity_is_the_switch_form():
     lp = dsl.MoELayer("moe", ["x"], 4, hidden_dim=8, capacity_factor=0.5)
     layer = _layer(lp, [(1, 16, 6)])
     assert not layer.gated and len(layer.param_shapes()) == 5
+
+
+# -- ReLU-gated experts, and the router on a second bottom -------------------
+
+def _reglu_loop(g, h, params, top_k, first, held, act=jax.nn.relu):
+    """jax.numpy, a loop over the held experts with a mask: the router
+    reads h, the experts read g; nothing dropped."""
+    router, wg, wu, wd = params
+    n = g.shape[0] * g.shape[1]
+    gt, ht = g.reshape(n, -1), h.reshape(n, -1)
+    p = jax.nn.softmax(jnp.dot(ht, router.T, precision="highest"), -1)
+    top, idx = jax.lax.top_k(p, top_k)
+    top = top / jnp.sum(top, -1, keepdims=True)
+    y = jnp.zeros_like(gt)
+    for j in range(held):
+        weight = jnp.sum(jnp.where(idx == first + j, top, 0.0), -1)
+        y = y + weight[:, None] * (
+            (act(gt @ wg[j].T) * (gt @ wu[j].T)) @ wd[j].T)
+    return y.reshape(g.shape), idx
+
+
+@pytest.mark.parametrize("held,first,tile,skew", [
+    (8, 0, 4, False), (3, 2, 8, False), (8, 0, 4, True), (4, 4, 128, True)])
+def test_reglu_experts_routed_from_a_second_bottom(held, first, tile, skew):
+    """Output, and the gradients of BOTH bottoms and every blob, against
+    the loop; under the skew most tokens' first choice is one expert and
+    the windows spill, with nothing dropped."""
+    lp = dsl.MoELayer("moe", ["g", "h"], 8, hidden_dim=12, top_k=3,
+                      experts_held=held, first_expert=first, tile_rows=tile,
+                      expert_activation="relu")
+    layer = _layer(lp, [(2, 24, 6), (2, 24, 6)])
+    assert layer.act == "relu" and layer.router_bottom
+    params = _params(layer, seed=7)
+    rs = np.random.RandomState(8)
+    g = jnp.asarray(rs.randn(2, 24, 6), jnp.float32)
+    h = jnp.asarray(np.abs(rs.randn(2, 24, 6)) + 0.5, jnp.float32)
+    if skew:
+        params[0] = params[0].at[first].set(jnp.full((6,), 0.5))
+    cot = jnp.asarray(rs.randn(2, 24, 6), jnp.float32)
+    want, idx = _reglu_loop(g, h, params, 3, first, held)
+    if skew:
+        assert int(jnp.sum(idx[:, 0] == first)) >= 40
+    got = layer.apply(params, [g, h], True, None)[0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    # the experts do not read h, the router does not read g
+    other = layer.apply(params, [g, h[:, ::-1]], True, None)[0]
+    assert np.abs(np.asarray(other - got)).max() > 1e-3
+    assert np.abs(np.asarray(layer.apply(
+        params, [g, g], True, None)[0] - got)).max() > 1e-3
+
+    def mine(g, h, ps):
+        return jnp.sum(layer.apply(ps, [g, h], True, None)[0] * cot)
+
+    def loop(g, h, ps):
+        return jnp.sum(_reglu_loop(g, h, ps, 3, first, held)[0] * cot)
+    gm = jax.grad(mine, (0, 1, 2))(g, h, params)
+    gl = jax.grad(loop, (0, 1, 2))(g, h, params)
+    for a, b in zip(jax.tree_util.tree_leaves(gm),
+                    jax.tree_util.tree_leaves(gl)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=5e-4)
+    # the router's gradient reaches the second bottom, nothing else does
+    assert np.abs(np.asarray(gm[1])).max() > 0
+
+
+def test_relu_and_silu_experts_differ_only_in_the_activation():
+    """One bottom, both activations, against the same loop."""
+    for act, fn in (("relu", jax.nn.relu), ("silu", jax.nn.silu)):
+        lp = dsl.MoELayer("moe", ["x"], 8, hidden_dim=12, top_k=2,
+                          tile_rows=4, expert_activation=act)
+        layer = _layer(lp, [(2, 10, 6)])
+        params = _params(layer, seed=9)
+        x = jnp.asarray(np.random.RandomState(10).randn(2, 10, 6),
+                        jnp.float32)
+        want, _ = _reglu_loop(x, x, params, 2, 0, 8, act=fn)
+        np.testing.assert_allclose(
+            np.asarray(layer.apply(params, [x], True, None)[0]),
+            np.asarray(want), atol=2e-5, rtol=2e-5)
+        ga = jax.grad(lambda x: jnp.sum(jnp.sin(
+            layer.apply(params, [x], True, None)[0])))(x)
+        gb = jax.grad(lambda x: jnp.sum(jnp.sin(
+            _reglu_loop(x, x, params, 2, 0, 8, act=fn)[0])))(x)
+        np.testing.assert_allclose(np.asarray(ga), np.asarray(gb),
+                                   atol=5e-5, rtol=5e-4)
+
+
+def test_second_bottom_and_activation_belong_to_the_no_drop_form():
+    with pytest.raises(ValueError, match="no-drop form"):
+        _layer(dsl.MoELayer("moe", ["x", "h"], 8, hidden_dim=12),
+               [(2, 10, 6), (2, 10, 6)])
+    with pytest.raises(ValueError, match="silu or relu"):
+        _layer(dsl.MoELayer("moe", ["x"], 8, hidden_dim=12, top_k=2,
+                            expert_activation="gelu"), [(2, 10, 6)])
+    with pytest.raises(ValueError, match="router's bottom"):
+        _layer(dsl.MoELayer("moe", ["x", "h"], 8, hidden_dim=12, top_k=2),
+               [(2, 10, 6), (2, 5, 6)])
+    # defaults: an existing layer's prototxt does not name the new fields
+    lp = dsl.MoELayer("moe", ["x"], 8, hidden_dim=12, top_k=2)
+    assert not lp.moe_param.has("expert_activation")
+    assert _layer(lp, [(2, 10, 6)]).act == "silu"
+    ap = dsl.AttentionLayer("a", ["x"], 2, causal=True)
+    assert not ap.attention_param.has("window")

@@ -144,10 +144,14 @@ def test_window_rows_is_static_and_sized_by_an_even_routing():
     # the LM cell: 16,384 tokens x top-10, 16 of 512 experts held, tiles of
     # 128: an even routing sends 5,120 pairs, the window takes 6,400
     assert moe_ops.window_rows(16384, 10, 16, 512, 128) == 50 * 128
+    # the SmallThinker cell: 32,768 tokens x top-6, 8 of 64 held: 24,576
+    # pairs and a quarter more in ONE window at the default tile
+    assert moe_ops.window_rows(32768, 6, 8, 64, 128) == 240 * 128
     # a share of a toy layer, and the whole of it: never more than
     # WINDOW_TILES tiles, so memory is a window's and not the routing's
     assert moe_ops.window_rows(96, 10, 8, 32, 8) == 38 * 8
-    assert moe_ops.window_rows(96, 10, 32, 32, 8) == 64 * 8
+    assert moe_ops.window_rows(96, 10, 32, 32, 8) == 120 * 8
+    assert moe_ops.window_rows(256, 10, 32, 32, 8) == 256 * 8
     # no more rows than pairs that can land here, in whole tiles
     assert moe_ops.window_rows(24, 4, 4, 4, 8) == 96
     assert moe_ops.window_rows(1, 8, 8, 8, 8) == 8

@@ -134,29 +134,37 @@ def test_gdn_chunk_kernels_compile(one_chip, which):
              kernels=["gdn_chunk_bwd"])
 
 
-# the held experts' grouped products at the hybrid LM's shape: 2 x 8,192
-# tokens, top-10 of 512 experts with 16 held, 2048 x 512, one window of
-# 6,400 rows in tiles of 128, bfloat16 in, float32 out
-MOE_N, MOE_K, MOE_HELD, MOE_E, MOE_F, MOE_TILE = 16384, 10, 16, 2048, 512, 128
+# the held experts' grouped products at the two LM cells' shapes, bfloat16
+# in, float32 out, tiles of 128: (tokens, top_k, held, experts, embed,
+# hidden, activation, the window's rows, the most temporaries)
+MOE_TILE = 128
+MOE_SHAPES = {
+    # 2 x 8,192 tokens, top-10 of 512 with 16 held, 2048 x 512, SiLU
+    "qwen3_next": (16384, 10, 16, 512, 2048, 512, "silu", 6400, 1 << 30),
+    # 2 x 16,384 tokens, top-6 of 64 with 8 held, 2560 x 768, ReLU: an even
+    # routing's 24,576 pairs and a quarter more in one window
+    "smallthinker": (32768, 6, 8, 64, 2560, 768, "relu", 30720, 3 << 30)}
 
 
 @pytest.mark.parametrize("which", ["forward", "backward"])
-def test_moe_grouped_products_compile(one_chip, monkeypatch, which):
+@pytest.mark.parametrize("shape", list(MOE_SHAPES))
+def test_moe_grouped_products_compile(one_chip, monkeypatch, shape, which):
     # the kernels interpret themselves wherever the backend is the CPU,
     # as it is here: steer that in the test, the program has no option
     monkeypatch.setattr(pm, "_should_interpret", lambda: False)
-    window = moe_ops.window_rows(MOE_N, MOE_K, MOE_HELD, 512, MOE_TILE)
-    assert window == 6400
-    x = ((MOE_N, MOE_E), jnp.bfloat16)
-    pairs = ((MOE_N * MOE_K,), jnp.float32)
-    experts = ((MOE_N * MOE_K,), jnp.int32)
-    up = ((MOE_HELD, MOE_F, MOE_E), jnp.bfloat16)
-    down = ((MOE_HELD, MOE_E, MOE_F), jnp.bfloat16)
+    n, k, held, experts_, e, f, act, rows, most = MOE_SHAPES[shape]
+    window = moe_ops.window_rows(n, k, held, experts_, MOE_TILE)
+    assert window == rows
+    x = ((n, e), jnp.bfloat16)
+    pairs = ((n * k,), jnp.float32)
+    experts = ((n * k,), jnp.int32)
+    up = ((held, f, e), jnp.bfloat16)
+    down = ((held, e, f), jnp.bfloat16)
 
     def run(x, pw, pair_expert, wg, wu, wd):
-        plan = moe_ops.plan_windows(pair_expert, MOE_HELD, window)
+        plan = moe_ops.plan_windows(pair_expert, held, window)
         return moe_ops.held_experts(x, pw, plan, wg, wu, wd, MOE_TILE,
-                                    MOE_K, window, True)
+                                    k, window, True, act)
     if which == "forward":
         compiled = _compile(run, one_chip, x, pairs, experts, up, up, down,
                             kernels=["moe_gmm_fwd"])
@@ -166,10 +174,10 @@ def test_moe_grouped_products_compile(one_chip, monkeypatch, which):
                 x, pw, pair_expert, wg, wu, wd), x, pw, wg, wu, wd)[1](dy)
         compiled = _compile(
             grads, one_chip, x, pairs, experts, up, up, down,
-            ((MOE_N, MOE_E), jnp.float32),
+            ((n, e), jnp.float32),
             kernels=["moe_gmm_fwd", "moe_gmm_bwd", "moe_gmm_dw"])
     # a window's buffers, not tokens x top_k rows of anything
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert compiled.memory_analysis().temp_size_in_bytes < most
 
 
 def test_gated_delta_net_backward_holds_less_than_the_scans_did(
@@ -342,3 +350,27 @@ def test_input_transform_is_two_matmuls_and_no_loop(one_chip, record, crop):
         # transform's float32 OUTPUT has the record's shape)
         assert "f32[%d,%d,%d,%d]" % ((n,) + record) not in text
     assert compiled.cost_analysis()["bytes accessed"] < 4e9
+
+
+# the window layers of the window/global hybrid LM: 28 query heads over 4
+# key-value heads of 128 at S=16,384, a window of 4,096: the band's grids
+# (9 key blocks a query block of the 32, 9 query blocks a key block)
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_flash_window_kernels_compile(one_chip, which):
+    q = ((2, 28, 16384, 128), jnp.bfloat16)
+    kv = ((2, 4, 16384, 128), jnp.bfloat16)
+    scale, window = 128 ** -0.5, 4096
+    if which == "forward":
+        compiled = _compile(lambda q, k, v: pa._flash_forward(
+            q, k, v, True, scale, 512, 512, False, window),
+            one_chip, q, kv, kv, kernels=["flash_swa_fwd"])
+        assert "flash_fwd" not in compiled.as_text()
+        return
+    lse = jax.eval_shape(
+        lambda q, k: pa._flash_forward(q, k, k, True, scale, 512, 512, True,
+                                       window),
+        jax.ShapeDtypeStruct(*q), jax.ShapeDtypeStruct(*kv))[1]
+    _compile(lambda q, k, v, o, lse, g: pa._flash_backward(
+        q, k, v, o, lse, g, True, scale, 512, 512, False, window),
+        one_chip, q, kv, kv, q, (lse.shape, lse.dtype), q,
+        kernels=["flash_swa_dq", "flash_swa_dkv"])
