@@ -1226,8 +1226,13 @@ def _add_perf_flags(p, scan=False):
                    help="rematerialization policy for the train trace: "
                         "none (store everything), dots (checkpoint_dots "
                         "— keep matmul outputs, recompute elementwise), "
-                        "full (recompute whole segments). Default: "
-                        "SPARKNET_REMAT env var, else none")
+                        "full (recompute whole segments). Under dots "
+                        "and full the outputs of a segment's pallas "
+                        "kernels are kept, not computed a second time: "
+                        "a flash pass's output and logsumexp, one "
+                        "attention output a layer more in memory; the "
+                        "delta rule's output, states and inverses. "
+                        "Default: SPARKNET_REMAT env var, else none")
     if scan:
         p.add_argument("--scan", choices=("auto", "on", "off"),
                        default=None,

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from ..proto.message import Message
 from . import fillers as F
 from .registry import get as get_layer, V1_TYPE_MAP
+from .remat import KERNEL_OUT
 
 # import for registration side effects
 from .. import ops as _ops  # noqa: F401
@@ -64,11 +65,19 @@ def _checkpointed(fn, pol):
     """Wrap fn in jax.checkpoint under the named remat policy: "full"
     recomputes everything in the backward, "dots" saves matmul/conv
     outputs and recomputes the cheap elementwise tails (the standard
-    memory/FLOPs middle ground for transformer blocks)."""
+    memory/FLOPs middle ground for transformer blocks). Under both, what a
+    kernel's wrapper named `KERNEL_OUT` (graph/remat.py: a flash pass's
+    output and logsumexp, the delta rule's output, states and inverses)
+    is kept, so a pallas kernel's forward runs once, not a second time
+    for its own backward: one attention output a layer (and 4 bytes a
+    row a head) stays live across the whole backward pass, memory that
+    plain jax.checkpoint(fn) did not hold."""
+    kept = jax.checkpoint_policies.save_only_these_names(KERNEL_OUT)
     if pol == "dots":
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.checkpoint_dots)
-    return jax.checkpoint(fn)
+        # a pallas call is no dot: without the name "dots" runs it twice
+        kept = jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.checkpoint_dots, kept)
+    return jax.checkpoint(fn, policy=kept)
 
 
 def upgrade_v1(net_param):
@@ -691,13 +700,18 @@ class CompiledNet:
           train=True, runs of layers sharing a "prefix/" name (the
           zoo's per-block convention) execute under jax.checkpoint with
           the named policy: the backward recomputes their internals
-          instead of saving every intermediate activation. Segment-
-          INTERNAL blobs are then absent from the returned dict (only
-          segment boundaries, loss tops and net outputs survive).
+          instead of saving every intermediate activation — except the
+          outputs of a segment's pallas kernels, which are kept
+          (_checkpointed: a flash pass's output and logsumexp, the delta
+          rule's output, states and inverses), so a kernel's forward
+          runs once. Segment-INTERNAL blobs are then absent from the
+          returned dict (only segment boundaries, loss tops and net
+          outputs survive).
         * scan (SPARKNET_SCAN: auto|on|off) — structurally identical
           block chains (_scan_runs) execute as one lax.scan over
           stacked params: one traced body instead of depth copies.
-          Block-internal blobs are absent; remat checkpoints the body.
+          Block-internal blobs are absent; remat checkpoints the body
+          (what it keeps is stacked over the iterations).
         * epilogue (SPARKNET_EPILOGUE: auto|on|off) — conv bias+ReLU
           (+LRN) tails run as one fused pallas pass (_active_epilogue).
 

@@ -159,7 +159,7 @@ class Attention(Layer):
             if self.causal:
                 live, half = band_blocks(s, self.window)
             o = flash_attention(q, k, v, self.causal, None, 512, 512,
-                                self.window)
+                                self.window, self.lp.name)
         else:
             path = "dense"
             reason = "flash is not set" if not self.flash \

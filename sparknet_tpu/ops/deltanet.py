@@ -279,7 +279,8 @@ class GatedDeltaNet(Layer):
                 # here and not at the top: a process without such a layer
                 # never imports pallas (1.4 s of every cell's set-up, PR 29)
                 from .pallas_deltanet import chunk_rule
-                o, _ = chunk_rule(q, k, v, beta, g, chunk=self.chunk)
+                o, _ = chunk_rule(q, k, v, beta, g, chunk=self.chunk,
+                                  layer=self.lp.name)
         with jax.named_scope("gdn_gate_norm"):
             o = rms_norm(o, norm, self.eps, zero_centered=False)
             o = o * jax.nn.silu(z.reshape(b, t, hv, dv).astype(jnp.float32))

@@ -8,7 +8,7 @@ blocks streamed through VMEM, with the MXU doing the two matmuls per block.
 K/V arrive in (block_k, D) tiles via a third, sequential grid dimension, so
 VMEM usage is O(block) regardless of S.
 
-Training memory is O(block) too: the forward additionally emits the
+Training memory is O(block) in VMEM: the forward additionally emits the
 per-row logsumexp (LSE, lane-replicated like jax's own TPU kernel), and
 the backward re-derives each probability block as P = exp(S - LSE) inside
 two pallas kernels — dQ with K/V streamed innermost, dK/dV with Q/dO
@@ -20,6 +20,17 @@ streamed innermost (the FlashAttention-2 recurrences):
     dS_ij   = P_ij * (dO_i V_j^T - delta_i)
     dQ_i   += scale * dS_ij K_j
     dK_j   += scale * dS_ij^T Q_i
+
+In HBM the backward reads q, k, v and two results of the forward: the
+output O (the size of q) and ONE column of the LSE (4 bytes a row a head,
+broadcast back to the kernels' 128 lanes where the backward starts). Those
+two go through `graph/remat.py:keep`: under `--remat full` or `dots` a
+block recomputes q, k and v in the backward pass and KEEPS O and the LSE,
+so the forward kernel runs once and not again for its own backward. That
+is memory plain `jax.checkpoint` did not hold: at 2 x 16,384 tokens and 28
+heads of 128 in bfloat16 235 MB + 3.7 MB a layer, live from the layer's
+forward to its backward; one `remat.kept` record each in the ring of
+obs/trace.py a trace of the forward rule.
 
 A sliding window (`window` > 0, a trace-time constant, with `causal`): key
 j is visible to query i iff i - window < j <= i. The window form's grids
@@ -45,6 +56,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..graph.remat import keep
 from .pallas_lrn import _should_interpret
 
 NEG_INF = -1e30
@@ -433,16 +445,17 @@ def _flash_backward(q, k, v, o, lse, g, causal, scale, block_q, block_k,
             dv.reshape(b, hkv, s, d))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
-                    block_k=512, window=0):
+                    block_k=512, window=0, layer=None):
     """Flash attention (B, H, S, D) -> (B, H, S, D); exact, O(block) VMEM
     in both forward and backward. scale defaults to 1/sqrt(D). k and v may
     have fewer heads, (B, Hkv, S, D) with H a multiple of Hkv: query head
     h reads key-value head h // (H / Hkv) in place, and the backward sums
     a shared head's gradient over its group inside the kernel. `window`
     (with `causal`): query i sees keys i - window < j <= i, and the blocks
-    outside that band are not visited.
+    outside that band are not visited. `layer` is the caller's name in the
+    `remat.kept` records of what the backward keeps (the header).
 
     Default blocks are 512x512: the grid's K/V dimension is sequential,
     so small blocks are dispatch-latency-bound — at S=32k, 512x512 runs
@@ -455,16 +468,20 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=512,
     return out
 
 
-def _fwd(q, k, v, causal, scale, block_q, block_k, window):
+def _fwd(q, k, v, causal, scale, block_q, block_k, window, layer):
     scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
                               _should_interpret(), window)
+    out = keep(out, layer, "o")
+    # one column of the 128 equal lanes: what lives until the backward
+    lse = keep(lse[:, :, 0], layer, "lse")
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, scale, block_q, block_k, window, res, g):
+def _bwd(causal, scale, block_q, block_k, window, layer, res, g):
     q, k, v, o, lse = res
     scale = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
+    lse = jnp.broadcast_to(lse[:, :, None], lse.shape + (LANES,))
     return _flash_backward(q, k, v, o, lse, g, causal, scale, block_q,
                            block_k, _should_interpret(), window)
 

@@ -45,7 +45,12 @@ gradients of q, k, v, beta and G. With dR = T^T dU the triangular system's
 gradient is dA = -dR U^T. Products that share a right-hand side are one
 product of stacked rows, products that are summed one longer contraction:
 a product costs about the same 0.14 us whatever its rows (chip runs, PR
-30), so their number is what is kept small.
+30), so their number is what is kept small. The forward's four results
+(o, the state after, the groups' states, T) go through
+`graph/remat.py:keep`: a block under `--remat full` or `dots` keeps what
+its backward reads of them (268 + 67 + 134 MB a layer at that shape, o in
+float32) and the forward kernel runs once, not again for its own backward;
+one `remat.kept` record each in the ring of obs/trace.py.
 
 Per-token scalars (beta, G and their gradients) travel as ROWS, a
 (chunks, r C) table a key head that stays in VMEM across the chunk axis; a
@@ -64,6 +69,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..graph.remat import keep
 from .deltanet import L2_EPS
 from .pallas_lrn import _should_interpret
 
@@ -478,19 +484,25 @@ def _backward(q, k, v, beta, g, starts, tinv, do, ds_end, chunk, group,
     )(q, k, v, beta, g, starts, tinv, do, ds_end)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _chunks(q, k, v, beta, g, s0, chunk, group):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _chunks(q, k, v, beta, g, s0, chunk, group, layer):
     return tuple(_forward(q, k, v, beta, g, s0, chunk, group,
                           _should_interpret(), residuals=False))
 
 
-def _chunks_fwd(q, k, v, beta, g, s0, chunk, group):
-    o, s_end, starts, tinv = _forward(q, k, v, beta, g, s0, chunk, group,
-                                      _should_interpret())
+def _chunks_fwd(q, k, v, beta, g, s0, chunk, group, layer):
+    # what the backward pass reads of the forward kernel's results goes
+    # through graph/remat.py:keep, so a block under remat keeps them and
+    # the kernel does not run again for its own backward
+    results = _forward(q, k, v, beta, g, s0, chunk, group,
+                       _should_interpret())
+    o, s_end, starts, tinv = [
+        keep(a, layer, name)
+        for a, name in zip(results, ("o", "s_end", "starts", "tinv"))]
     return (o, s_end), (q, k, v, beta, g, starts, tinv)
 
 
-def _chunks_bwd(chunk, group, res, cot):
+def _chunks_bwd(chunk, group, layer, res, cot):
     q, k, v, beta, g, starts, tinv = res
     do, ds_end = cot
     return _backward(q, k, v, beta, g, starts, tinv, do, ds_end, chunk,
@@ -500,14 +512,15 @@ def _chunks_bwd(chunk, group, res, cot):
 _chunks.defvjp(_chunks_fwd, _chunks_bwd)
 
 
-def chunk_rule(q, k, v, beta, g, state=None, chunk=64):
+def chunk_rule(q, k, v, beta, g, state=None, chunk=64, layer=None):
     """The gated delta rule through the kernel pair. q, k (B, T, Hk, Dk)
     RAW: the kernels normalise them as ops/deltanet.py's `l2_normalize`
     does and scale q by Dk^-0.5; v (B, T, Hv, Dv) with Hv a multiple of Hk (value head j
     reads key head j // (Hv / Hk)), beta and g (B, T, Hv), `state` (B, Hv,
     Dk, Dv) or None for zeros -> (o (B, T, Hv, Dv) float32, the state
     after). Dk and Dv are multiples of the lane width. T is padded to whole
-    groups of chunks with tokens that leave the state alone."""
+    groups of chunks with tokens that leave the state alone. `layer` is
+    the caller's name in the `remat.kept` records (the header)."""
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2:]
     r = hv // hk
@@ -530,5 +543,5 @@ def chunk_rule(q, k, v, beta, g, state=None, chunk=64):
                        v.reshape(b, t + pad, hv * dv),
                        rows(beta).reshape(table),
                        jnp.cumsum(rows(g), axis=-1).reshape(table),
-                       state.astype(_F32), chunk, group)
+                       state.astype(_F32), chunk, group, layer)
     return o.reshape(b, t + pad, hv, dv)[:, :t], s_end

@@ -269,8 +269,14 @@ class Solver:
 
     def set_remat(self, policy):
         """Set the remat policy (the --remat CLI knob): "none", "dots"
-        (save matmul outputs, recompute elementwise tails), or "full".
-        Overrides the SPARKNET_REMAT env-var fallback."""
+        (save matmul outputs, recompute elementwise tails), or "full"
+        (recompute a block in the backward pass). Under "dots" and "full"
+        the outputs of a block's pallas kernels are kept, so a kernel's
+        forward runs once (graph/remat.py): a flash pass's output, the
+        size of q, and 4 bytes a row a head of logsumexp; the delta
+        rule's output, group states and inverses — memory held from a
+        layer's forward to its backward that a bare jax.checkpoint did not
+        hold. Overrides the SPARKNET_REMAT env-var fallback."""
         from ..graph.compiler import REMAT_POLICIES
         if policy not in REMAT_POLICIES:
             raise ValueError(

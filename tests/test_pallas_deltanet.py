@@ -181,6 +181,8 @@ batch = {"data": np.zeros((2, 3, 227, 227), np.float32),
          "label": np.zeros((2,), np.int32)}
 assert len(solver.op_scopes(batch)) > 50        # one step, traced and compiled
 print("CNN", pallas())
+# the name the remat policies keep is the compiler's too (graph/remat.py)
+print("NAME", "sparknet_tpu.graph.remat" in sys.modules)
 
 from sparknet_tpu.graph.registry import get
 from sparknet_tpu.models import dsl
@@ -213,15 +215,18 @@ def test_a_process_that_steps_caffenet_never_imports_pallas():
     compiling one step of it leaves pallas out of `sys.modules`, and so
     does tracing the no-drop MoE where its grouped product is XLA's (off
     the TPU, ops/pallas_moe.py is never imported); tracing a GatedDeltaNet
-    at head size 128 brings it in."""
+    at head size 128 brings it in. The module that holds the name a remat
+    policy keeps (graph/remat.py) is imported by the compiler, so by the
+    CNN process too, and is core jax alone."""
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run([sys.executable, "-c", _GUARD], cwd=REPO, env=env,
                          capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stderr[-3000:]
     lines = dict(ln.split(" ", 1) for ln in res.stdout.splitlines()
-                 if ln.startswith(("CNN ", "MOE ", "GDN ")))
+                 if ln.startswith(("CNN ", "NAME ", "MOE ", "GDN ")))
     assert lines["CNN"] == "[]", lines["CNN"]
+    assert lines["NAME"] == "True"
     assert lines["MOE"] == "[]", lines["MOE"]
     assert "jax.experimental.pallas" in lines["GDN"]
     assert "jax.experimental.pallas.tpu" in lines["GDN"]

@@ -158,3 +158,176 @@ def test_remat_no_stale_pre_segment_blob(monkeypatch):
     # it must be ABSENT, never the stale pre-segment value
     assert "x" in blobs_off
     assert "x" not in blobs_on
+
+
+# --- under remat a kernel's forward runs once ------------------------------
+# A kernel's wrapper names what its backward reads of its forward's results
+# (graph/remat.py:keep) and every policy of compiler._checkpointed saves that
+# name; the parent's jax.checkpoint(fn) ran the forward kernel a second time.
+# CPU, interpret mode.
+
+from sparknet_tpu.graph import compiler                     # noqa: E402
+from sparknet_tpu.obs.trace import default_tracer           # noqa: E402
+
+S, E = 256, 64
+
+
+def _flash_case(heads, kv_heads, window):
+    """x -> x + out(flash(q(x), k(x), v(x))): (block, x, weights, the
+    forward kernel's name, what is kept: array -> (shape, dtype))."""
+    from sparknet_tpu.ops.pallas_attention import flash_attention
+    d = E // heads
+    rs = np.random.RandomState(heads + window)
+    x = jnp.asarray(rs.randn(1, S, E), jnp.float32)
+    ws = [jnp.asarray(0.2 * rs.randn(E, n * d), jnp.float32)
+          for n in (heads, kv_heads, kv_heads)]
+    ws.append(jnp.asarray(0.2 * rs.randn(E, E), jnp.float32))
+
+    def block(x, wq, wk, wv, wo):
+        q, k, v = [jnp.moveaxis((x @ w).reshape(1, S, -1, d), 1, 2)
+                   for w in (wq, wk, wv)]
+        o = flash_attention(q, k, v, True, None, 64, 64, window, "blk/attn")
+        return x + jnp.moveaxis(o, 2, 1).reshape(1, S, E) @ wo
+    kept = {"o": ((1, heads, S, d), "float32"),
+            "lse": ((heads, S), "float32")}     # one column, not 128 lanes
+    return block, x, ws, "flash_swa_fwd" if window else "flash_fwd", kept
+
+
+def _delta_case():
+    """The delta rule's kernel pair at head size 128: one key head, two
+    value heads, two chunks of 64."""
+    from sparknet_tpu.ops.pallas_deltanet import chunk_rule
+    t, e, d, hv = 128, 32, 128, 2
+    rs = np.random.RandomState(3)
+    x = jnp.asarray(rs.randn(1, t, e), jnp.float32)
+    ws = [jnp.asarray(0.2 * rs.randn(e, n), jnp.float32)
+          for n in (d, d, hv * d, hv, hv)]
+    ws.append(jnp.asarray(0.2 * rs.randn(hv * d, e), jnp.float32))
+
+    def block(x, wq, wk, wv, wb, wg, wo):
+        o, _ = chunk_rule((x @ wq).reshape(1, t, 1, d),
+                          (x @ wk).reshape(1, t, 1, d),
+                          (x @ wv).reshape(1, t, hv, d),
+                          jax.nn.sigmoid(x @ wb),
+                          -jax.nn.softplus(x @ wg), layer="blk/mixer")
+        return x + o.reshape(1, t, hv * d) @ wo
+    # the state after is named too, and nothing in this backward reads it
+    kept = {"o": ((1, t, hv * d), "float32"),
+            "s_end": ((1, hv, d, d), "float32"),
+            "starts": ((1, hv, 1, d, d), "float32"),
+            "tinv": ((1, 1, 2, 64, hv * 64), "float32")}
+    return block, x, ws, "gdn_chunk_fwd", kept
+
+
+_KERNEL_CASES = {"causal": lambda: _flash_case(2, 2, 0),
+                 "window": lambda: _flash_case(2, 2, 96),
+                 "gqa": lambda: _flash_case(4, 2, 0),
+                 "delta": _delta_case}
+
+
+def _parents_checkpoint(fn, pol):
+    """compiler._checkpointed as it was before the name."""
+    if pol == "dots":
+        return jax.checkpoint(
+            fn, policy=jax.checkpoint_policies.checkpoint_dots)
+    return jax.checkpoint(fn)
+
+
+def _assert_bit_equal(got, want):
+    got, want = (jax.tree_util.tree_leaves(t) for t in (got, want))
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        assert np.abs(np.asarray(b)).max() > 0
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("scanned", [False, True], ids=["block", "scan"])
+@pytest.mark.parametrize("pol", ["full", "dots"])
+@pytest.mark.parametrize("case", list(_KERNEL_CASES))
+def test_a_kernels_forward_runs_once_under_remat(case, pol, scanned):
+    """The gradient's jaxpr of a checkpointed block holds ONE forward
+    kernel call where the parent's holds two, alone and as the body of a
+    scan over two like blocks, and loss and gradients are the parent's
+    bit for bit."""
+    block, x, ws, kernel, _ = _KERNEL_CASES[case]()
+
+    def step(checkpointed):
+        body = checkpointed(block, pol)
+
+        def loss(x, *ws):
+            if not scanned:
+                return jnp.sum(body(x, *ws) ** 2)
+            stacked = [jnp.stack([w, 0.5 * w]) for w in ws]
+            y, _ = jax.lax.scan(lambda c, w: (body(c, *w), None), x, stacked)
+            return jnp.sum(y ** 2)
+        return jax.value_and_grad(loss, tuple(range(1, 1 + len(ws))))
+
+    mine, parents = step(compiler._checkpointed), step(_parents_checkpoint)
+
+    def forward_calls(fn):
+        return str(jax.make_jaxpr(fn)(x, *ws)).count(f"name={kernel}\n")
+    assert (forward_calls(mine), forward_calls(parents)) == (1, 2)
+    _assert_bit_equal(jax.jit(mine)(x, *ws), jax.jit(parents)(x, *ws))
+
+
+@pytest.mark.parametrize("case", list(_KERNEL_CASES))
+def test_what_a_block_keeps_is_the_named_arrays_and_its_inputs(case):
+    """`saved_residuals` of a block under "full": the segment's inputs, the
+    arrays the wrapper named that the backward reads, and nothing else from
+    inside the wrapper — no lane-replicated logsumexp; and one `remat.kept`
+    record a named array in the ring, with its bytes."""
+    block, x, ws, _, kept = _KERNEL_CASES[case]()
+    ring = default_tracer()
+    before = len(ring.spans("remat.kept"))
+    # what jax.ad_checkpoint.print_saved_residuals prints, as a list
+    from jax._src.ad_checkpoint import saved_residuals
+    saved = saved_residuals(compiler._checkpointed(block, "full"), x, *ws)
+    records = ring.spans("remat.kept")[before:]
+    assert [(r["array"], r["shape"], r["dtype"]) for r in records] == [
+        (name, *kept[name]) for name in kept]
+    for r in records:
+        assert r["layer"] in ("blk/attn", "blk/mixer")
+        assert r["bytes"] == 4 * int(np.prod(r["shape"]))
+
+    inside = [(tuple(a.shape), str(a.dtype)) for a, why in saved
+              if "from the argument" not in why]
+    assert sum("from the argument" in why for _, why in saved) == 1 + len(ws)
+    assert sorted(inside) == sorted(
+        kept[name] for name in kept if name != "s_end")
+    if case != "delta":
+        assert not [s for s, _ in inside if s[-1] == 128]
+        assert sum(f"named '{compiler.KERNEL_OUT}'" in why
+                   for _, why in saved) >= 1
+
+
+def _flash_lm(num_layers=2):
+    return zoo.transformer_lm(vocab_size=48, seq_len=128, batch_size=1,
+                              d_model=32, num_layers=num_layers,
+                              num_heads=2, flash=True)
+
+
+@pytest.mark.parametrize("scan", ["on", "off"])
+@pytest.mark.parametrize("pol", ["full", "dots"])
+def test_compiled_net_runs_each_flash_forward_once(monkeypatch, pol, scan):
+    """Through CompiledNet.apply, as a solver takes it: two flash blocks as
+    remat segments (scan off: two forward calls where the parent's has
+    four) and as one checkpointed scan body (one where it has two); loss
+    and gradients the parent's bit for bit."""
+    monkeypatch.setenv("SPARKNET_SCAN", scan)
+    net = CompiledNet(_flash_lm(), TRAIN)
+    net.remat = pol
+    params, state = net.init(jax.random.PRNGKey(0))
+    toks = np.random.RandomState(0).randint(0, 48, (1, 129))
+    batch = {"data": toks[:, :-1], "label": toks[:, 1:]}
+
+    def read():
+        # a function of its own a reading: jax keeps a traced one
+        step = jax.value_and_grad(lambda p: net.loss_fn(
+            p, state, batch, rng=jax.random.PRNGKey(7))[0])
+        calls = str(jax.make_jaxpr(step)(params)).count("name=flash_fwd\n")
+        return calls, jax.jit(step)(params)
+    mine, got = read()
+    monkeypatch.setattr(compiler, "_checkpointed", _parents_checkpoint)
+    parents, want = read()
+    assert (mine, parents) == ((1, 2) if scan == "on" else (2, 4))
+    _assert_bit_equal(got, want)
