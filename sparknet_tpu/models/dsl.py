@@ -3,8 +3,8 @@
 The capability of the reference's Scala DSL (Layers.scala:18-137 — RDDLayer,
 ConvolutionLayer, PoolingLayer, InnerProductLayer, ReLULayer, SoftmaxWithLoss,
 NetParam), extended with the builders the bigger nets need (LRN, Dropout,
-Concat, Accuracy, BatchNorm, Eltwise, Attention, GatedDeltaNet, RMSNorm,
-MoE). Each returns a proto
+Concat, Accuracy, BatchNorm, Eltwise, Attention, GatedDeltaNet, ShortConv,
+RMSNorm, MoE). Each returns a proto
 Message, so DSL output and parsed prototxt are the same IR.
 """
 
@@ -83,6 +83,10 @@ def ReLULayer(name, bottoms, tops=None):
     return _base("ReLU", name, bottoms, tops=tops)
 
 
+def SigmoidLayer(name, bottoms, tops=None):
+    return _base("Sigmoid", name, bottoms, tops=tops)
+
+
 def SoftmaxWithLoss(name, bottoms, axis=None):
     kw = {}
     if axis is not None:
@@ -137,16 +141,18 @@ def AttentionLayer(name, bottoms, num_heads, head_dim=None, causal=False,
                    ring=False, flash=False, num_kv_heads=None,
                    qk_norm=False, rotary_dim=0, rope_theta=None,
                    output_gate=False, norm_eps=None, weight_filler=None,
-                   param=None, window=None):
+                   param=None, window=None, qk_norm_zero_centered=None):
     """sparknet_tpu extension for the long-context path (see
     parallel.ring_attention, ops.pallas_attention). `num_kv_heads` selects
     the grouped-query form (bias-free q/k/v/out projections; qk_norm,
     rotary_dim, rope_theta, output_gate belong to it). `window` (with
     causal): a sliding window of that many keys, the query's own among
-    them."""
+    them. `qk_norm_zero_centered` False: the plain form of the two norms."""
     ap = dict(num_heads=num_heads, causal=causal, ring=ring, flash=flash)
     if window:
         ap["window"] = window
+    if qk_norm_zero_centered is not None:
+        ap["qk_norm_zero_centered"] = qk_norm_zero_centered
     if head_dim is not None:
         ap["head_dim"] = head_dim
     if num_kv_heads is not None:
@@ -181,6 +187,20 @@ def GatedDeltaNetLayer(name, bottoms, num_k_heads, num_v_heads, head_k_dim,
                               gated_delta_net_param=gp), param)
 
 
+def ShortConvLayer(name, bottoms, kernel=None, weight_filler=None,
+                   conv_filler=None, param=None):
+    """sparknet_tpu extension: the gated short convolution
+    (ops/shortconv.py); `param` lists its three blobs' multipliers
+    (W_in, conv, W_out)."""
+    sp = {}
+    for key, val in (("kernel", kernel), ("weight_filler", weight_filler),
+                     ("conv_filler", conv_filler)):
+        if val is not None:
+            sp[key] = val
+    return _with_params(_base("ShortConv", name, bottoms,
+                              short_conv_param=sp or None), param)
+
+
 def RMSNormLayer(name, bottoms, tops=None, eps=None, zero_centered=None,
                  param=None):
     """sparknet_tpu extension: last-axis RMS norm, one blob."""
@@ -194,13 +214,13 @@ def RMSNormLayer(name, bottoms, tops=None, eps=None, zero_centered=None,
 
 
 def EmbedLayer(name, bottoms, input_dim, num_output, weight_filler=None,
-               bias_term=None):
+               bias_term=None, param=None):
     ep = dict(input_dim=input_dim, num_output=num_output)
     if weight_filler is not None:
         ep["weight_filler"] = weight_filler
     if bias_term is not None:
         ep["bias_term"] = bias_term
-    return _base("Embed", name, bottoms, embed_param=ep)
+    return _with_params(_base("Embed", name, bottoms, embed_param=ep), param)
 
 
 def PositionalEmbedLayer(name, bottoms, max_positions, num_output,
@@ -217,7 +237,8 @@ def MoELayer(name, bottoms, num_experts, hidden_dim=None,
              aux_loss_weight=None, weight_filler=None, stats=False,
              top_k=None, experts_held=None, first_expert=None,
              shared_hidden_dim=None, norm_topk_prob=None, tile_rows=None,
-             expert_activation=None):
+             expert_activation=None, score_function=None,
+             selection_bias=None, topk_eps=None, routed_scaling_factor=None):
     """sparknet_tpu extension: MoE FFN. The top-1 Switch form:
     aux_loss_weight adds a second top carrying the load-balancing loss
     with that loss_weight; stats=True adds a third (weight-0) diagnostics
@@ -227,7 +248,9 @@ def MoELayer(name, bottoms, num_experts, hidden_dim=None,
     them from `first_expert` on held here, an optional shared expert);
     there stats=True adds one (weight-0) top [share of pairs on held
     experts, largest over mean held load]. `expert_activation` "relu"
-    makes the experts ReLU-gated; a second bottom feeds the router."""
+    makes the experts ReLU-gated; a second bottom feeds the router.
+    `score_function` "sigmoid", `selection_bias`, `topk_eps` and
+    `routed_scaling_factor` are the route's (ops/moe.py)."""
     if top_k is not None:
         mp = dict(num_experts=num_experts, gated_experts=True, top_k=top_k)
         for key, val in (("hidden_dim", hidden_dim),
@@ -237,6 +260,10 @@ def MoELayer(name, bottoms, num_experts, hidden_dim=None,
                          ("norm_topk_prob", norm_topk_prob),
                          ("tile_rows", tile_rows),
                          ("expert_activation", expert_activation),
+                         ("score_function", score_function),
+                         ("selection_bias", selection_bias),
+                         ("topk_eps", topk_eps),
+                         ("routed_scaling_factor", routed_scaling_factor),
                          ("weight_filler", weight_filler)):
             if val is not None:
                 mp[key] = val

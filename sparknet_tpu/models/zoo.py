@@ -5,8 +5,8 @@ caffe/models/bvlc_googlenet), re-expressed with the DSL so the framework is
 self-contained — no prototxt files needed (though stock ones load too).
 """
 
-from .dsl import (GatedDeltaNetLayer, RMSNormLayer,
-                  NetParam, RDDLayer, ConvolutionLayer, PoolingLayer,
+from .dsl import (GatedDeltaNetLayer, RMSNormLayer, ShortConvLayer,
+                  SigmoidLayer, NetParam, RDDLayer, ConvolutionLayer, PoolingLayer,
                   InnerProductLayer, ReLULayer, SoftmaxWithLoss,
                   AccuracyLayer, LRNLayer, DropoutLayer, ConcatLayer,
                   EltwiseLayer, AttentionLayer, EmbedLayer,
@@ -517,6 +517,135 @@ def smallthinker(vocab_size=151936, seq_len=16384, batch_size=2,
         SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
     ]
     return NetParam("SmallThinker", *layers)
+
+
+def lfm2_moe(vocab_size=65536, seq_len=8192, batch_size=3,
+             hidden_size=2048, intermediate_size=7168,
+             moe_intermediate_size=1792, num_hidden_layers=24,
+             layer_types=None, num_dense_layers=2, num_attention_heads=32,
+             num_key_value_heads=8, head_dim=64, rope_theta=1e6,
+             norm_eps=1e-5, conv_L_cache=3, num_experts=32,
+             num_experts_per_tok=4, norm_topk_prob=True,
+             routed_scaling_factor=1.0, use_expert_bias=True,
+             experts_held=None, first_expert=0, flash=True, moe_stats=False):
+    """LFM2-MoE (`model_type` lfm2_moe) as a trainable net: blocks of
+    y = x + Op(RMSNorm(x)), out = y + FF(RMSNorm(y)), plain RMSNorm (w
+    filled with 1). `Op` of a layer whose `layer_types` entry is "conv" is
+    the gated short convolution (ops/shortconv.py: [B | C | u] = W_in h,
+    a causal depthwise conv of `conv_L_cache` taps over B * u, gated by C,
+    W_out; no bias); of a "full_attention" layer grouped-query attention
+    without bias, a plain RMSNorm (a weight of `head_dim`) on every query
+    and key head before rotate-half rotary on the whole head, causal
+    softmax(q k^T / sqrt(head_dim)) v. `FF` of the first `num_dense_layers`
+    layers is W_2 (silu(W_1 x) * W_3 x) at `intermediate_size`, built from
+    three InnerProducts, a Sigmoid and a product (silu(a) = a * sigmoid(a));
+    of the others a no-drop top-k MoE of SiLU-gated experts routed by
+    s = sigmoid(W_r x) in float32: the chosen are the largest of s + b (b
+    the `expert_bias`, a buffer of zeros no gradient trains), their weights
+    the unbiased s divided by (their sum + 1e-6), times
+    `routed_scaling_factor`; no shared expert. After the last block a plain
+    RMSNorm and logits = h E^T with the EMBEDDING'S OWN table (one blob,
+    owned by `tok_embed`, its gradient the sum of both uses); mean
+    cross-entropy per token. Defaults are the published sizes of
+    LFM2-8B-A1B; `layer_types` defaults to its 24 entries (attention at
+    layers 2, 6, 10, 14, 18 and 21, conv elsewhere).
+
+    Departures from the published description, all assumptions where the
+    config is silent: `head_dim` hidden / heads = 64; the tied head; the
+    chunk order B, C, u of the in projection; the load-balancing update of
+    the expert bias and any auxiliary loss are left out.
+
+    One chip's share of an expert-parallel group as in `qwen3_next`:
+    `experts_held` from `first_expert` on, `vocab_size` the held rows,
+    `num_hidden_layers`, `layer_types` and `num_dense_layers` this pipeline
+    stage's.
+
+    Matrices and the embedding are filled gaussian(0.02) (NOT the
+    embedding at 1 as in `smallthinker`: the table is the head's too, and a
+    token's own logit would be 2048 / rms of the residual stream), the conv
+    taps uniform(+-1/sqrt(taps)).
+
+    Layers are named block{i}/ln1 | mixer | res1 | ln2 | ff... | res2 (a
+    dense block's feed-forward ff_gate, ff_up, ff_sig, ff_act, ff_down; a
+    MoE block's moe), so the remat groups are the blocks and the conv
+    blocks that follow one another with a MoE scan as one run."""
+    e = hidden_size
+    if layer_types is None:
+        layer_types = ["full_attention" if i in (2, 6, 10, 14, 18, 21)
+                       else "conv" for i in range(24)]
+    layer_types = list(layer_types)[:num_hidden_layers]
+    if len(layer_types) != num_hidden_layers or \
+            set(layer_types) - {"conv", "full_attention"}:
+        raise ValueError(f"lfm2_moe: layer_types {layer_types} for "
+                         f"{num_hidden_layers} layers")
+    gauss = dict(type="gaussian", std=0.02)
+    keep, nodecay = dict(lr_mult=1, decay_mult=1), dict(lr_mult=1,
+                                                        decay_mult=0)
+    table = dict(name="tok_embed_table", lr_mult=1, decay_mult=1)
+    layers = [
+        RDDLayer("data", [batch_size, seq_len]),
+        RDDLayer("label", [batch_size, seq_len]),
+        EmbedLayer("tok_embed", ["data"], vocab_size, e, weight_filler=gauss,
+                   bias_term=False, param=[table]),
+    ]
+
+    def fc(p, name, bottom, width):
+        return InnerProductLayer(
+            f"{p}/{name}", [f"{p}/{bottom}"], width, weight_filler=gauss,
+            axis=2, bias_term=False, param=[keep])
+    x = "tok_embed"
+    for i, kind in enumerate(layer_types):
+        p = f"block{i}"
+        if kind == "conv":
+            mixer = ShortConvLayer(f"{p}/mixer", [f"{p}/ln1"],
+                                   kernel=conv_L_cache, weight_filler=gauss,
+                                   param=[keep] * 3)
+        else:
+            mixer = AttentionLayer(
+                f"{p}/mixer", [f"{p}/ln1"], num_attention_heads,
+                head_dim=head_dim, causal=True, flash=flash,
+                num_kv_heads=num_key_value_heads, qk_norm=True,
+                qk_norm_zero_centered=False, rotary_dim=head_dim,
+                rope_theta=rope_theta, norm_eps=norm_eps,
+                weight_filler=gauss, param=[keep] * 4 + [nodecay] * 2)
+        layers += [
+            RMSNormLayer(f"{p}/ln1", [x], eps=norm_eps, zero_centered=False,
+                         param=[nodecay]),
+            mixer,
+            EltwiseLayer(f"{p}/res1", [x, f"{p}/mixer"]),
+            RMSNormLayer(f"{p}/ln2", [f"{p}/res1"], eps=norm_eps,
+                         zero_centered=False, param=[nodecay]),
+        ]
+        if i < num_dense_layers:
+            layers += [
+                fc(p, "ff_gate", "ln2", intermediate_size),
+                fc(p, "ff_up", "ln2", intermediate_size),
+                SigmoidLayer(f"{p}/ff_sig", [f"{p}/ff_gate"]),
+                EltwiseLayer(f"{p}/ff_act", [f"{p}/ff_gate", f"{p}/ff_sig",
+                                             f"{p}/ff_up"], operation="PROD"),
+                fc(p, "ff_down", "ff_act", e),
+            ]
+            ff = f"{p}/ff_down"
+        else:
+            layers.append(MoELayer(
+                f"{p}/moe", [f"{p}/ln2"], num_experts,
+                hidden_dim=moe_intermediate_size, top_k=num_experts_per_tok,
+                experts_held=experts_held, first_expert=first_expert,
+                norm_topk_prob=norm_topk_prob, score_function="sigmoid",
+                selection_bias=bool(use_expert_bias), topk_eps=1e-6,
+                routed_scaling_factor=routed_scaling_factor,
+                weight_filler=gauss, stats=moe_stats))
+            ff = f"{p}/moe"
+        layers.append(EltwiseLayer(f"{p}/res2", [f"{p}/res1", ff]))
+        x = f"{p}/res2"
+    layers += [
+        RMSNormLayer("ln_f", [x], eps=norm_eps, zero_centered=False,
+                     param=[nodecay]),
+        InnerProductLayer("lm_head", ["ln_f"], vocab_size, axis=2,
+                          bias_term=False, param=[table]),
+        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
+    ]
+    return NetParam("LFM2MoE", *layers)
 
 
 def transformer_lm_pieces(vocab_size=512, seq_len=256, batch_size=8,

@@ -17,6 +17,7 @@ from . import (  # noqa: F401
     feed,
     attention,
     deltanet,
+    shortconv,
     moe,
     python_layer,
 )

@@ -12,7 +12,10 @@ The grouped-query form (attention_param.num_kv_heads set) is the attention
 of today's language models: separate bias-free projections
   q (H*D, E) — or [q | gate] per head, (H*2D, E), with output_gate —,
   k (Hkv*D, E), v (Hkv*D, E), out (E, H*D), then with qk_norm the two
-  zero-centred RMSNorm weights (D,) of the query and key heads;
+  RMSNorm weights (D,) of the query and key heads, applied to every head
+  before the rotary: zero-centred, y = x / rms(x) * (1 + w) with w filled
+  with 0, or with qk_norm_zero_centered false the plain y = x / rms(x) * w
+  with w filled with 1;
 each key-value head serves H / Hkv query heads; rotary embedding
 (rotate-half convention, positions 0..S-1) on the first rotary_dim
 dimensions of every head, the rest untouched; softmax(q k^T / sqrt(D)) v;
@@ -25,8 +28,12 @@ i - window < j <= i. The flash kernel then runs over the band of key blocks
 alone (`flash_swa_*` in a device trace), the dense path masks the same way.
 Which core a layer took is in the ring of obs/trace.py, one `attn.path`
 record a trace of the layer: `path` = `kernel`, `dense` or `ring`, the
-`reason`, the `window`, and `live_blocks` / `causal_blocks`, the key blocks
-the kernel visits over those of the causal half (equal without a window).
+`reason`, the `window`, the `head_dim`, and `live_blocks` / `causal_blocks`,
+the key blocks the kernel visits over those of the causal half (equal
+without a window). A head narrower than the 128 lanes of a vector register
+(64) goes through the same kernels as a block of its own width: every
+q, k, v, o tile and the accumulator fill half of each lane row, two heads
+are NOT paired into one row, and the `reason` says so.
 """
 
 import jax
@@ -84,6 +91,7 @@ class Attention(Layer):
         self.rope_theta = float(p.rope_theta)
         self.output_gate = bool(p.output_gate)
         self.norm_eps = float(p.norm_eps)
+        self.qk_zero_centered = bool(p.qk_norm_zero_centered)
         self.window = int(p.window)
         if self.window and (not self.causal or self.ring):
             raise ValueError(f"{lp.name}: a window needs causal attention "
@@ -114,8 +122,10 @@ class Attention(Layer):
                       ((kv, self.embed), wf, *mults[2]),
                       ((self.embed, self.inner), wf, *mults[3])]
             if self.qk_norm:
-                shapes += [((self.head_dim,), None, *mults[4]),
-                           ((self.head_dim,), None, *mults[5])]
+                fill = None if self.qk_zero_centered else Message(
+                    "FillerParameter", type="constant", value=1.0)
+                shapes += [((self.head_dim,), fill, *mults[4]),
+                           ((self.head_dim,), fill, *mults[5])]
             return shapes
         mults = _param_mults(self.lp, 4)
         return [
@@ -156,6 +166,10 @@ class Attention(Layer):
             # imports pallas (1.4 s of every cell's set-up, PR 29)
             from .pallas_attention import band_blocks, flash_attention
             path, reason = "kernel", "flash is set and 128 divides S"
+            if q.shape[-1] % 128:
+                reason += (f"; a head of {q.shape[-1]} is a block of its "
+                           "own width, part of a lane row of 128: heads "
+                           "are not paired into a row")
             if self.causal:
                 live, half = band_blocks(s, self.window)
             o = flash_attention(q, k, v, self.causal, None, 512, 512,
@@ -172,7 +186,7 @@ class Attention(Layer):
         now = tracer.now_ns()
         tracer.record("attn.path", now, now, layer=self.lp.name, path=path,
                       reason=reason, window=self.window, live_blocks=live,
-                      causal_blocks=half)
+                      causal_blocks=half, head_dim=int(q.shape[-1]))
         return o
 
     def _apply_gqa(self, params, x):
@@ -188,8 +202,8 @@ class Attention(Layer):
         k = (x @ wk.T).reshape(b, s, hk, d)
         v = (x @ wv.T).reshape(b, s, hk, d)
         if self.qk_norm:
-            q = rms_norm(q, params[4], self.norm_eps)
-            k = rms_norm(k, params[5], self.norm_eps)
+            q = rms_norm(q, params[4], self.norm_eps, self.qk_zero_centered)
+            k = rms_norm(k, params[5], self.norm_eps, self.qk_zero_centered)
         with jax.named_scope("rope"):
             q = rotary(q, self.rotary_dim, self.rope_theta)
             k = rotary(k, self.rotary_dim, self.rope_theta)

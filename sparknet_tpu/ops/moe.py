@@ -7,7 +7,16 @@ Two forms, chosen by moe_param.gated_experts:
   with biases, optional all_to_all expert parallelism: everything below
   the next rule. `top_k = 1` with a `capacity_factor` is this form.
 * True: p = softmax(W_r x) over ALL num_experts in float32; the top_k
-  largest, their weights divided by their sum (norm_topk_prob);
+  largest, their weights divided by their sum (norm_topk_prob). Four
+  fields, unset in the nets that came first, make the route another
+  model's: `score_function` "sigmoid" scores each expert by
+  sigmoid(W_r x) alone; `selection_bias` adds a blob b (num_experts,),
+  zeros that no gradient trains (lr_mult and decay_mult 0), and the top_k
+  are then the largest of p + b while their weights stay the UNBIASED p
+  (the bias picks the experts and does not weigh them); `topk_eps` is
+  added to the chosen weights' sum before it divides them;
+  `routed_scaling_factor` multiplies them. The update that balances the
+  load through b belongs to a solver and is not here.
   routed = sum over the chosen experts THAT THIS LAYER HOLDS of
   p_e W_down,e (act(W_gate,e x) * W_up,e x), no bias anywhere, act SiLU
   or, with moe_param.expert_activation "relu", ReLU (the forward, its
@@ -40,7 +49,9 @@ Two forms, chosen by moe_param.gated_experts:
   row tile `tile_rows`, the number of row tiles a traced value);
   elsewhere XLA's `lax.ragged_dot_general` over the same window. Which
   one is in the ring of obs/trace.py, one `moe.path` record a trace of
-  the layer (`path` = `kernel` or `xla`, with the `reason`). With
+  the layer (`path` = `kernel` or `xla`, with the `reason`, the experts'
+  `activation`, the route's `score` and whether it has a
+  `selection_bias`). With
   shared_hidden_dim > 0 a shared expert sees every token:
   shared = sigmoid(w_s . x) W_down (SiLU(W_gate x) * W_up x), and
   y = routed + shared. Tops: [output] or [output, stats]; stats (weight
@@ -52,6 +63,7 @@ Two forms, chosen by moe_param.gated_experts:
     router (num_experts, E) | w_gate (held, F, E) | w_up (held, F, E)
     | w_down (held, E, F) | then with a shared expert: ws_gate (Fs, E)
     | ws_up (Fs, E) | ws_down (E, Fs) | shared gate (1, E)
+    | then with selection_bias: bias (num_experts,), always the last
   Scopes inside the layer's own: moe_route (softmax, top-k, sort),
   moe_dispatch (gathering a window's rows), moe_experts (the grouped
   products), moe_combine (weighting and scattering back), moe_shared.
@@ -330,6 +342,13 @@ class MoE(Layer):
         self.tile = int(p.tile_rows)
         self.act = str(p.expert_activation)
         self.router_bottom = len(bottom_shapes) > 1
+        self.score = str(p.score_function)
+        self.selection_bias = bool(int(p.selection_bias))
+        self.topk_eps = float(p.topk_eps)
+        self.scaling = float(p.routed_scaling_factor)
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"{lp.name}: score_function {self.score!r}: "
+                             "want softmax or sigmoid")
         if self.act not in ("silu", "relu"):
             raise ValueError(f"{lp.name}: expert_activation {self.act!r}: "
                              "want silu or relu")
@@ -337,6 +356,12 @@ class MoE(Layer):
             raise ValueError(
                 f"{lp.name}: expert_activation and a second bottom for the "
                 "router belong to the no-drop form (moe_param.gated_experts)")
+        if not self.gated and (self.score != "softmax" or self.selection_bias
+                               or self.topk_eps or self.scaling != 1.0):
+            raise ValueError(
+                f"{lp.name}: score_function, selection_bias, topk_eps and "
+                "routed_scaling_factor belong to the no-drop form "
+                "(moe_param.gated_experts)")
         if self.router_bottom and tuple(bottom_shapes[1]) != (b, s, e):
             raise ValueError(
                 f"{lp.name}: the router's bottom {tuple(bottom_shapes[1])} "
@@ -412,6 +437,9 @@ class MoE(Layer):
         if Fs:
             shapes += [((Fs, E), wf, *mults[4]), ((Fs, E), wf, *mults[5]),
                        ((E, Fs), wf, *mults[6]), ((1, E), wf, *mults[7])]
+        if self.selection_bias:
+            # a buffer: zeros, and neither a rate nor a decay moves it
+            shapes.append(((self.num_experts,), None, 0.0, 0.0))
         return shapes
 
     def out_shapes(self):
@@ -505,15 +533,28 @@ class MoE(Layer):
         return tops
 
     # -- the no-drop form ---------------------------------------------------
-    def route(self, xt, router):
+    def route(self, xt, router, bias=None):
         """-> (expert indices (n, k) into all the router's outputs, their
-        weights (n, k) float32)."""
+        weights (n, k) float32). With `bias` (num_experts,) the experts
+        are the top_k of score + bias, the weights their unbiased
+        scores."""
         logits = jnp.dot(xt.astype(jnp.float32),
                          router.astype(jnp.float32).T,
                          precision=lax.Precision.HIGHEST)
-        top, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), self.top_k)
+        score = jax.nn.sigmoid(logits) if self.score == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
+        if bias is None:
+            top, idx = lax.top_k(score, self.top_k)
+        else:
+            _, idx = lax.top_k(score + bias.astype(jnp.float32), self.top_k)
+            top = jnp.take_along_axis(score, idx, axis=-1)
         if self.norm_topk:
-            top = top / jnp.sum(top, -1, keepdims=True)
+            total = jnp.sum(top, -1, keepdims=True)
+            if self.topk_eps:
+                total = total + self.topk_eps
+            top = top / total
+        if self.scaling != 1.0:
+            top = top * self.scaling
         return idx, top
 
     def apply_stateful(self, params, state, bottoms, train, rng):
@@ -523,7 +564,9 @@ class MoE(Layer):
         xt = x.reshape(n, e)
         with jax.named_scope("moe_route"):
             routed = bottoms[1].reshape(n, e) if self.router_bottom else xt
-            idx, top = self.route(routed, params[0])
+            idx, top = self.route(
+                routed, params[0],
+                params[-1] if self.selection_bias else None)
             local = idx.reshape(n * k) - self.first
             pair_expert = jnp.where((local >= 0) & (local < held), local,
                                     held).astype(jnp.int32)
@@ -535,7 +578,8 @@ class MoE(Layer):
         tracer.record("moe.path", now, now, layer=self.lp.name,
                       path="xla" if why_xla else "kernel",
                       reason=why_xla or "backend, widths and tile_rows fit",
-                      activation=self.act)
+                      activation=self.act, score=self.score,
+                      selection_bias=self.selection_bias)
         wg, wu, wd = (w.astype(x.dtype) for w in params[1:4])
         y = held_experts(xt, top.reshape(n * k), plan, wg, wu, wd,
                          self.tile, k, window, why_xla is None, self.act)

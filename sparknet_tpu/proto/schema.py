@@ -236,6 +236,7 @@ MESSAGES = {
         "rms_norm_param": (203, "RMSNormParameter", "opt", None),
         "gated_delta_net_param": (204, "GatedDeltaNetParameter", "opt",
                                   None),
+        "short_conv_param": (205, "ShortConvParameter", "opt", None),
     },
     "TransformationParameter": {
         "scale": (1, "float", "opt", 1.0),
@@ -591,6 +592,10 @@ MESSAGES = {
         # <= i, its own among them; 0 = none. The flash kernel skips the
         # blocks outside the band, the dense path masks the same way.
         "window": (13, "uint32", "opt", 0),
+        # with qk_norm: False makes the two norms the plain form
+        # y = x / rms(x) * w with w filled with 1 (True, the default:
+        # y = x / rms(x) * (1 + w), w filled with 0)
+        "qk_norm_zero_centered": (14, "bool", "opt", True),
     },
     # sparknet_tpu extension: last-axis RMS norm, y = x / rms(x) * (1 + w)
     # (zero_centered, w filled with 0) or * w (w filled with 1).
@@ -609,6 +614,15 @@ MESSAGES = {
         "chunk": (6, "uint32", "opt", 64),
         "norm_eps": (7, "float", "opt", 1e-6),
         "weight_filler": (8, "FillerParameter", "opt", None),
+    },
+    # sparknet_tpu extension: the gated short convolution (ops/shortconv.py),
+    # the mixer of a conv/attention hybrid: in projection to [B | C | u],
+    # a causal depthwise conv of `kernel` taps over B * u, gated by C, out
+    # projection. conv_filler unset: uniform(+-1/sqrt(kernel)).
+    "ShortConvParameter": {
+        "kernel": (1, "uint32", "opt", 3),
+        "weight_filler": (2, "FillerParameter", "opt", None),
+        "conv_filler": (3, "FillerParameter", "opt", None),
     },
     # sparknet_tpu extension: last-axis layer norm for transformer blocks.
     "LayerNormParameter": {
@@ -643,6 +657,17 @@ MESSAGES = {
         # (another normalised view of the residual) and the experts the
         # first.
         "expert_activation": (13, "string", "opt", "silu"),
+        # the no-drop form's route. score_function: "softmax" over all the
+        # router's outputs, or "sigmoid" of each. selection_bias: one more
+        # blob (num_experts,), filled with 0, lr_mult and decay_mult 0 (a
+        # buffer no gradient trains): the top_k are the largest of
+        # score + bias, their weights the UNBIASED scores. topk_eps is
+        # added to the chosen scores' sum before norm_topk_prob divides by
+        # it; routed_scaling_factor multiplies the weights.
+        "score_function": (14, "string", "opt", "softmax"),
+        "selection_bias": (15, "bool", "opt", False),
+        "topk_eps": (16, "float", "opt", 0.0),
+        "routed_scaling_factor": (17, "float", "opt", 1.0),
     },
 }
 

@@ -103,7 +103,9 @@ def _gqa(b, h, hkv, s, d, seed=3):
     return mk(h), mk(hkv), mk(hkv)
 
 
-@pytest.mark.parametrize("h,hkv,d", [(4, 2, 64), (8, 1, 32), (4, 2, 256)])
+# (8, 2, 64): four query heads a key-value head of 64, half a lane row
+@pytest.mark.parametrize("h,hkv,d", [(4, 2, 64), (8, 1, 32), (4, 2, 256),
+                                     (8, 2, 64)])
 def test_flash_shared_kv_heads_forward(h, hkv, d):
     """Query head i reads key-value head i // (H / Hkv) in place."""
     q, k, v = _gqa(2, h, hkv, 256, d)
@@ -114,7 +116,8 @@ def test_flash_shared_kv_heads_forward(h, hkv, d):
                                atol=3e-5, rtol=3e-5)
 
 
-@pytest.mark.parametrize("h,hkv,d", [(4, 2, 64), (4, 2, 256), (6, 3, 32)])
+@pytest.mark.parametrize("h,hkv,d", [(4, 2, 64), (4, 2, 256), (6, 3, 32),
+                                     (8, 2, 64)])
 def test_flash_shared_kv_heads_backward(h, hkv, d):
     """A shared head's gradient is the sum over its group, made inside the
     dK/dV kernel; dk and dv come back with the key-value heads' shape."""
@@ -270,3 +273,26 @@ def test_window_needs_causal():
     q, k, v = _rand_qkv(1, 2, 128, 32)
     with pytest.raises(ValueError, match="causal"):
         flash_attention(q, k, v, False, None, 64, 64, 32)
+
+
+# what the three causal kernels traced to at PR 34 (sha256 of the jaxpr's
+# text, forward and both backward kernels, bfloat16, S 256 in blocks of
+# 128): the accepted cells' heads. A PR that changes the kernels on purpose
+# says so and brings new digests; one that adds a head size does not.
+CAUSAL_JAXPRS = {
+    (4, 2, 128): "e52ee4a1a0c597a6",
+    (4, 1, 256): "693aa00dbe24f150"}
+
+
+@pytest.mark.parametrize("shape", list(CAUSAL_JAXPRS))
+def test_causal_kernels_at_the_accepted_heads_trace_as_before(shape):
+    import hashlib
+    h, hkv, d = shape
+    q = jnp.zeros((1, h, 256, d), jnp.bfloat16)
+    k = jnp.zeros((1, hkv, 256, d), jnp.bfloat16)
+    step = jax.value_and_grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, True, None, 128, 128).astype(jnp.float32)), (0, 1, 2))
+    text = str(jax.make_jaxpr(step)(q, k, k))
+    assert "/root" not in text and "0x" not in text    # no path, no address
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        CAUSAL_JAXPRS[shape]

@@ -433,3 +433,98 @@ def test_second_bottom_and_activation_belong_to_the_no_drop_form():
     assert _layer(lp, [(2, 10, 6)]).act == "silu"
     ap = dsl.AttentionLayer("a", ["x"], 2, causal=True)
     assert not ap.attention_param.has("window")
+
+
+# -- the route's later fields (score_function, selection_bias, topk_eps,
+# routed_scaling_factor): unset, the layer is the one it was --------------
+
+def _route_as_it_was(layer, xt, router):
+    """`MoE.route` as PR 34 left it, word for word: softmax over all the
+    outputs, the top_k of the same numbers, divided by their sum."""
+    from jax import lax
+    logits = jnp.dot(xt.astype(jnp.float32),
+                     router.astype(jnp.float32).T,
+                     precision=lax.Precision.HIGHEST)
+    top, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), layer.top_k)
+    if layer.norm_topk:
+        top = top / jnp.sum(top, -1, keepdims=True)
+    return idx, top
+
+
+# the two accepted LM cells' layers: (tokens, embed, hidden, experts, top_k,
+# held, shared, activation, a second bottom for the router)
+ROUTE_SHAPES = {
+    "qwen3_next": (16384, 2048, 512, 512, 10, 16, 512, None, False),
+    "smallthinker": (32768, 2560, 768, 64, 6, 8, None, "relu", True)}
+
+
+@pytest.mark.parametrize("cell", list(ROUTE_SHAPES))
+def test_route_and_layer_with_the_new_fields_unset_trace_as_before(
+        cell, monkeypatch):
+    """Jaxpr for jaxpr at both accepted cells' shapes (nothing runs): the
+    route, and the whole layer forward and backward, against the route as
+    the parent had it; naming the defaults changes nothing either."""
+    n, e, f, experts, k, held, shared, act, second = ROUTE_SHAPES[cell]
+    bottoms = ["x", "h"] if second else ["x"]
+    shapes = [(2, n // 2, e)] * len(bottoms)
+
+    def build(**more):
+        return _layer(dsl.MoELayer(
+            "moe", bottoms, experts, hidden_dim=f, top_k=k,
+            experts_held=held, shared_hidden_dim=shared,
+            expert_activation=act, **more), shapes)
+    layer = build()
+    assert not layer.lp.moe_param.has("score_function")
+    assert (layer.score, layer.selection_bias, layer.topk_eps,
+            layer.scaling) == ("softmax", False, 0.0, 1.0)
+    xt = jax.ShapeDtypeStruct((n, e), jnp.bfloat16)
+    router = jax.ShapeDtypeStruct((experts, e), jnp.float32)
+    was = str(jax.make_jaxpr(lambda x, r: _route_as_it_was(layer, x, r))(
+        xt, router))
+    assert str(jax.make_jaxpr(layer.route)(xt, router)) == was
+
+    def whole(layer):
+        params = [jax.ShapeDtypeStruct(s[0], jnp.float32)
+                  for s in layer.param_shapes()]
+        xs = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes]
+
+        def loss(params, xs):
+            y = layer.apply(params, xs, True, None)[0]
+            return jnp.sum(y.astype(jnp.float32))
+        return str(jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, xs))
+    now = whole(layer)
+    named = build(score_function="softmax", selection_bias=False,
+                  topk_eps=0.0, routed_scaling_factor=1.0)
+    assert len(named.param_shapes()) == len(layer.param_shapes())
+    assert whole(named) == now
+    older = build()
+    monkeypatch.setattr(
+        older, "route",
+        lambda xt, router, bias=None: _route_as_it_was(older, xt, router))
+    assert whole(older) == now
+
+
+def test_sigmoid_scores_without_a_bias_and_the_scaling_factor():
+    """The score function alone: sigmoid of each logit, top_k of the same
+    numbers, (sum + eps) under them, times the factor; the bias blob is
+    the last, after a shared expert's."""
+    lp = dsl.MoELayer("moe", ["x"], 8, hidden_dim=12, top_k=3, tile_rows=4,
+                      score_function="sigmoid", topk_eps=1e-6,
+                      routed_scaling_factor=1.5, shared_hidden_dim=6,
+                      selection_bias=True)
+    layer = _layer(lp, [(2, 10, 6)])
+    assert [s[0] for s in layer.param_shapes()][-2:] == [(1, 6), (8,)]
+    params = _params(layer, seed=11)
+    x = jnp.asarray(np.random.RandomState(12).randn(20, 6), jnp.float32)
+    idx, top = layer.route(x, params[0])
+    score = np.asarray(jax.nn.sigmoid(x @ params[0].T), np.float64)
+    want = -np.sort(-score, axis=1)[:, :3]
+    np.testing.assert_allclose(
+        np.asarray(top), 1.5 * want / (want.sum(1, keepdims=True) + 1e-6),
+        rtol=1e-5)
+    assert (np.asarray(idx) == np.argsort(-score, axis=1)[:, :3]).all()
+    # in the layer the last blob decides who is chosen
+    y0 = layer.apply(params, [x.reshape(2, 10, 6)], True, None)[0]
+    lifted = params[:-1] + [params[-1].at[5].add(10.0)]
+    y1 = layer.apply(lifted, [x.reshape(2, 10, 6)], True, None)[0]
+    assert np.abs(np.asarray(y1 - y0)).max() > 1e-3

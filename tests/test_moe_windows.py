@@ -147,6 +147,11 @@ def test_window_rows_is_static_and_sized_by_an_even_routing():
     # the SmallThinker cell: 32,768 tokens x top-6, 8 of 64 held: 24,576
     # pairs and a quarter more in ONE window at the default tile
     assert moe_ops.window_rows(32768, 6, 8, 64, 128) == 240 * 128
+    # the LFM2 cell: 24,576 tokens x top-4, 8 of 32 held: the same 24,576
+    # pairs, the same one window of 30,720 rows (batch 4 would want 40,960
+    # and get the cap of 32,768: an even routing's 32,768 pairs fill it)
+    assert moe_ops.window_rows(24576, 4, 8, 32, 128) == 30720
+    assert moe_ops.window_rows(32768, 4, 8, 32, 128) == 32768
     # a share of a toy layer, and the whole of it: never more than
     # WINDOW_TILES tiles, so memory is a window's and not the routing's
     assert moe_ops.window_rows(96, 10, 8, 32, 8) == 38 * 8

@@ -86,12 +86,21 @@ def test_flash_backward_compiles(one_chip, head_dim):
         kernels=["flash_dq", "flash_dkv"])
 
 
-# the grouped-query pass of the hybrid LM: 16 query heads over 2 key-value
-# heads of 256 at S=8192, read in place; the dK/dV kernel sums a group
-def test_flash_gqa_head256_compiles(one_chip):
-    q = ((2, 16, 8192, 256), jnp.bfloat16)
-    kv = ((2, 2, 8192, 256), jnp.bfloat16)
-    scale = 256 ** -0.5
+# the grouped-query pass of the hybrid LMs at S=8192, read in place; the
+# dK/dV kernel sums a group: (batch, query heads, key-value heads, head)
+GQA_SHAPES = {
+    # 16 query heads over 2 key-value heads of 256
+    "head256": (2, 16, 2, 256),
+    # 32 over 8 of 64: every tile half a lane row wide
+    "lfm2_head64": (3, 32, 8, 64)}
+
+
+@pytest.mark.parametrize("shape", list(GQA_SHAPES))
+def test_flash_gqa_compiles(one_chip, shape):
+    b, h, hkv, d = GQA_SHAPES[shape]
+    q = ((b, h, 8192, d), jnp.bfloat16)
+    kv = ((b, hkv, 8192, d), jnp.bfloat16)
+    scale = d ** -0.5
     _compile(lambda q, k, v: pa._flash_forward(
         q, k, v, True, scale, 512, 512, False), one_chip, q, kv, kv,
         kernels=["flash_fwd"])
@@ -143,7 +152,10 @@ MOE_SHAPES = {
     "qwen3_next": (16384, 10, 16, 512, 2048, 512, "silu", 6400, 1 << 30),
     # 2 x 16,384 tokens, top-6 of 64 with 8 held, 2560 x 768, ReLU: an even
     # routing's 24,576 pairs and a quarter more in one window
-    "smallthinker": (32768, 6, 8, 64, 2560, 768, "relu", 30720, 3 << 30)}
+    "smallthinker": (32768, 6, 8, 64, 2560, 768, "relu", 30720, 3 << 30),
+    # 3 x 8,192 tokens, top-4 of 32 with 8 held, 2048 x 1792, SiLU: the
+    # same 24,576 pairs and the same window, 3,072 rows an expert
+    "lfm2_moe": (24576, 4, 8, 32, 2048, 1792, "silu", 30720, 3 << 30)}
 
 
 @pytest.mark.parametrize("which", ["forward", "backward"])
