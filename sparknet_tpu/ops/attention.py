@@ -30,10 +30,12 @@ Which core a layer took is in the ring of obs/trace.py, one `attn.path`
 record a trace of the layer: `path` = `kernel`, `dense` or `ring`, the
 `reason`, the `window`, the `head_dim`, and `live_blocks` / `causal_blocks`,
 the key blocks the kernel visits over those of the causal half (equal
-without a window). A head narrower than the 128 lanes of a vector register
-(64) goes through the same kernels as a block of its own width: every
-q, k, v, o tile and the accumulator fill half of each lane row, two heads
-are NOT paired into one row, and the `reason` says so.
+without a window), with `masked_blocks`, those of the live blocks that an
+edge of the visible region crosses: the kernel masks these alone. A head
+narrower than the 128 lanes of a vector register (64) goes through the
+same kernels as a block of its own width: every q, k, v, o tile fills half
+of each lane row, two heads are NOT paired into one row, and the `reason`
+says so.
 """
 
 import jax
@@ -157,14 +159,15 @@ class Attention(Layer):
         chosen from what the layer sees, and recorded as `attn.path`."""
         s, grp = q.shape[2], q.shape[1] // k.shape[1]
         seq_axis = context.axis("seq")
-        live = half = 0
+        live = half = masked = 0
         if self.ring and seq_axis is not None:
             path, reason = "ring", f"ring over the mesh axis {seq_axis}"
             o = ring_attention(q, k, v, seq_axis, causal=self.causal)
         elif self.flash and s % 128 == 0:
             # here and not at the top: a process without such a layer never
             # imports pallas (1.4 s of every cell's set-up, PR 29)
-            from .pallas_attention import band_blocks, flash_attention
+            from .pallas_attention import (band_blocks, edge_blocks,
+                                           flash_attention)
             path, reason = "kernel", "flash is set and 128 divides S"
             if q.shape[-1] % 128:
                 reason += (f"; a head of {q.shape[-1]} is a block of its "
@@ -172,6 +175,7 @@ class Attention(Layer):
                            "are not paired into a row")
             if self.causal:
                 live, half = band_blocks(s, self.window)
+                masked = edge_blocks(s, self.window)
             o = flash_attention(q, k, v, self.causal, None, 512, 512,
                                 self.window, self.lp.name)
         else:
@@ -186,7 +190,8 @@ class Attention(Layer):
         now = tracer.now_ns()
         tracer.record("attn.path", now, now, layer=self.lp.name, path=path,
                       reason=reason, window=self.window, live_blocks=live,
-                      causal_blocks=half, head_dim=int(q.shape[-1]))
+                      causal_blocks=half, masked_blocks=masked,
+                      head_dim=int(q.shape[-1]))
         return o
 
     def _apply_gqa(self, params, x):

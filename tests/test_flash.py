@@ -94,6 +94,18 @@ def test_flash_fits_blocks_to_indivisible_sequence():
         flash_attention(q, k, v, False)
 
 
+@pytest.mark.parametrize("block,s,want", [
+    (512, 1280, 256),       # 320 divides too, but is no whole lane row
+    (512, 640, 128),
+    (64, 96, 48),           # no lane-aligned divisor: the sublane-aligned one
+    (512, 8192, 512)])
+def test_fit_block_prefers_whole_lane_rows(block, s, want):
+    """A query block is the lane dimension of a score tile: of the
+    divisors of S the lane-aligned come first."""
+    from sparknet_tpu.ops.pallas_attention import _fit_block
+    assert _fit_block(block, s) == want
+
+
 # -- shared key-value heads and head size 256 (the grouped-query form) -------
 
 def _gqa(b, h, hkv, s, d, seed=3):
@@ -207,16 +219,14 @@ def test_window_kernels_match_masked_dense(case):
         np.asarray(_masked_dense(q, k, v, window)), atol=2e-5, rtol=2e-5)
 
 
-def _kernel_calls(fn, *args):
-    """[(name, grid)] of the pallas calls in fn's jaxpr, custom_vjp and
-    all."""
+def _pallas_calls(fn, *args):
+    """The pallas_call equations in fn's jaxpr, custom_vjp and all."""
     found = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                found.append((eqn.params["name"],
-                              tuple(eqn.params["grid_mapping"].grid)))
+                found.append(eqn)
             for val in eqn.params.values():
                 for sub in (val if isinstance(val, (list, tuple))
                             else [val]):
@@ -225,6 +235,12 @@ def _kernel_calls(fn, *args):
                         walk(getattr(inner, "jaxpr", inner))
     walk(jax.make_jaxpr(fn)(*args).jaxpr)
     return found
+
+
+def _kernel_calls(fn, *args):
+    """[(name, grid)] of fn's pallas calls."""
+    return [(eqn.params["name"], tuple(eqn.params["grid_mapping"].grid))
+            for eqn in _pallas_calls(fn, *args)]
 
 
 def test_window_grids_are_the_band_and_dead_blocks_are_skipped():
@@ -275,13 +291,150 @@ def test_window_needs_causal():
         flash_attention(q, k, v, False, None, 64, 64, 32)
 
 
-# what the three causal kernels traced to at PR 34 (sha256 of the jaxpr's
+# -- a tile's mask and fetches, from its block indices alone (PR 37) ---------
+
+# (S, window, block_q, block_k): the window cases, the causal form at equal
+# and unlike blocks, a window smaller than a block, one that ends on a
+# block edge
+TILE_CASES = {
+    **{name: case[:4] for name, case in WINDOW_CASES.items()},
+    "causal": (256, 0, 64, 64),
+    "causal_wide_key_blocks": (256, 0, 32, 64),
+    "causal_wide_query_blocks": (384, 0, 128, 64),
+    "window_under_a_block": (256, 24, 64, 64),
+    "window_of_two_blocks": (512, 128, 64, 64),
+}
+
+
+@pytest.mark.parametrize("case", list(TILE_CASES))
+def test_interior_tiles_have_no_hidden_pair_and_edge_tiles_have_one(case):
+    """The kernels' scalar predicate against the (S, S) mask written out: a
+    live block called interior has every pair visible, every other live
+    block hides a pair, and `edge_blocks` counts the latter."""
+    from sparknet_tpu.ops import pallas_attention as pa
+    s, window, bq, bk = TILE_CASES[case]
+    i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+    seen = (j <= i) & ((i - j < window) if window else True)
+    edges = 0
+    for qi in range(s // bq):
+        first, last = pa._key_band(qi, bq, bk, window, np)
+        for kj in range(s // bk):
+            tile = seen[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk]
+            assert tile.any() == (first <= kj <= last), (qi, kj)
+            if not tile.any():
+                continue
+            inside = bool(pa._interior(qi, kj, bq, bk, window, np))
+            assert inside == bool(tile.all()), (qi, kj)
+            edges += not inside
+    assert edges == pa.edge_blocks(s, window, bq, bk)
+    assert 0 < edges <= pa.band_blocks(s, window, bq, bk)[0]
+
+
+# the cells' shapes in blocks of 512: (masked, live) blocks a head
+@pytest.mark.parametrize("s,window,want", [
+    (16384, 4096, (56, 252)),       # SmallThinker's window layers
+    (16384, 0, (32, 528)),          # its global layer
+    (8192, 0, (16, 136))])          # the Qwen3-Next and LFM2 cells
+def test_masked_blocks_at_the_cells_shapes(s, window, want):
+    from sparknet_tpu.ops.pallas_attention import band_blocks, edge_blocks
+    assert (edge_blocks(s, window), band_blocks(s, window)[0]) == want
+
+
+def _block_specs(fn, *args):
+    """{kernel name: (grid, [in block mapping])} of fn's pallas calls."""
+    maps = {}
+    for eqn in _pallas_calls(fn, *args):
+        gm = eqn.params["grid_mapping"]
+        maps[eqn.params["name"]] = (
+            tuple(gm.grid), list(gm.block_mappings[:gm.num_inputs]))
+    return maps
+
+
+def _index(mapping, *ids):
+    """The block index a block mapping's index map gives at a grid point."""
+    jaxpr = mapping.index_map_jaxpr
+    return tuple(int(x) for x in jax.core.eval_jaxpr(
+        jaxpr.jaxpr, jaxpr.consts, *(np.int32(i) for i in ids)))
+
+
+# (S, block_q, block_k, heads, key-value heads) of the causal form
+DEAD_STEP_CASES = {"square": (256, 64, 64, 2, 1),
+                   "wide_key_blocks": (256, 32, 64, 2, 2),
+                   "wide_query_blocks": (384, 128, 64, 4, 2)}
+
+
+def _streamed(maps, *live_steps):
+    """The operands whose block moves along the innermost grid axis."""
+    one, other = live_steps
+    found = [m for m in maps if _index(m, *one) != _index(m, *other)]
+    assert len(found) >= 2
+    return found
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dq", "flash_dkv"])
+@pytest.mark.parametrize("case", list(DEAD_STEP_CASES))
+def test_a_dead_causal_step_fetches_nothing(case, kernel):
+    """In every dead step of the causal grids the index map of every
+    streamed operand (a block of rows, or of the columns of a transposed
+    operand or a row of statistics) gives the block of the neighbouring
+    live step of the same row (a repeated index is not fetched again),
+    and in every live step the step's own block."""
+    s, bq, bk, h, hkv = DEAD_STEP_CASES[case]
+    grp, nq, nk = h // hkv, s // bq, s // bk
+    q, k, v, cot = _rand_gqa(h, hkv, s, 32)
+    grid, maps = _block_specs(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, True, None, bq, bk) * cot), (0, 1, 2)),
+        q, k, v)[kernel]
+    dead = 0
+    if kernel == "flash_dkv":       # (kv head, key block, group x query block)
+        assert grid == (2 * hkv, nk, grp * nq)
+        streamed = _streamed(maps, (1, 0, 0), (1, 0, 1))    # q, dO, lse, delta
+        assert len(streamed) == 4
+        for j in range(nk):
+            first = (j * bk) // bq          # the diagonal's query block
+            for t in range(grp * nq):
+                head, i = grp + t // nq, max(t % nq, first)
+                dead += t % nq < first
+                for m in streamed:
+                    assert _index(m, 1, j, t) in ((head, i, 0),
+                                                  (head, 0, i)), (j, t)
+        assert dead == grp * sum((j * bk) // bq for j in range(nk)) > 0
+        return
+    assert grid == (2 * h, nq, nk)
+    streamed = _streamed(maps, (3, nq - 1, 0), (3, nq - 1, 1))  # of k, v
+    for i in range(nq):
+        last = (i * bq + bq - 1) // bk      # the diagonal's key block
+        for j in range(nk):
+            dead += j > last
+            for m in streamed:
+                assert _index(m, 3, i, j) in ((3 // grp, min(j, last), 0),
+                                              (3 // grp, 0, min(j, last)))
+    assert dead == sum(nk - 1 - (i * bq + bq - 1) // bk
+                       for i in range(nq)) > 0
+
+
+def test_without_a_mask_no_step_is_dead_and_no_index_is_held():
+    q, k, v, cot = _rand_gqa(2, 1, 256, 32)
+    for name, (grid, maps) in _block_specs(jax.grad(lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v, False, None, 64, 64) * cot),
+            (0, 1, 2)), q, k, v).items():
+        for m in _streamed(maps, (0, 0, 0), (0, 0, 1)):
+            assert [max(_index(m, 0, 0, t)[1:]) for t in range(4)] == [
+                0, 1, 2, 3], name
+
+
+# what the three causal kernels trace to since PR 37 (sha256 of the jaxpr's
 # text, forward and both backward kernels, bfloat16, S 256 in blocks of
-# 128): the accepted cells' heads. A PR that changes the kernels on purpose
-# says so and brings new digests; one that adds a head size does not.
+# 128): the accepted cells' heads, with the LFM2 cell's 64. PR 37 changed
+# the kernels on purpose (score tiles transposed and the statistics rows,
+# dead steps hold their index, edge tiles alone are masked, scale on q's
+# block, delta an operand in place of O) and brought these digests in place
+# of PR 34's. A PR that changes the kernels on purpose says so and brings
+# new ones; one that adds a head size does not.
 CAUSAL_JAXPRS = {
-    (4, 2, 128): "e52ee4a1a0c597a6",
-    (4, 1, 256): "693aa00dbe24f150"}
+    (4, 2, 128): "23bf00bd611a084a",
+    (4, 1, 256): "f3cc40f40db72e79",
+    (4, 1, 64): "30209550953bf34b"}
 
 
 @pytest.mark.parametrize("shape", list(CAUSAL_JAXPRS))
