@@ -33,6 +33,48 @@ TRAIN, TEST = 0, 1
 
 REMAT_POLICIES = ("none", "dots", "full")
 
+#: The part of a step that a layer's device time is counted under, by the
+#: layer's TYPE: what `CompiledNet.parts()` says of every layer and the
+#: solver writes into the ring as `net.parts`, so that whoever adds up a
+#: device trace (benchmark/step_parts.py) needs no model's layer names.
+#: The list is closed: `conv`, `pool`, `lrn`, `norm`, `final_norm` (a norm
+#: read by heads alone), `proj` (an InnerProduct), `head` (one whose top is
+#: a loss layer's first bottom), `embed`, `residual` (an Eltwise SUM), `act`
+#: (activations, dropout, softmax, an Eltwise PROD or MAX), `shape`
+#: (concat, slice, reshape and their kin), `loss` (with accuracy), `other`
+#: (a type not listed here). A mixer or an MoE layer counts under the
+#: scopes it opens inside its own: `attn_proj_in`, `rope`, `attn_core`,
+#: `attn_proj_out` (ops/attention.py); `gdn_proj_in`, `gdn_conv`,
+#: `gdn_scan`, `gdn_gate_norm`, `gdn_proj_out` (ops/deltanet.py);
+#: `shortconv_in`, `shortconv_mix`, `shortconv_out` (ops/shortconv.py);
+#: `moe_route`, `moe_dispatch`, `moe_experts`, `moe_combine`, `moe_shared`,
+#: `moe_glue` (ops/moe.py); what such a layer traces outside them counts
+#: under `attn`, `gdn`, `shortconv`, `moe` and should read nothing (the
+#: Switch form of the MoE opens no scope and reads as `moe`). Beside the
+#: layers a step has `input_transform`, `total_loss` (the weighted sum of
+#: the loss tops), `grad_accum` (iter_size's micro-batches), `update`,
+#: `grad_exchange` (parallel/data_parallel.py: the gradients' all-reduce
+#: with the buckets laid flat and cut up again round it), and
+#: `layer_scan.<first block>` round a scan over blocks (`_apply_scan`).
+PART_OF_TYPE = {
+    "Convolution": "conv", "Deconvolution": "conv", "Im2col": "conv",
+    "Pooling": "pool", "SPP": "pool", "LRN": "lrn",
+    "BatchNorm": "norm", "LayerNorm": "norm", "RMSNorm": "norm",
+    "MVN": "norm", "InnerProduct": "proj", "Embed": "embed",
+    "PositionalEmbed": "embed", "Eltwise": "residual",
+    "Attention": "attn", "GatedDeltaNet": "gdn", "ShortConv": "shortconv",
+    "MoE": "moe",
+    **dict.fromkeys(("ReLU", "PReLU", "Sigmoid", "TanH", "BNLL", "AbsVal",
+                     "Power", "Exp", "Log", "Threshold", "Dropout",
+                     "Softmax"), "act"),
+    **dict.fromkeys(("Concat", "Slice", "Split", "Flatten", "Reshape",
+                     "Tile", "ArgMax", "Reduction", "Silence",
+                     "BatchReindex", "Filter"), "shape"),
+    **dict.fromkeys(("SoftmaxWithLoss", "EuclideanLoss", "HingeLoss",
+                     "SigmoidCrossEntropyLoss", "MultinomialLogisticLoss",
+                     "InfogainLoss", "ContrastiveLoss", "Accuracy"), "loss"),
+}
+
 
 def _env_remat():
     """SPARKNET_REMAT -> policy name. Back-compat: "0"/"1" mean
@@ -322,6 +364,33 @@ class CompiledNet:
 
     def feed_shapes(self):
         return {n: self.blob_shapes[n] for n in self.feed_blobs()}
+
+    def parts(self):
+        """{layer name: part} of every layer that is no feed, from
+        `PART_OF_TYPE` and the two things a type cannot say: which
+        InnerProduct is a head (its top is a loss layer's first bottom),
+        and which norm is the final one (heads alone read it)."""
+        parts, readers = {}, {}
+        for lp, impl, bottoms, tops in self.layers:
+            if getattr(impl, "is_feed", False):
+                continue
+            part = PART_OF_TYPE.get(lp.type, "other")
+            if lp.type == "Eltwise" and impl.op != impl.SUM:
+                part = "act"
+            parts[lp.name] = part
+            for i, b in enumerate(bottoms):
+                readers.setdefault(b, []).append((lp.name, impl, i))
+        tops_of = {lp.name: tops for lp, _, _, tops in self.layers}
+        for name in [n for n, p in parts.items() if p == "proj"]:
+            if any(impl.loss_like and i == 0
+                   for t in tops_of[name] for _, impl, i in readers.get(t, ())):
+                parts[name] = "head"
+        for name in [n for n, p in parts.items() if p == "norm"]:
+            read_by = [r for t in tops_of[name]
+                       for r, _, _ in readers.get(t, ())]
+            if read_by and all(parts[r] == "head" for r in read_by):
+                parts[name] = "final_norm"
+        return parts
 
     # -- init --------------------------------------------------------------
     def init(self, rng):
@@ -647,12 +716,19 @@ class CompiledNet:
         from . import fission
         lo, glen, n = run["lo"], run["glen"], run["n"]
         g0 = self.layers[lo:lo + glen]
+        # the groups' params stacked, the loop, its carry and the buffers
+        # jax stacks for the backward pass (with what graph/remat.py:keep
+        # names) are the scan's own and no layer's: one scope round them
+        # says so in a device trace
+        scope = "layer_scan." + g0[0][0].name.split("/")[0]
         stacked = []
-        for j in range(glen):
-            names = [self.layers[lo + g * glen + j][0].name
-                     for g in range(n)]
-            stacked.append([jnp.stack([params[nm][i] for nm in names])
-                            for i in range(len(params.get(names[0], [])))])
+        with jax.named_scope(scope):
+            for j in range(glen):
+                names = [self.layers[lo + g * glen + j][0].name
+                         for g in range(n)]
+                stacked.append(
+                    [jnp.stack([params[nm][i] for nm in names])
+                     for i in range(len(params.get(names[0], [])))])
         entry, body_out = run["entry"], run["body_out"]
 
         def body(x, ps):
@@ -669,7 +745,8 @@ class CompiledNet:
         if pol != "none":
             body = _checkpointed(body, pol)
         x0 = fission.materialize(blobs[entry])
-        xN, _ = jax.lax.scan(body, x0, stacked)
+        with jax.named_scope(scope):
+            xN, _ = jax.lax.scan(body, x0, stacked)
         blobs[run["out"]] = xN
 
     def _segment_externals(self, lo, hi):
@@ -804,12 +881,14 @@ class CompiledNet:
     def total_loss(self, blobs):
         """Weighted sum of loss tops (reference net.cpp ForwardFromTo loss
         accumulation via loss_weight)."""
-        total = jnp.zeros((), jnp.float32)
-        for lp, impl, bottoms, tops in self.layers:
-            for t, w in zip(tops, self.loss_weights[lp.name]):
-                if w:
-                    total = total + w * jnp.sum(blobs[t]).astype(jnp.float32)
-        return total
+        with jax.named_scope("total_loss"):
+            total = jnp.zeros((), jnp.float32)
+            for lp, impl, bottoms, tops in self.layers:
+                for t, w in zip(tops, self.loss_weights[lp.name]):
+                    if w:
+                        total = total + w * jnp.sum(
+                            blobs[t]).astype(jnp.float32)
+            return total
 
     def loss_fn(self, params, state, batch, rng=None):
         blobs, new_state = self.apply(params, state, batch, rng=rng)

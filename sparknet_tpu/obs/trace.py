@@ -22,6 +22,14 @@ them, so jax's compile events (``compile.trace`` / ``.lower`` /
 ``.backend`` / ``.cache_load``, heard through ``jax.monitoring``) find the
 span open on their thread as parent and land in that span's tracer.
 
+Beside spans the ring holds records that say what a trace of the program
+chose or is made of, each written once where it is decided: ``attn.path``,
+``gdn.path``, ``moe.path``, ``shortconv.path``, ``remat.kept``, ``moe.load``
+and ``net.parts`` — the net's name and, for every layer, the part of a
+step its device time counts under (graph/compiler.py:PART_OF_TYPE has the
+closed list), which is what lets a reader of a device trace add a step up
+without knowing any model's layer names.
+
 ``default_tracer()`` is the process-wide tracer that ``Solver`` and
 ``PrefetchIterator`` use when none is passed; ``default_tracer().spans()``
 reads the ring. "Off" means no profiler session is running: there is no

@@ -36,6 +36,12 @@ narrower than the 128 lanes of a vector register (64) goes through the
 same kernels as a block of its own width: every q, k, v, o tile fills half
 of each lane row, two heads are NOT paired into one row, and the `reason`
 says so.
+
+Everything the layer traces lies under one scope inside its own, in both
+forms, so that a device trace adds up by them: attn_proj_in (the q, k, v
+products with their weights' casts, the reshapes, the head norms, the
+output gate's split, the move to (B, H, S, D)), rope, attn_core (`_core`),
+attn_proj_out (the move back, the gate, the out projection).
 """
 
 import jax
@@ -144,14 +150,22 @@ class Attention(Layer):
         x = bottoms[0]                                   # (B, S, E)
         if self.gqa:
             return [self._apply_gqa(params, x)]
-        wqkv, bqkv, wo, bo = [p.astype(x.dtype) for p in params]
+        # every operation under one of three scopes (`rope` is the fourth,
+        # in the grouped-query form): a device trace adds up by them
+        with jax.named_scope("attn_proj_in"):
+            wqkv, bqkv = [p.astype(x.dtype) for p in params[:2]]
+        with jax.named_scope("attn_proj_out"):
+            wo, bo = [p.astype(x.dtype) for p in params[2:]]
         b, s, _ = x.shape
-        qkv = x @ wqkv.T + bqkv                          # (B, S, 3*H*D)
-        qkv = qkv.reshape(b, s, 3, self.num_heads, self.head_dim)
-        q, k, v = [jnp.moveaxis(qkv[:, :, i], 1, 2) for i in range(3)]
-        o = self._core(q, k, v)
-        o = jnp.moveaxis(o, 2, 1).reshape(b, s, self.inner)
-        return [o @ wo.T + bo]
+        with jax.named_scope("attn_proj_in"):
+            qkv = x @ wqkv.T + bqkv                      # (B, S, 3*H*D)
+            qkv = qkv.reshape(b, s, 3, self.num_heads, self.head_dim)
+            q, k, v = [jnp.moveaxis(qkv[:, :, i], 1, 2) for i in range(3)]
+        with jax.named_scope("attn_core"):
+            o = self._core(q, k, v)
+        with jax.named_scope("attn_proj_out"):
+            o = jnp.moveaxis(o, 2, 1).reshape(b, s, self.inner)
+            return [o @ wo.T + bo]
 
     def _core(self, q, k, v):
         """softmax(q k^T / sqrt(D) + mask) v of q (B, H, S, D) against k, v
@@ -195,27 +209,38 @@ class Attention(Layer):
         return o
 
     def _apply_gqa(self, params, x):
-        wq, wk, wv, wo = [p.astype(x.dtype) for p in params[:4]]
+        # the weights are cast first, as before the scopes: the traced
+        # operations keep their order, so the lowered step keeps its text
+        with jax.named_scope("attn_proj_in"):
+            wq, wk, wv = [p.astype(x.dtype) for p in params[:3]]
+        with jax.named_scope("attn_proj_out"):
+            wo = params[3].astype(x.dtype)
         b, s, _ = x.shape
         h, hk, d = self.num_heads, self.kv_heads, self.head_dim
-        q = x @ wq.T
-        gate = None
-        if self.output_gate:
-            q = q.reshape(b, s, h, 2 * d)
-            q, gate = q[..., :d], q[..., d:].reshape(b, s, h * d)
-        q = q.reshape(b, s, h, d)
-        k = (x @ wk.T).reshape(b, s, hk, d)
-        v = (x @ wv.T).reshape(b, s, hk, d)
-        if self.qk_norm:
-            q = rms_norm(q, params[4], self.norm_eps, self.qk_zero_centered)
-            k = rms_norm(k, params[5], self.norm_eps, self.qk_zero_centered)
+        with jax.named_scope("attn_proj_in"):
+            q = x @ wq.T
+            gate = None
+            if self.output_gate:
+                q = q.reshape(b, s, h, 2 * d)
+                q, gate = q[..., :d], q[..., d:].reshape(b, s, h * d)
+            q = q.reshape(b, s, h, d)
+            k = (x @ wk.T).reshape(b, s, hk, d)
+            v = (x @ wv.T).reshape(b, s, hk, d)
+            if self.qk_norm:
+                q = rms_norm(q, params[4], self.norm_eps,
+                             self.qk_zero_centered)
+                k = rms_norm(k, params[5], self.norm_eps,
+                             self.qk_zero_centered)
         with jax.named_scope("rope"):
             q = rotary(q, self.rotary_dim, self.rope_theta)
             k = rotary(k, self.rotary_dim, self.rope_theta)
-        q, k, v = [jnp.moveaxis(a, 1, 2) for a in (q, k, v)]  # (B, H, S, D)
+        with jax.named_scope("attn_proj_in"):
+            q, k, v = [jnp.moveaxis(a, 1, 2) for a in (q, k, v)]  # (B,H,S,D)
         with jax.named_scope("attn_core"):
             o = self._core(q, k, v)
-        o = jnp.moveaxis(o, 2, 1).reshape(b, s, h * d)
-        if gate is not None:
-            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
-        return o @ wo.T
+        with jax.named_scope("attn_proj_out"):
+            o = jnp.moveaxis(o, 2, 1).reshape(b, s, h * d)
+            if gate is not None:
+                o = o * jax.nn.sigmoid(
+                    gate.astype(jnp.float32)).astype(o.dtype)
+            return o @ wo.T

@@ -52,6 +52,10 @@ see there (no switch, no argument):
 
 Which one a layer took is in the ring of obs/trace.py: one `gdn.path`
 record a trace of the layer, `path` = `kernel` or `xla` with the `reason`.
+Everything the layer traces lies under one of five scopes inside its own,
+so that a device trace adds up by them: gdn_proj_in (W_qkvz, W_ba and the
+split), gdn_conv, gdn_scan (the heads' reshapes, beta, g and the rule),
+gdn_gate_norm, gdn_proj_out.
 A is strictly lower triangular, so its powers vanish at `chunk` and the
 inverse is the exact product (I - A)(I + A^2)(I + A^4)...: log2(chunk)
 squarings, all matrix products (the kernels use it on the diagonal blocks
@@ -248,9 +252,10 @@ class GatedDeltaNet(Layer):
         b, t, _ = x.shape
         hk, hv, dk, dv = self.hk, self.hv, self.dk, self.dv
         kd, vd = hk * dk, hv * dv
-        qkvz = x @ w_qkvz.astype(x.dtype).T
-        qkv, z = qkvz[..., :2 * kd + vd], qkvz[..., 2 * kd + vd:]
-        ba = (x @ w_ba.astype(x.dtype).T).astype(jnp.float32)
+        with jax.named_scope("gdn_proj_in"):
+            qkvz = x @ w_qkvz.astype(x.dtype).T
+            qkv, z = qkvz[..., :2 * kd + vd], qkvz[..., 2 * kd + vd:]
+            ba = (x @ w_ba.astype(x.dtype).T).astype(jnp.float32)
         with jax.named_scope("gdn_conv"):
             qkv = jax.nn.silu(causal_depthwise_conv(
                 qkv, conv.astype(x.dtype)))
@@ -285,4 +290,5 @@ class GatedDeltaNet(Layer):
             o = rms_norm(o, norm, self.eps, zero_centered=False)
             o = o * jax.nn.silu(z.reshape(b, t, hv, dv).astype(jnp.float32))
             o = o.reshape(b, t, vd).astype(x.dtype)
-        return [o @ w_out.astype(x.dtype).T]
+        with jax.named_scope("gdn_proj_out"):
+            return [o @ w_out.astype(x.dtype).T]

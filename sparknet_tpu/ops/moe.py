@@ -66,7 +66,11 @@ Two forms, chosen by moe_param.gated_experts:
     | then with selection_bias: bias (num_experts,), always the last
   Scopes inside the layer's own: moe_route (softmax, top-k, sort),
   moe_dispatch (gathering a window's rows), moe_experts (the grouped
-  products), moe_combine (weighting and scattering back), moe_shared.
+  products), moe_combine (weighting and scattering back), moe_shared,
+  and moe_glue for what is left, so that the layer's device time adds up
+  by them: the held weights cast to the compute type (and their gradients
+  cast back), the window loop itself with its zero start, the result's
+  cast. The top-1 form below opens no scope.
 
 The top-1 form:
 
@@ -561,7 +565,8 @@ class MoE(Layer):
         x = bottoms[0]
         b, s, e = x.shape
         n, k, held = b * s, self.top_k, self.held
-        xt = x.reshape(n, e)
+        with jax.named_scope("moe_glue"):
+            xt = x.reshape(n, e)
         with jax.named_scope("moe_route"):
             routed = bottoms[1].reshape(n, e) if self.router_bottom else xt
             idx, top = self.route(
@@ -580,9 +585,12 @@ class MoE(Layer):
                       reason=why_xla or "backend, widths and tile_rows fit",
                       activation=self.act, score=self.score,
                       selection_bias=self.selection_bias)
-        wg, wu, wd = (w.astype(x.dtype) for w in params[1:4])
-        y = held_experts(xt, top.reshape(n * k), plan, wg, wu, wd,
-                         self.tile, k, window, why_xla is None, self.act)
+        # what the window loop costs beside its body's three scopes: the
+        # held weights cast to the compute type, the loop's zero start
+        with jax.named_scope("moe_glue"):
+            wg, wu, wd = (w.astype(x.dtype) for w in params[1:4])
+            y = held_experts(xt, top.reshape(n * k), plan, wg, wu, wd,
+                             self.tile, k, window, why_xla is None, self.act)
         if self.shared_hidden:
             with jax.named_scope("moe_shared"):
                 sg, su, sd, gate = (w.astype(x.dtype) for w in params[4:8])
@@ -591,14 +599,16 @@ class MoE(Layer):
                     xt, gate.T, preferred_element_type=jnp.float32))
                 y = y + open_ * jnp.dot(h, sd.T,
                                         preferred_element_type=jnp.float32)
-        tops = [y.reshape(b, s, e).astype(x.dtype)]
+        with jax.named_scope("moe_glue"):
+            tops = [y.reshape(b, s, e).astype(x.dtype)]
         if len(self.lp.top) > 1:
-            load = plan["count"].astype(jnp.float32)
-            here = jnp.sum(load)
-            stats = lax.stop_gradient(jnp.stack([
-                here / (n * k),
-                jnp.max(load) * held / jnp.maximum(here, 1.0),
-                plan["windows"].astype(jnp.float32)]))
+            with jax.named_scope("moe_route"):
+                load = plan["count"].astype(jnp.float32)
+                here = jnp.sum(load)
+                stats = lax.stop_gradient(jnp.stack([
+                    here / (n * k),
+                    jnp.max(load) * held / jnp.maximum(here, 1.0),
+                    plan["windows"].astype(jnp.float32)]))
             tops.append(stats)
             return tops, [stats]
         return tops, state

@@ -65,7 +65,14 @@ class ShortConv(Layer):
 
     def apply(self, params, bottoms, train, rng):
         x = bottoms[0]
-        w_in, conv, w_out = (p.astype(x.dtype) for p in params)
+        # each weight's cast under the scope that uses it, all three first
+        # as before: the traced operations keep their order
+        with jax.named_scope("shortconv_in"):
+            w_in = params[0].astype(x.dtype)
+        with jax.named_scope("shortconv_mix"):
+            conv = params[1].astype(x.dtype)
+        with jax.named_scope("shortconv_out"):
+            w_out = params[2].astype(x.dtype)
         e = self.embed
         tracer = default_tracer()
         now = tracer.now_ns()

@@ -268,9 +268,14 @@ class DataParallelSolver(Solver):
         overlap_on = overlap_enabled()
 
         def grad_consensus(consensus_fn, grads, weight):
-            if overlap_on:
-                return bucketed_consensus(consensus_fn, grads, weight, axis)
-            return consensus_fn(grads, weight, axis)
+            # the gradients' exchange and what it costs beside the
+            # all-reduce itself (the buckets laid flat and cut up again,
+            # the mean's division): one scope, so a device trace adds it up
+            with jax.named_scope("grad_exchange"):
+                if overlap_on:
+                    return bucketed_consensus(consensus_fn, grads, weight,
+                                              axis)
+                return consensus_fn(grads, weight, axis)
 
         def one_grad(params, state, batch, rng):
             def lf(p):
@@ -286,8 +291,9 @@ class DataParallelSolver(Solver):
             # so the mesh step equals the one-device step on the same
             # batch; any other random layer folds its shard's index in
             # (parallel.context.batch_shard, read while `one_grad` traces)
-            w = jax.lax.axis_index(axis)
-            my_alive = alive[w]
+            with jax.named_scope("grad_exchange"):
+                w = jax.lax.axis_index(axis)
+                my_alive = alive[w]
             if iter_size == 1:
                 with context.batch_shard_context(axis, n_workers):
                     loss, grads, state = one_grad(params, state, batch, rng)
@@ -301,26 +307,30 @@ class DataParallelSolver(Solver):
                     # fp32 accumulation regardless of param dtype (the
                     # mixed-precision contract; bitwise the old
                     # zeros_like path for fp32 params)
-                    return (accum_add(acc, g), state, i + 1), loss
-                (grads, state, _), losses = jax.lax.scan(
-                    body, (accum_init(params), state, 0), batch)
-                loss = jnp.mean(losses)
+                    with jax.named_scope("grad_accum"):
+                        acc = accum_add(acc, g)
+                    return (acc, state, i + 1), loss
+                with jax.named_scope("grad_accum"):
+                    (grads, state, _), losses = jax.lax.scan(
+                        body, (accum_init(params), state, 0), batch)
+                    loss = jnp.mean(losses)
             # validity: the host-declared alive bit AND (with elasticity
             # armed) the on-device finite check — a NaN'd shard can't
             # poison the consensus even before the host evicts it
-            if elastic_on:
-                finite = jnp.logical_and(tree_finite(grads),
-                                         jnp.isfinite(loss))
-                valid = my_alive * finite.astype(jnp.float32)
-            else:
-                valid = my_alive
-            if async_on:
-                sweight = valid * staleness_discount(lag[w], s_bound,
-                                                     s_decay)
-                inc = (sweight > 0).astype(jnp.float32)
-            else:
-                sweight = valid
-                inc = valid
+            with jax.named_scope("grad_exchange"):
+                if elastic_on:
+                    finite = jnp.logical_and(tree_finite(grads),
+                                             jnp.isfinite(loss))
+                    valid = my_alive * finite.astype(jnp.float32)
+                else:
+                    valid = my_alive
+                if async_on:
+                    sweight = valid * staleness_discount(lag[w], s_bound,
+                                                         s_decay)
+                    inc = (sweight > 0).astype(jnp.float32)
+                else:
+                    sweight = valid
+                    inc = valid
             # THE collective: replaces P2PSync's up-tree gradient sum —
             # with stats on, masked_consensus_stats is the same masked
             # average plus each live shard's drift from it (the
@@ -354,13 +364,16 @@ class DataParallelSolver(Solver):
             else:
                 grads, _ = grad_consensus(masked_consensus, grads, valid)
                 aux = {}
-            loss = masked_scalar_mean(loss, inc, axis)
-            # BN running stats etc. must stay replicated
-            if async_on:
-                state, _ = weighted_consensus(state, sweight, axis)
-            else:
-                state, _ = masked_consensus(state, valid, axis)
-            params, history = updater(params, grads, history, lr_fn(it), it)
+            with jax.named_scope("grad_exchange"):
+                loss = masked_scalar_mean(loss, inc, axis)
+                # BN running stats etc. must stay replicated
+                if async_on:
+                    state, _ = weighted_consensus(state, sweight, axis)
+                else:
+                    state, _ = masked_consensus(state, valid, axis)
+            with jax.named_scope("update"):
+                rate = lr_fn(it)
+            params, history = updater(params, grads, history, rate, it)
             return params, state, history, loss, aux
 
         bspec = _batch_specs(batch_example, axis,

@@ -168,6 +168,11 @@ class Solver:
                             raise
                         self.log("No TEST-phase net; training without a "
                                  "test net")
+                # which part of a step each layer's device time counts
+                # under (graph/compiler.py:PART_OF_TYPE), once a net
+                now = self.tracer.now_ns()
+                self.tracer.record("net.parts", now, now, net=self.net.name,
+                                   parts=self.net.parts())
             if remat is not None:
                 # the policy `set_remat` takes, said where the solver is
                 # built (no jit exists yet, so nothing to rebuild)
@@ -395,13 +400,18 @@ class Solver:
                     acc, state, i = carry
                     loss, g, state = one_grad(
                         params, state, micro, jax.random.fold_in(rng, i))
-                    return (accum_add(acc, g), state, i + 1), loss
-                (grads, state, _), losses = jax.lax.scan(
-                    body, (accum_init(params), state, 0), batch)
-                loss = jnp.mean(losses)
-            rate = lr_fn(it)
+                    with jax.named_scope("grad_accum"):
+                        acc = accum_add(acc, g)
+                    return (acc, state, i + 1), loss
+                with jax.named_scope("grad_accum"):
+                    (grads, state, _), losses = jax.lax.scan(
+                        body, (accum_init(params), state, 0), batch)
+                    loss = jnp.mean(losses)
+            with jax.named_scope("update"):
+                rate = lr_fn(it)
             params, history = updater(params, grads, history, rate, it)
-            return params, state, history, loss, it + 1
+            with jax.named_scope("update"):
+                return params, state, history, loss, it + 1
 
         return step
 
