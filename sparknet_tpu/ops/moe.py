@@ -40,18 +40,37 @@ Two forms, chosen by moe_param.gated_experts:
   case of all tokens x top_k rows. A window is one gather of its rows of
   x, a grouped product over its ragged groups (gate, up, SiLU x up, down;
   backward the same recomputed, then dh, dx and the three weight
-  gradients as per-group lhs^T rhs accumulated in float32) and one
-  scatter-add. The product has two implementations behind `_grouped`,
-  chosen at trace time from what the layer can see (`_why_xla`): on a
-  TPU backend, with embed and hidden widths multiples of 128 and
-  tile_rows of 8, the megablox kernels through ops/pallas_moe.py
+  gradients as per-group lhs^T rhs accumulated in float32) and the
+  COMBINE, which brings the window's rows back to their tokens and
+  gathers (PR 39; it was one scatter-add of the window's float32 rows,
+  which on a v5e at a width of 2,560 costs by the size of the token array
+  it adds onto, not by its rows: 17 ms a call against 5 at 2,048, PERF.md
+  section 7): the window's pairs sorted by pair index, which is token by
+  token (`_token_major`: one sort of `window` keys that
+  carries each row's place; the plan's `pos`, the inverse of `order` on
+  the held pairs, says which pairs the window holds, so how many rows
+  each token has in it and where the first lies), gather 1 of the
+  window's rows into that order, a SEGMENT ADD (a token's top_k experts
+  differ, so at most J = min(top_k, held) of its rows lie in a window,
+  side by side: J - 1 shifted dense adds in float32, ascending by pair
+  index, leave each token's sum on its first row), gather 2 of each
+  token's first row, added onto the result (zeros before the first
+  window). Every pair is added once, in float32, in an order fixed
+  by the pair index: two runs agree to the bit. The only scatter left is
+  d pair_weight's, `window` scalars. The product has two implementations
+  behind `_grouped`, chosen at trace time from what the layer can see
+  (`_why_xla`): on a TPU backend, with embed and hidden widths multiples
+  of 128 and tile_rows of 8, the megablox kernels through ops/pallas_moe.py
   (`moe_gmm_fwd`, `moe_gmm_bwd`, `moe_gmm_dw` in a device trace, the
   row tile `tile_rows`, the number of row tiles a traced value);
-  elsewhere XLA's `lax.ragged_dot_general` over the same window. Which
-  one is in the ring of obs/trace.py, one `moe.path` record a trace of
-  the layer (`path` = `kernel` or `xla`, with the `reason`, the experts'
-  `activation`, the route's `score` and whether it has a
-  `selection_bias`). With
+  elsewhere XLA's `lax.ragged_dot_general` over the same window; the
+  segment add goes with it (`moe_segment_add`, one read and one write of
+  the window, beside XLA's shifted adds, which copy the window once a
+  shift). Which one is in the ring of obs/trace.py, one `moe.path` record
+  a trace of the layer (`path` = `kernel` or `xla`, with the `reason`,
+  the experts' `activation`, the route's `score`, whether it has a
+  `selection_bias`, and the combine's form: `combine` = `gather`,
+  `segment` = J). With
   shared_hidden_dim > 0 a shared expert sees every token:
   shared = sigmoid(w_s . x) W_down (SiLU(W_gate x) * W_up x), and
   y = routed + shared. Tops: [output] or [output, stats]; stats (weight
@@ -64,13 +83,15 @@ Two forms, chosen by moe_param.gated_experts:
     | w_down (held, E, F) | then with a shared expert: ws_gate (Fs, E)
     | ws_up (Fs, E) | ws_down (E, Fs) | shared gate (1, E)
     | then with selection_bias: bias (num_experts,), always the last
-  Scopes inside the layer's own: moe_route (softmax, top-k, sort),
-  moe_dispatch (gathering a window's rows), moe_experts (the grouped
-  products), moe_combine (weighting and scattering back), moe_shared,
-  and moe_glue for what is left, so that the layer's device time adds up
-  by them: the held weights cast to the compute type (and their gradients
-  cast back), the window loop itself with its zero start, the result's
-  cast. The top-1 form below opens no scope.
+  Scopes inside the layer's own: moe_route (softmax, top-k, sort, the
+  plan with its `pos`), moe_dispatch (gathering a window's rows),
+  moe_experts (the grouped products), moe_combine (the token-major sort,
+  both gathers, the weights and the segment add; d pair_weight's scalar
+  scatter), moe_shared, and moe_glue for what is left, so that the
+  layer's device time adds up by them: the held weights cast to the
+  compute type (and their gradients cast back), the window loop itself
+  with its zero start, the result's cast. The
+  top-1 form below opens no scope.
 
 The top-1 form:
 
@@ -137,9 +158,11 @@ from .convolution import _param_mults
 
 # -- the no-drop form: held experts over ragged groups, a window at a time ---
 
-# the most rows of a window, in tiles (32,768 rows at tile_rows 128: a
-# window's scatter-add costs about 8 ms besides its rows on a v5e, so a share
-# whose even routing fits one window should get it in one, PR 33)
+# the most rows of a window, in tiles (32,768 rows at tile_rows 128: a share
+# whose even routing fits one window should get it in one, PR 33: a second
+# window pays the sort, both gathers and the kernels' fixed parts for a few
+# rows; the scatter-add that cost 8 ms a window besides its rows left at
+# PR 39)
 WINDOW_TILES = 256
 
 _NT = (((1,), (2,)), ((), ()))      # lhs (m, k) . rhs (g, n, k)
@@ -153,7 +176,7 @@ def window_rows(n_tokens, top_k, held, num_experts, tile):
     quarter more, so that one window takes them and skew spills into a
     second; never more than WINDOW_TILES tiles, nor than every pair that
     can land here (a token's top_k experts differ: at most n_tokens x
-    min(top_k, held)). Gathers, scatter-adds and buffers are paid for
+    min(top_k, held)). Gathers, the segment add and buffers are paid for
     every row of a window, filled or not."""
     even = n_tokens * top_k * held / num_experts
     most = n_tokens * min(top_k, held)
@@ -166,26 +189,95 @@ def plan_windows(pair_expert, held, window):
     ones first, so each held expert's pairs are one contiguous group;
     padded so that every window is a slice of it), `bounds` (group e is
     order[bounds[e]:bounds[e + 1]]; bounds[held] pairs land here), per held
-    expert its `count`, and the number of `windows` of `window` rows of
-    `order` that hold them all. One sort and one count: no gather."""
+    expert its `count`, the number of `windows` of `window` rows of
+    `order` that hold them all, and `pos`, each held pair's place in
+    `order` (order[pos[p]] == p: its group's start and the earlier pairs of
+    its expert, a running count; the pairs not held read the number of
+    pairs, past every window). One sort and one running count: no gather,
+    no scatter."""
+    pairs = pair_expert.shape[0]
     order = jnp.argsort(pair_expert, stable=True).astype(jnp.int32)
-    bounds = jnp.sum(pair_expert[None, :] < jnp.arange(held + 1)[:, None],
-                     axis=1, dtype=jnp.int32)
-    return {"order": jnp.pad(order, (0, -order.shape[0] % window)),
-            "bounds": bounds, "count": bounds[1:] - bounds[:-1],
-            "windows": -(-bounds[-1] // window)}
+    onto = pair_expert[None, :] == jnp.arange(held)[:, None]   # (held, pairs)
+    upto = jnp.cumsum(onto, axis=1, dtype=jnp.int32)
+    count = upto[:, -1]
+    bounds = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                              jnp.cumsum(count, dtype=jnp.int32)])
+    pos = jnp.sum(jnp.where(onto, bounds[:-1, None] + upto - 1, 0), axis=0,
+                  dtype=jnp.int32)
+    return {"order": jnp.pad(order, (0, -pairs % window)),
+            "bounds": bounds, "count": count,
+            "windows": -(-bounds[-1] // window),
+            "pos": jnp.where(pair_expert < held, pos, pairs)}
 
 
 def _window(plan, w, window, top_k):
     """Window w of the sorted pairs: (pair index, token index, valid, each
-    group's rows inside the window). Rows past the last held pair are not
-    valid and belong to no group."""
+    group's rows inside the window, the first row `lo`). Rows past the last
+    held pair are not valid and belong to no group; the valid rows come
+    first."""
     lo = w * window
     pair = lax.dynamic_slice(plan["order"], (lo,), (window,))
     edges = jnp.clip(plan["bounds"], lo, lo + window)
-    return (pair, pair // top_k,
-            lo + jnp.arange(window) < plan["bounds"][-1],
-            edges[1:] - edges[:-1])
+    return (pair, pair // top_k, jnp.arange(window) < edges[-1] - lo,
+            edges[1:] - edges[:-1], lo)
+
+
+def _token_major(plan, pair, valid, lo, top_k):
+    """The same window with each token's rows side by side, for the
+    combine: `pair` (the window's pair indices ascending, which is token by
+    token; the rows of no pair last, reading the number of pairs), `row`
+    (where each lies in the expert-sorted window), `tok` (its token; the
+    number of tokens on the rows of no pair), and per token its `count` of
+    rows in this window and the `first` of them. One sort of `window` keys
+    and one running count over the tokens."""
+    pos = plan["pos"]
+    pairs, window = pos.shape[0], pair.shape[0]
+    tm, row = lax.sort((jnp.where(valid, pair, pairs),
+                        jnp.arange(window, dtype=jnp.int32)), num_keys=1)
+    here = (pos >= lo) & (pos < lo + jnp.sum(valid, dtype=jnp.int32))
+    count = jnp.sum(here.reshape(-1, top_k), axis=1, dtype=jnp.int32)
+    return {"pair": tm, "row": row, "tok": tm // top_k, "count": count,
+            "first": jnp.cumsum(count, dtype=jnp.int32) - count}
+
+
+def _shifted_adds(z, tok, segment):
+    """XLA's form of the segment add: z (rows, E) float32 sorted by token
+    `tok` -> row i plus the rows i + 1 .. i + segment - 1 of i's token,
+    added in ascending order."""
+    total = z
+    for d in range(1, min(segment, z.shape[0])):
+        same = jnp.pad(tok[d:] == tok[:-d], (0, d))
+        total = total + jnp.where(
+            same[:, None], jnp.pad(z[d:], ((0, d), (0, 0))), 0.0)
+    return total
+
+
+def _combine(rows, weight, tm, segment, kernel):
+    """Each token's sum of its rows of this window -> (n, E) float32, zeros
+    for a token with none: `rows` (window, E) float32 in the expert-sorted
+    order, times `weight` per token-major row where given. Without a
+    scatter: gather the rows token by token, add each token's adjacent
+    rows onto its first (a token has at most `segment` rows here; shifted
+    dense adds in float32, ascending by pair index), gather each token's
+    first row. The rows of no pair add nothing, whatever they hold. The
+    shifted adds are one kernel where `kernel` says so and a block fits
+    (ops/pallas_moe.py:segment_add), else XLA's."""
+    window, tokens = rows.shape[0], tm["count"].shape[0]
+    z, tok, block = rows[tm["row"]], tm["tok"], 0
+    if kernel:
+        from . import pallas_moe
+        block = pallas_moe.segment_block(window, segment)
+    if block:
+        total = pallas_moe.segment_add(z, weight, tok, tokens, segment,
+                                       block)
+    else:
+        if weight is not None:
+            z = z * weight[:, None]
+        z = jnp.where((tok < tokens)[:, None], z, 0.0)
+        total = jnp.pad(_shifted_adds(z, tok, segment), ((0, 1), (0, 0)))
+    # the rows past the window are zeros: a token with no row here gathers
+    # the first of them
+    return total[jnp.where(tm["count"] > 0, tm["first"], window)]
 
 
 def _grouped(kernel, tile, sizes):
@@ -242,9 +334,12 @@ def held_experts(x, pair_weight, plan, wg, wu, wd, tile, top_k, window,
     from `plan_windows(.., window)`, `window` a multiple of `tile`, the row
     tile of the grouped product; `kernel`: the pallas product, else XLA's.
     -> (n, E) float32. A `fori_loop` over the windows in use: one gather,
-    the grouped products, one scatter-add a window; the backward pass is a
-    second such loop that recomputes each window, so nothing is stored per
-    window."""
+    the grouped products and the gathering combine (`_token_major`,
+    `_combine`) a window, added onto the result; the backward pass is a
+    second such loop that recomputes each window, so nothing is stored
+    per window. A token's pairs lie on different experts (as `top_k` gives
+    them), or at least no more than min(top_k, held) of them are held:
+    the segment add reaches no further."""
     return _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window,
                      kernel, act)[0]
 
@@ -255,9 +350,11 @@ def held_experts(x, pair_weight, plan, wg, wu, wd, tile, top_k, window,
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
 def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window, kernel,
               act="silu"):
+    segment = min(top_k, wg.shape[0])
+
     def body(w, y):
         with jax.named_scope("moe_dispatch"):
-            pair, tok, valid, sizes = _window(plan, w, window, top_k)
+            pair, tok, valid, sizes, lo = _window(plan, w, window, top_k)
             xw = x[tok]
         with jax.named_scope("moe_experts"):
             dot, _ = _grouped(kernel, tile, sizes)
@@ -266,9 +363,9 @@ def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window, kernel,
             h = (_gate(a, act) * b).astype(x.dtype)
             out = dot(h, wd, True, "moe_gmm_fwd")
         with jax.named_scope("moe_combine"):
-            wt = pair_weight[pair]
-            return y.at[tok].add(
-                jnp.where(valid[:, None], out * wt[:, None], 0.0))
+            tm = _token_major(plan, pair, valid, lo, top_k)
+            wt = pair_weight[jnp.minimum(tm["pair"], pair_weight.shape[0] - 1)]
+            return y + _combine(out, wt, tm, segment, kernel)
 
     y = lax.fori_loop(0, plan["windows"], body,
                       jnp.zeros(x.shape, jnp.float32))
@@ -279,11 +376,12 @@ def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window, kernel,
 def _held_bwd(tile, top_k, window, kernel, act, res, dy):
     x, pair_weight, plan, wg, wu, wd = res
     dy = dy.astype(jnp.float32)
+    segment = min(top_k, wg.shape[0])
 
     def body(w, carry):
         dx, dpw, dwg, dwu, dwd = carry
         with jax.named_scope("moe_dispatch"):
-            pair, tok, valid, sizes = _window(plan, w, window, top_k)
+            pair, tok, valid, sizes, lo = _window(plan, w, window, top_k)
             xw, dyr = x[tok], dy[tok]
         with jax.named_scope("moe_experts"):
             dot, dot_t = _grouped(kernel, tile, sizes)
@@ -305,7 +403,8 @@ def _held_bwd(tile, top_k, window, kernel, act, res, dy):
             dwu = dot_t(db, xw, dwu, "moe_gmm_dw")
             dwd = dot_t(dyw, h, dwd, "moe_gmm_dw")
         with jax.named_scope("moe_combine"):
-            dx = dx.at[tok].add(jnp.where(valid[:, None], dxw, 0.0))
+            tm = _token_major(plan, pair, valid, lo, top_k)
+            dx = dx + _combine(dxw, None, tm, segment, kernel)
             # a row of no group may repeat a real pair's index: add 0 there
             dpw = dpw.at[pair].add(dwt)
         return dx, dpw, dwg, dwu, dwd
@@ -584,7 +683,8 @@ class MoE(Layer):
                       path="xla" if why_xla else "kernel",
                       reason=why_xla or "backend, widths and tile_rows fit",
                       activation=self.act, score=self.score,
-                      selection_bias=self.selection_bias)
+                      selection_bias=self.selection_bias,
+                      combine="gather", segment=min(k, held))
         # what the window loop costs beside its body's three scopes: the
         # held weights cast to the compute type, the loop's zero start
         with jax.named_scope("moe_glue"):

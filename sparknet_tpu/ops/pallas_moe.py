@@ -18,15 +18,24 @@ the un-jitted function under a `jax.named_scope` makes the device trace say
 The rows after the last group are not written by `gmm` (whatever the
 buffer held stays there: the caller masks them) and not read by `tgmm`.
 
+And the one dense pass of the combine (PR 39): `segment_add`, a window's
+rows token by token in, each token's sum on its first row out, in one
+read and one write (`moe_segment_add` in a device trace). XLA's form of
+the same shifted adds copies the window once a shift, because a slice at a
+row offset that is no multiple of 8 is off the tiling (step 0 of PR 39:
+1.7 ms a shift of 30,720 rows of 2,560).
+
 Imported in the branch of ops/moe.py that calls it, never at the top of a
 module every process imports (PR 29: 1.4 s of `import
 jax.experimental.pallas` in every cell's set-up).
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
 from .pallas_lrn import _should_interpret
@@ -85,3 +94,97 @@ def _tgmm(lhs, rhs, sizes, total, tile, name, interpret):
             lhs.T, rhs, sizes, jnp.float32,
             (tile, *_blocks(k, n, _TGMM_BLOCK)), existing_out=total,
             interpret=interpret)
+
+
+# -- the combine's segment add ------------------------------------------------
+
+_SEGMENT_ROWS, _SEGMENT_LANES = 512, 1024
+
+
+def _halo(segment):
+    """Rows of the next block that a block's last rows reach into: a power
+    of two, whole sublane tiles."""
+    return max(8, 1 << (segment - 2).bit_length()) if segment > 1 else 8
+
+
+def segment_block(window, segment):
+    """Rows a block of `segment_add`, or 0 where none fits (a window of
+    few, odd tiles under a long segment: the caller keeps XLA's form)."""
+    block = math.gcd(window, _SEGMENT_ROWS)
+    return block if block % 8 == 0 and block >= _halo(segment) else 0
+
+
+def _segment_add_kernel(tokens, segment, weighted, *refs):
+    if weighted:
+        tok_ref, tokh_ref, wt_ref, wth_ref, z_ref, zh_ref, out_ref = refs
+    else:
+        tok_ref, tokh_ref, z_ref, zh_ref, out_ref = refs
+    rows = z_ref.shape[0]
+    tok = jnp.concatenate([tok_ref[...], tokh_ref[...]], axis=0)
+    z = jnp.concatenate([z_ref[...], zh_ref[...]], axis=0)
+    if weighted:
+        z = z * jnp.concatenate([wt_ref[...], wth_ref[...]], axis=0)
+    # the rows of no pair hold whatever the buffer held: they add nothing
+    z = jnp.where(tok < tokens, z, 0.0)
+    total, mine = z[:rows], tok[:rows]
+    for d in range(1, segment):
+        total = total + jnp.where(tok[d:d + rows] == mine, z[d:d + rows],
+                                  0.0)
+    out_ref[...] = total
+
+
+def segment_add(z, weight, tok, tokens, segment, block):
+    """z (window, E) float32, its rows sorted by their token `tok`
+    (window,) int32 (`tokens` on the rows of no pair, which come last),
+    times `weight` (window,) a row where given -> (window + block, E)
+    float32: row i holds the sum of rows i, i + 1, .. of i's token, `segment`
+    at most, added in ascending order, so a token's first row holds the
+    token's sum; the last `block` rows are zeros (the row to gather for a
+    token with no row here). `block` from `segment_block`."""
+    return _segment_add(z, weight, tok, tokens, segment, block,
+                        _should_interpret())
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _segment_add(z, weight, tok, tokens, segment, block, interpret):
+    window, embed = z.shape
+    halo = _halo(segment)
+    lanes = next((c for c in range(_SEGMENT_LANES, 0, -128)
+                  if embed % c == 0), embed)
+    blocks = window // block
+    per = block // halo                      # halo blocks a row block
+
+    def rows(i, j):
+        return jnp.minimum(i, blocks - 1), j
+
+    def rows_after(i, j):
+        return jnp.minimum((i + 1) * per, window // halo - 1), j
+
+    def column(i, j):
+        return i, 0
+
+    def column_after(i, j):
+        return (i + 1) * per, 0
+
+    def padded(v, fill):
+        # a block of its own for the zero rows, and the halo after it
+        return jnp.pad(v, (0, 2 * block),
+                       constant_values=fill).reshape(-1, 1)
+    columns = [padded(tok, tokens)] * 2
+    specs = [pl.BlockSpec((block, 1), column),
+             pl.BlockSpec((halo, 1), column_after)]
+    if weight is not None:
+        columns += [padded(weight, 0.0)] * 2
+        specs += specs
+    with jax.named_scope("moe_segment_add"):
+        return pl.pallas_call(
+            functools.partial(_segment_add_kernel, tokens, segment,
+                              weight is not None),
+            grid=(blocks + 1, embed // lanes),
+            in_specs=specs + [pl.BlockSpec((block, lanes), rows),
+                              pl.BlockSpec((halo, lanes), rows_after)],
+            out_specs=pl.BlockSpec((block, lanes), lambda i, j: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((window + block, embed),
+                                           jnp.float32),
+            interpret=interpret, name="moe_segment_add",
+        )(*columns, z, z)

@@ -3,9 +3,14 @@
 them in both implementations: XLA's ragged product, and the megablox
 kernels of `ops/pallas_moe.py` in interpret mode (the CPU has no other).
 
-The oracle is a dense loop over the held experts with a mask: every token
-through every expert, weighted by what the routing gave it there.
+Two oracles: a dense loop over the held experts with a mask (every token
+through every expert, weighted by what the routing gave it there), and,
+for the combine, which since PR 39 gathers (`_token_major`, `_combine`),
+the scatter-add it replaced: every held pair's row, weighted, added onto
+its token by `.at[tok].add`, kept here.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -140,6 +145,192 @@ def test_held_experts_across_window_boundaries(case, kernel):
         close(a, b)
 
 
+def scatter_oracle(x, pair_weight, pair_expert, wg, wu, wd, top_k):
+    """The combine as a scatter-add: each held pair's row through its
+    expert, times its weight, added onto its token."""
+    pairs = np.flatnonzero(np.asarray(pair_expert) < HELD)
+    tok, e = pairs // top_k, np.asarray(pair_expert)[pairs]
+    xw = x[tok]
+    h = jax.nn.silu(jnp.einsum("me,mfe->mf", xw, wg[e])) \
+        * jnp.einsum("me,mfe->mf", xw, wu[e])
+    out = jnp.einsum("mf,mef->me", h, wd[e])
+    return jnp.zeros(x.shape, jnp.float32).at[tok].add(
+        out * pair_weight[pairs][:, None])
+
+
+def _experts_of_tokens(n, top_k, rows):
+    """(n x top_k,) local experts, nothing held but what `rows` (token ->
+    its experts, slot by slot) says."""
+    pair_expert = np.full((n, top_k), HELD, np.int32)
+    for tok, experts in rows.items():
+        pair_expert[tok, :len(experts)] = experts
+    return pair_expert.reshape(-1)
+
+
+def _all_on_expert_0_but(n, top_k, free):
+    pair_expert = np.zeros((n, top_k), np.int32)
+    pair_expert.reshape(-1)[free] = HELD
+    return pair_expert.reshape(-1)
+
+
+# 24 tokens x top-4 over the four held experts: (each pair's local expert,
+# the window's rows, the windows the loop runs)
+COMBINE_CASES = {
+    # token 5's four pairs are all held, on four experts, and with three
+    # other tokens' pairs they fit one window: the whole segment of J = 4
+    "a_token_with_all_its_pairs_in_one_window": (_experts_of_tokens(
+        24, 4, {5: [0, 1, 2, 3], 2: [1, HELD, 0], 9: [3, 3], 23: [HELD, 2],
+                0: [2]}), 16, 1),
+    # token 7's pair on expert 0 lies in the first window and its pairs on
+    # expert 3 in the third: its sum is made window after window
+    "a_token_astride_the_window_boundaries": (_experts_of_tokens(
+        24, 4, {**{t: [1, 2] for t in range(8, 24)}, 7: [0, 3, HELD, 3],
+                3: [0, 0, 1, 3]}), 16, 3),
+    "every_pair_on_one_expert": (np.zeros(96, np.int32), 16, 6),
+    "nothing_held": (np.full(96, HELD, np.int32), 16, 0),
+    # 96 pairs in windows of 40 rows: `order` is padded by 24 rows that
+    # repeat pair 0, which is held, and the third window runs over them
+    # and over the rows of the five pairs not held
+    "padding_rows_repeat_a_held_pair": (_all_on_expert_0_but(
+        24, 4, [13, 14, 40, 77, 95]), 40, 3),
+}
+
+
+@FORMS
+@pytest.mark.parametrize("case", list(COMBINE_CASES))
+def test_gather_combine_against_a_scatter_add_oracle(case, kernel):
+    """held_experts' output, dx, d pair_weight and the weights' gradients
+    as the scatter-add gave them, every pair added once and none dropped;
+    and the same bits on a second run: the order of the float32 additions
+    is fixed by the pair index."""
+    pair_expert, window, windows = COMBINE_CASES[case]
+    n, top_k = 24, 4
+    kx, kp, kw, kc = jax.random.split(jax.random.PRNGKey(len(case)), 4)
+    x = jax.random.normal(kx, (n, E))
+    pair_weight = jax.random.uniform(kp, (n * top_k,), minval=0.1)
+    cot = jax.random.normal(kc, (n, E))
+    plan = moe_ops.plan_windows(jnp.asarray(pair_expert), HELD, window)
+    assert int(plan["windows"]) == windows
+
+    def mine(x, pw, wg, wu, wd):
+        return moe_ops.held_experts(x, pw, plan, wg, wu, wd, TILE, top_k,
+                                    window, kernel)
+
+    def theirs(x, pw, wg, wu, wd):
+        return scatter_oracle(x, pw, pair_expert, wg, wu, wd, top_k)
+    args = (x, pair_weight, *weights(kw))
+    got, vjp = jax.vjp(mine, *args)
+    want, vjp_want = jax.vjp(theirs, *args)
+    grads = vjp(cot)
+    close(got, want)
+    for a, b in zip(grads, vjp_want(cot)):
+        close(a, b)
+    # a token that nothing here serves reads exactly 0, and so does the
+    # weight's gradient of a pair that is not held
+    idle = np.all(pair_expert.reshape(n, top_k) == HELD, axis=1)
+    assert not np.asarray(got)[idle].any()
+    assert not np.asarray(grads[1])[pair_expert == HELD].any()
+    again, vjp_again = jax.vjp(mine, *args)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(again))
+    for a, b in zip(grads, vjp_again(cot)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("case", list(COMBINE_CASES))
+def test_pos_is_the_inverse_of_order_on_the_held_pairs(case):
+    pair_expert, window, _ = COMBINE_CASES[case]
+    plan = moe_ops.plan_windows(jnp.asarray(pair_expert), HELD, window)
+    order, pos = np.asarray(plan["order"]), np.asarray(plan["pos"])
+    held = pair_expert < HELD
+    assert pos.dtype == np.int32 and pos.shape == pair_expert.shape
+    np.testing.assert_array_equal(order[pos[held]], np.flatnonzero(held))
+    assert (pos[held] < np.asarray(plan["bounds"])[-1]).all()
+    # the others lie past every window
+    assert (pos[~held] == pair_expert.size).all()
+    assert order.size % window == 0
+
+
+@pytest.mark.parametrize("case", list(COMBINE_CASES))
+def test_a_window_token_by_token(case):
+    """`_token_major` of every window that runs: the held pairs of the
+    window ascending, each with its row of the expert-sorted window, every
+    token's rows side by side from its `first`, at most J of them."""
+    pair_expert, window, windows = COMBINE_CASES[case]
+    top_k, pairs = 4, pair_expert.size
+    plan = moe_ops.plan_windows(jnp.asarray(pair_expert), HELD, window)
+    for w in range(windows):
+        pair, _, valid, _, lo = moe_ops._window(plan, w, window, top_k)
+        tm = {k: np.asarray(v) for k, v in moe_ops._token_major(
+            plan, pair, valid, lo, top_k).items()}
+        live = int(np.asarray(valid).sum())
+        here = np.sort(np.asarray(pair)[:live])
+        np.testing.assert_array_equal(tm["pair"][:live], here)
+        assert (tm["pair"][live:] == pairs).all()
+        np.testing.assert_array_equal(
+            np.asarray(pair)[tm["row"][:live]], here)
+        assert tm["count"].sum() == live and tm["count"].max() <= top_k
+        for tok in np.flatnonzero(tm["count"]):
+            rows = slice(tm["first"][tok], tm["first"][tok] + tm["count"][tok])
+            assert (tm["tok"][rows] == tok).all()
+
+
+# (the longest run of one token's rows, the window's rows, the block the
+# kernel takes): runs that cross block boundaries, a halo of 8 and of 16
+SEGMENT_CASES = {"runs_of_4_in_one_block": (4, 16, 16),
+                 "runs_of_4_across_blocks_of_8": (4, 40, 8),
+                 "runs_of_10_reach_16_rows_on": (10, 48, 16),
+                 "runs_of_2": (2, 24, 8)}
+
+
+@pytest.mark.parametrize("weighted", [True, False], ids=["weighted", "plain"])
+@pytest.mark.parametrize("case", list(SEGMENT_CASES))
+def test_segment_add_kernel_to_the_bit(case, weighted):
+    """`pallas_moe.segment_add` (interpret mode): a token's first row holds
+    the float32 sum of its rows added in ascending order, bit for bit
+    where no weight multiplies them; the rows of no pair, NaN here as a
+    kernel's unwritten rows may be, add nothing; the rows past the window
+    are zeros."""
+    from sparknet_tpu.ops import pallas_moe
+    segment, window, block = SEGMENT_CASES[case]
+    assert pallas_moe.segment_block(window, segment) == block
+    rng = np.random.RandomState(window)
+    runs, left = [], window - 3            # the last three rows hold no pair
+    while left:
+        runs.append(min(left, rng.randint(1, segment + 1)))
+        left -= runs[-1]
+    tokens = 2 * len(runs)
+    tok = np.concatenate([np.repeat(2 * np.arange(len(runs)), runs),
+                          np.full(3, tokens)]).astype(np.int32)
+    z = rng.randn(window, 128).astype(np.float32)
+    z[-3:] = np.nan
+    wt = rng.rand(window).astype(np.float32) if weighted else None
+    got = np.asarray(pallas_moe.segment_add(
+        jnp.asarray(z), None if wt is None else jnp.asarray(wt),
+        jnp.asarray(tok), tokens, segment, block))
+    assert got.shape == (window + block, 128)
+    assert not got[window:].any()
+    rows = z * wt[:, None] if weighted else z
+    first = 0
+    for run in runs:
+        want = rows[first]
+        for r in range(first + 1, first + run):
+            want = want + rows[r]
+        if weighted:    # the CPU contracts a product and an add into one
+            np.testing.assert_allclose(got[first], want, rtol=2e-6,
+                                       atol=1e-6)
+        else:
+            np.testing.assert_array_equal(got[first], want)
+        first += run
+
+
+def test_segment_add_has_no_block_for_a_long_segment_in_a_short_window():
+    from sparknet_tpu.ops import pallas_moe
+    # 9 rows on need a halo of 16, and a window of 24 rows has blocks of 8
+    assert pallas_moe.segment_block(24, 10) == 0
+    assert pallas_moe.segment_block(6400, 10) == 256
+    assert pallas_moe.segment_block(30720, 6) == 512
+
+
 def test_window_rows_is_static_and_sized_by_an_even_routing():
     # the LM cell: 16,384 tokens x top-10, 16 of 512 experts held, tiles of
     # 128: an even routing sends 5,120 pairs, the window takes 6,400
@@ -164,7 +355,7 @@ def test_window_rows_is_static_and_sized_by_an_even_routing():
 
 
 def moe_paths():
-    return [(s["layer"], s["path"], s["reason"])
+    return [(s["layer"], s["path"], s["reason"], s["combine"], s["segment"])
             for s in default_tracer().spans("moe.path")]
 
 
@@ -178,8 +369,9 @@ def test_layer_takes_the_product_it_can_and_records_it(
         monkeypatch, backend, widths, tile, path, reason):
     """One `moe.path` record a trace of the layer, as `gdn.path`: the
     kernels on a TPU backend where widths and tile_rows allow, else XLA's
-    ragged product over the same window. The backend is the test's to
-    pretend: the program has no option for it."""
+    ragged product over the same window, the combine's form beside it.
+    The backend is the test's to pretend: the program has no option for
+    it."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     embed, hidden = widths
     name = f"moe_{backend}_{hidden}_{tile}"
@@ -192,8 +384,14 @@ def test_layer_takes_the_product_it_can_and_records_it(
     text = str(jax.make_jaxpr(
         lambda p, x: impl.apply(p, [x], True, None)[0])(
         blobs, jax.ShapeDtypeStruct((1, 16, embed), jnp.float32)))
-    assert moe_paths()[before:] == [(name, path, reason)]
+    # the combine gathers, a token's at most min(top_k, held) = 2 rows of a
+    # window added by segments
+    assert moe_paths()[before:] == [(name, path, reason, "gather", 2)]
     assert ("pallas_call" in text) == (path == "kernel")
     assert ("ragged_dot" in text) == (path == "xla")
-    # one structure either way: a loop of dynamic length over windows
-    assert " while[" in text and "scatter-add" in text
+    # one structure either way: a loop of dynamic length over windows, and
+    # since PR 39 no scatter of float32 rows in its forward pass (what is
+    # left are the kernels' group metadata, a few scalars)
+    assert " while[" in text
+    assert not re.search(r":f32\[\d+,\d+\] = scatter", text)
+    assert "gather" in text and "sort" in text

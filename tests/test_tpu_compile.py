@@ -15,6 +15,7 @@ ONE file, so one worker loads libtpu and keeps it.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -143,7 +144,7 @@ def test_gdn_chunk_kernels_compile(one_chip, which):
              kernels=["gdn_chunk_bwd"])
 
 
-# the held experts' grouped products at the two LM cells' shapes, bfloat16
+# the held experts' grouped products at the three LM cells' shapes, bfloat16
 # in, float32 out, tiles of 128: (tokens, top_k, held, experts, embed,
 # hidden, activation, the window's rows, the most temporaries)
 MOE_TILE = 128
@@ -179,7 +180,7 @@ def test_moe_grouped_products_compile(one_chip, monkeypatch, shape, which):
                                     k, window, True, act)
     if which == "forward":
         compiled = _compile(run, one_chip, x, pairs, experts, up, up, down,
-                            kernels=["moe_gmm_fwd"])
+                            kernels=["moe_gmm_fwd", "moe_segment_add"])
     else:
         def grads(x, pw, pair_expert, wg, wu, wd, dy):
             return jax.vjp(lambda x, pw, wg, wu, wd: run(
@@ -187,9 +188,42 @@ def test_moe_grouped_products_compile(one_chip, monkeypatch, shape, which):
         compiled = _compile(
             grads, one_chip, x, pairs, experts, up, up, down,
             ((n, e), jnp.float32),
-            kernels=["moe_gmm_fwd", "moe_gmm_bwd", "moe_gmm_dw"])
+            kernels=["moe_gmm_fwd", "moe_gmm_bwd", "moe_gmm_dw",
+                     "moe_segment_add"])
     # a window's buffers, not tokens x top_k rows of anything
     assert compiled.memory_analysis().temp_size_in_bytes < most
+    # the combine gathers (PR 39): no scatter of float32 rows of the
+    # embedding width is left, forward or backward (what is left scatters
+    # scalars: d pair_weight, the kernels' group metadata)
+    wide = re.findall(rf"f32\[\d+,{e}\]\S* scatter\(", compiled.as_text())
+    assert not wide, wide
+
+
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["forward", "backward"])
+@pytest.mark.parametrize("shape", list(MOE_SHAPES))
+def test_moe_segment_add_compiles(one_chip, monkeypatch, shape, weighted):
+    """The combine's one dense pass alone at the three cells' windows: a
+    block and a halo that fit the tiling at a segment of 6, 4 and 10 rows
+    (the last reaches 9 rows into the next block: a halo of 16)."""
+    monkeypatch.setattr(pm, "_should_interpret", lambda: False)
+    n, k, held, _, e, _, _, window, _ = MOE_SHAPES[shape]
+    segment = min(k, held)
+    block = pm.segment_block(window, segment)
+    assert block == {6400: 256, 30720: 512}[window]
+    rows = ((window, e), jnp.float32)
+    column = ((window,), jnp.float32)
+    tok = ((window,), jnp.int32)
+    if weighted:
+        compiled = _compile(
+            lambda z, wt, tok: pm.segment_add(z, wt, tok, n, segment, block),
+            one_chip, rows, column, tok, kernels=["moe_segment_add"])
+    else:
+        compiled = _compile(
+            lambda z, tok: pm.segment_add(z, None, tok, n, segment, block),
+            one_chip, rows, tok, kernels=["moe_segment_add"])
+    # one read and one write of the window: nothing is copied round the call
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def test_gated_delta_net_backward_holds_less_than_the_scans_did(
