@@ -591,18 +591,23 @@ class CompiledNet:
         layer's fields less its name, bottoms, tops and blobs: a windowed
         attention and a global one of the same shapes are not one body)
         — and is stateless,
-        rng-free, loss-free, feed-free, with no cross-layer param
-        sharing. Chaining requires group i's one external input to be
+        rng-free, feed-free, with no cross-layer param sharing, and
+        loss-free but for the scalar loss tops of a layer that says a scan
+        may stack them (`scan_loss_tops`: an attention's index loss),
+        which ride out of the scan as its per-iteration outputs and land
+        in ``blobs`` under every group's own name. Chaining requires
+        group i's one external input to be
         group i-1's one externally consumed top, read by nothing else.
         Under those conditions the whole run executes as ONE traced
         block body under lax.scan over stacked per-group params,
         collapsing per-layer trace/dispatch/compile cost from O(depth)
         to O(1) — the d512 LM row's dominant overhead (PERF.md).
 
-        Returns [{lo, hi, glen, n, entry, body_out, out}]: layer range,
-        group length/count, group-0's external input blob, group-0's
-        boundary top (the scan carry), and the LAST group's boundary
-        blob name (where the carry lands)."""
+        Returns [{lo, hi, glen, n, entry, body_out, out, losses}]: layer
+        range, group length/count, group-0's external input blob,
+        group-0's boundary top (the scan carry), the LAST group's boundary
+        blob name (where the carry lands), and the loss tops as (layer in
+        the group, top) indices."""
         if self._scan_cache is not None:
             return self._scan_cache
         pgroups = []                       # (prefix, lo, hi)
@@ -620,14 +625,18 @@ class CompiledNet:
         def group_info(gi):
             """(signature, entry, boundary) or None if ineligible."""
             pfx, lo, hi = pgroups[gi]
-            produced, sig, externals = set(), [], set()
+            produced, sig, externals, losses = set(), [], set(), []
             strip = len(pfx) + 1
             for li in range(lo, hi):
                 lp, impl, bottoms, tops = self.layers[li]
                 if getattr(impl, "is_feed", False) or impl.has_state \
-                        or impl.needs_rng \
-                        or any(self.loss_weights[lp.name]):
+                        or impl.needs_rng:
                     return None
+                if any(self.loss_weights[lp.name]):
+                    if not getattr(impl, "scan_loss_tops", False):
+                        return None
+                    losses += [(li - lo, ti) for ti, w in enumerate(
+                        self.loss_weights[lp.name]) if w]
                 if any(owner != lp.name
                        for owner, _ in self.param_refs[lp.name]):
                     return None
@@ -652,12 +661,18 @@ class CompiledNet:
                 produced.update(tops)
             if len(externals) != 1:
                 return None
+            loss_tops = {self.layers[lo + j][3][ti] for j, ti in losses}
+            if any(t in self.layers[lj][2] for t in loss_tops
+                   for lj in range(nl)):
+                return None
             out = {t for li in range(lo, hi) for t in self.layers[li][3]
                    if t in self.output_blobs
                    or any(t in self.layers[lj][2] for lj in range(hi, nl))}
+            out -= loss_tops
             if len(out) != 1:
                 return None
-            return tuple(sig), next(iter(externals)), next(iter(out))
+            return (tuple(sig), next(iter(externals)), next(iter(out)),
+                    losses)
 
         infos = [group_info(gi) for gi in range(len(pgroups))]
 
@@ -687,7 +702,8 @@ class CompiledNet:
                              "n": gj - gi + 1,
                              "entry": infos[gi][1],
                              "body_out": infos[gi][2],
-                             "out": infos[gj][2]})
+                             "out": infos[gj][2],
+                             "losses": infos[gi][3]})
             gi = gj + 1
         self._scan_cache = runs
         return runs
@@ -740,14 +756,20 @@ class CompiledNet:
                                        train, None)
                 for t, v in zip(tops, tvals):
                     sblobs[t] = v
-            return sblobs[body_out], None
+            return sblobs[body_out], [sblobs[g0[j][3][ti]]
+                                      for j, ti in run["losses"]]
 
         if pol != "none":
             body = _checkpointed(body, pol)
         x0 = fission.materialize(blobs[entry])
         with jax.named_scope(scope):
-            xN, _ = jax.lax.scan(body, x0, stacked)
+            xN, losses = jax.lax.scan(body, x0, stacked)
         blobs[run["out"]] = xN
+        # a group's loss tops, stacked over the groups, under their names
+        for (j, ti), stacked_loss in zip(run["losses"], losses):
+            for g in range(n):
+                blobs[self.layers[lo + g * glen + j][3][ti]] = \
+                    stacked_loss[g]
 
     def _segment_externals(self, lo, hi):
         """Blob names a [lo, hi) segment must surface: consumed by later

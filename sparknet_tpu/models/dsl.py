@@ -141,13 +141,21 @@ def AttentionLayer(name, bottoms, num_heads, head_dim=None, causal=False,
                    ring=False, flash=False, num_kv_heads=None,
                    qk_norm=False, rotary_dim=0, rope_theta=None,
                    output_gate=False, norm_eps=None, weight_filler=None,
-                   param=None, window=None, qk_norm_zero_centered=None):
+                   param=None, window=None, qk_norm_zero_centered=None,
+                   index_heads=None, index_head_dim=None, index_topk=None,
+                   index_stats=False):
     """sparknet_tpu extension for the long-context path (see
     parallel.ring_attention, ops.pallas_attention). `num_kv_heads` selects
     the grouped-query form (bias-free q/k/v/out projections; qk_norm,
     rotary_dim, rope_theta, output_gate belong to it). `window` (with
     causal): a sliding window of that many keys, the query's own among
-    them. `qk_norm_zero_centered` False: the plain form of the two norms."""
+    them. `qk_norm_zero_centered` False: the plain form of the two norms.
+    `index_heads`, `index_head_dim`, `index_topk` (with causal and
+    num_kv_heads): a learned index picks every query's `index_topk` keys
+    (ops/dsa.py); five more blobs (W_qI, W_kI, W_w, the index key's
+    LayerNorm weight and bias) and a second top `<name>_kl`, the index's
+    own loss, of weight 1; `index_stats` adds a third (weight 0), the
+    share of the selected keys inside a window of `index_topk`."""
     ap = dict(num_heads=num_heads, causal=causal, ring=ring, flash=flash)
     if window:
         ap["window"] = window
@@ -164,8 +172,15 @@ def AttentionLayer(name, bottoms, num_heads, head_dim=None, causal=False,
             ap["norm_eps"] = norm_eps
     if weight_filler is not None:
         ap["weight_filler"] = weight_filler
-    return _with_params(_base("Attention", name, bottoms,
-                              attention_param=ap), param)
+    if index_heads is None:
+        return _with_params(_base("Attention", name, bottoms,
+                                  attention_param=ap), param)
+    ap.update(index_heads=index_heads, index_head_dim=index_head_dim,
+              index_topk=index_topk, index_stats=index_stats)
+    tops = [name, f"{name}_kl"] + ([f"{name}_stats"] if index_stats else [])
+    lp = _base("Attention", name, bottoms, tops=tops, attention_param=ap)
+    lp.loss_weight.extend([0.0, 1.0] + ([0.0] if index_stats else []))
+    return _with_params(lp, param)
 
 
 def GatedDeltaNetLayer(name, bottoms, num_k_heads, num_v_heads, head_k_dim,

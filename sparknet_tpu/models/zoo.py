@@ -648,6 +648,106 @@ def lfm2_moe(vocab_size=65536, seq_len=8192, batch_size=3,
     return NetParam("LFM2MoE", *layers)
 
 
+def keye_vl2(vocab_size=151936, seq_len=32768, batch_size=1,
+             hidden_size=2048, num_hidden_layers=48, num_attention_heads=32,
+             num_key_value_heads=4, head_dim=128, rope_theta=1e7,
+             rms_norm_eps=1e-6, indexer_num_heads=16, indexer_head_dim=64,
+             indexer_topk=2048, num_experts=128, num_experts_per_tok=8,
+             moe_intermediate_size=768, norm_topk_prob=True,
+             experts_held=None, first_expert=0, flash=True, moe_stats=False,
+             index_stats=False):
+    """Keye-VL-2.0-30B-A3B's decoder (`model_type` KeyeVL2, the language
+    model's settings) as a trainable net: blocks of y = x + Attn(RMSNorm(x)),
+    out = y + MoE(RMSNorm(y)), plain RMSNorm (w filled with 1), every layer
+    alike. `Attn` is grouped-query attention without bias, a plain RMSNorm
+    (a weight of `head_dim`) on every query and key head, rotate-half rotary
+    on the whole head, over the `indexer_topk` keys s <= t that a lightning
+    indexer picks for every query (ops/dsa.py: `indexer_num_heads` index
+    queries of `indexer_head_dim`, ONE index key a token, I[t, s] = sum_j
+    w[t, j] relu(qI[t, j] . kI[s]); every key while t < `indexer_topk`);
+    the layer's second top is the indexer's own loss L_I = mean_t KL(p_t ||
+    softmax over the set of I[t]), p_t the main heads' probabilities summed
+    and normalised, a constant of that loss. The step's loss is the
+    cross-entropy plus the sum of the layers' L_I; the indexer reads
+    stop_gradient of the block's norm, so the cross-entropy reaches no
+    indexer blob and L_I nothing else. `MoE` is a no-drop top-k
+    MoE of SiLU-gated experts routed by softmax(W_r y) in float32 with the
+    chosen weights renormalised, no shared expert; untied embedding and
+    head; mean cross-entropy per token. Defaults are the published sizes.
+
+    Assumed, where the config has no key (the configuration file lists the
+    same): `mrope_section` splits the rotary frequencies over three position
+    streams that are one position on text, so the rotary is the plain one;
+    the query/key head norms (the decoder family's); the indexer's form from
+    DeepSeek-V3.2's report at this config's sizes: a LayerNorm on kI,
+    rotate-half rotary on the first half of every index query and of the
+    key, w = W_w h / sqrt(heads x head_dim); `q_chunk_size` /
+    `kv_chunk_size` are the tiles in which index scores are computed, no
+    unit of selection; ties at a query's threshold are all kept; L_I's
+    weight 1, summed over the layers; fillers gaussian(0.02) for matrices
+    and gaussian(1) for the embedding (as `smallthinker`: the head is
+    untied and the first mixer is attention, whose output all tokens
+    share). Left out: the vision tower and its projector, V3.2's dense
+    warm-up stage of the indexer, the router's auxiliary loss, dropout,
+    packing.
+
+    One chip's share of an expert-parallel group as in `qwen3_next`:
+    `experts_held` from `first_expert` on, `vocab_size` the held rows,
+    `num_hidden_layers` this pipeline stage's layers.
+
+    Layers are named block{i}/ln1 | attn | res1 | ln2 | moe | res2 (the
+    attention's second top block{i}/attn_kl): all blocks are alike and scan
+    as one run, their L_I riding out of the scan."""
+    e = hidden_size
+    gauss = dict(type="gaussian", std=0.02)
+    keep, nodecay = dict(lr_mult=1, decay_mult=1), dict(lr_mult=1,
+                                                        decay_mult=0)
+    layers = [
+        RDDLayer("data", [batch_size, seq_len]),
+        RDDLayer("label", [batch_size, seq_len]),
+        EmbedLayer("tok_embed", ["data"], vocab_size, e,
+                   weight_filler=dict(type="gaussian", std=1.0),
+                   bias_term=False),
+    ]
+    x = "tok_embed"
+    for i in range(num_hidden_layers):
+        p = f"block{i}"
+        layers += [
+            RMSNormLayer(f"{p}/ln1", [x], eps=rms_norm_eps,
+                         zero_centered=False, param=[nodecay]),
+            AttentionLayer(
+                f"{p}/attn", [f"{p}/ln1"], num_attention_heads,
+                head_dim=head_dim, causal=True, flash=flash,
+                num_kv_heads=num_key_value_heads, qk_norm=True,
+                qk_norm_zero_centered=False, rotary_dim=head_dim,
+                rope_theta=rope_theta, norm_eps=rms_norm_eps,
+                weight_filler=gauss, index_heads=indexer_num_heads,
+                index_head_dim=indexer_head_dim, index_topk=indexer_topk,
+                index_stats=index_stats,
+                param=[keep] * 4 + [nodecay] * 2 + [keep] * 3
+                + [nodecay] * 2),
+            EltwiseLayer(f"{p}/res1", [x, f"{p}/attn"]),
+            RMSNormLayer(f"{p}/ln2", [f"{p}/res1"], eps=rms_norm_eps,
+                         zero_centered=False, param=[nodecay]),
+            MoELayer(f"{p}/moe", [f"{p}/ln2"], num_experts,
+                     hidden_dim=moe_intermediate_size,
+                     top_k=num_experts_per_tok, experts_held=experts_held,
+                     first_expert=first_expert,
+                     norm_topk_prob=norm_topk_prob, weight_filler=gauss,
+                     stats=moe_stats),
+            EltwiseLayer(f"{p}/res2", [f"{p}/res1", f"{p}/moe"]),
+        ]
+        x = f"{p}/res2"
+    layers += [
+        RMSNormLayer("ln_f", [x], eps=rms_norm_eps, zero_centered=False,
+                     param=[nodecay]),
+        InnerProductLayer("lm_head", ["ln_f"], vocab_size,
+                          weight_filler=gauss, axis=2, bias_term=False),
+        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
+    ]
+    return NetParam("KeyeVL2", *layers)
+
+
 def transformer_lm_pieces(vocab_size=512, seq_len=256, batch_size=8,
                           d_model=256, num_heads=8, d_ff=None,
                           max_positions=None, flash=True):

@@ -37,6 +37,30 @@ same kernels as a block of its own width: every q, k, v, o tile fills half
 of each lane row, two heads are NOT paired into one row, and the `reason`
 says so.
 
+attention_param.index_heads / index_head_dim / index_topk (with causal and
+num_kv_heads): a learned index picks every query's keys, ops/dsa.py has
+the mathematics. Five more blobs after the head norms: W_qI (HI*DI, E),
+W_kI (DI, E), W_w (HI, E), the weight and bias (DI,) of a LayerNorm on the
+index key (the layer's norm_eps); rotate-half rotary at the layer's theta
+on the first half of every index query and of the key; the index
+heads' weights w = W_w h / sqrt(HI * DI) in float32. The indexer reads
+stop_gradient of the layer's input. A SECOND top, `<name>_kl`, carries the
+index's own loss L_I (a scalar; its loss_weight is the layer's second), and
+with index_stats a third of weight 0 (ops/dsa.py:selection_stats: the
+share of the selected keys that a window of index_topk would have caught
+and the mean keys a query; kept in the layer's state, which the solver
+records as `dsa.window` where it waits for a loss). The core
+is ops/pallas_dsa.py's five kernels where `flash` is set and 128 divides S,
+else the plain form; one `dsa.select` record a trace of the layer besides
+`attn.path`: `topk`, `tiles_causal` and `tiles_visited` (the key blocks of
+the causal half and those the core visits: all of them, a set is data and
+skips no tile), `mean_keys` a query, and the `select` and `core` forms in
+words. Its scopes, none inside another: dsa_index_proj (the three index
+products, the LayerNorm, the index rotary), dsa_select (the index scores by
+tiles and every query's threshold), attn_core (the masked flash passes; in
+the backward also the index's gradients, which need the heads'
+probabilities), dsa_kl (L_I's value).
+
 Everything the layer traces lies under one scope inside its own, in both
 forms, so that a device trace adds up by them: attn_proj_in (the q, k, v
 products with their weights' casts, the reshapes, the head norms, the
@@ -54,6 +78,7 @@ from ..parallel import context
 from ..parallel.ring import ring_attention, dense_attention
 from .convolution import _param_mults
 from .normalization import rms_norm
+from . import dsa
 
 
 def rotary(x, rotary_dim, theta):
@@ -115,6 +140,46 @@ class Attention(Layer):
         if self.gqa and self.ring:
             raise ValueError(f"{lp.name}: the grouped-query form has no "
                              "ring mode")
+        # a learned index picks the keys (ops/dsa.py)
+        self.index = p.has("index_heads") or p.has("index_topk") \
+            or p.has("index_head_dim")
+        if self.index:
+            if not (p.has("index_heads") and p.has("index_head_dim")
+                    and p.has("index_topk")):
+                raise ValueError(f"{lp.name}: an index needs index_heads, "
+                                 "index_head_dim and index_topk")
+            self.index_heads = int(p.index_heads)
+            self.index_dim = int(p.index_head_dim)
+            self.index_topk = int(p.index_topk)
+            self.index_stats = bool(p.index_stats)
+            # the statistics live in the layer's state, as the MoE's: the
+            # solver reads them where it already waits for a loss
+            self.has_state = self.index_stats
+            if self.index_stats:
+                self.monitor = ("dsa.window", ("window_share", "mean_keys"))
+            for bad, why in ((self.window, "a window"), (self.ring, "ring"),
+                             (not self.causal, "no causal mask"),
+                             (not self.gqa, "no num_kv_heads (the "
+                              "grouped-query form)"),
+                             (self.output_gate, "an output gate")):
+                if bad:
+                    raise ValueError(f"{lp.name}: an index picks the keys "
+                                     f"of causal grouped-query attention; "
+                                     f"it has no meaning with {why}")
+            if self.index_topk < 1 or self.index_heads < 1 \
+                    or self.index_dim < 1:
+                raise ValueError(
+                    f"{lp.name}: index_topk {self.index_topk}, index_heads "
+                    f"{self.index_heads} and index_head_dim "
+                    f"{self.index_dim} must be at least 1")
+            if self.index_dim % 4:
+                raise ValueError(
+                    f"{lp.name}: index_head_dim {self.index_dim} is no "
+                    "multiple of 4 (the rotary turns its first half)")
+
+    #: its loss top is a scalar that a scan over blocks may stack
+    #: (graph/compiler.py:_scan_runs)
+    scan_loss_tops = True
 
     def param_shapes(self):
         # unlike stock Caffe layers (default constant-0), an attention with
@@ -122,7 +187,7 @@ class Attention(Layer):
         wf = self.p.weight_filler if self.p.has("weight_filler") \
             else Message("FillerParameter", type="xavier")
         if self.gqa:
-            mults = _param_mults(self.lp, 6)
+            mults = _param_mults(self.lp, 11)
             kv = self.kv_heads * self.head_dim
             q_out = self.inner * (2 if self.output_gate else 1)
             shapes = [((q_out, self.embed), wf, *mults[0]),
@@ -134,6 +199,15 @@ class Attention(Layer):
                     "FillerParameter", type="constant", value=1.0)
                 shapes += [((self.head_dim,), fill, *mults[4]),
                            ((self.head_dim,), fill, *mults[5])]
+            if self.index:
+                at = len(shapes)
+                one = Message("FillerParameter", type="constant", value=1.0)
+                hi, di = self.index_heads, self.index_dim
+                shapes += [((hi * di, self.embed), wf, *mults[at]),
+                           ((di, self.embed), wf, *mults[at + 1]),
+                           ((hi, self.embed), wf, *mults[at + 2]),
+                           ((di,), one, *mults[at + 3]),
+                           ((di,), None, *mults[at + 4])]
             return shapes
         mults = _param_mults(self.lp, 4)
         return [
@@ -144,12 +218,22 @@ class Attention(Layer):
         ]
 
     def out_shapes(self):
-        return [tuple(self.bottom_shapes[0])]
+        out = [tuple(self.bottom_shapes[0])]
+        if self.index:
+            out += [()] + ([(2,)] if self.index_stats else [])
+        return out
+
+    def state_shapes(self):
+        return [((2,), 0.0)] if self.index and self.index_stats else []
+
+    def apply_stateful(self, params, state, bottoms, train, rng):
+        tops = self._apply_gqa(params, bottoms[0])
+        return tops, [tops[2]]
 
     def apply(self, params, bottoms, train, rng):
         x = bottoms[0]                                   # (B, S, E)
         if self.gqa:
-            return [self._apply_gqa(params, x)]
+            return self._apply_gqa(params, x)
         # every operation under one of three scopes (`rope` is the fourth,
         # in the grouped-query form): a device trace adds up by them
         with jax.named_scope("attn_proj_in"):
@@ -236,11 +320,71 @@ class Attention(Layer):
             k = rotary(k, self.rotary_dim, self.rope_theta)
         with jax.named_scope("attn_proj_in"):
             q, k, v = [jnp.moveaxis(a, 1, 2) for a in (q, k, v)]  # (B,H,S,D)
-        with jax.named_scope("attn_core"):
-            o = self._core(q, k, v)
+        extra = []
+        if self.index:
+            o, extra = self._core_index(q, k, v, x, params[-5:])
+        else:
+            with jax.named_scope("attn_core"):
+                o = self._core(q, k, v)
         with jax.named_scope("attn_proj_out"):
             o = jnp.moveaxis(o, 2, 1).reshape(b, s, h * d)
             if gate is not None:
                 o = o * jax.nn.sigmoid(
                     gate.astype(jnp.float32)).astype(o.dtype)
-            return o @ wo.T
+            return [o @ wo.T] + extra
+
+    def _core_index(self, q, k, v, x, blobs):
+        """-> (o (B, H, S, D), [L_I] or [L_I, stats]): the index's three
+        products from stop_gradient of the layer's input, then the core
+        over the keys it picks."""
+        b, s, _ = x.shape
+        hi, di, topk = self.index_heads, self.index_dim, self.index_topk
+        with jax.named_scope("dsa_index_proj"):
+            xi = jax.lax.stop_gradient(x)
+            wqi, wki, ww = [p.astype(x.dtype) for p in blobs[:3]]
+            qi = (xi @ wqi.T).reshape(b, s, hi, di)
+            kf = (xi @ wki.T).astype(jnp.float32)
+            kf = kf - jnp.mean(kf, axis=-1, keepdims=True)
+            kf = kf * jax.lax.rsqrt(jnp.mean(kf * kf, axis=-1, keepdims=True)
+                                    + self.norm_eps)
+            ki = (kf * blobs[3].astype(jnp.float32)
+                  + blobs[4].astype(jnp.float32)).astype(x.dtype)
+            w = jnp.einsum("bse,je->bjs", xi, ww,
+                           preferred_element_type=jnp.float32) \
+                * (hi * di) ** -0.5
+            qi = rotary(qi, di // 2, self.rope_theta)
+            ki = rotary(ki[:, :, None, :], di // 2,
+                        self.rope_theta)[:, :, 0, :]
+            qi = jnp.moveaxis(qi, 1, 2)                   # (B, HI, S, DI)
+        tracer = default_tracer()
+        if self.flash and s % 128 == 0:
+            # here and not at the top: see `_core`
+            from .pallas_dsa import causal_tiles, sparse_attention
+            path, reason = "kernel", "flash is set and 128 divides S"
+            tiles = causal_tiles(s)
+            o, kl = sparse_attention(q, k, v, qi, ki, w, topk, self.lp.name)
+        else:
+            path, tiles = "dense", 0
+            reason = "flash is not set" if not self.flash \
+                else f"128 does not divide the sequence length {s}"
+            with jax.named_scope("attn_core"):
+                o, kl = dsa.sparse_attention_plain(q, k, v, qi, ki, w, topk)
+        extra = [kl.astype(jnp.float32)]
+        if self.index_stats:
+            with jax.named_scope("dsa_stats"):
+                extra.append(dsa.selection_stats(qi, ki, w, topk))
+        now = tracer.now_ns()
+        core = "masked tiles of the causal half, the index tile recomputed " \
+            "in every kernel" if path == "kernel" else "whole score matrices"
+        select = "a threshold a query, the topk-th largest by 32 counting " \
+            "passes" if path == "kernel" else "jax.lax.top_k"
+        tracer.record("attn.path", now, now, layer=self.lp.name, path=path,
+                      reason=reason, window=0, live_blocks=tiles,
+                      causal_blocks=tiles, masked_blocks=tiles,
+                      head_dim=int(q.shape[-1]), core=core, select=select)
+        full = min(s, topk)     # queries up to here take every key
+        tracer.record("dsa.select", now, now, layer=self.lp.name, topk=topk,
+                      tiles_causal=tiles, tiles_visited=tiles,
+                      mean_keys=(full * (full + 1) / 2 + (s - full) * full)
+                      / s, select=select, core=core)
+        return o, extra

@@ -34,7 +34,9 @@ Two forms, chosen by moe_param.gated_experts:
   experts' pairs come first and each expert's are one contiguous group,
   unpadded; they run a WINDOW of rows at a time (`window_rows`, static:
   the pairs an even routing sends here and a quarter more, in whole
-  tiles of `tile_rows`, at most `WINDOW_TILES` tiles), as many windows
+  tiles of `tile_rows`, at most `WINDOW_TILES` tiles; a layer that names no
+  `tile_rows` takes `fit_tile`'s: 128, or the multiple of it at which that
+  quarter more still fits one window), as many windows
   as the routing needs: a loop of dynamic length, so memory is bound by
   one window's buffers and work by the routing, neither by the worst
   case of all tokens x top_k rows. A window is one gather of its rows of
@@ -181,6 +183,16 @@ def window_rows(n_tokens, top_k, held, num_experts, tile):
     even = n_tokens * top_k * held / num_experts
     most = n_tokens * min(top_k, held)
     return min(WINDOW_TILES, math.ceil(min(1.25 * even, most) / tile)) * tile
+
+
+def fit_tile(n_tokens, top_k, held, num_experts, tile=128):
+    """The row tile of a layer that names none: `tile`, in as many whole
+    multiples as it takes for an even routing's pairs and a quarter more to
+    fit the WINDOW_TILES tiles of one window. At 128 rows a share of 32,768
+    even pairs is the cap itself, and every other step spills a few rows
+    into a second window (18 ms, two step times in one run, PR 40)."""
+    even = n_tokens * top_k * held / num_experts
+    return tile * max(1, math.ceil(1.25 * even / (WINDOW_TILES * tile)))
 
 
 def plan_windows(pair_expert, held, window):
@@ -442,7 +454,8 @@ class MoE(Layer):
         self.held = int(p.experts_held) or self.num_experts
         self.first = int(p.first_expert)
         self.shared_hidden = int(p.shared_hidden_dim)
-        self.tile = int(p.tile_rows)
+        self.tile = int(p.tile_rows) if p.has("tile_rows") else fit_tile(
+            b * s, self.top_k, self.held, self.num_experts)
         self.act = str(p.expert_activation)
         self.router_bottom = len(bottom_shapes) > 1
         self.score = str(p.score_function)

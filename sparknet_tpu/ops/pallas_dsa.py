@@ -1,0 +1,547 @@
+"""Pallas kernels of attention over an index-picked key set (ops/dsa.py has
+the mathematics and the plain form): masked dense tiles, the mask made in
+the kernel from the indexer's tile.
+
+A per-query key set is data, so no block map can skip by it; gathering a
+query's 2,048 keys for 4 key-value heads of 128 is 4 MB a query. So the
+core runs the causal half's tiles, and every kernel that needs the set
+RECOMPUTES the indexer's (block_k, block_q) tile beside the main products
+— 16 products of depth 64 against 32 heads x 2 of depth 128, an eighth —
+and compares it with the query's threshold: `I[t, s] >= thr[t]`. For that
+one tile to serve all the query heads a grid step holds ALL of them: the
+grids are (batch, query block, key block) and the heads are a loop inside
+the step (the kernels of ops/pallas_attention.py put a head on the grid).
+The set is never stored: the threshold is 4 bytes a query.
+
+Five kernels, by their names in a device trace:
+
+- `dsa_index_select` (scope `dsa_select`): a block of queries against all
+  their keys: the index scores by tiles into a VMEM scratch as sortable
+  integers, then the `topk`-th largest of every query by 32 counting
+  passes over the scratch (one bit of the answer a pass; `jax.lax.top_k`
+  at k = 2,048 is a sort on a TPU), and the logsumexp of the selected
+  scores (L_I's softmax). Out: `thr` and `lse_i`, (B, 1, S) float32.
+- `flash_sparse_fwd` (scope `attn_core`): the flash recurrence over the
+  selected keys; out o and the heads' logsumexp.
+- `dsa_kl` (scope `dsa_kl`): L_I's value a query, from the heads'
+  probabilities summed in the tile (their scores once more).
+- `flash_sparse_dq`, `flash_sparse_dkv` (scope `attn_core`): the
+  FlashAttention-2 backward of the main loss AND, since they hold every
+  head's probabilities of a tile anyway, L_I's backward: dI = softmax(I) -
+  mean_h P_h on the set, then dw, d qI (dq's grid) and d kI (dkv's grid).
+  The two cotangents do not mix: dq, dk, dv read dO alone, the indexer's
+  three read L_I's alone.
+
+A score tile is held transposed, (block_k, block_q), as in
+ops/pallas_attention.py: a query's statistics are (1, block_q) rows.
+The backward reads the forward's `thr`: `graph/remat.py:keep` holds it
+(with `lse_i`, o and the logsumexp) across a block's replay, so the index
+scores' selection runs once a step.
+
+The indexer's tile must come out bit for bit alike in all five kernels (a
+key at the threshold is in the set everywhere or nowhere): one helper,
+`_index_tile`, the same operations in the same order, the products of
+depth 64 whole.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..graph.remat import keep
+from .pallas_attention import NEG_INF, _fit_block
+from .pallas_lrn import _should_interpret
+
+INT_MIN = np.int32(-2 ** 31)
+VMEM_LIMIT = 100 * 1024 * 1024
+
+_NT = (((1,), (1,)), ((), ()))      # (m, d) x (n, d) -> (m, n)
+_NN = (((1,), (0,)), ((), ()))      # (m, k) x (k, n) -> (m, n)
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _params():
+    """Every grid here is (batch, an outer block, the streamed block); a
+    step holds all the heads of its block, more than the default limit."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT)
+
+
+def _index_parts(ki_ref, qi_ref, j):
+    """x_j^T (bk, bq) = kI . qI_j of index head j, float32."""
+    return _dot(ki_ref[0].astype(jnp.float32),
+                qi_ref[0, j].astype(jnp.float32), _NT)
+
+
+def _index_tile(ki_ref, qi_ref, w_ref):
+    """I^T (bk, bq) = sum_j w_j relu(x_j), in the one order every kernel
+    shares."""
+    acc = None
+    for j in range(qi_ref.shape[1]):
+        term = w_ref[0, j] * jnp.maximum(_index_parts(ki_ref, qi_ref, j), 0.0)
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _seen(qi, kj, bq, bk):
+    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+    k_pos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+    return q_pos >= k_pos
+
+
+def _set_tile(ki_ref, qi_ref, w_ref, thr_ref, qi, kj):
+    """(the key set's tile (bk, bq) bool, I^T of the tile)."""
+    scores = _index_tile(ki_ref, qi_ref, w_ref)
+    bk, bq = scores.shape
+    return (scores >= thr_ref[0]) & _seen(qi, kj, bq, bk), scores
+
+
+def _sortable(x):
+    """float32 -> int32 whose signed order is the floats' (-0.0 made +0.0
+    first, so that equal floats are equal integers)."""
+    bits = jax.lax.bitcast_convert_type(x + 0.0, jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+def _unsortable(key):
+    return jax.lax.bitcast_convert_type(
+        jnp.where(key < 0, key ^ jnp.int32(0x7FFFFFFF), key), jnp.float32)
+
+
+# ---------------------------------------------------------------- selection
+
+def _select_kernel(qi_ref, ki_ref, w_ref, thr_ref, lse_ref, keys_ref, *,
+                   topk):
+    r, c, nc = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    bq, bk = qi_ref.shape[2], ki_ref.shape[1]
+    last = (r * bq + bq - 1) // bk          # the last chunk with a seen key
+
+    @pl.when(c <= last)
+    def _scores():
+        keys = _sortable(_index_tile(ki_ref, qi_ref, w_ref))
+        keys_ref[pl.ds(pl.multiple_of(c * bk, bk), bk), :] = jnp.where(
+            _seen(r, c, bq, bk), keys, INT_MIN)
+
+    @pl.when(c == nc - 1)
+    def _select():
+        def over_chunks(fn, init):
+            def chunk(i, acc):
+                return fn(acc, keys_ref[pl.ds(pl.multiple_of(i * bk, bk),
+                                              bk), :])
+            return jax.lax.fori_loop(0, last + 1, chunk, init)
+
+        def bit(i, prefix):
+            # the answer's bits from the top: keep a bit where at least
+            # `topk` keys are still at or above the candidate (flipping the
+            # sign bit of INT_MIN first: the non-negative half lies above)
+            cand = prefix ^ jnp.left_shift(jnp.int32(1), 31 - i)
+            count = over_chunks(
+                lambda acc, blk: acc + jnp.sum(
+                    jnp.where(blk >= cand, 1.0, 0.0), axis=0, keepdims=True),
+                jnp.zeros((1, bq), jnp.float32))
+            return jnp.where(count >= topk, cand, prefix)
+
+        kth = jax.lax.fori_loop(0, 32, bit,
+                                jnp.full((1, bq), INT_MIN, jnp.int32))
+        pos = r * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
+        # a query with no more than topk keys takes them all
+        cut = jnp.where(pos < topk, INT_MIN + 1, kth)
+        top = _unsortable(over_chunks(
+            lambda acc, blk: jnp.maximum(
+                acc, jnp.max(blk, axis=0, keepdims=True)),
+            jnp.full((1, bq), INT_MIN + 1, jnp.int32)))
+        total = over_chunks(
+            lambda acc, blk: acc + jnp.sum(jnp.where(
+                blk >= cut, jnp.exp(_unsortable(blk) - top), 0.0),
+                axis=0, keepdims=True),
+            jnp.zeros((1, bq), jnp.float32))
+        thr_ref[0] = jnp.where(pos < topk, -jnp.inf, _unsortable(kth))
+        lse_ref[0] = top + jnp.log(total)
+
+
+def _select(qi, ki, w, topk, block_q, block_k, interpret):
+    """-> thr, lse_i, each (B, 1, S) float32."""
+    b, hi, s, di = qi.shape
+    bq, bk = _fit_block(block_q, s), _fit_block(block_k, s)
+
+    def chunks(bb, r, c):
+        return (bb, jnp.minimum(c, (r * bq + bq - 1) // bk), 0)
+    row = pl.BlockSpec((1, 1, bq), lambda bb, r, c: (bb, 0, r))
+    return pl.pallas_call(
+        functools.partial(_select_kernel, topk=topk),
+        grid=(b, s // bq, s // bk),
+        in_specs=[pl.BlockSpec((1, hi, bq, di),
+                               lambda bb, r, c: (bb, 0, r, 0)),
+                  pl.BlockSpec((1, bk, di), chunks),
+                  pl.BlockSpec((1, hi, 1, bq),
+                               lambda bb, r, c: (bb, 0, 0, r))],
+        out_specs=[row, row],
+        out_shape=[jax.ShapeDtypeStruct((b, 1, s), jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((s, bq), jnp.int32)],
+        compiler_params=_params(), interpret=interpret,
+        name="dsa_index_select",
+    )(qi, ki, w)
+
+
+# ------------------------------------------------------------------ forward
+
+def _masked_scores(k_ref, q_ref, h, grp, scale, sel):
+    """(scores^T (bk, bq) of head h on the set, -1e30 off it; q scaled)."""
+    qs = q_ref[0, h].astype(jnp.float32) * scale
+    sc = _dot(k_ref[0, h // grp].astype(jnp.float32), qs, _NT)
+    return jnp.where(sel, sc, NEG_INF), qs
+
+
+def _fwd_kernel(q_ref, k_ref, vt_ref, qi_ref, ki_ref, w_ref, thr_ref, o_ref,
+                lse_ref, acc_ref, m_ref, l_ref, *, scale, grp):
+    qi, kj, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    heads, bq, bk = q_ref.shape[1], q_ref.shape[2], k_ref.shape[2]
+
+    @pl.when(kj == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(kj <= (qi * bq + bq - 1) // bk)
+    def _live():
+        sel, _ = _set_tile(ki_ref, qi_ref, w_ref, thr_ref, qi, kj)
+
+        def head(h, carry):
+            sc, _ = _masked_scores(k_ref, q_ref, h, grp, scale, sel)
+            m_prev = m_ref[h]                                   # (1, bq)
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(sc - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=0, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + _dot(
+                vt_ref[0, h // grp].astype(jnp.float32), p, _NN)
+            m_ref[h] = m_new
+            return carry
+        jax.lax.fori_loop(0, heads, head, 0)
+
+    @pl.when(kj == nk - 1)
+    def _finish():
+        def head(h, carry):
+            l = jnp.maximum(l_ref[h], 1e-30)
+            o_ref[0, h] = (acc_ref[h] / l).T.astype(o_ref.dtype)
+            lse_ref[0, h] = m_ref[h] + jnp.log(l)
+            return carry
+        jax.lax.fori_loop(0, heads, head, 0)
+
+
+def _specs(b, h, hkv, hi, s, d, di, bq, bk):
+    """Block specs of the (batch, query block, key step) grids; a dead step
+    above the diagonal holds the last live key block (nothing fetched)."""
+    def kcol(j, i):
+        return jnp.minimum(j, (i * bq + bq - 1) // bk)
+    return {
+        "q": pl.BlockSpec((1, h, bq, d), lambda bb, i, j: (bb, 0, i, 0)),
+        "k": pl.BlockSpec((1, hkv, bk, d),
+                          lambda bb, i, j: (bb, 0, kcol(j, i), 0)),
+        "kt": pl.BlockSpec((1, hkv, d, bk),
+                           lambda bb, i, j: (bb, 0, 0, kcol(j, i))),
+        "stat": pl.BlockSpec((1, h, 1, bq), lambda bb, i, j: (bb, 0, 0, i)),
+        "qi": pl.BlockSpec((1, hi, bq, di), lambda bb, i, j: (bb, 0, i, 0)),
+        "ki": pl.BlockSpec((1, bk, di), lambda bb, i, j: (bb, kcol(j, i), 0)),
+        "kit": pl.BlockSpec((1, di, bk),
+                            lambda bb, i, j: (bb, 0, kcol(j, i))),
+        "w": pl.BlockSpec((1, hi, 1, bq), lambda bb, i, j: (bb, 0, 0, i)),
+        "row": pl.BlockSpec((1, 1, bq), lambda bb, i, j: (bb, 0, i)),
+    }
+
+
+def _forward(q, k, v, qi, ki, w, thr, scale, bq, bk, interpret):
+    """-> o (B, H, S, D), the heads' logsumexp (B, H, 1, S) float32."""
+    b, h, s, d = q.shape
+    hkv, hi, di = k.shape[1], qi.shape[1], qi.shape[3]
+    sp = _specs(b, h, hkv, hi, s, d, di, bq, bk)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, grp=h // hkv),
+        grid=(b, s // bq, s // bk),
+        in_specs=[sp["q"], sp["k"], sp["kt"], sp["qi"], sp["ki"], sp["w"],
+                  sp["row"]],
+        out_specs=[sp["q"], sp["stat"]],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((h, d, bq), jnp.float32),
+                        pltpu.VMEM((h, 1, bq), jnp.float32),
+                        pltpu.VMEM((h, 1, bq), jnp.float32)],
+        compiler_params=_params(), interpret=interpret,
+        name="flash_sparse_fwd",
+    )(q, k, jnp.swapaxes(v, 2, 3), qi, ki, w, thr)
+
+
+# ---------------------------------------------------------------- L_I value
+
+def _heads_probs(k_ref, q_ref, lse_ref, grp, scale, sel, each=None):
+    """sum over the heads of P_h^T = exp(S_h - LSE_h) on the set, (bk, bq);
+    `each(h, p, qs)` sees every head's tile on the way."""
+    def head(h, total):
+        sc, qs = _masked_scores(k_ref, q_ref, h, grp, scale, sel)
+        p = jnp.exp(sc - lse_ref[0, h])
+        if each is not None:
+            each(h, p, qs)
+        return total + p
+    return jax.lax.fori_loop(0, q_ref.shape[1], head,
+                             jnp.zeros(sel.shape, jnp.float32))
+
+
+def _kl_kernel(q_ref, k_ref, qi_ref, ki_ref, w_ref, thr_ref, lse_ref,
+               lsei_ref, kl_ref, acc_ref, *, scale, grp):
+    qi, kj, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    heads, bq, bk = q_ref.shape[1], q_ref.shape[2], k_ref.shape[2]
+
+    @pl.when(kj == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(kj <= (qi * bq + bq - 1) // bk)
+    def _live():
+        sel, scores = _set_tile(ki_ref, qi_ref, w_ref, thr_ref, qi, kj)
+        target = _heads_probs(k_ref, q_ref, lse_ref, grp, scale, sel) \
+            * (1.0 / heads)
+        live = target > 0
+        term = target * (jnp.log(jnp.where(live, target, 1.0))
+                         - (scores - lsei_ref[0]))
+        acc_ref[:] += jnp.sum(jnp.where(live, term, 0.0), axis=0,
+                              keepdims=True)
+
+    @pl.when(kj == nk - 1)
+    def _finish():
+        kl_ref[0] = acc_ref[:]
+
+
+def _kl_rows(q, k, qi, ki, w, thr, lse, lse_i, scale, bq, bk, interpret):
+    """-> KL(p_t || softmax_{S_t} I[t]) a query, (B, 1, S) float32."""
+    b, h, s, d = q.shape
+    hkv, hi, di = k.shape[1], qi.shape[1], qi.shape[3]
+    sp = _specs(b, h, hkv, hi, s, d, di, bq, bk)
+    return pl.pallas_call(
+        functools.partial(_kl_kernel, scale=scale, grp=h // hkv),
+        grid=(b, s // bq, s // bk),
+        in_specs=[sp["q"], sp["k"], sp["qi"], sp["ki"], sp["w"], sp["row"],
+                  sp["stat"], sp["row"]],
+        out_specs=sp["row"],
+        out_shape=jax.ShapeDtypeStruct((b, 1, s), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, bq), jnp.float32)],
+        compiler_params=_params(), interpret=interpret, name="dsa_kl",
+    )(q, k, qi, ki, w, thr, lse, lse_i)
+
+
+# ----------------------------------------------------------------- backward
+
+def _index_grad(scores, total, heads, sel, lsei_ref):
+    """dI^T (bk, bq) of L_I's SUM over the queries: softmax(I) - mean_h P_h
+    on the set."""
+    return jnp.where(sel, jnp.exp(scores - lsei_ref[0])
+                     - total * (1.0 / heads), 0.0)
+
+
+def _dq_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref,
+               qi_ref, ki_ref, kit_ref, w_ref, thr_ref, lsei_ref, dq_ref,
+               dqi_ref, dw_ref, acc_ref, acci_ref, accw_ref, *, scale, grp):
+    qi, kj, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    heads, bq, bk = q_ref.shape[1], q_ref.shape[2], k_ref.shape[2]
+    n_index = qi_ref.shape[1]
+
+    @pl.when(kj == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        acci_ref[:] = jnp.zeros_like(acci_ref)
+        accw_ref[:] = jnp.zeros_like(accw_ref)
+
+    @pl.when(kj <= (qi * bq + bq - 1) // bk)
+    def _live():
+        sel, scores = _set_tile(ki_ref, qi_ref, w_ref, thr_ref, qi, kj)
+
+        def each(h, p, qs):
+            dp = _dot(v_ref[0, h // grp].astype(jnp.float32),
+                      do_ref[0, h].astype(jnp.float32), _NT)
+            ds = p * (dp - delta_ref[0, h])
+            # dQ^T += K^T dS^T, (d, bq)
+            acc_ref[h] += _dot(kt_ref[0, h // grp].astype(jnp.float32), ds,
+                               _NN)
+        total = _heads_probs(k_ref, q_ref, lse_ref, grp, scale, sel, each)
+        di = _index_grad(scores, total, heads, sel, lsei_ref)
+        kit = kit_ref[0].astype(jnp.float32)
+        for j in range(n_index):
+            x = _index_parts(ki_ref, qi_ref, j)
+            accw_ref[j] += jnp.sum(di * jnp.maximum(x, 0.0), axis=0,
+                                   keepdims=True)
+            dx = jnp.where(x > 0, di * w_ref[0, j], 0.0)
+            acci_ref[j] += _dot(kit, dx, _NN)                  # (di, bq)
+
+    @pl.when(kj == nk - 1)
+    def _finish():
+        def head(h, carry):
+            dq_ref[0, h] = (acc_ref[h] * scale).T.astype(dq_ref.dtype)
+            return carry
+        jax.lax.fori_loop(0, heads, head, 0)
+        for j in range(n_index):
+            dqi_ref[0, j] = acci_ref[j].T
+            dw_ref[0, j] = accw_ref[j]
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
+                ki_ref, w_ref, thr_ref, lsei_ref, dk_ref, dv_ref, dki_ref,
+                dk_acc, dv_acc, dki_acc, *, scale, grp):
+    kj, qi, nq = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    heads, bq, bk = q_ref.shape[1], q_ref.shape[2], k_ref.shape[2]
+    n_index = qi_ref.shape[1]
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dki_acc[:] = jnp.zeros_like(dki_acc)
+
+    @pl.when(qi >= (kj * bk) // bq)
+    def _live():
+        sel, scores = _set_tile(ki_ref, qi_ref, w_ref, thr_ref, qi, kj)
+
+        def each(h, p, qs):
+            do = do_ref[0, h].astype(jnp.float32)
+            dv_acc[h // grp] += _dot(p, do, _NN)
+            dp = _dot(v_ref[0, h // grp].astype(jnp.float32), do, _NT)
+            ds = p * (dp - delta_ref[0, h])
+            dk_acc[h // grp] += _dot(ds, qs, _NN)
+        total = _heads_probs(k_ref, q_ref, lse_ref, grp, scale, sel, each)
+        di = _index_grad(scores, total, heads, sel, lsei_ref)
+        for j in range(n_index):
+            x = _index_parts(ki_ref, qi_ref, j)
+            dx = jnp.where(x > 0, di * w_ref[0, j], 0.0)
+            dki_acc[:] += _dot(dx, qi_ref[0, j].astype(jnp.float32), _NN)
+
+    @pl.when(qi == nq - 1)
+    def _finish():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dki_ref[0] = dki_acc[:]
+
+
+def _backward(q, k, v, qi, ki, w, thr, lse_i, o, lse, g, scale, bq, bk,
+              interpret):
+    """-> dq, dk, dv of the main loss (cotangent `g` of o) and d qI, d kI,
+    d w of L_I's SUM over the queries, float32 (the caller scales them)."""
+    b, h, s, d = q.shape
+    hkv, hi, di = k.shape[1], qi.shape[1], qi.shape[3]
+    grp = h // hkv
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1)[:, :, None, :]                 # (B, H, 1, S)
+    sp = _specs(b, h, hkv, hi, s, d, di, bq, bk)
+    dq, dqi, dw = pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, grp=grp),
+        grid=(b, s // bq, s // bk),
+        in_specs=[sp["q"], sp["k"], sp["kt"], sp["k"], sp["q"], sp["stat"],
+                  sp["stat"], sp["qi"], sp["ki"], sp["kit"], sp["w"],
+                  sp["row"], sp["row"]],
+        out_specs=[sp["q"], sp["qi"], sp["w"]],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(qi.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(w.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((h, d, bq), jnp.float32),
+                        pltpu.VMEM((hi, di, bq), jnp.float32),
+                        pltpu.VMEM((hi, 1, bq), jnp.float32)],
+        compiler_params=_params(), interpret=interpret,
+        name="flash_sparse_dq",
+    )(q, k, jnp.swapaxes(k, 2, 3), v, g, lse, delta, qi, ki,
+      jnp.swapaxes(ki, 1, 2), w, thr, lse_i)
+
+    # key block outermost, query blocks streamed: a dead step below the
+    # diagonal holds the first live query block
+    def qrow(i, j):
+        return jnp.maximum(i, (j * bk) // bq)
+    qT = pl.BlockSpec((1, h, bq, d), lambda bb, j, i: (bb, 0, qrow(i, j), 0))
+    kT = pl.BlockSpec((1, hkv, bk, d), lambda bb, j, i: (bb, 0, j, 0))
+    statT = pl.BlockSpec((1, h, 1, bq),
+                         lambda bb, j, i: (bb, 0, 0, qrow(i, j)))
+    qiT = pl.BlockSpec((1, hi, bq, di),
+                       lambda bb, j, i: (bb, 0, qrow(i, j), 0))
+    kiT = pl.BlockSpec((1, bk, di), lambda bb, j, i: (bb, j, 0))
+    wT = pl.BlockSpec((1, hi, 1, bq), lambda bb, j, i: (bb, 0, 0, qrow(i, j)))
+    rowT = pl.BlockSpec((1, 1, bq), lambda bb, j, i: (bb, 0, qrow(i, j)))
+    dk, dv, dki = pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, grp=grp),
+        grid=(b, s // bk, s // bq),
+        in_specs=[qT, kT, kT, qT, statT, statT, qiT, kiT, wT, rowT, rowT],
+        out_specs=[kT, kT, kiT],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(ki.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hkv, bk, d), jnp.float32),
+                        pltpu.VMEM((hkv, bk, d), jnp.float32),
+                        pltpu.VMEM((bk, di), jnp.float32)],
+        compiler_params=_params(), interpret=interpret,
+        name="flash_sparse_dkv",
+    )(q, k, v, g, lse, delta, qi, ki, w, thr, lse_i)
+    return dq, dk, dv, dqi, dki, dw
+
+
+# ------------------------------------------------------------ the function
+
+def blocks(s, block_q=512, block_k=512, select_q=128, select_k=1024):
+    """The tiles a sequence of `s` is cut into: (query block, key block) of
+    the three core kernels and of `dsa_kl`, then of `dsa_index_select`."""
+    return (_fit_block(block_q, s), _fit_block(block_k, s),
+            _fit_block(select_q, s), _fit_block(select_k, s))
+
+
+def causal_tiles(s, block_q=512, block_k=512):
+    """Key blocks a core kernel visits, summed over the query blocks: the
+    causal half's, every one of them masked by the set."""
+    bq, bk = blocks(s, block_q, block_k)[:2]
+    return int(np.sum((np.arange(s // bq) * bq + bq - 1) // bk + 1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def sparse_attention(q, k, v, qi, ki, w, topk, layer=None):
+    """-> (o (B, H, S, D), L_I ()) of ops/dsa.py: q (B, H, S, D); k, v
+    (B, Hkv, S, D); qi (B, HI, S, DI); ki (B, S, DI); w (B, HI, S)
+    float32. `layer` names the caller in the `remat.kept` records."""
+    return _fwd(q, k, v, qi, ki, w, topk, layer)[0]
+
+
+def _fwd(q, k, v, qi, ki, w, topk, layer):
+    b, _, s, d = q.shape
+    scale = 1.0 / (d ** 0.5)
+    bq, bk, sq, sk = blocks(s)
+    interpret = _should_interpret()
+    w4 = w.astype(jnp.float32)[:, :, None, :]
+    with jax.named_scope("dsa_select"):
+        thr, lse_i = _select(qi, ki, w4, topk, sq, sk, interpret)
+        thr, lse_i = keep(thr, layer, "thr"), keep(lse_i, layer, "lse_i")
+    with jax.named_scope("attn_core"):
+        o, lse = _forward(q, k, v, qi, ki, w4, thr, scale, bq, bk, interpret)
+        o, lse = keep(o, layer, "o"), keep(lse, layer, "lse")
+    with jax.named_scope("dsa_kl"):
+        kl = jnp.sum(_kl_rows(q, k, qi, ki, w4, thr, lse, lse_i, scale, bq,
+                              bk, interpret)) / (b * s)
+    return (o, kl), (q, k, v, qi, ki, w, thr, lse_i, o, lse)
+
+
+def _bwd(topk, layer, res, cts):
+    q, k, v, qi, ki, w, thr, lse_i, o, lse = res
+    g, g_kl = cts
+    b, _, s, d = q.shape
+    bq, bk = blocks(s)[:2]
+    with jax.named_scope("attn_core"):
+        dq, dk, dv, dqi, dki, dw = _backward(
+            q, k, v, qi, ki, w.astype(jnp.float32)[:, :, None, :], thr,
+            lse_i, o, lse, g, 1.0 / (d ** 0.5), bq, bk, _should_interpret())
+        share = g_kl.astype(jnp.float32) / (b * s)
+        return (dq, dk, dv, (dqi * share).astype(qi.dtype),
+                (dki * share).astype(ki.dtype),
+                (dw[:, :, 0, :] * share).astype(w.dtype))
+
+
+sparse_attention.defvjp(_fwd, _bwd)

@@ -596,6 +596,18 @@ MESSAGES = {
         # y = x / rms(x) * w with w filled with 1 (True, the default:
         # y = x / rms(x) * (1 + w), w filled with 0)
         "qk_norm_zero_centered": (14, "bool", "opt", True),
+        # a learned index picks every query's keys (ops/dsa.py; with
+        # causal and num_kv_heads): index_heads index queries of
+        # index_head_dim and ONE index key a token, rotary on the first
+        # half of them, a LayerNorm (the layer's norm_eps) on the key; the
+        # index_topk keys with the largest index score are the query's. A
+        # second top carries the index's own KL loss; with index_stats a
+        # third (weight 0) the share of the selected keys inside a window
+        # of index_topk.
+        "index_heads": (15, "uint32", "opt", None),
+        "index_head_dim": (16, "uint32", "opt", None),
+        "index_topk": (17, "uint32", "opt", None),
+        "index_stats": (18, "bool", "opt", False),
     },
     # sparknet_tpu extension: last-axis RMS norm, y = x / rms(x) * (1 + w)
     # (zero_centered, w filled with 0) or * w (w filled with 1).

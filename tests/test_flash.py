@@ -449,3 +449,30 @@ def test_causal_kernels_at_the_accepted_heads_trace_as_before(shape):
     assert "/root" not in text and "0x" not in text    # no path, no address
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         CAUSAL_JAXPRS[shape]
+
+
+# The kernels of attention over an index-picked key set (PR 40) live in a
+# file of their own, ops/pallas_dsa.py, and share two names with this one
+# (`NEG_INF`, `_fit_block`): the window form's trace is held here beside the
+# causal form's above, so that a change made for that model in the shared
+# tile code (`_scores`, `_live_tiles`, `_block_maps`, `edge_blocks`) shows.
+# (heads, kv heads, head size, window) at S 256 and blocks of 128.
+WINDOW_JAXPRS = {
+    (4, 2, 128, 64): "80c5671c70c04caf",
+    (4, 1, 64, 96): "15ba2f84258a756c"}
+
+
+@pytest.mark.parametrize("shape", list(WINDOW_JAXPRS))
+def test_window_kernels_trace_as_before_the_index_picked_form(shape):
+    import hashlib
+    h, hkv, d, window = shape
+    q = jnp.zeros((1, h, 256, d), jnp.bfloat16)
+    k = jnp.zeros((1, hkv, 256, d), jnp.bfloat16)
+    step = jax.value_and_grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, True, None, 128, 128, window).astype(jnp.float32)),
+        (0, 1, 2))
+    text = str(jax.make_jaxpr(step)(q, k, k))
+    assert "/root" not in text and "0x" not in text    # no path, no address
+    assert "flash_swa_fwd" in text and "flash_sparse" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        WINDOW_JAXPRS[shape]

@@ -354,6 +354,30 @@ def test_window_rows_is_static_and_sized_by_an_even_routing():
     assert moe_ops.window_rows(3, 2, 8, 8, 8) == 8
 
 
+@pytest.mark.parametrize("shape,top_k,held,experts,named,tile", [
+    ((2, 8192, 128), 10, 16, 512, None, 128),    # the Qwen3-Next cell
+    ((2, 16384, 128), 6, 8, 64, None, 128),      # the SmallThinker cell
+    ((3, 8192, 128), 4, 8, 32, None, 128),       # the LFM2 cell
+    # 32,768 even pairs: a quarter more is 40,960 rows, 256 tiles of 160 do
+    # not exist, so two 128s a tile (the Keye cell; the LFM2 net at batch 4)
+    ((1, 32768, 128), 8, 16, 128, None, 256),
+    ((4, 8192, 128), 4, 8, 32, None, 256),
+    # a tile the net names is the layer's, whatever spills
+    ((1, 32768, 128), 8, 16, 128, 128, 128),
+    ((1, 16, 128), 2, 4, 8, 8, 8)])
+def test_a_layer_that_names_no_tile_gets_one_its_even_share_fits(
+        shape, top_k, held, experts, named, tile):
+    lp = dsl.MoELayer("moe", ["x"], experts, hidden_dim=128, top_k=top_k,
+                      experts_held=held, first_expert=0, tile_rows=named)
+    impl = get_layer(lp.type)(lp, [shape], 0)
+    assert impl.tile == tile
+    n = shape[0] * shape[1]
+    even = n * top_k * held / experts
+    if named is None:
+        assert moe_ops.window_rows(n, top_k, held, experts, tile) \
+            >= 1.25 * even
+
+
 def moe_paths():
     return [(s["layer"], s["path"], s["reason"], s["combine"], s["segment"])
             for s in default_tracer().spans("moe.path")]

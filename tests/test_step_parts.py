@@ -168,7 +168,15 @@ def test_the_readers_list_of_inner_scopes_is_the_programs():
         with open(os.path.join(os.path.dirname(ops.__file__),
                                mod + ".py")) as f:
             opened |= set(re.findall(r'named_scope\("(\w+)"\)', f.read()))
-    assert opened == set(step_parts.INNER)
+    # an index-picked key set's scopes (PR 40: `dsa_index_proj` and the
+    # opt-in `dsa_stats` here, `dsa_select` and `dsa_kl` in
+    # ops/pallas_dsa.py) are not the benchmark's: an operation under one of
+    # them counts under the layer's part, `attn`, which keeps the ledger
+    # closed without an edit to `INNER`
+    assert {s for s in opened if not s.startswith("dsa_")} == \
+        set(step_parts.INNER)
+    assert {s for s in opened if s.startswith("dsa_")} == \
+        {"dsa_index_proj", "dsa_stats"}
 
 
 def test_iter_size_accumulates_under_its_own_scope():
@@ -502,7 +510,11 @@ def test_the_six_readers_name_no_layer_and_read_one_ledger(monkeypatch):
         cells = meta.pop("workloads")
         assert mod.META == meta
         assert mod.read({}) == want[name]
-        assert len(cells) == (7 if name == "step_unscoped_ms" else 3)
+        # the cells PR 38 gave them come first; a later cell is appended
+        first = 4 if name == "step_unscoped_ms" else 0
+        assert cells[first:first + 3] == ["qwen3next_ep32_s8192_b2",
+                                          "smallthinker_ep8_s16384_b2",
+                                          "lfm2moe_ep4_s8192_b3"]
     monkeypatch.setattr(step_parts, "ledger", lambda ctx: None)
     assert all(importlib.import_module(f"layer_metrics.{n}").read({}) is None
                for n in names)

@@ -25,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from sparknet_tpu.ops import moe as moe_ops
 from sparknet_tpu.ops import pallas_attention as pa
 from sparknet_tpu.ops import pallas_deltanet as pd
+from sparknet_tpu.ops import pallas_dsa
 from sparknet_tpu.ops import pallas_epilogue as pe
 from sparknet_tpu.ops import pallas_lrn as plrn
 from sparknet_tpu.ops import pallas_moe as pm
@@ -420,3 +421,39 @@ def test_flash_window_kernels_compile(one_chip, which):
         q, k, v, o, lse, g, True, scale, 512, 512, False, window),
         one_chip, q, kv, kv, q, (lse.shape, lse.dtype), q,
         kernels=["flash_swa_dq", "flash_swa_dkv"])
+
+
+# attention over an index-picked key set at the Keye cell's shape: 32 query
+# heads on 4 key-value heads of 128, 16 index heads of 64 and one index key,
+# S = 32,768, topk 2,048; all the heads of a query block in one grid step
+# (30 MB of VMEM under the kernels' own limit), the selection's scratch of
+# 32,768 x 128 sortable integers (16 MB)
+@pytest.mark.parametrize("which", ["select", "forward", "kl", "backward"])
+def test_index_picked_attention_kernels_compile(one_chip, which):
+    b, h, hk, s, d, hi, di, topk = 1, 32, 4, 32768, 128, 16, 64, 2048
+    bf, f32 = jnp.bfloat16, jnp.float32
+    q, kv = ((b, h, s, d), bf), ((b, hk, s, d), bf)
+    qi, ki, w = ((b, hi, s, di), bf), ((b, s, di), bf), ((b, hi, 1, s), f32)
+    row, stat = ((b, 1, s), f32), ((b, h, 1, s), f32)
+    bq, bk, sq, sk = pallas_dsa.blocks(s)
+    assert (bq, bk, sq, sk) == (512, 512, 128, 1024)
+    scale = d ** -0.5
+    if which == "select":
+        _compile(lambda qi, ki, w: pallas_dsa._select(
+            qi, ki, w, topk, sq, sk, False), one_chip, qi, ki, w,
+            kernels=["dsa_index_select"])
+    elif which == "forward":
+        _compile(lambda q, k, v, qi, ki, w, thr: pallas_dsa._forward(
+            q, k, v, qi, ki, w, thr, scale, bq, bk, False),
+            one_chip, q, kv, kv, qi, ki, w, row,
+            kernels=["flash_sparse_fwd"])
+    elif which == "kl":
+        _compile(lambda q, k, qi, ki, w, thr, lse, lse_i: pallas_dsa._kl_rows(
+            q, k, qi, ki, w, thr, lse, lse_i, scale, bq, bk, False),
+            one_chip, q, kv, qi, ki, w, row, stat, row, kernels=["dsa_kl"])
+    else:
+        _compile(lambda q, k, v, qi, ki, w, thr, lse_i, o, lse, g:
+                 pallas_dsa._backward(q, k, v, qi, ki, w, thr, lse_i, o, lse,
+                                      g, scale, bq, bk, False),
+                 one_chip, q, kv, kv, qi, ki, w, row, row, q, stat, q,
+                 kernels=["flash_sparse_dq", "flash_sparse_dkv"])
