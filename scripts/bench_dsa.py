@@ -1,19 +1,24 @@
 #!/usr/bin/env python3
-"""The five kernels of ops/pallas_dsa.py alone, one layer at the Keye cell's
-shape: what the selection, the masked core, L_I's value and the two
-backward kernels cost, and whether the set comes out alike in all of them
-(every query past `topk` must count exactly `topk` keys in the core's own
-recomputed tiles, ties aside).
+"""The four kernels of ops/pallas_dsa.py alone, one layer at the Keye cell's
+shape: what the selection, the masked core, L_I's value and the backward
+cost, and whether the set comes out alike in all of them (every query past
+`topk` must count exactly `topk` keys in the core's own recomputed tiles,
+ties aside).
 
     chiprun --chips 1 -- python3 scripts/bench_dsa.py
     JAX_PLATFORMS=cpu python3 scripts/bench_dsa.py --toy      # rehearsal
 
 Each row is the median of `--reps` calls of one jitted function after a
 warm-up call, the host's clock round `block_until_ready`. Printed and
-written as JSON under chiprun_out/.
+written as JSON to `--out`, with the backward's six results as sums and
+digests: a copy of this script in another commit's tree, run with the same
+`--seed`, says whether that commit's backward gives the same bits (the
+first key block's dk and dv apart: a sum through HBM that loses a tile
+loses it there first).
 """
 
 import argparse
+import hashlib
 import json
 import os
 import statistics
@@ -24,6 +29,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 
 from sparknet_tpu.ops import pallas_dsa as pd  # noqa: E402
 
@@ -43,10 +49,21 @@ def timed(fn, args, reps):
     return statistics.median(times), out
 
 
+def digest(x):
+    """A result's sum and absolute sum (float64, on the host) and its
+    bytes' SHA-256: equal digests are equal bits."""
+    x = np.asarray(x)
+    wide = x.astype(np.float64)
+    return {"sum": float(wide.sum()), "abs_sum": float(np.abs(wide).sum()),
+            "sha256": hashlib.sha256(x.tobytes()).hexdigest()[:16]}
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--toy", action="store_true")
     ap.add_argument("--reps", type=int, default=3)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--out", default="chiprun_out/bench_dsa.json")
     ap.add_argument("--seq", type=int, default=None)
     ap.add_argument("--blocks", default=None,
                     help="block_q,block_k,select_q,select_k")
@@ -54,7 +71,7 @@ def main():
     b, h, hk, s, d, hi, di, topk = TOY if args.toy else SHAPE
     s = args.seq or s
     dt = jnp.float32 if args.toy else jnp.bfloat16
-    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    ks = jax.random.split(jax.random.PRNGKey(args.seed % 2 ** 32), 8)
     q = jax.random.normal(ks[0], (b, h, s, d), dt)
     k = jax.random.normal(ks[1], (b, hk, s, d), dt)
     v = jax.random.normal(ks[2], (b, hk, s, d), dt)
@@ -67,7 +84,7 @@ def main():
     bq, bk, sq, sk = pd.blocks(s, *given)
     interp, scale = pd._should_interpret(), d ** -0.5
     rows = {"shape": [b, h, hk, s, d, hi, di, topk],
-            "blocks": [bq, bk, sq, sk],
+            "blocks": [bq, bk, sq, sk], "seed": args.seed,
             "device": jax.devices()[0].device_kind}
 
     ms, (thr, lse_i) = timed(
@@ -83,10 +100,14 @@ def main():
         (q, k, qi, ki, w, thr, lse, lse_i), args.reps)
     rows["dsa_kl_ms"] = ms
     rows["kl_mean"] = float(jnp.mean(kl))
-    ms, _ = timed(
+    ms, grads = timed(
         lambda *a: pd._backward(*a, scale, bq, bk, interp),
         (q, k, v, qi, ki, w, thr, lse_i, o, lse, g), args.reps)
-    rows["flash_sparse_dq_dkv_ms"] = ms
+    rows["backward_ms"] = ms
+    rows["backward"] = {name: digest(x) for name, x in zip(
+        ("dq", "dk", "dv", "dqi", "dki", "dw"), grads)}
+    rows["backward"]["dk_block0"] = digest(grads[1][:, :, :bk])
+    rows["backward"]["dv_block0"] = digest(grads[2][:, :, :bk])
     # the set as the core's kernels see it: a forward whose main scores
     # are all 0 has exp(logsumexp) = the number of keys in a query's set
     _, lse0 = jax.jit(lambda *a: pd._forward(*a, scale, bq, bk, interp))(
@@ -97,8 +118,8 @@ def main():
     rows["queries_off_topk"] = int(jnp.sum(off > 0))
     rows["largest_miscount"] = float(jnp.max(off))
     print(json.dumps(rows, indent=1))
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/bench_dsa.json", "w") as f:
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    with open(args.out, "w") as f:
         json.dump(rows, f, indent=1)
 
 

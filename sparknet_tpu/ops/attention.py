@@ -50,7 +50,7 @@ with index_stats a third of weight 0 (ops/dsa.py:selection_stats: the
 share of the selected keys that a window of index_topk would have caught
 and the mean keys a query; kept in the layer's state, which the solver
 records as `dsa.window` where it waits for a loss). The core
-is ops/pallas_dsa.py's five kernels where `flash` is set and 128 divides S,
+is ops/pallas_dsa.py's four kernels where `flash` is set and 128 divides S,
 else the plain form; one `dsa.select` record a trace of the layer besides
 `attn.path`: `topk`, `tiles_causal` and `tiles_visited` (the key blocks of
 the causal half and those the core visits: all of them, a set is data and
@@ -378,10 +378,14 @@ class Attention(Layer):
             "in every kernel" if path == "kernel" else "whole score matrices"
         select = "a threshold a query, the topk-th largest by 32 counting " \
             "passes" if path == "kernel" else "jax.lax.top_k"
+        backward = "one kernel: dq in VMEM, dk dv dkI through HBM a tile" \
+            if path == "kernel" else "XLA's transpose of the whole matrices"
         tracer.record("attn.path", now, now, layer=self.lp.name, path=path,
                       reason=reason, window=0, live_blocks=tiles,
                       causal_blocks=tiles, masked_blocks=tiles,
-                      head_dim=int(q.shape[-1]), core=core, select=select)
+                      head_dim=int(q.shape[-1]), core=core, select=select,
+                      backward=backward,
+                      backward_kernels=int(path == "kernel"))
         full = min(s, topk)     # queries up to here take every key
         tracer.record("dsa.select", now, now, layer=self.lp.name, topk=topk,
                       tiles_causal=tiles, tiles_visited=tiles,

@@ -13,7 +13,7 @@ grids are (batch, query block, key block) and the heads are a loop inside
 the step (the kernels of ops/pallas_attention.py put a head on the grid).
 The set is never stored: the threshold is 4 bytes a query.
 
-Five kernels, by their names in a device trace:
+Four kernels, by their names in a device trace:
 
 - `dsa_index_select` (scope `dsa_select`): a block of queries against all
   their keys: the index scores by tiles into a VMEM scratch as sortable
@@ -25,12 +25,21 @@ Five kernels, by their names in a device trace:
   selected keys; out o and the heads' logsumexp.
 - `dsa_kl` (scope `dsa_kl`): L_I's value a query, from the heads'
   probabilities summed in the tile (their scores once more).
-- `flash_sparse_dq`, `flash_sparse_dkv` (scope `attn_core`): the
-  FlashAttention-2 backward of the main loss AND, since they hold every
-  head's probabilities of a tile anyway, L_I's backward: dI = softmax(I) -
-  mean_h P_h on the set, then dw, d qI (dq's grid) and d kI (dkv's grid).
-  The two cotangents do not mix: dq, dk, dv read dO alone, the indexer's
-  three read L_I's alone.
+- `flash_sparse_dq` (scope `attn_core`): the whole backward, a tile's
+  index tile, scores and probabilities made once for all six gradients:
+  the FlashAttention-2 backward of the main loss (dq, dk, dv) AND, since
+  the tile holds every head's probabilities anyway, L_I's: dI =
+  softmax(I) - mean_h P_h on the set, then dw, d qI, d kI from the x_j the
+  index tile left in VMEM. The two cotangents do not mix: dq, dk, dv read
+  dO alone, the indexer's three read L_I's alone. The grid is dq's
+  (batch, query block, key block): 32 query heads' dq are 8 MB a block and
+  stay in VMEM over a row of tiles, the 4 key-value heads' dk and dv and
+  d kI are 2.25 MB a block and go through HBM, float32, by the kernel's
+  own copies (`_bwd_kernel` says why not the pipeline's), each hidden
+  behind a neighbouring tile's products. Every sum keeps the order two
+  kernels gave it (dq over key blocks, dk, dv, d kI over query blocks and
+  heads, ascending). With as many key-value heads as query heads the
+  other orientation (key block outermost, dq through HBM) moves less.
 
 A score tile is held transposed, (block_k, block_q), as in
 ops/pallas_attention.py: a query's statistics are (1, block_q) rows.
@@ -38,7 +47,7 @@ The backward reads the forward's `thr`: `graph/remat.py:keep` holds it
 (with `lse_i`, o and the logsumexp) across a block's replay, so the index
 scores' selection runs once a step.
 
-The indexer's tile must come out bit for bit alike in all five kernels (a
+The indexer's tile must come out bit for bit alike in all four kernels (a
 key at the threshold is in the set everywhere or nowhere): one helper,
 `_index_tile`, the same operations in the same order, the products of
 depth 64 whole.
@@ -68,11 +77,11 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
-def _params():
+def _params(outer="parallel"):
     """Every grid here is (batch, an outer block, the streamed block); a
     step holds all the heads of its block, more than the default limit."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", outer, "arbitrary"),
         vmem_limit_bytes=VMEM_LIMIT)
 
 
@@ -82,12 +91,16 @@ def _index_parts(ki_ref, qi_ref, j):
                 qi_ref[0, j].astype(jnp.float32), _NT)
 
 
-def _index_tile(ki_ref, qi_ref, w_ref):
+def _index_tile(ki_ref, qi_ref, w_ref, parts_ref=None):
     """I^T (bk, bq) = sum_j w_j relu(x_j), in the one order every kernel
-    shares."""
+    shares; the x_j are left in `parts_ref` (HI, bk, bq) where one is
+    given."""
     acc = None
     for j in range(qi_ref.shape[1]):
-        term = w_ref[0, j] * jnp.maximum(_index_parts(ki_ref, qi_ref, j), 0.0)
+        x = _index_parts(ki_ref, qi_ref, j)
+        if parts_ref is not None:
+            parts_ref[j] = x
+        term = w_ref[0, j] * jnp.maximum(x, 0.0)
         acc = term if acc is None else acc + term
     return acc
 
@@ -98,9 +111,9 @@ def _seen(qi, kj, bq, bk):
     return q_pos >= k_pos
 
 
-def _set_tile(ki_ref, qi_ref, w_ref, thr_ref, qi, kj):
+def _set_tile(ki_ref, qi_ref, w_ref, thr_ref, qi, kj, parts_ref=None):
     """(the key set's tile (bk, bq) bool, I^T of the tile)."""
-    scores = _index_tile(ki_ref, qi_ref, w_ref)
+    scores = _index_tile(ki_ref, qi_ref, w_ref, parts_ref)
     bk, bq = scores.shape
     return (scores >= thr_ref[0]) & _seen(qi, kj, bq, bk), scores
 
@@ -195,10 +208,10 @@ def _select(qi, ki, w, topk, block_q, block_k, interpret):
 # ------------------------------------------------------------------ forward
 
 def _masked_scores(k_ref, q_ref, h, grp, scale, sel):
-    """(scores^T (bk, bq) of head h on the set, -1e30 off it; q scaled)."""
+    """scores^T (bk, bq) of head h on the set, -1e30 off it."""
     qs = q_ref[0, h].astype(jnp.float32) * scale
     sc = _dot(k_ref[0, h // grp].astype(jnp.float32), qs, _NT)
-    return jnp.where(sel, sc, NEG_INF), qs
+    return jnp.where(sel, sc, NEG_INF)
 
 
 def _fwd_kernel(q_ref, k_ref, vt_ref, qi_ref, ki_ref, w_ref, thr_ref, o_ref,
@@ -217,7 +230,7 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, qi_ref, ki_ref, w_ref, thr_ref, o_ref,
         sel, _ = _set_tile(ki_ref, qi_ref, w_ref, thr_ref, qi, kj)
 
         def head(h, carry):
-            sc, _ = _masked_scores(k_ref, q_ref, h, grp, scale, sel)
+            sc = _masked_scores(k_ref, q_ref, h, grp, scale, sel)
             m_prev = m_ref[h]                                   # (1, bq)
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -283,15 +296,11 @@ def _forward(q, k, v, qi, ki, w, thr, scale, bq, bk, interpret):
 
 # ---------------------------------------------------------------- L_I value
 
-def _heads_probs(k_ref, q_ref, lse_ref, grp, scale, sel, each=None):
-    """sum over the heads of P_h^T = exp(S_h - LSE_h) on the set, (bk, bq);
-    `each(h, p, qs)` sees every head's tile on the way."""
+def _heads_probs(k_ref, q_ref, lse_ref, grp, scale, sel):
+    """sum over the heads of P_h^T = exp(S_h - LSE_h) on the set, (bk, bq)."""
     def head(h, total):
-        sc, qs = _masked_scores(k_ref, q_ref, h, grp, scale, sel)
-        p = jnp.exp(sc - lse_ref[0, h])
-        if each is not None:
-            each(h, p, qs)
-        return total + p
+        sc = _masked_scores(k_ref, q_ref, h, grp, scale, sel)
+        return total + jnp.exp(sc - lse_ref[0, h])
     return jax.lax.fori_loop(0, q_ref.shape[1], head,
                              jnp.zeros(sel.shape, jnp.float32))
 
@@ -347,12 +356,48 @@ def _index_grad(scores, total, heads, sel, lsei_ref):
                      - total * (1.0 / heads), 0.0)
 
 
-def _dq_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref,
-               qi_ref, ki_ref, kit_ref, w_ref, thr_ref, lsei_ref, dq_ref,
-               dqi_ref, dw_ref, acc_ref, acci_ref, accw_ref, *, scale, grp):
-    qi, kj, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+def _bwd_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref,
+                qi_ref, ki_ref, kit_ref, w_ref, thr_ref, lsei_ref, dq_ref,
+                dqi_ref, dw_ref, dk_hbm, dv_hbm, dki_hbm, acc_ref, acci_ref,
+                accw_ref, dk_buf, dv_buf, dki_buf, parts_ref, slot_ref, sems,
+                *, scale, grp):
+    """One (query block, key block) tile of the whole backward. The query
+    side (dq, d qI, d w) accumulates in VMEM over a row of tiles, as the
+    pipeline's blocks; the key side (dk, dv, d kI, float32 in HBM) is the
+    kernel's own to move: a key block's sums so far come into one of two
+    VMEM slots while the tile before is computed, take this tile's parts
+    and go back. The pipeline cannot carry them: a block whose index stays
+    between two grid steps is neither written nor fetched again, and dead
+    steps and a row's end repeat indices."""
+    b, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nq, nk = pl.num_programs(1), pl.num_programs(2)
     heads, bq, bk = q_ref.shape[1], q_ref.shape[2], k_ref.shape[2]
-    n_index = qi_ref.shape[1]
+    n_index, width = qi_ref.shape[1], qi_ref.shape[3]
+    last = (qi * bq + bq - 1) // bk         # the row's last live key block
+
+    def moves(block, slot, way):
+        """The three copies of key block `block` between HBM and `slot`:
+        way 0 reads, way 1 writes."""
+        rows = pl.ds(pl.multiple_of(block * bk, bk), bk)
+        pairs = ((dk_hbm.at[b, :, rows, :], dk_buf.at[slot]),
+                 (dv_hbm.at[b, :, rows, :], dv_buf.at[slot]),
+                 (dki_hbm.at[b, rows, :], dki_buf.at[slot]))
+        return [pltpu.make_async_copy(*(pair if way == 0 else pair[::-1]),
+                                      sems.at[way, n])
+                for n, pair in enumerate(pairs)]
+
+    def start(block, slot, way):
+        for copy in moves(block, slot, way):
+            copy.start()
+
+    def wait(slot, way):
+        for copy in moves(kj, slot, way):   # a wait reads the shapes alone
+            copy.wait()
+
+    def clear(slot):
+        dk_buf[slot] = jnp.zeros(dk_buf.shape[1:], jnp.float32)
+        dv_buf[slot] = jnp.zeros(dv_buf.shape[1:], jnp.float32)
+        dki_buf[slot] = jnp.zeros(dki_buf.shape[1:], jnp.float32)
 
     @pl.when(kj == 0)
     def _init():
@@ -360,26 +405,94 @@ def _dq_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref,
         acci_ref[:] = jnp.zeros_like(acci_ref)
         accw_ref[:] = jnp.zeros_like(accw_ref)
 
-    @pl.when(kj <= (qi * bq + bq - 1) // bk)
+    @pl.when(kj <= last)
     def _live():
-        sel, scores = _set_tile(ki_ref, qi_ref, w_ref, thr_ref, qi, kj)
+        # the live tiles of a batch entry in grid order: this one's place
+        first = (qi == 0) & (kj == 0)
 
-        def each(h, p, qs):
-            dp = _dot(v_ref[0, h // grp].astype(jnp.float32),
-                      do_ref[0, h].astype(jnp.float32), _NT)
-            ds = p * (dp - delta_ref[0, h])
-            # dQ^T += K^T dS^T, (d, bq)
-            acc_ref[h] += _dot(kt_ref[0, h // grp].astype(jnp.float32), ds,
-                               _NN)
-        total = _heads_probs(k_ref, q_ref, lse_ref, grp, scale, sel, each)
+        @pl.when(first)
+        def _first():
+            slot_ref[0] = 0
+            clear(0)
+        ends_row = kj == last
+        final = ends_row & (qi == nq - 1)
+        # a key block that the next (the previous) live tile names as well
+        # stays in its slot: no copy at all
+        stays = ends_row & (last == 0) & jnp.logical_not(final)
+        stayed = (kj == 0) & (qi > 0) & ((qi * bq - 1) // bk == 0)
+        nxt_q = jnp.where(ends_row, qi + 1, qi)
+        nxt_k = jnp.where(ends_row, 0, kj + 1)
+        slot = slot_ref[0]
+        other = 1 - slot
+
+        sel, scores = _set_tile(ki_ref, qi_ref, w_ref, thr_ref, qi, kj,
+                                parts_ref)
+
+        # a key block's first tile starts from zero, any later one from
+        # the sums the tile before asked for
+        @pl.when((qi != (kj * bk) // bq) & jnp.logical_not(stayed))
+        def _arrived():
+            wait(slot, 0)
+
+        def heads_of(g, kf, ktf, vf):
+            """The query heads of key-value head `g`, its blocks cast
+            once."""
+            def head(h, total):
+                qs = q_ref[0, h].astype(jnp.float32) * scale
+                sc = jnp.where(sel, _dot(kf, qs, _NT), NEG_INF)
+                p = jnp.exp(sc - lse_ref[0, h])
+                do = do_ref[0, h].astype(jnp.float32)
+                dv_buf[slot, g] += _dot(p, do, _NN)
+                ds = p * (_dot(vf, do, _NT) - delta_ref[0, h])
+                acc_ref[h] += _dot(ktf, ds, _NN)    # dQ^T += K^T dS^T
+                dk_buf[slot, g] += _dot(ds, qs, _NN)
+                return total + p
+            return head
+        total = jnp.zeros(sel.shape, jnp.float32)
+        for g in range(heads // grp):
+            total = jax.lax.fori_loop(
+                g * grp, (g + 1) * grp,
+                heads_of(g, k_ref[0, g].astype(jnp.float32),
+                         kt_ref[0, g].astype(jnp.float32),
+                         v_ref[0, g].astype(jnp.float32)), total)
+
+        # the other slot: the tile before wrote from it (long done by now),
+        # the next tile's key block comes into it behind the index's part
+        @pl.when(jnp.logical_not(first) & jnp.logical_not(stayed))
+        def _written():
+            wait(other, 1)
+
+        @pl.when(jnp.logical_not(final) & jnp.logical_not(stays))
+        def _next():
+            fresh = nxt_q == (nxt_k * bk) // bq
+
+            @pl.when(fresh)
+            def _zero():
+                clear(other)
+
+            @pl.when(jnp.logical_not(fresh))
+            def _fetch():
+                start(nxt_k, other, 0)
+
         di = _index_grad(scores, total, heads, sel, lsei_ref)
         kit = kit_ref[0].astype(jnp.float32)
         for j in range(n_index):
-            x = _index_parts(ki_ref, qi_ref, j)
+            x = parts_ref[j]
             accw_ref[j] += jnp.sum(di * jnp.maximum(x, 0.0), axis=0,
                                    keepdims=True)
             dx = jnp.where(x > 0, di * w_ref[0, j], 0.0)
             acci_ref[j] += _dot(kit, dx, _NN)                  # (di, bq)
+            dki_buf[slot, :, :width] += _dot(
+                dx, qi_ref[0, j].astype(jnp.float32), _NN)
+
+        @pl.when(jnp.logical_not(stays))
+        def _send():
+            start(kj, slot, 1)
+            slot_ref[0] = other
+
+        @pl.when(final)
+        def _drain():
+            wait(slot, 1)
 
     @pl.when(kj == nk - 1)
     def _finish():
@@ -392,99 +505,49 @@ def _dq_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref,
             dw_ref[0, j] = accw_ref[j]
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
-                ki_ref, w_ref, thr_ref, lsei_ref, dk_ref, dv_ref, dki_ref,
-                dk_acc, dv_acc, dki_acc, *, scale, grp):
-    kj, qi, nq = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
-    heads, bq, bk = q_ref.shape[1], q_ref.shape[2], k_ref.shape[2]
-    n_index = qi_ref.shape[1]
-
-    @pl.when(qi == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-        dki_acc[:] = jnp.zeros_like(dki_acc)
-
-    @pl.when(qi >= (kj * bk) // bq)
-    def _live():
-        sel, scores = _set_tile(ki_ref, qi_ref, w_ref, thr_ref, qi, kj)
-
-        def each(h, p, qs):
-            do = do_ref[0, h].astype(jnp.float32)
-            dv_acc[h // grp] += _dot(p, do, _NN)
-            dp = _dot(v_ref[0, h // grp].astype(jnp.float32), do, _NT)
-            ds = p * (dp - delta_ref[0, h])
-            dk_acc[h // grp] += _dot(ds, qs, _NN)
-        total = _heads_probs(k_ref, q_ref, lse_ref, grp, scale, sel, each)
-        di = _index_grad(scores, total, heads, sel, lsei_ref)
-        for j in range(n_index):
-            x = _index_parts(ki_ref, qi_ref, j)
-            dx = jnp.where(x > 0, di * w_ref[0, j], 0.0)
-            dki_acc[:] += _dot(dx, qi_ref[0, j].astype(jnp.float32), _NN)
-
-    @pl.when(qi == nq - 1)
-    def _finish():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
-        dki_ref[0] = dki_acc[:]
-
-
 def _backward(q, k, v, qi, ki, w, thr, lse_i, o, lse, g, scale, bq, bk,
               interpret):
     """-> dq, dk, dv of the main loss (cotangent `g` of o) and d qI, d kI,
     d w of L_I's SUM over the queries, float32 (the caller scales them)."""
     b, h, s, d = q.shape
     hkv, hi, di = k.shape[1], qi.shape[1], qi.shape[3]
-    grp = h // hkv
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, :, None, :]                 # (B, H, 1, S)
     sp = _specs(b, h, hkv, hi, s, d, di, bq, bk)
-    dq, dqi, dw = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, grp=grp),
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    # a copy cannot cut an array in HBM inside a row of 128 lanes: d kI's
+    # rows of `di` travel at the width they have in VMEM anyway
+    lanes = -(-di // 128) * 128
+    dq, dqi, dw, dk, dv, dki = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, grp=h // hkv),
         grid=(b, s // bq, s // bk),
         in_specs=[sp["q"], sp["k"], sp["kt"], sp["k"], sp["q"], sp["stat"],
                   sp["stat"], sp["qi"], sp["ki"], sp["kit"], sp["w"],
                   sp["row"], sp["row"]],
-        out_specs=[sp["q"], sp["qi"], sp["w"]],
+        out_specs=[sp["q"], sp["qi"], sp["w"], hbm, hbm, hbm],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(qi.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(w.shape, jnp.float32)],
+                   jax.ShapeDtypeStruct(w.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(k.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(v.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((b, s, lanes), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((h, d, bq), jnp.float32),
                         pltpu.VMEM((hi, di, bq), jnp.float32),
-                        pltpu.VMEM((hi, 1, bq), jnp.float32)],
-        compiler_params=_params(), interpret=interpret,
+                        pltpu.VMEM((hi, 1, bq), jnp.float32),
+                        pltpu.VMEM((2, hkv, bk, d), jnp.float32),
+                        pltpu.VMEM((2, hkv, bk, d), jnp.float32),
+                        pltpu.VMEM((2, bk, lanes), jnp.float32),
+                        pltpu.VMEM((hi, bk, bq), jnp.float32),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.SemaphoreType.DMA((2, 3))],
+        # dk, dv, d kI add up over the query blocks: only the batch's
+        # entries are each other's strangers
+        compiler_params=_params("arbitrary"), interpret=interpret,
         name="flash_sparse_dq",
     )(q, k, jnp.swapaxes(k, 2, 3), v, g, lse, delta, qi, ki,
       jnp.swapaxes(ki, 1, 2), w, thr, lse_i)
-
-    # key block outermost, query blocks streamed: a dead step below the
-    # diagonal holds the first live query block
-    def qrow(i, j):
-        return jnp.maximum(i, (j * bk) // bq)
-    qT = pl.BlockSpec((1, h, bq, d), lambda bb, j, i: (bb, 0, qrow(i, j), 0))
-    kT = pl.BlockSpec((1, hkv, bk, d), lambda bb, j, i: (bb, 0, j, 0))
-    statT = pl.BlockSpec((1, h, 1, bq),
-                         lambda bb, j, i: (bb, 0, 0, qrow(i, j)))
-    qiT = pl.BlockSpec((1, hi, bq, di),
-                       lambda bb, j, i: (bb, 0, qrow(i, j), 0))
-    kiT = pl.BlockSpec((1, bk, di), lambda bb, j, i: (bb, j, 0))
-    wT = pl.BlockSpec((1, hi, 1, bq), lambda bb, j, i: (bb, 0, 0, qrow(i, j)))
-    rowT = pl.BlockSpec((1, 1, bq), lambda bb, j, i: (bb, 0, qrow(i, j)))
-    dk, dv, dki = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, grp=grp),
-        grid=(b, s // bk, s // bq),
-        in_specs=[qT, kT, kT, qT, statT, statT, qiT, kiT, wT, rowT, rowT],
-        out_specs=[kT, kT, kiT],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct(ki.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((hkv, bk, d), jnp.float32),
-                        pltpu.VMEM((hkv, bk, d), jnp.float32),
-                        pltpu.VMEM((bk, di), jnp.float32)],
-        compiler_params=_params(), interpret=interpret,
-        name="flash_sparse_dkv",
-    )(q, k, v, g, lse, delta, qi, ki, w, thr, lse_i)
-    return dq, dk, dv, dqi, dki, dw
+    return (dq, dk.astype(k.dtype), dv.astype(v.dtype), dqi, dki[..., :di],
+            dw)
 
 
 # ------------------------------------------------------------ the function

@@ -6,7 +6,7 @@ copied): small widths, seeded weights, float32 on the CPU.
 The reference computes index scores and main scores a block of query rows
 at a time, selects by an exact `jax.lax.top_k` and writes L_I with both
 stop_gradients; the program goes through `Attention` with its index fields:
-the plain form (ops/dsa.py) or the five kernels of ops/pallas_dsa.py in
+the plain form (ops/dsa.py) or the four kernels of ops/pallas_dsa.py in
 interpret mode.
 
 Where a kernel is held against the plain form, the index's operands are
@@ -229,14 +229,23 @@ def _losses(fn, weights):
     return run
 
 
+# the last two: the cell's 8 query heads a key-value head, four and more key
+# blocks and `topk` under a key block, so that a key block's dk, dv and d kI
+# take parts from several query rows through HBM and back
+@pytest.mark.parametrize("tiles", [(64, 128), (64, 64)])
 @pytest.mark.parametrize("shape", [(1, 4, 2, 256, 16, 4, 8, 32),
                                    (2, 4, 4, 128, 32, 2, 16, 8),
-                                   (1, 8, 2, 384, 16, 3, 8, 130)])
-def test_kernels_match_the_plain_form(monkeypatch, shape):
-    """Value, L_I and all six gradients, several tiles a grid axis."""
+                                   (1, 8, 2, 384, 16, 3, 8, 130),
+                                   (1, 8, 1, 512, 16, 4, 8, 40),
+                                   (2, 16, 2, 512, 16, 2, 8, 200)])
+def test_kernels_match_the_plain_form(monkeypatch, shape, tiles):
+    """Value, L_I and all six gradients, several tiles a grid axis; at
+    64 x 128 the first two query rows end on key block 0 (the backward
+    keeps it in its slot), at 64 x 64 the diagonal tile is row 0's only
+    live one and every later row names key block 0 after another block."""
     from sparknet_tpu.ops import pallas_dsa
     b, h, hk, s, d, hi, di, topk = shape
-    monkeypatch.setattr(pallas_dsa, "blocks", lambda s, *a: (64, 128, 32, 64))
+    monkeypatch.setattr(pallas_dsa, "blocks", lambda s, *a: tiles + (32, 64))
     q, k, v = qkv(jax.random.PRNGKey(9), b, h, hk, s, d)
     qi, ki, w = exact_index(jax.random.PRNGKey(10), b, hi, s, di)
     args = (q, k, v, qi, ki, w)
@@ -531,7 +540,8 @@ def test_paths_selection_and_kept_arrays_are_recorded():
     assert {r["layer"] for r in paths} == {"block0/attn", "block1/attn"}
     assert all(r["path"] == "kernel" and "index tile" in r["core"]
                and "counting" in r["select"] and r["live_blocks"] == 1
-               for r in paths)
+               and r["backward"].startswith("one kernel: dq in VMEM")
+               and r["backward_kernels"] == 1 for r in paths)
     picks = ring.spans("dsa.select")[marks["dsa.select"]:]
     assert picks and all(
         r["topk"] == 16 and r["tiles_visited"] == r["tiles_causal"] == 1

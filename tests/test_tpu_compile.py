@@ -452,8 +452,13 @@ def test_index_picked_attention_kernels_compile(one_chip, which):
             q, k, qi, ki, w, thr, lse, lse_i, scale, bq, bk, False),
             one_chip, q, kv, qi, ki, w, row, stat, row, kernels=["dsa_kl"])
     else:
-        _compile(lambda q, k, v, qi, ki, w, thr, lse_i, o, lse, g:
-                 pallas_dsa._backward(q, k, v, qi, ki, w, thr, lse_i, o, lse,
-                                      g, scale, bq, bk, False),
-                 one_chip, q, kv, kv, qi, ki, w, row, row, q, stat, q,
-                 kernels=["flash_sparse_dq", "flash_sparse_dkv"])
+        # one kernel: dq's grid with the key side's gradients beside it
+        compiled = _compile(
+            lambda q, k, v, qi, ki, w, thr, lse_i, o, lse, g:
+            pallas_dsa._backward(q, k, v, qi, ki, w, thr, lse_i, o, lse, g,
+                                 scale, bq, bk, False),
+            one_chip, q, kv, kv, qi, ki, w, row, row, q, stat, q,
+            kernels=["flash_sparse_dq"])
+        text = compiled.as_text()
+        assert "flash_sparse_dkv" not in text
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
