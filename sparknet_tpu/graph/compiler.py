@@ -47,9 +47,11 @@ REMAT_POLICIES = ("none", "dots", "full")
 #: `attn_proj_out` (ops/attention.py); `gdn_proj_in`, `gdn_conv`,
 #: `gdn_scan`, `gdn_gate_norm`, `gdn_proj_out` (ops/deltanet.py);
 #: `shortconv_in`, `shortconv_mix`, `shortconv_out` (ops/shortconv.py);
+#: `ssm_proj_in`, `ssm_conv`, `ssm_scan`, `ssm_gate_norm`, `ssm_proj_out`
+#: (ops/mamba2.py);
 #: `moe_route`, `moe_dispatch`, `moe_experts`, `moe_combine`, `moe_shared`,
 #: `moe_glue` (ops/moe.py); what such a layer traces outside them counts
-#: under `attn`, `gdn`, `shortconv`, `moe` and should read nothing (the
+#: under `attn`, `gdn`, `shortconv`, `ssm`, `moe` and should read nothing (the
 #: Switch form of the MoE opens no scope and reads as `moe`). Beside the
 #: layers a step has `input_transform`, `total_loss` (the weighted sum of
 #: the loss tops), `grad_accum` (iter_size's micro-batches), `update`,
@@ -63,7 +65,7 @@ PART_OF_TYPE = {
     "MVN": "norm", "InnerProduct": "proj", "Embed": "embed",
     "PositionalEmbed": "embed", "Eltwise": "residual",
     "Attention": "attn", "GatedDeltaNet": "gdn", "ShortConv": "shortconv",
-    "MoE": "moe",
+    "Mamba2": "ssm", "MoE": "moe",
     **dict.fromkeys(("ReLU", "PReLU", "Sigmoid", "TanH", "BNLL", "AbsVal",
                      "Power", "Exp", "Log", "Threshold", "Dropout",
                      "Softmax"), "act"),

@@ -143,7 +143,7 @@ def AttentionLayer(name, bottoms, num_heads, head_dim=None, causal=False,
                    output_gate=False, norm_eps=None, weight_filler=None,
                    param=None, window=None, qk_norm_zero_centered=None,
                    index_heads=None, index_head_dim=None, index_topk=None,
-                   index_stats=False):
+                   index_stats=False, out_filler=None):
     """sparknet_tpu extension for the long-context path (see
     parallel.ring_attention, ops.pallas_attention). `num_kv_heads` selects
     the grouped-query form (bias-free q/k/v/out projections; qk_norm,
@@ -155,8 +155,11 @@ def AttentionLayer(name, bottoms, num_heads, head_dim=None, causal=False,
     (ops/dsa.py); five more blobs (W_qI, W_kI, W_w, the index key's
     LayerNorm weight and bias) and a second top `<name>_kl`, the index's
     own loss, of weight 1; `index_stats` adds a third (weight 0), the
-    share of the selected keys inside a window of `index_topk`."""
+    share of the selected keys inside a window of `index_topk`.
+    `out_filler` fills the grouped-query form's out projection."""
     ap = dict(num_heads=num_heads, causal=causal, ring=ring, flash=flash)
+    if out_filler is not None:
+        ap["out_filler"] = out_filler
     if window:
         ap["window"] = window
     if qk_norm_zero_centered is not None:
@@ -216,6 +219,29 @@ def ShortConvLayer(name, bottoms, kernel=None, weight_filler=None,
                               short_conv_param=sp or None), param)
 
 
+def Mamba2Layer(name, bottoms, num_heads, head_dim, state_size, n_groups,
+                conv_kernel=None, chunk=None, norm_eps=None,
+                weight_filler=None, out_filler=None, dt_min=None,
+                dt_max=None, stats=False, param=None):
+    """sparknet_tpu extension: the Mamba-2 state-space mixer
+    (ops/mamba2.py); `param` lists its eight blobs' multipliers (W_in,
+    conv, conv bias, A_log, D, dt_bias, norm, W_out). stats=True adds a
+    (weight-0) top with the share of a state that survives a chunk."""
+    mp = dict(num_heads=num_heads, head_dim=head_dim, state_size=state_size,
+              n_groups=n_groups)
+    for key, val in (("conv_kernel", conv_kernel), ("chunk", chunk),
+                     ("norm_eps", norm_eps), ("weight_filler", weight_filler),
+                     ("out_filler", out_filler), ("dt_min", dt_min),
+                     ("dt_max", dt_max), ("stats", stats or None)):
+        if val is not None:
+            mp[key] = val
+    tops = [name, f"{name}_stats"] if stats else [name]
+    lp = _base("Mamba2", name, bottoms, tops=tops, mamba2_param=mp)
+    if stats:
+        lp.loss_weight.extend([0.0, 0.0])
+    return _with_params(lp, param)
+
+
 def RMSNormLayer(name, bottoms, tops=None, eps=None, zero_centered=None,
                  param=None):
     """sparknet_tpu extension: last-axis RMS norm, one blob."""
@@ -253,7 +279,8 @@ def MoELayer(name, bottoms, num_experts, hidden_dim=None,
              top_k=None, experts_held=None, first_expert=None,
              shared_hidden_dim=None, norm_topk_prob=None, tile_rows=None,
              expert_activation=None, score_function=None,
-             selection_bias=None, topk_eps=None, routed_scaling_factor=None):
+             selection_bias=None, topk_eps=None, routed_scaling_factor=None,
+             expert_gate_matrix=None, shared_gate=None, down_filler=None):
     """sparknet_tpu extension: MoE FFN. The top-1 Switch form:
     aux_loss_weight adds a second top carrying the load-balancing loss
     with that loss_weight; stats=True adds a third (weight-0) diagnostics
@@ -265,7 +292,11 @@ def MoELayer(name, bottoms, num_experts, hidden_dim=None,
     experts, largest over mean held load]. `expert_activation` "relu"
     makes the experts ReLU-gated; a second bottom feeds the router.
     `score_function` "sigmoid", `selection_bias`, `topk_eps` and
-    `routed_scaling_factor` are the route's (ops/moe.py)."""
+    `routed_scaling_factor` are the route's (ops/moe.py).
+    `expert_gate_matrix` False makes an expert two matrices (with
+    `expert_activation` "relu2": W_down relu(W_up x)^2), `shared_gate`
+    False adds the shared expert with no sigmoid gate; `down_filler` fills
+    the down projections."""
     if top_k is not None:
         mp = dict(num_experts=num_experts, gated_experts=True, top_k=top_k)
         for key, val in (("hidden_dim", hidden_dim),
@@ -279,6 +310,9 @@ def MoELayer(name, bottoms, num_experts, hidden_dim=None,
                          ("selection_bias", selection_bias),
                          ("topk_eps", topk_eps),
                          ("routed_scaling_factor", routed_scaling_factor),
+                         ("expert_gate_matrix", expert_gate_matrix),
+                         ("shared_gate", shared_gate),
+                         ("down_filler", down_filler),
                          ("weight_filler", weight_filler)):
             if val is not None:
                 mp[key] = val

@@ -10,7 +10,8 @@ from .dsl import (GatedDeltaNetLayer, RMSNormLayer, ShortConvLayer,
                   InnerProductLayer, ReLULayer, SoftmaxWithLoss,
                   AccuracyLayer, LRNLayer, DropoutLayer, ConcatLayer,
                   EltwiseLayer, AttentionLayer, EmbedLayer,
-                  PositionalEmbedLayer, LayerNormLayer, MoELayer)
+                  PositionalEmbedLayer, LayerNormLayer, MoELayer,
+                  Mamba2Layer)
 
 
 def _conv(name, bottom, num_output, kernel, stride=1, pad=0, group=None,
@@ -746,6 +747,143 @@ def keye_vl2(vocab_size=151936, seq_len=32768, batch_size=1,
         SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
     ]
     return NetParam("KeyeVL2", *layers)
+
+
+#: `hybrid_override_pattern` of Nemotron-Labs-TwoTower-30B-A3B-Base-BF16:
+#: 23 M (Mamba-2), 23 E (MoE), 6 * (attention); MEMEM*E five times over,
+#: then MEMEMEM*E, then MEMEMEME
+NEMOTRON_H_PATTERN = "MEMEM*E" * 5 + "MEMEMEM*E" + "MEMEMEME"
+
+
+def nemotron_h(vocab_size=131072, seq_len=8192, batch_size=2,
+               hidden_size=2688, pattern=NEMOTRON_H_PATTERN, layers=None,
+               mamba_num_heads=64, mamba_head_dim=64, ssm_state_size=128,
+               n_groups=8, conv_kernel=4, chunk_size=128,
+               time_step_min=0.001, time_step_max=0.1,
+               num_attention_heads=32, num_key_value_heads=2, head_dim=128,
+               n_routed_experts=128, num_experts_per_tok=6,
+               moe_intermediate_size=1856,
+               moe_shared_expert_intermediate_size=3712, norm_topk_prob=True,
+               routed_scaling_factor=2.5, layer_norm_epsilon=1e-5,
+               experts_held=None, first_expert=0, flash=True,
+               moe_stats=False, ssm_stats=False):
+    """The Nemotron-H tower of Nemotron-Labs-TwoTower-30B-A3B (`model_type`
+    nemotron_h) as a trainable causal language model: a net from a PATTERN
+    STRING, one letter a block, EVERY block out = x + Mixer(RMSNorm(x))
+    with ONE mixer (plain RMSNorm, w filled with 1, eps
+    `layer_norm_epsilon`), a final RMSNorm, untied embedding and head, mean
+    cross-entropy per token. The mixer by its letter:
+
+      M  Mamba-2 (ops/mamba2.py): `mamba_num_heads` heads of
+         `mamba_head_dim`, state `ssm_state_size`, B and C in `n_groups`
+         groups, a causal depthwise conv of `conv_kernel` taps with a bias,
+         the scan in chunks of `chunk_size`, the gated RMSNorm over each
+         group's channels, no bias on either projection
+      *  grouped-query attention, causal, no bias, NO positional encoding,
+         no head norm, no gate, no window
+      E  a no-drop MoE (ops/moe.py): s = sigmoid(W_r h) in float32 over
+         `n_routed_experts`; the `num_experts_per_tok` largest of s + b
+         (b a buffer of zeros that no gradient trains), their weights the
+         unbiased s divided by (their sum + 1e-20), times
+         `routed_scaling_factor`; an expert TWO matrices, W_down
+         relu(W_up h)^2, and one shared expert of the same form at
+         `moe_shared_expert_intermediate_size`, added with no gate
+
+    Defaults are the published sizes. `layers` = (first, end) are the blocks
+    of `pattern` that this pipeline stage holds (None: all of them), named
+    by their place in the whole pattern.
+
+    Assumed, where the config has no key or the family's code decides (the
+    configuration file lists the same): the Mamba-2 inner width is heads x
+    head size = 4,096, not `expand` x hidden; attention has no positional
+    encoding (the family gives its attention layers none: the state-space
+    layers carry order; `rope_theta` and `partial_rotary_factor` are keys
+    its code does not read — a rotary variant would be one field,
+    `rotary_dim`); the route's correction bias and its 1e-20 (`n_group` and
+    `topk_group` 1: no group limit); the group norm's groups are the
+    `n_groups` (512 channels each) and it norms AFTER the gate; fillers:
+    A_log uniform(0, log 16) (A in [1, 16); the Mamba-2 code draws A
+    itself uniformly, a filler the seeded benchmark weights cannot state),
+    dt_bias uniform between the inverse softplus of `time_step_min` and of
+    `time_step_max` (delta starts in [0.001, 0.1], all but log-uniform;
+    `time_step_floor` 1e-4 lies below and never binds; `time_step_limit`
+    (0, inf) is no clamp), D and norms 1, the conv's taps and bias
+    uniform(+-1/sqrt(taps)) (torch's Conv1d), matrices gaussian(0.02) with
+    W_out, W_o and the experts' down projections divided by
+    sqrt(len(pattern)) (`rescale_prenorm_residual`: by the WHOLE model's
+    depth, whatever this stage holds), the embedding gaussian(1.0) (as
+    `smallthinker`: the head is untied). Left out: the second (denoiser)
+    tower, adaLN, bidirectional in-block attention, cross-tower
+    conditioning, the block-diffusion objective and its noise schedule (no
+    key of the config sizes or places them), the bias's load-balancing
+    update and any auxiliary loss, dropout, packing and the state's reset
+    at a document's start.
+
+    One chip's share of an expert-parallel group as in `qwen3_next`:
+    `experts_held` from `first_expert` on, `vocab_size` the held rows.
+
+    Layers are named block{i}/ln | mixer | res: the remat groups are the
+    blocks; neighbours are unlike, so no run of blocks scans."""
+    first, end = (0, len(pattern)) if layers is None else layers
+    unknown = sorted(set(pattern) - set("ME*"))
+    if unknown:
+        raise ValueError(f"nemotron_h: pattern letters {unknown}: want M "
+                         "(Mamba-2), E (MoE) or * (attention)")
+    if not 0 <= first < end <= len(pattern):
+        raise ValueError(f"nemotron_h: layers {(first, end)} lie outside "
+                         f"the pattern's {len(pattern)} blocks")
+    e = hidden_size
+    gauss = dict(type="gaussian", std=0.02)
+    small = dict(type="gaussian", std=0.02 / len(pattern) ** 0.5)
+    keep, nodecay = dict(lr_mult=1, decay_mult=1), dict(lr_mult=1,
+                                                        decay_mult=0)
+    net = [
+        RDDLayer("data", [batch_size, seq_len]),
+        RDDLayer("label", [batch_size, seq_len]),
+        EmbedLayer("tok_embed", ["data"], vocab_size, e,
+                   weight_filler=dict(type="gaussian", std=1.0),
+                   bias_term=False),
+    ]
+    x = "tok_embed"
+    for i in range(first, end):
+        p, h = f"block{i}", [f"block{i}/ln"]
+        if pattern[i] == "M":
+            mixer = Mamba2Layer(
+                f"{p}/mixer", h, mamba_num_heads, mamba_head_dim,
+                ssm_state_size, n_groups, conv_kernel=conv_kernel,
+                chunk=chunk_size, norm_eps=layer_norm_epsilon,
+                weight_filler=gauss, out_filler=small,
+                dt_min=time_step_min, dt_max=time_step_max, stats=ssm_stats,
+                param=[keep, keep] + [nodecay] * 5 + [keep])
+        elif pattern[i] == "*":
+            mixer = AttentionLayer(
+                f"{p}/mixer", h, num_attention_heads, head_dim=head_dim,
+                causal=True, flash=flash, num_kv_heads=num_key_value_heads,
+                weight_filler=gauss, out_filler=small, param=[keep] * 4)
+        else:
+            mixer = MoELayer(
+                f"{p}/mixer", h, n_routed_experts,
+                hidden_dim=moe_intermediate_size, top_k=num_experts_per_tok,
+                experts_held=experts_held, first_expert=first_expert,
+                shared_hidden_dim=moe_shared_expert_intermediate_size,
+                norm_topk_prob=norm_topk_prob, score_function="sigmoid",
+                selection_bias=True, topk_eps=1e-20,
+                routed_scaling_factor=routed_scaling_factor,
+                expert_activation="relu2", expert_gate_matrix=False,
+                shared_gate=False, weight_filler=gauss, down_filler=small,
+                stats=moe_stats)
+        net += [RMSNormLayer(h[0], [x], eps=layer_norm_epsilon,
+                             zero_centered=False, param=[nodecay]),
+                mixer, EltwiseLayer(f"{p}/res", [x, f"{p}/mixer"])]
+        x = f"{p}/res"
+    net += [
+        RMSNormLayer("ln_f", [x], eps=layer_norm_epsilon,
+                     zero_centered=False, param=[nodecay]),
+        InnerProductLayer("lm_head", ["ln_f"], vocab_size,
+                          weight_filler=gauss, axis=2, bias_term=False),
+        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
+    ]
+    return NetParam("NemotronH", *net)
 
 
 def transformer_lm_pieces(vocab_size=512, seq_len=256, batch_size=8,

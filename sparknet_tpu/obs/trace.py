@@ -150,6 +150,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._buf = collections.deque(maxlen=max_buffer)  # spk: guarded-by=_lock
         self.dropped = 0            # spk: guarded-by=_lock
+        self.put = 0                # spk: guarded-by=_lock
         self.max_buffer = max_buffer
         _listen_for_compiles()
 
@@ -191,6 +192,7 @@ class Tracer:
             if len(self._buf) == self.max_buffer:
                 self.dropped += 1   # the ring drops its oldest record
             self._buf.append(rec)
+            self.put += 1
         if to_sink and self.sink is not None:
             self.sink.log("span", **self._as_dict(rec))
 
@@ -210,6 +212,23 @@ class Tracer:
         with self._lock:
             recs = list(self._buf)
         return [self._as_dict(r) for r in recs
+                if not names or r[0] in names]
+
+    def mark(self):
+        """How many records the ring has taken so far, dropped ones
+        among them: a place in the stream that `since` reads from. (A
+        count of `spans(name)` is no such place: once the ring is full it
+        drops from the left, and the count stands still.)"""
+        with self._lock:
+            return self.put
+
+    def since(self, mark, *names):
+        """`spans(*names)` of the records put after `mark()` gave
+        `mark`, those the ring has dropped since left out."""
+        with self._lock:
+            recs = list(self._buf)
+            first = self.put - len(recs)    # the oldest record's place
+        return [self._as_dict(r) for r in recs[max(0, mark - first):]
                 if not names or r[0] in names]
 
     def export_chrome(self, path):

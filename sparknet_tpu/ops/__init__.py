@@ -18,6 +18,7 @@ from . import (  # noqa: F401
     attention,
     deltanet,
     shortconv,
+    mamba2,
     moe,
     python_layer,
 )

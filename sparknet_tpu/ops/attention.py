@@ -19,7 +19,8 @@ of today's language models: separate bias-free projections
 each key-value head serves H / Hkv query heads; rotary embedding
 (rotate-half convention, positions 0..S-1) on the first rotary_dim
 dimensions of every head, the rest untouched; softmax(q k^T / sqrt(D)) v;
-with output_gate o * sigmoid(gate) before the out projection. The flash
+with output_gate o * sigmoid(gate) before the out projection
+(`out_filler` fills that one where a model starts it smaller). The flash
 kernel reads the shared key-value heads in place (no repeat in memory);
 the dense path repeats them.
 
@@ -190,10 +191,11 @@ class Attention(Layer):
             mults = _param_mults(self.lp, 11)
             kv = self.kv_heads * self.head_dim
             q_out = self.inner * (2 if self.output_gate else 1)
+            of = self.p.out_filler if self.p.has("out_filler") else wf
             shapes = [((q_out, self.embed), wf, *mults[0]),
                       ((kv, self.embed), wf, *mults[1]),
                       ((kv, self.embed), wf, *mults[2]),
-                      ((self.embed, self.inner), wf, *mults[3])]
+                      ((self.embed, self.inner), of, *mults[3])]
             if self.qk_norm:
                 fill = None if self.qk_zero_centered else Message(
                     "FillerParameter", type="constant", value=1.0)

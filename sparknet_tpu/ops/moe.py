@@ -71,8 +71,9 @@ Two forms, chosen by moe_param.gated_experts:
   shift). Which one is in the ring of obs/trace.py, one `moe.path` record
   a trace of the layer (`path` = `kernel` or `xla`, with the `reason`,
   the experts' `activation`, the route's `score`, whether it has a
-  `selection_bias`, and the combine's form: `combine` = `gather`,
-  `segment` = J). With
+  `selection_bias`, the combine's form: `combine` = `gather`,
+  `segment` = J, the expert's `matrices`, 3 or 2, and the shared expert's
+  gate, `shared_gate`). With
   shared_hidden_dim > 0 a shared expert sees every token:
   shared = sigmoid(w_s . x) W_down (SiLU(W_gate x) * W_up x), and
   y = routed + shared. Tops: [output] or [output, stats]; stats (weight
@@ -85,6 +86,23 @@ Two forms, chosen by moe_param.gated_experts:
     | w_down (held, E, F) | then with a shared expert: ws_gate (Fs, E)
     | ws_up (Fs, E) | ws_down (E, Fs) | shared gate (1, E)
     | then with selection_bias: bias (num_experts,), always the last
+  AN EXPERT OF TWO MATRICES (`expert_gate_matrix` false): W_down,e
+  act(W_up,e x), act SiLU, ReLU or, with `expert_activation` "relu2",
+  relu(u)^2 with derivative 2 relu(u), in the forward, its recomputation
+  and the backward alike; a window is two grouped products (backward: the
+  same recomputed, dh, dx and TWO weight gradients). The blob list has no
+  w_gate, and a shared expert is the same form with no ws_gate: W_sd
+  act(W_su x). `shared_gate` false adds the shared expert as it is, with
+  no sigmoid(w_s . x) before it and no blob for it (either form of the
+  expert). A hidden width off the lane width (1,856 = 14.5 x 128) still
+  takes the kernels: the held weights' copies in the compute type are
+  padded with zero columns to the next multiple of 128 where they are cast
+  (a zero column's activation is 0 in every form, its gradient is cut off
+  again); the blobs, their gradients and the optimizer's state keep the
+  published width.
+    router | w_up (held, F, E) | w_down (held, E, F) | then with a shared
+    expert: ws_up (Fs, E) | ws_down (E, Fs) | with shared_gate: (1, E)
+    | with selection_bias: bias (num_experts,)
   Scopes inside the layer's own: moe_route (softmax, top-k, sort, the
   plan with its `pos`), moe_dispatch (gathering a window's rows),
   moe_experts (the grouped products), moe_combine (the token-major sort,
@@ -322,12 +340,19 @@ def _grouped(kernel, tile, sizes):
 
 
 def _gate(a, act):
-    """act(a) of the gate's float32 product."""
+    """act(a) of the gate's float32 product (of the up product, where an
+    expert has two matrices)."""
+    if act == "relu2":
+        return jnp.square(jnp.maximum(a, 0.0))
     return jnp.maximum(a, 0.0) if act == "relu" else jax.nn.silu(a)
 
 
 def _gate_and_slope(a, act):
-    """(act(a), act'(a)): ReLU's derivative is a mask, 0 at 0."""
+    """(act(a), act'(a)): ReLU's derivative is a mask, 0 at 0, and
+    relu(a)^2's is 2 relu(a)."""
+    if act == "relu2":
+        on = jnp.maximum(a, 0.0)
+        return jnp.square(on), 2.0 * on
     if act == "relu":
         on = a > 0
         return jnp.where(on, a, 0.0), on.astype(a.dtype)
@@ -340,7 +365,9 @@ def _gate_and_slope(a, act):
 def held_experts(x, pair_weight, plan, wg, wu, wd, tile, top_k, window,
                  kernel, act="silu"):
     """sum over each token's held experts e of pair_weight x W_down,e
-    (act(W_gate,e x) * W_up,e x), `act` "silu" or "relu". x (n, E) and the
+    (act(W_gate,e x) * W_up,e x), `act` "silu" or "relu"; with `wg` None
+    an expert is two matrices, W_down,e act(W_up,e x), and `act` may be
+    "relu2". x (n, E) and the
     weights in the
     compute type, pair_weight (n x top_k,) float32 (token-major), `plan`
     from `plan_windows(.., window)`, `window` a multiple of `tile`, the row
@@ -362,7 +389,7 @@ def held_experts(x, pair_weight, plan, wg, wu, wd, tile, top_k, window,
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
 def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window, kernel,
               act="silu"):
-    segment = min(top_k, wg.shape[0])
+    segment = min(top_k, wd.shape[0])
 
     def body(w, y):
         with jax.named_scope("moe_dispatch"):
@@ -370,9 +397,13 @@ def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window, kernel,
             xw = x[tok]
         with jax.named_scope("moe_experts"):
             dot, _ = _grouped(kernel, tile, sizes)
-            a = dot(xw, wg, True, "moe_gmm_fwd")
-            b = dot(xw, wu, True, "moe_gmm_fwd")
-            h = (_gate(a, act) * b).astype(x.dtype)
+            if wg is None:
+                h = _gate(dot(xw, wu, True, "moe_gmm_fwd"),
+                          act).astype(x.dtype)
+            else:
+                a = dot(xw, wg, True, "moe_gmm_fwd")
+                b = dot(xw, wu, True, "moe_gmm_fwd")
+                h = (_gate(a, act) * b).astype(x.dtype)
             out = dot(h, wd, True, "moe_gmm_fwd")
         with jax.named_scope("moe_combine"):
             tm = _token_major(plan, pair, valid, lo, top_k)
@@ -388,7 +419,7 @@ def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window, kernel,
 def _held_bwd(tile, top_k, window, kernel, act, res, dy):
     x, pair_weight, plan, wg, wu, wd = res
     dy = dy.astype(jnp.float32)
-    segment = min(top_k, wg.shape[0])
+    segment = min(top_k, wd.shape[0])
 
     def body(w, carry):
         dx, dpw, dwg, dwu, dwd = carry
@@ -397,21 +428,30 @@ def _held_bwd(tile, top_k, window, kernel, act, res, dy):
             xw, dyr = x[tok], dy[tok]
         with jax.named_scope("moe_experts"):
             dot, dot_t = _grouped(kernel, tile, sizes)
-            a = dot(xw, wg, True, "moe_gmm_fwd")
-            b = dot(xw, wu, True, "moe_gmm_fwd")
-            gate, dgate = _gate_and_slope(a, act)
-            h = (gate * b).astype(x.dtype)
+            if wg is None:
+                gate, dgate = _gate_and_slope(
+                    dot(xw, wu, True, "moe_gmm_fwd"), act)
+                h = gate.astype(x.dtype)
+            else:
+                a = dot(xw, wg, True, "moe_gmm_fwd")
+                b = dot(xw, wu, True, "moe_gmm_fwd")
+                gate, dgate = _gate_and_slope(a, act)
+                h = (gate * b).astype(x.dtype)
             wt = jnp.where(valid, pair_weight[pair], 0.0)
             # d pair_weight = dy . (h W_down^T), row by row
             out = dot(h, wd, True, "moe_gmm_fwd")
             dwt = jnp.where(valid, jnp.sum(dyr * out, -1), 0.0)
             dyw = (dyr * wt[:, None]).astype(x.dtype)
             dh = dot(dyw, wd, False, "moe_gmm_bwd")
-            da = (dh * b * dgate).astype(x.dtype)
-            db = (dh * gate).astype(x.dtype)
-            dxw = dot(da, wg, False, "moe_gmm_bwd") \
-                + dot(db, wu, False, "moe_gmm_bwd")
-            dwg = dot_t(da, xw, dwg, "moe_gmm_dw")
+            if wg is None:
+                db = (dh * dgate).astype(x.dtype)
+                dxw = dot(db, wu, False, "moe_gmm_bwd")
+            else:
+                da = (dh * b * dgate).astype(x.dtype)
+                db = (dh * gate).astype(x.dtype)
+                dxw = dot(da, wg, False, "moe_gmm_bwd") \
+                    + dot(db, wu, False, "moe_gmm_bwd")
+                dwg = dot_t(da, xw, dwg, "moe_gmm_dw")
             dwu = dot_t(db, xw, dwu, "moe_gmm_dw")
             dwd = dot_t(dyw, h, dwd, "moe_gmm_dw")
         with jax.named_scope("moe_combine"):
@@ -421,11 +461,12 @@ def _held_bwd(tile, top_k, window, kernel, act, res, dy):
             dpw = dpw.at[pair].add(dwt)
         return dx, dpw, dwg, dwu, dwd
 
-    zeros = [jnp.zeros(a.shape, jnp.float32)
+    zeros = [None if a is None else jnp.zeros(a.shape, jnp.float32)
              for a in (x, pair_weight, wg, wu, wd)]
     dx, dpw, dwg, dwu, dwd = lax.fori_loop(0, plan["windows"], body,
                                            tuple(zeros))
-    return (dx.astype(x.dtype), dpw, None, dwg.astype(wg.dtype),
+    return (dx.astype(x.dtype), dpw, None,
+            None if wg is None else dwg.astype(wg.dtype),
             dwu.astype(wu.dtype), dwd.astype(wd.dtype))
 
 
@@ -457,6 +498,8 @@ class MoE(Layer):
         self.tile = int(p.tile_rows) if p.has("tile_rows") else fit_tile(
             b * s, self.top_k, self.held, self.num_experts)
         self.act = str(p.expert_activation)
+        self.gate_matrix = bool(int(p.expert_gate_matrix))
+        self.shared_gate = bool(int(p.shared_gate))
         self.router_bottom = len(bottom_shapes) > 1
         self.score = str(p.score_function)
         self.selection_bias = bool(int(p.selection_bias))
@@ -465,13 +508,28 @@ class MoE(Layer):
         if self.score not in ("softmax", "sigmoid"):
             raise ValueError(f"{lp.name}: score_function {self.score!r}: "
                              "want softmax or sigmoid")
-        if self.act not in ("silu", "relu"):
+        if self.act not in ("silu", "relu", "relu2"):
             raise ValueError(f"{lp.name}: expert_activation {self.act!r}: "
-                             "want silu or relu")
-        if not self.gated and (self.act != "silu" or self.router_bottom):
+                             "want silu or relu (or relu2, an expert "
+                             "without a gate matrix)")
+        if self.act == "relu2" and self.gate_matrix:
             raise ValueError(
-                f"{lp.name}: expert_activation and a second bottom for the "
-                "router belong to the no-drop form (moe_param.gated_experts)")
+                f"{lp.name}: expert_activation relu2 is the two-matrix "
+                "expert's, W_down relu(W_up x)^2 (expert_gate_matrix "
+                "false); with a gate matrix it has no meaning here")
+        if not self.gated and (self.act != "silu" or self.router_bottom
+                               or not self.gate_matrix
+                               or not self.shared_gate
+                               or p.has("down_filler")):
+            raise ValueError(
+                f"{lp.name}: expert_activation, expert_gate_matrix, "
+                "shared_gate, down_filler and a second bottom for the "
+                "router belong to the no-drop form (moe_param.gated_experts); "
+                "the Switch form's expert is W_2 relu(W_1 x + b_1) + b_2")
+        if not self.shared_gate and not self.shared_hidden:
+            raise ValueError(
+                f"{lp.name}: shared_gate is the shared expert's and "
+                "shared_hidden_dim names none")
         if not self.gated and (self.score != "softmax" or self.selection_bias
                                or self.topk_eps or self.scaling != 1.0):
             raise ValueError(
@@ -508,9 +566,9 @@ class MoE(Layer):
         or None when the kernels of ops/pallas_moe.py take it."""
         if jax.default_backend() != "tpu":
             return f"the backend is {jax.default_backend()}, not a TPU"
-        if self.embed % 128 or self.hidden % 128:
-            return (f"widths {self.embed} and {self.hidden} are not "
-                    "multiples of the lane width 128")
+        if self.embed % 128:
+            return (f"the width {self.embed} is not a multiple of the lane "
+                    "width 128")
         if self.tile % 8:
             return f"tile_rows {self.tile} is not a multiple of 8"
         if dtype not in (jnp.bfloat16, jnp.float32):
@@ -541,22 +599,32 @@ class MoE(Layer):
                 ((X, E, F), wf or xavier(F), *mults[3]),    # w2
                 ((X, E), None, *mults[4])]                  # b2
 
+    def blob_names(self):
+        """The no-drop form's blobs in order, by what each is."""
+        gate, shared = self.gate_matrix, bool(self.shared_hidden)
+        return (["router"] + ["w_gate"] * gate + ["w_up", "w_down"]
+                + (["ws_gate"] * gate + ["ws_up", "ws_down"]
+                   + ["shared_gate"] * self.shared_gate) * shared
+                + ["bias"] * self.selection_bias)
+
     def _gated_param_shapes(self):
-        mults = _param_mults(self.lp, 8)
+        names = self.blob_names()
+        mults = _param_mults(self.lp, len(names))
         E, F, Fs = self.embed, self.hidden, self.shared_hidden
         wf = self.p.weight_filler if self.p.has("weight_filler") \
             else Message("FillerParameter", type="gaussian", std=0.02)
-        shapes = [((self.num_experts, E), wf, *mults[0]),   # router
-                  ((self.held, F, E), wf, *mults[1]),       # w_gate
-                  ((self.held, F, E), wf, *mults[2]),       # w_up
-                  ((self.held, E, F), wf, *mults[3])]       # w_down
-        if Fs:
-            shapes += [((Fs, E), wf, *mults[4]), ((Fs, E), wf, *mults[5]),
-                       ((E, Fs), wf, *mults[6]), ((1, E), wf, *mults[7])]
-        if self.selection_bias:
-            # a buffer: zeros, and neither a rate nor a decay moves it
-            shapes.append(((self.num_experts,), None, 0.0, 0.0))
-        return shapes
+        df = self.p.down_filler if self.p.has("down_filler") else wf
+        shape = {"router": ((self.num_experts, E), wf),
+                 "w_gate": ((self.held, F, E), wf),
+                 "w_up": ((self.held, F, E), wf),
+                 "w_down": ((self.held, E, F), df),
+                 "ws_gate": ((Fs, E), wf), "ws_up": ((Fs, E), wf),
+                 "ws_down": ((E, Fs), df), "shared_gate": ((1, E), wf)}
+        # the bias is a buffer: zeros, and neither a rate nor a decay
+        # moves it
+        return [((self.num_experts,), None, 0.0, 0.0) if name == "bias"
+                else (*shape[name], *mults[i])
+                for i, name in enumerate(names)]
 
     def out_shapes(self):
         shapes = [tuple(self.bottom_shapes[0])]
@@ -673,6 +741,26 @@ class MoE(Layer):
             top = top * self.scaling
         return idx, top
 
+    def _shared(self, xt, blob):
+        """The shared expert of every token, float32: behind its sigmoid
+        gate unless `shared_gate` is off; of the experts' form."""
+        names = ["ws_gate"] * self.gate_matrix + ["ws_up", "ws_down"] \
+            + ["shared_gate"] * self.shared_gate
+        w = {n: blob[n].astype(xt.dtype) for n in names}
+        if self.gate_matrix:
+            h = jax.nn.silu(xt @ w["ws_gate"].T) * (xt @ w["ws_up"].T)
+        else:
+            h = _gate(jnp.dot(xt, w["ws_up"].T,
+                              preferred_element_type=jnp.float32),
+                      self.act).astype(xt.dtype)
+        if not self.shared_gate:
+            return jnp.dot(h, w["ws_down"].T,
+                           preferred_element_type=jnp.float32)
+        open_ = jax.nn.sigmoid(jnp.dot(
+            xt, w["shared_gate"].T, preferred_element_type=jnp.float32))
+        return open_ * jnp.dot(h, w["ws_down"].T,
+                               preferred_element_type=jnp.float32)
+
     def apply_stateful(self, params, state, bottoms, train, rng):
         x = bottoms[0]
         b, s, e = x.shape
@@ -692,26 +780,38 @@ class MoE(Layer):
         why_xla = self._why_xla(x.dtype)
         tracer = default_tracer()
         now = tracer.now_ns()
+        # a hidden width off the lane width: the kernels see zero columns
+        # up to the next multiple of 128, the blobs do not
+        pad = -self.hidden % 128 if why_xla is None else 0
         tracer.record("moe.path", now, now, layer=self.lp.name,
                       path="xla" if why_xla else "kernel",
-                      reason=why_xla or "backend, widths and tile_rows fit",
+                      reason=why_xla or "backend, widths and tile_rows fit"
+                      + (f"; the hidden width {self.hidden} padded by {pad} "
+                         "zero columns in the cast copies" if pad else ""),
                       activation=self.act, score=self.score,
                       selection_bias=self.selection_bias,
-                      combine="gather", segment=min(k, held))
+                      combine="gather", segment=min(k, held),
+                      matrices=3 if self.gate_matrix else 2,
+                      shared_gate=bool(self.shared_hidden)
+                      and self.shared_gate)
+        blob = dict(zip(self.blob_names(), params))
+
+        def cast(name, axis):       # `axis`: where the hidden width lies
+            w = blob.get(name)
+            if w is None:
+                return None
+            w = w.astype(x.dtype)
+            return jnp.pad(w, [(0, pad if d == axis else 0)
+                               for d in range(3)]) if pad else w
         # what the window loop costs beside its body's three scopes: the
         # held weights cast to the compute type, the loop's zero start
         with jax.named_scope("moe_glue"):
-            wg, wu, wd = (w.astype(x.dtype) for w in params[1:4])
+            wg, wu, wd = cast("w_gate", 1), cast("w_up", 1), cast("w_down", 2)
             y = held_experts(xt, top.reshape(n * k), plan, wg, wu, wd,
                              self.tile, k, window, why_xla is None, self.act)
         if self.shared_hidden:
             with jax.named_scope("moe_shared"):
-                sg, su, sd, gate = (w.astype(x.dtype) for w in params[4:8])
-                h = jax.nn.silu(xt @ sg.T) * (xt @ su.T)
-                open_ = jax.nn.sigmoid(jnp.dot(
-                    xt, gate.T, preferred_element_type=jnp.float32))
-                y = y + open_ * jnp.dot(h, sd.T,
-                                        preferred_element_type=jnp.float32)
+                y = y + self._shared(xt, blob)
         with jax.named_scope("moe_glue"):
             tops = [y.reshape(b, s, e).astype(x.dtype)]
         if len(self.lp.top) > 1:

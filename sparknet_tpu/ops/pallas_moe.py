@@ -46,15 +46,33 @@ _GMM_BLOCK, _TGMM_BLOCK = 1 << 20, 1 << 19
 
 
 def _blocks(k, n, most):
-    """(tk, tn): k and n whole, the larger halved (or, where it has no
-    half that is a multiple of the lane width, cut to one lane width)
-    while the block holds more than `most` elements."""
+    """(tk, tn): k and n whole, the larger halved while the block holds
+    more than `most` elements. A width with no half that is a multiple of
+    the lane width (2,688 = 21 x 128, 1,920 = 15 x 128: PR 42; the widths
+    before it all halve down to their blocks) takes the pair of divisors
+    in whole lane rows with the most elements that fit: (2688, 384) for
+    2,688 x 1,920, where a cut to one lane row made 21 steps of (128,
+    1920)."""
+    whole = k, n
     while k * n > most:
+        if (k if k >= n else n) % 256:
+            return _fitting_divisors(*whole, most)
         if k >= n:
-            k = k // 2 if k % 256 == 0 else 128
+            k //= 2
         else:
-            n = n // 2 if n % 256 == 0 else 128
+            n //= 2
     return k, n
+
+
+def _fitting_divisors(k, n, most):
+    """The divisors (tk, tn) of k and n, multiples of the lane width each,
+    with the largest product that `most` allows; of equal products the
+    squarer pair."""
+    def lane_divisors(x):
+        return [d for d in range(128, x + 1, 128) if x % d == 0]
+    return max(((tk, tn) for tk in lane_divisors(k) for tn in lane_divisors(n)
+                if tk * tn <= most or (tk, tn) == (128, 128)),
+               key=lambda b: (b[0] * b[1], min(b)))
 
 
 def grouped_dot(sizes, tile, lhs, rhs, transpose_rhs, name):

@@ -237,6 +237,7 @@ MESSAGES = {
         "gated_delta_net_param": (204, "GatedDeltaNetParameter", "opt",
                                   None),
         "short_conv_param": (205, "ShortConvParameter", "opt", None),
+        "mamba2_param": (206, "Mamba2Parameter", "opt", None),
     },
     "TransformationParameter": {
         "scale": (1, "float", "opt", 1.0),
@@ -608,6 +609,10 @@ MESSAGES = {
         "index_head_dim": (16, "uint32", "opt", None),
         "index_topk": (17, "uint32", "opt", None),
         "index_stats": (18, "bool", "opt", False),
+        # the grouped-query form's out projection filled by this one and
+        # not by weight_filler (a model that scales its residual branches'
+        # last matrix down at the start)
+        "out_filler": (19, "FillerParameter", "opt", None),
     },
     # sparknet_tpu extension: last-axis RMS norm, y = x / rms(x) * (1 + w)
     # (zero_centered, w filled with 0) or * w (w filled with 1).
@@ -635,6 +640,30 @@ MESSAGES = {
         "kernel": (1, "uint32", "opt", 3),
         "weight_filler": (2, "FillerParameter", "opt", None),
         "conv_filler": (3, "FillerParameter", "opt", None),
+    },
+    # sparknet_tpu extension: the Mamba-2 state-space mixer (ops/mamba2.py):
+    # num_heads heads of head_dim channels, a state of state_size a
+    # channel, B and C shared by the heads of one of n_groups groups, a
+    # causal depthwise conv of conv_kernel taps with a bias, the scan in
+    # chunks of `chunk` tokens, a gated RMSNorm over each group's channels.
+    # out_filler fills W_out (unset: weight_filler); the taps and their
+    # bias are filled uniform(+-1/sqrt(conv_kernel)); dt_bias is filled so
+    # that softplus(dt_bias) lies in [dt_min, dt_max]. With
+    # `stats` a second top (weight 0) carries the mean share of a state
+    # that survives one chunk.
+    "Mamba2Parameter": {
+        "num_heads": (1, "uint32", "opt", 1),
+        "head_dim": (2, "uint32", "opt", 64),
+        "state_size": (3, "uint32", "opt", 128),
+        "n_groups": (4, "uint32", "opt", 1),
+        "conv_kernel": (5, "uint32", "opt", 4),
+        "chunk": (6, "uint32", "opt", 128),
+        "norm_eps": (7, "float", "opt", 1e-5),
+        "weight_filler": (8, "FillerParameter", "opt", None),
+        "out_filler": (9, "FillerParameter", "opt", None),
+        "dt_min": (10, "float", "opt", 0.001),
+        "dt_max": (11, "float", "opt", 0.1),
+        "stats": (12, "bool", "opt", False),
     },
     # sparknet_tpu extension: last-axis layer norm for transformer blocks.
     "LayerNormParameter": {
@@ -680,6 +709,17 @@ MESSAGES = {
         "selection_bias": (15, "bool", "opt", False),
         "topk_eps": (16, "float", "opt", 0.0),
         "routed_scaling_factor": (17, "float", "opt", 1.0),
+        # the no-drop form's EXPERT. expert_gate_matrix false: an expert is
+        # TWO matrices, W_down act(W_up x), and the blob list has no
+        # w_gate (nor the shared expert a ws_gate); "relu2", relu(u)^2, is
+        # an activation of that form alone. shared_gate false: the shared
+        # expert is added as it is, with no sigmoid(w_s . x) in front and
+        # no blob for it; it takes the experts' form and, in the two-matrix
+        # form, their activation. down_filler fills w_down and ws_down
+        # (unset: weight_filler).
+        "expert_gate_matrix": (18, "bool", "opt", True),
+        "shared_gate": (19, "bool", "opt", True),
+        "down_filler": (20, "FillerParameter", "opt", None),
     },
 }
 

@@ -116,8 +116,10 @@ def _gqa(b, h, hkv, s, d, seed=3):
 
 
 # (8, 2, 64): four query heads a key-value head of 64, half a lane row
+# (32, 2, 128): sixteen query heads a key-value head, the hybrid
+# state-space model's ratio (4, 7 and 8 before PR 42)
 @pytest.mark.parametrize("h,hkv,d", [(4, 2, 64), (8, 1, 32), (4, 2, 256),
-                                     (8, 2, 64)])
+                                     (8, 2, 64), (32, 2, 128)])
 def test_flash_shared_kv_heads_forward(h, hkv, d):
     """Query head i reads key-value head i // (H / Hkv) in place."""
     q, k, v = _gqa(2, h, hkv, 256, d)
@@ -129,7 +131,7 @@ def test_flash_shared_kv_heads_forward(h, hkv, d):
 
 
 @pytest.mark.parametrize("h,hkv,d", [(4, 2, 64), (4, 2, 256), (6, 3, 32),
-                                     (8, 2, 64)])
+                                     (8, 2, 64), (32, 2, 128)])
 def test_flash_shared_kv_heads_backward(h, hkv, d):
     """A shared head's gradient is the sum over its group, made inside the
     dK/dV kernel; dk and dv come back with the key-value heads' shape."""

@@ -526,8 +526,7 @@ def test_the_four_blocks_scan_as_one_run_and_carry_their_losses_out():
 def test_paths_selection_and_kept_arrays_are_recorded():
     from sparknet_tpu.obs.trace import Tracer
     ring = default_tracer()
-    marks = {n: len(ring.spans(n))
-             for n in ("attn.path", "dsa.select", "remat.kept")}
+    mark = ring.mark()
     tracer = Tracer()
     sp = Message("SolverParameter", display=1, random_seed=0, **SOLVER)
     solver = Solver(sp, net_param=toy_net(flash=True, seq_len=128,
@@ -536,18 +535,18 @@ def test_paths_selection_and_kept_arrays_are_recorded():
                     log_fn=None, tracer=tracer, remat="full")
     draw = np.random.RandomState(2).randint(0, 64, (2, 129)).astype(np.int32)
     solver.step(1, iter([{"data": draw[:, :-1], "label": draw[:, 1:]}]))
-    paths = ring.spans("attn.path")[marks["attn.path"]:]
+    paths = ring.since(mark, "attn.path")
     assert {r["layer"] for r in paths} == {"block0/attn", "block1/attn"}
     assert all(r["path"] == "kernel" and "index tile" in r["core"]
                and "counting" in r["select"] and r["live_blocks"] == 1
                and r["backward"].startswith("one kernel: dq in VMEM")
                and r["backward_kernels"] == 1 for r in paths)
-    picks = ring.spans("dsa.select")[marks["dsa.select"]:]
+    picks = ring.since(mark, "dsa.select")
     assert picks and all(
         r["topk"] == 16 and r["tiles_visited"] == r["tiles_causal"] == 1
         and abs(r["mean_keys"] - (136 + 112 * 16) / 128) < 1e-9
         for r in picks)
-    kept = ring.spans("remat.kept")[marks["remat.kept"]:]
+    kept = ring.since(mark, "remat.kept")
     assert {r["array"] for r in kept if r["layer"] == "block0/attn"} == \
         {"thr", "lse_i", "o", "lse"}
     window = tracer.spans("dsa.window")

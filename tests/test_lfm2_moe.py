@@ -541,8 +541,7 @@ def test_paths_and_load_are_recorded():
     solver = Solver(sp, net_param=toy_net(moe_stats=True), log_fn=None,
                     tracer=tracer)
     ring = default_tracer()
-    marks = {n: len(ring.spans(n))
-             for n in ("moe.path", "attn.path", "shortconv.path")}
+    mark = ring.mark()
     data, labels = tokens(2)
     solver.step(2, iter([{"data": data, "label": labels}] * 2))
     loads = tracer.spans("moe.load")
@@ -550,13 +549,13 @@ def test_paths_and_load_are_recorded():
                                            for i in range(1, 5)}
     for r in loads:
         assert 0.0 < r["held_share"] < 1.0 and r["windows"] >= 1.0
-    moe = ring.spans("moe.path")[marks["moe.path"]:]
+    moe = ring.since(mark, "moe.path")
     assert moe and all(r["score"] == "sigmoid" and r["selection_bias"]
                        and r["activation"] == "silu" for r in moe)
-    attn = ring.spans("attn.path")[marks["attn.path"]:]
+    attn = ring.since(mark, "attn.path")
     assert attn and all(r["layer"] == "block1/mixer"
                         and r["head_dim"] == 16 for r in attn)
-    conv = ring.spans("shortconv.path")[marks["shortconv.path"]:]
+    conv = ring.since(mark, "shortconv.path")
     assert {r["layer"] for r in conv} == {f"block{i}/mixer"
                                           for i in (0, 2, 3, 4)}
     assert all(r["kernel"] == 3 and r["channels"] == 32 for r in conv)

@@ -378,15 +378,77 @@ def test_a_layer_that_names_no_tile_gets_one_its_even_share_fits(
             >= 1.25 * even
 
 
-def moe_paths():
+# What the window of a THREE-matrix expert traces (forward and backward,
+# XLA's ragged products), by activation: PR 42 gave the window a second
+# form for an expert of two matrices, and the four accepted LM cells'
+# steps must keep their text. Digests taken on the parent commit.
+THREE_MATRIX_JAXPRS = {"silu": "e3787889089128bb",
+                       "relu": "4364e0255f8740b2"}
+
+
+def _window_digest(act, gate=True):
+    import hashlib
+    n, e, f, held, k, tile = 32, 16, 24, 4, 2, 8
+    window = moe_ops.window_rows(n, k, held, 8, tile)
+    x = jnp.zeros((n, e), jnp.bfloat16)
+    pw = jnp.zeros((n * k,), jnp.float32)
+    pe = jnp.zeros((n * k,), jnp.int32)
+    wu = jnp.zeros((held, f, e), jnp.bfloat16)
+    wd = jnp.zeros((held, e, f), jnp.bfloat16)
+
+    def loss(x, pw, wg, wu, wd):
+        plan = moe_ops.plan_windows(pe, held, window)
+        return jnp.sum(moe_ops.held_experts(x, pw, plan, wg, wu, wd, tile,
+                                            k, window, False, act))
+    text = str(jax.make_jaxpr(jax.value_and_grad(loss, (0, 1, 2, 3, 4)))(
+        x, pw, wu if gate else None, wu, wd))
+    assert "/root" not in text and "0x" not in text    # no path, no address
+    return hashlib.sha256(text.encode()).hexdigest()[:16], text
+
+
+@pytest.mark.parametrize("act", list(THREE_MATRIX_JAXPRS))
+def test_the_three_matrix_window_traces_as_before(act):
+    assert _window_digest(act)[0] == THREE_MATRIX_JAXPRS[act]
+
+
+def test_the_two_matrix_window_is_two_products_and_two_weight_gradients():
+    three, two = _window_digest("silu")[1], _window_digest("relu2", False)[1]
+    # forward 3 and 2; backward the same recomputed, dh, dx (2 and 1) and
+    # the weight gradients (3 and 2)
+    assert three.count("ragged_dot_general") == 3 + 3 + 1 + 2 + 3
+    assert two.count("ragged_dot_general") == 2 + 2 + 1 + 1 + 2
+
+
+@pytest.mark.parametrize("k,n,most,want", [
+    # the accepted cells' widths halve down to their blocks, as before
+    (2048, 512, 1 << 20, (2048, 512)), (2048, 512, 1 << 19, (1024, 512)),
+    (2560, 768, 1 << 20, (1280, 768)), (768, 2560, 1 << 19, (768, 640)),
+    (2048, 1792, 1 << 20, (1024, 896)), (1792, 2048, 1 << 19, (896, 512)),
+    # 2,688 = 21 x 128 and 1,920 = 15 x 128 have no such half: divisors in
+    # whole lane rows, the most elements that fit (PR 42; a cut to ONE lane
+    # row, 21 steps of (128, 1920), read 9.5% of the products' roofline)
+    (2688, 1920, 1 << 20, (2688, 384)), (1920, 2688, 1 << 20, (384, 2688)),
+    (2688, 1920, 1 << 19, (896, 384)), (1920, 2688, 1 << 19, (384, 896)),
+    (128, 128, 1 << 10, (128, 128))])
+def test_block_sizes_of_the_grouped_products(k, n, most, want):
+    from sparknet_tpu.ops import pallas_moe
+    tk, tn = pallas_moe._blocks(k, n, most)
+    assert (tk, tn) == want
+    assert k % tk == 0 and n % tn == 0 and tk % 128 == 0 and tn % 128 == 0
+
+
+def moe_paths(mark):
     return [(s["layer"], s["path"], s["reason"], s["combine"], s["segment"])
-            for s in default_tracer().spans("moe.path")]
+            for s in default_tracer().since(mark, "moe.path")]
 
 
 @pytest.mark.parametrize("backend,widths,tile,path,reason", [
     ("cpu", (128, 128), 8, "xla", "the backend is cpu, not a TPU"),
-    ("tpu", (128, 64), 8, "xla", "widths 128 and 64 are not multiples of "
-                                 "the lane width 128"),
+    ("tpu", (64, 128), 8, "xla", "the width 64 is not a multiple of the "
+                                 "lane width 128"),
+    # a hidden width off the lane width is the kernels' to pad (PR 42)
+    ("tpu", (128, 64), 8, "kernel", "backend, widths and tile_rows fit; "
+     "the hidden width 64 padded by 64 zero columns in the cast copies"),
     ("tpu", (128, 128), 4, "xla", "tile_rows 4 is not a multiple of 8"),
     ("tpu", (128, 128), 8, "kernel", "backend, widths and tile_rows fit")])
 def test_layer_takes_the_product_it_can_and_records_it(
@@ -404,13 +466,13 @@ def test_layer_takes_the_product_it_can_and_records_it(
     impl = get_layer(lp.type)(lp, [(1, 16, embed)], 0)
     blobs = [jax.ShapeDtypeStruct(s[0], jnp.float32)
              for s in impl.param_shapes()]
-    before = len(moe_paths())
+    before = default_tracer().mark()
     text = str(jax.make_jaxpr(
         lambda p, x: impl.apply(p, [x], True, None)[0])(
         blobs, jax.ShapeDtypeStruct((1, 16, embed), jnp.float32)))
     # the combine gathers, a token's at most min(top_k, held) = 2 rows of a
     # window added by segments
-    assert moe_paths()[before:] == [(name, path, reason, "gather", 2)]
+    assert moe_paths(before) == [(name, path, reason, "gather", 2)]
     assert ("pallas_call" in text) == (path == "kernel")
     assert ("ragged_dot" in text) == (path == "xla")
     # one structure either way: a loop of dynamic length over windows, and

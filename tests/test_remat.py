@@ -278,11 +278,11 @@ def test_what_a_block_keeps_is_the_named_arrays_and_its_inputs(case):
     record a named array in the ring, with its bytes."""
     block, x, ws, _, kept = _KERNEL_CASES[case]()
     ring = default_tracer()
-    before = len(ring.spans("remat.kept"))
+    before = ring.mark()
     # what jax.ad_checkpoint.print_saved_residuals prints, as a list
     from jax._src.ad_checkpoint import saved_residuals
     saved = saved_residuals(compiler._checkpointed(block, "full"), x, *ws)
-    records = ring.spans("remat.kept")[before:]
+    records = ring.since(before, "remat.kept")
     assert [(r["array"], r["shape"], r["dtype"]) for r in records] == [
         (name, *kept[name]) for name in kept]
     for r in records:

@@ -164,7 +164,7 @@ def test_mixer_and_scan_scopes_forward_and_backward(scan, remat):
 def test_the_readers_list_of_inner_scopes_is_the_programs():
     import sparknet_tpu.ops as ops
     opened = set()
-    for mod in ("attention", "deltanet", "shortconv", "moe"):
+    for mod in ("attention", "deltanet", "shortconv", "moe", "mamba2"):
         with open(os.path.join(os.path.dirname(ops.__file__),
                                mod + ".py")) as f:
             opened |= set(re.findall(r'named_scope\("(\w+)"\)', f.read()))
@@ -173,10 +173,14 @@ def test_the_readers_list_of_inner_scopes_is_the_programs():
     # ops/pallas_dsa.py) are not the benchmark's: an operation under one of
     # them counts under the layer's part, `attn`, which keeps the ledger
     # closed without an edit to `INNER`
-    assert {s for s in opened if not s.startswith("dsa_")} == \
+    # nor are the state-space mixer's (PR 42): they count under `ssm`
+    assert {s for s in opened if not s.startswith(("dsa_", "ssm_"))} == \
         set(step_parts.INNER)
     assert {s for s in opened if s.startswith("dsa_")} == \
         {"dsa_index_proj", "dsa_stats"}
+    assert {s for s in opened if s.startswith("ssm_")} == {
+        "ssm_proj_in", "ssm_conv", "ssm_scan", "ssm_gate_norm",
+        "ssm_proj_out"}
 
 
 def test_iter_size_accumulates_under_its_own_scope():

@@ -94,7 +94,9 @@ GQA_SHAPES = {
     # 16 query heads over 2 key-value heads of 256
     "head256": (2, 16, 2, 256),
     # 32 over 8 of 64: every tile half a lane row wide
-    "lfm2_head64": (3, 32, 8, 64)}
+    "lfm2_head64": (3, 32, 8, 64),
+    # 32 over 2 of 128: sixteen query heads a key-value head
+    "nemotron_g16": (2, 32, 2, 128)}
 
 
 @pytest.mark.parametrize("shape", list(GQA_SHAPES))
@@ -157,7 +159,11 @@ MOE_SHAPES = {
     "smallthinker": (32768, 6, 8, 64, 2560, 768, "relu", 30720, 3 << 30),
     # 3 x 8,192 tokens, top-4 of 32 with 8 held, 2048 x 1792, SiLU: the
     # same 24,576 pairs and the same window, 3,072 rows an expert
-    "lfm2_moe": (24576, 4, 8, 32, 2048, 1792, "silu", 30720, 3 << 30)}
+    "lfm2_moe": (24576, 4, 8, 32, 2048, 1792, "silu", 30720, 3 << 30),
+    # 2 x 8,192 tokens, top-6 of 128 with 8 held, 2688 x 1856 PADDED TO
+    # 1,920 as the layer pads its cast copies, TWO matrices, relu^2: an
+    # even routing's 6,144 pairs and a quarter more in one window
+    "nemotron_h": (16384, 6, 8, 128, 2688, 1920, "relu2", 7680, 1 << 30)}
 
 
 @pytest.mark.parametrize("which", ["forward", "backward"])
@@ -177,8 +183,10 @@ def test_moe_grouped_products_compile(one_chip, monkeypatch, shape, which):
 
     def run(x, pw, pair_expert, wg, wu, wd):
         plan = moe_ops.plan_windows(pair_expert, held, window)
-        return moe_ops.held_experts(x, pw, plan, wg, wu, wd, MOE_TILE,
-                                    k, window, True, act)
+        # an expert of two matrices has no gate matrix
+        return moe_ops.held_experts(x, pw, plan,
+                                    None if act == "relu2" else wg, wu, wd,
+                                    MOE_TILE, k, window, True, act)
     if which == "forward":
         compiled = _compile(run, one_chip, x, pairs, experts, up, up, down,
                             kernels=["moe_gmm_fwd", "moe_segment_add"])
@@ -204,14 +212,15 @@ def test_moe_grouped_products_compile(one_chip, monkeypatch, shape, which):
                          ids=["forward", "backward"])
 @pytest.mark.parametrize("shape", list(MOE_SHAPES))
 def test_moe_segment_add_compiles(one_chip, monkeypatch, shape, weighted):
-    """The combine's one dense pass alone at the three cells' windows: a
-    block and a halo that fit the tiling at a segment of 6, 4 and 10 rows
-    (the last reaches 9 rows into the next block: a halo of 16)."""
+    """The combine's one dense pass alone at the cells' windows: a block
+    and a halo that fit the tiling at a segment of 6, 4 and 10 rows (the
+    last reaches 9 rows into the next block: a halo of 16), at widths of
+    2,048, 2,560 and 2,688 (21 lane rows: blocks of 896 lanes)."""
     monkeypatch.setattr(pm, "_should_interpret", lambda: False)
     n, k, held, _, e, _, _, window, _ = MOE_SHAPES[shape]
     segment = min(k, held)
     block = pm.segment_block(window, segment)
-    assert block == {6400: 256, 30720: 512}[window]
+    assert block == {6400: 256, 30720: 512, 7680: 512}[window]
     rows = ((window, e), jnp.float32)
     column = ((window,), jnp.float32)
     tok = ((window,), jnp.int32)
@@ -253,6 +262,37 @@ def test_gated_delta_net_backward_holds_less_than_the_scans_did(
     compiled = _compile(grads, one_chip, x, (x[0], jnp.float32), *blobs,
                         kernels=["gdn_chunk_fwd", "gdn_chunk_bwd"])
     assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
+
+
+def test_mamba2_layer_compiles_and_holds_one_rows_masks(one_chip):
+    """One Mamba-2 mixer's forward and backward at the Nemotron cell's
+    shape (2 x 8,192 tokens, 64 heads of 64, state 128, 8 groups, chunks of
+    128, bfloat16 in): XLA's chunked form, no kernel. A row's decay masks
+    are 64 heads x 64 chunks x 128 x 128 (268 MB in float32), its chunk
+    states 134 MB: the temporaries must stay those of ONE row's scan
+    beside the projections' (3.1 GB when this test was written), not the
+    batch's, and the step has 5 GB for everything."""
+    from sparknet_tpu.graph.registry import get as get_layer
+    from sparknet_tpu.models import dsl
+    embed = 2688
+    lp = dsl.Mamba2Layer("mixer", ["x"], 64, 64, 128, 8, conv_kernel=4,
+                         chunk=128, norm_eps=1e-5)
+    x = ((2, 8192, embed), jnp.bfloat16)
+    impl = get_layer(lp.type)(lp, [x[0]], 0)
+    blobs = [(s[0], jnp.float32) for s in impl.param_shapes()]
+
+    def grads(x, cot, *blobs):
+        def loss(x, blobs):
+            y = impl.apply(list(blobs), [x], True, None)[0]
+            return jnp.sum(y.astype(jnp.float32) * cot)
+        return jax.grad(loss, (0, 1))(x, blobs)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (x, (x[0], jnp.float32), *blobs)]
+    compiled = jax.jit(grads).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and "ssm_scan" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.6e9
 
 
 # LRN where CaffeNet runs it (after each pool) and at GoogLeNet's conv2

@@ -151,11 +151,36 @@ def test_train_steps_leave_step_records_with_prep_and_enqueue():
 
 
 def test_a_solver_without_a_tracer_records_into_the_default_one():
-    before = len(default_tracer().spans("solver.step"))
+    before = default_tracer().mark()
     s = _solver(zoo.cifar10_full(batch_size=4))
     assert s.tracer is default_tracer()
     s.train_step(_cifar_batch())
-    assert len(default_tracer().spans("solver.step")) == before + 1
+    assert len(default_tracer().since(before, "solver.step")) == 1
+
+
+@pytest.mark.parametrize("filled", [0, 5, 8, 20])
+def test_records_since_a_mark_survive_the_rings_wrap(filled):
+    """A count of `spans(name)` stops meaning "new since then" once the
+    bounded ring is full and drops from the left (what failed
+    test_lfm2_moe.py::test_paths_and_load_are_recorded in a whole run: the
+    files before it on its worker had filled the default ring); `mark` /
+    `since` count every record ever put."""
+    from sparknet_tpu.obs.trace import Tracer
+    tr = Tracer(max_buffer=8)
+    for i in range(filled):
+        tr.record("x.path", 0, 0, i=i)
+    count, mark = len(tr.spans("x.path")), tr.mark()
+    for i in range(3):
+        tr.record("x.path", 0, 0, i=100 + i)
+        tr.record("other", 0, 0)
+    assert [r["i"] for r in tr.since(mark, "x.path")] == [100, 101, 102]
+    assert len(tr.since(mark)) == 6
+    if filled >= 8:     # the old reading: nothing new, or the wrong records
+        assert [r["i"] for r in tr.spans("x.path")[count:]] != [100, 101, 102]
+    # a mark that the ring has since run past gives what is left
+    for i in range(10):
+        tr.record("x.path", 0, 0, i=200 + i)
+    assert [r["i"] for r in tr.since(mark, "x.path")] == list(range(202, 210))
 
 
 @pytest.mark.parametrize("cls", ["DataParallelSolver", "LocalSGDSolver"])
