@@ -55,11 +55,33 @@ two decay-weighted operands, (C B^T) * L and exp(l_end - l_s) dx, each
 formed in float32 and rounded ONCE (what a flash kernel does with its
 probabilities).
 
-One form today, XLA's, a row of the batch at a time (`lax.map` over the
-rows, each row's scan checkpointed: the backward holds one row's decay
-masks, 64 heads x 64 chunks x 128 x 128, not the batch's); one `ssm.path`
-record a trace of the layer in the ring of obs/trace.py says so (`path` =
-`chunked`, with heads, head size, state, groups, chunk and the `reason`).
+Two forms compute this, chosen where the layer is traced from what it can
+see there (no switch, no argument):
+
+- the pallas kernels of ops/pallas_ssd.py (`ssd_chunk_fwd`,
+  `ssd_chunk_bwd`) when `state_size` and `chunk` are both 128 and a
+  group's H / G heads of P channels fill whole lane tiles (H P / G a
+  multiple of 128, P a divisor or a multiple of 128; the published 8 heads
+  of 64): a grid step is one chunk of one group, its heads' decay masks,
+  its one C B^T tile and the state (N x r P float32, carried across a
+  sequential grid axis) never leave VMEM, and the carry is the recurrence
+  H_c = exp(l_end) H_{c-1} + S_c itself. The forward stores the state every
+  chunk starts from (268 MB a layer at 2 x 8,192 tokens) for a backward
+  kernel that walks the chunks in reverse; y, those states and the last one
+  go through `graph/remat.py:keep`, so under `remat` the forward kernel
+  runs once a step. The CPU backend runs the kernels in interpret mode
+  (the tests). The module is imported in the branch of `apply_stateful`
+  that calls it: a process that traces no such layer never imports pallas
+  (tests/test_pallas_deltanet.py holds that).
+- XLA's form below for every other shape (the tests' toy heads), which is
+  also the oracle between the kernels and the token-by-token recurrence: a
+  row of the batch at a time (`lax.map` over the rows, each row's scan
+  checkpointed: the backward holds one row's decay masks, heads x chunks x
+  chunk x chunk, not the batch's).
+
+Which one a layer took is in the ring of obs/trace.py: one `ssm.path`
+record a trace of the layer, `path` = `kernel` or `chunked` with heads,
+head size, state, groups, chunk and the `reason`.
 Everything the layer traces lies under one of five scopes inside its own,
 none inside another, so that a device trace adds up by them: ssm_proj_in
 (W_in and the split), ssm_conv, ssm_scan (delta, A, the chunked scan, the D
@@ -157,11 +179,22 @@ def ssd_chunked(x, dt, a, b, c, chunk=128):
 def gated_group_norm(y, z, w, groups, eps):
     """w * g / rms(g) with g = y * silu(z), the mean square over each of
     `groups` runs of the last axis: the gate first, then the norm. y, z
-    (..., C), w (C,) -> (..., C) float32."""
+    (..., C), w (C,) -> (..., C) float32. A group's squares are summed,
+    and its factor spread back over its channels, by products with the 0/1
+    matrix of which channel lies in which group, at the highest precision
+    (float32 sums, as a reduction's). A reshape to (..., groups, C /
+    groups) would put the groups where a tile's rows of tokens are, and
+    XLA then moves the whole array before it reduces (0.82 ms a pass over
+    a mixer's 268 MB, nine times a step, and the norm alone 9.7 ms where
+    this form takes 4.4: chip runs, PR 43)."""
     g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    gg = g.reshape(g.shape[:-1] + (groups, g.shape[-1] // groups))
-    gg = gg * lax.rsqrt(jnp.mean(gg * gg, axis=-1, keepdims=True) + eps)
-    return gg.reshape(g.shape) * w.astype(jnp.float32)
+    c = g.shape[-1]
+    member = (jnp.arange(c)[:, None] // (c // groups)
+              == jnp.arange(groups)).astype(jnp.float32)       # (C, groups)
+    mean_sq = jnp.einsum("...c,cg->...g", g * g, member,
+                         precision=_HI) / (c // groups)
+    return g * jnp.einsum("...g,cg->...c", lax.rsqrt(mean_sq + eps), member,
+                          precision=_HI) * w.astype(jnp.float32)
 
 
 def inverse_softplus(y):
@@ -201,6 +234,21 @@ class Mamba2(Layer):
             if len(lp.top) != 2:
                 raise ValueError(f"{lp.name}: mamba2_param.stats wants a "
                                  "second top")
+
+    def _why_xla(self):
+        """Why this layer's shapes keep XLA's chunked form, or None when
+        the kernels of ops/pallas_ssd.py take them."""
+        r, p = self.heads // self.groups, self.head_dim
+        if self.state != 128 or self.chunk != 128:
+            return (f"state {self.state} and chunk {self.chunk} are not "
+                    "both 128, the kernels' one tile")
+        if (r * p) % 128 or (p % 128 and 128 % p):
+            return (f"a group's {r} heads of {p} do not fill whole lane "
+                    "tiles of 128")
+        if r > 64:
+            return (f"{r} heads a group: their per-token rows do not fit "
+                    "one transpose of a chunk")
+        return None
 
     def param_shapes(self):
         mults = _param_mults(self.lp, 8)
@@ -242,13 +290,14 @@ class Mamba2(Layer):
         bsz, s, _ = x.shape
         h, p, g, n = self.heads, self.head_dim, self.groups, self.state
         f32 = jnp.float32
+        why_xla = self._why_xla()
         tracer = default_tracer()
         now = tracer.now_ns()
         tracer.record("ssm.path", now, now, layer=self.lp.name,
-                      path="chunked", heads=h, head_dim=p, state=n, groups=g,
-                      chunk=self.chunk,
-                      reason="XLA's chunked form, a row of the batch at a "
-                             "time; the layer has no kernel of its own")
+                      path="chunked" if why_xla else "kernel", heads=h,
+                      head_dim=p, state=n, groups=g, chunk=self.chunk,
+                      reason=why_xla or "state, chunk and a group's heads "
+                                        "fit the kernels' tiles")
         with jax.named_scope("ssm_proj_in"):
             zxbcdt = x @ w_in.astype(x.dtype).T
             z = zxbcdt[..., :self.inner]
@@ -263,8 +312,15 @@ class Mamba2(Layer):
             b = xbc[..., self.inner:self.inner + g * n].reshape(bsz, s, g, n)
             c = xbc[..., self.inner + g * n:].reshape(bsz, s, g, n)
             delta = jax.nn.softplus(dt + dt_bias.astype(f32))
-            y, _, survive = ssd_chunked(xs, delta, -jnp.exp(a_log.astype(f32)),
-                                        b, c, self.chunk)
+            a = -jnp.exp(a_log.astype(f32))
+            if why_xla:
+                y, _, survive = ssd_chunked(xs, delta, a, b, c, self.chunk)
+            else:
+                # here and not at the top: a process without such a layer
+                # never imports pallas (1.4 s of every cell's set-up, PR 29)
+                from .pallas_ssd import chunk_scan
+                y, _, survive = chunk_scan(xs, delta, a, b, c, self.chunk,
+                                           layer=self.lp.name)
             y = y + d_skip.astype(f32)[:, None] * xs.astype(f32)
         with jax.named_scope("ssm_gate_norm"):
             y = gated_group_norm(y.reshape(bsz, s, self.inner), z, norm, g,

@@ -209,8 +209,9 @@ def test_the_survival_statistic_and_the_path_are_recorded():
     attn = ring.since(mark, "attn.path")
     assert attn and all(r["layer"] == "block2/mixer"
                         and r["path"] == "kernel" for r in attn)
-    # what the backward reuses: the flash pass's output and logsumexp; the
-    # scan is XLA's and names nothing
+    # what the backward reuses: the flash pass's output and logsumexp; at
+    # the toy heads (8 wide, state 16) the scan is XLA's form and names
+    # nothing (tests/test_pallas_ssd.py has the kernels' three)
     kept = ring.since(mark, "remat.kept")
     assert {(r["layer"], r["array"]) for r in kept} == {
         ("block2/mixer", "o"), ("block2/mixer", "lse")}

@@ -194,12 +194,26 @@ jax.eval_shape(lambda p, x: impl.apply(p, [x], True, None)[0], blobs,
                jax.ShapeDtypeStruct((1, 16, 128), "float32"))
 print("MOE", pallas())
 
+def mamba2(heads, head_dim, state, groups, chunk):
+    lp = dsl.Mamba2Layer("ssm", ["x"], heads, head_dim, state, groups,
+                         conv_kernel=4, chunk=chunk)
+    impl = get(lp.type)(lp, [(1, 256, 32)], 0)
+    blobs = [jax.ShapeDtypeStruct(s[0], "float32")
+             for s in impl.param_shapes()]
+    jax.eval_shape(lambda p, x: impl.apply(p, [x], True, None)[0], blobs,
+                   jax.ShapeDtypeStruct((1, 256, 32), "float32"))
+
+mamba2(8, 8, 16, 2, 16)
+print("SSM_TOY", pallas())
+
 lp = dsl.GatedDeltaNetLayer("mixer", ["x"], 1, 2, 128, 128)
 impl = get(lp.type)(lp, [(1, 64, 32)], 0)
 blobs = [jax.ShapeDtypeStruct(s[0], "float32") for s in impl.param_shapes()]
 jax.eval_shape(lambda p, x: impl.apply(p, [x], True, None)[0], blobs,
                jax.ShapeDtypeStruct((1, 64, 32), "float32"))
 print("GDN", pallas())
+mamba2(8, 64, 128, 1, 128)
+print("SSM", "sparknet_tpu.ops.pallas_ssd" in sys.modules)
 """
 
 
@@ -215,18 +229,22 @@ def test_a_process_that_steps_caffenet_never_imports_pallas():
     compiling one step of it leaves pallas out of `sys.modules`, and so
     does tracing the no-drop MoE where its grouped product is XLA's (off
     the TPU, ops/pallas_moe.py is never imported); tracing a GatedDeltaNet
-    at head size 128 brings it in. The module that holds the name a remat
-    policy keeps (graph/remat.py) is imported by the compiler, so by the
-    CNN process too, and is core jax alone."""
+    at head size 128 brings it in, and so does a Mamba2 at the published
+    heads (8 of 64 a group, state and chunks of 128: `ops/pallas_ssd.py`),
+    where one at toy heads leaves all of pallas out. The module that holds the name a remat policy keeps (graph/remat.py) is imported by
+    the compiler, so by the CNN process too, and is core jax alone."""
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run([sys.executable, "-c", _GUARD], cwd=REPO, env=env,
                          capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stderr[-3000:]
     lines = dict(ln.split(" ", 1) for ln in res.stdout.splitlines()
-                 if ln.startswith(("CNN ", "NAME ", "MOE ", "GDN ")))
+                 if ln.startswith(("CNN ", "NAME ", "MOE ", "GDN ", "SSM_TOY ",
+                                  "SSM ")))
     assert lines["CNN"] == "[]", lines["CNN"]
     assert lines["NAME"] == "True"
     assert lines["MOE"] == "[]", lines["MOE"]
     assert "jax.experimental.pallas" in lines["GDN"]
     assert "jax.experimental.pallas.tpu" in lines["GDN"]
+    assert lines["SSM_TOY"] == "[]", lines["SSM_TOY"]
+    assert lines["SSM"] == "True"

@@ -264,20 +264,51 @@ def test_gated_delta_net_backward_holds_less_than_the_scans_did(
     assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
 
 
-def test_mamba2_layer_compiles_and_holds_one_rows_masks(one_chip):
+# the Mamba-2 scan's kernel pair at the Nemotron cell's shape: 2 x 8,192
+# tokens, 64 heads of 64 over 8 groups, state and chunks of 128, x, B and
+# C bfloat16 as the conv leaves them
+SSD_B, SSD_S, SSD_H, SSD_P, SSD_G, SSD_N, SSD_Q = 2, 8192, 64, 64, 8, 128, 128
+
+
+@pytest.mark.parametrize("which", ["forward", "forward_for_backward",
+                                   "backward"])
+def test_ssd_chunk_kernels_compile(one_chip, which):
+    from sparknet_tpu.ops import pallas_ssd as ps
+    r, nc = SSD_H // SSD_G, SSD_S // SSD_Q
+    x = ((SSD_B, SSD_S, SSD_H * SSD_P), jnp.bfloat16)
+    bc = ((SSD_B, SSD_S, SSD_G * SSD_N), jnp.bfloat16)
+    rows = ((SSD_B, SSD_G, nc, ps._rows(r), SSD_Q), jnp.float32)
+    if which != "backward":     # differentiated (with the states), or not
+        _compile(lambda *a: ps._forward(
+            *a, r, SSD_P, False, which == "forward_for_backward"), one_chip,
+            x, bc, bc, rows, rows, kernels=["ssd_chunk_fwd"])
+        return
+    last = ((SSD_B, SSD_G, SSD_N, r * SSD_P), jnp.float32)
+    starts = ((SSD_B, SSD_G, nc, SSD_N, r * SSD_P), jnp.float32)
+    _compile(lambda *a: ps._backward(*a, r, SSD_P, False), one_chip,
+             x, bc, bc, rows, rows, starts, last, (x[0], jnp.float32), last,
+             kernels=["ssd_chunk_bwd"])
+
+
+def test_mamba2_layer_compiles_to_the_kernel_pair(one_chip, monkeypatch):
     """One Mamba-2 mixer's forward and backward at the Nemotron cell's
-    shape (2 x 8,192 tokens, 64 heads of 64, state 128, 8 groups, chunks of
-    128, bfloat16 in): XLA's chunked form, no kernel. A row's decay masks
-    are 64 heads x 64 chunks x 128 x 128 (268 MB in float32), its chunk
-    states 134 MB: the temporaries must stay those of ONE row's scan
-    beside the projections' (3.1 GB when this test was written), not the
-    batch's, and the step has 5 GB for everything."""
+    shape (bfloat16 in) through the kernel pair, both under `ssm_scan`.
+    XLA's chunked form held one row's decay masks (64 heads x 64 chunks x
+    128 x 128, 268 MB in float32) and chunk states beside the projections'
+    and the gate's float32 arrays: 3.1 GB of temporaries here (PR 42, this
+    compile, bound 3.6e9). The kernels' masks never leave VMEM; what lies
+    in HBM for them is y and every chunk's first state (268 MB each), and
+    with the group norm's sums as products (no moved copy of y) the
+    temporaries read 2.30 GB when this test was written."""
     from sparknet_tpu.graph.registry import get as get_layer
     from sparknet_tpu.models import dsl
+    from sparknet_tpu.ops import pallas_ssd as ps
+    # the described chip is not the backend: the kernels, not interpret
+    monkeypatch.setattr(ps, "_should_interpret", lambda: False)
     embed = 2688
-    lp = dsl.Mamba2Layer("mixer", ["x"], 64, 64, 128, 8, conv_kernel=4,
-                         chunk=128, norm_eps=1e-5)
-    x = ((2, 8192, embed), jnp.bfloat16)
+    lp = dsl.Mamba2Layer("mixer", ["x"], SSD_H, SSD_P, SSD_N, SSD_G,
+                         conv_kernel=4, chunk=SSD_Q, norm_eps=1e-5)
+    x = ((SSD_B, SSD_S, embed), jnp.bfloat16)
     impl = get_layer(lp.type)(lp, [x[0]], 0)
     blobs = [(s[0], jnp.float32) for s in impl.param_shapes()]
 
@@ -287,12 +318,13 @@ def test_mamba2_layer_compiles_and_holds_one_rows_masks(one_chip):
             return jnp.sum(y.astype(jnp.float32) * cot)
         return jax.grad(loss, (0, 1))(x, blobs)
 
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-            for s, d in (x, (x[0], jnp.float32), *blobs)]
-    compiled = jax.jit(grads).lower(*args).compile()
+    compiled = _compile(grads, one_chip, x, (x[0], jnp.float32), *blobs,
+                        kernels=["ssd_chunk_fwd", "ssd_chunk_bwd"])
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text and "ssm_scan" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.6e9
+    for kernel in ("ssd_chunk_fwd", "ssd_chunk_bwd"):
+        assert re.search(rf"ssm_scan[^\"]*/{kernel}/pallas_call", text), kernel
+    assert not re.search(r"ssm_scan[^\"]*/while", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
 
 
 # LRN where CaffeNet runs it (after each pool) and at GoogLeNet's conv2
