@@ -10,15 +10,20 @@ ties aside).
 
 Each row is the median of `--reps` calls of one jitted function after a
 warm-up call, the host's clock round `block_until_ready`. Printed and
-written as JSON to `--out`, with the backward's six results as sums and
-digests: a copy of this script in another commit's tree, run with the same
-`--seed`, says whether that commit's backward gives the same bits (the
+written as JSON to `--out`, with the selection's two results and the
+backward's six as sums and digests: a copy of this script in another
+commit's tree, run with the same `--seed`, says whether that commit's
+threshold, its index logsumexp and its backward give the same bits (the
 first key block's dk and dv apart: a sum through HBM that loses a tile
-loses it there first).
+loses it there first). `dsa_scores_write_ms` is the selection's kernel
+stopped after the scores' write (no bit planes, no search): the rest of
+`dsa_index_select_ms` is the search's; a tree whose kernel has no such
+stop leaves the row out.
 """
 
 import argparse
 import hashlib
+import inspect
 import json
 import os
 import statistics
@@ -50,10 +55,12 @@ def timed(fn, args, reps):
 
 
 def digest(x):
-    """A result's sum and absolute sum (float64, on the host) and its
+    """A result's sum and absolute sum (float64, on the host, the finite
+    entries: a threshold is -inf while a query takes every key) and its
     bytes' SHA-256: equal digests are equal bits."""
     x = np.asarray(x)
     wide = x.astype(np.float64)
+    wide = wide[np.isfinite(wide)]
     return {"sum": float(wide.sum()), "abs_sum": float(np.abs(wide).sum()),
             "sha256": hashlib.sha256(x.tobytes()).hexdigest()[:16]}
 
@@ -91,6 +98,12 @@ def main():
         lambda qi, ki, w: pd._select(qi, ki, w, topk, sq, sk, interp),
         (qi, ki, w), args.reps)
     rows["dsa_index_select_ms"] = ms
+    rows["select"] = {"thr": digest(thr), "lse_i": digest(lse_i)}
+    if "search" in inspect.signature(pd._select).parameters:
+        rows["dsa_scores_write_ms"] = timed(
+            lambda qi, ki, w: pd._select(qi, ki, w, topk, sq, sk, interp,
+                                         search=False),
+            (qi, ki, w), args.reps)[0]
     ms, (o, lse) = timed(
         lambda *a: pd._forward(*a, scale, bq, bk, interp),
         (q, k, v, qi, ki, w, thr), args.reps)

@@ -361,12 +361,13 @@ class Attention(Layer):
         tracer = default_tracer()
         if self.flash and s % 128 == 0:
             # here and not at the top: see `_core`
-            from .pallas_dsa import causal_tiles, sparse_attention
+            from .pallas_dsa import (BITS_A_PASS, SELECT_PASSES,
+                                     causal_tiles, sparse_attention)
             path, reason = "kernel", "flash is set and 128 divides S"
-            tiles = causal_tiles(s)
+            tiles, passes, bits = causal_tiles(s), SELECT_PASSES, BITS_A_PASS
             o, kl = sparse_attention(q, k, v, qi, ki, w, topk, self.lp.name)
         else:
-            path, tiles = "dense", 0
+            path, tiles, passes, bits = "dense", 0, 0, 0
             reason = "flash is not set" if not self.flash \
                 else f"128 does not divide the sequence length {s}"
             with jax.named_scope("attn_core"):
@@ -378,8 +379,9 @@ class Attention(Layer):
         now = tracer.now_ns()
         core = "masked tiles of the causal half, the index tile recomputed " \
             "in every kernel" if path == "kernel" else "whole score matrices"
-        select = "a threshold a query, the topk-th largest by 32 counting " \
-            "passes" if path == "kernel" else "jax.lax.top_k"
+        select = "a threshold a query, the topk-th largest by counting the " \
+            "scores' 32 bit planes, 32 keys a word and a bit a walk" \
+            if path == "kernel" else "jax.lax.top_k"
         backward = "one kernel: dq in VMEM, dk dv dkI through HBM a tile" \
             if path == "kernel" else "XLA's transpose of the whole matrices"
         tracer.record("attn.path", now, now, layer=self.lp.name, path=path,
@@ -392,5 +394,6 @@ class Attention(Layer):
         tracer.record("dsa.select", now, now, layer=self.lp.name, topk=topk,
                       tiles_causal=tiles, tiles_visited=tiles,
                       mean_keys=(full * (full + 1) / 2 + (s - full) * full)
-                      / s, select=select, core=core)
+                      / s, select=select, core=core, select_passes=passes,
+                      bits_a_pass=bits)
         return o, extra

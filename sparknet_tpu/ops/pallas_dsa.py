@@ -17,10 +17,15 @@ Four kernels, by their names in a device trace:
 
 - `dsa_index_select` (scope `dsa_select`): a block of queries against all
   their keys: the index scores by tiles into a VMEM scratch as sortable
-  integers, then the `topk`-th largest of every query by 32 counting
-  passes over the scratch (one bit of the answer a pass; `jax.lax.top_k`
-  at k = 2,048 is a sort on a TPU), and the logsumexp of the selected
-  scores (L_I's softmax). Out: `thr` and `lse_i`, (B, 1, S) float32.
+  integers, their maximum a query taken as they are written, and each
+  chunk of them once more as 32 BIT PLANES (a word holds one bit of 32
+  keys). Then the `topk`-th largest of every query, a bit of the answer
+  a walk from the top: the live keys with the bit set are one `and` and
+  one population count a word, so 32 walks read the planes once where 32
+  counting passes over the keys read them 32 times (`jax.lax.top_k` at
+  k = 2,048 is a sort on a TPU). Last the logsumexp of the selected
+  scores (L_I's softmax), one pass over the keys. Out: `thr` and
+  `lse_i`, (B, 1, S) float32.
 - `flash_sparse_fwd` (scope `attn_core`): the flash recurrence over the
   selected keys; out o and the heads' logsumexp.
 - `dsa_kl` (scope `dsa_kl`): L_I's value a query, from the heads'
@@ -54,6 +59,7 @@ depth 64 whole.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -132,66 +138,145 @@ def _unsortable(key):
 
 # ---------------------------------------------------------------- selection
 
-def _select_kernel(qi_ref, ki_ref, w_ref, thr_ref, lse_ref, keys_ref, *,
-                   topk):
+SLABS = 32      # a chunk's rows are cut into as many slabs as a key has bits
+# what the `dsa.select` record says of the search (ops/attention.py): walks a
+# query block makes (32 of a bit plane and its live words, a 32nd of the
+# keys' size each, and the sum's one of the keys), answer bits a walk
+SELECT_PASSES, BITS_A_PASS = SLABS + 1, 1
+
+
+def _plane_rows(bk):
+    """Rows of a chunk's bit plane: a 32nd of the chunk, the chunk first
+    filled up to 32 slabs of whole (8, 128) registers."""
+    return -(-bk // (8 * SLABS)) * 8
+
+
+def _bit_planes(keys):
+    """A chunk's sortable integers (bk, bq) as 32 planes (bk / 32, bq):
+    bit 31 - b of plane i is bit 31 - i of slab b's key, plane 0 inverted
+    (the sign bit is SET in the lower half of the signed order). The
+    32 x 32 bit matrix of the slabs' words is transposed in five rounds of
+    masked swaps (Hacker's Delight 7-3), 15 operations a key where a
+    counting pass over the keys costs 3; the shift may be arithmetic,
+    every mask clears what it drags in. A chunk that is not 32 whole slabs
+    is filled up with INT_MIN, which no walk counts."""
+    bk, bq = keys.shape
+    m = _plane_rows(bk)
+    if m * SLABS > bk:
+        keys = jnp.concatenate(
+            [keys, jnp.full((m * SLABS - bk, bq), INT_MIN, jnp.int32)])
+    x = [keys[b * m:(b + 1) * m] for b in range(SLABS)]
+    for j, mask in ((16, 0x0000FFFF), (8, 0x00FF00FF), (4, 0x0F0F0F0F),
+                    (2, 0x33333333), (1, 0x55555555)):
+        for k in range(SLABS):
+            if not k & j:
+                swap = (x[k] ^ (x[k + j] >> j)) & jnp.int32(mask)
+                x[k] = x[k] ^ swap
+                x[k + j] = x[k + j] ^ (swap << j)
+    return [~x[0]] + x[1:]
+
+
+def _select_kernel(qi_ref, ki_ref, w_ref, thr_ref, lse_ref, keys_ref,
+                   top_ref, planes_ref, alive_ref, *, topk, stride, search):
     r, c, nc = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
     bq, bk = qi_ref.shape[2], ki_ref.shape[1]
+    m = _plane_rows(bk)
     last = (r * bq + bq - 1) // bk          # the last chunk with a seen key
 
     @pl.when(c <= last)
     def _scores():
-        keys = _sortable(_index_tile(ki_ref, qi_ref, w_ref))
-        keys_ref[pl.ds(pl.multiple_of(c * bk, bk), bk), :] = jnp.where(
-            _seen(r, c, bq, bk), keys, INT_MIN)
+        keys = jnp.where(_seen(r, c, bq, bk),
+                         _sortable(_index_tile(ki_ref, qi_ref, w_ref)),
+                         INT_MIN)
+        keys_ref[pl.ds(pl.multiple_of(c * bk, bk), bk), :] = keys
+        # the scores' maximum (the softmax's shift) rides with the write
+        part = jnp.max(keys.reshape(bk // 8, 8, bq), axis=0)
+        top_ref[...] = jnp.maximum(
+            part, jnp.where(c == 0, INT_MIN + 1, top_ref[...]))
+        if search:
+            for i, plane in enumerate(_bit_planes(keys)):
+                planes_ref[i, c] = plane
 
     @pl.when(c == nc - 1)
     def _select():
-        def over_chunks(fn, init):
-            def chunk(i, acc):
-                return fn(acc, keys_ref[pl.ds(pl.multiple_of(i * bk, bk),
-                                              bk), :])
-            return jax.lax.fori_loop(0, last + 1, chunk, init)
-
-        def bit(i, prefix):
-            # the answer's bits from the top: keep a bit where at least
-            # `topk` keys are still at or above the candidate (flipping the
-            # sign bit of INT_MIN first: the non-negative half lies above)
-            cand = prefix ^ jnp.left_shift(jnp.int32(1), 31 - i)
-            count = over_chunks(
-                lambda acc, blk: acc + jnp.sum(
-                    jnp.where(blk >= cand, 1.0, 0.0), axis=0, keepdims=True),
-                jnp.zeros((1, bq), jnp.float32))
-            return jnp.where(count >= topk, cand, prefix)
-
-        kth = jax.lax.fori_loop(0, 32, bit,
-                                jnp.full((1, bq), INT_MIN, jnp.int32))
         pos = r * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
+        top = _unsortable(jnp.max(top_ref[...], axis=0, keepdims=True))
+        if not search:      # scripts/bench_dsa.py: the scores' write alone
+            thr_ref[0] = jnp.full((1, bq), -jnp.inf, jnp.float32)
+            lse_ref[0] = top
+            return
+        steps = last // stride + 1          # `stride` chunks a step
+
+        def walk(i, alive_of):
+            """The live keys with bit 31 - i set, counted a query: every
+            step's live words are made by `alive_of`, stored, and counted
+            under plane i. 32 keys a word and one population count: a walk
+            reads a 32nd of what a pass over the keys reads."""
+            def step(t, acc):
+                at = pl.ds(pl.multiple_of(t * stride, stride), stride)
+                alive = alive_of(t, at)
+                alive_ref[at] = alive
+                return acc + jax.lax.population_count(
+                    alive & planes_ref[i, at])
+            acc = jax.lax.fori_loop(
+                0, steps, step, jnp.zeros((stride, m, bq), jnp.int32))
+            return jnp.sum(jnp.sum(acc, axis=0), axis=0, keepdims=True)
+
+        def keep(i, count, kth, rank):
+            """The answer's bits from the top: bit 31 - i is set where at
+            least `rank` live keys have it; where not, those keys lie above
+            the answer and leave the rank. -> kth, rank and what the next
+            walk turns plane i by (0: the keys with the bit stay live,
+            -1: those without)."""
+            take = count >= rank
+            return (jnp.where(take, kth | jnp.left_shift(
+                        jnp.int32(1), 31 - i), kth),
+                    jnp.where(take, rank, rank - count),
+                    jnp.where(take, 0, -1))
+
+        def seen_chunks(t, at):             # a row's end: chunks never written
+            chunk = t * stride + jax.lax.broadcasted_iota(
+                jnp.int32, (stride, m, bq), 0)
+            return jnp.where(chunk <= last, -1, 0)
+
+        def bit(i, carry):
+            kth, rank, turn = carry
+            return keep(i, walk(i, lambda t, at: alive_ref[at] & (
+                planes_ref[i - 1, at] ^ turn)), kth, rank)
+
+        zero = jnp.zeros((1, bq), jnp.int32)
+        kth = jax.lax.fori_loop(
+            1, 32, bit, keep(0, walk(0, seen_chunks), zero, zero + topk))[0]
+        kth = kth ^ INT_MIN                 # the planes' order is unsigned
         # a query with no more than topk keys takes them all
         cut = jnp.where(pos < topk, INT_MIN + 1, kth)
-        top = _unsortable(over_chunks(
-            lambda acc, blk: jnp.maximum(
-                acc, jnp.max(blk, axis=0, keepdims=True)),
-            jnp.full((1, bq), INT_MIN + 1, jnp.int32)))
-        total = over_chunks(
-            lambda acc, blk: acc + jnp.sum(jnp.where(
+
+        def chunk(i, acc):
+            blk = keys_ref[pl.ds(pl.multiple_of(i * bk, bk), bk), :]
+            return acc + jnp.sum(jnp.where(
                 blk >= cut, jnp.exp(_unsortable(blk) - top), 0.0),
-                axis=0, keepdims=True),
-            jnp.zeros((1, bq), jnp.float32))
+                axis=0, keepdims=True)
+        total = jax.lax.fori_loop(0, last + 1, chunk,
+                                  jnp.zeros((1, bq), jnp.float32))
         thr_ref[0] = jnp.where(pos < topk, -jnp.inf, _unsortable(kth))
         lse_ref[0] = top + jnp.log(total)
 
 
-def _select(qi, ki, w, topk, block_q, block_k, interpret):
-    """-> thr, lse_i, each (B, 1, S) float32."""
+def _select(qi, ki, w, topk, block_q, block_k, interpret, search=True):
+    """-> thr, lse_i, each (B, 1, S) float32. `search=False` stops after
+    the scores' write (scripts/bench_dsa.py's split of the kernel's time)."""
     b, hi, s, di = qi.shape
     bq, bk = _fit_block(block_q, s), _fit_block(block_k, s)
+    nk, m = s // bk, _plane_rows(bk)
+    stride = math.gcd(4, nk)
 
     def chunks(bb, r, c):
         return (bb, jnp.minimum(c, (r * bq + bq - 1) // bk), 0)
     row = pl.BlockSpec((1, 1, bq), lambda bb, r, c: (bb, 0, r))
     return pl.pallas_call(
-        functools.partial(_select_kernel, topk=topk),
-        grid=(b, s // bq, s // bk),
+        functools.partial(_select_kernel, topk=topk, stride=stride,
+                          search=search),
+        grid=(b, s // bq, nk),
         in_specs=[pl.BlockSpec((1, hi, bq, di),
                                lambda bb, r, c: (bb, 0, r, 0)),
                   pl.BlockSpec((1, bk, di), chunks),
@@ -199,7 +284,10 @@ def _select(qi, ki, w, topk, block_q, block_k, interpret):
                                lambda bb, r, c: (bb, 0, 0, r))],
         out_specs=[row, row],
         out_shape=[jax.ShapeDtypeStruct((b, 1, s), jnp.float32)] * 2,
-        scratch_shapes=[pltpu.VMEM((s, bq), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((s, bq), jnp.int32),
+                        pltpu.VMEM((8, bq), jnp.int32),
+                        pltpu.VMEM((SLABS, nk, m, bq), jnp.int32),
+                        pltpu.VMEM((nk, m, bq), jnp.int32)],
         compiler_params=_params(), interpret=interpret,
         name="dsa_index_select",
     )(qi, ki, w)

@@ -499,7 +499,8 @@ def test_flash_window_kernels_compile(one_chip, which):
 # heads on 4 key-value heads of 128, 16 index heads of 64 and one index key,
 # S = 32,768, topk 2,048; all the heads of a query block in one grid step
 # (30 MB of VMEM under the kernels' own limit), the selection's scratch of
-# 32,768 x 128 sortable integers (16 MB)
+# 32,768 x 128 sortable integers (16 MB) and as much again for their bit
+# planes
 @pytest.mark.parametrize("which", ["select", "forward", "kl", "backward"])
 def test_index_picked_attention_kernels_compile(one_chip, which):
     b, h, hk, s, d, hi, di, topk = 1, 32, 4, 32768, 128, 16, 64, 2048
