@@ -262,6 +262,114 @@ def googlenet(batch_size=32, num_classes=1000, with_data=True,
     return NetParam("GoogleNet", *layers)
 
 
+# ---------------------------------------------------------- language models
+
+_KEEP = dict(lr_mult=1, decay_mult=1)
+_NODECAY = dict(lr_mult=1, decay_mult=0)
+_XAVIER = dict(type="xavier")
+
+
+def _gauss(std=0.02):
+    return dict(type="gaussian", std=std)
+
+
+def _lm_embed(vocab_size, width, positions=None, **embed):
+    """The token embedding "tok_embed" and, with `positions`, the learned
+    positional table "pos_embed" added to it as "embed". Returns (layers,
+    the blob the first block reads)."""
+    layers = [EmbedLayer("tok_embed", ["data"], vocab_size, width, **embed)]
+    if not positions:
+        return layers, "tok_embed"
+    layers.append(PositionalEmbedLayer(
+        "pos_embed", ["tok_embed"], positions, width,
+        weight_filler=embed["weight_filler"], tops=["embed"]))
+    return layers, "embed"
+
+
+def _lm_block(p, x, branches, norm):
+    """One block from the description of its pre-norm residual branches:
+    for each (ln, body, res) in order, `norm(p + ln, x)`, the layers
+    `body(p, p + ln)` builds (the last one's first top is the branch's
+    output) and the sum `p + res` of x and that output, which the next
+    branch reads. `p` is the block's name prefix, its slash included.
+    Returns (layers, the block's boundary blob)."""
+    layers = []
+    for ln, body, res in branches:
+        inner = body(p, p + ln)
+        layers += [norm(p + ln, x), *inner,
+                   EltwiseLayer(p + res, [x, inner[-1].top[0]])]
+        x = p + res
+    return layers, x
+
+
+def _lm_head(x, vocab_size, norm, **head):
+    """The final norm "ln_f", the logits "lm_head" (tied to the embedding
+    where `head` names the table's `param`) and the mean cross-entropy per
+    token "loss" over "label"."""
+    return [norm("ln_f", x),
+            InnerProductLayer("lm_head", ["ln_f"], vocab_size, axis=2, **head),
+            SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2)]
+
+
+def _lm_stack(name, batch_size, seq_len, vocab_size, width, blocks, norm,
+              embed, head, positions=None, with_data=True):
+    """A causal language model as ONE pre-norm stack, the only place that
+    spells it: the feeds "data" and "label" (B, S) int32 (token ids, next
+    token ids), the embedding (`_lm_embed`), for every (i, branches) of
+    `blocks` the block "block{i}/" (`_lm_block`), the final norm, the head
+    and the loss (`_lm_head`). `norm(name, bottom)` builds the family's
+    norm layer; `embed` and `head` are the keywords of the embedding and
+    of the head's InnerProduct.
+
+    THE NAMING CONTRACT, which graph/compiler.py reads and nothing else
+    states: every layer of block i is named "block{i}/<suffix>", and
+    nothing outside it is; a block reads ONE blob from outside itself,
+    the boundary blob of the block before (the embedding's for the
+    first), and only its own last sum is read outside it; blocks whose
+    descriptions are equal come out layer for layer alike. By the first,
+    `CompiledNet._remat_groups` makes a block one rematerialization
+    segment (`--remat`); by all three, `CompiledNet._scan_runs` runs
+    neighbours that are alike as one `lax.scan` body over their stacked
+    blobs (`--scan`). A block that shares a blob with another, reads a
+    second outside blob or is named off the prefix forfeits both, in
+    silence: so a builder describes its blocks' branches and leaves the
+    names, the chaining and the order to this function."""
+    layers = []
+    if with_data:
+        layers += [RDDLayer("data", [batch_size, seq_len]),
+                   RDDLayer("label", [batch_size, seq_len])]
+    first, x = _lm_embed(vocab_size, width, positions, **embed)
+    layers += first
+    for i, branches in blocks:
+        block, x = _lm_block(f"block{i}/", x, branches, norm)
+        layers += block
+    return NetParam(name, *layers, *_lm_head(x, vocab_size, norm, **head))
+
+
+def _layer_norm(name, x):
+    return LayerNormLayer(name, [x])
+
+
+def _transformer_block(d_model, num_heads, d_ff, flash, ring=False, moe=None):
+    """transformer_lm's block: ln1 | attn | res1, then ln2 | ffn1 | relu |
+    ffn2 | res2 or, with `moe` (MoELayer's keywords), ln2 | moe | res2."""
+    def attn(p, h):
+        return [AttentionLayer(p + "attn", [h], num_heads, causal=True,
+                               flash=flash, ring=ring)]
+
+    def ffn(p, h):
+        return [InnerProductLayer(p + "ffn1", [h], d_ff,
+                                  weight_filler=_XAVIER, axis=2),
+                ReLULayer(p + "relu", [p + "ffn1"], tops=[p + "ffn1"]),
+                InnerProductLayer(p + "ffn2", [p + "ffn1"], d_model,
+                                  weight_filler=_XAVIER, axis=2)]
+
+    def switch(p, h):
+        return [MoELayer(p + "moe", [h], hidden_dim=d_ff,
+                         expert_parallel=True, **moe)]
+    return [("ln1", attn, "res1"), ("ln2", switch if moe else ffn, "res2")]
+
+
 def transformer_lm(vocab_size=512, seq_len=256, batch_size=8, d_model=256,
                    num_layers=4, num_heads=8, d_ff=None, max_positions=None,
                    flash=True, ring=False, with_data=True, moe_experts=0,
@@ -282,65 +390,27 @@ def transformer_lm(vocab_size=512, seq_len=256, batch_size=8, d_model=256,
     Blobs: "data" (B, S) int32 token ids, "label" (B, S) int32 next-token
     ids. Loss is mean cross-entropy per token (SoftmaxWithLoss axis=2).
 
-    Every "block{i}/" group is emitted by this one loop, so the blocks
-    are structurally isomorphic by construction and chain through a
-    single boundary blob — exactly what graph/compiler.py's
-    scan-over-layers detector (_scan_runs) requires to collapse the
-    stack into one lax.scan body (SPARKNET_SCAN / ``--scan``), and what
-    the per-block remat segments checkpoint (``--remat``). Renaming
-    blocks away from the shared prefix, sharing params across blocks,
-    or giving one block a different shape silently forfeits both.
+    Every "block{i}/" group comes out of `_lm_stack`'s one loop, which
+    states what scan-over-layers (``--scan``) and the per-block remat
+    segments (``--remat``) need of the names.
     """
-    d_ff = d_ff or 4 * d_model
-    max_positions = max_positions or seq_len
-    xavier = dict(type="xavier")
-    layers = []
-    if with_data:
-        layers += [RDDLayer("data", [batch_size, seq_len]),
-                   RDDLayer("label", [batch_size, seq_len])]
-    layers += [
-        EmbedLayer("tok_embed", ["data"], vocab_size, d_model,
-                   weight_filler=xavier),
-        PositionalEmbedLayer("pos_embed", ["tok_embed"], max_positions,
-                             d_model, weight_filler=xavier,
-                             tops=["embed"]),
-    ]
-    x = "embed"
-    for i in range(num_layers):
-        p = f"block{i}"
-        layers += [
-            LayerNormLayer(f"{p}/ln1", [x]),
-            AttentionLayer(f"{p}/attn", [f"{p}/ln1"], num_heads,
-                           causal=True, flash=flash, ring=ring),
-            EltwiseLayer(f"{p}/res1", [x, f"{p}/attn"]),
-            LayerNormLayer(f"{p}/ln2", [f"{p}/res1"]),
-        ]
-        if moe_experts:
-            layers += [
-                MoELayer(f"{p}/moe", [f"{p}/ln2"], moe_experts,
-                         hidden_dim=d_ff, expert_parallel=True,
-                         aux_loss_weight=moe_aux_weight,
-                         capacity_factor=moe_capacity_factor,
-                         stats=moe_stats),
-                EltwiseLayer(f"{p}/res2", [f"{p}/res1", f"{p}/moe"]),
-            ]
-        else:
-            layers += [
-                InnerProductLayer(f"{p}/ffn1", [f"{p}/ln2"], d_ff,
-                                  weight_filler=xavier, axis=2),
-                ReLULayer(f"{p}/relu", [f"{p}/ffn1"], tops=[f"{p}/ffn1"]),
-                InnerProductLayer(f"{p}/ffn2", [f"{p}/ffn1"], d_model,
-                                  weight_filler=xavier, axis=2),
-                EltwiseLayer(f"{p}/res2", [f"{p}/res1", f"{p}/ffn2"]),
-            ]
-        x = f"{p}/res2"
-    layers += [
-        LayerNormLayer("ln_f", [x]),
-        InnerProductLayer("lm_head", ["ln_f"], vocab_size,
-                          weight_filler=xavier, axis=2),
-        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
-    ]
-    return NetParam("TransformerLM", *layers)
+    moe = dict(num_experts=moe_experts, aux_loss_weight=moe_aux_weight,
+               capacity_factor=moe_capacity_factor,
+               stats=moe_stats) if moe_experts else None
+    block = _transformer_block(d_model, num_heads, d_ff or 4 * d_model,
+                               flash, ring, moe)
+    return _lm_stack(
+        "TransformerLM", batch_size, seq_len, vocab_size, d_model,
+        [(i, block) for i in range(num_layers)], _layer_norm,
+        embed=dict(weight_filler=_XAVIER), head=dict(weight_filler=_XAVIER),
+        positions=max_positions or seq_len, with_data=with_data)
+
+
+def _rms_norm(eps, **kw):
+    """The family's RMSNorm as `_lm_stack` takes it: no decay on its
+    weight."""
+    return lambda name, x: RMSNormLayer(name, [x], eps=eps, param=[_NODECAY],
+                                        **kw)
 
 
 def qwen3_next(vocab_size=151936, seq_len=8192, batch_size=2,
@@ -372,61 +442,42 @@ def qwen3_next(vocab_size=151936, seq_len=8192, batch_size=2,
     stage. Left out: the multi-token-prediction module, the router's
     auxiliary loss, dropout (none published).
 
-    Layers are named block{i}/ln1 | mixer | res1 | ln2 | moe | res2, so the
-    remat groups are the blocks and a run of like blocks scans."""
-    e = hidden_size
-    gauss = dict(type="gaussian", std=init_std)
-    keep, nodecay = dict(lr_mult=1, decay_mult=1), dict(lr_mult=1,
-                                                        decay_mult=0)
-    layers = [
-        RDDLayer("data", [batch_size, seq_len]),
-        RDDLayer("label", [batch_size, seq_len]),
-        EmbedLayer("tok_embed", ["data"], vocab_size, e,
-                   weight_filler=gauss, bias_term=False),
-    ]
-    x = "tok_embed"
-    for i in range(num_hidden_layers):
-        p = f"block{i}"
-        if (i + 1) % full_attention_interval == 0:
-            mixer = AttentionLayer(
-                f"{p}/mixer", [f"{p}/ln1"], num_attention_heads,
-                head_dim=head_dim, causal=True, flash=flash,
-                num_kv_heads=num_key_value_heads, qk_norm=True,
-                rotary_dim=int(head_dim * partial_rotary_factor),
-                rope_theta=rope_theta, output_gate=True,
-                norm_eps=rms_norm_eps, weight_filler=gauss,
-                param=[keep] * 4 + [nodecay] * 2)
-        else:
-            mixer = GatedDeltaNetLayer(
-                f"{p}/mixer", [f"{p}/ln1"], linear_num_key_heads,
-                linear_num_value_heads, linear_key_head_dim,
-                linear_value_head_dim, conv_kernel=linear_conv_kernel_dim,
-                norm_eps=rms_norm_eps, weight_filler=gauss,
-                param=[keep] * 3 + [nodecay] * 3 + [keep])
-        layers += [
-            RMSNormLayer(f"{p}/ln1", [x], eps=rms_norm_eps,
-                         param=[nodecay]),
-            mixer,
-            EltwiseLayer(f"{p}/res1", [x, f"{p}/mixer"]),
-            RMSNormLayer(f"{p}/ln2", [f"{p}/res1"], eps=rms_norm_eps,
-                         param=[nodecay]),
-            MoELayer(f"{p}/moe", [f"{p}/ln2"], num_experts,
-                     hidden_dim=moe_intermediate_size,
-                     top_k=num_experts_per_tok, experts_held=experts_held,
-                     first_expert=first_expert,
-                     shared_hidden_dim=shared_expert_intermediate_size,
-                     norm_topk_prob=norm_topk_prob, weight_filler=gauss,
-                     stats=moe_stats),
-            EltwiseLayer(f"{p}/res2", [f"{p}/res1", f"{p}/moe"]),
-        ]
-        x = f"{p}/res2"
-    layers += [
-        RMSNormLayer("ln_f", [x], eps=rms_norm_eps, param=[nodecay]),
-        InnerProductLayer("lm_head", ["ln_f"], vocab_size,
-                          weight_filler=gauss, axis=2, bias_term=False),
-        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
-    ]
-    return NetParam("Qwen3Next", *layers)
+    Layers are named block{i}/ln1 | mixer | res1 | ln2 | moe | res2
+    (`_lm_stack`): the remat groups are the blocks and a run of like
+    blocks scans."""
+    gauss = _gauss(init_std)
+
+    def attention(p, h):
+        return [AttentionLayer(
+            p + "mixer", [h], num_attention_heads, head_dim=head_dim,
+            causal=True, flash=flash, num_kv_heads=num_key_value_heads,
+            qk_norm=True, rotary_dim=int(head_dim * partial_rotary_factor),
+            rope_theta=rope_theta, output_gate=True, norm_eps=rms_norm_eps,
+            weight_filler=gauss, param=[_KEEP] * 4 + [_NODECAY] * 2)]
+
+    def deltanet(p, h):
+        return [GatedDeltaNetLayer(
+            p + "mixer", [h], linear_num_key_heads, linear_num_value_heads,
+            linear_key_head_dim, linear_value_head_dim,
+            conv_kernel=linear_conv_kernel_dim, norm_eps=rms_norm_eps,
+            weight_filler=gauss,
+            param=[_KEEP] * 3 + [_NODECAY] * 3 + [_KEEP])]
+
+    def moe(p, h):
+        return [MoELayer(
+            p + "moe", [h], num_experts, hidden_dim=moe_intermediate_size,
+            top_k=num_experts_per_tok, experts_held=experts_held,
+            first_expert=first_expert,
+            shared_hidden_dim=shared_expert_intermediate_size,
+            norm_topk_prob=norm_topk_prob, weight_filler=gauss,
+            stats=moe_stats)]
+    return _lm_stack(
+        "Qwen3Next", batch_size, seq_len, vocab_size, hidden_size,
+        [(i, [("ln1", deltanet if (i + 1) % full_attention_interval
+               else attention, "res1"), ("ln2", moe, "res2")])
+         for i in range(num_hidden_layers)], _rms_norm(rms_norm_eps),
+        embed=dict(weight_filler=gauss, bias_term=False),
+        head=dict(weight_filler=gauss, bias_term=False))
 
 
 def smallthinker(vocab_size=151936, seq_len=16384, batch_size=2,
@@ -463,61 +514,39 @@ def smallthinker(vocab_size=151936, seq_len=16384, batch_size=2,
     the tokens apart (at 0.02 the deeper layers route nearly every token to
     the same six experts).
 
-    Layers are named block{i}/ln1 | attn | res1 | ln2 | moe | res2; the
-    three window blocks of a period are alike and scan, the global one is
-    a body of its own."""
-    e = hidden_size
+    Layers are named block{i}/ln1 | attn | res1 | ln2 | moe | res2
+    (`_lm_stack`); the three window blocks of a period are alike and scan,
+    the global one is a body of its own."""
     period = [0, 1, 1, 1]
     rotary, windowed = (
         list(given) if given is not None
         else [period[i % 4] for i in range(num_hidden_layers)]
         for given in (rope_layout, sliding_window_layout))
-    gauss = dict(type="gaussian", std=0.02)
-    keep, nodecay = dict(lr_mult=1, decay_mult=1), dict(lr_mult=1,
-                                                        decay_mult=0)
-    layers = [
-        RDDLayer("data", [batch_size, seq_len]),
-        RDDLayer("label", [batch_size, seq_len]),
-        EmbedLayer("tok_embed", ["data"], vocab_size, e,
-                   weight_filler=dict(type="gaussian", std=1.0),
-                   bias_term=False),
-    ]
-    x = "tok_embed"
-    for i in range(num_hidden_layers):
-        p = f"block{i}"
-        layers += [
-            RMSNormLayer(f"{p}/ln1", [x], eps=rms_norm_eps,
-                         zero_centered=False, param=[nodecay]),
-            AttentionLayer(
-                f"{p}/attn", [f"{p}/ln1"], num_attention_heads,
-                head_dim=head_dim, causal=True, flash=flash,
-                num_kv_heads=num_key_value_heads,
-                rotary_dim=head_dim if rotary[i] else 0,
-                rope_theta=rope_theta, weight_filler=gauss,
-                window=sliding_window_size if windowed[i] else 0,
-                param=[keep] * 4),
-            EltwiseLayer(f"{p}/res1", [x, f"{p}/attn"]),
-            RMSNormLayer(f"{p}/ln2", [f"{p}/res1"], eps=rms_norm_eps,
-                         zero_centered=False, param=[nodecay]),
-            MoELayer(f"{p}/moe", [f"{p}/ln2", f"{p}/ln1"],
-                     moe_num_primary_experts,
-                     hidden_dim=moe_ffn_hidden_size,
-                     top_k=moe_num_active_primary_experts,
-                     experts_held=experts_held, first_expert=first_expert,
-                     norm_topk_prob=norm_topk_prob,
-                     expert_activation="relu", weight_filler=gauss,
-                     stats=moe_stats),
-            EltwiseLayer(f"{p}/res2", [f"{p}/res1", f"{p}/moe"]),
-        ]
-        x = f"{p}/res2"
-    layers += [
-        RMSNormLayer("ln_f", [x], eps=rms_norm_eps, zero_centered=False,
-                     param=[nodecay]),
-        InnerProductLayer("lm_head", ["ln_f"], vocab_size,
-                          weight_filler=gauss, axis=2, bias_term=False),
-        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
-    ]
-    return NetParam("SmallThinker", *layers)
+    gauss = _gauss()
+
+    def attention(i):
+        return lambda p, h: [AttentionLayer(
+            p + "attn", [h], num_attention_heads, head_dim=head_dim,
+            causal=True, flash=flash, num_kv_heads=num_key_value_heads,
+            rotary_dim=head_dim if rotary[i] else 0, rope_theta=rope_theta,
+            weight_filler=gauss,
+            window=sliding_window_size if windowed[i] else 0,
+            param=[_KEEP] * 4)]
+
+    def moe(p, h):
+        return [MoELayer(
+            p + "moe", [h, p + "ln1"], moe_num_primary_experts,
+            hidden_dim=moe_ffn_hidden_size,
+            top_k=moe_num_active_primary_experts, experts_held=experts_held,
+            first_expert=first_expert, norm_topk_prob=norm_topk_prob,
+            expert_activation="relu", weight_filler=gauss, stats=moe_stats)]
+    return _lm_stack(
+        "SmallThinker", batch_size, seq_len, vocab_size, hidden_size,
+        [(i, [("ln1", attention(i), "res1"), ("ln2", moe, "res2")])
+         for i in range(num_hidden_layers)],
+        _rms_norm(rms_norm_eps, zero_centered=False),
+        embed=dict(weight_filler=_gauss(1.0), bias_term=False),
+        head=dict(weight_filler=gauss, bias_term=False))
 
 
 def lfm2_moe(vocab_size=65536, seq_len=8192, batch_size=3,
@@ -568,9 +597,8 @@ def lfm2_moe(vocab_size=65536, seq_len=8192, batch_size=3,
 
     Layers are named block{i}/ln1 | mixer | res1 | ln2 | ff... | res2 (a
     dense block's feed-forward ff_gate, ff_up, ff_sig, ff_act, ff_down; a
-    MoE block's moe), so the remat groups are the blocks and the conv
-    blocks that follow one another with a MoE scan as one run."""
-    e = hidden_size
+    MoE block's moe; `_lm_stack`), so the remat groups are the blocks and
+    the conv blocks that follow one another with a MoE scan as one run."""
     if layer_types is None:
         layer_types = ["full_attention" if i in (2, 6, 10, 14, 18, 21)
                        else "conv" for i in range(24)]
@@ -579,74 +607,49 @@ def lfm2_moe(vocab_size=65536, seq_len=8192, batch_size=3,
             set(layer_types) - {"conv", "full_attention"}:
         raise ValueError(f"lfm2_moe: layer_types {layer_types} for "
                          f"{num_hidden_layers} layers")
-    gauss = dict(type="gaussian", std=0.02)
-    keep, nodecay = dict(lr_mult=1, decay_mult=1), dict(lr_mult=1,
-                                                        decay_mult=0)
+    gauss = _gauss()
     table = dict(name="tok_embed_table", lr_mult=1, decay_mult=1)
-    layers = [
-        RDDLayer("data", [batch_size, seq_len]),
-        RDDLayer("label", [batch_size, seq_len]),
-        EmbedLayer("tok_embed", ["data"], vocab_size, e, weight_filler=gauss,
-                   bias_term=False, param=[table]),
-    ]
 
-    def fc(p, name, bottom, width):
-        return InnerProductLayer(
-            f"{p}/{name}", [f"{p}/{bottom}"], width, weight_filler=gauss,
-            axis=2, bias_term=False, param=[keep])
-    x = "tok_embed"
-    for i, kind in enumerate(layer_types):
-        p = f"block{i}"
-        if kind == "conv":
-            mixer = ShortConvLayer(f"{p}/mixer", [f"{p}/ln1"],
-                                   kernel=conv_L_cache, weight_filler=gauss,
-                                   param=[keep] * 3)
-        else:
-            mixer = AttentionLayer(
-                f"{p}/mixer", [f"{p}/ln1"], num_attention_heads,
-                head_dim=head_dim, causal=True, flash=flash,
-                num_kv_heads=num_key_value_heads, qk_norm=True,
-                qk_norm_zero_centered=False, rotary_dim=head_dim,
-                rope_theta=rope_theta, norm_eps=norm_eps,
-                weight_filler=gauss, param=[keep] * 4 + [nodecay] * 2)
-        layers += [
-            RMSNormLayer(f"{p}/ln1", [x], eps=norm_eps, zero_centered=False,
-                         param=[nodecay]),
-            mixer,
-            EltwiseLayer(f"{p}/res1", [x, f"{p}/mixer"]),
-            RMSNormLayer(f"{p}/ln2", [f"{p}/res1"], eps=norm_eps,
-                         zero_centered=False, param=[nodecay]),
-        ]
-        if i < num_dense_layers:
-            layers += [
-                fc(p, "ff_gate", "ln2", intermediate_size),
-                fc(p, "ff_up", "ln2", intermediate_size),
-                SigmoidLayer(f"{p}/ff_sig", [f"{p}/ff_gate"]),
-                EltwiseLayer(f"{p}/ff_act", [f"{p}/ff_gate", f"{p}/ff_sig",
-                                             f"{p}/ff_up"], operation="PROD"),
-                fc(p, "ff_down", "ff_act", e),
-            ]
-            ff = f"{p}/ff_down"
-        else:
-            layers.append(MoELayer(
-                f"{p}/moe", [f"{p}/ln2"], num_experts,
-                hidden_dim=moe_intermediate_size, top_k=num_experts_per_tok,
-                experts_held=experts_held, first_expert=first_expert,
-                norm_topk_prob=norm_topk_prob, score_function="sigmoid",
-                selection_bias=bool(use_expert_bias), topk_eps=1e-6,
-                routed_scaling_factor=routed_scaling_factor,
-                weight_filler=gauss, stats=moe_stats))
-            ff = f"{p}/moe"
-        layers.append(EltwiseLayer(f"{p}/res2", [f"{p}/res1", ff]))
-        x = f"{p}/res2"
-    layers += [
-        RMSNormLayer("ln_f", [x], eps=norm_eps, zero_centered=False,
-                     param=[nodecay]),
-        InnerProductLayer("lm_head", ["ln_f"], vocab_size, axis=2,
-                          bias_term=False, param=[table]),
-        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
-    ]
-    return NetParam("LFM2MoE", *layers)
+    def conv(p, h):
+        return [ShortConvLayer(p + "mixer", [h], kernel=conv_L_cache,
+                               weight_filler=gauss, param=[_KEEP] * 3)]
+
+    def attention(p, h):
+        return [AttentionLayer(
+            p + "mixer", [h], num_attention_heads, head_dim=head_dim,
+            causal=True, flash=flash, num_kv_heads=num_key_value_heads,
+            qk_norm=True, qk_norm_zero_centered=False, rotary_dim=head_dim,
+            rope_theta=rope_theta, norm_eps=norm_eps, weight_filler=gauss,
+            param=[_KEEP] * 4 + [_NODECAY] * 2)]
+
+    def dense(p, h):
+        def fc(name, bottom, width):
+            return InnerProductLayer(p + name, [bottom], width,
+                                     weight_filler=gauss, axis=2,
+                                     bias_term=False, param=[_KEEP])
+        return [fc("ff_gate", h, intermediate_size),
+                fc("ff_up", h, intermediate_size),
+                SigmoidLayer(p + "ff_sig", [p + "ff_gate"]),
+                EltwiseLayer(p + "ff_act", [p + "ff_gate", p + "ff_sig",
+                                            p + "ff_up"], operation="PROD"),
+                fc("ff_down", p + "ff_act", hidden_size)]
+
+    def moe(p, h):
+        return [MoELayer(
+            p + "moe", [h], num_experts, hidden_dim=moe_intermediate_size,
+            top_k=num_experts_per_tok, experts_held=experts_held,
+            first_expert=first_expert, norm_topk_prob=norm_topk_prob,
+            score_function="sigmoid", selection_bias=bool(use_expert_bias),
+            topk_eps=1e-6, routed_scaling_factor=routed_scaling_factor,
+            weight_filler=gauss, stats=moe_stats)]
+    return _lm_stack(
+        "LFM2MoE", batch_size, seq_len, vocab_size, hidden_size,
+        [(i, [("ln1", conv if kind == "conv" else attention, "res1"),
+              ("ln2", dense if i < num_dense_layers else moe, "res2")])
+         for i, kind in enumerate(layer_types)],
+        _rms_norm(norm_eps, zero_centered=False),
+        embed=dict(weight_filler=gauss, bias_term=False, param=[table]),
+        head=dict(bias_term=False, param=[table]))
 
 
 def keye_vl2(vocab_size=151936, seq_len=32768, batch_size=1,
@@ -697,56 +700,35 @@ def keye_vl2(vocab_size=151936, seq_len=32768, batch_size=1,
     `num_hidden_layers` this pipeline stage's layers.
 
     Layers are named block{i}/ln1 | attn | res1 | ln2 | moe | res2 (the
-    attention's second top block{i}/attn_kl): all blocks are alike and scan
-    as one run, their L_I riding out of the scan."""
-    e = hidden_size
-    gauss = dict(type="gaussian", std=0.02)
-    keep, nodecay = dict(lr_mult=1, decay_mult=1), dict(lr_mult=1,
-                                                        decay_mult=0)
-    layers = [
-        RDDLayer("data", [batch_size, seq_len]),
-        RDDLayer("label", [batch_size, seq_len]),
-        EmbedLayer("tok_embed", ["data"], vocab_size, e,
-                   weight_filler=dict(type="gaussian", std=1.0),
-                   bias_term=False),
-    ]
-    x = "tok_embed"
-    for i in range(num_hidden_layers):
-        p = f"block{i}"
-        layers += [
-            RMSNormLayer(f"{p}/ln1", [x], eps=rms_norm_eps,
-                         zero_centered=False, param=[nodecay]),
-            AttentionLayer(
-                f"{p}/attn", [f"{p}/ln1"], num_attention_heads,
-                head_dim=head_dim, causal=True, flash=flash,
-                num_kv_heads=num_key_value_heads, qk_norm=True,
-                qk_norm_zero_centered=False, rotary_dim=head_dim,
-                rope_theta=rope_theta, norm_eps=rms_norm_eps,
-                weight_filler=gauss, index_heads=indexer_num_heads,
-                index_head_dim=indexer_head_dim, index_topk=indexer_topk,
-                index_stats=index_stats,
-                param=[keep] * 4 + [nodecay] * 2 + [keep] * 3
-                + [nodecay] * 2),
-            EltwiseLayer(f"{p}/res1", [x, f"{p}/attn"]),
-            RMSNormLayer(f"{p}/ln2", [f"{p}/res1"], eps=rms_norm_eps,
-                         zero_centered=False, param=[nodecay]),
-            MoELayer(f"{p}/moe", [f"{p}/ln2"], num_experts,
-                     hidden_dim=moe_intermediate_size,
-                     top_k=num_experts_per_tok, experts_held=experts_held,
-                     first_expert=first_expert,
-                     norm_topk_prob=norm_topk_prob, weight_filler=gauss,
-                     stats=moe_stats),
-            EltwiseLayer(f"{p}/res2", [f"{p}/res1", f"{p}/moe"]),
-        ]
-        x = f"{p}/res2"
-    layers += [
-        RMSNormLayer("ln_f", [x], eps=rms_norm_eps, zero_centered=False,
-                     param=[nodecay]),
-        InnerProductLayer("lm_head", ["ln_f"], vocab_size,
-                          weight_filler=gauss, axis=2, bias_term=False),
-        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
-    ]
-    return NetParam("KeyeVL2", *layers)
+    attention's second top block{i}/attn_kl; `_lm_stack`): all blocks are
+    alike and scan as one run, their L_I riding out of the scan."""
+    gauss = _gauss()
+
+    def attention(p, h):
+        return [AttentionLayer(
+            p + "attn", [h], num_attention_heads, head_dim=head_dim,
+            causal=True, flash=flash, num_kv_heads=num_key_value_heads,
+            qk_norm=True, qk_norm_zero_centered=False, rotary_dim=head_dim,
+            rope_theta=rope_theta, norm_eps=rms_norm_eps,
+            weight_filler=gauss, index_heads=indexer_num_heads,
+            index_head_dim=indexer_head_dim, index_topk=indexer_topk,
+            index_stats=index_stats,
+            param=[_KEEP] * 4 + [_NODECAY] * 2 + [_KEEP] * 3
+            + [_NODECAY] * 2)]
+
+    def moe(p, h):
+        return [MoELayer(
+            p + "moe", [h], num_experts, hidden_dim=moe_intermediate_size,
+            top_k=num_experts_per_tok, experts_held=experts_held,
+            first_expert=first_expert, norm_topk_prob=norm_topk_prob,
+            weight_filler=gauss, stats=moe_stats)]
+    block = [("ln1", attention, "res1"), ("ln2", moe, "res2")]
+    return _lm_stack(
+        "KeyeVL2", batch_size, seq_len, vocab_size, hidden_size,
+        [(i, block) for i in range(num_hidden_layers)],
+        _rms_norm(rms_norm_eps, zero_centered=False),
+        embed=dict(weight_filler=_gauss(1.0), bias_term=False),
+        head=dict(weight_filler=gauss, bias_term=False))
 
 
 #: `hybrid_override_pattern` of Nemotron-Labs-TwoTower-30B-A3B-Base-BF16:
@@ -822,8 +804,9 @@ def nemotron_h(vocab_size=131072, seq_len=8192, batch_size=2,
     One chip's share of an expert-parallel group as in `qwen3_next`:
     `experts_held` from `first_expert` on, `vocab_size` the held rows.
 
-    Layers are named block{i}/ln | mixer | res: the remat groups are the
-    blocks; neighbours are unlike, so no run of blocks scans."""
+    Layers are named block{i}/ln | mixer | res (`_lm_stack`): the remat
+    groups are the blocks; neighbours are unlike, so no run of blocks
+    scans."""
     first, end = (0, len(pattern)) if layers is None else layers
     unknown = sorted(set(pattern) - set("ME*"))
     if unknown:
@@ -832,58 +815,42 @@ def nemotron_h(vocab_size=131072, seq_len=8192, batch_size=2,
     if not 0 <= first < end <= len(pattern):
         raise ValueError(f"nemotron_h: layers {(first, end)} lie outside "
                          f"the pattern's {len(pattern)} blocks")
-    e = hidden_size
-    gauss = dict(type="gaussian", std=0.02)
-    small = dict(type="gaussian", std=0.02 / len(pattern) ** 0.5)
-    keep, nodecay = dict(lr_mult=1, decay_mult=1), dict(lr_mult=1,
-                                                        decay_mult=0)
-    net = [
-        RDDLayer("data", [batch_size, seq_len]),
-        RDDLayer("label", [batch_size, seq_len]),
-        EmbedLayer("tok_embed", ["data"], vocab_size, e,
-                   weight_filler=dict(type="gaussian", std=1.0),
-                   bias_term=False),
-    ]
-    x = "tok_embed"
-    for i in range(first, end):
-        p, h = f"block{i}", [f"block{i}/ln"]
-        if pattern[i] == "M":
-            mixer = Mamba2Layer(
-                f"{p}/mixer", h, mamba_num_heads, mamba_head_dim,
-                ssm_state_size, n_groups, conv_kernel=conv_kernel,
-                chunk=chunk_size, norm_eps=layer_norm_epsilon,
-                weight_filler=gauss, out_filler=small,
-                dt_min=time_step_min, dt_max=time_step_max, stats=ssm_stats,
-                param=[keep, keep] + [nodecay] * 5 + [keep])
-        elif pattern[i] == "*":
-            mixer = AttentionLayer(
-                f"{p}/mixer", h, num_attention_heads, head_dim=head_dim,
-                causal=True, flash=flash, num_kv_heads=num_key_value_heads,
-                weight_filler=gauss, out_filler=small, param=[keep] * 4)
-        else:
-            mixer = MoELayer(
-                f"{p}/mixer", h, n_routed_experts,
-                hidden_dim=moe_intermediate_size, top_k=num_experts_per_tok,
-                experts_held=experts_held, first_expert=first_expert,
-                shared_hidden_dim=moe_shared_expert_intermediate_size,
-                norm_topk_prob=norm_topk_prob, score_function="sigmoid",
-                selection_bias=True, topk_eps=1e-20,
-                routed_scaling_factor=routed_scaling_factor,
-                expert_activation="relu2", expert_gate_matrix=False,
-                shared_gate=False, weight_filler=gauss, down_filler=small,
-                stats=moe_stats)
-        net += [RMSNormLayer(h[0], [x], eps=layer_norm_epsilon,
-                             zero_centered=False, param=[nodecay]),
-                mixer, EltwiseLayer(f"{p}/res", [x, f"{p}/mixer"])]
-        x = f"{p}/res"
-    net += [
-        RMSNormLayer("ln_f", [x], eps=layer_norm_epsilon,
-                     zero_centered=False, param=[nodecay]),
-        InnerProductLayer("lm_head", ["ln_f"], vocab_size,
-                          weight_filler=gauss, axis=2, bias_term=False),
-        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
-    ]
-    return NetParam("NemotronH", *net)
+    gauss, small = _gauss(), _gauss(0.02 / len(pattern) ** 0.5)
+
+    def mamba(p, h):
+        return [Mamba2Layer(
+            p + "mixer", [h], mamba_num_heads, mamba_head_dim,
+            ssm_state_size, n_groups, conv_kernel=conv_kernel,
+            chunk=chunk_size, norm_eps=layer_norm_epsilon,
+            weight_filler=gauss, out_filler=small, dt_min=time_step_min,
+            dt_max=time_step_max, stats=ssm_stats,
+            param=[_KEEP, _KEEP] + [_NODECAY] * 5 + [_KEEP])]
+
+    def attention(p, h):
+        return [AttentionLayer(
+            p + "mixer", [h], num_attention_heads, head_dim=head_dim,
+            causal=True, flash=flash, num_kv_heads=num_key_value_heads,
+            weight_filler=gauss, out_filler=small, param=[_KEEP] * 4)]
+
+    def moe(p, h):
+        return [MoELayer(
+            p + "mixer", [h], n_routed_experts,
+            hidden_dim=moe_intermediate_size, top_k=num_experts_per_tok,
+            experts_held=experts_held, first_expert=first_expert,
+            shared_hidden_dim=moe_shared_expert_intermediate_size,
+            norm_topk_prob=norm_topk_prob, score_function="sigmoid",
+            selection_bias=True, topk_eps=1e-20,
+            routed_scaling_factor=routed_scaling_factor,
+            expert_activation="relu2", expert_gate_matrix=False,
+            shared_gate=False, weight_filler=gauss, down_filler=small,
+            stats=moe_stats)]
+    mixer = {"M": mamba, "*": attention, "E": moe}
+    return _lm_stack(
+        "NemotronH", batch_size, seq_len, vocab_size, hidden_size,
+        [(i, [("ln", mixer[pattern[i]], "res")]) for i in range(first, end)],
+        _rms_norm(layer_norm_epsilon, zero_centered=False),
+        embed=dict(weight_filler=_gauss(1.0), bias_term=False),
+        head=dict(weight_filler=gauss, bias_term=False))
 
 
 def transformer_lm_pieces(vocab_size=512, seq_len=256, batch_size=8,
@@ -898,42 +865,23 @@ def transformer_lm_pieces(vocab_size=512, seq_len=256, batch_size=8,
     (suffix) stay outside the pipeline, replicated — the stage-
     heterogeneous ends the pipeline docstring plans for.
 
-    Layer names match transformer_lm's per-block names (ln1/attn/ffn1/
-    ffn2...) so params map 1:1 onto "block{i}/<name>" for equivalence
+    The three are `_lm_stack`'s parts from transformer_lm's own block
+    description, the block without its "block{i}/" prefix (ln1/attn/ffn1/
+    ffn2...), so params map 1:1 onto "block{i}/<name>" for equivalence
     tests and checkpoint conversion.
     """
-    d_ff = d_ff or 4 * d_model
-    max_positions = max_positions or seq_len
-    xavier = dict(type="xavier")
+    def stream():
+        return RDDLayer("x", [batch_size, seq_len, d_model])
     prefix = NetParam(
-        "TransformerLM_prefix",
-        RDDLayer("data", [batch_size, seq_len]),
-        EmbedLayer("tok_embed", ["data"], vocab_size, d_model,
-                   weight_filler=xavier),
-        PositionalEmbedLayer("pos_embed", ["tok_embed"], max_positions,
-                             d_model, weight_filler=xavier, tops=["embed"]),
-    )
+        "TransformerLM_prefix", RDDLayer("data", [batch_size, seq_len]),
+        *_lm_embed(vocab_size, d_model, max_positions or seq_len,
+                   weight_filler=_XAVIER)[0])
     block = NetParam(
-        "TransformerLM_block",
-        RDDLayer("x", [batch_size, seq_len, d_model]),
-        LayerNormLayer("ln1", ["x"]),
-        AttentionLayer("attn", ["ln1"], num_heads, causal=True, flash=flash),
-        EltwiseLayer("res1", ["x", "attn"]),
-        LayerNormLayer("ln2", ["res1"]),
-        InnerProductLayer("ffn1", ["ln2"], d_ff, weight_filler=xavier,
-                          axis=2),
-        ReLULayer("relu", ["ffn1"], tops=["ffn1"]),
-        InnerProductLayer("ffn2", ["ffn1"], d_model, weight_filler=xavier,
-                          axis=2),
-        EltwiseLayer("res2", ["res1", "ffn2"]),
-    )
+        "TransformerLM_block", stream(),
+        *_lm_block("", "x", _transformer_block(
+            d_model, num_heads, d_ff or 4 * d_model, flash), _layer_norm)[0])
     suffix = NetParam(
-        "TransformerLM_suffix",
-        RDDLayer("x", [batch_size, seq_len, d_model]),
+        "TransformerLM_suffix", stream(),
         RDDLayer("label", [batch_size, seq_len]),
-        LayerNormLayer("ln_f", ["x"]),
-        InnerProductLayer("lm_head", ["ln_f"], vocab_size,
-                          weight_filler=xavier, axis=2),
-        SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2),
-    )
+        *_lm_head("x", vocab_size, _layer_norm, weight_filler=_XAVIER))
     return prefix, block, suffix

@@ -95,7 +95,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..graph.remat import keep
-from .pallas_lrn import _should_interpret
+from .backend import _should_interpret
 
 NEG_INF = -1e30
 LANES = 128

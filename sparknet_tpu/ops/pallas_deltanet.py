@@ -70,8 +70,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..graph.remat import keep
+from .backend import _should_interpret
 from .deltanet import L2_EPS
-from .pallas_lrn import _should_interpret
 
 _F32 = jnp.float32
 GROUP = 8           # chunks between two stored states

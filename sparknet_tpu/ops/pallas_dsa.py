@@ -68,8 +68,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..graph.remat import keep
+from .backend import _should_interpret
 from .pallas_attention import NEG_INF, _fit_block
-from .pallas_lrn import _should_interpret
 
 INT_MIN = np.int32(-2 ** 31)
 VMEM_LIMIT = 100 * 1024 * 1024

@@ -38,7 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .pallas_lrn import SPATIAL_BLOCK, _should_interpret, _window_sum, \
+from .backend import _should_interpret
+from .pallas_lrn import SPATIAL_BLOCK, _window_sum, \
     _call_bwd as _lrn_call_bwd
 
 

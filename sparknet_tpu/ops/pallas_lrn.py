@@ -32,14 +32,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import _should_interpret
+
 SPATIAL_BLOCK = 512
-
-
-def _should_interpret():
-    """Interpret mode is for the CPU tests only. Every other backend
-    compiles the kernel, and raises where it cannot: nothing on the chip
-    path quietly runs the interpreter instead."""
-    return jax.default_backend() == "cpu"
 
 
 def _window_sum(t, size, lo):

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
-from .pallas_lrn import _should_interpret
+from .backend import _should_interpret
 
 # elements of the largest block: 2 MB of bfloat16 weights a buffer for
 # `gmm`, 2 MB of float32 accumulator, output and running total for `tgmm`

@@ -70,7 +70,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..graph.remat import keep
-from .pallas_lrn import _should_interpret
+from .backend import _should_interpret
 
 _F32 = jnp.float32
 _HI = lax.Precision.HIGHEST

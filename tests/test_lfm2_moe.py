@@ -1,6 +1,7 @@
 """LFM2-MoE's layers and the whole 5-block model against the plain
-reference (`benchmark/reference/lfm2_moe.py`, imported from where it lies,
-not copied): small widths, seeded weights, float32 on the CPU.
+reference (`benchmark/reference/lfm2_moe.py`): small widths, seeded
+weights, float32 on the CPU. The family's record and the bodies of the
+tests every family has are in `tests/lm_family.py`.
 
 The reference computes the short convolution by its padded sum, attention
 as a masked softmax a block of rows at a time, the dense feed-forward by
@@ -10,68 +11,21 @@ InnerProducts with a Sigmoid and a product, and ragged groups a window of
 rows at a time. The head reads the embedding's own table.
 """
 
-import importlib
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import sparknet_tpu.ops  # noqa: F401  (registers the layers)
-from sparknet_tpu.graph.registry import get as get_layer
 from sparknet_tpu.models import dsl, zoo
 from sparknet_tpu.obs.trace import default_tracer
-from sparknet_tpu.proto import Message, text_format
 from sparknet_tpu.solver.solver import Solver
+from tests import lm_family as lm
+from tests.lm_family import (close, fill, layer, ref,  # noqa: F401  (fixture)
+                             same_value_and_grads)
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-
-
-@pytest.fixture(scope="module")
-def ref():
-    if BENCH not in sys.path:
-        sys.path.insert(0, BENCH)
-    return importlib.import_module("reference.lfm2_moe")
-
-
-STAGE = ["conv", "full_attention", "conv", "conv", "conv"]
-TOY = dict(hidden_size=32, intermediate_size=48, moe_intermediate_size=16,
-           num_attention_heads=4, num_key_value_heads=2, head_dim=16,
-           rope_theta=1e6, norm_eps=1e-5, conv_L_cache=3, num_experts=4,
-           num_experts_per_tok=2, norm_topk_prob=True,
-           routed_scaling_factor=1.0, use_expert_bias=True, vocab_size=64,
-           router_outputs=16, first_expert=0, seq_len=64,
-           layer_types=STAGE, num_dense_layers=1, num_hidden_layers=5)
-
-
-def close(a, b, tol=2e-4):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    scale = max(np.abs(b).max(), 1e-12)
-    assert np.abs(a - b).max() <= tol * scale, \
-        (np.abs(a - b).max(), scale)
-
-
-def layer(lp, shapes):
-    return get_layer(lp.type)(lp, shapes, 0)
-
-
-def fill(impl, key, std=0.3):
-    return [std * jax.random.normal(jax.random.fold_in(key, i), shape,
-                                    jnp.float32)
-            for i, (shape, *_) in enumerate(impl.param_shapes())]
-
-
-def same_value_and_grads(mine, theirs, args, cot, tol=5e-4):
-    close(mine(*args), theirs(*args))
-    which = tuple(range(len(args)))
-    gm = jax.grad(lambda *a: jnp.sum(mine(*a) * cot), which)(*args)
-    gt = jax.grad(lambda *a: jnp.sum(theirs(*a) * cot), which)(*args)
-    for a, b in zip(jax.tree_util.tree_leaves(gm),
-                    jax.tree_util.tree_leaves(gt)):
-        close(a, b, tol=tol)
+FAMILY = lm.LFM2_MOE
+TOY = FAMILY.toy
+STAGE = TOY["layer_types"]
 
 
 # ------------------------------------------------------ the short convolution
@@ -264,19 +218,12 @@ def test_route_picks_by_the_biased_score_and_weighs_by_the_unbiased(ref):
 @pytest.mark.parametrize("held,first", [(4, 0), (4, 8), (16, 0)])
 def test_moe_held_share_matches_reference_with_a_bias(ref, held, first):
     impl = moe_layer(held, first)
-    blobs = fill(impl, jax.random.PRNGKey(11))
-    g = jax.random.normal(jax.random.PRNGKey(12), (2, 24, 32))
-    cot = jax.random.normal(jax.random.PRNGKey(13), (2, 24, 32))
-    d = dict(TOY, num_experts=held, first_expert=first)
-
-    def mine(g, blobs):
-        return impl.apply(blobs, [g], True, None)[0]
-
-    def theirs(g, blobs):
-        return ref.moe(g.reshape(48, 32), blobs, d).reshape(2, 24, 32)
-    same_value_and_grads(mine, theirs, (g, blobs), cot)
+    (g,), blobs, cot = lm.held_share(
+        FAMILY, impl, [(2, 24, 32)],
+        dict(TOY, num_experts=held, first_expert=first), 11)
     # no gradient trains the bias
-    grad = jax.grad(lambda p: jnp.sum(mine(g, p) * cot))(blobs)
+    grad = jax.grad(lambda p: jnp.sum(
+        impl.apply(p, [g], True, None)[0] * cot))(blobs)
     assert not np.asarray(grad[4]).any()
 
 
@@ -295,21 +242,8 @@ def test_moe_shares_add_up_to_the_uncut_layer(ref):
     blobs = fill(whole, jax.random.PRNGKey(14))
     g = jax.random.normal(jax.random.PRNGKey(15), (2, 24, 32))
     cot = jax.random.normal(jax.random.PRNGKey(16), (2, 24, 32))
-
-    def run(impl, blobs):
-        out, vjp = jax.vjp(lambda g: impl.apply(blobs, [g], True, None)[0],
-                           g)
-        return (out,) + vjp(cot)
-
-    total = None
-    for chip in range(4):
-        lo = 8 * chip
-        part = run(build(8, lo), [blobs[0]] + [w[lo:lo + 8]
-                                               for w in blobs[1:4]]
-                   + [blobs[4]])
-        total = part if total is None else tuple(
-            a + b for a, b in zip(total, part))
-    for a, b in zip(total, run(whole, blobs)):
+    total = lm.sum_of_shares(build, 4, 8, blobs, [g], cot)
+    for a, b in zip(total, lm.out_and_input_grads(whole, blobs, [g], cot)):
         close(a, b, tol=5e-4)
     d = dict(TOY, num_experts=32, router_outputs=32, num_experts_per_tok=4,
              first_expert=0)
@@ -319,72 +253,27 @@ def test_moe_shares_add_up_to_the_uncut_layer(ref):
 
 # ---------------------------------------------------------- the whole model
 
-def toy_net(**over):
-    d = dict(TOY, **over)
-    held = d.pop("num_experts")
-    return zoo.lfm2_moe(batch_size=2, num_experts=d.pop("router_outputs"),
-                        experts_held=held, **d)
-
-
-SOLVER = dict(type="Adam", base_lr=1e-3, lr_policy="fixed", momentum=0.9,
-              momentum2=0.95, delta=1e-8, weight_decay=0.1)
-
-
-def toy_config():
-    """A configuration file's keys at the toy's sizes: the published list
-    of 7 layer types and 2 leading dense layers, of which layers 0 and 2-5
-    are held."""
-    config = {k: v for k, v in TOY.items()
-              if k not in ("router_outputs", "first_expert", "seq_len",
-                           "layer_types", "num_dense_layers",
-                           "num_hidden_layers")}
-    config.update(
-        layer_types=["conv", "conv", "full_attention", "conv", "conv",
-                     "conv", "full_attention"],
-        num_dense_layers=2, layers_held=[0, 2, 3, 4, 5],
-        published={"num_experts": 16}, builder_args={"seq_len": 64})
-    return config
-
-
-def tokens(seed=0):
-    draw = np.random.RandomState(seed).randint(0, 64, (2, 65))
-    return draw[:, :-1].astype(np.int32), draw[:, 1:].astype(np.int32)
-
-
-def seeded(solver, reference, seed=0, bias=0.0):
-    """The reference's fillers into the program's solver; `bias` makes the
-    expert biases a seeded draw of that size."""
-    sys.path.insert(0, BENCH)
-    import weights
-    w0 = weights.make_weights(reference.specs, seed)
-    assert set(w0) == set(solver.params)
-    for i, name in enumerate(sorted(w0)):
-        if bias and name.endswith("/moe"):
-            w0[name][4] = bias * jax.random.normal(
-                jax.random.PRNGKey(100 + i), w0[name][4].shape)
-    for name, blobs in w0.items():
-        assert [b.shape for b in blobs] == \
-            [p.shape for p in solver.params[name]], name
-        solver.params[name] = [jnp.array(b) for b in blobs]
-    return w0
-
-
-def grads_of(solver, batch):
-    net = solver.net
-    return jax.grad(lambda p: net.loss_fn(p, solver.state, batch)[0])(
-        solver.params)
+def biased(bias):
+    """An edit of seeded weights: the expert biases a seeded draw of that
+    size (0: as filled, zeros)."""
+    def edit(w0):
+        for i, name in enumerate(sorted(w0)):
+            if bias and name.endswith("/moe"):
+                w0[name][4] = bias * jax.random.normal(
+                    jax.random.PRNGKey(100 + i), w0[name][4].shape)
+    return edit
 
 
 def test_the_reference_reads_its_stage_from_the_published_list(ref):
-    d = ref.dims(toy_config())
+    d = ref.dims(FAMILY.config())
     assert d["layer_types"] == STAGE and d["num_dense_layers"] == 1
     assert d["num_hidden_layers"] == 5 and d["router_outputs"] == 16
     assert {k: d[k] for k in TOY} == TOY
 
 
 def test_net_is_the_published_layout():
-    net = zoo.lfm2_moe(batch_size=1, seq_len=128, experts_held=8)
-    by_name = {lp.name: lp for lp in net.layer}
+    by_name = lm.layout(zoo.lfm2_moe(batch_size=1, seq_len=128,
+                                     experts_held=8))
     kinds = [by_name[f"block{i}/mixer"].type for i in range(24)]
     assert [i for i, k in enumerate(kinds) if k == "Attention"] == \
         [2, 6, 10, 14, 18, 21]
@@ -407,33 +296,28 @@ def test_net_is_the_published_layout():
     # the head reads the embedding's table
     assert by_name["lm_head"].param[0].name == \
         by_name["tok_embed"].param[0].name != ""
-    # the prototxt round trip keeps the new fields
-    again = text_format.loads(text_format.dumps(net), "NetParameter")
-    assert again == net
 
 
 def test_the_head_is_tied_to_the_embedding(ref):
     """One blob, owned by the embedding; its gradient is the sum of what
     the embedding's lookup and the head's product each give."""
-    reference = ref.build(toy_config(), 2)
-    sp = Message("SolverParameter", display=0, random_seed=0, **SOLVER)
-    solver = Solver(sp, net_param=toy_net(), log_fn=None)
+    reference = ref.build(FAMILY.config(), 2)
+    solver = FAMILY.solver()
     assert "lm_head" not in solver.params
     assert "lm_head" not in dict(reference.specs)
     assert solver.net.param_refs["lm_head"] == [("tok_embed", 0)]
-    w0 = seeded(solver, reference)
-    data, labels = tokens(4)
-    batch = {"data": jnp.asarray(data), "label": jnp.asarray(labels)}
-    got = grads_of(solver, batch)["tok_embed"][0]
+    w0 = lm.seeded(solver, reference)
+    batch = lm.batch_of(4)
+    got = lm.grads_of(solver, batch)["tok_embed"][0]
     want = jax.grad(lambda p: ref.forward_loss(
         p, batch["data"], batch["label"], reference.d) / 128)(w0)
     close(got, want["tok_embed"][0], tol=2e-3)
     # untied, the same table in two blobs: the two gradients add up to it
-    untied = toy_net()
+    untied = FAMILY.net()
     for lp in untied.layer:
         if lp.name in ("tok_embed", "lm_head"):
             lp.param[0].name = ""
-    other = Solver(sp, net_param=untied, log_fn=None)
+    other = Solver(solver.param, net_param=untied, log_fn=None)
     seeded_params = dict(solver.params,
                          lm_head=[solver.params["tok_embed"][0]])
     parts = jax.grad(lambda p: other.net.loss_fn(p, other.state, batch)[0])(
@@ -444,35 +328,7 @@ def test_the_head_is_tied_to_the_embedding(ref):
 
 @pytest.mark.parametrize("bias", [0.0, 0.3])
 def test_whole_model_three_adam_steps_match_reference(ref, bias):
-    reference = ref.build(toy_config(), 2)
-    sp = Message("SolverParameter", display=0, random_seed=0, **SOLVER)
-    solver = Solver(sp, net_param=toy_net(), log_fn=None)
-    # the program's multipliers are the reference's, blob for blob
-    for name, blobs in reference.specs:
-        assert solver.updater.mults[name] == [b[2] for b in blobs], name
-    w0 = seeded(solver, reference, bias=bias)
-    step = reference.make_step(SOLVER, block_rows=1)
-    data, labels = tokens()
-    params, history = w0, None
-    for i in range(3):
-        got = float(solver.train_step({"data": data, "label": labels}))
-        params, history, want, grads = step(params, history, data, labels,
-                                            None)
-        assert abs(got - float(want)) <= 2e-5 * abs(float(want)), i
-        if i == 0:
-            # the first gradient, out of Adam's first moment
-            for name, blobs in grads.items():
-                for j, g in enumerate(blobs):
-                    decay = dict(reference.specs)[name][j][2][1]
-                    m1 = solver.history[name][j][0]
-                    close(m1 / 0.1 - 0.1 * decay * w0[name][j], g,
-                          tol=2e-3)
-    for name, blobs in params.items():
-        for j, w in enumerate(blobs):
-            got = np.asarray(solver.params[name][j] - w0[name][j])
-            want = np.asarray(w - w0[name][j])
-            assert np.linalg.norm(got - want) <= \
-                0.05 * np.linalg.norm(want) + 1e-12, (name, j)
+    solver, w0 = lm.three_adam_steps(FAMILY, edit=biased(bias))
     # three steps of Adam with decay leave the bias where it was
     for name in w0:
         if name.endswith("/moe"):
@@ -483,13 +339,10 @@ def test_whole_model_three_adam_steps_match_reference(ref, bias):
 def test_dense_and_moe_feed_forward_in_one_net(ref):
     """The dense block's feed-forward (three InnerProducts, a Sigmoid and
     a product) is the reference's SwiGLU, beside the MoE blocks."""
-    reference = ref.build(toy_config(), 2)
-    sp = Message("SolverParameter", display=0, random_seed=0, **SOLVER)
-    solver = Solver(sp, net_param=toy_net(), log_fn=None)
-    w0 = seeded(solver, reference, seed=2)
-    data, labels = tokens(5)
-    batch = {"data": jnp.asarray(data), "label": jnp.asarray(labels)}
-    blobs, _ = solver.net.apply(solver.params, solver.state, batch,
+    reference = ref.build(FAMILY.config(), 2)
+    solver = FAMILY.solver()
+    w0 = lm.seeded(solver, reference, seed=2)
+    blobs, _ = solver.net.apply(solver.params, solver.state, lm.batch_of(5),
                                 train=True)
     g = blobs["block0/ln2"]
     want = jnp.stack([ref.dense_ff(g[b], [w0[f"block0/{n}"][0] for n in (
@@ -503,59 +356,29 @@ def test_dense_and_moe_feed_forward_in_one_net(ref):
 @pytest.mark.parametrize("remat,scan", [("full", "off"), ("none", "on"),
                                         ("full", "on")])
 def test_remat_and_scan_leave_the_gradients_alone(ref, remat, scan):
-    sp = Message("SolverParameter", display=0, random_seed=0, **SOLVER)
-    data, labels = tokens(1)
-    batch = {"data": jnp.asarray(data), "label": jnp.asarray(labels)}
-    plain = Solver(sp, net_param=toy_net(), log_fn=None)
-    plain.set_scan("off")
-    knobbed = Solver(sp, net_param=toy_net(), log_fn=None, remat=remat)
-    knobbed.set_scan(scan)
-    # the dense block and the attention block are bodies of their own; the
-    # three conv blocks with a MoE are one run
-    runs = knobbed.net._scan_runs()
-    assert [(r["n"], r["glen"], r["entry"]) for r in runs] == \
-        [(3, 6, "block1/res2")]
-    seeded(plain, ref.build(toy_config(), 2), bias=0.3)
-    seeded(knobbed, ref.build(toy_config(), 2), bias=0.3)
-    want, got = grads_of(plain, batch), grads_of(knobbed, batch)
-    for name in want:
-        for a, b in zip(got[name], want[name]):
-            close(a, b, tol=1e-4)
+    lm.remat_and_scan(FAMILY, remat, scan, edit=biased(0.3))
 
 
 def test_two_periods_scan_as_conv_runs_between_attention_blocks():
-    sp = Message("SolverParameter", display=0, random_seed=0, **SOLVER)
-    solver = Solver(sp, net_param=toy_net(
+    solver = FAMILY.solver(dict(
         num_hidden_layers=10, num_dense_layers=2,
         layer_types=["conv", "conv"] + ["full_attention", "conv", "conv",
-                                        "conv"] * 2), log_fn=None)
+                                        "conv"] * 2))
     # the two leading dense conv blocks are alike too
     assert [(r["n"], r["entry"]) for r in solver.net._scan_runs()] == \
         [(2, "tok_embed"), (3, "block2/res2"), (3, "block6/res2")]
 
 
 def test_paths_and_load_are_recorded():
-    from sparknet_tpu.obs.trace import Tracer
-    tracer = Tracer()
-    sp = Message("SolverParameter", display=1, random_seed=0, **SOLVER)
-    solver = Solver(sp, net_param=toy_net(moe_stats=True), log_fn=None,
-                    tracer=tracer)
-    ring = default_tracer()
-    mark = ring.mark()
-    data, labels = tokens(2)
-    solver.step(2, iter([{"data": data, "label": labels}] * 2))
-    loads = tracer.spans("moe.load")
-    assert {r["layer"] for r in loads} == {f"block{i}/moe"
-                                           for i in range(1, 5)}
-    for r in loads:
-        assert 0.0 < r["held_share"] < 1.0 and r["windows"] >= 1.0
-    moe = ring.since(mark, "moe.path")
+    tracer, since = lm.traced_steps(FAMILY, 2, dict(moe_stats=True))
+    lm.held_loads(tracer, [f"block{i}/moe" for i in range(1, 5)])
+    moe = since("moe.path")
     assert moe and all(r["score"] == "sigmoid" and r["selection_bias"]
                        and r["activation"] == "silu" for r in moe)
-    attn = ring.since(mark, "attn.path")
+    attn = since("attn.path")
     assert attn and all(r["layer"] == "block1/mixer"
                         and r["head_dim"] == 16 for r in attn)
-    conv = ring.since(mark, "shortconv.path")
+    conv = since("shortconv.path")
     assert {r["layer"] for r in conv} == {f"block{i}/mixer"
                                           for i in (0, 2, 3, 4)}
     assert all(r["kernel"] == 3 and r["channels"] == 32 for r in conv)

@@ -13,7 +13,9 @@ import jax.numpy as jnp
 
 from sparknet_tpu import proto
 from sparknet_tpu.graph import CompiledNet, TRAIN, TEST
-from sparknet_tpu.models import dsl, lenet, cifar10_full, caffenet, googlenet
+from sparknet_tpu.models import (dsl, zoo, lenet, cifar10_full, caffenet,
+                                 googlenet)
+from tests.lm_family import stack_contract
 
 REF = "/root/reference/caffe"
 
@@ -117,3 +119,28 @@ class TestZooParity:
                                        rng=jax.random.PRNGKey(1))
         assert np.isfinite(float(loss))
         assert blobs["pool5/7x7_s1"].shape == (2, 1024, 1, 1)
+
+
+@pytest.mark.parametrize("builder,args", [
+    ("transformer_lm", dict(moe_experts=4)),
+    ("transformer_lm", dict(with_data=False)),
+    ("qwen3_next", {}), ("smallthinker", {}), ("lfm2_moe", {}),
+    ("keye_vl2", dict(index_stats=True, moe_stats=True)),
+    ("nemotron_h", dict(layers=(35, 44), ssm_stats=True))])
+def test_an_lm_builder_keeps_the_stacks_naming_contract(builder, args):
+    """`zoo._lm_stack` states it and `tests/lm_family.py:stack_contract`
+    reads it off the built net, at the published sizes: a block's layers
+    together under "block{i}/", one blob in from the block before, one
+    out; what the remat groups and the scan runs of graph/compiler.py
+    stand on."""
+    net = getattr(zoo, builder)(**args)
+    assert len(stack_contract(net)) == len(
+        {lp.name.split("/")[0] for lp in net.layer if "/" in lp.name})
+
+
+def test_the_pipelines_block_is_transformer_lms_without_its_prefix():
+    _, block, _ = zoo.transformer_lm_pieces()
+    whole = [lp.name for lp in zoo.transformer_lm().layer
+             if lp.name.startswith("block0/")]
+    assert [lp.name for lp in block.layer][1:] == \
+        [name[len("block0/"):] for name in whole]

@@ -1,9 +1,9 @@
 """The Nemotron-H tower — the Mamba-2 mixer's chunked scan, the two-matrix
 squared-ReLU experts with their ungated shared expert, attention at 16
 query heads a key-value head, and the whole net from a pattern string —
-against the plain reference (`benchmark/reference/nemotron_h.py`, imported
-from where it lies, not copied): small widths, seeded weights, float32 on
-the CPU.
+against the plain reference (`benchmark/reference/nemotron_h.py`): small
+widths, seeded weights, float32 on the CPU. The family's record and the
+bodies of the tests every family has are in `tests/lm_family.py`.
 
 The reference computes the state-space layer as the RECURRENCE, token by
 token; the program in chunks. So every comparison of the two is a test of
@@ -11,7 +11,6 @@ the chunked form: of its decay masks, its chunk states and its carry.
 """
 
 import importlib
-import os
 import sys
 
 import jax
@@ -19,47 +18,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import sparknet_tpu.ops  # noqa: F401  (registers the layers)
-from sparknet_tpu.graph.registry import get as get_layer
 from sparknet_tpu.models import dsl, zoo
 from sparknet_tpu.obs.trace import Tracer, default_tracer
 from sparknet_tpu.ops import mamba2 as m2
-from sparknet_tpu.proto import Message
-from sparknet_tpu.solver.solver import Solver
+from tests import lm_family as lm
+from tests.lm_family import close, layer, ref  # noqa: F401  (a fixture)
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-
-
-@pytest.fixture(scope="module")
-def ref():
-    if BENCH not in sys.path:
-        sys.path.insert(0, BENCH)
-    return importlib.import_module("reference.nemotron_h")
-
-
-TOY = dict(hidden_size=32, mamba_num_heads=4, mamba_head_dim=8,
-           ssm_state_size=16, n_groups=2, conv_kernel=4, chunk_size=16,
-           time_step_min=0.001, time_step_max=0.1, num_attention_heads=16,
-           num_key_value_heads=1, head_dim=8, n_routed_experts=4,
-           num_experts_per_tok=2, moe_intermediate_size=24,
-           moe_shared_expert_intermediate_size=40, norm_topk_prob=True,
-           routed_scaling_factor=2.5, layer_norm_epsilon=1e-5,
-           vocab_size=64, router_outputs=16, first_expert=0, seq_len=48,
-           pattern="ME*E", whole_pattern="ME*E", carry=True)
-
-
-def close(a, b, tol=2e-4):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    scale = max(np.abs(b).max(), 1e-12)
-    assert np.abs(a - b).max() <= tol * scale, \
-        (np.abs(a - b).max(), scale)
+FAMILY = lm.NEMOTRON_H
+TOY = FAMILY.toy
 
 
 def mamba_layer(seq, batch=2, chunk=16, **over):
     lp = dsl.Mamba2Layer("ssm", ["x"], 4, 8, 16, 2, conv_kernel=4,
                          chunk=chunk, norm_eps=1e-5, **over)
-    return get_layer(lp.type)(lp, [(batch, seq, 32)], 0)
+    return layer(lp, [(batch, seq, 32)])
 
 
 def mamba_blobs(impl, key):
@@ -183,36 +155,31 @@ def test_the_gated_norm_gates_first_and_norms_each_group():
 
 
 def test_the_survival_statistic_and_the_path_are_recorded():
-    tracer, ring = Tracer(), default_tracer()
-    mark = ring.mark()
-    sp = Message("SolverParameter", display=1, random_seed=0, **SOLVER)
-    solver = Solver(sp, net_param=toy_net(ssm_stats=True, moe_stats=True,
-                                          flash=True, seq_len=128),
-                    log_fn=None, tracer=tracer, remat="full")
-    draw = np.random.RandomState(2).randint(0, 64, (2, 129)).astype(np.int32)
-    solver.step(1, iter([{"data": draw[:, :-1], "label": draw[:, 1:]}]))
+    tracer, since = lm.traced_steps(
+        FAMILY, 1, dict(ssm_stats=True, moe_stats=True, flash=True,
+                        seq_len=128), remat="full")
     stats = tracer.spans("ssm.stats")
     assert {r["layer"] for r in stats} == {"block0/mixer"}
     assert all(0.0 < r["chunk_survival"] < 1.0 for r in stats)
-    paths = ring.since(mark, "ssm.path")
+    paths = since("ssm.path")
     assert paths and all(
         r["layer"] == "block0/mixer" and r["path"] == "chunked"
         and (r["heads"], r["head_dim"], r["state"], r["groups"],
              r["chunk"]) == (4, 8, 16, 2, 16) for r in paths)
-    moe = ring.since(mark, "moe.path")
+    moe = since("moe.path")
     assert {r["layer"] for r in moe} == {"block1/mixer", "block3/mixer"}
     assert all(r["matrices"] == 2 and r["activation"] == "relu2"
                and r["shared_gate"] is False and r["score"] == "sigmoid"
                and r["selection_bias"] for r in moe)
     assert {r["layer"] for r in tracer.spans("moe.load")} == \
         {"block1/mixer", "block3/mixer"}
-    attn = ring.since(mark, "attn.path")
+    attn = since("attn.path")
     assert attn and all(r["layer"] == "block2/mixer"
                         and r["path"] == "kernel" for r in attn)
     # what the backward reuses: the flash pass's output and logsumexp; at
     # the toy heads (8 wide, state 16) the scan is XLA's form and names
     # nothing (tests/test_pallas_ssd.py has the kernels' three)
-    kept = ring.since(mark, "remat.kept")
+    kept = since("remat.kept")
     assert {(r["layer"], r["array"]) for r in kept} == {
         ("block2/mixer", "o"), ("block2/mixer", "lse")}
 
@@ -222,7 +189,7 @@ def test_a_three_matrix_net_records_what_it_recorded():
     presence on a net that leaves them unset; the others are unchanged."""
     lp = dsl.MoELayer("moe", ["x"], 8, hidden_dim=16, top_k=2,
                       experts_held=4, shared_hidden_dim=16)
-    impl = get_layer(lp.type)(lp, [(1, 16, 32)], 0)
+    impl = layer(lp, [(1, 16, 32)])
     assert impl.blob_names() == ["router", "w_gate", "w_up", "w_down",
                                  "ws_gate", "ws_up", "ws_down",
                                  "shared_gate"]
@@ -248,12 +215,11 @@ def moe_layer(held=4, first=0, n=48, embed=32, hidden=24, shared=40,
                       topk_eps=1e-20, routed_scaling_factor=2.5,
                       expert_activation="relu2", expert_gate_matrix=False,
                       shared_gate=False, **over)
-    return get_layer(lp.type)(lp, [(1, n, embed)], 0)
+    return layer(lp, [(1, n, embed)])
 
 
 def moe_blobs(impl, key, bias=True):
-    blobs = [0.3 * jax.random.normal(jax.random.fold_in(key, i), s[0])
-             for i, s in enumerate(impl.param_shapes())]
+    blobs = lm.fill(impl, key)
     if not bias:
         blobs[-1] = jnp.zeros_like(blobs[-1])
     return blobs
@@ -337,14 +303,11 @@ def test_sixteen_shares_of_eight_experts_add_up_to_the_whole_layer(ref):
     blobs = moe_blobs(whole, key)
     router, w_up, w_down, ws_up, ws_down, bias = blobs
     g = jax.random.normal(jax.random.fold_in(key, 9), (1, n, e))
-    nothing = [jnp.zeros_like(ws_up), jnp.zeros_like(ws_down)]
-    total = None
-    for chip in range(16):
-        lo = 8 * chip
-        share = moe_layer(held=8, first=lo, experts=128, top_k=6, n=n)
-        part = share.apply([router, w_up[lo:lo + 8], w_down[lo:lo + 8]]
-                           + nothing + [bias], [g], True, None)[0]
-        total = part if total is None else total + part
+    (total,) = lm.sum_of_shares(
+        lambda per, lo: moe_layer(held=per, first=lo, experts=128, top_k=6,
+                                  n=n),
+        16, 8, [router, w_up, w_down, jnp.zeros_like(ws_up),
+                jnp.zeros_like(ws_down), bias], [g], routed=2)
     total = total + jnp.square(jax.nn.relu(g @ ws_up.T)) @ ws_down.T
     close(total, whole.apply(blobs, [g], True, None)[0], tol=5e-4)
     d = dict(TOY, n_routed_experts=128, router_outputs=128,
@@ -367,7 +330,7 @@ def test_the_moe_refuses_what_has_no_meaning(fields, why):
     else:
         lp = dsl.MoELayer("blk/moe", ["x"], 8, hidden_dim=16, **args)
     with pytest.raises(ValueError, match=why) as err:
-        get_layer(lp.type)(lp, [(1, 16, 32)], 0)
+        layer(lp, [(1, 16, 32)])
     assert "blk/moe" in str(err.value)
 
 
@@ -382,12 +345,11 @@ def test_attention_at_sixteen_query_heads_a_key_value_head(ref, flash):
     s = 128
     lp = dsl.AttentionLayer("attn", ["x"], 16, head_dim=8, causal=True,
                             flash=flash, num_kv_heads=1)
-    impl = get_layer(lp.type)(lp, [(2, s, 32)], 0)
+    impl = layer(lp, [(2, s, 32)])
     assert [p[0] for p in impl.param_shapes()] == [
         (128, 32), (8, 32), (8, 32), (32, 128)]
     key = jax.random.PRNGKey(15)
-    blobs = [0.3 * jax.random.normal(jax.random.fold_in(key, i), p[0])
-             for i, p in enumerate(impl.param_shapes())]
+    blobs = lm.fill(impl, key)
     x = jax.random.normal(jax.random.fold_in(key, 9), (2, s, 32))
     probe = jax.random.normal(jax.random.fold_in(key, 10), (2, s, 32))
     mark = default_tracer().mark()
@@ -410,54 +372,18 @@ def test_attention_at_sixteen_query_heads_a_key_value_head(ref, flash):
 
 # ---------------------------------------------------------- the whole model
 
-def toy_net(**over):
-    d = dict(TOY, **over)
-    held, pattern = d.pop("n_routed_experts"), d.pop("whole_pattern")
-    d.pop("carry")
-    return zoo.nemotron_h(batch_size=2, pattern=pattern,
-                          layers=(0, len(d.pop("pattern"))),
-                          n_routed_experts=d.pop("router_outputs"),
-                          experts_held=held, **d)
-
-
-SOLVER = dict(type="Adam", base_lr=1e-3, lr_policy="fixed", momentum=0.9,
-              momentum2=0.95, delta=1e-8, weight_decay=0.1)
-
-
-def toy_config(**args):
-    config = {k: v for k, v in TOY.items()
-              if k not in ("router_outputs", "first_expert", "seq_len",
-                           "pattern", "whole_pattern", "carry")}
-    config.update(hybrid_override_pattern="ME*E",
-                  published={"n_routed_experts": 16,
-                             "hybrid_override_pattern": "ME*E"},
-                  builder_args=dict({"seq_len": 48}, **args))
-    return config
-
-
-def seeded(solver, reference, seed=0):
-    sys.path.insert(0, BENCH)
-    import weights
-    w0 = weights.make_weights(reference.specs, seed)
-    assert set(w0) == set(solver.params)
-    for name, blobs in w0.items():
-        assert [b.shape for b in blobs] == \
-            [p.shape for p in solver.params[name]], name
-        solver.params[name] = [jnp.array(b) for b in blobs]
-    return w0
-
-
 def test_the_reference_reads_the_config(ref):
-    d = ref.dims(toy_config())
+    d = ref.dims(FAMILY.config())
     assert d == dict(TOY, seq_len=48)
     # a stage of a longer model keeps the whole model's pattern
-    cut = dict(toy_config(), hybrid_override_pattern="ME")
+    cut = dict(FAMILY.config(), hybrid_override_pattern="ME")
     assert ref.dims(cut)["whole_pattern"] == "ME*E"
     assert ref.dims(cut)["pattern"] == "ME"
 
 
 def test_net_is_one_mixer_a_block_from_the_pattern():
     net = zoo.nemotron_h(experts_held=8, vocab_size=16384, layers=(0, 7))
+    lm.layout(net)
     kinds = [(l.name, l.type) for l in net.layer]
     assert [n for n, _ in kinds[:3]] == ["data", "label", "tok_embed"]
     mixers = [t for n, t in kinds if n.endswith("/mixer")]
@@ -492,37 +418,8 @@ def test_the_builder_refuses_a_letter_or_a_range_it_does_not_know(args, why):
 
 @pytest.mark.parametrize("remat", ["none", "full"])
 def test_whole_model_three_adam_steps_match_reference(ref, remat):
-    reference = ref.build(toy_config(), 2)
-    sp = Message("SolverParameter", display=0, random_seed=0, **SOLVER)
-    solver = Solver(sp, net_param=toy_net(), log_fn=None, remat=remat)
-    assert solver.net._scan_runs() == []        # unlike neighbours unroll
-    for name, blobs in reference.specs:
-        assert solver.updater.mults[name] == [b[2] for b in blobs], name
-    w0 = seeded(solver, reference)
-    step = reference.make_step(SOLVER, block_rows=1)
-    draw = np.random.RandomState(0).randint(
-        0, 64, (2, reference.seq + 1)).astype(np.int32)
-    data, labels = draw[:, :-1], draw[:, 1:]
-    params, history = w0, None
-    for i in range(3):
-        got = float(solver.train_step({"data": data, "label": labels}))
-        params, history, want, grads = step(params, history, data, labels,
-                                            None)
-        assert abs(got - float(want)) <= 5e-5 * abs(float(want)), i
-        if i == 0:
-            # the first gradient, out of Adam's first moment
-            for name, blobs in grads.items():
-                for j, g in enumerate(blobs):
-                    decay = dict(reference.specs)[name][j][2][1]
-                    m1 = solver.history[name][j][0]
-                    close(m1 / 0.1 - 0.1 * decay * w0[name][j], g,
-                          tol=5e-3)
-    for name, blobs in params.items():
-        for j, w in enumerate(blobs):
-            got = np.asarray(solver.params[name][j] - w0[name][j])
-            want = np.asarray(w - w0[name][j])
-            assert np.linalg.norm(got - want) <= \
-                0.05 * np.linalg.norm(want) + 1e-12, (name, j)
+    solver, _ = lm.three_adam_steps(FAMILY, remat=remat)
+    assert solver.net._scan_runs() == list(FAMILY.runs) == []
     # the route's bias is a buffer: nothing moved it
     assert float(jnp.max(jnp.abs(solver.params["block1/mixer"][-1]))) == 0.0
 
@@ -534,15 +431,13 @@ def test_the_carry_dropped_control_is_another_model(ref):
     and delta near 0.01, the scan is small beside the D skip and the loss
     itself moves by less than float32 shows: the chip's control reads the
     published widths)."""
-    reference = ref.build(toy_config(pattern="M"), 2)   # one mixer alone
-    sys.path.insert(0, BENCH)
-    import weights
-    w0 = weights.make_weights(reference.specs, 1)
-    draw = np.random.RandomState(1).randint(0, 64, (2, 49)).astype(np.int32)
+    reference = ref.build(FAMILY.config(pattern="M"), 2)    # one mixer alone
+    w0 = lm.bench("weights").make_weights(reference.specs, 1)
+    data, labels = lm.tokens(1, 48)
 
     def loss(d):
         return jax.value_and_grad(lambda p: ref.forward_loss(
-            p, draw[:, :-1], draw[:, 1:], d) / 96)(w0)
+            p, data, labels, d) / 96)(w0)
     (l1, g1), (l0, g0) = loss(reference.d), loss(dict(reference.d,
                                                       carry=False))
     assert abs(float(l1) - float(l0)) < 1e-4 * abs(float(l1))
@@ -561,17 +456,14 @@ def test_every_operation_of_the_mixer_has_a_part_in_the_closed_ledger():
     `ssm_*` scopes counts under the layer's part `ssm`, backward and
     recomputation too, nothing of the layer is `unscoped`, and none of the
     five opens inside another."""
-    sys.path.insert(0, BENCH)
-    import step_parts
     tracer = Tracer(None)
-    sp = Message("SolverParameter", display=0, random_seed=0, **SOLVER)
-    solver = Solver(sp, net_param=toy_net(pattern="M", whole_pattern="M"),
-                    log_fn=None, tracer=tracer, remat="full")
-    draw = np.random.RandomState(3).randint(0, 64, (2, 49)).astype(np.int32)
-    batch = {"data": draw[:, :-1], "label": draw[:, 1:]}
+    solver = FAMILY.solver(dict(pattern="M", whole_pattern="M"),
+                           tracer=tracer, remat="full")
+    data, labels = lm.tokens(3, 48)
+    batch = {"data": data, "label": labels}
     parts = tracer.spans("net.parts")[-1]["parts"]
     assert parts["block0/mixer"] == "ssm"
-    table = step_parts.Parts(parts)
+    table = lm.bench("step_parts").Parts(parts)
     paths = [q for p in solver.op_scopes(batch).values()
              for q in p.split(";")
              if q.startswith("jit(") and "block0/mixer" in q]

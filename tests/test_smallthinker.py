@@ -1,6 +1,7 @@
 """SmallThinker's layers and the whole 4-block model against the plain
-reference (`benchmark/reference/smallthinker.py`, imported from where it
-lies, not copied): small widths, seeded weights, float32 on the CPU.
+reference (`benchmark/reference/smallthinker.py`): small widths, seeded
+weights, float32 on the CPU. The family's record and the bodies of the
+tests every family has are in `tests/lm_family.py`.
 
 The reference computes attention as a masked softmax a block of rows at a
 time and the MoE as a loop over the held experts with a mask, the router
@@ -9,57 +10,18 @@ flash path (the window kernels over the band alone) and over ragged groups
 a window of rows at a time.
 """
 
-import importlib
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import sparknet_tpu.ops  # noqa: F401  (registers the layers)
-from sparknet_tpu.graph.registry import get as get_layer
 from sparknet_tpu.models import dsl, zoo
 from sparknet_tpu.obs.trace import default_tracer
-from sparknet_tpu.proto import Message, text_format
-from sparknet_tpu.solver.solver import Solver
+from tests import lm_family as lm
+from tests.lm_family import close, fill, layer, ref  # noqa: F401  (fixture)
 
-BENCH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark")
-
-
-@pytest.fixture(scope="module")
-def ref():
-    if BENCH not in sys.path:
-        sys.path.insert(0, BENCH)
-    return importlib.import_module("reference.smallthinker")
-
-
-TOY = dict(hidden_size=32, num_hidden_layers=4, num_attention_heads=4,
-           num_key_value_heads=2, head_dim=16, rope_theta=1.5e6,
-           rms_norm_eps=1e-6, rope_layout=[0, 1, 1, 1],
-           sliding_window_layout=[0, 1, 1, 1], sliding_window_size=24,
-           moe_num_primary_experts=4, moe_num_active_primary_experts=3,
-           moe_ffn_hidden_size=16, norm_topk_prob=True, vocab_size=64,
-           router_outputs=16, first_expert=0, seq_len=64)
-
-
-def close(a, b, tol=2e-4):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    scale = max(np.abs(b).max(), 1e-12)
-    assert np.abs(a - b).max() <= tol * scale, \
-        (np.abs(a - b).max(), scale)
-
-
-def layer(lp, shapes):
-    return get_layer(lp.type)(lp, shapes, 0)
-
-
-def fill(impl, key, std=0.3):
-    return [std * jax.random.normal(jax.random.fold_in(key, i), shape,
-                                    jnp.float32)
-            for i, (shape, *_) in enumerate(impl.param_shapes())]
+FAMILY = lm.SMALLTHINKER
+TOY = FAMILY.toy
 
 
 # ---------------------------------------------------------------- attention
@@ -167,26 +129,9 @@ def moe_layer(held, first, outputs=16, top_k=3, tile=4):
 
 @pytest.mark.parametrize("held,first", [(4, 0), (4, 8), (16, 0)])
 def test_moe_held_share_matches_reference(ref, held, first):
-    impl = moe_layer(held, first)
-    blobs = fill(impl, jax.random.PRNGKey(8))
-    g = jax.random.normal(jax.random.PRNGKey(9), (2, 24, 32))
-    h = jax.random.normal(jax.random.PRNGKey(10), (2, 24, 32))
-    cot = jax.random.normal(jax.random.PRNGKey(11), (2, 24, 32))
-    d = dict(TOY, moe_num_primary_experts=held, first_expert=first)
-
-    def mine(g, h, blobs):
-        return impl.apply(blobs, [g, h], True, None)[0]
-
-    def theirs(g, h, blobs):
-        return ref.moe(g.reshape(48, 32), h.reshape(48, 32), blobs,
-                       d).reshape(2, 24, 32)
-    close(mine(g, h, blobs), theirs(g, h, blobs))
-    gm = jax.grad(lambda *a: jnp.sum(mine(*a) * cot), (0, 1, 2))(g, h, blobs)
-    gt = jax.grad(lambda *a: jnp.sum(theirs(*a) * cot), (0, 1, 2))(g, h,
-                                                                   blobs)
-    for a, b in zip(jax.tree_util.tree_leaves(gm),
-                    jax.tree_util.tree_leaves(gt)):
-        close(a, b, tol=5e-4)
+    lm.held_share(FAMILY, moe_layer(held, first), [(2, 24, 32)] * 2,
+                  dict(TOY, moe_num_primary_experts=held,
+                       first_expert=first), 8)
 
 
 def test_moe_shares_add_up_to_the_uncut_layer(ref):
@@ -194,34 +139,21 @@ def test_moe_shares_add_up_to_the_uncut_layer(ref):
     on every one, add up to the uncut 64-expert layer — output and the
     gradients of both inputs — in the program and against the reference
     given all 64."""
-    whole = layer(dsl.MoELayer(
-        "moe", ["g", "h"], 64, hidden_dim=16, top_k=6,
-        expert_activation="relu", tile_rows=4), [(2, 24, 32)] * 2)
+    def build(held, first):
+        return layer(dsl.MoELayer(
+            "moe", ["g", "h"], 64, hidden_dim=16, top_k=6,
+            experts_held=held, first_expert=first, expert_activation="relu",
+            tile_rows=4), [(2, 24, 32)] * 2)
+    whole = build(None, None)
     blobs = fill(whole, jax.random.PRNGKey(12))
     g = jax.random.normal(jax.random.PRNGKey(13), (2, 24, 32))
     h = jax.random.normal(jax.random.PRNGKey(14), (2, 24, 32))
     cot = jax.random.normal(jax.random.PRNGKey(15), (2, 24, 32))
-
-    def run(impl, blobs):
-        def f(g, h):
-            return impl.apply(blobs, [g, h], True, None)[0]
-        out, vjp = jax.vjp(f, g, h)
-        return (out,) + vjp(cot)
-
-    total = None
-    for chip in range(8):
-        lo = 8 * chip
-        share = layer(dsl.MoELayer(
-            "moe", ["g", "h"], 64, hidden_dim=16, top_k=6, experts_held=8,
-            first_expert=lo, expert_activation="relu", tile_rows=4),
-            [(2, 24, 32)] * 2)
-        part = run(share, [blobs[0]] + [w[lo:lo + 8] for w in blobs[1:]])
-        total = part if total is None else tuple(
-            a + b for a, b in zip(total, part))
-    want = run(whole, blobs)
+    total = lm.sum_of_shares(build, 8, 8, blobs, [g, h], cot)
     # every chip computes the router's gradient on h from its own pairs:
     # the shares' sum is the whole layer's, for g, for h and for the output
-    for a, b in zip(total, want):
+    for a, b in zip(total, lm.out_and_input_grads(whole, blobs, [g, h],
+                                                  cot)):
         close(a, b, tol=5e-4)
     d = dict(TOY, moe_num_primary_experts=64, router_outputs=64,
              moe_num_active_primary_experts=6, first_expert=0)
@@ -235,50 +167,11 @@ def test_moe_shares_add_up_to_the_uncut_layer(ref):
 
 # ---------------------------------------------------------- the whole model
 
-def toy_net(**over):
-    d = dict(TOY, **over)
-    held = d.pop("moe_num_primary_experts")
-    return zoo.smallthinker(
-        batch_size=2, moe_num_primary_experts=d.pop("router_outputs"),
-        experts_held=held, **d)
-
-
-SOLVER = dict(type="Adam", base_lr=1e-3, lr_policy="fixed", momentum=0.9,
-              momentum2=0.95, delta=1e-8, weight_decay=0.1)
-
-
-def toy_config():
-    config = {k: v for k, v in TOY.items()
-              if k not in ("router_outputs", "first_expert", "seq_len")}
-    config.update(published={"moe_num_primary_experts": 16},
-                  builder_args={"seq_len": 64})
-    return config
-
-
-def tokens(seed=0):
-    draw = np.random.RandomState(seed).randint(0, 64, (2, 65))
-    return draw[:, :-1].astype(np.int32), draw[:, 1:].astype(np.int32)
-
-
-def seeded(solver, reference, seed=0):
-    """The reference's fillers into the program's solver."""
-    sys.path.insert(0, BENCH)
-    import weights
-    w0 = weights.make_weights(reference.specs, seed)
-    assert set(w0) == set(solver.params)
-    for name, blobs in w0.items():
-        assert [b.shape for b in blobs] == \
-            [p.shape for p in solver.params[name]], name
-        solver.params[name] = [jnp.array(b) for b in blobs]
-    return w0
-
-
 def test_net_is_the_published_layout():
-    net = zoo.smallthinker(batch_size=1, seq_len=128, num_hidden_layers=8,
-                           vocab_size=64, hidden_size=32, head_dim=16,
-                           num_attention_heads=4, num_key_value_heads=2,
-                           moe_ffn_hidden_size=16, experts_held=8)
-    by_name = {lp.name: lp for lp in net.layer}
+    by_name = lm.layout(zoo.smallthinker(
+        batch_size=1, seq_len=128, num_hidden_layers=8, vocab_size=64,
+        hidden_size=32, head_dim=16, num_attention_heads=4,
+        num_key_value_heads=2, moe_ffn_hidden_size=16, experts_held=8))
     for i in range(8):
         ap = by_name[f"block{i}/attn"].attention_param
         assert (int(ap.window), int(ap.rotary_dim)) == \
@@ -289,63 +182,31 @@ def test_net_is_the_published_layout():
         assert (mp.moe_param.num_experts, mp.moe_param.top_k,
                 mp.moe_param.experts_held) == (64, 6, 8)
         assert not by_name[f"block{i}/ln1"].rms_norm_param.zero_centered
-    # the prototxt round trip keeps the new fields
-    again = text_format.loads(text_format.dumps(net), "NetParameter")
-    assert again == net
 
 
 def test_whole_model_three_adam_steps_match_reference(ref):
-    reference = ref.build(toy_config(), 2)
-    sp = Message("SolverParameter", display=0, random_seed=0, **SOLVER)
-    solver = Solver(sp, net_param=toy_net(), log_fn=None)
-    # the program's multipliers are the reference's, blob for blob
-    for name, blobs in reference.specs:
-        assert solver.updater.mults[name] == [b[2] for b in blobs], name
-    w0 = seeded(solver, reference)
-    step = reference.make_step(SOLVER, block_rows=1)
-    data, labels = tokens()
-    params, history = w0, None
-    for i in range(3):
-        got = float(solver.train_step({"data": data, "label": labels}))
-        params, history, want, grads = step(params, history, data, labels,
-                                            None)
-        assert abs(got - float(want)) <= 2e-5 * abs(float(want)), i
-        if i == 0:
-            # the first gradient, out of Adam's first moment
-            for name, blobs in grads.items():
-                for j, g in enumerate(blobs):
-                    decay = dict(reference.specs)[name][j][2][1]
-                    m1 = solver.history[name][j][0]
-                    close(m1 / 0.1 - 0.1 * decay * w0[name][j], g,
-                          tol=2e-3)
-    for name, blobs in params.items():
-        for j, w in enumerate(blobs):
-            got = np.asarray(solver.params[name][j] - w0[name][j])
-            want = np.asarray(w - w0[name][j])
-            assert np.linalg.norm(got - want) <= \
-                0.05 * np.linalg.norm(want), (name, j)
+    lm.three_adam_steps(FAMILY)
 
 
 def test_the_router_reads_the_first_norm_not_the_second(ref):
     """The router's weight gradient in the whole model is the reference's
     with the router on the pre-attention norm, and is not what a router on
     the post-attention norm (the experts' input) would get."""
-    reference = ref.build(toy_config(), 2)
-    sp = Message("SolverParameter", display=0, random_seed=0, **SOLVER)
-    solver = Solver(sp, net_param=toy_net(), log_fn=None)
-    w0 = seeded(solver, reference, seed=1)
+    reference = ref.build(FAMILY.config(), 2)
+    solver = FAMILY.solver()
+
     # a small embedding, so that attention's output is a visible part of
     # the residual and the two norms differ
-    w0["tok_embed"] = [0.02 * w0["tok_embed"][0]]
-    solver.params["tok_embed"] = [jnp.array(w0["tok_embed"][0])]
-    data, labels = tokens(3)
-    batch = {"data": jnp.asarray(data), "label": jnp.asarray(labels)}
+    def small_embedding(w0):
+        w0["tok_embed"] = [0.02 * w0["tok_embed"][0]]
+    w0 = lm.seeded(solver, reference, seed=1, edit=small_embedding)
+    batch = lm.batch_of(3)
 
     def reference_grad():
         return jax.grad(lambda p: ref.forward_loss(
             p, batch["data"], batch["label"], reference.d) / 128)(
                 w0)["block1/moe"][0]
-    got = grads_of(solver, batch)["block1/moe"][0]
+    got = lm.grads_of(solver, batch)["block1/moe"][0]
     close(got, reference_grad(), tol=2e-3)
     whole = ref.moe
     try:
@@ -358,62 +219,25 @@ def test_the_router_reads_the_first_norm_not_the_second(ref):
         0.05 * np.linalg.norm(np.asarray(got))
 
 
-def grads_of(solver, batch):
-    net = solver.net
-    return jax.grad(lambda p: net.loss_fn(p, solver.state, batch)[0])(
-        solver.params)
-
-
 @pytest.mark.parametrize("remat,scan", [("full", "off"), ("none", "on"),
                                         ("full", "on")])
 def test_remat_and_scan_leave_the_gradients_alone(ref, remat, scan):
-    sp = Message("SolverParameter", display=0, random_seed=0, **SOLVER)
-    data, labels = tokens(1)
-    batch = {"data": jnp.asarray(data), "label": jnp.asarray(labels)}
-    plain = Solver(sp, net_param=toy_net(), log_fn=None)
-    plain.set_scan("off")
-    knobbed = Solver(sp, net_param=toy_net(), log_fn=None, remat=remat)
-    knobbed.set_scan(scan)
-    # one period: the global block is a body of its own (same shapes,
-    # other settings), the three window blocks are a run; each block's MoE
-    # reads two blobs of its own block, from different depths
-    runs = knobbed.net._scan_runs()
-    assert [(r["n"], r["glen"], r["entry"]) for r in runs] == \
-        [(3, 6, "block0/res2")]
-    seeded(plain, ref.build(toy_config(), 2))
-    seeded(knobbed, ref.build(toy_config(), 2))
-    want, got = grads_of(plain, batch), grads_of(knobbed, batch)
-    for name in want:
-        for a, b in zip(got[name], want[name]):
-            close(a, b, tol=1e-4)
+    lm.remat_and_scan(FAMILY, remat, scan)
 
 
 def test_two_periods_scan_as_window_runs_between_global_blocks():
-    sp = Message("SolverParameter", display=0, random_seed=0, **SOLVER)
-    solver = Solver(sp, net_param=toy_net(
+    solver = FAMILY.solver(dict(
         num_hidden_layers=8, rope_layout=[0, 1, 1, 1] * 2,
-        sliding_window_layout=[0, 1, 1, 1] * 2), log_fn=None)
+        sliding_window_layout=[0, 1, 1, 1] * 2))
     assert [(r["n"], r["entry"]) for r in solver.net._scan_runs()] == \
         [(3, "block0/res2"), (3, "block4/res2")]
 
 
 def test_moe_load_is_recorded_where_the_solver_fetches_a_loss():
-    from sparknet_tpu.obs.trace import Tracer
-    tracer = Tracer()
     # this test's records alone: the ring is the process's, and a worker
     # that ran the Nemotron tests first holds their `relu2` paths
-    mark = default_tracer().mark()
-    sp = Message("SolverParameter", display=1, random_seed=0, **SOLVER)
-    solver = Solver(sp, net_param=toy_net(moe_stats=True), log_fn=None,
-                    tracer=tracer)
-    data, labels = tokens(2)
-    solver.step(2, iter([{"data": data, "label": labels}] * 2))
-    loads = tracer.spans("moe.load")
-    assert {r["layer"] for r in loads} == {f"block{i}/moe"
-                                           for i in range(4)}
-    for r in loads:
-        assert 0.0 < r["held_share"] < 1.0 and r["windows"] >= 1.0
-    paths = [r for r in default_tracer().since(mark, "moe.path")
-             if r.get("activation")]
+    tracer, since = lm.traced_steps(FAMILY, 2, dict(moe_stats=True))
+    lm.held_loads(tracer, [f"block{i}/moe" for i in range(4)])
+    paths = [r for r in since("moe.path") if r.get("activation")]
     assert paths and {r["activation"] for r in paths} <= {"relu", "silu"}
     assert paths[-1]["activation"] == "relu"
