@@ -71,7 +71,7 @@ PART_OF_TYPE = {
                      "Softmax"), "act"),
     **dict.fromkeys(("Concat", "Slice", "Split", "Flatten", "Reshape",
                      "Tile", "ArgMax", "Reduction", "Silence",
-                     "BatchReindex", "Filter"), "shape"),
+                     "BatchReindex", "Filter", "Shift"), "shape"),
     **dict.fromkeys(("SoftmaxWithLoss", "EuclideanLoss", "HingeLoss",
                      "SigmoidCrossEntropyLoss", "MultinomialLogisticLoss",
                      "InfogainLoss", "ContrastiveLoss", "Accuracy"), "loss"),
@@ -393,6 +393,35 @@ class CompiledNet:
             if read_by and all(parts[r] == "head" for r in read_by):
                 parts[name] = "final_norm"
         return parts
+
+    def prediction_depths(self):
+        """A language model's further prediction depths (models/zoo.py:
+        `_lm_mtp`), read off the net: [(loss layer, its loss weight)] of
+        every loss whose logits come from a layer that owns no blob and
+        borrows those of an earlier loss's logits layer — a second head on
+        the first one's matrix. A head tied to the embedding's table alone
+        is none."""
+        made_by = {t: lp.name for lp, _, _, tops in self.layers for t in tops}
+        seen, depths = set(), []
+        for lp, impl, bottoms, _ in self.layers:
+            head = made_by.get(bottoms[0]) if impl.loss_like and bottoms \
+                else None
+            if head is None:
+                continue
+            refs = self.param_refs[head]
+            if refs and all(k[0] != head and k in seen for k in refs):
+                depths.append((lp.name, self.loss_weights[lp.name][0]))
+            seen.update(refs)
+        return depths
+
+    def shared_params(self):
+        """The ParamSpec names that more than one layer gives, sorted."""
+        users = {}
+        for lp, _, _, _ in self.layers:
+            for spec in lp.param:
+                if spec.has("name"):
+                    users.setdefault(spec.name, set()).add(lp.name)
+        return sorted(n for n, ls in users.items() if len(ls) > 1)
 
     # -- init --------------------------------------------------------------
     def init(self, rng):

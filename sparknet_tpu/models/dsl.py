@@ -3,8 +3,8 @@
 The capability of the reference's Scala DSL (Layers.scala:18-137 — RDDLayer,
 ConvolutionLayer, PoolingLayer, InnerProductLayer, ReLULayer, SoftmaxWithLoss,
 NetParam), extended with the builders the bigger nets need (LRN, Dropout,
-Concat, Accuracy, BatchNorm, Eltwise, Attention, GatedDeltaNet, ShortConv,
-RMSNorm, MoE). Each returns a proto
+Concat, Shift, Accuracy, BatchNorm, Eltwise, Attention, GatedDeltaNet,
+ShortConv, RMSNorm, MoE). Each returns a proto
 Message, so DSL output and parsed prototxt are the same IR.
 """
 
@@ -87,11 +87,17 @@ def SigmoidLayer(name, bottoms, tops=None):
     return _base("Sigmoid", name, bottoms, tops=tops)
 
 
-def SoftmaxWithLoss(name, bottoms, axis=None):
+def SoftmaxWithLoss(name, bottoms, axis=None, ignore_label=None,
+                    loss_weight=None):
     kw = {}
     if axis is not None:
         kw["softmax_param"] = dict(axis=axis)
-    return _base("SoftmaxWithLoss", name, bottoms, **kw)
+    if ignore_label is not None:
+        kw["loss_param"] = dict(ignore_label=ignore_label)
+    lp = _base("SoftmaxWithLoss", name, bottoms, **kw)
+    if loss_weight is not None:
+        lp.loss_weight.append(float(loss_weight))
+    return lp
 
 
 def AccuracyLayer(name, bottoms, top_k=1, include=TEST):
@@ -109,6 +115,13 @@ def LRNLayer(name, bottoms, local_size=5, alpha=1.0, beta=0.75,
 def DropoutLayer(name, bottoms, tops=None, ratio=0.5):
     return _base("Dropout", name, bottoms, tops=tops,
                  dropout_param=dict(dropout_ratio=ratio))
+
+
+def ShiftLayer(name, bottoms, offset=1, axis=1, fill=0.0):
+    """sparknet_tpu extension: y[i] = x[i + offset] along `axis`, `fill`
+    where that reads past an end."""
+    return _base("Shift", name, bottoms,
+                 shift_param=dict(axis=axis, offset=offset, fill=fill))
 
 
 def ConcatLayer(name, bottoms, axis=1):
@@ -143,7 +156,9 @@ def AttentionLayer(name, bottoms, num_heads, head_dim=None, causal=False,
                    output_gate=False, norm_eps=None, weight_filler=None,
                    param=None, window=None, qk_norm_zero_centered=None,
                    index_heads=None, index_head_dim=None, index_topk=None,
-                   index_stats=False, out_filler=None):
+                   index_stats=False, out_filler=None, q_lora_rank=None,
+                   kv_lora_rank=None, qk_nope_head_dim=None,
+                   qk_rope_head_dim=None, v_head_dim=None):
     """sparknet_tpu extension for the long-context path (see
     parallel.ring_attention, ops.pallas_attention). `num_kv_heads` selects
     the grouped-query form (bias-free q/k/v/out projections; qk_norm,
@@ -156,8 +171,19 @@ def AttentionLayer(name, bottoms, num_heads, head_dim=None, causal=False,
     LayerNorm weight and bias) and a second top `<name>_kl`, the index's
     own loss, of weight 1; `index_stats` adds a third (weight 0), the
     share of the selected keys inside a window of `index_topk`.
-    `out_filler` fills the grouped-query form's out projection."""
+    `out_filler` fills the grouped-query form's out projection.
+    `kv_lora_rank` selects multi-head latent attention (with causal and
+    without num_kv_heads; ops/attention.py): `q_lora_rank`,
+    `qk_nope_head_dim`, `qk_rope_head_dim` and `v_head_dim` are its other
+    sizes, `rope_theta` and `norm_eps` its rotary's and its two latent
+    norms'; seven blobs."""
     ap = dict(num_heads=num_heads, causal=causal, ring=ring, flash=flash)
+    latent = dict(q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+                  qk_nope_head_dim=qk_nope_head_dim,
+                  qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim)
+    if kv_lora_rank is not None:
+        latent.update(rope_theta=rope_theta, norm_eps=norm_eps)
+    ap.update({k: v for k, v in latent.items() if v is not None})
     if out_filler is not None:
         ap["out_filler"] = out_filler
     if window:

@@ -11,7 +11,7 @@ from .dsl import (GatedDeltaNetLayer, RMSNormLayer, ShortConvLayer,
                   AccuracyLayer, LRNLayer, DropoutLayer, ConcatLayer,
                   EltwiseLayer, AttentionLayer, EmbedLayer,
                   PositionalEmbedLayer, LayerNormLayer, MoELayer,
-                  Mamba2Layer)
+                  Mamba2Layer, ShiftLayer)
 
 
 def _conv(name, bottom, num_output, kernel, stride=1, pad=0, group=None,
@@ -302,17 +302,56 @@ def _lm_block(p, x, branches, norm):
     return layers, x
 
 
-def _lm_head(x, vocab_size, norm, **head):
+def _lm_head(x, vocab_size, norm, p="", label="label", loss=None, **head):
     """The final norm "ln_f", the logits "lm_head" (tied to the embedding
     where `head` names the table's `param`) and the mean cross-entropy per
-    token "loss" over "label"."""
-    return [norm("ln_f", x),
-            InnerProductLayer("lm_head", ["ln_f"], vocab_size, axis=2, **head),
-            SoftmaxWithLoss("loss", ["lm_head", "label"], axis=2)]
+    token "loss" over `label`; `p` before the three names and `loss`
+    (SoftmaxWithLoss's keywords) are a further prediction depth's."""
+    return [norm(p + "ln_f", x),
+            InnerProductLayer(p + "lm_head", [p + "ln_f"], vocab_size, axis=2,
+                              **head),
+            SoftmaxWithLoss(p + "loss", [p + "lm_head", label], axis=2,
+                            **(loss or {}))]
+
+
+#: the label a further prediction depth's loss ignores (`_lm_mtp`)
+IGNORE_LABEL = -1
+
+
+def _lm_mtp(depth, x, label, vocab_size, width, branches, norm, embed, head,
+            loss_weight, proj):
+    """Multi-token-prediction module `depth` (1 is the first), DeepSeek-V3's
+    form: for position i, h'_i = W_eh [norm_e(Emb(t_{i+depth})) ;
+    norm_h(x_i)] with the MAIN model's table (`embed` names it), `x` the
+    residual stream of the depth before (the main stack's last boundary
+    blob for the first) and `label` that depth's labels, which are this
+    depth's input tokens; ONE more block "block_mtp{depth}/" of `branches`
+    over h'; a final norm of its own, the MAIN model's head (`head` names
+    its blob), and the cross-entropy against `label` moved one place left,
+    its last place `IGNORE_LABEL`, which the loss ignores, times
+    `loss_weight`. `proj` are the keywords of W_eh's InnerProduct (width x
+    2 width, no bias). Returns (layers, the block's boundary blob, this
+    depth's labels): the module's layers outside the block are named
+    "mtp{depth}_<what>", so the block is a rematerialization segment of
+    its own, reads one blob from outside itself and joins no scan."""
+    m = f"mtp{depth}_"
+    layers = [EmbedLayer(m + "embed", [label], vocab_size, width, **embed),
+              norm(m + "ln_e", m + "embed"), norm(m + "ln_h", x),
+              ConcatLayer(m + "cat", [m + "ln_e", m + "ln_h"], axis=2),
+              InnerProductLayer(m + "proj", [m + "cat"], width, axis=2,
+                                bias_term=False, **proj)]
+    block, x = _lm_block(f"block_mtp{depth}/", m + "proj", branches, norm)
+    layers += block
+    layers.append(ShiftLayer(m + "label", [label], offset=1, axis=1,
+                             fill=IGNORE_LABEL))
+    layers += _lm_head(x, vocab_size, norm, p=m, label=m + "label",
+                       loss=dict(ignore_label=IGNORE_LABEL,
+                                 loss_weight=loss_weight), **head)
+    return layers, x, m + "label"
 
 
 def _lm_stack(name, batch_size, seq_len, vocab_size, width, blocks, norm,
-              embed, head, positions=None, with_data=True):
+              embed, head, positions=None, with_data=True, mtp=None):
     """A causal language model as ONE pre-norm stack, the only place that
     spells it: the feeds "data" and "label" (B, S) int32 (token ids, next
     token ids), the embedding (`_lm_embed`), for every (i, branches) of
@@ -333,7 +372,19 @@ def _lm_stack(name, batch_size, seq_len, vocab_size, width, blocks, norm,
     blobs (`--scan`). A block that shares a blob with another, reads a
     second outside blob or is named off the prefix forfeits both, in
     silence: so a builder describes its blocks' branches and leaves the
-    names, the chaining and the order to this function."""
+    names, the chaining and the order to this function.
+
+    `mtp` = dict(depths, branches, loss_weight, proj) adds `depths`
+    multi-token-prediction modules after the loss (`_lm_mtp`), each with
+    one block of `branches`; with one or more the embedding's table and the
+    head's blob are named ("tok_embed_table", "lm_head_table", unless `embed` / `head`
+    name theirs) and every module shares both, so that their gradients are
+    the sums of all their uses."""
+    depths = mtp["depths"] if mtp else 0
+    if depths:
+        keep = dict(lr_mult=1, decay_mult=1)
+        embed = dict({"param": [dict(keep, name="tok_embed_table")]}, **embed)
+        head = dict({"param": [dict(keep, name="lm_head_table")]}, **head)
     layers = []
     if with_data:
         layers += [RDDLayer("data", [batch_size, seq_len]),
@@ -343,7 +394,14 @@ def _lm_stack(name, batch_size, seq_len, vocab_size, width, blocks, norm,
     for i, branches in blocks:
         block, x = _lm_block(f"block{i}/", x, branches, norm)
         layers += block
-    return NetParam(name, *layers, *_lm_head(x, vocab_size, norm, **head))
+    layers += _lm_head(x, vocab_size, norm, **head)
+    label = "label"
+    for depth in range(1, depths + 1):
+        more, x, label = _lm_mtp(
+            depth, x, label, vocab_size, width, mtp["branches"], norm,
+            embed, head, mtp["loss_weight"], mtp.get("proj", {}))
+        layers += more
+    return NetParam(name, *layers)
 
 
 def _layer_norm(name, x):
@@ -411,6 +469,25 @@ def _rms_norm(eps, **kw):
     weight."""
     return lambda name, x: RMSNormLayer(name, [x], eps=eps, param=[_NODECAY],
                                         **kw)
+
+
+def _silu_ff(intermediate_size, hidden_size, filler):
+    """A dense feed-forward W_2 (silu(W_1 x) * W_3 x) as `_lm_block` takes a
+    branch's body: ff_gate, ff_up, ff_sig, ff_act, ff_down — three
+    InnerProducts without bias, a Sigmoid and a product (silu(a) = a *
+    sigmoid(a))."""
+    def body(p, h):
+        def fc(name, bottom, width):
+            return InnerProductLayer(p + name, [bottom], width,
+                                     weight_filler=filler, axis=2,
+                                     bias_term=False, param=[_KEEP])
+        return [fc("ff_gate", h, intermediate_size),
+                fc("ff_up", h, intermediate_size),
+                SigmoidLayer(p + "ff_sig", [p + "ff_gate"]),
+                EltwiseLayer(p + "ff_act", [p + "ff_gate", p + "ff_sig",
+                                            p + "ff_up"], operation="PROD"),
+                fc("ff_down", p + "ff_act", hidden_size)]
+    return body
 
 
 def qwen3_next(vocab_size=151936, seq_len=8192, batch_size=2,
@@ -622,17 +699,7 @@ def lfm2_moe(vocab_size=65536, seq_len=8192, batch_size=3,
             rope_theta=rope_theta, norm_eps=norm_eps, weight_filler=gauss,
             param=[_KEEP] * 4 + [_NODECAY] * 2)]
 
-    def dense(p, h):
-        def fc(name, bottom, width):
-            return InnerProductLayer(p + name, [bottom], width,
-                                     weight_filler=gauss, axis=2,
-                                     bias_term=False, param=[_KEEP])
-        return [fc("ff_gate", h, intermediate_size),
-                fc("ff_up", h, intermediate_size),
-                SigmoidLayer(p + "ff_sig", [p + "ff_gate"]),
-                EltwiseLayer(p + "ff_act", [p + "ff_gate", p + "ff_sig",
-                                            p + "ff_up"], operation="PROD"),
-                fc("ff_down", p + "ff_act", hidden_size)]
+    dense = _silu_ff(intermediate_size, hidden_size, gauss)
 
     def moe(p, h):
         return [MoELayer(
@@ -851,6 +918,99 @@ def nemotron_h(vocab_size=131072, seq_len=8192, batch_size=2,
         _rms_norm(layer_norm_epsilon, zero_centered=False),
         embed=dict(weight_filler=_gauss(1.0), bias_term=False),
         head=dict(weight_filler=gauss, bias_term=False))
+
+
+def glm4_moe_lite(vocab_size=154880, seq_len=8192, batch_size=1,
+                  hidden_size=2048, intermediate_size=10240,
+                  moe_intermediate_size=1536, num_hidden_layers=47,
+                  first_k_dense_replace=1, num_attention_heads=20,
+                  q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+                  qk_rope_head_dim=64, v_head_dim=256, rope_theta=1e6,
+                  rms_norm_eps=1e-5, n_routed_experts=64,
+                  num_experts_per_tok=4, n_shared_experts=1,
+                  norm_topk_prob=True, routed_scaling_factor=1.8,
+                  num_nextn_predict_layers=1, mtp_loss_weight=0.3,
+                  experts_held=None, first_expert=0, flash=True,
+                  moe_stats=False):
+    """GLM-4.7-Flash (`model_type` glm4_moe_lite) as a trainable net: blocks
+    of y = x + Attn(RMSNorm(x)), out = y + FF(RMSNorm(y)), plain RMSNorm (w
+    filled with 1), a final RMSNorm, untied embedding and head, mean
+    cross-entropy per token. `Attn` is multi-head latent attention
+    (ops/attention.py's latent form): queries through a latent of
+    `q_lora_rank`, keys and values through one of `kv_lora_rank`, each with
+    its own RMSNorm; `num_attention_heads` heads of [`qk_nope_head_dim` |
+    `qk_rope_head_dim`], rotate-half rotary on the rotary part alone, the
+    key's rotary part ONE vector a token shared by all heads; value heads
+    of `v_head_dim`; no bias, window, head norm or gate. `FF` of the first
+    `first_k_dense_replace` layers is W_2 (silu(W_1 y) * W_3 y) at
+    `intermediate_size` (`_silu_ff`); of the others a no-drop MoE
+    (ops/moe.py): s = sigmoid(W_r y) in float32 over `n_routed_experts`,
+    the `num_experts_per_tok` largest of s + b (b a buffer of zeros that no
+    gradient trains; `n_group` and `topk_group` 1: no group limit), their
+    weights the unbiased s divided by (their sum + 1e-20), times
+    `routed_scaling_factor`; SiLU-gated three-matrix experts at
+    `moe_intermediate_size`, and `n_shared_experts` x that width as one
+    shared expert of every token, added with no gate. With
+    `num_nextn_predict_layers` that many multi-token-prediction modules
+    (`_lm_mtp`: the main model's table and head shared, one more MoE block
+    each), their losses times `mtp_loss_weight`. Defaults are the published
+    sizes.
+
+    Assumed, where the config has no key (the configuration file lists the
+    same): rotate-half rotary (DeepSeek's interleaved pairs are the same
+    function under a fixed permutation of the rotary rows of W_qb and
+    W_kva); the module's form, DeepSeek-V3's, with W_eh reading [embedding
+    ; hidden] in that order; `mtp_loss_weight` 0.3; the 1e-20; fillers
+    gaussian(0.02) for matrices and gaussian(1) for the embedding (as
+    `smallthinker`: the head is untied and the first mixer is attention).
+    Left out: the bias's load-balancing update, any auxiliary loss,
+    dropout, packing.
+
+    One chip's share of an expert-parallel group as in `qwen3_next`:
+    `experts_held` from `first_expert` on, `vocab_size` the held rows,
+    `num_hidden_layers` this pipeline stage's layers (the first of the
+    model's: `first_k_dense_replace` counts from layer 0).
+
+    Layers are named block{i}/ln1 | attn | res1 | ln2 | ff... | res2 (a
+    dense block's feed-forward ff_gate, ff_up, ff_sig, ff_act, ff_down; a
+    MoE block's moe; `_lm_stack`): the dense block is a body of its own,
+    the MoE blocks are alike and scan as one run, a prediction module's
+    block_mtp{k}/ stands outside it."""
+    gauss = _gauss()
+
+    def attention(p, h):
+        return [AttentionLayer(
+            p + "attn", [h], num_attention_heads, causal=True, flash=flash,
+            q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+            qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rope_theta=rope_theta, norm_eps=rms_norm_eps,
+            weight_filler=gauss,
+            param=[_KEEP, _NODECAY, _KEEP, _KEEP, _NODECAY, _KEEP, _KEEP])]
+
+    def moe(p, h):
+        return [MoELayer(
+            p + "moe", [h], n_routed_experts,
+            hidden_dim=moe_intermediate_size, top_k=num_experts_per_tok,
+            experts_held=experts_held, first_expert=first_expert,
+            shared_hidden_dim=n_shared_experts * moe_intermediate_size,
+            norm_topk_prob=norm_topk_prob, score_function="sigmoid",
+            selection_bias=True, topk_eps=1e-20,
+            routed_scaling_factor=routed_scaling_factor, shared_gate=False,
+            weight_filler=gauss, stats=moe_stats)]
+    dense = _silu_ff(intermediate_size, hidden_size, gauss)
+    sparse = [("ln1", attention, "res1"), ("ln2", moe, "res2")]
+    return _lm_stack(
+        "GLM4MoELite", batch_size, seq_len, vocab_size, hidden_size,
+        [(i, [("ln1", attention, "res1"), ("ln2", dense, "res2")]
+          if i < first_k_dense_replace else sparse)
+         for i in range(num_hidden_layers)],
+        _rms_norm(rms_norm_eps, zero_centered=False),
+        embed=dict(weight_filler=_gauss(1.0), bias_term=False),
+        head=dict(weight_filler=gauss, bias_term=False),
+        mtp=dict(depths=num_nextn_predict_layers, branches=sparse,
+                 loss_weight=mtp_loss_weight,
+                 proj=dict(weight_filler=gauss, param=[_KEEP])))
 
 
 def transformer_lm_pieces(vocab_size=512, seq_len=256, batch_size=8,

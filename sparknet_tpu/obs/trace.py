@@ -24,11 +24,16 @@ span open on their thread as parent and land in that span's tracer.
 
 Beside spans the ring holds records that say what a trace of the program
 chose or is made of, each written once where it is decided: ``attn.path``,
-``gdn.path``, ``moe.path``, ``shortconv.path``, ``remat.kept``, ``moe.load``
-and ``net.parts`` — the net's name and, for every layer, the part of a
-step its device time counts under (graph/compiler.py:PART_OF_TYPE has the
-closed list), which is what lets a reader of a device trace add a step up
-without knowing any model's layer names.
+``gdn.path``, ``moe.path``, ``shortconv.path``, ``remat.kept``, ``moe.load``,
+``lm.mtp`` (a net with multi-token-prediction modules: their ``depth``,
+the ``loss_weight``, their ``losses`` and the names of the ``shared``
+blobs) and ``net.parts`` — the net's name and, for every layer, the part
+of a step its device time counts under (graph/compiler.py:PART_OF_TYPE has
+the closed list), which is what lets a reader of a device trace add a step
+up without knowing any model's layer names. An ``attn.path`` of a latent
+layer says ``form`` = ``latent`` and its five sizes besides, with
+``shared_key_bytes``, what one pass writes to give the one rotary key a
+token a head axis.
 
 ``default_tracer()`` is the process-wide tracer that ``Solver`` and
 ``PrefetchIterator`` use when none is passed; ``default_tracer().spans()``

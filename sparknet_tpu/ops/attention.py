@@ -62,8 +62,37 @@ tiles and every query's threshold), attn_core (the masked flash passes; in
 the backward also the index's gradients, which need the heads'
 probabilities), dsa_kl (L_I's value).
 
-Everything the layer traces lies under one scope inside its own, in both
-forms, so that a device trace adds up by them: attn_proj_in (the q, k, v
+The latent form (attention_param.kv_lora_rank set, with causal; all five
+of q_lora_rank Rq, kv_lora_rank Rkv, qk_nope_head_dim Dn, qk_rope_head_dim
+Dr, v_head_dim Dv) is multi-head latent attention as a TRAINING step runs
+it, keys and values expanded from their latent every pass. Seven bias-free
+blobs, in order:
+  W_qa (Rq, E), the query latent's RMSNorm weight (Rq,), W_qb (H*(Dn+Dr),
+  Rq), W_kva (Rkv+Dr, E), the key-value latent's RMSNorm weight (Rkv,),
+  W_kvb (H*(Dn+Dv), Rkv), out (E, H*Dv);
+c_q = RMSNorm(W_qa h), a query head [q_nope (Dn) | q_pe (Dr)] of W_qb c_q;
+[c_kv (Rkv) | k_pe (Dr)] = W_kva h, c_kv = RMSNorm(c_kv) (both norms plain,
+y = x / rms(x) * w with w filled with 1, the layer's norm_eps, float32);
+[k_nope (Dn) | v (Dv)] a head of W_kvb c_kv; rotate-half rotary at
+rope_theta on the LAST Dr dimensions of every query head and on k_pe, which
+has no head axis: ONE rotary key a token, shared by all H heads; a key head
+is [k_nope | k_pe]; softmax(q k^T / sqrt(Dn + Dr)) v; the out projection of
+the H value heads. The absorbed form (scores against c_kv itself) is
+decode's and is not here. q, k and v reach `_core` as H heads each, so the
+kernels and the `attn.path` record are the other forms'; the record says
+besides `form` = `latent`, `q_rank`, `kv_rank`, `nope_dim`, `rope_dim`,
+`v_dim` and `shared_key_bytes`, the bytes one pass of the layer writes to
+give k_pe a head axis (0 once a kernel reads it in place). The flash kernel
+needs a value head as wide as the key's (Dv = Dn + Dr); else the dense
+path, and the `reason` says so. Inside attn_proj_in the form opens three
+scopes of its own: mla_q_latent (both query products and the norm between
+them), mla_kv_latent (W_kva, the split, the norm, W_kvb), mla_k_assemble
+(k_pe broadcast over the heads and joined to k_nope). It has no meaning
+with window, ring, index_heads, output_gate, qk_norm, num_kv_heads or
+rotary_dim, and says so by name.
+
+Everything the layer traces lies under one scope inside its own, in every
+form, so that a device trace adds up by them: attn_proj_in (the q, k, v
 products with their weights' casts, the reshapes, the head norms, the
 output gate's split, the move to (B, H, S, D)), rope, attn_core (`_core`),
 attn_proj_out (the move back, the gate, the out projection).
@@ -100,6 +129,11 @@ def rotary(x, rotary_dim, theta):
     return jnp.concatenate([out, x[..., rotary_dim:]], -1)
 
 
+#: the latent form's sizes, in the order of AttentionParameter's fields
+LATENT_SIZES = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim")
+
+
 @register
 class Attention(Layer):
     type_name = "Attention"
@@ -127,6 +161,15 @@ class Attention(Layer):
         self.norm_eps = float(p.norm_eps)
         self.qk_zero_centered = bool(p.qk_norm_zero_centered)
         self.window = int(p.window)
+        # a learned index picks the keys (ops/dsa.py)
+        self.index = p.has("index_heads") or p.has("index_topk") \
+            or p.has("index_head_dim")
+        # multi-head latent attention: checked first, so that it refuses
+        # another form's field by the field's name
+        self.latent = any(p.has(n) for n in LATENT_SIZES)
+        if self.latent:
+            self._init_latent(p)
+            return
         if self.window and (not self.causal or self.ring):
             raise ValueError(f"{lp.name}: a window needs causal attention "
                              "and has no ring mode")
@@ -141,9 +184,6 @@ class Attention(Layer):
         if self.gqa and self.ring:
             raise ValueError(f"{lp.name}: the grouped-query form has no "
                              "ring mode")
-        # a learned index picks the keys (ops/dsa.py)
-        self.index = p.has("index_heads") or p.has("index_topk") \
-            or p.has("index_head_dim")
         if self.index:
             if not (p.has("index_heads") and p.has("index_head_dim")
                     and p.has("index_topk")):
@@ -178,6 +218,40 @@ class Attention(Layer):
                     f"{lp.name}: index_head_dim {self.index_dim} is no "
                     "multiple of 4 (the rotary turns its first half)")
 
+    def _init_latent(self, p):
+        lp = self.lp
+        lacks = [n for n in LATENT_SIZES
+                 if not p.has(n) or int(getattr(p, n)) < 1]
+        if lacks:
+            raise ValueError(f"{lp.name}: the latent form needs "
+                             f"{', '.join(lacks)} (all five sizes, each at "
+                             "least 1)")
+        self.q_rank, self.kv_rank, self.nope_dim, self.rope_dim, \
+            self.v_dim = (int(getattr(p, n)) for n in LATENT_SIZES)
+        for bad, why in (
+                (self.window, "window"), (self.ring, "ring"),
+                (self.index, "index_heads, index_head_dim or index_topk"),
+                (self.output_gate, "output_gate"), (self.qk_norm, "qk_norm"),
+                (self.gqa, "num_kv_heads (every head has a key of its own "
+                 "from the latent)"),
+                (self.rotary_dim, "rotary_dim (qk_rope_head_dim is the "
+                 "rotary part)"),
+                (not self.causal, "causal false")):
+            if bad:
+                raise ValueError(f"{lp.name}: the latent form has no "
+                                 f"meaning with {why}")
+        if self.rope_dim % 2:
+            raise ValueError(f"{lp.name}: qk_rope_head_dim {self.rope_dim} "
+                             "is odd (the rotary turns halves)")
+        if p.has("head_dim") and \
+                self.head_dim != self.nope_dim + self.rope_dim:
+            raise ValueError(
+                f"{lp.name}: head_dim {self.head_dim} is not "
+                "qk_nope_head_dim + qk_rope_head_dim = "
+                f"{self.nope_dim + self.rope_dim}")
+        self.head_dim = self.nope_dim + self.rope_dim
+        self.inner = self.num_heads * self.v_dim
+
     #: its loss top is a scalar that a scan over blocks may stack
     #: (graph/compiler.py:_scan_runs)
     scan_loss_tops = True
@@ -187,6 +261,19 @@ class Attention(Layer):
         # zero projections is a degenerate identity-killer — default xavier
         wf = self.p.weight_filler if self.p.has("weight_filler") \
             else Message("FillerParameter", type="xavier")
+        if self.latent:
+            mults = _param_mults(self.lp, 7)
+            one = Message("FillerParameter", type="constant", value=1.0)
+            of = self.p.out_filler if self.p.has("out_filler") else wf
+            h, e = self.num_heads, self.embed
+            return [((self.q_rank, e), wf, *mults[0]),
+                    ((self.q_rank,), one, *mults[1]),
+                    ((h * self.head_dim, self.q_rank), wf, *mults[2]),
+                    ((self.kv_rank + self.rope_dim, e), wf, *mults[3]),
+                    ((self.kv_rank,), one, *mults[4]),
+                    ((h * (self.nope_dim + self.v_dim), self.kv_rank), wf,
+                     *mults[5]),
+                    ((e, self.inner), of, *mults[6])]
         if self.gqa:
             mults = _param_mults(self.lp, 11)
             kv = self.kv_heads * self.head_dim
@@ -234,6 +321,8 @@ class Attention(Layer):
 
     def apply(self, params, bottoms, train, rng):
         x = bottoms[0]                                   # (B, S, E)
+        if self.latent:
+            return self._apply_latent(params, x)
         if self.gqa:
             return self._apply_gqa(params, x)
         # every operation under one of three scopes (`rope` is the fourth,
@@ -253,17 +342,18 @@ class Attention(Layer):
             o = jnp.moveaxis(o, 2, 1).reshape(b, s, self.inner)
             return [o @ wo.T + bo]
 
-    def _core(self, q, k, v):
+    def _core(self, q, k, v, **said):
         """softmax(q k^T / sqrt(D) + mask) v of q (B, H, S, D) against k, v
         (B, Hkv, S, D): over the ring, through the flash kernel or dense,
-        chosen from what the layer sees, and recorded as `attn.path`."""
+        chosen from what the layer sees, and recorded as `attn.path` with
+        what the caller `said` of its form besides."""
         s, grp = q.shape[2], q.shape[1] // k.shape[1]
         seq_axis = context.axis("seq")
         live = half = masked = 0
         if self.ring and seq_axis is not None:
             path, reason = "ring", f"ring over the mesh axis {seq_axis}"
             o = ring_attention(q, k, v, seq_axis, causal=self.causal)
-        elif self.flash and s % 128 == 0:
+        elif self.flash and s % 128 == 0 and v.shape[-1] == q.shape[-1]:
             # here and not at the top: a process without such a layer never
             # imports pallas (1.4 s of every cell's set-up, PR 29)
             from .pallas_attention import (band_blocks, edge_blocks,
@@ -280,8 +370,13 @@ class Attention(Layer):
                                 self.window, self.lp.name)
         else:
             path = "dense"
-            reason = "flash is not set" if not self.flash \
-                else f"128 does not divide the sequence length {s}"
+            if not self.flash:
+                reason = "flash is not set"
+            elif s % 128:
+                reason = f"128 does not divide the sequence length {s}"
+            else:
+                reason = (f"a value head of {v.shape[-1]} beside a key head "
+                          f"of {q.shape[-1]}: the kernel takes one width")
             if grp > 1:
                 k, v = (jnp.repeat(a, grp, axis=1) for a in (k, v))
             o = dense_attention(q, k, v, causal=self.causal,
@@ -291,8 +386,48 @@ class Attention(Layer):
         tracer.record("attn.path", now, now, layer=self.lp.name, path=path,
                       reason=reason, window=self.window, live_blocks=live,
                       causal_blocks=half, masked_blocks=masked,
-                      head_dim=int(q.shape[-1]))
+                      head_dim=int(q.shape[-1]), **said)
         return o
+
+    def _apply_latent(self, params, x):
+        """The latent form (the module's docstring has the equations)."""
+        with jax.named_scope("attn_proj_in"):
+            wqa, wqb, wkva, wkvb = [params[i].astype(x.dtype)
+                                    for i in (0, 2, 3, 5)]
+        with jax.named_scope("attn_proj_out"):
+            wo = params[6].astype(x.dtype)
+        b, s, _ = x.shape
+        h, dn, dr, dv = (self.num_heads, self.nope_dim, self.rope_dim,
+                         self.v_dim)
+        with jax.named_scope("attn_proj_in"):
+            with jax.named_scope("mla_q_latent"):
+                cq = rms_norm(x @ wqa.T, params[1], self.norm_eps, False)
+                q = (cq @ wqb.T).reshape(b, s, h, dn + dr)
+            with jax.named_scope("mla_kv_latent"):
+                ckv = x @ wkva.T                        # [c_kv | k_pe]
+                k_pe = ckv[..., self.kv_rank:][:, :, None]  # (B, S, 1, Dr)
+                ckv = rms_norm(ckv[..., :self.kv_rank], params[4],
+                               self.norm_eps, False)
+                kv = (ckv @ wkvb.T).reshape(b, s, h, dn + dv)
+        with jax.named_scope("rope"):
+            q_pe = rotary(q[..., dn:], dr, self.rope_theta)
+            k_pe = rotary(k_pe, dr, self.rope_theta)
+        with jax.named_scope("attn_proj_in"):
+            q = jnp.concatenate([q[..., :dn], q_pe], -1)
+            with jax.named_scope("mla_k_assemble"):
+                k = jnp.concatenate(
+                    [kv[..., :dn], jnp.broadcast_to(k_pe, (b, s, h, dr))],
+                    -1)
+            q, k, v = [jnp.moveaxis(a, 1, 2)                # (B, H, S, D)
+                       for a in (q, k, kv[..., dn:])]
+        with jax.named_scope("attn_core"):
+            o = self._core(
+                q, k, v, form="latent", q_rank=self.q_rank,
+                kv_rank=self.kv_rank, nope_dim=dn, rope_dim=dr, v_dim=dv,
+                shared_key_bytes=b * s * h * dr * x.dtype.itemsize)
+        with jax.named_scope("attn_proj_out"):
+            o = jnp.moveaxis(o, 2, 1).reshape(b, s, h * dv)
+            return [o @ wo.T]
 
     def _apply_gqa(self, params, x):
         # the weights are cast first, as before the scopes: the traced

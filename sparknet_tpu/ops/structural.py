@@ -225,6 +225,33 @@ class Tile(Layer):
 
 
 @register
+class Shift(Layer):
+    """sparknet_tpu extension: y[i] = x[i + offset] along `axis`, `fill`
+    where that reads past an end (models/zoo.py:_lm_stack: the labels of a
+    second prediction depth)."""
+    type_name = "Shift"
+
+    def __init__(self, lp, bottom_shapes, phase):
+        super().__init__(lp, bottom_shapes, phase)
+        sp = lp.shift_param
+        self.axis = self.canonical_axis(sp.axis)
+        self.offset = int(sp.offset)
+        self.fill = float(sp.fill)
+
+    def out_shapes(self):
+        return [tuple(self.bottom_shapes[0])]
+
+    def apply(self, params, bottoms, train, rng):
+        x = bottoms[0]
+        at = jnp.arange(x.shape[self.axis]) + self.offset
+        at = at.reshape([-1 if a == self.axis else 1
+                         for a in range(x.ndim)])
+        inside = (at >= 0) & (at < x.shape[self.axis])
+        return [jnp.where(inside, jnp.roll(x, -self.offset, self.axis),
+                          jnp.asarray(self.fill, x.dtype))]
+
+
+@register
 class ArgMax(Layer):
     type_name = "ArgMax"
 

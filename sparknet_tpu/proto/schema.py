@@ -238,6 +238,7 @@ MESSAGES = {
                                   None),
         "short_conv_param": (205, "ShortConvParameter", "opt", None),
         "mamba2_param": (206, "Mamba2Parameter", "opt", None),
+        "shift_param": (207, "ShiftParameter", "opt", None),
     },
     "TransformationParameter": {
         "scale": (1, "float", "opt", 1.0),
@@ -613,6 +614,29 @@ MESSAGES = {
         # not by weight_filler (a model that scales its residual branches'
         # last matrix down at the start)
         "out_filler": (19, "FillerParameter", "opt", None),
+        # multi-head latent attention (ops/attention.py; with causal):
+        # naming kv_lora_rank selects it, and it needs all five. Queries
+        # through a latent of q_lora_rank, keys and values through one of
+        # kv_lora_rank, each with an RMSNorm (norm_eps) of its own; a head
+        # is [qk_nope_head_dim | qk_rope_head_dim], rotate-half rotary
+        # (rope_theta) on the last qk_rope_head_dim alone, the key's rotary
+        # part ONE vector a token that all num_heads heads share; a value
+        # head has v_head_dim. It has no meaning with window, ring,
+        # index_heads, output_gate, qk_norm, num_kv_heads or rotary_dim.
+        "q_lora_rank": (20, "uint32", "opt", None),
+        "kv_lora_rank": (21, "uint32", "opt", None),
+        "qk_nope_head_dim": (22, "uint32", "opt", None),
+        "qk_rope_head_dim": (23, "uint32", "opt", None),
+        "v_head_dim": (24, "uint32", "opt", None),
+    },
+    # sparknet_tpu extension: y[i] = x[i + offset] along `axis`; the places
+    # that read past either end hold `fill` (a second prediction depth's
+    # labels: the next tokens moved one place, the last place a label the
+    # loss ignores).
+    "ShiftParameter": {
+        "axis": (1, "int32", "opt", 1),
+        "offset": (2, "int32", "opt", 1),
+        "fill": (3, "float", "opt", 0.0),
     },
     # sparknet_tpu extension: last-axis RMS norm, y = x / rms(x) * (1 + w)
     # (zero_centered, w filled with 0) or * w (w filled with 1).

@@ -173,6 +173,14 @@ class Solver:
                 now = self.tracer.now_ns()
                 self.tracer.record("net.parts", now, now, net=self.net.name,
                                    parts=self.net.parts())
+                # a multi-token-prediction module, where the net has one
+                depths = self.net.prediction_depths()
+                if depths:
+                    self.tracer.record(
+                        "lm.mtp", now, now, net=self.net.name,
+                        depth=len(depths), loss_weight=depths[0][1],
+                        losses=[name for name, _ in depths],
+                        shared=self.net.shared_params())
             if remat is not None:
                 # the policy `set_remat` takes, said where the solver is
                 # built (no jit exists yet, so nothing to rebuild)

@@ -1,6 +1,7 @@
 """What the language-model family files (`test_qwen3_next.py`,
 `test_smallthinker.py`, `test_lfm2_moe.py`, `test_keye_vl2.py`,
-`test_nemotron_h.py`) share; pytest collects nothing here.
+`test_nemotron_h.py`, `test_glm4_moe_lite.py`) share; pytest collects
+nothing here.
 
 A family is a `Family` record: its builder in `models/zoo.py`, its plain
 reference (`benchmark/reference/<family>.py`, imported from where it lies,
@@ -279,6 +280,28 @@ NEMOTRON_H = Family(
     adapt=_nemotron_args, step_tol=(5e-5, 5e-3, 1e-12))
 
 
+_GLM4_MOE_LITE = dict(
+    hidden_size=32, intermediate_size=48, moe_intermediate_size=16,
+    num_hidden_layers=5, first_k_dense_replace=1, num_attention_heads=4,
+    q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=12,
+    qk_rope_head_dim=4, v_head_dim=16, rope_theta=1e6, rms_norm_eps=1e-5,
+    n_routed_experts=4, num_experts_per_tok=2, n_shared_experts=1,
+    norm_topk_prob=True, routed_scaling_factor=1.8,
+    num_nextn_predict_layers=0, vocab_size=64, router_outputs=16,
+    first_expert=0, seq_len=64)
+
+#: the leading dense block is a body of its own, the four MoE blocks one
+#: run; a prediction module's block (`num_nextn_predict_layers` 1) stands
+#: after the loss and joins no run
+GLM4_MOE_LITE = Family(
+    "glm4_moe_lite", zoo.glm4_moe_lite, _GLM4_MOE_LITE, "n_routed_experts",
+    lambda **args: _config(
+        _GLM4_MOE_LITE, (), published={"n_routed_experts": 16},
+        builder_args=dict({"seq_len": 64}, **args)),
+    over=dict(flash=False), step_tol=(5e-5, 5e-3, 1e-12),
+    runs=(dict(n=4, glen=6, entry="block0/res2"),))
+
+
 # ------------------------------------------ the bodies of the shared tests
 
 def three_adam_steps(fam, over=None, config=None, edit=None, **solver_args):
@@ -428,7 +451,9 @@ def stack_contract(net):
     """`models/zoo.py:_lm_stack`'s naming contract on a built net: the
     layers of a block lie together under one "block{i}/" prefix, a block
     reads one blob from outside itself, the boundary of the block before,
-    and one of its tops is read outside it."""
+    and one of its tops is read outside it. A prediction module's block
+    ("block_mtp{k}/", after the loss) reads the top of the layer just
+    before it instead, which nothing else reads."""
     blocks, order = {}, []
     for lp in net.layer:
         if "/" in lp.name:
@@ -440,9 +465,14 @@ def stack_contract(net):
     assert order and all(p.startswith("block") for p in order), order
     first = next(i for i, lp in enumerate(net.layer) if "/" in lp.name)
     boundary = net.layer[first - 1].top[0]
+    names = [lp.name for lp in net.layer]
     for p in order:
         tops = {t for lp in blocks[p] for t in lp.top}
         outside = {b for lp in blocks[p] for b in lp.bottom} - tops
+        if p.startswith("block_mtp"):
+            (boundary,) = net.layer[names.index(blocks[p][0].name) - 1].top
+            assert [lp.name for lp in net.layer if boundary in lp.bottom
+                    and not lp.name.startswith(p + "/")] == [], p
         assert outside == {boundary}, (p, outside)
         read = {b for lp in net.layer if not lp.name.startswith(p + "/")
                 for b in lp.bottom} & tops

@@ -173,9 +173,14 @@ def test_the_readers_list_of_inner_scopes_is_the_programs():
     # ops/pallas_dsa.py) are not the benchmark's: an operation under one of
     # them counts under the layer's part, `attn`, which keeps the ledger
     # closed without an edit to `INNER`
-    # nor are the state-space mixer's (PR 42): they count under `ssm`
-    assert {s for s in opened if not s.startswith(("dsa_", "ssm_"))} == \
+    # nor are the state-space mixer's (PR 42): they count under `ssm`; nor
+    # the latent attention's three (PR 46), which lie INSIDE `attn_proj_in`
+    # and count under it (the deepest element of `INNER` on a path decides)
+    assert {s for s in opened
+            if not s.startswith(("dsa_", "ssm_", "mla_"))} == \
         set(step_parts.INNER)
+    assert {s for s in opened if s.startswith("mla_")} == {
+        "mla_q_latent", "mla_kv_latent", "mla_k_assemble"}
     assert {s for s in opened if s.startswith("dsa_")} == \
         {"dsa_index_proj", "dsa_stats"}
     assert {s for s in opened if s.startswith("ssm_")} == {

@@ -96,7 +96,9 @@ GQA_SHAPES = {
     # 32 over 8 of 64: every tile half a lane row wide
     "lfm2_head64": (3, 32, 8, 64),
     # 32 over 2 of 128: sixteen query heads a key-value head
-    "nemotron_g16": (2, 32, 2, 128)}
+    "nemotron_g16": (2, 32, 2, 128),
+    # the latent attention's: 20 over 20 of 256, every head a key of its own
+    "glm_mla": (1, 20, 20, 256)}
 
 
 @pytest.mark.parametrize("shape", list(GQA_SHAPES))
@@ -325,6 +327,42 @@ def test_mamba2_layer_compiles_to_the_kernel_pair(one_chip, monkeypatch):
         assert re.search(rf"ssm_scan[^\"]*/{kernel}/pallas_call", text), kernel
     assert not re.search(r"ssm_scan[^\"]*/while", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
+
+
+def test_latent_attention_layer_compiles_to_the_flash_kernels(one_chip,
+                                                              monkeypatch):
+    """One latent attention's forward and backward at the GLM cell's shape
+    (8,192 tokens of 2,048 in bfloat16, 20 heads of 192 + 64 | 256, ranks
+    768 and 512): the three flash kernels under `attn_core`, the key's
+    assembly under `mla_k_assemble` inside `attn_proj_in`, and temporaries
+    of 0.70 GB when this test was written (q, k, v and their gradients at
+    20 heads of 256 are 84 MB each)."""
+    from sparknet_tpu.graph.registry import get as get_layer
+    from sparknet_tpu.models import dsl
+    monkeypatch.setattr(pa, "_should_interpret", lambda: False)
+    lp = dsl.AttentionLayer("attn", ["x"], 20, causal=True, flash=True,
+                            q_lora_rank=768, kv_lora_rank=512,
+                            qk_nope_head_dim=192, qk_rope_head_dim=64,
+                            v_head_dim=256, rope_theta=1e6, norm_eps=1e-5)
+    x = ((1, 8192, 2048), jnp.bfloat16)
+    impl = get_layer(lp.type)(lp, [x[0]], 0)
+    assert sum(functools.reduce(lambda a, b: a * b, s[0])
+               for s in impl.param_shapes()) == 21_759_232
+    blobs = [(s[0], jnp.float32) for s in impl.param_shapes()]
+
+    def grads(x, cot, *blobs):
+        def loss(x, blobs):
+            y = impl.apply(list(blobs), [x], True, None)[0]
+            return jnp.sum(y.astype(jnp.float32) * cot)
+        return jax.grad(loss, (0, 1))(x, blobs)
+
+    compiled = _compile(grads, one_chip, x, (x[0], jnp.float32), *blobs,
+                        kernels=["flash_fwd", "flash_dq", "flash_dkv"])
+    text = compiled.as_text()
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert re.search(rf"attn_core[^\"]*/{kernel}", text), kernel
+    assert re.search(r"attn_proj_in\)+/mla_k_assemble/", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.95e9
 
 
 # LRN where CaffeNet runs it (after each pool) and at GoogLeNet's conv2

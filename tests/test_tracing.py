@@ -158,6 +158,55 @@ def test_a_solver_without_a_tracer_records_into_the_default_one():
     assert len(default_tracer().since(before, "solver.step")) == 1
 
 
+def _toy_glm(**over):
+    return zoo.glm4_moe_lite(
+        vocab_size=64, seq_len=32, batch_size=2, hidden_size=32,
+        intermediate_size=48, moe_intermediate_size=16, num_hidden_layers=2,
+        num_attention_heads=4, q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16,
+        n_routed_experts=16, num_experts_per_tok=2, experts_held=4,
+        flash=False, **over)
+
+
+def test_a_latent_attention_says_its_form_and_what_the_shared_key_costs():
+    """`attn.path` of a latent layer: the fields every attention writes,
+    and `form`, the five sizes and `shared_key_bytes` — one pass's write of
+    the ONE rotary key a token over the heads (2 x 32 tokens x 4 heads x 4
+    dimensions in float32 here; 0 once a kernel reads it in place)."""
+    mark = default_tracer().mark()
+    s = _solver(_toy_glm(num_nextn_predict_layers=0), type="Adam")
+    rs = np.random.RandomState(0)
+    ids = rs.randint(0, 64, (2, 33)).astype(np.int32)
+    s.train_step({"data": ids[:, :-1], "label": ids[:, 1:]})
+    recs = default_tracer().since(mark, "attn.path")
+    assert {r["layer"] for r in recs} == {"block0/attn", "block1/attn"}
+    for r in recs:
+        assert (r["form"], r["q_rank"], r["kv_rank"], r["nope_dim"],
+                r["rope_dim"], r["v_dim"]) == ("latent", 24, 16, 12, 4, 16)
+        assert r["shared_key_bytes"] == 2 * 32 * 4 * 4 * 4
+        assert (r["path"], r["head_dim"], r["window"]) == ("dense", 16, 0)
+    # a net without the module says nothing of one
+    assert default_tracer().since(mark, "lm.mtp") == []
+
+
+def test_a_net_with_a_prediction_module_says_so_once_a_net():
+    """`lm.mtp`, beside `net.parts` where the solver builds its net: how
+    many depths, their loss weight and losses, and the blobs the module
+    shares with the main model."""
+    tr = Tracer(None)
+    _solver(_toy_glm(), tracer=tr, type="Adam")
+    (rec,) = tr.spans("lm.mtp")
+    assert (rec["net"], rec["depth"], rec["losses"]) == \
+        ("GLM4MoELite", 1, ["mtp1_loss"])
+    assert rec["loss_weight"] == pytest.approx(0.3)
+    assert rec["shared"] == ["lm_head_table", "tok_embed_table"]
+    parts = tr.spans("net.parts")[-1]["parts"]
+    assert (parts["mtp1_lm_head"], parts["mtp1_ln_f"], parts["mtp1_proj"],
+            parts["mtp1_embed"], parts["mtp1_label"], parts["mtp1_cat"]) == \
+        ("head", "final_norm", "proj", "embed", "shape", "shape")
+    assert parts["block_mtp1/attn"] == "attn"
+
+
 @pytest.mark.parametrize("filled", [0, 5, 8, 20])
 def test_records_since_a_mark_survive_the_rings_wrap(filled):
     """A count of `spans(name)` stops meaning "new since then" once the
