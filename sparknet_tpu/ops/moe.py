@@ -35,8 +35,11 @@ Two forms, chosen by moe_param.gated_experts:
   unpadded; they run a WINDOW of rows at a time (`window_rows`, static:
   the pairs an even routing sends here and a quarter more, in whole
   tiles of `tile_rows`, at most `WINDOW_TILES` tiles; a layer that names no
-  `tile_rows` takes `fit_tile`'s: 128, or the multiple of it at which that
-  quarter more still fits one window), as many windows
+  `tile_rows` takes `fit_tile`'s, from the rows an even routing sends one
+  held expert: 512 from 3,072 rows, 256 from 512, else 128, as the cells'
+  steps were timed; never one so small that the quarter more misses one
+  window, and on the kernels' path none whose blocks miss VMEM at the
+  layer's widths: `kernel_tile`), as many windows
   as the routing needs: a loop of dynamic length, so memory is bound by
   one window's buffers and work by the routing, neither by the worst
   case of all tokens x top_k rows. A window is one gather of its rows of
@@ -72,8 +75,9 @@ Two forms, chosen by moe_param.gated_experts:
   a trace of the layer (`path` = `kernel` or `xla`, with the `reason`,
   the experts' `activation`, the route's `score`, whether it has a
   `selection_bias`, the combine's form: `combine` = `gather`,
-  `segment` = J, the expert's `matrices`, 3 or 2, and the shared expert's
-  gate, `shared_gate`). With
+  `segment` = J, the row `tile` with the `rows_an_expert` it was fitted to
+  and the `window`'s rows, the expert's `matrices`, 3 or 2, and the shared
+  expert's gate, `shared_gate`). With
   shared_hidden_dim > 0 a shared expert sees every token:
   shared = sigmoid(w_s . x) W_down (SiLU(W_gate x) * W_up x), and
   y = routed + shared. Tops: [output] or [output, stats]; stats (weight
@@ -203,14 +207,59 @@ def window_rows(n_tokens, top_k, held, num_experts, tile):
     return min(WINDOW_TILES, math.ceil(min(1.25 * even, most) / tile)) * tile
 
 
-def fit_tile(n_tokens, top_k, held, num_experts, tile=128):
-    """The row tile of a layer that names none: `tile`, in as many whole
-    multiples as it takes for an even routing's pairs and a quarter more to
-    fit the WINDOW_TILES tiles of one window. At 128 rows a share of 32,768
-    even pairs is the cap itself, and every other step spills a few rows
-    into a second window (18 ms, two step times in one run, PR 40)."""
-    even = n_tokens * top_k * held / num_experts
-    return tile * max(1, math.ceil(1.25 * even / (WINDOW_TILES * tile)))
+# the row tiles a layer that names none may get, and the fewest rows an
+# expert from which it takes each: where the tile read faster than the one
+# below it INSIDE a cell's step, settled by timing the products alone at
+# the six LM cells' shapes and then the cells (`scripts/bench_gmm.py`;
+# PERF.md section 6, PR 47). By the products 256 reads a fifth faster than
+# 128 at 512 and 768 rows an expert and no faster at 320; 512 reads 14% and
+# 7% faster than 256 at 3,072 and 5% slower at 512. At 2,048 the products
+# alone read 6% faster at 512, the layer whole and the cell's step tie
+# (2,041.7 -> 2,040.9 ms, the traced scope 55.3 -> 54.9): a tie keeps the
+# smaller tile, and nothing was timed between 2,048 and 3,072
+ROW_TILES = (128, 256, 512)
+LEAST_ROWS = (0, 512, 3072)
+
+
+def fit_tile(n_tokens, top_k, held, num_experts):
+    """The row tile of a layer that names none, from the rows an even
+    routing sends ONE held expert, n_tokens x top_k / num_experts: the
+    largest of ROW_TILES whose LEAST_ROWS such a group reaches. The grouped
+    product streams a group's whole weight matrices through VMEM once for
+    every row tile it visits, so a tile of t rows does t operations for
+    every byte of bfloat16 weights, and a v5e wants 240: at 128 the
+    products wait for the weights, from 256 on the MXU sets the pace and a
+    larger tile saves a visit's fixed part. But a group's last tile is
+    shared with the next group and visited once for each, every visit a
+    whole tile's products, so a tile that is a large part of a group wastes
+    what it saves: 256 pays from two whole tiles a group, 512 was seen to
+    pay at six and not at four. Never a tile so small that an even
+    routing's pairs and a quarter more miss the WINDOW_TILES tiles of one
+    window: at 128 rows a share of 32,768 even pairs is the cap itself,
+    and every other step spills a few rows into a second window (18 ms, two
+    step times in one run, PR 40)."""
+    rows, least = n_tokens * top_k / num_experts, ROW_TILES[0]
+    by_group = max(t for t, r in zip(ROW_TILES, LEAST_ROWS) if rows >= r)
+    by_window = least * math.ceil(
+        1.25 * rows * held / (WINDOW_TILES * least))
+    # the next of ROW_TILES, so that a tile nobody timed or compiled (384)
+    # comes out only where even the largest is too small for one window
+    by_window = min((t for t in ROW_TILES if t >= by_window),
+                    default=by_window)
+    return max(by_group, by_window)
+
+
+def kernel_tile(tile, embed, hidden, itemsize):
+    """`tile` where the kernels' blocks at these widths and this compute
+    type fit VMEM at that row tile (`pallas_moe.fits`), else the largest of
+    ROW_TILES below it at which they do: `fit_tile` reads the routing
+    alone, and the float32 output block of the wider product grows with the
+    tile (2,688 x 1,920 takes 256 and not 512 in bfloat16 and 128 in
+    float32, 2,048 x 1,792 in float32 256). Where none fits the tile
+    stays, and the compiler says so as it did before any tile was fitted."""
+    from .pallas_moe import fits
+    return next((t for t in (tile, *reversed(ROW_TILES))
+                 if t <= tile and fits(t, embed, hidden, itemsize)), tile)
 
 
 def plan_windows(pair_expert, held, window):
@@ -765,6 +814,13 @@ class MoE(Layer):
         x = bottoms[0]
         b, s, e = x.shape
         n, k, held = b * s, self.top_k, self.held
+        why_xla = self._why_xla(x.dtype)
+        # a hidden width off the lane width: the kernels see zero columns
+        # up to the next multiple of 128, the blobs do not
+        pad = -self.hidden % 128 if why_xla is None else 0
+        tile = self.tile
+        if why_xla is None and not self.p.has("tile_rows"):
+            tile = kernel_tile(tile, e, self.hidden + pad, x.dtype.itemsize)
         with jax.named_scope("moe_glue"):
             xt = x.reshape(n, e)
         with jax.named_scope("moe_route"):
@@ -775,14 +831,10 @@ class MoE(Layer):
             local = idx.reshape(n * k) - self.first
             pair_expert = jnp.where((local >= 0) & (local < held), local,
                                     held).astype(jnp.int32)
-            window = window_rows(n, k, held, self.num_experts, self.tile)
+            window = window_rows(n, k, held, self.num_experts, tile)
             plan = plan_windows(pair_expert, held, window)
-        why_xla = self._why_xla(x.dtype)
         tracer = default_tracer()
         now = tracer.now_ns()
-        # a hidden width off the lane width: the kernels see zero columns
-        # up to the next multiple of 128, the blobs do not
-        pad = -self.hidden % 128 if why_xla is None else 0
         tracer.record("moe.path", now, now, layer=self.lp.name,
                       path="xla" if why_xla else "kernel",
                       reason=why_xla or "backend, widths and tile_rows fit"
@@ -791,6 +843,8 @@ class MoE(Layer):
                       activation=self.act, score=self.score,
                       selection_bias=self.selection_bias,
                       combine="gather", segment=min(k, held),
+                      tile=tile, window=window,
+                      rows_an_expert=round(n * k / self.num_experts),
                       matrices=3 if self.gate_matrix else 2,
                       shared_gate=bool(self.shared_hidden)
                       and self.shared_gate)
@@ -808,7 +862,7 @@ class MoE(Layer):
         with jax.named_scope("moe_glue"):
             wg, wu, wd = cast("w_gate", 1), cast("w_up", 1), cast("w_down", 2)
             y = held_experts(xt, top.reshape(n * k), plan, wg, wu, wd,
-                             self.tile, k, window, why_xla is None, self.act)
+                             tile, k, window, why_xla is None, self.act)
         if self.shared_hidden:
             with jax.named_scope("moe_shared"):
                 y = y + self._shared(xt, blob)

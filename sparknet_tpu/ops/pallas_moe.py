@@ -75,6 +75,30 @@ def _fitting_divisors(k, n, most):
                key=lambda b: (b[0] * b[1], min(b)))
 
 
+# the VMEM a kernel gets unasked on a v5e
+_VMEM = 16 << 20
+
+
+def fits(tile, embed, hidden, itemsize):
+    """Whether every grouped product of an expert of `embed` x `hidden`
+    fits VMEM at a row tile of `tile`, operands of `itemsize` bytes: both
+    operand blocks in two buffers each; `gmm`'s float32 output block in two
+    and its accumulator, `tgmm`'s output, the running total it adds to (two
+    each) and its accumulator. Against compiles for the described chip at
+    the six cells' widths, tiles 128 to 512, both types (PR 47) this says
+    no wherever the compiler does, and no to one expert it takes (2,048 x
+    512 in bfloat16 at 512)."""
+    def gmm_bytes(k, n):
+        tk, tn = _blocks(k, n, _GMM_BLOCK)
+        return 2 * itemsize * (tile * tk + tk * tn) + 3 * 4 * tile * tn
+
+    def tgmm_bytes(k, n):
+        tk, tn = _blocks(k, n, _TGMM_BLOCK)
+        return 2 * itemsize * tile * (tk + tn) + 5 * 4 * tk * tn
+    return max(gmm_bytes(embed, hidden), gmm_bytes(hidden, embed),
+               tgmm_bytes(embed, hidden), tgmm_bytes(hidden, embed)) < _VMEM
+
+
 def grouped_dot(sizes, tile, lhs, rhs, transpose_rhs, name):
     """lhs (m, k) against rhs (groups, k, n), or (groups, n, k) with
     `transpose_rhs`: row r of group e times rhs[e] -> (m, n) float32, in
@@ -127,8 +151,12 @@ def _halo(segment):
 
 def segment_block(window, segment):
     """Rows a block of `segment_add`, or 0 where none fits (a window of
-    few, odd tiles under a long segment: the caller keeps XLA's form)."""
-    block = math.gcd(window, _SEGMENT_ROWS)
+    few, odd tiles under a long segment: the caller keeps XLA's form).
+    Half of _SEGMENT_ROWS at most under a segment of more than 8 rows:
+    512 rows of 1,024 lanes and their shifted copies miss VMEM from 9 on
+    (18 MB; segments of 9, 10, 12, 16, 17 and 32 compiled for the described
+    chip, PR 47; at 8, and at 256 rows up to 32, they fit)."""
+    block = math.gcd(window, _SEGMENT_ROWS // (1 if segment <= 8 else 2))
     return block if block % 8 == 0 and block >= _halo(segment) else 0
 
 

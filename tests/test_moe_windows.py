@@ -145,6 +145,51 @@ def test_held_experts_across_window_boundaries(case, kernel):
         close(a, b)
 
 
+@pytest.mark.parametrize("matrices,act", [(3, "silu"), (2, "relu2")],
+                         ids=["three_matrices", "two_matrices"])
+@pytest.mark.parametrize("tile", [8, 32])
+def test_a_row_tile_larger_than_some_groups(tile, matrices, act):
+    """The kernels (interpret mode) at a row tile that is larger than some
+    groups, with an empty group: groups of 0, 3, 19 and 40 rows in one
+    window of 64, which at a tile of 32 puts two groups and a part of the
+    third into the first tile and ends the last group six rows into the
+    second. Forward and every gradient against the same window through
+    `lax.ragged_dot_general`."""
+    counts, n, top_k, window = [0, 3, 19, 40], 24, 4, 64
+    rng = np.random.RandomState(tile + matrices)
+    pairs = np.concatenate([np.repeat(np.arange(HELD), counts),
+                            np.full(n * top_k - sum(counts), HELD)])
+    pair_expert = jnp.asarray(rng.permutation(pairs), jnp.int32)
+    kx, kp, kw, kc = jax.random.split(jax.random.PRNGKey(tile), 4)
+    x = jax.random.normal(kx, (n, E))
+    pair_weight = jax.random.uniform(kp, (n * top_k,), minval=0.1)
+    cot = jax.random.normal(kc, (n, E))
+    plan = moe_ops.plan_windows(pair_expert, HELD, window)
+    assert int(plan["windows"]) == 1
+    np.testing.assert_array_equal(np.asarray(plan["count"]), counts)
+    wg, wu, wd = weights(kw)
+    if matrices == 2:
+        wg = None
+
+    def layer(kernel):
+        return jax.vjp(lambda x, pw, wg, wu, wd: moe_ops.held_experts(
+            x, pw, plan, wg, wu, wd, tile, top_k, window, kernel, act),
+            x, pair_weight, wg, wu, wd)
+    got, vjp = layer(True)
+    want, vjp_want = layer(False)
+    assert np.asarray(want).any()
+    close(got, want)
+    grads, grads_want = vjp(cot), vjp_want(cot)
+    assert len(grads) == 5 and (grads[2] is None) == (matrices == 2)
+    for a, b in zip(grads, grads_want):
+        if b is not None:
+            assert np.asarray(b).any()
+            close(a, b)
+    # the empty group's weights get no gradient, to the bit
+    for g in grads[2:]:
+        assert g is None or not np.asarray(g[0]).any()
+
+
 def scatter_oracle(x, pair_weight, pair_expert, wg, wu, wd, top_k):
     """The combine as a scatter-add: each held pair's row through its
     expert, times its weight, added onto its token."""
@@ -329,6 +374,11 @@ def test_segment_add_has_no_block_for_a_long_segment_in_a_short_window():
     assert pallas_moe.segment_block(24, 10) == 0
     assert pallas_moe.segment_block(6400, 10) == 256
     assert pallas_moe.segment_block(30720, 6) == 512
+    # a segment above 8 rows in blocks of 256 at most: 512 of them and their
+    # shifted copies miss VMEM (tests/test_tpu_compile.py compiles
+    # Qwen3-Next's net at batch 8, a window of 50 x 512 rows)
+    assert pallas_moe.segment_block(40960, 8) == 512
+    assert pallas_moe.segment_block(25600, 10) == 256
 
 
 def test_window_rows_is_static_and_sized_by_an_even_routing():
@@ -355,16 +405,44 @@ def test_window_rows_is_static_and_sized_by_an_even_routing():
 
 
 @pytest.mark.parametrize("shape,top_k,held,experts,named,tile", [
-    ((2, 8192, 128), 10, 16, 512, None, 128),    # the Qwen3-Next cell
-    ((2, 16384, 128), 6, 8, 64, None, 128),      # the SmallThinker cell
-    ((3, 8192, 128), 4, 8, 32, None, 128),       # the LFM2 cell
-    # 32,768 even pairs: a quarter more is 40,960 rows, 256 tiles of 160 do
-    # not exist, so two 128s a tile (the Keye cell; the LFM2 net at batch 4)
-    ((1, 32768, 128), 8, 16, 128, None, 256),
-    ((4, 8192, 128), 4, 8, 32, None, 256),
+    # 3,072 rows an expert: six whole tiles of 512, and the experts' weights
+    # are fetched once for 512 rows and not once for 128 (PR 47)
+    pytest.param((3, 8192, 128), 4, 8, 32, None, 512, id="lfm2"),
+    pytest.param((2, 16384, 128), 6, 8, 64, None, 512, id="smallthinker"),
+    # 2,048 rows an expert: 512 tied with 256 in the cell's step, and the
+    # tie keeps the tile the cell had: 32,768 even pairs, whose quarter more
+    # is 40,960 rows, 256 tiles of 160, so never under two 128s a tile
+    pytest.param((1, 32768, 128), 8, 16, 128, None, 256, id="keye"),
+    # 320 rows an expert keep 128; 768 and 512 take 256
+    pytest.param((2, 8192, 128), 10, 16, 512, None, 128, id="qwen3_next"),
+    pytest.param((2, 8192, 128), 6, 8, 128, None, 256, id="nemotron"),
+    pytest.param((1, 8192, 128), 4, 8, 64, None, 256, id="glm"),
+    # the LFM2 net at batch 4: 4,096 rows an expert, and the window's floor
+    # of 256 under it
+    pytest.param((4, 8192, 128), 4, 8, 32, None, 512, id="lfm2_batch_4"),
+    # the edges: 3,072 rows an expert take 512 and one row fewer 256, 512
+    # take 256 and one fewer 128
+    pytest.param((3, 8192, 128), 8, 8, 64, None, 512, id="from_3072_rows"),
+    pytest.param((3, 8184, 128), 8, 8, 64, None, 256, id="a_row_short_of_it"),
+    pytest.param((1, 4096, 128), 8, 8, 64, None, 256, id="from_512_rows"),
+    pytest.param((1, 4088, 128), 8, 8, 64, None, 128, id="a_row_short_of_512"),
+    # few rows an expert, but a share so wide that 128s miss one window:
+    # the floor alone sets the tile
+    pytest.param((1, 32768, 128), 2, 128, 256, None, 256,
+                 id="the_floor_of_one_window"),
+    # 128s and 256s miss the window, 384 would not: the next on the list, so
+    # that only tiles that were timed and compiled come out ...
+    pytest.param((1, 32768, 128), 3, 192, 256, None, 512,
+                 id="a_floor_off_the_list"),
+    # ... save where the list's largest misses it too
+    pytest.param((1, 65536, 128), 4, 128, 256, None, 640,
+                 id="a_floor_above_the_list"),
+    # a toy layer's groups are smaller than any tile: 128
+    pytest.param((1, 16, 128), 2, 4, 8, None, 128, id="toy"),
     # a tile the net names is the layer's, whatever spills
-    ((1, 32768, 128), 8, 16, 128, 128, 128),
-    ((1, 16, 128), 2, 4, 8, 8, 8)])
+    pytest.param((1, 32768, 128), 8, 16, 128, 128, 128, id="named_128"),
+    pytest.param((3, 8192, 128), 4, 8, 32, 128, 128, id="named_under_rule"),
+    pytest.param((1, 16, 128), 2, 4, 8, 8, 8, id="named_8")])
 def test_a_layer_that_names_no_tile_gets_one_its_even_share_fits(
         shape, top_k, held, experts, named, tile):
     lp = dsl.MoELayer("moe", ["x"], experts, hidden_dim=128, top_k=top_k,
@@ -374,8 +452,53 @@ def test_a_layer_that_names_no_tile_gets_one_its_even_share_fits(
     n = shape[0] * shape[1]
     even = n * top_k * held / experts
     if named is None:
-        assert moe_ops.window_rows(n, top_k, held, experts, tile) \
-            >= 1.25 * even
+        # one window still takes an even routing and a quarter more (or
+        # every pair that can land here, where that is less) ...
+        want = min(1.25 * even, n * min(top_k, held))
+        assert moe_ops.window_rows(n, top_k, held, experts, tile) >= want
+        # ... and a tile above 128 is one the expected group reaches the
+        # least rows of, unless the next tile down would miss that window
+        below = max([t for t in moe_ops.ROW_TILES if t < tile], default=128)
+        least = dict(zip(moe_ops.ROW_TILES, moe_ops.LEAST_ROWS))
+        assert tile == 128 or n * top_k / experts >= least.get(tile, 1e9) \
+            or moe_ops.window_rows(n, top_k, held, experts, below) < want
+
+
+@pytest.mark.parametrize("widths,itemsize,fitted,tile", [
+    # the six cells' widths in their compute type, bfloat16, at the tile
+    # their routing fits: the blocks fit VMEM, the tile stays
+    pytest.param((2048, 1792), 2, 512, 512, id="lfm2"),
+    pytest.param((2560, 768), 2, 512, 512, id="smallthinker"),
+    pytest.param((2048, 768), 2, 256, 256, id="keye"),
+    pytest.param((2048, 512), 2, 128, 128, id="qwen3_next"),
+    pytest.param((2688, 1920), 2, 256, 256, id="nemotron"),
+    pytest.param((2048, 1536), 2, 256, 256, id="glm"),
+    # 3,072 rows an expert and more fit 512 by the routing; at 2,688 x 1,920
+    # the float32 output block (512, 2688), twice and its accumulator, is
+    # 16.5 MB alone, and 512 x 2,048's is 12 MB beside 5 MB of operands
+    pytest.param((2688, 1920), 2, 512, 256, id="nemotron_3072_rows"),
+    pytest.param((2048, 512), 2, 512, 256, id="qwen3_next_3072_rows"),
+    # float32 operands are twice the bytes: a tile down, at Nemotron's
+    # widths from its own cell's 256
+    pytest.param((2048, 1792), 4, 512, 256, id="lfm2_float32"),
+    pytest.param((2560, 768), 4, 512, 256, id="smallthinker_float32"),
+    pytest.param((2688, 1920), 4, 256, 128, id="nemotron_float32"),
+    pytest.param((2048, 768), 4, 512, 512, id="keye_float32"),
+    # a floor above the list that fits stays; one that does not comes down
+    # to the list
+    pytest.param((2048, 768), 2, 640, 640, id="above_the_list"),
+    pytest.param((2688, 1920), 2, 640, 256, id="above_the_list_too_wide")])
+def test_a_fitted_tile_comes_down_to_what_the_kernels_blocks_fit(
+        widths, itemsize, fitted, tile):
+    """`fit_tile` reads the routing alone; on the kernels' path the layer
+    takes the largest tile not above it whose blocks fit VMEM at its
+    widths and compute type (tests/test_tpu_compile.py compiles both
+    sides of the edge for the described chip)."""
+    from sparknet_tpu.ops import pallas_moe
+    assert moe_ops.kernel_tile(fitted, *widths, itemsize) == tile
+    assert pallas_moe.fits(tile, *widths, itemsize)
+    if tile < fitted:
+        assert not pallas_moe.fits(fitted, *widths, itemsize)
 
 
 # What the window of a THREE-matrix expert traces (forward and backward,
@@ -438,7 +561,8 @@ def test_block_sizes_of_the_grouped_products(k, n, most, want):
 
 
 def moe_paths(mark):
-    return [(s["layer"], s["path"], s["reason"], s["combine"], s["segment"])
+    return [(s["layer"], s["path"], s["reason"], s["combine"], s["segment"],
+             s["tile"], s["rows_an_expert"], s["window"])
             for s in default_tracer().since(mark, "moe.path")]
 
 
@@ -471,8 +595,11 @@ def test_layer_takes_the_product_it_can_and_records_it(
         lambda p, x: impl.apply(p, [x], True, None)[0])(
         blobs, jax.ShapeDtypeStruct((1, 16, embed), jnp.float32)))
     # the combine gathers, a token's at most min(top_k, held) = 2 rows of a
-    # window added by segments
-    assert moe_paths(before) == [(name, path, reason, "gather", 2)]
+    # window added by segments; the tile the net named, the 16 x 2 / 8 = 4
+    # rows an even routing sends an expert, and the window of 20 pairs in
+    # whole tiles
+    assert moe_paths(before) == [(name, path, reason, "gather", 2, tile, 4,
+                                  -(-20 // tile) * tile)]
     assert ("pallas_call" in text) == (path == "kernel")
     assert ("ragged_dot" in text) == (path == "xla")
     # one structure either way: a loop of dynamic length over windows, and
@@ -481,3 +608,37 @@ def test_layer_takes_the_product_it_can_and_records_it(
     assert " while[" in text
     assert not re.search(r":f32\[\d+,\d+\] = scatter", text)
     assert "gather" in text and "sort" in text
+
+
+@pytest.mark.parametrize("shape,top_k,experts,hidden,dtype,tile,window", [
+    # the LFM2 cell: 3,072 rows an expert, blocks of (512, 1024, 896)
+    pytest.param((3, 8192, 2048), 4, 32, 1792, jnp.bfloat16, 512, 30720,
+                 id="lfm2"),
+    # Nemotron's widths at batch 8, 3,072 rows an expert: the routing fits
+    # 512 and the blocks do not, in float32 not 256 either
+    pytest.param((8, 8192, 2688), 6, 128, 1856, jnp.bfloat16, 256, 30720,
+                 id="nemotron_batch_8"),
+    pytest.param((8, 8192, 2688), 6, 128, 1856, jnp.float32, 128, 30720,
+                 id="nemotron_batch_8_float32")])
+def test_layer_on_the_kernels_takes_a_tile_their_blocks_fit(
+        monkeypatch, shape, top_k, experts, hidden, dtype, tile, window):
+    """A trace of the layer at a zoo net's real widths on a pretended TPU
+    backend (shapes only: nothing is allocated): `moe.path` carries the
+    tile the kernels got, which is the routing's (`impl.tile`) brought
+    down to what fits VMEM, and the window in whole tiles of it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lp = dsl.MoELayer("moe_fit", ["x"], experts, hidden_dim=hidden,
+                      top_k=top_k, experts_held=8, first_expert=0)
+    impl = get_layer(lp.type)(lp, [shape], 0)
+    n = shape[0] * shape[1]
+    assert impl.tile == moe_ops.fit_tile(n, top_k, 8, experts) == 512
+    blobs = [jax.ShapeDtypeStruct(s[0], jnp.float32)
+             for s in impl.param_shapes()]
+    before = default_tracer().mark()
+    text = str(jax.make_jaxpr(
+        lambda p, x: impl.apply(p, [x], True, None)[0])(
+        blobs, jax.ShapeDtypeStruct(shape, dtype)))
+    (path,) = moe_paths(before)
+    assert path[1] == "kernel"
+    assert path[5:] == (tile, round(n * top_k / experts), window)
+    assert "pallas_call" in text

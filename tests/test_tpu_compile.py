@@ -149,46 +149,86 @@ def test_gdn_chunk_kernels_compile(one_chip, which):
              kernels=["gdn_chunk_bwd"])
 
 
-# the held experts' grouped products at the three LM cells' shapes, bfloat16
-# in, float32 out, tiles of 128: (tokens, top_k, held, experts, embed,
-# hidden, activation, the window's rows, the most temporaries)
-MOE_TILE = 128
+# the held experts' grouped products at the LM cells' shapes, bfloat16 in,
+# float32 out, the row tile `fit_tile` gives the layer: (tokens, top_k,
+# held, experts, embed, hidden, activation, the tile, the window's rows, the
+# most temporaries)
 MOE_SHAPES = {
-    # 2 x 8,192 tokens, top-10 of 512 with 16 held, 2048 x 512, SiLU
-    "qwen3_next": (16384, 10, 16, 512, 2048, 512, "silu", 6400, 1 << 30),
+    # 2 x 8,192 tokens, top-10 of 512 with 16 held, 2048 x 512, SiLU: 320
+    # rows an expert, tiles of 128
+    "qwen3_next": (16384, 10, 16, 512, 2048, 512, "silu", 128, 6400,
+                   1 << 30),
     # 2 x 16,384 tokens, top-6 of 64 with 8 held, 2560 x 768, ReLU: an even
-    # routing's 24,576 pairs and a quarter more in one window
-    "smallthinker": (32768, 6, 8, 64, 2560, 768, "relu", 30720, 3 << 30),
+    # routing's 24,576 pairs and a quarter more in one window, 3,072 rows
+    # an expert in tiles of 512 (PR 47: blocks of (512, 1280, 768))
+    "smallthinker": (32768, 6, 8, 64, 2560, 768, "relu", 512, 30720,
+                     3 << 30),
     # 3 x 8,192 tokens, top-4 of 32 with 8 held, 2048 x 1792, SiLU: the
-    # same 24,576 pairs and the same window, 3,072 rows an expert
-    "lfm2_moe": (24576, 4, 8, 32, 2048, 1792, "silu", 30720, 3 << 30),
+    # same 24,576 pairs, the same window and tile (blocks of (512, 1024,
+    # 896), a float32 output block of 1.8 MB)
+    "lfm2_moe": (24576, 4, 8, 32, 2048, 1792, "silu", 512, 30720, 3 << 30),
+    # 1 x 32,768 tokens, top-8 of 128 with 16 held, 2048 x 768, SiLU: 32,768
+    # even pairs and a quarter more in 160 tiles of 256 (2,048 rows an
+    # expert: 512 tied in the cell's step, and the tie keeps 256)
+    "keye_vl2": (32768, 8, 16, 128, 2048, 768, "silu", 256, 40960, 3 << 30),
     # 2 x 8,192 tokens, top-6 of 128 with 8 held, 2688 x 1856 PADDED TO
     # 1,920 as the layer pads its cast copies, TWO matrices, relu^2: an
-    # even routing's 6,144 pairs and a quarter more in one window
-    "nemotron_h": (16384, 6, 8, 128, 2688, 1920, "relu2", 7680, 1 << 30)}
+    # even routing's 6,144 pairs and a quarter more in one window, 768 rows
+    # an expert in tiles of 256; at these widths a tile of 512 would NOT
+    # fit VMEM (a float32 output block of (512, 2688) twice and its
+    # accumulator: PERF.md section 7), and 768 rows an expert do not ask
+    # for it
+    "nemotron_h": (16384, 6, 8, 128, 2688, 1920, "relu2", 256, 7680,
+                   1 << 30),
+    # 1 x 8,192 tokens, top-4 of 64 with 8 held, 2048 x 1536, SiLU: 512 rows
+    # an expert in tiles of 256, the smallest window any cell has
+    "glm4_moe_lite": (8192, 4, 8, 64, 2048, 1536, "silu", 256, 5120,
+                      1 << 30)}
 
 
-@pytest.mark.parametrize("which", ["forward", "backward"])
-@pytest.mark.parametrize("shape", list(MOE_SHAPES))
-def test_moe_grouped_products_compile(one_chip, monkeypatch, shape, which):
-    # the kernels interpret themselves wherever the backend is the CPU,
-    # as it is here: steer that in the test, the program has no option
-    monkeypatch.setattr(pm, "_should_interpret", lambda: False)
-    n, k, held, experts_, e, f, act, rows, most = MOE_SHAPES[shape]
-    window = moe_ops.window_rows(n, k, held, experts_, MOE_TILE)
+# the same at shapes whose routing fits a larger tile than the kernels'
+# blocks do at the layer's widths and compute type (`moe_ops.kernel_tile`):
+# what the default path of a zoo net traces beyond its cell's batch, or in
+# float32; and a shape at which the parent's segment add did not compile. As
+# above, with the compute type and the routing's tile before the layer's.
+MOE_CAPPED = {
+    # Nemotron's net at batch 8: 3,072 rows an expert take 512 by the
+    # routing, whose float32 output block (512, 2688) misses VMEM: 256
+    "nemotron_h_batch_8": (65536, 6, 8, 128, 2688, 1920, "relu2",
+                           jnp.bfloat16, 512, 256, 30720, 4 << 30),
+    # Qwen3-Next's at batch 8: 1,280 rows an expert in tiles of 256, a
+    # window of 50 x 512 rows under a segment of 10, which the segment add
+    # takes in blocks of 256 (512 and their shifted copies miss VMEM: the
+    # parent did not compile here at any tile)
+    "qwen3_next_batch_8": (65536, 10, 16, 512, 2048, 512, "silu",
+                           jnp.bfloat16, 256, 256, 25600, 3 << 30),
+    # float32 operands: the LFM2 cell's 512 comes down to 256, Nemotron's
+    # own cell's 256 to the 128 it had before a tile was fitted
+    "lfm2_moe_float32": (24576, 4, 8, 32, 2048, 1792, "silu", jnp.float32,
+                         512, 256, 30720, 4 << 30),
+    "nemotron_h_float32": (16384, 6, 8, 128, 2688, 1920, "relu2",
+                           jnp.float32, 256, 128, 7680, 2 << 30)}
+
+
+def _compile_held_experts(one_chip, which, case, dtype):
+    n, k, held, experts_, e, f, act, tile, rows, most = case
+    fitted = moe_ops.fit_tile(n, k, held, experts_)
+    assert moe_ops.kernel_tile(fitted, e, f, jnp.dtype(dtype).itemsize) \
+        == tile
+    window = moe_ops.window_rows(n, k, held, experts_, tile)
     assert window == rows
-    x = ((n, e), jnp.bfloat16)
+    x = ((n, e), dtype)
     pairs = ((n * k,), jnp.float32)
     experts = ((n * k,), jnp.int32)
-    up = ((held, f, e), jnp.bfloat16)
-    down = ((held, e, f), jnp.bfloat16)
+    up = ((held, f, e), dtype)
+    down = ((held, e, f), dtype)
 
     def run(x, pw, pair_expert, wg, wu, wd):
         plan = moe_ops.plan_windows(pair_expert, held, window)
         # an expert of two matrices has no gate matrix
         return moe_ops.held_experts(x, pw, plan,
                                     None if act == "relu2" else wg, wu, wd,
-                                    MOE_TILE, k, window, True, act)
+                                    tile, k, window, True, act)
     if which == "forward":
         compiled = _compile(run, one_chip, x, pairs, experts, up, up, down,
                             kernels=["moe_gmm_fwd", "moe_segment_add"])
@@ -203,11 +243,37 @@ def test_moe_grouped_products_compile(one_chip, monkeypatch, shape, which):
                      "moe_segment_add"])
     # a window's buffers, not tokens x top_k rows of anything
     assert compiled.memory_analysis().temp_size_in_bytes < most
+    return compiled, fitted
+
+
+@pytest.mark.parametrize("which", ["forward", "backward"])
+@pytest.mark.parametrize("shape", list(MOE_SHAPES))
+def test_moe_grouped_products_compile(one_chip, monkeypatch, shape, which):
+    # the kernels interpret themselves wherever the backend is the CPU,
+    # as it is here: steer that in the test, the program has no option
+    monkeypatch.setattr(pm, "_should_interpret", lambda: False)
+    case = MOE_SHAPES[shape]
+    compiled, fitted = _compile_held_experts(one_chip, which, case,
+                                             jnp.bfloat16)
+    # at its cell's shape no family's widths hold the routing's tile down
+    assert fitted == case[7]
     # the combine gathers (PR 39): no scatter of float32 rows of the
     # embedding width is left, forward or backward (what is left scatters
     # scalars: d pair_weight, the kernels' group metadata)
-    wide = re.findall(rf"f32\[\d+,{e}\]\S* scatter\(", compiled.as_text())
+    wide = re.findall(rf"f32\[\d+,{case[4]}\]\S* scatter\(",
+                      compiled.as_text())
     assert not wide, wide
+
+
+@pytest.mark.parametrize("which", ["forward", "backward"])
+@pytest.mark.parametrize("shape", list(MOE_CAPPED))
+def test_moe_grouped_products_compile_at_the_tile_their_blocks_fit(
+        one_chip, monkeypatch, shape, which):
+    monkeypatch.setattr(pm, "_should_interpret", lambda: False)
+    *case, dtype, by_routing, tile, rows, most = MOE_CAPPED[shape]
+    _, fitted = _compile_held_experts(
+        one_chip, which, (*case, tile, rows, most), dtype)
+    assert fitted == by_routing
 
 
 @pytest.mark.parametrize("weighted", [True, False],
@@ -219,10 +285,11 @@ def test_moe_segment_add_compiles(one_chip, monkeypatch, shape, weighted):
     last reaches 9 rows into the next block: a halo of 16), at widths of
     2,048, 2,560 and 2,688 (21 lane rows: blocks of 896 lanes)."""
     monkeypatch.setattr(pm, "_should_interpret", lambda: False)
-    n, k, held, _, e, _, _, window, _ = MOE_SHAPES[shape]
+    n, k, held, _, e, _, _, _, window, _ = MOE_SHAPES[shape]
     segment = min(k, held)
     block = pm.segment_block(window, segment)
-    assert block == {6400: 256, 30720: 512, 7680: 512}[window]
+    assert block == {6400: 256, 30720: 512, 7680: 512, 40960: 512,
+                     5120: 512}[window]
     rows = ((window, e), jnp.float32)
     column = ((window,), jnp.float32)
     tok = ((window,), jnp.int32)
