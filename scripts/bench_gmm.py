@@ -121,11 +121,11 @@ def kernel_rows(sizes, tile, window, e, f, matrices, keys, inner, reps):
     def tgmm_loop(lhs, rhs):
         @jax.jit
         def many(sizes, lhs, rhs):
-            def body(i, total):
-                return pallas_moe.grouped_dot_t(sizes, tile, lhs, rhs, total,
-                                                "moe_gmm_dw")
-            return lax.fori_loop(0, inner, body, jnp.zeros(
-                (held, lhs.shape[1], rhs.shape[1]), jnp.float32))
+            def body(i, acc):
+                out = pallas_moe.grouped_dot_t(sizes + jnp.minimum(i, 0),
+                                               tile, lhs, rhs, "moe_gmm_dw")
+                return acc + out[0, 0, 0]
+            return lax.fori_loop(0, inner, body, jnp.float32(0))
         return median_ms(many, (sizes, lhs, rhs), reps)[0] / inner
     wide = matrices - 1             # products between E and F a direction
     return {

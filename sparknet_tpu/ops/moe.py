@@ -40,7 +40,10 @@ Two forms, chosen by moe_param.gated_experts:
   steps were timed; never one so small that the quarter more misses one
   window, and on the kernels' path none whose blocks miss VMEM at the
   layer's widths: `kernel_tile`), as many windows
-  as the routing needs: a loop of dynamic length, so memory is bound by
+  as the routing needs: a loop of dynamic length (in the backward pass
+  the first window, which an even routing fills and every step runs, on
+  its own, and the loop over the windows after it, skew's spill), so
+  memory is bound by
   one window's buffers and work by the routing, neither by the worst
   case of all tokens x top_k rows. A window is one gather of its rows of
   x, a grouped product over its ragged groups (gate, up, SiLU x up, down;
@@ -60,7 +63,11 @@ Two forms, chosen by moe_param.gated_experts:
   side by side: J - 1 shifted dense adds in float32, ascending by pair
   index, leave each token's sum on its first row), gather 2 of each
   token's first row, added onto the result (zeros before the first
-  window). Every pair is added once, in float32, in an order fixed
+  window). In the BACKWARD pass the first window's results are the start
+  (PR 48): dx is its combine's gather, with nothing zeroed before it and
+  nothing added onto it, and the weight gradients' float32 totals are its
+  own, written once; a later window's are added onto them. Every
+  pair is added once, in float32, in an order fixed
   by the pair index: two runs agree to the bit. The only scatter left is
   d pair_weight's, `window` scalars. The product has two implementations
   behind `_grouped`, chosen at trace time from what the layer can see
@@ -76,15 +83,17 @@ Two forms, chosen by moe_param.gated_experts:
   the experts' `activation`, the route's `score`, whether it has a
   `selection_bias`, the combine's form: `combine` = `gather`,
   `segment` = J, the row `tile` with the `rows_an_expert` it was fitted to
-  and the `window`'s rows, the expert's `matrices`, 3 or 2, and the shared
-  expert's gate, `shared_gate`). With
+  and the `window`'s rows, `first_window` = `backward`: the pass in which
+  the first window stands outside the loop over windows, the expert's
+  `matrices`, 3 or 2, and the shared expert's gate, `shared_gate`). With
   shared_hidden_dim > 0 a shared expert sees every token:
   shared = sigmoid(w_s . x) W_down (SiLU(W_gate x) * W_up x), and
   y = routed + shared. Tops: [output] or [output, stats]; stats (weight
   0, kept as layer state so that the solver can read it where it already
   waits for a loss, as `moe.load`) = [share of the token-expert pairs
   that land on held experts, largest over mean load of the held experts,
-  windows the loop ran: more than 1 is skew spilling past a window].
+  windows run: more than 1 is skew spilling past a window, and the
+  backward's spill loop ran that many less 1].
   Blobs:
     router (num_experts, E) | w_gate (held, F, E) | w_up (held, F, E)
     | w_down (held, E, F) | then with a shared expert: ws_gate (Fs, E)
@@ -114,7 +123,8 @@ Two forms, chosen by moe_param.gated_experts:
   scatter), moe_shared, and moe_glue for what is left, so that the
   layer's device time adds up by them: the held weights cast to the
   compute type (and their gradients cast back), the window loop itself
-  with its zero start, the result's cast. The
+  with its zero start (backward: the spill loop and its adds onto the
+  first window's results), the result's cast. The
   top-1 form below opens no scope.
 
 The top-1 form:
@@ -362,9 +372,9 @@ def _combine(rows, weight, tm, segment, kernel):
 def _grouped(kernel, tile, sizes):
     """The grouped product over a window's ragged groups, in one of two
     implementations: `dot(lhs, rhs, transpose_rhs, name)` -> (m, n) float32
-    and `dot_t(lhs, rhs, total, name)` -> total + per group lhs^T rhs. The
-    kernels leave the rows of no group unwritten, the XLA form zeroes
-    them: the caller masks."""
+    and `dot_t(lhs, rhs, name)` -> per group lhs^T rhs, (groups, k, n)
+    float32, zeros for a group of no row. The kernels leave the rows of no
+    group unwritten, the XLA form zeroes them: the caller masks."""
     if kernel:
         # here and not at the top: a process without such a layer never
         # imports pallas (1.4 s of every cell's set-up, PR 29)
@@ -383,8 +393,8 @@ def _grouped(kernel, tile, sizes):
     def dot(lhs, rhs, transpose_rhs, name):
         return ragged(lhs, rhs, _NT if transpose_rhs else _NN, [0], name)
 
-    def dot_t(lhs, rhs, total, name):
-        return total + ragged(lhs, rhs, _TN, [], name)
+    def dot_t(lhs, rhs, name):
+        return ragged(lhs, rhs, _TN, [], name)
     return dot, dot_t
 
 
@@ -423,11 +433,20 @@ def held_experts(x, pair_weight, plan, wg, wu, wd, tile, top_k, window,
     tile of the grouped product; `kernel`: the pallas product, else XLA's.
     -> (n, E) float32. A `fori_loop` over the windows in use: one gather,
     the grouped products and the gathering combine (`_token_major`,
-    `_combine`) a window, added onto the result; the backward pass is a
-    second such loop that recomputes each window, so nothing is stored
-    per window. A token's pairs lie on different experts (as `top_k` gives
-    them), or at least no more than min(top_k, held) of them are held:
-    the segment add reaches no further."""
+    `_combine`) a window, added onto the result. The backward pass
+    recomputes each window, so nothing is stored per window, and its
+    FIRST window, which an even routing fills and every step runs, stands
+    outside any loop: its dx and weight gradients are the start, nothing
+    is zeroed before them and nothing added onto them, and a `fori_loop`
+    over the windows 1 .. plan["windows"] - 1, skew's spill, adds each
+    later window's onto them; one jitted function (`_window_bwd`) is the
+    window at both places. (The forward's first window stays in its
+    loop: outside it bought half the backward's gain in the LFM2 cell's
+    step for twice its cost in the cell's set-up, a second place that
+    holds the window's kernels: PERF.md section 6, PR 48.) A
+    token's pairs lie on different experts (as `top_k` gives them), or at
+    least no more than min(top_k, held) of them are held: the segment add
+    reaches no further."""
     return _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window,
                      kernel, act)[0]
 
@@ -464,56 +483,89 @@ def _held_fwd(x, pair_weight, plan, wg, wu, wd, tile, top_k, window, kernel,
     return y, (x, pair_weight, plan, wg, wu, wd)
 
 
+# The backward pass's window: jitted under a name of its own, so that the
+# first window and the spill loop's body (and every trace of `_held_bwd` a
+# step makes) trace it ONCE: both places bind one jaxpr. As a plain inner
+# function it is the same executable and 1.1 s more of a warm first step
+# on the chip's host (jax's own log, LFM2 cell: the step's trace 6.25-6.29
+# s so, 5.09-5.19 s jitted, 5.01-5.36 s at the parent: PERF.md section 6,
+# PR 48). Both places hand it `w` as a plain int32: a Python 0 and a
+# loop's index from a Python 1 are weakly typed, another type to jit, and
+# the window would trace twice.
+
+
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12))
+def _window_bwd(x, pair_weight, plan, wg, wu, wd, dy, w, tile, top_k, window,
+                kernel, act):
+    """Window `w` of the backward pass, recomputed: (its contribution to
+    dx (n, E) float32, the window's `pair` indices and d pair_weight on
+    each of its rows, 0 on the rows of no pair, for the caller's scalar
+    scatter, then d w_gate (None for an expert of two matrices), d w_up,
+    d w_down: per group lhs^T rhs in float32, written once)."""
+    with jax.named_scope("moe_dispatch"):
+        pair, tok, valid, sizes, lo = _window(plan, w, window, top_k)
+        xw, dyr = x[tok], dy[tok]
+    with jax.named_scope("moe_experts"):
+        dot, dot_t = _grouped(kernel, tile, sizes)
+        if wg is None:
+            gate, dgate = _gate_and_slope(
+                dot(xw, wu, True, "moe_gmm_fwd"), act)
+            h = gate.astype(x.dtype)
+        else:
+            a = dot(xw, wg, True, "moe_gmm_fwd")
+            b = dot(xw, wu, True, "moe_gmm_fwd")
+            gate, dgate = _gate_and_slope(a, act)
+            h = (gate * b).astype(x.dtype)
+        wt = jnp.where(valid, pair_weight[pair], 0.0)
+        # d pair_weight = dy . (h W_down^T), row by row
+        out = dot(h, wd, True, "moe_gmm_fwd")
+        dwt = jnp.where(valid, jnp.sum(dyr * out, -1), 0.0)
+        dyw = (dyr * wt[:, None]).astype(x.dtype)
+        dh = dot(dyw, wd, False, "moe_gmm_bwd")
+        dwg = None
+        if wg is None:
+            db = (dh * dgate).astype(x.dtype)
+            dxw = dot(db, wu, False, "moe_gmm_bwd")
+        else:
+            da = (dh * b * dgate).astype(x.dtype)
+            db = (dh * gate).astype(x.dtype)
+            dxw = dot(da, wg, False, "moe_gmm_bwd") \
+                + dot(db, wu, False, "moe_gmm_bwd")
+            dwg = dot_t(da, xw, "moe_gmm_dw")
+        dwu = dot_t(db, xw, "moe_gmm_dw")
+        dwd = dot_t(dyw, h, "moe_gmm_dw")
+    with jax.named_scope("moe_combine"):
+        tm = _token_major(plan, pair, valid, lo, top_k)
+        dx = _combine(dxw, None, tm, min(top_k, wd.shape[0]), kernel)
+    return dx, pair, dwt, dwg, dwu, dwd
+
+
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
 def _held_bwd(tile, top_k, window, kernel, act, res, dy):
     x, pair_weight, plan, wg, wu, wd = res
     dy = dy.astype(jnp.float32)
-    segment = min(top_k, wd.shape[0])
 
-    def body(w, carry):
-        dx, dpw, dwg, dwu, dwd = carry
-        with jax.named_scope("moe_dispatch"):
-            pair, tok, valid, sizes, lo = _window(plan, w, window, top_k)
-            xw, dyr = x[tok], dy[tok]
-        with jax.named_scope("moe_experts"):
-            dot, dot_t = _grouped(kernel, tile, sizes)
-            if wg is None:
-                gate, dgate = _gate_and_slope(
-                    dot(xw, wu, True, "moe_gmm_fwd"), act)
-                h = gate.astype(x.dtype)
-            else:
-                a = dot(xw, wg, True, "moe_gmm_fwd")
-                b = dot(xw, wu, True, "moe_gmm_fwd")
-                gate, dgate = _gate_and_slope(a, act)
-                h = (gate * b).astype(x.dtype)
-            wt = jnp.where(valid, pair_weight[pair], 0.0)
-            # d pair_weight = dy . (h W_down^T), row by row
-            out = dot(h, wd, True, "moe_gmm_fwd")
-            dwt = jnp.where(valid, jnp.sum(dyr * out, -1), 0.0)
-            dyw = (dyr * wt[:, None]).astype(x.dtype)
-            dh = dot(dyw, wd, False, "moe_gmm_bwd")
-            if wg is None:
-                db = (dh * dgate).astype(x.dtype)
-                dxw = dot(db, wu, False, "moe_gmm_bwd")
-            else:
-                da = (dh * b * dgate).astype(x.dtype)
-                db = (dh * gate).astype(x.dtype)
-                dxw = dot(da, wg, False, "moe_gmm_bwd") \
-                    + dot(db, wu, False, "moe_gmm_bwd")
-                dwg = dot_t(da, xw, dwg, "moe_gmm_dw")
-            dwu = dot_t(db, xw, dwu, "moe_gmm_dw")
-            dwd = dot_t(dyw, h, dwd, "moe_gmm_dw")
+    def one(w, dpw):
+        dx, pair, dwt, *dws = _window_bwd(
+            x, pair_weight, plan, wg, wu, wd, dy,
+            lax.convert_element_type(w, jnp.int32), tile, top_k, window,
+            kernel, act)
         with jax.named_scope("moe_combine"):
-            tm = _token_major(plan, pair, valid, lo, top_k)
-            dx = dx + _combine(dxw, None, tm, segment, kernel)
             # a row of no group may repeat a real pair's index: add 0 there
-            dpw = dpw.at[pair].add(dwt)
-        return dx, dpw, dwg, dwu, dwd
+            return dx, dpw.at[pair].add(dwt), dws
 
-    zeros = [None if a is None else jnp.zeros(a.shape, jnp.float32)
-             for a in (x, pair_weight, wg, wu, wd)]
-    dx, dpw, dwg, dwu, dwd = lax.fori_loop(0, plan["windows"], body,
-                                           tuple(zeros))
+    def spill(w, totals):
+        dx, dpw, dws = totals
+        more, dpw, more_dws = one(w, dpw)
+        # a later window's weight gradients onto the totals, one more pass
+        # over them in a window that skew alone brings
+        return dx + more, dpw, jax.tree.map(jnp.add, dws, more_dws)
+
+    # the first window's results are the start: nothing zeroed but d
+    # pair_weight's scalars, nothing added
+    dx, dpw, (dwg, dwu, dwd) = lax.fori_loop(
+        1, plan["windows"], spill,
+        one(0, jnp.zeros(pair_weight.shape, jnp.float32)))
     return (dx.astype(x.dtype), dpw, None,
             None if wg is None else dwg.astype(wg.dtype),
             dwu.astype(wu.dtype), dwd.astype(wd.dtype))
@@ -843,7 +895,7 @@ class MoE(Layer):
                       activation=self.act, score=self.score,
                       selection_bias=self.selection_bias,
                       combine="gather", segment=min(k, held),
-                      tile=tile, window=window,
+                      tile=tile, window=window, first_window="backward",
                       rows_an_expert=round(n * k / self.num_experts),
                       matrices=3 if self.gate_matrix else 2,
                       shared_gate=bool(self.shared_hidden)
@@ -859,6 +911,7 @@ class MoE(Layer):
                                for d in range(3)]) if pad else w
         # what the window loop costs beside its body's three scopes: the
         # held weights cast to the compute type, the loop's zero start
+        # (backward: the spill loop's adds onto the first window's results)
         with jax.named_scope("moe_glue"):
             wg, wu, wd = cast("w_gate", 1), cast("w_up", 1), cast("w_down", 2)
             y = held_experts(xt, top.reshape(n * k), plan, wg, wu, wd,

@@ -8,7 +8,8 @@ nobody. `gmm` walks the row tiles that hold a row of some group (a grid
 whose second extent is a traced value: as many tiles as the routing fills,
 a tile that two groups share visited once for each) and multiplies each
 by its group's matrix; `tgmm` accumulates each group's lhs^T rhs in
-float32 inside the kernel and writes it once. What this module adds is the
+float32 inside the kernel and writes it once (zeros for a group of no
+row: it visits the empty groups too). What this module adds is the
 block sizes (the row tile is the layer's `tile_rows`, the other two extents
 whole where the blocks fit the 16 MB of VMEM a kernel gets unasked) and a
 name a call: upstream's are jitted functions called `gmm` and `tgmm`, and
@@ -41,7 +42,7 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 from .backend import _should_interpret
 
 # elements of the largest block: 2 MB of bfloat16 weights a buffer for
-# `gmm`, 2 MB of float32 accumulator, output and running total for `tgmm`
+# `gmm`, 2 MB of float32 accumulator and output for `tgmm`
 _GMM_BLOCK, _TGMM_BLOCK = 1 << 20, 1 << 19
 
 
@@ -83,18 +84,20 @@ def fits(tile, embed, hidden, itemsize):
     """Whether every grouped product of an expert of `embed` x `hidden`
     fits VMEM at a row tile of `tile`, operands of `itemsize` bytes: both
     operand blocks in two buffers each; `gmm`'s float32 output block in two
-    and its accumulator, `tgmm`'s output, the running total it adds to (two
-    each) and its accumulator. Against compiles for the described chip at
-    the six cells' widths, tiles 128 to 512, both types (PR 47) this says
-    no wherever the compiler does, and no to one expert it takes (2,048 x
-    512 in bfloat16 at 512)."""
+    and its accumulator, `tgmm`'s likewise (it adds onto no running total
+    since PR 48; with one it held two buffers more, which was the larger
+    count at four of the six cells' widths and decided the verdict at
+    none: PERF.md section 6, PR 48). Against compiles for the described chip
+    at the six cells' widths, tiles 128 to 512, both types (PR 47) this
+    says no wherever the compiler does, and no to one expert it takes
+    (2,048 x 512 in bfloat16 at 512)."""
     def gmm_bytes(k, n):
         tk, tn = _blocks(k, n, _GMM_BLOCK)
         return 2 * itemsize * (tile * tk + tk * tn) + 3 * 4 * tile * tn
 
     def tgmm_bytes(k, n):
         tk, tn = _blocks(k, n, _TGMM_BLOCK)
-        return 2 * itemsize * tile * (tk + tn) + 5 * 4 * tk * tn
+        return 2 * itemsize * tile * (tk + tn) + 3 * 4 * tk * tn
     return max(gmm_bytes(embed, hidden), gmm_bytes(hidden, embed),
                tgmm_bytes(embed, hidden), tgmm_bytes(hidden, embed)) < _VMEM
 
@@ -107,11 +110,11 @@ def grouped_dot(sizes, tile, lhs, rhs, transpose_rhs, name):
                 _should_interpret())
 
 
-def grouped_dot_t(sizes, tile, lhs, rhs, total, name):
-    """total[e] + (lhs's rows of group e)^T (rhs's rows of group e):
-    lhs (m, k), rhs (m, n), total (groups, k, n) float32, updated in
-    place."""
-    return _tgmm(lhs, rhs, sizes, total, tile, name, _should_interpret())
+def grouped_dot_t(sizes, tile, lhs, rhs, name):
+    """(lhs's rows of group e)^T (rhs's rows of group e) for every group
+    e: lhs (m, k), rhs (m, n) -> (groups, k, n) float32, written once;
+    zeros for a group of no row."""
+    return _tgmm(lhs, rhs, sizes, tile, name, _should_interpret())
 
 
 # jitted under names of their own: a window's calls of one shape (gate and
@@ -128,14 +131,13 @@ def _gmm(lhs, rhs, sizes, tile, transpose_rhs, name, interpret):
             transpose_rhs=transpose_rhs, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6))
-def _tgmm(lhs, rhs, sizes, total, tile, name, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _tgmm(lhs, rhs, sizes, tile, name, interpret):
     k, n = lhs.shape[1], rhs.shape[1]
     with jax.named_scope(name):
         return tgmm.__wrapped__(
             lhs.T, rhs, sizes, jnp.float32,
-            (tile, *_blocks(k, n, _TGMM_BLOCK)), existing_out=total,
-            interpret=interpret)
+            (tile, *_blocks(k, n, _TGMM_BLOCK)), interpret=interpret)
 
 
 # -- the combine's segment add ------------------------------------------------

@@ -78,12 +78,15 @@ def test_grouped_product_against_a_masked_loop_over_experts(case, kernel):
     close(got_nn[:used], want_nn)
     if not kernel:
         assert not np.asarray(got_nt[used:]).any()
-    # per group lhs^T rhs on top of a running total, the rows of no group
-    # left out whatever they hold
-    total = jax.random.normal(kw, (HELD, E, F))
+    # per group lhs^T rhs, written once (zeros for a group of no row), the
+    # rows of no group left out whatever they hold
     dirty = lhs.at[used:].set(jnp.nan) if kernel else lhs
     want_t = jnp.stack([masked(e, lhs).T @ rhs[:used] for e in range(HELD)])
-    close(dot_t(dirty, rhs, total, "moe_gmm_dw"), total + want_t)
+    got_t = dot_t(dirty, rhs, "moe_gmm_dw")
+    assert got_t.dtype == jnp.float32
+    close(got_t, want_t)
+    for e in np.flatnonzero(sizes == 0):
+        assert not np.asarray(got_t[e]).any()
 
 
 def oracle(x, pair_weight, pair_expert, wg, wu, wd, top_k):
@@ -106,6 +109,11 @@ LAYER_CASES = {
     "groups_off_the_tile": ([5, 11, 3, 9], 2),
     "every_pair_on_one_expert": ([96, 0, 0, 0], 6),
     "nothing_held": ([0, 0, 0, 0], 0),
+    # the backward's first window stands outside its loop over windows (PR
+    # 48): full to its last row the loop runs no trip, one row more and it
+    # runs one
+    "exactly_one_full_window": ([4, 6, 0, 6], 1),
+    "one_window_and_one_row": ([5, 6, 0, 6], 2),
 }
 
 
@@ -504,13 +512,17 @@ def test_a_fitted_tile_comes_down_to_what_the_kernels_blocks_fit(
 # What the window of a THREE-matrix expert traces (forward and backward,
 # XLA's ragged products), by activation: PR 42 gave the window a second
 # form for an expert of two matrices, and the four accepted LM cells'
-# steps must keep their text. Digests taken on the parent commit.
-THREE_MATRIX_JAXPRS = {"silu": "e3787889089128bb",
-                       "relu": "4364e0255f8740b2"}
+# steps must keep their text. Digests RETAKEN at PR 48 on its own tree: the
+# parent's text cannot be kept, the backward's first window moved out of
+# its loop (e3787889089128bb and 4364e0255f8740b2 before).
+THREE_MATRIX_JAXPRS = {"silu": "413c8ca2648edeea",
+                       "relu": "0d850c70e6be11a9"}
 
 
-def _window_digest(act, gate=True):
-    import hashlib
+def _window_loss(act, gate=True):
+    """(value and gradients of a toy layer's summed output on the XLA
+    path, its arguments: 32 tokens x top-2, 4 of 8 experts held, a window
+    of 40 rows)."""
     n, e, f, held, k, tile = 32, 16, 24, 4, 2, 8
     window = moe_ops.window_rows(n, k, held, 8, tile)
     x = jnp.zeros((n, e), jnp.bfloat16)
@@ -523,8 +535,14 @@ def _window_digest(act, gate=True):
         plan = moe_ops.plan_windows(pe, held, window)
         return jnp.sum(moe_ops.held_experts(x, pw, plan, wg, wu, wd, tile,
                                             k, window, False, act))
-    text = str(jax.make_jaxpr(jax.value_and_grad(loss, (0, 1, 2, 3, 4)))(
-        x, pw, wu if gate else None, wu, wd))
+    return (jax.value_and_grad(loss, (0, 1, 2, 3, 4)),
+            (x, pw, wu if gate else None, wu, wd))
+
+
+def _window_digest(act, gate=True):
+    import hashlib
+    fun, args = _window_loss(act, gate)
+    text = str(jax.make_jaxpr(fun)(*args))
     assert "/root" not in text and "0x" not in text    # no path, no address
     return hashlib.sha256(text.encode()).hexdigest()[:16], text
 
@@ -538,8 +556,54 @@ def test_the_two_matrix_window_is_two_products_and_two_weight_gradients():
     three, two = _window_digest("silu")[1], _window_digest("relu2", False)[1]
     # forward 3 and 2; backward the same recomputed, dh, dx (2 and 1) and
     # the weight gradients (3 and 2)
+    # of ONE window a pass: the backward's first window and the body of
+    # the loop over those after it bind one traced function (PR 48: a
+    # second trace of it is a second of every LM cell's warm set-up)
     assert three.count("ragged_dot_general") == 3 + 3 + 1 + 2 + 3
     assert two.count("ragged_dot_general") == 2 + 2 + 1 + 1 + 2
+
+
+def _zero_fills(text, shape):
+    """How many float32 arrays of `shape` (as StableHLO spells it, "32x16")
+    a lowered module fills with zeros: broadcasts of a scalar constant 0,
+    function by function (a constant's name is its function's)."""
+    fills = 0
+    for func in text.split("func.func")[1:]:
+        zeros = re.findall(r"(%\w+) = stablehlo\.constant "
+                           r"dense<0\.0+e\+00> : tensor<f32>", func)
+        fills += sum(len(re.findall(
+            rf"broadcast_in_dim {z}, dims = \[\] : \(tensor<f32>\) -> "
+            rf"tensor<{shape}xf32>", func)) for z in zeros)
+    return fills
+
+
+@pytest.mark.parametrize("matrices,act,products", [
+    (3, "silu", (3, 9)), (2, "relu2", (2, 6))],
+    ids=["three_matrices", "two_matrices"])
+def test_the_backwards_first_window_stands_outside_the_loop(
+        matrices, act, products):
+    """Lowered for a TPU (the XLA path: `chlo.ragged_dot`), the module
+    holds the forward window once and the backward window twice, before
+    the loop over the windows after the first and inside it, and the
+    backward's totals start as the first window's own: no float32 zeros of
+    dx's or a weight gradient's shape are made; the forward's loop still
+    starts from zeros of the result's shape. (The window is TRACED once,
+    which the count of ragged products in the jaxpr pins above; ISSUE 48
+    also asked for it "lowered once": jax 0.9 lowers a jitted function
+    apart where one call lies outside a `while` and one inside, and XLA
+    inlines every call, PERF.md section 6, PR 48.)"""
+    forward, backward = products
+    fun, args = _window_loss(act, matrices == 3)
+    text = jax.jit(fun).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count('"chlo.ragged_dot"(') == forward + 2 * backward
+    assert text.count("stablehlo.while") >= 2
+    # x is (32, 16) and a weight (4, 24, 16) or (4, 16, 24): the parent's
+    # loops started from such zeros, y forward, dx and the weight
+    # gradients' totals backward; y's stay, and d pair_weight's 64 scalars
+    assert _zero_fills(text, "32x16") == 1
+    assert not _zero_fills(text, "4x24x16") + _zero_fills(text, "4x16x24")
+    assert _zero_fills(text, "64")
 
 
 @pytest.mark.parametrize("k,n,most,want", [
@@ -600,6 +664,10 @@ def test_layer_takes_the_product_it_can_and_records_it(
     # whole tiles
     assert moe_paths(before) == [(name, path, reason, "gather", 2, tile, 4,
                                   -(-20 // tile) * tile)]
+    # ... and the pass whose first window stands outside the loop over
+    # windows (PR 48)
+    assert [s["first_window"] for s in default_tracer().since(
+        before, "moe.path")] == ["backward"]
     assert ("pallas_call" in text) == (path == "kernel")
     assert ("ragged_dot" in text) == (path == "xla")
     # one structure either way: a loop of dynamic length over windows, and
