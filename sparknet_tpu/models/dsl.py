@@ -158,7 +158,8 @@ def AttentionLayer(name, bottoms, num_heads, head_dim=None, causal=False,
                    index_heads=None, index_head_dim=None, index_topk=None,
                    index_stats=False, out_filler=None, q_lora_rank=None,
                    kv_lora_rank=None, qk_nope_head_dim=None,
-                   qk_rope_head_dim=None, v_head_dim=None):
+                   qk_rope_head_dim=None, v_head_dim=None, gate=None,
+                   rope=None):
     """sparknet_tpu extension for the long-context path (see
     parallel.ring_attention, ops.pallas_attention). `num_kv_heads` selects
     the grouped-query form (bias-free q/k/v/out projections; qk_norm,
@@ -176,7 +177,15 @@ def AttentionLayer(name, bottoms, num_heads, head_dim=None, causal=False,
     without num_kv_heads; ops/attention.py): `q_lora_rank`,
     `qk_nope_head_dim`, `qk_rope_head_dim` and `v_head_dim` are its other
     sizes, `rope_theta` and `norm_eps` its rotary's and its two latent
-    norms'; seven blobs."""
+    norms'; seven blobs. `gate` (with num_kv_heads) names the output
+    gate's form: "elementwise" is `output_gate`, "head" one scalar a head
+    from one more blob W_g (num_heads, E). `rope` (with rotary_dim) is a
+    dict of the rotary table's fields beside `rope_theta`: `rope_type`
+    "yarn" with `rope_factor`, `rope_original_positions`, `rope_beta_fast`,
+    `rope_beta_slow`, and `rope_scale`, the factor on cos and sin."""
+    if gate not in (None, "none", "elementwise", "head"):
+        raise ValueError(f"{name}: gate {gate!r}: none, elementwise or head")
+    output_gate = output_gate or gate == "elementwise"
     ap = dict(num_heads=num_heads, causal=causal, ring=ring, flash=flash)
     latent = dict(q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
                   qk_nope_head_dim=qk_nope_head_dim,
@@ -199,6 +208,9 @@ def AttentionLayer(name, bottoms, num_heads, head_dim=None, causal=False,
             ap["rope_theta"] = rope_theta
         if norm_eps is not None:
             ap["norm_eps"] = norm_eps
+    if gate == "head":
+        ap["head_gate"] = True
+    ap.update(rope or {})
     if weight_filler is not None:
         ap["weight_filler"] = weight_filler
     if index_heads is None:

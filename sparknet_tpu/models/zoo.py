@@ -1013,6 +1013,133 @@ def glm4_moe_lite(vocab_size=154880, seq_len=8192, batch_size=1,
                  proj=dict(weight_filler=gauss, param=[_KEEP])))
 
 
+#: Laguna's two kinds of attention layer, one period of the published list
+LAGUNA_PERIOD = ("full_attention", "sliding_attention", "sliding_attention",
+                 "sliding_attention")
+#: the published `rope_parameters` of Laguna-XS.2, by kind of layer
+LAGUNA_ROPE = {
+    "full_attention": dict(
+        rope_theta=500000, rope_type="yarn", factor=64,
+        original_max_position_embeddings=4096, beta_slow=1, beta_fast=64,
+        attention_factor=1.4158883083359672, partial_rotary_factor=0.5),
+    "sliding_attention": dict(rope_type="default", rope_theta=10000,
+                              partial_rotary_factor=1)}
+
+
+def laguna(vocab_size=100352, seq_len=8192, batch_size=2, hidden_size=2048,
+           intermediate_size=8192, num_hidden_layers=40,
+           num_key_value_heads=8, head_dim=128, layer_types=None,
+           num_attention_heads_per_layer=None, mlp_layer_types=None,
+           sliding_window=512, rope_parameters=None, rms_norm_eps=1e-6,
+           num_experts=256, num_experts_per_tok=8, moe_intermediate_size=512,
+           shared_expert_intermediate_size=512,
+           moe_routed_scaling_factor=2.5, experts_held=None, first_expert=0,
+           flash=True, moe_stats=False):
+    """Laguna (`model_type` laguna) as a trainable net: blocks of y = x +
+    Attn(RMSNorm(x)), out = y + FF(RMSNorm(y)), plain RMSNorm (w filled
+    with 1), a final RMSNorm, untied embedding and head, mean cross-entropy
+    per token. `Attn` of layer l is grouped-query attention without bias or
+    head norm, `num_attention_heads_per_layer[l]` query heads on
+    `num_key_value_heads` key-value heads of `head_dim`, with a PER-HEAD
+    output gate (o_h * sigmoid(W_g h)_h, W_g one row a head) and the kind
+    `layer_types[l]` says: `sliding_attention` sees `sliding_window` keys
+    (the query's own among them), `full_attention` the causal half; each
+    kind has its own rotary in `rope_parameters[kind]` — `rope_theta`,
+    `partial_rotary_factor` of the head turned (rotate-half), and with
+    `rope_type` "yarn" the table blended over `factor`,
+    `original_max_position_embeddings`, `beta_fast`, `beta_slow` with
+    `attention_factor` on cos and sin (ops/attention.py:rope_table). `FF`
+    is W_2 (silu(W_1 y) * W_3 y) at `intermediate_size` where
+    `mlp_layer_types[l]` is `dense`, else a no-drop MoE (ops/moe.py): s =
+    sigmoid(W_r y) in float32 over `num_experts`, the
+    `num_experts_per_tok` largest, their weights s divided by (their sum +
+    1e-20) times `moe_routed_scaling_factor`, on the experts' outputs;
+    SiLU-gated three-matrix experts at `moe_intermediate_size` and one
+    shared expert at `shared_expert_intermediate_size` of every token,
+    added with no gate. Defaults are the published sizes of Laguna-XS.2
+    (48 query heads in a full layer, 64 in a window layer, one period of
+    `LAGUNA_PERIOD` repeating, layer 0 dense); the three lists are read at
+    their first `num_hidden_layers` entries.
+
+    Assumed, where the config has no key (the configuration file lists the
+    same): the gate one scalar a head (`gating: true` has no width; the
+    published parameter count settles it), sigmoid scores with renormalised
+    weights and no correction bias, an ungated shared expert, no head norm,
+    rotate-half pairing, the `transformers` form of YaRN, fillers
+    gaussian(0.02) for matrices and gaussian(1) for the embedding (as
+    `smallthinker`). Left out: the router's auxiliary loss, dropout,
+    packing.
+
+    One chip's share of an expert-parallel group as in `qwen3_next`:
+    `experts_held` from `first_expert` on, `vocab_size` the held rows,
+    `num_hidden_layers` this pipeline stage's layers (the first of the
+    model's).
+
+    Layers are named block{i}/ln1 | attn | res1 | ln2 | ff... | res2 (a
+    dense block's feed-forward ff_gate, ff_up, ff_sig, ff_act, ff_down; a
+    MoE block's moe; `_lm_stack`): neighbours of one kind with one
+    feed-forward are alike and scan as one run (the three window layers of
+    a period); a full layer between them is a body of its own."""
+    n = num_hidden_layers
+    kinds = list(layer_types or [LAGUNA_PERIOD[i % 4] for i in range(n)])[:n]
+    heads = list(num_attention_heads_per_layer or
+                 [48 if k == "full_attention" else 64 for k in kinds])[:n]
+    feeds = list(mlp_layer_types or ["dense"] + ["sparse"] * (n - 1))[:n]
+    ropes = rope_parameters or LAGUNA_ROPE
+    for name, got in (("layer_types", kinds), ("mlp_layer_types", feeds),
+                      ("num_attention_heads_per_layer", heads)):
+        if len(got) != n:
+            raise ValueError(f"laguna: {name} has {len(got)} entries for "
+                             f"{n} layers")
+    unknown = sorted(set(kinds) - set(ropes)) + sorted(
+        set(feeds) - {"dense", "sparse"})
+    if unknown:
+        raise ValueError(f"laguna: {', '.join(unknown)}: a layer is one of "
+                         f"{', '.join(ropes)} with a dense or a sparse "
+                         "feed-forward")
+    gauss = _gauss()
+
+    def attention(i):
+        rp = ropes[kinds[i]]
+        rope = {}
+        if rp.get("rope_type", "default") == "yarn":
+            rope = dict(
+                rope_type="yarn", rope_factor=rp["factor"],
+                rope_original_positions=rp[
+                    "original_max_position_embeddings"],
+                rope_beta_fast=rp["beta_fast"],
+                rope_beta_slow=rp["beta_slow"])
+            if rp.get("attention_factor") is not None:
+                rope["rope_scale"] = rp["attention_factor"]
+        return lambda p, h: [AttentionLayer(
+            p + "attn", [h], heads[i], head_dim=head_dim, causal=True,
+            flash=flash, num_kv_heads=num_key_value_heads,
+            rotary_dim=int(head_dim * rp.get("partial_rotary_factor", 1)),
+            rope_theta=rp["rope_theta"], rope=rope, gate="head",
+            weight_filler=gauss,
+            window=sliding_window if kinds[i] == "sliding_attention" else 0,
+            param=[_KEEP] * 5)]
+
+    def moe(p, h):
+        return [MoELayer(
+            p + "moe", [h], num_experts, hidden_dim=moe_intermediate_size,
+            top_k=num_experts_per_tok, experts_held=experts_held,
+            first_expert=first_expert,
+            shared_hidden_dim=shared_expert_intermediate_size,
+            norm_topk_prob=True, score_function="sigmoid", topk_eps=1e-20,
+            routed_scaling_factor=moe_routed_scaling_factor,
+            shared_gate=False, weight_filler=gauss, stats=moe_stats)]
+    dense = _silu_ff(intermediate_size, hidden_size, gauss)
+    return _lm_stack(
+        "Laguna", batch_size, seq_len, vocab_size, hidden_size,
+        [(i, [("ln1", attention(i), "res1"),
+              ("ln2", dense if feeds[i] == "dense" else moe, "res2")])
+         for i in range(n)],
+        _rms_norm(rms_norm_eps, zero_centered=False),
+        embed=dict(weight_filler=_gauss(1.0), bias_term=False),
+        head=dict(weight_filler=gauss, bias_term=False))
+
+
 def transformer_lm_pieces(vocab_size=512, seq_len=256, batch_size=8,
                           d_model=256, num_heads=8, d_ff=None,
                           max_positions=None, flash=True):

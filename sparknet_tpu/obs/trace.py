@@ -33,7 +33,11 @@ the closed list), which is what lets a reader of a device trace add a step
 up without knowing any model's layer names. An ``attn.path`` of a latent
 layer says ``form`` = ``latent`` and its five sizes besides, with
 ``shared_key_bytes``, what one pass writes to give the one rotary key a
-token a head axis.
+token a head axis. Every ``attn.path`` says ``heads`` and ``kv_heads`` (a
+net may have layers of two head counts), the output gate's form ``gate``
+(``none``, ``elementwise``, ``head``) and the rotary's table ``rope``
+(``none``, ``plain``, ``yarn``) with ``rope_factor`` and ``rope_scale``,
+the factor on cos and sin.
 
 ``default_tracer()`` is the process-wide tracer that ``Solver`` and
 ``PrefetchIterator`` use when none is passed; ``default_tracer().spans()``

@@ -24,6 +24,30 @@ with output_gate o * sigmoid(gate) before the out projection
 kernel reads the shared key-value heads in place (no repeat in memory);
 the dense path repeats them.
 
+The output gate has a second form, PER HEAD (attention_param.head_gate;
+`gate="head"` in models/dsl.py; a layer takes one of the two): one more
+blob W_g (H, E) after the head norms, g = sigmoid(W_g x) in float32, one
+scalar a head and a token, and o_h * g_h before the out projection. Its
+product and sigmoid lie under the scope attn_gate inside attn_proj_in, its
+multiply under attn_gate inside attn_proj_out.
+
+The rotary's angles are position x inv_freq over the rotary_dim / 2 pairs,
+and inv_freq is a TABLE (`rope_table`): rope_type "plain", the default,
+inv_i = rope_theta ** (-2i / rotary_dim); rope_type "yarn" the blend the
+`transformers` library computes for YaRN, with d = rotary_dim, f =
+rope_factor, L = rope_original_positions:
+  extra_i = rope_theta ** (-2i / d), inter_i = extra_i / f,
+  low  = floor(d ln(L / (rope_beta_fast 2 pi)) / (2 ln rope_theta)),
+  high = ceil(d ln(L / (rope_beta_slow 2 pi)) / (2 ln rope_theta)),
+         both inside [0, d - 1] (`yarn_range`),
+  ramp_i = clip((i - low) / (high - low), 0, 1),
+  inv_i = inter_i ramp_i + extra_i (1 - ramp_i);
+rope_scale multiplies cos and sin (absent with yarn: 0.1 ln f + 1), so the
+turned dimensions of q and of k are scaled and the passing ones are not. A
+net chooses table and theta layer by layer: window layers with the plain
+table on the whole head beside full layers with YaRN on half of it. A layer
+that names none of these traces the operations it traced before them.
+
 attention_param.window (with causal) is a sliding window: query i sees keys
 i - window < j <= i. The flash kernel then runs over the band of key blocks
 alone (`flash_swa_*` in a device trace), the dense path masks the same way.
@@ -32,8 +56,12 @@ record a trace of the layer: `path` = `kernel`, `dense` or `ring`, the
 `reason`, the `window`, the `head_dim`, and `live_blocks` / `causal_blocks`,
 the key blocks the kernel visits over those of the causal half (equal
 without a window), with `masked_blocks`, those of the live blocks that an
-edge of the visible region crosses: the kernel masks these alone. A head
-narrower than the 128 lanes of a vector register (64) goes through the
+edge of the visible region crosses: the kernel masks these alone (a window
+of one block masks EVERY live block, and its tiles hold twice the band's
+pairs); `heads` and `kv_heads` as the core sees them (a net may change the
+first from layer to layer), `gate` = `none`, `elementwise` or `head`,
+`rope` = `none`, `plain` or `yarn` with `rope_factor` and `rope_scale`. A
+head narrower than the 128 lanes of a vector register (64) goes through the
 same kernels as a block of its own width: every q, k, v, o tile fills half
 of each lane row, two heads are NOT paired into one row, and the `reason`
 says so.
@@ -94,9 +122,12 @@ rotary_dim, and says so by name.
 Everything the layer traces lies under one scope inside its own, in every
 form, so that a device trace adds up by them: attn_proj_in (the q, k, v
 products with their weights' casts, the reshapes, the head norms, the
-output gate's split, the move to (B, H, S, D)), rope, attn_core (`_core`),
-attn_proj_out (the move back, the gate, the out projection).
+output gate's split or the per-head gate's product, the move to (B, H, S,
+D)), rope, attn_core (`_core`), attn_proj_out (the move back, the gate,
+the out projection).
 """
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -111,17 +142,53 @@ from .normalization import rms_norm
 from . import dsa
 
 
-def rotary(x, rotary_dim, theta):
+def yarn_range(rotary_dim, theta, original_positions, beta_fast, beta_slow):
+    """(low, high): the pair indices between which YaRN's ramp runs, as the
+    `transformers` library finds them — the dimension that turns `beta`
+    times inside `original_positions` is rotary_dim ln(original_positions /
+    (beta 2 pi)) / (2 ln theta); floor of the fast one's, ceil of the slow
+    one's, inside [0, rotary_dim - 1]."""
+    def turns(beta):
+        return rotary_dim * math.log(original_positions
+                                     / (beta * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    return (max(math.floor(turns(beta_fast)), 0),
+            min(math.ceil(turns(beta_slow)), rotary_dim - 1))
+
+
+def rope_table(rotary_dim, theta, yarn=None):
+    """The rotary's frequencies (rotary_dim / 2,) in float32: the plain
+    table theta ** (-2i / rotary_dim), or with `yarn` = (factor,
+    original_positions, beta_fast, beta_slow) YaRN's blend of it
+    (extrapolation) and of it divided by `factor` (interpolation):
+    inv_i = inter_i ramp_i + extra_i (1 - ramp_i), ramp_i = clip((i - low)
+    / (high - low), 0, 1) over `yarn_range`'s pair."""
+    inv = theta ** (-jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
+                    / rotary_dim)
+    if yarn is None:
+        return inv
+    factor, original_positions, beta_fast, beta_slow = yarn
+    low, high = yarn_range(rotary_dim, theta, original_positions, beta_fast,
+                           beta_slow)
+    ramp = jnp.clip((jnp.arange(rotary_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return inv / factor * ramp + inv * (1.0 - ramp)
+
+
+def rotary(x, rotary_dim, theta, yarn=None, scale=1.0):
     """Rotate-half rotary embedding of x (B, S, H, D) on its first
-    `rotary_dim` dimensions at positions 0..S-1, in float32."""
+    `rotary_dim` dimensions at positions 0..S-1, in float32, at the
+    frequencies of `rope_table(rotary_dim, theta, yarn)`; `scale`
+    multiplies cos and sin, so the turned dimensions alone."""
     if not rotary_dim:
         return x
     s = x.shape[1]
-    inv = theta ** (-jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
-                    / rotary_dim)
+    inv = rope_table(rotary_dim, theta, yarn)
     ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None, :]
     sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     xr = x[..., :rotary_dim].astype(jnp.float32)
     half = rotary_dim // 2
     rot = jnp.concatenate([-xr[..., half:], xr[..., :half]], -1)
@@ -161,6 +228,18 @@ class Attention(Layer):
         self.norm_eps = float(p.norm_eps)
         self.qk_zero_centered = bool(p.qk_norm_zero_centered)
         self.window = int(p.window)
+        # one scalar a head gates the output (a blob of its own)
+        self.head_gate = bool(p.head_gate)
+        # the rotary's table and the factor on its cos and sin
+        self.rope_type = str(p.rope_type)
+        self.rope_factor = float(p.rope_factor)
+        self.yarn = None
+        if self.rope_type == "yarn":
+            self.yarn = (self.rope_factor,
+                         int(p.rope_original_positions or 0),
+                         float(p.rope_beta_fast), float(p.rope_beta_slow))
+        self.rope_scale = float(p.rope_scale) if p.has("rope_scale") \
+            else 0.1 * math.log(self.rope_factor) + 1.0 if self.yarn else 1.0
         # a learned index picks the keys (ops/dsa.py)
         self.index = p.has("index_heads") or p.has("index_topk") \
             or p.has("index_head_dim")
@@ -177,10 +256,14 @@ class Attention(Layer):
             raise ValueError(f"{lp.name}: num_heads {self.num_heads} is not "
                              f"a multiple of num_kv_heads {self.kv_heads}")
         if not self.gqa and (self.qk_norm or self.rotary_dim
-                             or self.output_gate):
-            raise ValueError(f"{lp.name}: qk_norm, rotary_dim and "
-                             "output_gate need num_kv_heads (the "
+                             or self.output_gate or self.head_gate):
+            raise ValueError(f"{lp.name}: qk_norm, rotary_dim, output_gate "
+                             "and head_gate need num_kv_heads (the "
                              "grouped-query form)")
+        if self.head_gate and self.output_gate:
+            raise ValueError(f"{lp.name}: head_gate and output_gate are two "
+                             "forms of one gate; a layer takes one")
+        self._check_rope(p)
         if self.gqa and self.ring:
             raise ValueError(f"{lp.name}: the grouped-query form has no "
                              "ring mode")
@@ -202,7 +285,8 @@ class Attention(Layer):
                              (not self.causal, "no causal mask"),
                              (not self.gqa, "no num_kv_heads (the "
                               "grouped-query form)"),
-                             (self.output_gate, "an output gate")):
+                             (self.output_gate or self.head_gate,
+                              "an output gate")):
                 if bad:
                     raise ValueError(f"{lp.name}: an index picks the keys "
                                      f"of causal grouped-query attention; "
@@ -218,6 +302,32 @@ class Attention(Layer):
                     f"{lp.name}: index_head_dim {self.index_dim} is no "
                     "multiple of 4 (the rotary turns its first half)")
 
+    def _check_rope(self, p):
+        """The rotary table's fields, refused by name where they say
+        nothing: a table needs the rotary it is the table of."""
+        name = self.lp.name
+        if self.rope_type not in ("plain", "yarn"):
+            raise ValueError(f"{name}: rope_type {self.rope_type!r}: plain "
+                             "or yarn")
+        given = [f for f in ("rope_factor", "rope_original_positions",
+                             "rope_beta_fast", "rope_beta_slow")
+                 if p.has(f)]
+        if self.yarn is None:
+            if given:
+                raise ValueError(f"{name}: {', '.join(given)} belong to "
+                                 "rope_type yarn")
+        elif not self.rotary_dim:
+            raise ValueError(f"{name}: rope_type yarn needs rotary_dim (it "
+                             "is the rotary's table)")
+        elif self.yarn[1] < 1 or self.rope_factor < 1.0:
+            raise ValueError(
+                f"{name}: rope_type yarn needs rope_original_positions "
+                f"(at least 1, not {self.yarn[1]}) and a rope_factor of at "
+                f"least 1 (not {self.rope_factor})")
+        if p.has("rope_scale") and not self.rotary_dim:
+            raise ValueError(f"{name}: rope_scale multiplies the rotary's "
+                             "cos and sin and needs rotary_dim")
+
     def _init_latent(self, p):
         lp = self.lp
         lacks = [n for n in LATENT_SIZES
@@ -231,7 +341,10 @@ class Attention(Layer):
         for bad, why in (
                 (self.window, "window"), (self.ring, "ring"),
                 (self.index, "index_heads, index_head_dim or index_topk"),
-                (self.output_gate, "output_gate"), (self.qk_norm, "qk_norm"),
+                (self.output_gate, "output_gate"),
+                (self.head_gate, "head_gate"), (self.qk_norm, "qk_norm"),
+                (self.yarn or p.has("rope_scale"), "rope_type yarn or "
+                 "rope_scale (the latent form's rotary is the plain table)"),
                 (self.gqa, "num_kv_heads (every head has a key of its own "
                  "from the latent)"),
                 (self.rotary_dim, "rotary_dim (qk_rope_head_dim is the "
@@ -288,6 +401,9 @@ class Attention(Layer):
                     "FillerParameter", type="constant", value=1.0)
                 shapes += [((self.head_dim,), fill, *mults[4]),
                            ((self.head_dim,), fill, *mults[5])]
+            if self.head_gate:
+                shapes += [((self.num_heads, self.embed), wf,
+                            *mults[len(shapes)])]
             if self.index:
                 at = len(shapes)
                 one = Message("FillerParameter", type="constant", value=1.0)
@@ -347,7 +463,8 @@ class Attention(Layer):
         (B, Hkv, S, D): over the ring, through the flash kernel or dense,
         chosen from what the layer sees, and recorded as `attn.path` with
         what the caller `said` of its form besides."""
-        s, grp = q.shape[2], q.shape[1] // k.shape[1]
+        s, hk = q.shape[2], k.shape[1]
+        grp = q.shape[1] // hk
         seq_axis = context.axis("seq")
         live = half = masked = 0
         if self.ring and seq_axis is not None:
@@ -386,8 +503,18 @@ class Attention(Layer):
         tracer.record("attn.path", now, now, layer=self.lp.name, path=path,
                       reason=reason, window=self.window, live_blocks=live,
                       causal_blocks=half, masked_blocks=masked,
-                      head_dim=int(q.shape[-1]), **said)
+                      head_dim=int(q.shape[-1]), heads=int(q.shape[1]),
+                      kv_heads=int(hk), **self._gate_and_rope(), **said)
         return o
+
+    def _gate_and_rope(self):
+        """What `attn.path` says of the output gate's form and of the
+        rotary's table."""
+        gate = "head" if self.head_gate else \
+            "elementwise" if self.output_gate else "none"
+        rope = self.rope_type if self.rotary_dim or self.latent else "none"
+        return dict(gate=gate, rope=rope, rope_factor=self.rope_factor,
+                    rope_scale=self.rope_scale)
 
     def _apply_latent(self, params, x):
         """The latent form (the module's docstring has the equations)."""
@@ -440,7 +567,7 @@ class Attention(Layer):
         h, hk, d = self.num_heads, self.kv_heads, self.head_dim
         with jax.named_scope("attn_proj_in"):
             q = x @ wq.T
-            gate = None
+            gate = head_gate = None
             if self.output_gate:
                 q = q.reshape(b, s, h, 2 * d)
                 q, gate = q[..., :d], q[..., d:].reshape(b, s, h * d)
@@ -452,9 +579,18 @@ class Attention(Layer):
                              self.qk_zero_centered)
                 k = rms_norm(k, params[5], self.norm_eps,
                              self.qk_zero_centered)
+            if self.head_gate:
+                # one scalar a head and a token, in float32: (B, S, H)
+                with jax.named_scope("attn_gate"):
+                    wg = params[6 if self.qk_norm else 4].astype(x.dtype)
+                    head_gate = jax.nn.sigmoid(jnp.einsum(
+                        "bse,he->bsh", x, wg,
+                        preferred_element_type=jnp.float32))
         with jax.named_scope("rope"):
-            q = rotary(q, self.rotary_dim, self.rope_theta)
-            k = rotary(k, self.rotary_dim, self.rope_theta)
+            q = rotary(q, self.rotary_dim, self.rope_theta, self.yarn,
+                       self.rope_scale)
+            k = rotary(k, self.rotary_dim, self.rope_theta, self.yarn,
+                       self.rope_scale)
         with jax.named_scope("attn_proj_in"):
             q, k, v = [jnp.moveaxis(a, 1, 2) for a in (q, k, v)]  # (B,H,S,D)
         extra = []
@@ -464,7 +600,11 @@ class Attention(Layer):
             with jax.named_scope("attn_core"):
                 o = self._core(q, k, v)
         with jax.named_scope("attn_proj_out"):
-            o = jnp.moveaxis(o, 2, 1).reshape(b, s, h * d)
+            o = jnp.moveaxis(o, 2, 1)
+            if head_gate is not None:
+                with jax.named_scope("attn_gate"):
+                    o = o * head_gate.astype(o.dtype)[..., None]
+            o = o.reshape(b, s, h * d)
             if gate is not None:
                 o = o * jax.nn.sigmoid(
                     gate.astype(jnp.float32)).astype(o.dtype)
@@ -522,8 +662,9 @@ class Attention(Layer):
         tracer.record("attn.path", now, now, layer=self.lp.name, path=path,
                       reason=reason, window=0, live_blocks=tiles,
                       causal_blocks=tiles, masked_blocks=tiles,
-                      head_dim=int(q.shape[-1]), core=core, select=select,
-                      backward=backward,
+                      head_dim=int(q.shape[-1]), heads=int(q.shape[1]),
+                      kv_heads=int(k.shape[1]), **self._gate_and_rope(),
+                      core=core, select=select, backward=backward,
                       backward_kernels=int(path == "kernel"))
         full = min(s, topk)     # queries up to here take every key
         tracer.record("dsa.select", now, now, layer=self.lp.name, topk=topk,

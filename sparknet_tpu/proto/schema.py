@@ -628,6 +628,24 @@ MESSAGES = {
         "qk_nope_head_dim": (22, "uint32", "opt", None),
         "qk_rope_head_dim": (23, "uint32", "opt", None),
         "v_head_dim": (24, "uint32", "opt", None),
+        # the grouped-query form's PER-HEAD output gate: one more blob W_g
+        # (num_heads, E) after the head norms, o_h * sigmoid(W_g x)_h with
+        # one scalar a head and a token (output_gate is the elementwise
+        # one, which doubles W_q; a layer takes one of the two)
+        "head_gate": (25, "bool", "opt", False),
+        # the rotary's frequency table (with rotary_dim): "plain" is
+        # rope_theta ** (-2i / rotary_dim); "yarn" blends it with the table
+        # divided by rope_factor over the dimensions between the ones that
+        # turn rope_beta_fast and rope_beta_slow times inside
+        # rope_original_positions (ops/attention.py:rope_table), and
+        # rope_scale multiplies cos and sin (absent with yarn: 0.1 ln
+        # rope_factor + 1)
+        "rope_type": (26, "string", "opt", "plain"),
+        "rope_factor": (27, "float", "opt", 1.0),
+        "rope_original_positions": (28, "uint32", "opt", None),
+        "rope_beta_fast": (29, "float", "opt", 32.0),
+        "rope_beta_slow": (30, "float", "opt", 1.0),
+        "rope_scale": (31, "double", "opt", None),
     },
     # sparknet_tpu extension: y[i] = x[i + offset] along `axis`; the places
     # that read past either end hold `fill` (a second prediction depth's

@@ -1,7 +1,7 @@
 """What the language-model family files (`test_qwen3_next.py`,
 `test_smallthinker.py`, `test_lfm2_moe.py`, `test_keye_vl2.py`,
-`test_nemotron_h.py`, `test_glm4_moe_lite.py`) share; pytest collects
-nothing here.
+`test_nemotron_h.py`, `test_glm4_moe_lite.py`, `test_laguna.py`) share;
+pytest collects nothing here.
 
 A family is a `Family` record: its builder in `models/zoo.py`, its plain
 reference (`benchmark/reference/<family>.py`, imported from where it lies,
@@ -300,6 +300,39 @@ GLM4_MOE_LITE = Family(
         builder_args=dict({"seq_len": 64}, **args)),
     over=dict(flash=False), step_tol=(5e-5, 5e-3, 1e-12),
     runs=(dict(n=4, glen=6, entry="block0/res2"),))
+
+
+_LAGUNA = dict(
+    hidden_size=32, intermediate_size=48, num_hidden_layers=5,
+    num_key_value_heads=1, head_dim=16,
+    layer_types=["full_attention", "sliding_attention", "sliding_attention",
+                 "sliding_attention", "full_attention"],
+    num_attention_heads_per_layer=[6, 8, 8, 8, 6],
+    mlp_layer_types=["dense", "sparse", "sparse", "sparse", "sparse"],
+    sliding_window=24,
+    rope_parameters={
+        "full_attention": dict(
+            rope_theta=10000, rope_type="yarn", factor=4,
+            original_max_position_embeddings=64, beta_slow=1, beta_fast=4,
+            attention_factor=1.1386294361119891, partial_rotary_factor=0.5),
+        "sliding_attention": dict(rope_type="default", rope_theta=100,
+                                  partial_rotary_factor=1)},
+    rms_norm_eps=1e-6, num_experts=4, num_experts_per_tok=2,
+    moe_intermediate_size=16, shared_expert_intermediate_size=16,
+    moe_routed_scaling_factor=2.5, vocab_size=64, router_outputs=16,
+    first_expert=0, seq_len=64)
+
+#: the leading dense block with full attention is a body of its own, the
+#: three window blocks one run, the full block after them a body again; a
+#: full layer has 6 query heads on the one key-value head and a window
+#: layer 8, as the published 48 and 64 on 8
+LAGUNA = Family(
+    "laguna", zoo.laguna, _LAGUNA, "num_experts",
+    lambda **args: _config(
+        _LAGUNA, (), published={"num_experts": 16},
+        builder_args=dict({"seq_len": 64}, **args)),
+    step_tol=(5e-5, 5e-3, 1e-12),
+    runs=(dict(n=3, glen=6, entry="block0/res2"),))
 
 
 # ------------------------------------------ the bodies of the shared tests

@@ -195,6 +195,11 @@ WINDOW_CASES = {
     "wide_key_blocks": (256, 37, 32, 64, 2, 1),
     "wide_query_blocks": (384, 64, 128, 64, 2, 1),
     "seven_queries_a_kv_head": (256, 96, 64, 64, 7, 1),
+    # a window EQUAL to the block, so that every live tile is masked (the
+    # diagonal's by the causal edge, the one before it by the window's), at
+    # eight and at six query heads a key-value head
+    "window_is_the_block_group_of_8": (256, 64, 64, 64, 8, 1),
+    "window_is_the_block_group_of_6": (256, 64, 64, 64, 12, 2),
 }
 
 
@@ -336,7 +341,8 @@ def test_interior_tiles_have_no_hidden_pair_and_edge_tiles_have_one(case):
 @pytest.mark.parametrize("s,window,want", [
     (16384, 4096, (56, 252)),       # SmallThinker's window layers
     (16384, 0, (32, 528)),          # its global layer
-    (8192, 0, (16, 136))])          # the Qwen3-Next and LFM2 cells
+    (8192, 0, (16, 136)),           # the Qwen3-Next and LFM2 cells
+    (8192, 512, (31, 31))])         # a window of one block: all masked
 def test_masked_blocks_at_the_cells_shapes(s, window, want):
     from sparknet_tpu.ops.pallas_attention import band_blocks, edge_blocks
     assert (edge_blocks(s, window), band_blocks(s, window)[0]) == want

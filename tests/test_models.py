@@ -126,7 +126,8 @@ class TestZooParity:
     ("transformer_lm", dict(with_data=False)),
     ("qwen3_next", {}), ("smallthinker", {}), ("lfm2_moe", {}),
     ("keye_vl2", dict(index_stats=True, moe_stats=True)),
-    ("nemotron_h", dict(layers=(35, 44), ssm_stats=True))])
+    ("nemotron_h", dict(layers=(35, 44), ssm_stats=True)),
+    ("glm4_moe_lite", {}), ("laguna", dict(moe_stats=True))])
 def test_an_lm_builder_keeps_the_stacks_naming_contract(builder, args):
     """`zoo._lm_stack` states it and `tests/lm_family.py:stack_contract`
     reads it off the built net, at the published sizes: a block's layers

@@ -176,7 +176,10 @@ def test_the_readers_list_of_inner_scopes_is_the_programs():
     # nor are the state-space mixer's (PR 42): they count under `ssm`; nor
     # the latent attention's three (PR 46), which lie INSIDE `attn_proj_in`
     # and count under it (the deepest element of `INNER` on a path decides)
-    assert {s for s in opened
+    # nor the per-head gate's (PR 49), opened inside `attn_proj_in` (its
+    # product and sigmoid) and inside `attn_proj_out` (its multiply)
+    assert "attn_gate" in opened
+    assert {s for s in opened - {"attn_gate"}
             if not s.startswith(("dsa_", "ssm_", "mla_"))} == \
         set(step_parts.INNER)
     assert {s for s in opened if s.startswith("mla_")} == {
@@ -283,6 +286,13 @@ def test_part_rules_in_order():
     assert part("f", SCAN + "/body/closed_call/block0/moe/moe_glue/"
                 "jit(_held_fwd)/while/body/moe_experts/moe_gmm_fwd/"
                 "custom_call") == "moe_experts"
+    # a scope INNER does not know counts under the one it lies inside: the
+    # per-head gate's product under attn_proj_in, its multiply (here its
+    # gradient) under attn_proj_out
+    assert part("f", "jit(step)/jvp(block0/mixer)/attn_proj_in/attn_gate/"
+                "dot_general") == "attn_proj_in"
+    assert part("f", "jit(step)/transpose(jvp(block0/mixer))/attn_proj_out/"
+                "attn_gate/mul") == "attn_proj_out"
     assert part("f", SCAN + "/body/closed_call/block0/ln1/mul") == "norm"
     assert part("f", SCAN + "/body/closed_call/block0/mixer/mul") == "gdn"
     assert part("%while.7 = (f32[8]{0}) while(%t), body=%b", SCAN) \

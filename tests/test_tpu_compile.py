@@ -432,6 +432,59 @@ def test_latent_attention_layer_compiles_to_the_flash_kernels(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 0.95e9
 
 
+# the two kinds of attention layer of the window/full hybrid LM with two
+# head counts, at its cell's shape (2 x 8,192 tokens of 2,048 in bfloat16, 8
+# key-value heads of 128): (query heads, window, rotary dims, the rotary
+# table's fields, the kernels)
+GATED_GQA = {
+    "full_48_yarn": (48, 0, 64, dict(
+        rope_type="yarn", rope_factor=64, rope_original_positions=4096,
+        rope_beta_fast=64, rope_beta_slow=1, rope_scale=1.4158883083359672),
+        ("flash_fwd", "flash_dq", "flash_dkv")),
+    "window512_64": (64, 512, 128, {},
+                     ("flash_swa_fwd", "flash_swa_dq", "flash_swa_dkv"))}
+
+
+@pytest.mark.parametrize("kind", list(GATED_GQA))
+def test_head_gated_attention_layer_compiles_to_the_flash_kernels(
+        one_chip, monkeypatch, kind):
+    """One layer's forward and backward: a window EQUAL to the kernels'
+    block of 512 compiles at eight query heads a key-value head and the
+    causal form at six, the per-head gate's product and multiply lie under
+    `attn_gate` inside `attn_proj_in` and `attn_proj_out`, and the rotary
+    under `rope`."""
+    from sparknet_tpu.graph.registry import get as get_layer
+    from sparknet_tpu.models import dsl
+    monkeypatch.setattr(pa, "_should_interpret", lambda: False)
+    heads, window, rotary_dim, rope, kernels = GATED_GQA[kind]
+    lp = dsl.AttentionLayer(
+        "attn", ["x"], heads, head_dim=128, causal=True, flash=True,
+        num_kv_heads=8, rotary_dim=rotary_dim,
+        rope_theta=1e4 if window else 5e5, rope=rope, gate="head",
+        window=window)
+    x = ((2, 8192, 2048), jnp.bfloat16)
+    impl = get_layer(lp.type)(lp, [x[0]], 0)
+    assert [s[0] for s in impl.param_shapes()] == [
+        (heads * 128, 2048), (1024, 2048), (1024, 2048),
+        (2048, heads * 128), (heads, 2048)]
+    blobs = [(s[0], jnp.float32) for s in impl.param_shapes()]
+
+    def grads(x, cot, *blobs):
+        def loss(x, blobs):
+            y = impl.apply(list(blobs), [x], True, None)[0]
+            return jnp.sum(y.astype(jnp.float32) * cot)
+        return jax.grad(loss, (0, 1))(x, blobs)
+
+    compiled = _compile(grads, one_chip, x, (x[0], jnp.float32), *blobs,
+                        kernels=kernels)
+    text = compiled.as_text()
+    for kernel in kernels:
+        assert re.search(rf"attn_core[^\"]*/{kernel}", text), kernel
+    assert re.search(r"attn_proj_in\)*/attn_gate/", text)
+    assert re.search(r"attn_proj_out\)*/attn_gate/", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
 # LRN where CaffeNet runs it (after each pool) and at GoogLeNet's conv2
 # site, the one 3-op conv+relu+lrn site SPARKNET_EPILOGUE=auto fuses
 CAFFENET_NORM1 = (256, 96, 27, 27)
