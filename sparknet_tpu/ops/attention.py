@@ -45,8 +45,23 @@ rope_factor, L = rope_original_positions:
 rope_scale multiplies cos and sin (absent with yarn: 0.1 ln f + 1), so the
 turned dimensions of q and of k are scaled and the passing ones are not. A
 net chooses table and theta layer by layer: window layers with the plain
-table on the whole head beside full layers with YaRN on half of it. A layer
-that names none of these traces the operations it traced before them.
+table on the whole head beside full layers with YaRN on half of it.
+
+The rotary is ONE pass over its operand (`rotary`), x cos + rotate_half(x)
+sin in float32 with float32 tables: rotate-half is the product of x with a
+constant D x D matrix of 0 and +-1 (exact: every output is one input times
++-1, accumulated in float32), and cos and sin are as wide as the head, 1
+and 0 on the dimensions that pass, so nothing slices or joins the lane
+axis. The written-out form (`concatenate([-x2, x1])`, and a second join
+for a partial rotary) XLA does not fuse on a TPU: it made three passes of
+it, forward and backward each — a float32 copy of x, two half-width
+float32 arrays padded to whole lane rows, then the arithmetic —, 9.20 GB
+moved for the VJP at a bf16 q of (2, 8192, 64, 128) where reading and
+writing q twice is 1.07 GB, 101 ms of a 620 ms step in the cell that
+showed it most. The product is one fusion a pass with the move to (B, H,
+S, D) inside it: 2.22 GB for the same VJP, the same bits. Its backward is
+its own (`jax.custom_vjp`): the same pass over the cotangent with sin
+negated, the tables rebuilt from the static arguments.
 
 attention_param.window (with causal) is a sliding window: query i sees keys
 i - window < j <= i. The flash kernel then runs over the band of key blocks
@@ -60,7 +75,8 @@ edge of the visible region crosses: the kernel masks these alone (a window
 of one block masks EVERY live block, and its tiles hold twice the band's
 pairs); `heads` and `kv_heads` as the core sees them (a net may change the
 first from layer to layer), `gate` = `none`, `elementwise` or `head`,
-`rope` = `none`, `plain` or `yarn` with `rope_factor` and `rope_scale`. A
+`rope` = `none`, `plain` or `yarn` with `rope_factor` and `rope_scale`, and
+`rope_form`, how the rotary ran in words (`ROPE_FORM`, or `none`). A
 head narrower than the 128 lanes of a vector register (64) goes through the
 same kernels as a block of its own width: every q, k, v, o tile fills half
 of each lane row, two heads are NOT paired into one row, and the `reason`
@@ -127,10 +143,12 @@ D)), rope, attn_core (`_core`), attn_proj_out (the move back, the gate,
 the out projection).
 """
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..proto import Message
 from ..graph.registry import Layer, register
@@ -175,25 +193,97 @@ def rope_table(rotary_dim, theta, yarn=None):
     return inv / factor * ramp + inv * (1.0 - ramp)
 
 
-def rotary(x, rotary_dim, theta, yarn=None, scale=1.0):
-    """Rotate-half rotary embedding of x (B, S, H, D) on its first
-    `rotary_dim` dimensions at positions 0..S-1, in float32, at the
-    frequencies of `rope_table(rotary_dim, theta, yarn)`; `scale`
-    multiplies cos and sin, so the turned dimensions alone."""
-    if not rotary_dim:
-        return x
-    s = x.shape[1]
+#: what `attn.path` says of how a layer's rotary ran (`rope_form`)
+ROPE_FORM = "one pass: rotate-half as a product"
+
+
+def _rotate_half_matrix(d, rotary_dim, offset, dtype):
+    """R (d, d) of 0 and +-1 with x @ R = rotate-half of x on the dimensions
+    [offset, offset + rotary_dim): [-x2 | x1] of their halves [x1 | x2], and
+    0 on every other dimension. A trace-time constant, a numpy array: the
+    trace puts nothing on the device for it."""
+    half = rotary_dim // 2
+    r = np.zeros((d, d), dtype)
+    j = np.arange(half) + offset
+    r[j + half, j] = -1
+    r[j, j + half] = 1
+    return r
+
+
+def _rope_cos_sin(s, d, rotary_dim, theta, yarn, scale, offset):
+    """cos and sin (1, s, 1, d) in float32 at positions 0..s-1, the angles of
+    `rope_table` on both halves of [offset, offset + rotary_dim), `scale`
+    on both; cos = 1 and sin = 0 on the dimensions that pass."""
     inv = rope_table(rotary_dim, theta, yarn)
     ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None, :]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None, :]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)
     if scale != 1.0:
         cos, sin = cos * scale, sin * scale
-    xr = x[..., :rotary_dim].astype(jnp.float32)
-    half = rotary_dim // 2
-    rot = jnp.concatenate([-xr[..., half:], xr[..., :half]], -1)
-    out = (xr * cos + rot * sin).astype(x.dtype)
-    return jnp.concatenate([out, x[..., rotary_dim:]], -1)
+    pad = ((0, 0), (offset, d - offset - rotary_dim))
+    return (jnp.pad(cos, pad, constant_values=1.0)[None, :, None, :],
+            jnp.pad(sin, pad)[None, :, None, :])
+
+
+def _turn(x, table, sign):
+    """x cos + rotate_half(x) (sign sin) in float32, in x's dtype, `table`
+    being `rotary`'s static arguments. An x that is not bfloat16 goes
+    through the MXU at HIGHEST precision, so that a float32 net on the chip
+    is not rounded to bfloat16 on the way."""
+    rotary_dim, theta, yarn, scale, offset = table
+    d = x.shape[-1]
+    cos, sin = _rope_cos_sin(x.shape[1], d, rotary_dim, theta, yarn, scale,
+                             offset)
+    rot = jax.lax.dot_general(
+        x, _rotate_half_matrix(d, rotary_dim, offset, x.dtype),
+        (((3,), (0,)), ((), ())),
+        precision=None if x.dtype == jnp.bfloat16
+        else jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * cos + rot * (sign * sin)).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _rotary(x, table):
+    return _turn(x, table, 1.0)
+
+
+def _rotary_fwd(x, table):
+    return _turn(x, table, 1.0), None
+
+
+def _rotary_bwd(table, _, g):
+    # the transpose of a turn is the turn by the negative angle; the table
+    # is rebuilt from the static arguments, nothing is kept for it
+    return (_turn(g, table, -1.0),)
+
+
+_rotary.defvjp(_rotary_fwd, _rotary_bwd)
+
+
+def rotary(x, rotary_dim, theta, yarn=None, scale=1.0, offset=0):
+    """Rotate-half rotary embedding of x (B, S, H, D) on the `rotary_dim`
+    dimensions from `offset` on, at positions 0..S-1, in float32, at the
+    frequencies of `rope_table(rotary_dim, theta, yarn)`; `scale`
+    multiplies cos and sin, so the turned dimensions alone.
+
+    Nothing slices or joins x's last axis. Written with slices
+    (`concatenate([-x2, x1])`, and a second join where part of the head
+    passes) the chip's compiler made THREE passes of it, forward and
+    backward each: a float32 copy of x, two half-width float32 arrays
+    padded to whole lane rows, then the arithmetic; for the VJP at a bf16
+    q of (2, 8192, 64, 128) it counted 9.20 GB moved and 1.61 GB of
+    temporaries where reading and writing q twice is 1.07 GB. Here
+    rotate-half is a product with a constant D x D matrix of 0 and +-1 and
+    the tables are as wide as the head (cos 1 and sin 0 where a dimension
+    passes): one fusion a pass, 2.22 GB, no temporaries, the same bits.
+    Under its own VJP — the same pass over the cotangent with sin negated —
+    because autodiff of the product would send the float32 `g sin` through
+    the MXU at default precision, a rounding the backward never had."""
+    if not rotary_dim:
+        return x
+    return _rotary(x, (int(rotary_dim), float(theta), yarn, float(scale),
+                       int(offset)))
 
 
 #: the latent form's sizes, in the order of AttentionParameter's fields
@@ -512,9 +602,10 @@ class Attention(Layer):
         rotary's table."""
         gate = "head" if self.head_gate else \
             "elementwise" if self.output_gate else "none"
-        rope = self.rope_type if self.rotary_dim or self.latent else "none"
-        return dict(gate=gate, rope=rope, rope_factor=self.rope_factor,
-                    rope_scale=self.rope_scale)
+        turns = self.rotary_dim or self.latent
+        return dict(gate=gate, rope=self.rope_type if turns else "none",
+                    rope_form=ROPE_FORM if turns else "none",
+                    rope_factor=self.rope_factor, rope_scale=self.rope_scale)
 
     def _apply_latent(self, params, x):
         """The latent form (the module's docstring has the equations)."""
@@ -537,10 +628,9 @@ class Attention(Layer):
                                self.norm_eps, False)
                 kv = (ckv @ wkvb.T).reshape(b, s, h, dn + dv)
         with jax.named_scope("rope"):
-            q_pe = rotary(q[..., dn:], dr, self.rope_theta)
+            q = rotary(q, dr, self.rope_theta, offset=dn)
             k_pe = rotary(k_pe, dr, self.rope_theta)
         with jax.named_scope("attn_proj_in"):
-            q = jnp.concatenate([q[..., :dn], q_pe], -1)
             with jax.named_scope("mla_k_assemble"):
                 k = jnp.concatenate(
                     [kv[..., :dn], jnp.broadcast_to(k_pe, (b, s, h, dr))],

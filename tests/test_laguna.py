@@ -163,35 +163,65 @@ def test_yarn_table_and_factor_at_the_published_numbers():
         rtol=2e-5, atol=2e-6)
 
 
-@pytest.mark.parametrize("rotary_dim,theta", [(16, 1e4), (8, 1.5e6),
-                                              (64, 1e7)])
-def test_a_plain_table_is_bit_for_bit_the_rotary_of_before(rotary_dim,
-                                                           theta):
-    """`rotary(x, rotary_dim, theta)` as it stood before the table was a
-    parameter, written out here: a net that names no table traces the same
-    operations and gets the same bits."""
+# a YaRN table as the full layers' (the toy's fields) with its scale
+YARN_TABLE = (4.0, 64, 4.0, 1.0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("d,rotary_dim,offset,theta,yarn,scale", [
+    (64, 16, 0, 1e4, None, 1.0), (64, 8, 0, 1.5e6, None, 1.0),
+    (64, 64, 0, 1e7, None, 1.0), (128, 64, 0, 5e5, None, 1.0),
+    (128, 128, 0, 1e4, None, 1.0), (256, 64, 192, 1e6, None, 1.0),
+    (128, 64, 0, 5e5, YARN_TABLE, 1.1386294361119891)])
+def test_a_plain_table_is_bit_for_bit_the_rotary_of_before(
+        d, rotary_dim, offset, theta, yarn, scale, dtype):
+    """`rotary` as it stood before it was one pass, written out here with
+    its slices and joins (an `offset` was the latent form's slice and join
+    round the call): the product with the matrix of 0 and +-1 and the
+    full-width tables give the same BITS, values and gradients, in both
+    dtypes, at the head widths and turned spans the cells run. Operation
+    by operation, as the tests' CPU runs a layer outside `jit`: inside it
+    XLA:CPU contracts `a b + c d` into one of two fused multiply-adds,
+    not the same one in both programs (1 ulp; none with
+    `--xla_cpu_max_isa=AVX`)."""
     def before(x):
+        lead, x = x[..., :offset], x[..., offset:]
         s = x.shape[1]
-        inv = theta ** (-jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
-                        / rotary_dim)
+        inv = attn_ops.rope_table(rotary_dim, theta, yarn)
         ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
         cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None, :]
         sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None, :]
+        if scale != 1.0:
+            cos, sin = cos * scale, sin * scale
         xr = x[..., :rotary_dim].astype(jnp.float32)
         half = rotary_dim // 2
         rot = jnp.concatenate([-xr[..., half:], xr[..., :half]], -1)
         out = (xr * cos + rot * sin).astype(x.dtype)
-        return jnp.concatenate([out, x[..., rotary_dim:]], -1)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 96, 3, 64))
+        return jnp.concatenate([lead, out, x[..., rotary_dim:]], -1)
 
     def now(x):
-        return attn_ops.rotary(x, rotary_dim, theta)
-    assert str(jax.make_jaxpr(now)(x)) == str(jax.make_jaxpr(before)(x))
-    np.testing.assert_array_equal(np.asarray(now(x)), np.asarray(before(x)))
-    np.testing.assert_array_equal(
-        np.asarray(attn_ops.rope_table(rotary_dim, theta)),
-        np.asarray(theta ** (-jnp.arange(0, rotary_dim, 2,
-                                         dtype=jnp.float32) / rotary_dim)))
+        return attn_ops.rotary(x, rotary_dim, theta, yarn, scale, offset)
+    x, cot = (jax.random.normal(jax.random.PRNGKey(k), (2, 96, 3, d))
+              .astype(dtype) for k in (1, 2))
+    got, want = now(x), before(x)
+    assert got.dtype == want.dtype == dtype
+    assert np.asarray(want[..., offset:offset + rotary_dim]
+                      != x[..., offset:offset + rotary_dim]).mean() > 0.3
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def grad(f):
+        return jax.grad(lambda x: jnp.sum(
+            f(x).astype(jnp.float32) * cot.astype(jnp.float32)))(x)
+    g_got, g_want = grad(now), grad(before)
+    assert g_got.dtype == dtype and np.asarray(g_want != cot).mean() > 0.05
+    np.testing.assert_array_equal(np.asarray(g_got), np.asarray(g_want))
+    if yarn is None:
+        np.testing.assert_array_equal(
+            np.asarray(attn_ops.rope_table(rotary_dim, theta)),
+            np.asarray(theta ** (-jnp.arange(0, rotary_dim, 2,
+                                             dtype=jnp.float32)
+                                 / rotary_dim)))
 
 
 @pytest.mark.parametrize("fields,why", [
@@ -238,6 +268,7 @@ def test_attn_path_says_the_heads_the_gate_and_the_table():
                 rec["rope_factor"], rec["window"]) == \
             (6, 1, "head", "yarn", 4.0, 0)
         assert rec["rope_scale"] == pytest.approx(1.1386294361119891)
+        assert rec["rope_form"] == attn_ops.ROPE_FORM
         rec = record(attention_layer(WINDOW, seq, flash), seq)
         assert (rec["heads"], rec["kv_heads"], rec["gate"], rec["rope"],
                 rec["rope_factor"], rec["rope_scale"], rec["window"]) == \
@@ -258,8 +289,8 @@ def test_attn_path_says_the_heads_the_gate_and_the_table():
         "attn", ["x"], 4, head_dim=16, causal=True, num_kv_heads=2,
         output_gate=True), [(2, 64, 32)])
     rec = record(gated, 64)
-    assert (rec["gate"], rec["rope"], rec["path"]) == \
-        ("elementwise", "none", "dense")
+    assert (rec["gate"], rec["rope"], rec["rope_form"], rec["path"]) == \
+        ("elementwise", "none", "none", "dense")
 
 
 # ----------------------------------------------------- the MoE's shares

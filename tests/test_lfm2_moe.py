@@ -78,11 +78,11 @@ def test_short_conv_taps_are_filled_uniform_and_the_path_is_recorded():
     assert taps.type == "uniform"
     assert abs(taps.max - 3 ** -0.5) < 1e-6 and taps.min == -taps.max
     tracer = default_tracer()
-    before = len(tracer.spans("shortconv.path"))
+    mark = tracer.mark()
     x = jnp.ones((1, 8, 32))
     text = str(jax.make_jaxpr(lambda x, p: impl.apply(p, [x], True, None))(
         x, fill(impl, jax.random.PRNGKey(0))))
-    (rec,) = tracer.spans("shortconv.path")[before:]
+    (rec,) = tracer.since(mark, "shortconv.path")
     assert (rec["layer"], rec["kernel"], rec["channels"]) == \
         ("blockX/mixer", 3, 32) and rec["form"].startswith("xla")
     lowered = jax.jit(lambda x, p: impl.apply(p, [x], True, None)).lower(
@@ -124,7 +124,7 @@ def test_attention_with_the_plain_head_norms_matches_reference(
     d = dict(TOY, num_attention_heads=heads, num_key_value_heads=kv,
              head_dim=head)
     tracer = default_tracer()
-    before = len(tracer.spans("attn.path"))
+    mark = tracer.mark()
 
     def mine(x, blobs):
         return impl.apply(blobs, [x], True, None)[0]
@@ -133,7 +133,7 @@ def test_attention_with_the_plain_head_norms_matches_reference(
         return jnp.stack([ref.attention(x[b], blobs, d, rows=8)
                           for b in range(2)])
     same_value_and_grads(mine, theirs, (x, blobs), cot)
-    rec = tracer.spans("attn.path")[before]
+    rec = tracer.since(mark, "attn.path")[0]
     assert rec["head_dim"] == head
     assert rec["path"] == ("kernel" if flash else "dense")
     if flash and head == 64:

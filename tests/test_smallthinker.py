@@ -104,11 +104,13 @@ def test_attn_path_records_say_which_core_ran():
     for flash, seq, window in ((True, 256, 64), (True, 256, 0),
                                (False, 256, 64), (True, 200, 64)):
         impl = attention_layer(flash, seq, 1, window)
-        before = len(tracer.spans("attn.path"))
+        mark = tracer.mark()
         impl.apply(fill(impl, jax.random.PRNGKey(1)), [x[:, :seq]], True,
                    None)
-        (rec,) = tracer.spans("attn.path")[before:]
+        (rec,) = tracer.since(mark, "attn.path")
         assert rec["layer"] == "attn" and rec["window"] == window
+        assert rec["rope"] == "plain" and rec["rope_form"] == \
+            "one pass: rotate-half as a product"
         if flash and seq % 128 == 0:
             assert rec["path"] == "kernel"
             # one block of 256 at this size: the band is the block

@@ -435,14 +435,17 @@ def test_latent_attention_layer_compiles_to_the_flash_kernels(one_chip,
 # the two kinds of attention layer of the window/full hybrid LM with two
 # head counts, at its cell's shape (2 x 8,192 tokens of 2,048 in bfloat16, 8
 # key-value heads of 128): (query heads, window, rotary dims, the rotary
-# table's fields, the kernels)
+# table's fields, the kernels, the layer's temporaries: a tenth above what
+# the sandbox's compile reads, 0.917 and 1.188 GB — 1.819 and 2.304 GB
+# while the rotary was three passes with float32 copies between them)
 GATED_GQA = {
     "full_48_yarn": (48, 0, 64, dict(
         rope_type="yarn", rope_factor=64, rope_original_positions=4096,
         rope_beta_fast=64, rope_beta_slow=1, rope_scale=1.4158883083359672),
-        ("flash_fwd", "flash_dq", "flash_dkv")),
+        ("flash_fwd", "flash_dq", "flash_dkv"), 1.01e9),
     "window512_64": (64, 512, 128, {},
-                     ("flash_swa_fwd", "flash_swa_dq", "flash_swa_dkv"))}
+                     ("flash_swa_fwd", "flash_swa_dq", "flash_swa_dkv"),
+                     1.31e9)}
 
 
 @pytest.mark.parametrize("kind", list(GATED_GQA))
@@ -452,11 +455,12 @@ def test_head_gated_attention_layer_compiles_to_the_flash_kernels(
     block of 512 compiles at eight query heads a key-value head and the
     causal form at six, the per-head gate's product and multiply lie under
     `attn_gate` inside `attn_proj_in` and `attn_proj_out`, and the rotary
-    under `rope`."""
+    under `rope` in ONE pass over q: no half-width float32 copy of the
+    heads (`rotary`'s slices made two a call) and half the temporaries."""
     from sparknet_tpu.graph.registry import get as get_layer
     from sparknet_tpu.models import dsl
     monkeypatch.setattr(pa, "_should_interpret", lambda: False)
-    heads, window, rotary_dim, rope, kernels = GATED_GQA[kind]
+    heads, window, rotary_dim, rope, kernels, temp_limit = GATED_GQA[kind]
     lp = dsl.AttentionLayer(
         "attn", ["x"], heads, head_dim=128, causal=True, flash=True,
         num_kv_heads=8, rotary_dim=rotary_dim,
@@ -482,7 +486,34 @@ def test_head_gated_attention_layer_compiles_to_the_flash_kernels(
         assert re.search(rf"attn_core[^\"]*/{kernel}", text), kernel
     assert re.search(r"attn_proj_in\)*/attn_gate/", text)
     assert re.search(r"attn_proj_out\)*/attn_gate/", text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+    assert re.search(r"op_name=\"[^\"]*[(/]rope\)*/", text)
+    assert not re.search(rf"f32\[2,8192,{heads},64\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
+
+
+def test_rotary_and_its_vjp_are_one_pass_each(one_chip):
+    """`rotary` alone at the Laguna window layers' q with the move to (B,
+    H, S, D) that follows it in the layer, forward and VJP: no float32
+    array of q's size and no half-width one among the temporaries (1.61 GB
+    of them while rotate-half was a slice and a join; 4 MB of tables now),
+    and rotate-half is the product."""
+    from sparknet_tpu.ops.attention import rotary
+
+    def both(x, cot):
+        def turned(x):
+            with jax.named_scope("rope"):
+                return jnp.moveaxis(rotary(x, 128, 1e4), 1, 2)
+        y, vjp = jax.vjp(turned, x)
+        return y, vjp(cot)[0]
+
+    compiled = jax.jit(both).lower(*[
+        jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+        for s in ((2, 8192, 64, 128), (2, 64, 8192, 128))]).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"rope\)*/dot_general", text)) >= 2
+    entry = text[text.index("ENTRY"):]
+    assert not re.search(r"f32\[2,8192,64,(128|64)\]", entry)
+    assert compiled.memory_analysis().temp_size_in_bytes < 50e6
 
 
 # LRN where CaffeNet runs it (after each pool) and at GoogLeNet's conv2
