@@ -19,6 +19,10 @@ Layer map (vs reference SURVEY.md section 1):
   models/    NetParam DSL + model builders (replaces Layers.scala)
   utils/     checkpoint, metrics, timing, signals
 """
+import time as _time
+#: the ring's clock (obs/trace.py) when the package's import began: the
+#: process's first Solver writes `package.import` from here
+IMPORT_NS = _time.perf_counter_ns()
 
 __version__ = "0.1.0"
 

@@ -25,6 +25,7 @@ from ..proto.message import Message
 from . import fillers as F
 from .registry import get as get_layer, V1_TYPE_MAP
 from .remat import KERNEL_OUT
+from ..obs.trace import kernel_import
 
 # import for registration side effects
 from .. import ops as _ops  # noqa: F401
@@ -577,7 +578,8 @@ class CompiledNet:
         lrng = jax.random.fold_in(rng, li) if impl.needs_rng else None
         fuse = ep.get(li) if ep else None
         if fuse is not None and max(x for x in fuse if x is not None) < hi:
-            from ..ops import pallas_epilogue as pe
+            with kernel_import("sparknet_tpu.ops.pallas_epilogue"):
+                from ..ops import pallas_epilogue as pe
             ri, lrni = fuse
             bvals = [fission.materialize(v) for v in bvals]
             y = impl.apply_raw(lparams, bvals, train, lrng)

@@ -116,7 +116,7 @@ EVENTS = {
         "open": True,
     },
     'recompile': {
-        "fields": ['cache_size', 'first', 'iter', 'reason'],
+        "fields": ['backend_s', 'cache', 'cache_size', 'cause', 'first', 'iter', 'lower_s', 'reason'],
         "open": False,
     },
     'recovery': {

@@ -2,8 +2,8 @@
 
 The two silent step-time killers on XLA backends are recompiles (a shape
 change retraces mid-run) and memory growth (live arrays accumulating
-until allocator pressure or an OOM). stepstats.py already *detects*
-recompiles from the jitted callable's cache growth; this module samples
+until allocator pressure or an OOM). stepstats.py already *reports*
+recompiles from the tracer's ``program.build`` records; this module samples
 the surrounding state on the same cadence so a regression is
 explainable from the metrics stream alone:
 
@@ -11,9 +11,10 @@ explainable from the metrics stream alone:
                     holds (leaks show up as a monotonic climb)
   device memory     bytes_in_use / peak_bytes_in_use where the backend
                     reports them (TPU/GPU; absent on CPU)
-  compile cache     executable count across the solver's tracked jitted
-                    fns — growth beyond the expected warmup is the
-                    recompile storm stepstats flags per event
+  compile cache     programs the step's enqueue has built so far (the
+                    same records, obs/trace.py:StepBuilds) — growth
+                    beyond the expected warmup is the recompile storm
+                    stepstats flags per event
   host rss          ru_maxrss, the host-side twin (prefetch buffers,
                     snapshot staging)
 
@@ -21,6 +22,8 @@ Emitted as ``memstats`` events next to each sampled ``step``/round, so
 `sparknet report` and `sparknet monitor` can show memory next to step
 time.
 """
+
+from .trace import StepBuilds
 
 
 def live_array_stats():
@@ -51,35 +54,22 @@ def host_rss_bytes():
         return None
 
 
-def compile_cache_size(jit_fns):
-    """Total executable-cache entries across jitted callables (None when
-    none expose _cache_size)."""
-    total, seen = 0, False
-    for fn in jit_fns or ():
-        if fn is None:
-            continue
-        try:
-            total += int(fn._cache_size())
-            seen = True
-        except Exception:
-            continue
-    return total if seen else None
-
-
 class MemoryMonitor:
-    """sample(it, jit_fns=...) on the solver's step-sample cadence; each
-    sample emits one ``memstats`` event. Tracks peaks so flush() can
-    summarize even if the JSONL tail is lost."""
+    """sample(it) on the solver's step-sample cadence; each sample emits
+    one ``memstats`` event. Tracks peaks so flush() can summarize even if
+    the JSONL tail is lost. ``tracer``: the one the solver's step spans
+    record into, for the count of the step's programs."""
 
-    def __init__(self, sink, sample_every=1):
+    def __init__(self, sink, sample_every=1, tracer=None):
         self.sink = sink
+        self._builds = None if tracer is None else StepBuilds(tracer)
         self.sample_every = max(1, int(sample_every))
         self._n = 0
         self._last_cache = None
         self.peak_live_bytes = 0
         self.samples = 0
 
-    def sample(self, it, jit_fns=(), force=False, **extra):
+    def sample(self, it, force=False, **extra):
         self._n += 1
         if not force and (self._n - 1) % self.sample_every:
             return None
@@ -93,8 +83,10 @@ class MemoryMonitor:
         mem = device_memory()
         if mem:
             ev.update({f"hbm_{k}": v for k, v in mem.items()})
-        cache = compile_cache_size(jit_fns)
-        if cache is not None:
+        if self._builds is not None:
+            # the step's programs so far, by the tracer's records
+            self._builds.new()
+            cache = self._builds.count
             ev["compile_cache"] = cache
             if self._last_cache is not None and cache > self._last_cache:
                 ev["compile_cache_grew"] = cache - self._last_cache

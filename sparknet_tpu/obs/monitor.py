@@ -54,6 +54,7 @@ class MonitorState:
         self.last_alarm = None
         self.straggler_counts = collections.Counter()
         self.recompiles = 0
+        self.last_recompile = None
         self.recoveries = 0
         self.chaos = 0
         self.checkpoint_iter = None
@@ -172,6 +173,7 @@ class MonitorState:
         elif kind == "recompile":
             if not ev.get("first"):
                 self.recompiles += 1
+                self.last_recompile = ev
         elif kind == "recovery":
             self.recoveries += 1
         elif kind == "chaos":
@@ -332,6 +334,10 @@ class MonitorState:
                     f"device {self.step.get('device_ms', '?')} ms"]
             if self.recompiles:
                 bits.append(f"recompiles {self.recompiles}")
+                why = (self.last_recompile or {}).get("cause")
+                if why:
+                    bits.append(f"(step {self.last_recompile.get('iter')}: "
+                                f"{why[0]})")
             L.append("  step: " + "  ".join(bits))
         if self.worker_loss:
             L.append("  workers: loss " + self._fmt_workers(self.worker_loss)

@@ -109,7 +109,11 @@ def aggregate(events):
             "first_compile_iters": [e.get("iter") for e in recompiles
                                     if e.get("first")],
             "unexpected": [{"iter": e.get("iter"),
-                            "reason": e.get("reason")}
+                            "reason": e.get("reason"),
+                            "cause": e.get("cause"),
+                            "seconds": (e.get("lower_s") or 0)
+                            + (e.get("backend_s") or 0),
+                            "cache": e.get("cache")}
                            for e in recompiles if not e.get("first")][:50]}
 
     # -- comms -------------------------------------------------------------
@@ -651,7 +655,11 @@ def render(rep):
         L.append(f"  first compiles at iters: {rc.get('first_compile_iters')}")
         L.append(f"  unexpected recompiles: {rc.get('count', 0)}")
         for u in rc.get("unexpected", [])[:10]:
-            L.append(f"    iter {u.get('iter')}: {u.get('reason')}")
+            # what differed in the step's arguments (obs/trace.py)
+            why = "; ".join(u.get("cause") or ()) or u.get("reason")
+            took = f" ({u['seconds']:.2f} s, cache {u.get('cache')})" \
+                if _num(u.get("seconds")) and u.get("cache") else ""
+            L.append(f"    step {u.get('iter')} rebuilt{took}: {why}")
 
     c = rep.get("comms")
     if c:

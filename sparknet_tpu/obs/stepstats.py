@@ -11,14 +11,20 @@ by the steps in between is the true amortized per-step device time, queue
 drain included.
 
 Recompiles — the classic silent TPU perf killer (a shape change retraces
-and recompiles mid-run) — are detected from the jitted callable's
-``_cache_size()`` growth plus a feed-shape signature, and emitted as
-``recompile`` events (the first compile is expected, flagged first=True).
+and recompiles mid-run) — come from the tracer, which hears every program
+the step's enqueue builds as it happens (obs/trace.py: ``program.build``
+under ``solver.enqueue``, with the ``cause`` the step span wrote): one
+``recompile`` event a build (the step function's first is expected,
+flagged first=True), with what differed in the step's arguments.
 """
 
+import re
 import time
 
-import numpy as np
+from .trace import StepBuilds, default_tracer
+
+#: a leaf's shape or dtype among what `trace.diff_signatures` says differs
+_SHAPE_CHANGED = re.compile(r"[:,] (shape|dtype) ")
 
 
 def percentiles(vals, qs=(50, 95, 99)):
@@ -58,13 +64,17 @@ class StepAccounting:
       step         at sampled steps — host_ms (this dispatch), sync_ms
                    (block_until_ready wait), device_ms (amortized per-step
                    wall since the previous sample), steps_since_sync
-      recompile    whenever the jitted fn's executable cache grows
+      recompile    whenever the step's enqueue built a program
       hbm          at sampled steps, when the backend reports memory
       step_summary on flush() — full-histogram p50/p95/p99 + counts
     """
 
-    def __init__(self, sink, sample_every=20, max_hist=8192, name="train"):
+    def __init__(self, sink, sample_every=20, max_hist=8192, name="train",
+                 tracer=None):
         self.sink = sink
+        # the tracer the observed solver's step spans record into
+        self.builds = StepBuilds(tracer if tracer is not None
+                                 else default_tracer())
         self.sample_every = max(1, int(sample_every))
         self.max_hist = max_hist
         self.name = name
@@ -72,8 +82,6 @@ class StepAccounting:
         self.device_s = []          # amortized device seconds per sample
         self.steps = 0
         self.recompiles = 0         # beyond the expected first compile
-        self._last_cache = 0
-        self._sig = None
         self._nobs = 0
         self._last_sample_it = None
         self._last_sample_t = None
@@ -86,43 +94,26 @@ class StepAccounting:
         else:                       # ring overwrite, keeps recent window
             self.host_s[self.steps % self.max_hist] = v
 
-    def _check_recompile(self, it, jit_fn, batch):
-        sig = None
-        if batch is not None:
-            try:
-                sig = tuple(sorted(
-                    (k, tuple(np.shape(v)), str(getattr(v, "dtype", "")))
-                    for k, v in batch.items()))
-            except Exception:
-                sig = None
-        cache = None
-        if jit_fn is not None:
-            try:
-                cache = int(jit_fn._cache_size())
-            except Exception:
-                cache = None
-        if cache is not None and cache > self._last_cache:
-            first = self._last_cache == 0
+    def _check_recompile(self, it):
+        new = self.builds.new()
+        size = self.builds.count - len(new)
+        for b in new:
+            size += 1
+            cause = b.get("cause") or []
+            # a build no step span explained is first when none came before
+            first = cause == ["first"] or (not cause and size == 1)
             if not first:
                 self.recompiles += 1
             reason = "first_compile" if first else (
-                "shape_change" if sig is not None and self._sig is not None
-                and sig != self._sig else "retrace")
-            self.sink.log("recompile", iter=it, cache_size=cache,
-                          first=first, reason=reason)
-            self._last_cache = cache
-        elif cache is None and sig is not None and self._sig is not None \
-                and sig != self._sig:
-            # no cache introspection available; shape tracking still works
-            self.recompiles += 1
-            self.sink.log("recompile", iter=it, cache_size=None,
-                          first=False, reason="shape_change")
-        if sig is not None:
-            self._sig = sig
+                "shape_change" if any(_SHAPE_CHANGED.search(c)
+                                      for c in cause) else "retrace")
+            self.sink.log("recompile", iter=b.get("iter", it),
+                          cache_size=size, first=first, reason=reason,
+                          cause=cause, lower_s=b["lower_s"],
+                          backend_s=b["backend_s"], cache=b["cache"])
 
     # -- public API --------------------------------------------------------
-    def observe(self, it, host_s, result=None, jit_fn=None, batch=None,
-                sample=None):
+    def observe(self, it, host_s, result=None, sample=None):
         """Record one step. host_s: dispatch wall seconds. result: the
         step's output (blocked on at sample points). sample: None for the
         automatic cadence, True/False to force. Returns True when this
@@ -130,7 +121,7 @@ class StepAccounting:
         callers piggyback other fetch-costly sampling on it."""
         self.steps += 1
         self._push_host(host_s)
-        self._check_recompile(it, jit_fn, batch)
+        self._check_recompile(it)
         if sample is None:
             sample = self._nobs < 2 or self._last_sample_it is None \
                 or (it - self._last_sample_it) >= self.sample_every

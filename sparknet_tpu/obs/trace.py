@@ -39,6 +39,29 @@ net may have layers of two head counts), the output gate's form ``gate``
 (``none``, ``plain``, ``yarn``) with ``rope_factor`` and ``rope_scale``,
 the factor on cos and sin.
 
+Set-up is a closed ledger too. Every executable the process obtains —
+compiled, or loaded from the persistent cache — leaves ONE ``program.build``
+record beside jax's ``compile.*`` four, from the start of its lowering to
+the end of the backend's event: ``fun_name``, ``nth`` (the count of that
+name's builds in the process), ``lower_s``, ``backend_s``, ``cache``
+(``hit`` / ``miss`` / ``off``, with ``cache_load_s`` on a hit), the span
+open on the thread as parent and the ``iter`` of its ``solver.step``;
+``Tracer.builds`` counts them. A build under ``solver.enqueue`` also says
+its ``cause`` when the step closes, a list of strings: ``["first"]`` for the
+step function's first build, else what differs between the arguments the
+solver handed the jitted call this time and at the build before
+(``signature`` / ``diff_signatures``: path, shape, dtype, weak type,
+sharding, committedness, and the layout of a leaf whose buffer is still
+there — a donated leaf keeps the rest on its aval), at most ``MOST_CAUSES``
+of them with ``changed``, the number of leaves that differ, or ``["same
+signature"]``. The step span holds a reference to the arguments
+(``_Step.watch``) and takes a signature only when ``Tracer.builds`` moved
+while it was open. ``package.import`` (the package's first line to the
+process's first ``Solver.__init__``), ``import.kernel`` (``kernel_import``
+round a kernel module's in-branch import, written when the module was not
+loaded yet) and ``solver.history`` are the other set-up records;
+``benchmark/setup_parts.py`` adds them up.
+
 ``default_tracer()`` is the process-wide tracer that ``Solver`` and
 ``PrefetchIterator`` use when none is passed; ``default_tracer().spans()``
 reads the ring. "Off" means no profiler session is running: there is no
@@ -52,12 +75,15 @@ that used to live inline in cli.cmd_train.
 """
 
 import collections
+import contextlib
 import json
 import os
+import sys
 import threading
 import time
+import weakref
 
-from jax import monitoring
+from jax import monitoring, tree_util
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 #: records a ring holds: about 75 a second in a host-fed training loop
@@ -113,19 +139,30 @@ class _Step(_Span):
     group device work by) split into consecutive phases, each a child
     span carrying the step's ``iter``. After exit ``host_s`` holds the
     seconds of the last phase — the enqueue of the jitted call, which is
-    what step accounting reports as host dispatch time."""
+    what step accounting reports as host dispatch time.
 
-    __slots__ = ("_step_ann", "_phase", "host_s")
+    ``watch`` keeps a reference to the jitted function and the arguments
+    it is about to be called with. Only when the tracer counted a build
+    while the step was open does the exit take their signature (from the
+    avals, which outlive a donated buffer) and write the ``cause`` on the
+    ``program.build`` records of the enqueue: a steady step pays one
+    store and two reads of ``Tracer.builds``."""
+
+    __slots__ = ("_step_ann", "_phase", "host_s", "_builds", "_watched",
+                 "built")
 
     def __init__(self, tracer, name, it, first_phase):
         super().__init__(tracer, name, {"iter": it}, False)
         self._phase = first_phase
+        self._watched = None
+        self.built = None       # [(parent, attrs)] of the builds under it
 
     def __enter__(self):
         self._step_ann = StepTraceAnnotation("train",
                                              step_num=self.attrs["iter"])
         self._step_ann.__enter__()
         super().__enter__()
+        self._builds = self.tracer.builds
         self._phase = self._open(self._phase)
         return self
 
@@ -139,9 +176,17 @@ class _Step(_Span):
         self._phase.__exit__(None, None, None)
         self._phase = self._open(name)
 
+    def watch(self, fn, args, names=None):
+        """``fn(*args)`` is the jitted call this step is about to make;
+        ``names`` of the arguments where they are not STEP_ARGS."""
+        self._watched = (fn, args, names)
+
     def __exit__(self, *exc):
         self._phase.__exit__(*exc)
         self.host_s = (self.tracer.now_ns() - self._phase.t0) * 1e-9
+        if self.tracer.builds != self._builds:
+            self.tracer._explain_builds(self)
+        self._watched = None    # the step is over: let go of its batch
         super().__exit__(*exc)
         self._step_ann.__exit__(*exc)
         return False
@@ -160,6 +205,9 @@ class Tracer:
         self._buf = collections.deque(maxlen=max_buffer)  # spk: guarded-by=_lock
         self.dropped = 0            # spk: guarded-by=_lock
         self.put = 0                # spk: guarded-by=_lock
+        self.builds = 0             # spk: guarded-by=_lock
+        # step function -> the signature of the arguments at its last build
+        self._built_with = weakref.WeakKeyDictionary()
         self.max_buffer = max_buffer
         _listen_for_compiles()
 
@@ -182,10 +230,32 @@ class Tracer:
 
     def record(self, name, start_ns, end_ns, **small):
         """A finished span from ``now_ns()`` stamps the caller already
-        holds; its parent is the span open on this thread. Ring only."""
+        holds; its parent is the span open on this thread. Ring only.
+        -> the attrs as the ring holds them."""
         st = _stack()
         self._put(name, start_ns, end_ns, len(st),
                   st[-1].name if st else None, small, False)
+        return small
+
+    def _explain_builds(self, step):
+        """``step`` is closing and a build was counted while it was open:
+        write ``cause`` and ``changed`` on the ``program.build`` records of
+        its enqueue, and keep the arguments' signature for the step
+        function's next build."""
+        built = [attrs for parent, attrs in step.built or ()
+                 if parent == "solver.enqueue"]
+        if not built or step._watched is None:
+            return
+        fn, args, names = step._watched
+        sig = signature(args, names or STEP_ARGS)
+        before = self._built_with.get(fn)
+        self._built_with[fn] = sig
+        if before is None:
+            changed, cause = 0, ["first"]
+        else:
+            changed, cause = diff_signatures(before, sig)
+        for b in built:
+            b["cause"], b["changed"] = cause, changed
 
     def instant(self, name, **attrs):
         """A zero-duration mark (Chrome 'instant' event)."""
@@ -231,13 +301,17 @@ class Tracer:
         with self._lock:
             return self.put
 
-    def since(self, mark, *names):
-        """`spans(*names)` of the records put after `mark()` gave
-        `mark`, those the ring has dropped since left out."""
+    def _since(self, mark):
+        """(the raw records put after `mark`, the place after them)."""
         with self._lock:
             recs = list(self._buf)
             first = self.put - len(recs)    # the oldest record's place
-        return [self._as_dict(r) for r in recs[max(0, mark - first):]
+            return recs[max(0, mark - first):], self.put
+
+    def since(self, mark, *names):
+        """`spans(*names)` of the records put after `mark()` gave
+        `mark`, those the ring has dropped since left out."""
+        return [self._as_dict(r) for r in self._since(mark)[0]
                 if not names or r[0] in names]
 
     def export_chrome(self, path):
@@ -247,6 +321,30 @@ class Tracer:
             recs, dropped = list(self._buf), self.dropped
         return export_chrome(path, [self._as_dict(r) for r in recs],
                              dropped=dropped)
+
+
+class StepBuilds:
+    """A reader's place in one tracer's stream of the programs a step's
+    enqueue built (``program.build`` under ``solver.enqueue``): the one
+    source of the ``recompile`` event and of ``memstats``' count. A look
+    that finds ``Tracer.builds`` where it was reads nothing."""
+
+    def __init__(self, tracer):
+        self.tracer = tracer
+        self._mark, self._builds = tracer.mark(), tracer.builds
+        self.count = 0              # the step's builds seen so far
+
+    def new(self):
+        """The step's builds since the last look, oldest first."""
+        tr = self.tracer
+        if tr.builds == self._builds:
+            return []
+        self._builds = tr.builds
+        recs, self._mark = tr._since(self._mark)
+        new = [tr._as_dict(r) for r in recs
+               if r[0] == "program.build" and r[4] == "solver.enqueue"]
+        self.count += len(new)
+        return new
 
 
 _default = None
@@ -284,6 +382,28 @@ _COMPILE_EVENTS = {
 _MIN_TRACE_S = 0.01
 
 
+# between a program's lowering and its backend event jax says, on the same
+# thread, whether the persistent cache answered; a miss is said where the
+# entry is then written, so a program that the cache's thresholds leave out
+# (jax_persistent_cache_min_compile_time_secs) reads `off` like one
+# obtained with no cache at all: the cache did not take it
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_misses": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+_nth = collections.Counter()    # fun_name -> builds; spk: guarded-by=_module_lock
+
+
+def _pending():
+    """What this thread has heard of the program it is obtaining: the
+    lowering's (start, seconds, fun_name), the cache's answer, the load's
+    seconds."""
+    p = getattr(_tls, "pending", None)
+    if p is None:
+        p = _tls.pending = {"lower": None, "cache": "off", "load_s": None}
+    return p
+
+
 def _on_compile(event, seconds, **kw):
     name = _COMPILE_EVENTS.get(event)
     if name is None or (name == "compile.trace" and seconds < _MIN_TRACE_S):
@@ -291,19 +411,180 @@ def _on_compile(event, seconds, **kw):
     st = _stack()
     tracer = st[-1].tracer if st else default_tracer()
     end = tracer.now_ns()
-    tracer.record(name, end - int(seconds * 1e9), end,
-                  seconds=round(seconds, 6), **kw)
+    start = end - int(seconds * 1e9)
+    tracer.record(name, start, end, seconds=round(seconds, 6), **kw)
+    if name == "compile.lower":
+        _pending().update(lower=(start, seconds, kw.get("fun_name")),
+                          cache="off", load_s=None)
+    elif name == "compile.cache_load":
+        _pending()["load_s"] = seconds
+    elif name == "compile.backend":
+        _program_build(tracer, st, start, end, seconds, kw.get("fun_name"))
+
+
+def _on_cache_event(event, **kw):
+    answer = _CACHE_EVENTS.get(event)
+    if answer is not None:
+        _pending()["cache"] = answer
+
+
+def _program_build(tracer, st, start, end, backend_s, fun_name):
+    """The backend event closes a program: one ``program.build`` from the
+    start of its lowering (the lowering this thread heard last, when it
+    was of the same name) to now."""
+    p = _pending()
+    lower_s = 0.0
+    if p["lower"] is not None and p["lower"][2] == fun_name:
+        start, lower_s = p["lower"][0], p["lower"][1]
+    with _module_lock:
+        _nth[fun_name] += 1
+        nth = _nth[fun_name]
+    attrs = {"fun_name": fun_name, "nth": nth, "lower_s": round(lower_s, 6),
+             "backend_s": round(backend_s, 6), "cache": p["cache"]}
+    if p["cache"] == "hit" and p["load_s"] is not None:
+        attrs["cache_load_s"] = round(p["load_s"], 6)
+    p.update(lower=None, cache="off", load_s=None)
+    step = next((s for s in reversed(st) if isinstance(s, _Step)), None)
+    if step is not None:
+        attrs["iter"] = step.attrs["iter"]
+    attrs = tracer.record("program.build", start, end, **attrs)
+    with tracer._lock:
+        tracer.builds += 1
+    if step is not None:
+        if step.built is None:
+            step.built = []
+        step.built.append((st[-1].name, attrs))
 
 
 def _listen_for_compiles():
-    """Register the compile listener, once per process."""
+    """Register the compile listeners, once per process."""
     global _listening
     if _listening:
         return
     with _module_lock:
         if not _listening:
             monitoring.register_event_duration_secs_listener(_on_compile)
+            monitoring.register_event_listener(_on_cache_event)
             _listening = True
+
+
+# -- the arguments a step function was built with ---------------------------
+
+#: the arguments of every solver's jitted step, in order (the mesh solvers
+#: pass the last two)
+STEP_ARGS = ("params", "state", "history", "batch", "iter", "key", "alive",
+             "lag")
+FIELDS = ("shape", "dtype", "weak_type", "sharding", "committed", "layout")
+MOST_CAUSES = 8
+
+
+def _key(k):
+    """One element of a tree path, bare: a dict's key, a sequence's index,
+    an attribute's name."""
+    for attr in ("key", "idx", "name"):
+        if hasattr(k, attr):
+            return str(getattr(k, attr))
+    return str(k)
+
+
+def _leaf_signature(leaf, names):
+    aval = getattr(leaf, "aval", None)
+    if aval is None:        # a numpy array, a Python number
+        return (getattr(leaf, "shape", ()),
+                str(getattr(leaf, "dtype", type(leaf).__name__)),
+                not hasattr(leaf, "dtype"), None, False, None)
+    sharding = getattr(leaf, "sharding", None)
+    if id(sharding) not in names:
+        names[id(sharding)] = None if sharding is None else str(sharding)
+    layout = None
+    if not leaf.is_deleted():   # a donated buffer took its layout with it
+        layout = getattr(leaf.format, "layout", None)
+    return (aval.shape, str(aval.dtype), bool(aval.weak_type),
+            names[id(sharding)], bool(getattr(leaf, "committed", False)),
+            None if layout is None else str(layout))
+
+
+def signature(args, arg_names=STEP_ARGS):
+    """{path: FIELDS of the leaf} for every leaf of a step's arguments, the
+    path as ``history/conv1/0/0`` with the argument's name in front. Reads
+    no buffer: shape, dtype and weak type from the aval, sharding and
+    committedness from the array, which a donated array keeps; the layout
+    where the buffer is still there."""
+    sig, names = {}, {}
+    for i, arg in enumerate(args):
+        top = arg_names[i] if i < len(arg_names) else f"arg{i}"
+        for path, leaf in tree_util.tree_flatten_with_path(arg)[0]:
+            sig["/".join([top] + [_key(k) for k in path])] = \
+                _leaf_signature(leaf, names)
+    return sig
+
+
+def diff_signatures(before, now):
+    """-> (the number of leaves that differ, what differs as at most
+    MOST_CAUSES strings such as ``history/conv1/0/0: committed False -> True``). Leaves
+    of one argument that differ alike are said once, with their number;
+    nothing differs -> (0, ["same signature"])."""
+    alike, changed = {}, 0
+    for path in list(now) + [p for p in before if p not in now]:
+        a, b = before.get(path), now.get(path)
+        if a == b:
+            continue
+        changed += 1
+        if a is None or b is None:
+            what = "a new leaf" if a is None else "the leaf is gone"
+        else:
+            what = ", ".join(f"{f} {x} -> {y}"
+                             for f, x, y in zip(FIELDS, a, b) if x != y)
+        alike.setdefault((path.split("/", 1)[0], what), []).append(path)
+    if not changed:
+        return 0, ["same signature"]
+    cause = [f"{paths[0]}: {what}" + (
+        f" (and {len(paths) - 1} more of {top})" if len(paths) > 1 else "")
+        for (top, what), paths in alike.items()]
+    return changed, cause[:MOST_CAUSES]
+
+
+# -- set-up outside any jitted call ----------------------------------------
+
+@contextlib.contextmanager
+def kernel_import(module):
+    """Round the in-branch import of a kernel module (``with
+    kernel_import("sparknet_tpu.ops.pallas_lrn"): from .pallas_lrn import
+    ...``): one ``import.kernel`` record with ``module`` when the module
+    was not loaded yet — the first such import of a process brings
+    ``jax.experimental.pallas``, 1.4 s that a ``compile.trace`` hid."""
+    if module in sys.modules:
+        yield
+        return
+    st = _stack()
+    tracer = st[-1].tracer if st else default_tracer()
+    t0 = tracer.now_ns()
+    try:
+        yield
+    finally:
+        tracer.record("import.kernel", t0, tracer.now_ns(), module=module)
+
+
+_package_import_said = False    # spk: guarded-by=_module_lock
+
+
+def package_import(tracer, entry_ns):
+    """The first ``Solver.__init__`` of the process says what came before
+    it: one ``package.import`` from the stamp on the package's first line
+    to ``entry_ns``, with the count of the package's ``modules`` loaded
+    by then and whether ``pallas`` is among what they brought."""
+    global _package_import_said
+    with _module_lock:
+        if _package_import_said:
+            return
+        _package_import_said = True
+    stamp = getattr(sys.modules.get("sparknet_tpu"), "IMPORT_NS", None)
+    if stamp is None:
+        return
+    tracer.record("package.import", stamp, entry_ns,
+                  modules=sum(1 for m in list(sys.modules)
+                              if m.startswith("sparknet_tpu.")),
+                  pallas="jax.experimental.pallas" in sys.modules)
 
 
 def chrome_from_spans(spans, pid=None):

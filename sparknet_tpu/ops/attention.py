@@ -152,7 +152,7 @@ import numpy as np
 
 from ..proto import Message
 from ..graph.registry import Layer, register
-from ..obs.trace import default_tracer
+from ..obs.trace import default_tracer, kernel_import
 from ..parallel import context
 from ..parallel.ring import ring_attention, dense_attention
 from .convolution import _param_mults
@@ -563,8 +563,9 @@ class Attention(Layer):
         elif self.flash and s % 128 == 0 and v.shape[-1] == q.shape[-1]:
             # here and not at the top: a process without such a layer never
             # imports pallas (1.4 s of every cell's set-up, PR 29)
-            from .pallas_attention import (band_blocks, edge_blocks,
-                                           flash_attention)
+            with kernel_import("sparknet_tpu.ops.pallas_attention"):
+                from .pallas_attention import (band_blocks, edge_blocks,
+                                               flash_attention)
             path, reason = "kernel", "flash is set and 128 divides S"
             if q.shape[-1] % 128:
                 reason += (f"; a head of {q.shape[-1]} is a block of its "
@@ -726,8 +727,9 @@ class Attention(Layer):
         tracer = default_tracer()
         if self.flash and s % 128 == 0:
             # here and not at the top: see `_core`
-            from .pallas_dsa import (BITS_A_PASS, SELECT_PASSES,
-                                     causal_tiles, sparse_attention)
+            with kernel_import("sparknet_tpu.ops.pallas_dsa"):
+                from .pallas_dsa import (BITS_A_PASS, SELECT_PASSES,
+                                         causal_tiles, sparse_attention)
             path, reason = "kernel", "flash is set and 128 divides S"
             tiles, passes, bits = causal_tiles(s), SELECT_PASSES, BITS_A_PASS
             o, kl = sparse_attention(q, k, v, qi, ki, w, topk, self.lp.name)

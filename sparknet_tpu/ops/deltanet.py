@@ -69,7 +69,7 @@ from jax import lax
 
 from ..proto import Message
 from ..graph.registry import Layer, register
-from ..obs.trace import default_tracer
+from ..obs.trace import default_tracer, kernel_import
 from .convolution import _param_mults
 from .normalization import rms_norm
 
@@ -283,7 +283,8 @@ class GatedDeltaNet(Layer):
             else:
                 # here and not at the top: a process without such a layer
                 # never imports pallas (1.4 s of every cell's set-up, PR 29)
-                from .pallas_deltanet import chunk_rule
+                with kernel_import("sparknet_tpu.ops.pallas_deltanet"):
+                    from .pallas_deltanet import chunk_rule
                 o, _ = chunk_rule(q, k, v, beta, g, chunk=self.chunk,
                                   layer=self.lp.name)
         with jax.named_scope("gdn_gate_norm"):

@@ -14,6 +14,7 @@ from jax import lax
 import jax.numpy as jnp
 
 from ..graph.registry import Layer, register
+from ..obs.trace import kernel_import
 
 
 # XLA:TPU rewrites convolutions whose batch is under 8 with its
@@ -65,7 +66,8 @@ class LRN(Layer):
             s = ave_pool(x * x, kernel, stride, pad, out)
             scale = 1.0 + self.alpha * s
         elif x.ndim == 4 and _lrn_mode() == "pallas":
-            from .pallas_lrn import lrn_across
+            with kernel_import("sparknet_tpu.ops.pallas_lrn"):
+                from .pallas_lrn import lrn_across
             return [lrn_across(x, self.size, self.alpha, self.beta, self.k)]
         else:
             half = (self.size - 1) // 2

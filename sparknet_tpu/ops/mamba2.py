@@ -100,7 +100,7 @@ from jax import lax
 
 from ..proto import Message
 from ..graph.registry import Layer, register
-from ..obs.trace import default_tracer
+from ..obs.trace import default_tracer, kernel_import
 from .convolution import _param_mults
 from .deltanet import causal_depthwise_conv
 
@@ -318,7 +318,8 @@ class Mamba2(Layer):
             else:
                 # here and not at the top: a process without such a layer
                 # never imports pallas (1.4 s of every cell's set-up, PR 29)
-                from .pallas_ssd import chunk_scan
+                with kernel_import("sparknet_tpu.ops.pallas_ssd"):
+                    from .pallas_ssd import chunk_scan
                 y, _, survive = chunk_scan(xs, delta, a, b, c, self.chunk,
                                            layer=self.lp.name)
             y = y + d_skip.astype(f32)[:, None] * xs.astype(f32)

@@ -185,7 +185,7 @@ from jax import lax
 
 from ..proto import Message
 from ..graph.registry import Layer, register
-from ..obs.trace import default_tracer
+from ..obs.trace import default_tracer, kernel_import
 from ..parallel import context
 from .convolution import _param_mults
 
@@ -267,7 +267,8 @@ def kernel_tile(tile, embed, hidden, itemsize):
     tile (2,688 x 1,920 takes 256 and not 512 in bfloat16 and 128 in
     float32, 2,048 x 1,792 in float32 256). Where none fits the tile
     stays, and the compiler says so as it did before any tile was fitted."""
-    from .pallas_moe import fits
+    with kernel_import("sparknet_tpu.ops.pallas_moe"):
+        from .pallas_moe import fits
     return next((t for t in (tile, *reversed(ROW_TILES))
                  if t <= tile and fits(t, embed, hidden, itemsize)), tile)
 
@@ -354,7 +355,8 @@ def _combine(rows, weight, tm, segment, kernel):
     window, tokens = rows.shape[0], tm["count"].shape[0]
     z, tok, block = rows[tm["row"]], tm["tok"], 0
     if kernel:
-        from . import pallas_moe
+        with kernel_import("sparknet_tpu.ops.pallas_moe"):
+            from . import pallas_moe
         block = pallas_moe.segment_block(window, segment)
     if block:
         total = pallas_moe.segment_add(z, weight, tok, tokens, segment,
@@ -378,7 +380,8 @@ def _grouped(kernel, tile, sizes):
     if kernel:
         # here and not at the top: a process without such a layer never
         # imports pallas (1.4 s of every cell's set-up, PR 29)
-        from . import pallas_moe
+        with kernel_import("sparknet_tpu.ops.pallas_moe"):
+            from . import pallas_moe
         return (functools.partial(pallas_moe.grouped_dot, sizes, tile),
                 functools.partial(pallas_moe.grouped_dot_t, sizes, tile))
 

@@ -467,11 +467,12 @@ class DataParallelSolver(Solver):
             span.phase("solver.enqueue")
             dev_batch = shard_batch(batch, self.mesh, self.axis,
                                     batch_dim=0 if iter_size == 1 else 1)
-            self.params, self.state, self.history, loss, aux = \
-                self._jit_train(
-                    self.params, self.state, self.history, dev_batch,
+            args = (self.params, self.state, self.history, dev_batch,
                     jnp.asarray(self.iter, jnp.int32), key,
                     self._alive_mask(), self._staleness_lag())
+            span.watch(self._jit_train, args)
+            self.params, self.state, self.history, loss, aux = \
+                self._jit_train(*args)
             self.iter += 1
         host_s = span.host_s
         if self.staleness is not None and self.elastic is not None:
@@ -1002,11 +1003,12 @@ class LocalSGDSolver(Solver):
                 if self.host_axis is not None else self.axis
             span.phase("solver.enqueue")
             dev = shard_batch(batches, self.mesh, shard_axes, batch_dim=1)
-            self.params, self.state, self.history, loss, aux = \
-                self._jit_round(
-                    self.params, self.state, self.history, dev,
+            args = (self.params, self.state, self.history, dev,
                     jnp.asarray(self.iter, jnp.int32), key,
                     self._alive_mask(), self._staleness_lag())
+            span.watch(self._jit_round, args)
+            self.params, self.state, self.history, loss, aux = \
+                self._jit_round(*args)
             self.iter += self.tau
         return span, loss, aux
 

@@ -81,9 +81,9 @@ class PipelineLMSolver:
         self._comms_registered = False
         if self.metrics is not None:
             from ..obs import StepAccounting, CommsMeter, MemoryMonitor
-            self.stepstats = StepAccounting(self.metrics)
+            self.stepstats = StepAccounting(self.metrics, tracer=self.tracer)
             self.comms = CommsMeter(self.metrics)
-            self.memstats = MemoryMonitor(self.metrics)
+            self.memstats = MemoryMonitor(self.metrics, tracer=self.tracer)
         self.mesh = mesh if mesh is not None else make_mesh({axis: -1})
         self.axis = axis
         S = self.mesh.shape[axis]
@@ -231,11 +231,10 @@ class PipelineLMSolver:
         it = self.iter - 1
         self.comms.add_h2d(tree_bytes(batch))
         self.comms.tick(it)
-        sampled = self.stepstats.observe(it, host_s, result=result,
-                                         jit_fn=self._jit_train, batch=batch)
+        sampled = self.stepstats.observe(it, host_s, result=result)
         if sampled and self.memstats is not None:
             try:
-                self.memstats.sample(it, jit_fns=(self._jit_train,))
+                self.memstats.sample(it)
             except Exception as e:
                 self.log(f"memstats sampling failed: {e!r}")
 
@@ -258,24 +257,31 @@ class PipelineLMSolver:
             self.metrics = None
 
     def train_step(self, batch):
-        import time
-        if self._jit_train is None:
-            self._jit_train = self._build_train_step()
-        if jax.process_count() > 1 and not getattr(self, "_feed_checked",
-                                                   False):
-            self._feed_checked = True
-            check_global_feed(batch)
-        self.rng, key = jax.random.split(self.rng)
-        if self._it_dev is None:
-            self._it_dev = jnp.asarray(self.iter, jnp.int32)
-        t0 = time.perf_counter()
-        batch = place_tree({k: np.asarray(v) for k, v in batch.items()},
-                           {k: P() for k in batch}, self.mesh)
-        self.params, self.history, loss, self._it_dev = self._jit_train(
-            self.params, self.history, batch, self._it_dev, key)
-        self.iter += 1
+        # the spans of every solver's step (Solver._step_span): the tracer
+        # hears what the enqueue builds, and why
+        with self.tracer.step("solver.step", self.iter,
+                              "solver.prep") as span:
+            if self._jit_train is None:
+                self._jit_train = self._build_train_step()
+            if jax.process_count() > 1 and not getattr(
+                    self, "_feed_checked", False):
+                self._feed_checked = True
+                check_global_feed(batch)
+            self.rng, key = jax.random.split(self.rng)
+            if self._it_dev is None:
+                self._it_dev = jnp.asarray(self.iter, jnp.int32)
+            # the enqueue counts laying the batch over the mesh
+            span.phase("solver.enqueue")
+            batch = place_tree({k: np.asarray(v) for k, v in batch.items()},
+                               {k: P() for k in batch}, self.mesh)
+            args = (self.params, self.history, batch, self._it_dev, key)
+            span.watch(self._jit_train, args,
+                       ("params", "history", "batch", "iter", "key"))
+            self.params, self.history, loss, self._it_dev = \
+                self._jit_train(*args)
+            self.iter += 1
         self._last_loss = loss
-        self._obs_step(time.perf_counter() - t0, loss, batch)
+        self._obs_step(span.host_s, loss, batch)
         return loss
 
     def step(self, num_iters, data_iter):

@@ -173,10 +173,11 @@ class SeqParallelSolver(Solver):
                 dev = self._shard(batch)
                 if self._it_dev is None:     # device-resident, like Solver
                     self._it_dev = jnp.asarray(self.iter, jnp.int32)
+                args = (self.params, self.state, self.history, dev,
+                        self._it_dev, key)
+                span.watch(self._jit_train, args)
                 (self.params, self.state, self.history, loss,
-                 self._it_dev, aux) = self._jit_train(
-                    self.params, self.state, self.history, dev,
-                    self._it_dev, key)
+                 self._it_dev, aux) = self._jit_train(*args)
             self.iter += 1
         self._obs_step(span.host_s, loss, batch,
                        aux=dict(aux, kind="grads") if aux else None)

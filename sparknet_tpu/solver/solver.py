@@ -72,6 +72,7 @@ class Solver:
                  test_feed_shapes=None, base_dir="", dtype=jnp.float32,
                  log_fn=print, metrics=None, compute_dtype=None,
                  tracer=None, remat=None):
+        entry_ns = time.perf_counter_ns()       # the ring's clock
         self.param = solver_param
         self.log = log_fn or (lambda *a: None)
         # structured observability hooks: a JSONL MetricsLogger (or
@@ -85,11 +86,14 @@ class Solver:
             from ..utils.metrics import MetricsLogger
             metrics = MetricsLogger(metrics)
         self.metrics = metrics
-        from ..obs.trace import Tracer, default_tracer
+        from ..obs.trace import Tracer, default_tracer, package_import
         if tracer is None:
             tracer = Tracer(self.metrics) if self.metrics is not None \
                 else default_tracer()
         self.tracer = tracer
+        # what the process did before it had a solver: its imports, the
+        # builder's NetParameter (once a process)
+        package_import(tracer, entry_ns)
         self.stepstats = self.comms = None
         self._comms_registered = False
         # training-dynamics health layer (obs divergence/health/memstats):
@@ -101,12 +105,12 @@ class Solver:
         if self.metrics is not None:
             from ..obs import (StepAccounting, CommsMeter, DivergenceMeter,
                                HealthMonitor, MemoryMonitor)
-            self.stepstats = StepAccounting(self.metrics)
+            self.stepstats = StepAccounting(self.metrics, tracer=tracer)
             self.comms = CommsMeter(self.metrics)
             self.divergence = DivergenceMeter(self.metrics)
             self.health = HealthMonitor(self.metrics, log_fn=self.log,
                                         solver=self)
-            self.memstats = MemoryMonitor(self.metrics)
+            self.memstats = MemoryMonitor(self.metrics, tracer=tracer)
         self.watchdog = None
         # resilience hooks (sparknet_tpu.resilience): keep-N snapshot
         # retention (None = keep all), an optional RecoveryPolicy armed via
@@ -196,20 +200,24 @@ class Solver:
             with self.tracer.hot_span("net.init"):
                 self.params, self.state = self.net.init(init_key)
 
-        mults = {}
-        for lname, refs in self.net.param_refs.items():
-            owned = [k for k in refs if k[0] == lname]
-            if owned:
-                mults[lname] = [
-                    (self.net.param_meta[k][2], self.net.param_meta[k][3])
-                    for k in owned]
-        # layers that keep statistics in their state for the tracer
-        # (ops/moe.py): read where `step` already waits for a loss
-        self._monitors = [(lp.name, impl.monitor)
-                          for lp, impl, _, _ in self.net.layers
-                          if getattr(impl, "monitor", None)]
-        self.updater = Updater(solver_param, mults)
-        self.history = self.updater.init(self.params)
+        # a sibling after `solver.init`, which reads what it read: the
+        # updater's state is 100 to 370 more one-blob fill programs
+        with self.tracer.hot_span("solver.history"):
+            mults = {}
+            for lname, refs in self.net.param_refs.items():
+                owned = [k for k in refs if k[0] == lname]
+                if owned:
+                    mults[lname] = [
+                        (self.net.param_meta[k][2],
+                         self.net.param_meta[k][3])
+                        for k in owned]
+            # layers that keep statistics in their state for the tracer
+            # (ops/moe.py): read where `step` already waits for a loss
+            self._monitors = [(lp.name, impl.monitor)
+                              for lp, impl, _, _ in self.net.layers
+                              if getattr(impl, "monitor", None)]
+            self.updater = Updater(solver_param, mults)
+            self.history = self.updater.init(self.params)
         self.lr_fn = make_lr_fn(solver_param)
         self.iter = 0
         self._smoothed = collections.deque(
@@ -807,14 +815,11 @@ class Solver:
         from ..obs.comms import tree_bytes
         self.comms.add_h2d(tree_bytes(batch))
         self.comms.tick(it)
-        jit_fn = self._jit_train if self._jit_train is not None \
-            else getattr(self, "_jit_round", None)   # LocalSGDSolver
-        sampled = self.stepstats.observe(it, host_s, result=result,
-                                         jit_fn=jit_fn, batch=batch)
+        sampled = self.stepstats.observe(it, host_s, result=result)
         if sampled:
             if self.memstats is not None:
                 try:
-                    self.memstats.sample(it, jit_fns=(jit_fn,))
+                    self.memstats.sample(it)
                 except Exception as e:
                     self.log(f"memstats sampling failed: {e!r}")
             if aux:
@@ -996,9 +1001,11 @@ class Solver:
             if self._it_dev is None:
                 self._it_dev = jnp.asarray(self.iter, jnp.int32)
             span.phase("solver.enqueue")
+            args = (self.params, self.state, self.history, batch,
+                    self._it_dev, key)
+            span.watch(self._jit_train, args)
             self.params, self.state, self.history, loss, self._it_dev = \
-                self._jit_train(self.params, self.state, self.history, batch,
-                                self._it_dev, key)
+                self._jit_train(*args)
             self.iter += 1
         self._obs_step(span.host_s, loss, batch)
         return self._chaos_loss(loss)

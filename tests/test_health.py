@@ -297,12 +297,18 @@ class TestCommsEdgeCases:
 class TestMemoryMonitor:
     def test_sample_emits_memstats(self):
         ms, buf = sink()
-        mm = MemoryMonitor(ms)
+        from sparknet_tpu.obs.trace import Tracer
+        tr = Tracer(None)
+        mm = MemoryMonitor(ms, tracer=tr)
         f = jax.jit(lambda a: a + 1)
-        x = f(jax.numpy.zeros((64, 64), jax.numpy.float32))
+        # the step's programs are counted from the tracer's records
+        zeros = jax.numpy.zeros((64, 64), jax.numpy.float32)
+        with tr.step("solver.step", 5, "solver.prep") as span:
+            span.phase("solver.enqueue")
+            x = f(zeros)
         x.block_until_ready()
-        ev = mm.sample(5, jit_fns=(f, None))
-        assert ev["iter"] == 5
+        ev = mm.sample(5)
+        assert ev["iter"] == 5 and ev["compile_cache"] == 1
         assert ev["live_arrays"] >= 1 and ev["live_bytes"] > 0
         assert ev["host_rss_bytes"] > 0
         assert mm.peak_live_bytes >= ev["live_bytes"] > 0

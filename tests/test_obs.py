@@ -162,22 +162,42 @@ class TestStepAccounting:
         assert percentiles([7.0])["p99"] == 7.0
 
     def test_recompile_detection_via_cache_size(self):
+        """The one source since PR 51: the tracer's `program.build` records
+        under `solver.enqueue` (the name is older: jit's private
+        `_cache_size()` was polled here). Same count: the first build and
+        one more."""
+        from sparknet_tpu.obs.trace import Tracer
+        from sparknet_tpu.obs.event_schema import EVENTS
         buf = io.StringIO()
-        sa = StepAccounting(MetricsLogger(stream=buf), sample_every=1000)
+        tr = Tracer(None)
+        sa = StepAccounting(MetricsLogger(stream=buf), sample_every=1000,
+                            tracer=tr)
         f = jax.jit(lambda x: x * 2)
-        b1 = {"x": np.ones(3, np.float32)}
-        f(b1["x"])
-        sa.observe(0, 0.001, jit_fn=f, batch=b1, sample=False)
-        b2 = {"x": np.ones(4, np.float32)}
-        f(b2["x"])                          # shape change -> retrace
-        sa.observe(1, 0.001, jit_fn=f, batch=b2, sample=False)
+
+        def step(it, batch):
+            with tr.step("solver.step", it, "solver.prep") as span:
+                span.phase("solver.enqueue")
+                span.watch(f, (batch["x"],), ("data",))
+                f(batch["x"])
+            sa.observe(it, span.host_s, sample=False)
+
+        step(0, {"x": np.ones(3, np.float32)})
+        step(1, {"x": np.ones(4, np.float32)})  # shape change -> retrace
+        step(2, {"x": np.ones(4, np.float32)})  # steady: nothing
         evs = events_of(buf)
         rec = [e for e in evs if e["event"] == "recompile"]
         assert len(rec) == 2
         assert rec[0]["first"] is True and rec[0]["reason"] == "first_compile"
-        assert rec[1]["first"] is False
+        assert rec[0]["cause"] == ["first"] and rec[0]["cache_size"] == 1
+        assert rec[1]["first"] is False and rec[1]["iter"] == 1
         assert rec[1]["reason"] == "shape_change"
+        assert rec[1]["cause"] == ["data: shape (3,) -> (4,)"]
+        assert rec[1]["cache_size"] == 2 and rec[1]["cache"] == "off"
+        assert rec[1]["lower_s"] > 0 and rec[1]["backend_s"] > 0
         assert sa.recompiles == 1           # beyond the expected first
+        # the event is the schema's, cause and all
+        assert set(rec[1]) - {"event", "t", "run"} \
+            <= set(EVENTS["recompile"]["fields"])
 
     def test_sampling_and_summary(self):
         buf = io.StringIO()
@@ -257,6 +277,48 @@ class TestSolverObs:
         assert comms["h2d_bytes"] == sum(np.asarray(v).nbytes
                                          for v in b.values())
         assert comms["strategy"] == "Solver"
+
+    def test_recompile_event_says_the_cause_to_report_and_monitor(self):
+        """One source for the operator's `recompile` event (PR 51): the
+        tracer's `program.build` under the step's enqueue, with what
+        differed in the step's arguments."""
+        from sparknet_tpu.obs.event_schema import EVENTS
+        from sparknet_tpu.obs.monitor import MonitorState
+        s, buf = self._solver()
+        data = toy_batches()
+        s.train_step(next(data))
+        s.train_step(next(data))
+        odd = next(data)
+        odd["label"] = odd["label"].astype(np.int16)
+        s.train_step(odd)
+        s.train_step(odd)
+        s.close()
+        evs = events_of(buf)
+        first, again = [e for e in evs if e["event"] == "recompile"]
+        for e in (first, again):
+            assert set(e) - {"event", "t", "run"} == \
+                set(EVENTS["recompile"]["fields"])
+        assert first["first"] and first["cause"] == ["first"]
+        assert first["iter"] == 0 and first["cache_size"] == 1
+        assert again["iter"] == 2 and again["cache_size"] == 2
+        assert again["first"] is False and again["reason"] == "shape_change"
+        assert again["cause"] == ["batch/label: dtype int32 -> int16"]
+        assert again["cache"] == "off" and again["backend_s"] > 0
+        summary = next(e for e in evs if e["event"] == "step_summary")
+        assert summary["recompiles"] == 1
+        # memstats counts the same records where it samples (the first
+        # two steps here, before the rebuild)
+        assert [e["compile_cache"] for e in evs
+                if e["event"] == "memstats"] == [1, 1]
+        text = obs_report.render(obs_report.aggregate(evs))
+        assert "unexpected recompiles: 1" in text
+        assert "step 2 rebuilt" in text
+        assert "batch/label: dtype int32 -> int16" in text
+        mon = MonitorState()
+        for e in evs:
+            mon.update(e)
+        assert "recompiles 1" in mon.render()
+        assert "(step 2: batch/label: dtype int32 -> int16)" in mon.render()
 
     def test_dp_comms_byte_counters_two_device_mesh(self):
         from sparknet_tpu.parallel import DataParallelSolver, make_mesh

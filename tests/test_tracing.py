@@ -5,8 +5,12 @@ scopes and kernel names in the lowered step."""
 
 import contextlib
 import glob
+import json
+import os
 import re
 import signal
+import subprocess
+import sys
 import threading
 import time
 
@@ -454,3 +458,419 @@ def test_every_pallas_call_has_its_name(name):
             q, v, q, True).sum())(q)),
     }
     assert f"name={name}" in str(jax.make_jaxpr(fns[name])())
+
+
+# ------------------------------------------- set-up as a closed ledger (PR 51)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmark")
+
+
+def _child(code, timeout=600, **env_over):
+    """Run `code` in a fresh process of this checkout -> its stdout."""
+    env = dict(os.environ, **env_over)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    res = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
+                         capture_output=True, text=True, timeout=timeout)
+    assert res.returncode == 0, res.stderr[-3000:]
+    return res.stdout
+
+
+def _mlp(batch=8, dim=16, classes=4):
+    """The smallest net a Solver steps: two blobs to fill, one to feed."""
+    net = Message("NetParameter", name="mlp")
+    net.add("layer", name="d", type="JavaData", top=["data"],
+            java_data_param=dict(shape=dict(dim=[batch, dim])))
+    net.add("layer", name="l", type="JavaData", top=["label"],
+            java_data_param=dict(shape=dict(dim=[batch])))
+    net.add("layer", name="fc", type="InnerProduct", bottom=["data"],
+            top=["fc"], inner_product_param=dict(
+                num_output=classes, weight_filler=dict(type="xavier")))
+    net.add("layer", name="loss", type="SoftmaxWithLoss",
+            bottom=["fc", "label"], top=["loss"])
+    return net
+
+
+def _mlp_batch(batch=8, dim=16, classes=4, label=np.int32):
+    rs = np.random.RandomState(0)
+    return {"data": rs.randn(batch, dim).astype(np.float32),
+            "label": rs.randint(0, classes, batch).astype(label)}
+
+
+def _step_builds(tr, mark=0):
+    return [b for b in tr.since(mark, "program.build")
+            if b["parent"] == "solver.enqueue"]
+
+
+def test_every_executable_has_exactly_one_program_build():
+    tr = Tracer(None)
+
+    def build_probe_f(x):
+        return (x * 5.0 - 2.0).sum()
+
+    def build_probe_g(x):
+        return x + 7.0
+
+    f, g = jax.jit(build_probe_f), jax.jit(build_probe_g)
+    a, b = jnp.ones((3, 5)), jnp.ones((4, 5))
+    before = tr.builds
+    with tr.hot_span("caller"):
+        f(a).block_until_ready()
+        g(a).block_until_ready()
+        f(b).block_until_ready()                # another shape: f again
+        f(a).block_until_ready()                # cached: nothing
+    backends = tr.spans("compile.backend")
+    builds = tr.spans("program.build")
+    # one for one with jax's backend events, whatever else was built
+    assert [x["fun_name"] for x in builds] == \
+        [x["fun_name"] for x in backends]
+    assert tr.builds - before == len(builds)
+    mine = [x for x in builds if "build_probe" in x["fun_name"]]
+    assert [(x["fun_name"], x["nth"]) for x in mine] == [
+        ("jit(build_probe_f)", 1), ("jit(build_probe_g)", 1),
+        ("jit(build_probe_f)", 2)]
+    lowers = {(x["fun_name"], round(x["seconds"], 6)): x
+              for x in tr.spans("compile.lower")}
+    for x in mine:
+        assert x["parent"] == "caller" and x["cache"] == "off"
+        assert "cache_load_s" not in x and "iter" not in x
+        assert x["lower_s"] > 0 and x["backend_s"] > 0
+        # from the start of its lowering to the end of the backend's event
+        low = lowers[(x["fun_name"], x["lower_s"])]
+        back = next(c for c in backends if c["fun_name"] == x["fun_name"]
+                    and c["seconds"] == x["backend_s"])
+        assert x["start_ms"] == low["start_ms"]
+        assert x["start_ms"] + x["dur_ms"] == pytest.approx(
+            back["start_ms"] + back["dur_ms"], abs=1e-3)
+        assert x["dur_ms"] >= 1e3 * (x["lower_s"] + x["backend_s"]) - 1e-3
+
+
+_CACHE_CHILD = """
+import json
+import jax, jax.numpy as jnp
+from sparknet_tpu.obs.trace import Tracer
+tr = Tracer(None)
+def cache_probe(x):
+    return (x * 3.0).sum() + 1.0
+with tr.hot_span("caller"):
+    jax.jit(cache_probe)(jnp.ones((6, 6))).block_until_ready()
+print("BUILD " + json.dumps([b for b in tr.spans("program.build")
+                             if b["fun_name"] == "jit(cache_probe)"]))
+"""
+
+
+def test_cache_reads_miss_then_hit_across_processes_and_off_without(tmp_path):
+    def build(**env):
+        out = _child(_CACHE_CHILD, JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+                     JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1", **env)
+        (b,) = json.loads(next(ln for ln in out.splitlines()
+                               if ln.startswith("BUILD "))[6:])
+        return b
+
+    shared = dict(JAX_ENABLE_COMPILATION_CACHE="true",
+                  JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    first, second = build(**shared), build(**shared)
+    assert first["cache"] == "miss" and "cache_load_s" not in first
+    assert second["cache"] == "hit" and second["cache_load_s"] > 0
+    assert second["nth"] == 1           # per process
+    off = build(JAX_ENABLE_COMPILATION_CACHE="false")
+    assert off["cache"] == "off" and "cache_load_s" not in off
+
+
+@pytest.mark.parametrize("what", ["dtype", "committed"])
+def test_a_rebuild_names_the_leaf_that_differs(what):
+    tr = Tracer(None)
+    s = _solver(_mlp(), tracer=tr)
+    s.train_step(_mlp_batch())
+    s.train_step(_mlp_batch())
+    (first,) = _step_builds(tr)
+    assert first["cause"] == ["first"] and first["changed"] == 0
+    assert first["iter"] == 0 and first["fun_name"] == "jit(step)"
+    mark = tr.mark()
+    if what == "dtype":
+        s.train_step(_mlp_batch(label=np.int16))
+        want = "batch/label: dtype int32 -> int16"
+    else:
+        s.params["fc"][1] = jax.device_put(s.params["fc"][1],
+                                           jax.devices()[0])
+        s.train_step(_mlp_batch())
+        want = "params/fc/1: committed False -> True"
+    (again,) = _step_builds(tr, mark)
+    assert again["cause"] == [want] and again["changed"] == 1
+    assert again["iter"] == 2 and again["nth"] >= 2
+    if what == "committed":
+        # one committed argument commits every result: the next step is
+        # built once more, and says so leaf by leaf, alike leaves once
+        mark = tr.mark()
+        s.train_step(_mlp_batch())
+        (third,) = _step_builds(tr, mark)
+        assert third["changed"] > 1 and third["cause"][0].startswith(
+            "params/fc/0: committed False -> True")
+        assert any(c.startswith("history/fc/") and " more of history)" in c
+                   for c in third["cause"])
+
+
+def test_a_retrace_with_nothing_changed_says_same_signature():
+    tr = Tracer(None)
+    s = _solver(_mlp(), tracer=tr)
+    s.train_step(_mlp_batch())
+    mark = tr.mark()
+    jax.clear_caches()          # jax retraces for a reason of its own
+    s.train_step(_mlp_batch())
+    (again,) = _step_builds(tr, mark)
+    assert again["cause"] == ["same signature"] and again["changed"] == 0
+
+
+def test_steady_steps_take_no_signature_and_write_no_build(monkeypatch):
+    from sparknet_tpu.obs import trace
+    taken = []
+    real = trace.signature
+    monkeypatch.setattr(trace, "signature",
+                        lambda *a, **k: taken.append(1) or real(*a, **k))
+    tr = Tracer(None)
+    s = _solver(_mlp(), tracer=tr)
+    batch = _mlp_batch()
+    s.train_step(batch)
+    assert len(taken) == 1      # the first build's, kept for the next
+    s.train_step(batch)
+    del taken[:]
+    mark, builds = tr.mark(), tr.builds
+    for _ in range(5):
+        s.train_step(batch)
+    assert not taken and tr.builds == builds
+    # the parent's three records a step, and nothing else
+    assert [r["name"] for r in tr.since(mark)] == \
+        ["solver.prep", "solver.enqueue", "solver.step"] * 5
+
+
+@pytest.mark.parametrize("cls", ["Solver", "DataParallelSolver"])
+def test_plain_and_mesh_solvers_record_cause_through_the_same_code(
+        cls, monkeypatch):
+    from sparknet_tpu import parallel
+    explained = []
+    real = Tracer._explain_builds
+    monkeypatch.setattr(Tracer, "_explain_builds",
+                        lambda self, step: explained.append(
+                            step.attrs["iter"]) or real(self, step))
+    tr = Tracer(None)
+    sp = Message("SolverParameter", base_lr=0.01, lr_policy="fixed",
+                 display=0, random_seed=1)
+    make = Solver if cls == "Solver" else getattr(parallel, cls)
+    s = make(sp, net_param=_mlp(), log_fn=None, tracer=tr)
+    for _ in range(3):
+        s.train_step(_mlp_batch())
+    built = _step_builds(tr)
+    assert built and all("cause" in b and "changed" in b for b in built)
+    assert built[0]["cause"] == ["first"]
+    assert [b["cause"] for b in built if b["fun_name"] == "jit(step)"][0] \
+        == ["first"]
+    # only a step that built something was explained
+    assert explained == sorted({b["iter"] for b in
+                                tr.spans("program.build") if "iter" in b})
+    # the mesh solver commits what it lays over the mesh: nothing changes
+    # between its first call and its second, and the step is built once
+    if cls == "DataParallelSolver":
+        assert {b["iter"] for b in built} == {0}
+
+
+_KERNEL_IMPORT_CHILD = """
+import json, os, sys
+import numpy as np
+from sparknet_tpu.models import zoo
+from sparknet_tpu.obs.trace import default_tracer
+from sparknet_tpu.proto import Message
+from sparknet_tpu.solver.solver import Solver
+
+def solver():
+    return Solver(Message("SolverParameter", base_lr=0.01, lr_policy="fixed",
+                          display=0), log_fn=None,
+                  net_param=zoo.caffenet(batch_size=2, num_classes=10))
+batch = {"data": np.zeros((2, 3, 227, 227), np.float32),
+         "label": np.zeros((2,), np.int32)}
+tr = default_tracer()
+s = solver()
+s.train_step(batch); s.train_step(batch)
+print("CNN " + json.dumps([tr.spans("import.kernel"),
+                           tr.spans("package.import")]))
+os.environ["SPARKNET_LRN"] = "pallas"      # the same net, its LRN a kernel
+mark = tr.mark()
+s = solver()
+s.train_step(batch); s.train_step(batch)
+s = solver()
+s.train_step(batch)
+print("KERNEL " + json.dumps(tr.since(mark, "import.kernel",
+                                      "package.import", "compile.trace")))
+"""
+
+
+def test_import_kernel_is_written_once_and_never_by_a_caffenet_step():
+    out = _child(_KERNEL_IMPORT_CHILD)
+    lines = {ln.split(" ", 1)[0]: json.loads(ln.split(" ", 1)[1])
+             for ln in out.splitlines() if ln.startswith(("CNN ", "KERNEL "))}
+    kernel_imports, (package,) = lines["CNN"]
+    assert kernel_imports == []
+    # PR 30's rule as a recorded fact: no pallas before, none after
+    assert package["pallas"] is False and package["modules"] > 10
+    assert package["dur_ms"] > 0 and package["parent"] is None
+    recs = lines["KERNEL"]
+    (imp,) = [r for r in recs if r["name"] == "import.kernel"]
+    assert imp["module"] == "sparknet_tpu.ops.pallas_lrn"
+    assert imp["dur_ms"] > 0
+    # inside the trace that met the layer, which used to hide it
+    assert any(r["name"] == "compile.trace"
+               and r["start_ms"] <= imp["start_ms"]
+               and r["start_ms"] + r["dur_ms"]
+               >= imp["start_ms"] + imp["dur_ms"] for r in recs)
+    # and the package's import is said once a process
+    assert not [r for r in recs if r["name"] == "package.import"]
+
+
+_SETUP_PARTS_CHILD = """
+import importlib, json, sys
+sys.path.insert(0, "benchmark")
+import run
+rc = run.main(["--workload", sys.argv[1], "--rehearse", "--trace", "1",
+               "--seed", "3000000019"])
+import setup_parts
+from sparknet_tpu.obs.trace import default_tracer
+names = ["setup_import_s", "setup_kernel_import_s", "setup_init_programs",
+         "setup_init_build_s", "setup_step_trace_s", "setup_step_lower_s",
+         "setup_step_backend_s", "step_rebuilds", "setup_outside_s"]
+led = setup_parts.ledger({})
+print("READ " + json.dumps({
+    "rc": rc, "ledger": led,
+    "values": {n: importlib.import_module("layer_metrics." + n).read({})
+               for n in names},
+    "records": len(default_tracer().spans())}))
+"""
+
+
+@pytest.mark.parametrize("cell", ["caffenet_b1536_resident",
+                                  "lfm2moe_ep4_s8192_b3"])
+def test_setup_parts_of_a_rehearsal_add_up_and_every_reader_reads(cell):
+    out = _child(_SETUP_PARTS_CHILD.replace("sys.argv[1]", repr(cell)),
+                 timeout=900, JAX_PLATFORMS="cpu")
+    said = [ln for ln in out.splitlines() if ln.startswith("# setup parts ")]
+    assert len(said) == 1                   # once a process
+    line = json.loads(said[0][len("# setup parts "):])
+    got = json.loads(next(ln for ln in out.splitlines()
+                          if ln.startswith("READ "))[5:])
+    assert got["rc"] == 0
+    led, values = got["ledger"], got["values"]
+    assert abs(sum(led["parts"].values()) - led["interval_s"]) < 1e-3
+    assert abs(line["sum_s"] - line["interval_s"]) < 1e-3
+    assert set(led["parts"]) == set(line["parts"]) and len(led["parts"]) == 11
+    # the interval is the harness's own set-up, less what precedes the
+    # package's first import
+    assert abs(led["setup_after_import_s"] - led["interval_s"]) < 0.2
+    assert all(isinstance(v, (int, float)) and v >= 0
+               for v in values.values()), values
+    assert values["setup_import_s"] == led["parts"]["import"] > 0
+    assert values["setup_outside_s"] == led["parts"]["outside"] > 0
+    assert values["setup_init_programs"] > 4
+    assert values["setup_init_build_s"] > 0
+    assert values["setup_step_trace_s"] > 0
+    assert values["setup_step_lower_s"] > 0
+    assert values["setup_step_backend_s"] > 0
+    # the harness commits the weights it seeds and not the history it
+    # zeroes: the step's second call is built again, and says why
+    assert values["step_rebuilds"] == 1
+    first, second = led["step_builds"]
+    assert first["cause"] == ["first"] and second["iter"] == 1
+    assert any(c.startswith("history/") and "committed False -> True" in c
+               for c in second["cause"])
+    if cell.startswith("caffenet"):
+        assert values["setup_kernel_import_s"] == 0
+        assert led["kernel_modules"] == [] and led["pallas"] is False
+    else:       # the toy LM's flash pass runs its kernel, interpreted
+        assert values["setup_kernel_import_s"] > 0
+        assert led["kernel_modules"] == ["sparknet_tpu.ops.pallas_attention"]
+        assert values["setup_import_s"] >= values["setup_kernel_import_s"]
+
+
+@pytest.fixture
+def setup_parts(monkeypatch):
+    if BENCH not in sys.path:
+        monkeypatch.syspath_prepend(BENCH)
+    import harness
+    import program_spans
+    import setup_parts
+    tr = Tracer(None)
+    said = []
+    monkeypatch.setattr(program_spans, "default_tracer", lambda: tr)
+    monkeypatch.setattr(harness, "say", said.append)
+    monkeypatch.setattr(setup_parts, "_cache", [])
+    return setup_parts, tr, said
+
+
+def _hand_made_setup(tr, build=True):
+    """A set-up's records by hand, in ms of the ring's clock: import
+    0-100, init 100-400 with a 50 ms build, a step 500-800 that traces
+    for 100, lowers for 50 and compiles for 100, the window's step at
+    1000."""
+    ms = 1_000_000
+    t0 = tr.t0
+    tr.record("package.import", t0, t0 + 100 * ms, modules=40, pallas=False)
+    stack = tr._stack()
+
+    def put(name, a, b, parent=None, **kw):
+        tr._put(name, t0 + a * ms, t0 + b * ms, 1 if parent else 0, parent,
+                kw, False)
+
+    if build:
+        put("program.build", 200, 250, "net.init", fun_name="jit(fill)",
+            nth=1, lower_s=0.01, backend_s=0.04, cache="off")
+    put("net.init", 150, 300, "solver.init")
+    put("solver.init", 100, 400)
+    put("solver.prep", 500, 510, "solver.step", iter=0)
+    put("import.kernel", 520, 540, "solver.enqueue", module="m")
+    put("compile.trace", 510, 610, "solver.enqueue", fun_name="step")
+    put("compile.lower", 610, 660, "solver.enqueue", fun_name="jit(step)")
+    put("compile.backend", 670, 770, "solver.enqueue", fun_name="jit(step)")
+    if build:
+        put("program.build", 610, 770, "solver.enqueue",
+            fun_name="jit(step)", nth=1, lower_s=0.05, backend_s=0.1,
+            cache="off", iter=0, cause=["first"], changed=0)
+    put("solver.enqueue", 510, 790, "solver.step", iter=0)
+    put("solver.step", 500, 800, iter=0)
+    put("solver.step", 1000, 1010, iter=1)
+    assert not stack
+
+
+def test_setup_parts_partition_gives_every_instant_to_one_part(setup_parts):
+    sp, tr, said = setup_parts
+    _hand_made_setup(tr)
+    led = sp.ledger({"dispatch_s": [0.01]})
+    assert led["interval_s"] == pytest.approx(1.0)
+    want = {"import": 0.12, "net.build": 0.0, "init.build": 0.05,
+            "init.rest": 0.25, "step.prep": 0.01, "step.trace": 0.08,
+            "step.lower": 0.05, "step.backend": 0.1,
+            "step.enqueue_rest": 0.04, "fetch": 0.0, "outside": 0.3}
+    assert led["parts"] == pytest.approx(want, abs=1e-9)
+    assert sum(led["parts"].values()) == pytest.approx(1.0, abs=1e-9)
+    assert led["init_programs"] == 1 and led["step_rebuilds"] == 0
+    assert led["kernel_import_s"] == pytest.approx(0.02)
+    assert len(said) == 1 and said[0].startswith("# setup parts {")
+    sp.ledger({"dispatch_s": [0.01]})
+    assert len(said) == 1                   # computed and said once
+
+
+@pytest.mark.parametrize("why", ["dropped", "no program.build"])
+def test_setup_parts_gives_none_and_says_why(setup_parts, why):
+    sp, tr, said = setup_parts
+    if why == "dropped":
+        _hand_made_setup(tr)
+        # the ring wraps: set-up's oldest records fall off its left end
+        for _ in range(tr.max_buffer - len(tr.spans()) + 1):
+            tr.record("prefetch.wait", tr.t0, tr.t0)
+        assert tr.dropped == 1
+        assert not tr.spans("package.import")
+        want = "the ring dropped 1 records"
+    else:
+        _hand_made_setup(tr, build=False)       # a parent from before PR 51
+        want = "the program writes no program.build record"
+    assert sp.ledger({"dispatch_s": [0.01]}) is None
+    assert sp.seconds({"dispatch_s": [0.01]}, "import") is None
+    assert sp.count({"dispatch_s": [0.01]}, "step_rebuilds") is None
+    assert len(said) == 1
+    assert said[0].startswith("# setup parts none: ") and want in said[0]
